@@ -809,6 +809,10 @@ def worker_main(address, authkey: bytes, worker_id: str, session_name: str, env_
     # JAX_PLATFORMS / XLA_FLAGS take effect in this process).
     if env_vars:
         os.environ.update(env_vars)
+    # Before anything here can import jax (see compile_cache.py).
+    from ray_tpu._private import compile_cache
+
+    compile_cache.apply_default()
     if os.environ.get("RAY_TPU_BOOT_TRACE"):
         import time as _t
 

@@ -13,9 +13,10 @@ drive.  Design:
 - Layers are stacked on a leading `layers` axis and run under `lax.scan`
   (one compiled layer body, O(1) compile time in depth) with optional
   `jax.checkpoint` rematerialization for HBM.
-- Attention dispatches to the pallas flash kernel on TPU, blockwise scan
-  otherwise (ray_tpu.ops.attention), or ring attention when the mesh has a
-  nontrivial `seq` axis.
+- Attention dispatches to the pallas flash kernel when lowered for TPU
+  (under shard_map when there is a mesh), the XLA forms otherwise
+  (ray_tpu.ops.attention), or ring attention when the mesh has a nontrivial
+  `seq` axis.
 """
 
 from __future__ import annotations
@@ -174,19 +175,6 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
 
 
-def _ambient_mesh():
-    """The mesh from an enclosing `with mesh:` scope, if any."""
-    try:
-        # jax.interpreters.pxla.thread_resources is deprecated (jax 0.8.2+);
-        # the underlying accessor lives in jax._src.mesh.
-        from jax._src.mesh import thread_resources
-    except ImportError:  # future relocation: fall back to the deprecated path
-        from jax.interpreters.pxla import thread_resources
-
-    m = thread_resources.env.physical_mesh
-    return None if m.empty else m
-
-
 def _fitting_axis(axis, mesh, dim: int) -> Optional[str]:
     """Resolve a rules entry to a single mesh axis name that divides dim."""
     if axis is None or mesh is None:
@@ -238,8 +226,13 @@ def _layer(
     q = checkpoint_name(q, "q")
     kk = checkpoint_name(kk, "k")
     vv = checkpoint_name(vv, "v")
-    ring_mesh = mesh if mesh is not None else _ambient_mesh()
-    ring_axis = _ring_axis(rules, ring_mesh, q)
+    batch_axes = head_ax = None
+    if rules is not None:
+        batch_axes = rules.get("act_batch")
+        head_ax = _fitting_axis(rules.get("act_heads"), mesh, q.shape[2])
+        if head_ax is not None and kk.shape[2] % mesh.shape[head_ax] != 0:
+            head_ax = None  # GQA kv heads don't divide: replicate heads
+    ring_axis = _ring_axis(rules, mesh, q)
     if ring_axis is not None:
         # Sequence parallelism: activations are seq-sharded, so full
         # attention would force XLA to all-gather the sequence.  Ring
@@ -248,18 +241,19 @@ def _layer(
         # counterpart).
         from ray_tpu.ops.ring_attention import ring_attention_sharded
 
-        head_ax = _fitting_axis(rules.get("act_heads"), ring_mesh, q.shape[2])
-        if head_ax is not None and kk.shape[2] % ring_mesh.shape[head_ax] != 0:
-            head_ax = None  # GQA kv heads don't divide: replicate heads
         attn = ring_attention_sharded(
-            q, kk, vv, ring_mesh,
+            q, kk, vv, mesh,
             seq_axis=ring_axis,
-            batch_axes=rules.get("act_batch"),
+            batch_axes=batch_axes,
             head_axis=head_ax,
             causal=True,
         )
     else:
-        attn = dot_product_attention(q, kk, vv, causal=True, impl=c.attention_impl)
+        attn = dot_product_attention(
+            q, kk, vv, causal=True, impl=c.attention_impl,
+            mesh=mesh if rules is not None else None,
+            batch_axes=batch_axes, head_axis=head_ax,
+        )
     attn = checkpoint_name(attn, "attn")
     attn_out = jnp.einsum("bshd,hde->bse", attn, layer_params["attn"]["wo"].astype(dt))
     x = x + constrain(attn_out, ("act_batch", "act_seq", "act_embed"))
@@ -361,8 +355,13 @@ def forward(
     rules: Optional[Rules] = None,
     mesh=None,
 ) -> jax.Array:
-    """Token ids [B, S] -> logits [B, S, vocab] (f32)."""
+    """Token ids [B, S] -> logits [B, S, vocab] (f32).
+
+    `rules` come with the `mesh` they refer to: ring attention, the pipeline
+    schedule and the flash kernel's shard_map are all built from it."""
     c = config
+    if rules is not None and mesh is None:
+        raise ValueError("forward(rules=...) needs the mesh the rules refer to")
     x = params["embed"]["tokens"].astype(c.dtype)[tokens]
     if rules is not None:
         x = with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"), rules, mesh)
@@ -371,12 +370,11 @@ def forward(
     # Pipeline parallelism: rules shard the LAYER STACK over the pipeline
     # axis — run the GPipe microbatch schedule instead of a plain scan
     # (each stage device holds n_layers/P layers).
-    pp_mesh = mesh if mesh is not None else _ambient_mesh()
     pp_axis = None
     if rules is not None and rules.get("layers") is not None:
         ax = rules["layers"]
         ax = ax[0] if isinstance(ax, tuple) else ax
-        size = pp_mesh.shape[ax] if (pp_mesh is not None and ax in pp_mesh.axis_names) else 1
+        size = mesh.shape[ax] if ax in mesh.axis_names else 1
         if size > 1:
             # Explicit pp intent: misconfigurations are ERRORS, not silent
             # fallbacks — replicated layers instead of pipelining would only
@@ -424,7 +422,7 @@ def forward(
             pp_fsdp_axis = pp_fsdp_axes.pop() if pp_fsdp_axes else None
     if pp_axis is not None:
         x = _run_layers_pipelined(
-            params["layers"], x, positions, c, pp_mesh, pp_axis,
+            params["layers"], x, positions, c, mesh, pp_axis,
             rules=rules, fsdp_axis=pp_fsdp_axis,
         )
     else:

@@ -119,10 +119,6 @@ def blockwise_attention(
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -132,26 +128,48 @@ def dot_product_attention(
     scale: Optional[float] = None,
     block_size: int = 512,
     impl: Optional[str] = None,
+    mesh=None,
+    batch_axes=None,
+    head_axis: Optional[str] = None,
 ) -> jax.Array:
     """Dispatching attention entry point used by models/.
 
     impl: None (auto) | "reference" | "blockwise" | "pallas".
-    Auto picks the pallas flash kernel on TPU when shapes are tile-aligned,
-    else the blockwise scan.
-    """
-    if impl is None:
-        if _on_tpu() and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[-1] % 128 == 0:
-            impl = "pallas"
-        elif q.shape[1] > block_size:
-            impl = "blockwise"
-        else:
-            impl = "reference"
-    if impl == "reference":
-        return reference_attention(q, k, v, causal=causal, scale=scale)
-    if impl == "blockwise":
-        return blockwise_attention(q, k, v, causal=causal, scale=scale, block_size=block_size)
-    if impl == "pallas":
-        from ray_tpu.ops.pallas.flash_attention import flash_attention
+    Auto gives a program LOWERED FOR TPU the pallas flash kernel when the
+    shapes are tile-aligned; every other lowering, and every other shape,
+    gets the XLA forms (blockwise scan beyond block_size, else reference).
+    The choice rides `jax.lax.platform_dependent`, so it follows the
+    platform a step is compiled for, not the process's default backend.
 
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    raise ValueError(f"unknown attention impl {impl!r}")
+    mesh / batch_axes / head_axis say how q, k, v are sharded.  GSPMD
+    partitions the XLA forms by itself; a Mosaic kernel it cannot, so with
+    a mesh the kernel runs under shard_map over those axes.
+    """
+
+    def reference(q, k, v):
+        return reference_attention(q, k, v, causal=causal, scale=scale)
+
+    def blockwise(q, k, v):
+        return blockwise_attention(
+            q, k, v, causal=causal, scale=scale, block_size=block_size
+        )
+
+    def pallas(q, k, v):
+        from ray_tpu.ops.pallas import flash_attention as fa
+
+        if mesh is None:
+            return fa.flash_attention(q, k, v, causal=causal, scale=scale)
+        return fa.flash_attention_sharded(
+            q, k, v, mesh, batch_axes=batch_axes, head_axis=head_axis,
+            causal=causal, scale=scale,
+        )
+
+    if impl is None:
+        xla = blockwise if q.shape[1] > block_size else reference
+        if q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[-1] % 128 == 0:
+            return jax.lax.platform_dependent(q, k, v, tpu=pallas, default=xla)
+        return xla(q, k, v)
+    forms = {"reference": reference, "blockwise": blockwise, "pallas": pallas}
+    if impl not in forms:
+        raise ValueError(f"unknown attention impl {impl!r}")
+    return forms[impl](q, k, v)

@@ -16,8 +16,14 @@ materialization, O(S) memory):
 
 Causal masking skips fully-masked tile pairs via pl.when predication.
 
-On non-TPU backends the kernels run in interpret mode, so tests on the
-virtual CPU mesh exercise the same code path.
+Which form of a kernel runs is decided by the platform the enclosing program
+is LOWERED for (`jax.lax.platform_dependent`), never by the process-global
+default backend: a TPU lowering gets the Mosaic kernel, a CPU lowering gets
+interpret mode (so tests on the virtual CPU mesh exercise the same kernel
+bodies), and no other platform lowers at all.
+
+GSPMD cannot partition a Mosaic call, so under a mesh the kernel runs inside
+`shard_map` over the batch and head axes (`flash_attention_sharded`).
 
 Reference parity note: the reference (Ray) has no attention kernels at all
 (SURVEY.md §5.7) — this is TPU-native new work.
@@ -31,22 +37,23 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only helpers; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
 _LANES = 128  # VPU lane count: row-scalar scratch is kept lane-broadcast
 
 
-def _scratch(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    # Generic scratch allocation: works in interpret mode (scratch is
-    # allocated there too, so this must be a real scratch spec).
-    return jax.ShapeDtypeStruct(shape, dtype)
+def _pallas_call(kernel, **kwargs):
+    """`pl.pallas_call` whose form follows the platform lowered for: the
+    Mosaic kernel for TPU, interpret mode for CPU, an error anywhere else."""
+    compiled = pl.pallas_call(kernel, **kwargs)
+    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+
+    def call(*args):
+        return jax.lax.platform_dependent(*args, tpu=compiled, cpu=interpreted)
+
+    return call
 
 
 # -- forward ---------------------------------------------------------------
@@ -108,7 +115,7 @@ def _fwd_kernel(
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l_safe)
 
 
-def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     # Kernels work in [B, H, S, D].
@@ -119,7 +126,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret):
     block_k = min(block_k, sk)
     grid = (b, h, sq // block_q, sk // block_k)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal)
-    out, lse = pl.pallas_call(
+    out, lse = _pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -136,11 +143,10 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _scratch((block_q, d), jnp.float32),
-            _scratch((block_q, _LANES), jnp.float32),
-            _scratch((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -252,7 +258,7 @@ def _bwd_dkv_kernel(
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, interpret):
+def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     block_q = min(block_q, sq)
@@ -267,7 +273,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, interpre
     ).transpose(0, 2, 1)[..., None]  # [B, H, Sq, 1]
 
     dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal)
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         dq_kernel,
         grid=(b, h, sq // block_q, sk // block_k),
         in_specs=[
@@ -282,12 +288,11 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, interpre
             (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[_scratch((block_q, d), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
     )(qt, kt, vt, dot, lse, delta)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal)
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         dkv_kernel,
         grid=(b, h, sk // block_k, sq // block_q),
         in_specs=[
@@ -307,10 +312,9 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, interpre
             jax.ShapeDtypeStruct((b, h, sk, d), v.dtype),
         ],
         scratch_shapes=[
-            _scratch((block_k, d), jnp.float32),
-            _scratch((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=interpret,
     )(qt, kt, vt, dot, lse, delta)
     return (
         dq.transpose(0, 2, 1, 3),
@@ -322,23 +326,17 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, interpre
 # -- custom_vjp wiring -----------------------------------------------------
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k):
     out, _ = _flash_fwd(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=_interpret(),
+        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k
     )
     return out
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k):
     out, lse = _flash_fwd(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=_interpret(),
+        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k
     )
     return out, (q, k, v, out, lse)
 
@@ -348,7 +346,6 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, re
     return _flash_bwd(
         q, k, v, out, lse, g,
         causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k,
-        interpret=_interpret(),
     )
 
 
@@ -381,16 +378,54 @@ def flash_attention(
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     # Shrink each tile to the largest 128-multiple divisor of its sequence
     # length (tail tiles would be silently dropped by the grid floor
-    # division); only truly ragged lengths fall back to the blockwise scan.
-    block_q = _fit_block(q.shape[1], block_q)
-    block_k = _fit_block(k.shape[1], block_k)
-    bwd_block_q = _fit_block(q.shape[1], bwd_block_q)
-    bwd_block_k = _fit_block(k.shape[1], bwd_block_k)
-    if None in (block_q, block_k, bwd_block_q, bwd_block_k):
-        from ray_tpu.ops.attention import blockwise_attention
+    # division).  A length no tile divides is the caller's to route
+    # elsewhere (ops.attention.dot_product_attention does): answering with
+    # a different algorithm here would hide that the kernel did not run.
+    blocks = (
+        _fit_block(q.shape[1], block_q),
+        _fit_block(k.shape[1], block_k),
+        _fit_block(q.shape[1], bwd_block_q),
+        _fit_block(k.shape[1], bwd_block_k),
+    )
+    if None in blocks:
+        raise ValueError(
+            f"flash_attention: no tile divides seq lengths q={q.shape[1]} "
+            f"k={k.shape[1]} (requested blocks {block_q}/{block_k}, bwd "
+            f"{bwd_block_q}/{bwd_block_k})"
+        )
+    return _flash(q, k, v, causal, scale, *blocks)
 
-        return blockwise_attention(q, k, v, causal=causal, scale=scale)
-    return _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k)
+
+def flash_attention_sharded(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    mesh: Mesh,
+    *,
+    batch_axes=("data", "fsdp"),
+    head_axis: Optional[str] = "tensor",
+    causal: bool = True,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """flash_attention on global [B, S, H, D] arrays sharded over a mesh.
+
+    Attention is independent per batch row and per head, so each device
+    runs the kernel on its own [B/b, S, H/h, D] block with no collective.
+    Specs are shape-fitted like ring_attention_sharded's: a dim the axes
+    do not divide runs replicated.  The caller passes head_axis=None when
+    the kv heads do not divide it (GQA), so q and k/v stay aligned."""
+    from ray_tpu.parallel.sharding import _fit_spec
+
+    spec = P(batch_axes, None, head_axis, None)
+    qspec, kspec = _fit_spec(q.shape, spec, mesh), _fit_spec(k.shape, spec, mesh)
+    body = functools.partial(flash_attention, causal=causal, scale=scale)
+    return jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=(qspec, kspec, kspec),
+        out_specs=qspec,
+        check_vma=False,
+    )(q, k, v)
 
 
 def _fit_block(s: int, requested: int) -> Optional[int]:

@@ -116,7 +116,7 @@ def ring_attention_sharded(
     shape-fitted — a dim that doesn't divide runs replicated, which is
     correct, just unsharded.
     """
-    from ray_tpu.parallel.sharding import _fit_spec, shard_map
+    from ray_tpu.parallel.sharding import _fit_spec
 
     def fit(x):
         spec = P(batch_axes, seq_axis, head_axis, None)
@@ -130,7 +130,7 @@ def ring_attention_sharded(
 
     qspec, kspec = fit(q), fit(k)
     body = functools.partial(ring_attention, axis_name=seq_axis, causal=causal)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(qspec, kspec, kspec),

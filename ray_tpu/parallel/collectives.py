@@ -308,8 +308,6 @@ def device_allreduce(x, mesh, axis: str = "data", op: str = "sum"):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel.sharding import shard_map
-
     key = (mesh, axis, op)
     run = _device_allreduce_cache.get(key)
     if run is None:
@@ -322,7 +320,7 @@ def device_allreduce(x, mesh, axis: str = "data", op: str = "sum"):
 
         @jax.jit
         def run(v):
-            return shard_map(
+            return jax.shard_map(
                 lambda s: reducer(s, axis),
                 mesh=mesh,
                 in_specs=P(axis),

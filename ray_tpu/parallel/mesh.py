@@ -18,7 +18,6 @@ import math
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
 from jax.sharding import Mesh
 
 # Canonical logical axis order.  Physical layout: the innermost axes ("tensor",
@@ -103,18 +102,16 @@ def build_mesh(
     """Materialize a jax.sharding.Mesh from a MeshSpec.
 
     Uses mesh_utils.create_device_mesh so the physical device order respects
-    ICI topology (nearest-neighbor rings per axis) on real TPU slices; on CPU
-    (virtual device testing) it falls back to a simple reshape.
+    ICI topology (nearest-neighbor rings per axis) on real TPU slices; for
+    CPU devices (virtual device testing) that function is a plain reshape.
+    A topology it cannot lay out raises: a reshape in its place would run,
+    slowly, with neighbours that are not neighbours.
     """
+    from jax.experimental import mesh_utils
+
     devices = list(devices if devices is not None else jax.devices())
     spec = (spec or MeshSpec()).resolve(len(devices))
-    shape = spec.shape()
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(spec.shape(), devices=devices)
     return Mesh(dev_array, spec.axis_names)
 
 

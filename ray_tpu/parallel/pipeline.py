@@ -167,8 +167,6 @@ def pipeline_apply(
     per stage per step, and their grads reduce-scatter back (ZeRO-style).
     Leaves with None (or dims that don't divide) stay replicated.
     """
-    from ray_tpu.parallel.sharding import shard_map
-
     n_stages = mesh.shape[axis]
     batch_axes = tuple(a for a in batch_axes if a in mesh.axis_names and mesh.shape[a] > 1)
     if n_microbatches is None:
@@ -201,7 +199,7 @@ def pipeline_apply(
         axis=axis,
         gather_dims=gather_dims,
     )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_spec, xspec),
@@ -356,8 +354,6 @@ def pipeline_train_step_1f1b(
     last stage; gradients come back with the leading stage dim, mean-
     normalized over microbatches, and psum'd over the batch axes (data-
     parallel reduction included, like any SPMD train step)."""
-    from ray_tpu.parallel.sharding import shard_map
-
     n_stages = mesh.shape[axis]
     batch_axes = tuple(
         a for a in batch_axes if a in mesh.axis_names and mesh.shape[a] > 1
@@ -381,7 +377,7 @@ def pipeline_train_step_1f1b(
             )
         return loss, grads
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_spec, xspec, xspec),

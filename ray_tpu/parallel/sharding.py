@@ -21,25 +21,6 @@ MeshAxis = Union[None, str, Tuple[str, ...]]
 Rules = Dict[str, MeshAxis]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """jax.shard_map across jax versions: the top-level export (and its
-    `check_vma` kwarg) only exist from jax 0.6; older jax has
-    jax.experimental.shard_map with the same semantics under `check_rep`."""
-    try:
-        from jax import shard_map as _shard_map
-
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
 # Logical axis vocabulary used by models/ (see models/transformer.py).
 # Parameter axes and activation axes are distinct namespaces (act_*): under
 # FSDP, params shard their embed dim over `fsdp` while activations shard

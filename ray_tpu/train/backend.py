@@ -40,7 +40,9 @@ class JaxConfig(BackendConfig):
     """SPMD mesh bootstrap over the worker group.
 
     coordinator_port 0 = pick a free port on rank 0's host.
-    platform: force a jax platform in workers (tests use "cpu").
+    platform: force a jax platform in workers.  Tests use "cpu"; a chip run
+    passes "tpu", which makes a missing chip an error at worker start.  With
+    None, jax picks, and without a chip it falls back to CPU with a warning.
     """
 
     coordinator_port: int = 0

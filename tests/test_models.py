@@ -87,7 +87,7 @@ def test_sequence_parallel_forward():
     mesh = build_mesh(MeshSpec(data=2, seq=4))
     rules = resolve_rules("sp")
     with mesh:
-        out = jax.jit(lambda p, t: forward(p, t, cfg, rules=rules))(params, toks)
+        out = jax.jit(lambda p, t: forward(p, t, cfg, rules=rules, mesh=mesh))(params, toks)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-4, rtol=1e-4)
 
 
@@ -105,7 +105,7 @@ def test_sp_actually_runs_ring_attention():
     rules = resolve_rules("sp")
     with mesh:
         compiled = (
-            jax.jit(lambda p, t: forward(p, t, cfg, rules=rules))
+            jax.jit(lambda p, t: forward(p, t, cfg, rules=rules, mesh=mesh))
             .lower(params, toks)
             .compile()
         )

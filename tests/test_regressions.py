@@ -57,10 +57,13 @@ def test_exit_actor_from_concurrent_actor(ray_start_regular):
 
 
 def test_flash_attention_ragged_lengths():
-    """Non-block-divisible sequence lengths must not silently drop tails."""
+    """Non-block-divisible sequence lengths must not silently drop tails:
+    the kernel shrinks its tile to a divisor, refuses a length no tile
+    divides (no quiet switch to another algorithm), and the dispatcher
+    routes such lengths to the XLA forms."""
     import jax
 
-    from ray_tpu.ops.attention import reference_attention
+    from ray_tpu.ops.attention import dot_product_attention, reference_attention
     from ray_tpu.ops.pallas.flash_attention import flash_attention
 
     key = jax.random.PRNGKey(0)
@@ -69,7 +72,11 @@ def test_flash_attention_ragged_lengths():
     k = jax.random.normal(kk, (1, 192, 2, 32))
     v = jax.random.normal(kv, (1, 192, 2, 32))
     ref = reference_attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
+    with pytest.raises(ValueError, match="no tile divides"):
+        flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+    out = dot_product_attention(q, k, v, causal=True, block_size=128)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
 
 

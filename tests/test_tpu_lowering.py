@@ -1,0 +1,58 @@
+"""The train step lowers for TPU with the flash kernel in it, on every mesh.
+
+No chip and no libtpu needed: `lowering_platforms=("tpu",)` cross-lowers
+from the virtual CPU devices, which is as far as Mosaic kernels go before
+the TPU compiler.  It is far enough to catch what CPU execution cannot: off
+TPU the auto dispatch runs XLA attention, which GSPMD partitions happily,
+while a Mosaic call on a mesh of more than one device refuses to lower unless
+it sits inside a shard_map ("Mosaic kernels cannot be automatically
+partitioned").
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import LMTrainContext, TransformerConfig
+from ray_tpu.parallel import MeshSpec, build_mesh
+
+# 128-aligned sequence and head_dim: the shapes the auto dispatch gives to
+# the kernel.  remat on, like the bench configuration.
+CFG = TransformerConfig.tiny(
+    n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, max_seq_len=128,
+    remat=True, remat_policy="qkv_attn",
+)
+
+
+def _lowered_text(n_devices, spec, strategy, platforms=None):
+    mesh = build_mesh(spec, devices=jax.devices()[:n_devices])
+    ctx = LMTrainContext(CFG, mesh=mesh, strategy=strategy)
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
+    traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
+    kw = {"lowering_platforms": platforms} if platforms else {}
+    return traced.lower(**kw).as_text()
+
+
+@pytest.mark.parametrize(
+    "n_devices,spec,strategy",
+    [
+        (1, MeshSpec(data=1), "dp"),
+        (4, MeshSpec(data=4), "dp"),
+        (4, MeshSpec(data=1, fsdp=4), "fsdp"),
+        (4, MeshSpec(data=1, fsdp=2, tensor=2), "fsdp_tp"),
+        (4, MeshSpec(data=2, tensor=2), "tp"),
+    ],
+    ids=["dp1", "dp4", "fsdp4", "fsdp_tp4", "tp4"],
+)
+def test_train_step_lowers_for_tpu_with_kernel(n_devices, spec, strategy):
+    text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",))
+    # forward, its remat recompute, and the two backward kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_cpu_lowering_has_no_kernel():
+    """The same step lowered for the CPU it runs on holds no Mosaic call
+    (and no interpret-mode kernel either: auto dispatch is XLA attention)."""
+    text = _lowered_text(1, MeshSpec(data=1), "dp")
+    assert "tpu_custom_call" not in text
