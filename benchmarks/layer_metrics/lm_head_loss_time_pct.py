@@ -1,0 +1,14 @@
+"""Self time of the device ops whose innermost program scope is `lm_head` or `loss`: the
+vocabulary projection and the cross entropy on its logits (forward, backward and
+recompute), as % of the traced window, mean over the devices (`benchmarks/lib/trace_scopes.py`)."""
+
+from benchmarks.lib import trace_scopes
+
+layer = "model"
+unit = "%"
+source = "device_trace"
+moves = "tokens_per_s_per_chip"
+
+
+def read(run):
+    return trace_scopes.share_pct(run, ("lm_head", "loss"))

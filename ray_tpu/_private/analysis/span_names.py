@@ -10,7 +10,9 @@ catalog at all.
 This pass makes both a CI property:
 
   * every `tracing.span("literal", ...)` / `span("literal", ...)` call
-    with a literal name is collected into a generated catalog
+    with a literal name, and every `tracing.annotate("literal")` (the
+    same span, also written to the profiler's clock), is collected into
+    a generated catalog
     (span_names.txt, regenerated by `scripts/ray_tpu_lint.py
     --fix-allowlist`), so the span namespace is reviewable in one file
     and README's tracing section has a source of truth;
@@ -41,13 +43,17 @@ CATALOG_HEADER = (
 )
 
 
+_SPAN_CALLS = ("span", "annotate")
+
+
 def _span_call_name(call: ast.Call) -> Optional[str]:
-    """The literal span name of a `span("...")` / `tracing.span("...")`
-    call, else None (not a span call, or a dynamic name)."""
+    """The literal span name of a `span("...")` / `tracing.span("...")` /
+    `tracing.annotate("...")` call, else None (not a span call, or a
+    dynamic name)."""
     func = call.func
     is_span = (
-        isinstance(func, ast.Name) and func.id == "span"
-    ) or (isinstance(func, ast.Attribute) and func.attr == "span")
+        isinstance(func, ast.Name) and func.id in _SPAN_CALLS
+    ) or (isinstance(func, ast.Attribute) and func.attr in _SPAN_CALLS)
     if not is_span or not call.args:
         return None
     first = call.args[0]

@@ -30,6 +30,7 @@ from ray_tpu.parallel.sharding import (
     resolve_rules,
     tree_shardings,
 )
+from ray_tpu.util import tracing
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -118,14 +119,17 @@ class LMTrainContext:
         def _train_step(state, batch):
             def loss_fn(params):
                 logits = forward(params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
-                return cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
+                with jax.named_scope("loss"):
+                    return cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
 
             loss, grads = jax.value_and_grad(loss_fn)(state["params"])
-            updates, opt_state = opt.update(grads, state["opt_state"], state["params"])
-            params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = opt.update(grads, state["opt_state"], state["params"])
+                params = optax.apply_updates(state["params"], updates)
+                grad_norm = optax.global_norm(grads)
             metrics = {
                 "loss": loss,
-                "grad_norm": optax.global_norm(grads),
+                "grad_norm": grad_norm,
                 "step": state["step"] + 1,
             }
             return (
@@ -156,7 +160,7 @@ class LMTrainContext:
 
     # -- public API -------------------------------------------------------
     def init_state(self, seed: int = 0) -> Dict[str, Any]:
-        with self.mesh:
+        with tracing.annotate("init_state"), self.mesh:
             return self._init(jax.random.PRNGKey(seed))
 
     def make_batch(self, batch) -> Dict[str, jax.Array]:
@@ -176,8 +180,9 @@ class LMTrainContext:
 
     def train_step(self, state, batch) -> Tuple[Dict, Dict]:
         if not all(isinstance(x, jax.Array) for x in jax.tree_util.tree_leaves(batch)):
-            batch = self.make_batch(batch)
-        with self.mesh:
+            with tracing.annotate("train_step/make_batch"):
+                batch = self.make_batch(batch)
+        with tracing.annotate("train_step/dispatch"), self.mesh:
             state, metrics = self._train_step(state, batch)
         return state, metrics
 

@@ -53,6 +53,7 @@ def init_moe_params(cfg: MoEConfig, key: jax.Array, dtype=jnp.float32) -> Dict:
     }
 
 
+@jax.named_scope("layer/mlp")  # the transformer layer's FFN scope (PERF.md section 3)
 def moe_ffn(
     params: Dict,
     x: jax.Array,

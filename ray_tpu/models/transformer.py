@@ -213,19 +213,22 @@ def _layer(
         return with_logical_constraint(h, axes, rules, mesh)
 
     dt = c.dtype
-    h = rms_norm(x, layer_params["ln1"], c.norm_eps)
-    q = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wq"].astype(dt))
-    kk = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wk"].astype(dt))
-    vv = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wv"].astype(dt))
-    q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
-    kk = constrain(kk, ("act_batch", "act_seq", "act_kv_heads", "act_head_dim"))
-    q = apply_rope(q, positions, theta=c.rope_theta)
-    kk = apply_rope(kk, positions, theta=c.rope_theta)
     from jax.ad_checkpoint import checkpoint_name
 
-    q = checkpoint_name(q, "q")
-    kk = checkpoint_name(kk, "k")
-    vv = checkpoint_name(vv, "v")
+    # The scopes name each region in the compiled step's op metadata, which
+    # is what a device trace can tell fusions apart by (PERF.md section 3).
+    with jax.named_scope("layer/attn_proj"):
+        h = rms_norm(x, layer_params["ln1"], c.norm_eps)
+        q = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wq"].astype(dt))
+        kk = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wk"].astype(dt))
+        vv = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wv"].astype(dt))
+        q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
+        kk = constrain(kk, ("act_batch", "act_seq", "act_kv_heads", "act_head_dim"))
+        q = apply_rope(q, positions, theta=c.rope_theta)
+        kk = apply_rope(kk, positions, theta=c.rope_theta)
+        q = checkpoint_name(q, "q")
+        kk = checkpoint_name(kk, "k")
+        vv = checkpoint_name(vv, "v")
     batch_axes = head_ax = None
     if rules is not None:
         batch_axes = rules.get("act_batch")
@@ -233,37 +236,40 @@ def _layer(
         if head_ax is not None and kk.shape[2] % mesh.shape[head_ax] != 0:
             head_ax = None  # GQA kv heads don't divide: replicate heads
     ring_axis = _ring_axis(rules, mesh, q)
-    if ring_axis is not None:
-        # Sequence parallelism: activations are seq-sharded, so full
-        # attention would force XLA to all-gather the sequence.  Ring
-        # attention keeps KV rotating over ICI instead
-        # (ops/ring_attention.py; SURVEY.md §5.7 — novel, no reference
-        # counterpart).
-        from ray_tpu.ops.ring_attention import ring_attention_sharded
+    with jax.named_scope("layer/attn_core"):
+        if ring_axis is not None:
+            # Sequence parallelism: activations are seq-sharded, so full
+            # attention would force XLA to all-gather the sequence.  Ring
+            # attention keeps KV rotating over ICI instead
+            # (ops/ring_attention.py; SURVEY.md §5.7 — novel, no reference
+            # counterpart).
+            from ray_tpu.ops.ring_attention import ring_attention_sharded
 
-        attn = ring_attention_sharded(
-            q, kk, vv, mesh,
-            seq_axis=ring_axis,
-            batch_axes=batch_axes,
-            head_axis=head_ax,
-            causal=True,
-        )
-    else:
-        attn = dot_product_attention(
-            q, kk, vv, causal=True, impl=c.attention_impl,
-            mesh=mesh if rules is not None else None,
-            batch_axes=batch_axes, head_axis=head_ax,
-        )
-    attn = checkpoint_name(attn, "attn")
-    attn_out = jnp.einsum("bshd,hde->bse", attn, layer_params["attn"]["wo"].astype(dt))
-    x = x + constrain(attn_out, ("act_batch", "act_seq", "act_embed"))
+            attn = ring_attention_sharded(
+                q, kk, vv, mesh,
+                seq_axis=ring_axis,
+                batch_axes=batch_axes,
+                head_axis=head_ax,
+                causal=True,
+            )
+        else:
+            attn = dot_product_attention(
+                q, kk, vv, causal=True, impl=c.attention_impl,
+                mesh=mesh if rules is not None else None,
+                batch_axes=batch_axes, head_axis=head_ax,
+            )
+        attn = checkpoint_name(attn, "attn")
+    with jax.named_scope("layer/attn_proj"):
+        attn_out = jnp.einsum("bshd,hde->bse", attn, layer_params["attn"]["wo"].astype(dt))
+        x = x + constrain(attn_out, ("act_batch", "act_seq", "act_embed"))
 
-    h = rms_norm(x, layer_params["ln2"], c.norm_eps)
-    gate = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_gate"].astype(dt))
-    up = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_up"].astype(dt))
-    ff = constrain(jax.nn.silu(gate) * up, ("act_batch", "act_seq", "act_mlp"))
-    down = jnp.einsum("bsf,fe->bse", ff, layer_params["mlp"]["w_down"].astype(dt))
-    x = x + constrain(down, ("act_batch", "act_seq", "act_embed"))
+    with jax.named_scope("layer/mlp"):
+        h = rms_norm(x, layer_params["ln2"], c.norm_eps)
+        gate = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_gate"].astype(dt))
+        up = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_up"].astype(dt))
+        ff = constrain(jax.nn.silu(gate) * up, ("act_batch", "act_seq", "act_mlp"))
+        down = jnp.einsum("bsf,fe->bse", ff, layer_params["mlp"]["w_down"].astype(dt))
+        x = x + constrain(down, ("act_batch", "act_seq", "act_embed"))
     return x
 
 
@@ -362,9 +368,10 @@ def forward(
     c = config
     if rules is not None and mesh is None:
         raise ValueError("forward(rules=...) needs the mesh the rules refer to")
-    x = params["embed"]["tokens"].astype(c.dtype)[tokens]
-    if rules is not None:
-        x = with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"), rules, mesh)
+    with jax.named_scope("embed"):
+        x = params["embed"]["tokens"].astype(c.dtype)[tokens]
+        if rules is not None:
+            x = with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"), rules, mesh)
     positions = jnp.arange(tokens.shape[1])
 
     # Pipeline parallelism: rules shard the LAYER STACK over the pipeline
@@ -420,29 +427,35 @@ def forward(
                 )
             pp_axis = ax
             pp_fsdp_axis = pp_fsdp_axes.pop() if pp_fsdp_axes else None
-    if pp_axis is not None:
-        x = _run_layers_pipelined(
-            params["layers"], x, positions, c, mesh, pp_axis,
-            rules=rules, fsdp_axis=pp_fsdp_axis,
-        )
-    else:
-        layer_fn = functools.partial(
-            _layer, positions=positions, config=c, rules=rules, mesh=mesh
-        )
-        if c.remat:
-            layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
+    # `layers` names what the loop over the stack itself costs (each layer's
+    # weights sliced out of the stack, gradients and residuals stacked back);
+    # the regions of `_layer` are named inside it.
+    with jax.named_scope("layers"):
+        if pp_axis is not None:
+            x = _run_layers_pipelined(
+                params["layers"], x, positions, c, mesh, pp_axis,
+                rules=rules, fsdp_axis=pp_fsdp_axis,
+            )
+        else:
+            layer_fn = functools.partial(
+                _layer, positions=positions, config=c, rules=rules, mesh=mesh
+            )
+            if c.remat:
+                layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
 
-        def scan_body(carry, layer_params):
-            return layer_fn(carry, layer_params), None
+            def scan_body(carry, layer_params):
+                return layer_fn(carry, layer_params), None
 
-        x, _ = jax.lax.scan(scan_body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
-    head = (
-        params["embed"]["tokens"].T if c.tie_embeddings else params["lm_head"]
-    ).astype(c.dtype)
-    logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
-    if rules is not None:
-        logits = with_logical_constraint(
-            logits, ("act_batch", "act_seq", "act_vocab"), rules, mesh
-        )
+            x, _ = jax.lax.scan(scan_body, x, params["layers"])
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], c.norm_eps)
+    with jax.named_scope("lm_head"):
+        head = (
+            params["embed"]["tokens"].T if c.tie_embeddings else params["lm_head"]
+        ).astype(c.dtype)
+        logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
+        if rules is not None:
+            logits = with_logical_constraint(
+                logits, ("act_batch", "act_seq", "act_vocab"), rules, mesh
+            )
     return logits

@@ -44,14 +44,20 @@ NEG_INF = -1e30
 _LANES = 128  # VPU lane count: row-scalar scratch is kept lane-broadcast
 
 
-def _pallas_call(kernel, **kwargs):
+def _pallas_call(kernel, *, name: str, **kwargs):
     """`pl.pallas_call` whose form follows the platform lowered for: the
-    Mosaic kernel for TPU, interpret mode for CPU, an error anywhere else."""
-    compiled = pl.pallas_call(kernel, **kwargs)
-    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+    Mosaic kernel for TPU, interpret mode for CPU, an error anywhere else.
+
+    `name` is how a profile finds the kernel: it names the Mosaic kernel and
+    is a `named_scope` around the call, so it is in the op's metadata
+    whatever the compiler calls the instruction (`branch_0_fun.N`, after
+    `platform_dependent`'s branch)."""
+    compiled = pl.pallas_call(kernel, name=name, **kwargs)
+    interpreted = pl.pallas_call(kernel, name=name, interpret=True, **kwargs)
 
     def call(*args):
-        return jax.lax.platform_dependent(*args, tpu=compiled, cpu=interpreted)
+        with jax.named_scope(name):
+            return jax.lax.platform_dependent(*args, tpu=compiled, cpu=interpreted)
 
     return call
 
@@ -128,6 +134,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k):
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal)
     out, lse = _pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -275,6 +282,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
     dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal)
     dq = _pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(b, h, sq // block_q, sk // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -294,6 +302,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal)
     dk, dv = _pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(b, h, sk // block_k, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),

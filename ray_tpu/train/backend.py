@@ -61,9 +61,14 @@ def _pick_coordinator(port: int) -> str:
 def _init_jax_distributed(coordinator: str, world_size: int, rank: int, platform):
     import os
 
+    from ray_tpu.util import tracing
+
     if platform:
         os.environ["JAX_PLATFORMS"] = platform
-    import jax
+    # The two waits every chip worker pays before its loop's first line,
+    # split for `ray_tpu timeline` (RAY_TPU_TRACE=1).
+    with tracing.span("train::backend::import_jax"):
+        import jax
 
     if platform:
         jax.config.update("jax_platforms", platform)
@@ -73,9 +78,11 @@ def _init_jax_distributed(coordinator: str, world_size: int, rank: int, platform
             num_processes=world_size,
             process_id=rank,
         )
+    with tracing.span("train::backend::device_open"):
+        global_devices = len(jax.devices())
     return {
         "rank": rank,
-        "global_devices": len(jax.devices()),
+        "global_devices": global_devices,
         "local_devices": jax.local_device_count(),
     }
 

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import contextvars
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -168,6 +169,29 @@ def record_span(
         while len(_buffer) > _MAX_BUFFER:
             _buffer.pop(0)
     return c
+
+
+@contextmanager
+def annotate(name: str):
+    """A span of the PROGRAM's own host work on the profiler's clock.
+
+    Opens a `jax.profiler.TraceAnnotation` if and only if jax is already
+    imported (this module never imports it: the driver and the head stay
+    off jax), so under a profiler session the span lands on `/host:CPU`
+    next to the device planes; with tracing enabled the same interval is
+    also recorded through `record_span`, so `ray_tpu timeline` shows it.
+    With neither on it costs the annotation's one `TraceMe` check."""
+    jax = sys.modules.get("jax")
+    start = time.time() if _enabled else 0.0
+    try:
+        if jax is None:
+            yield
+        else:
+            with jax.profiler.TraceAnnotation(name):
+                yield
+    finally:
+        if _enabled:
+            record_span(name, start, time.time(), parent=_current.get())
 
 
 def drain_spans() -> List[Dict[str, Any]]:

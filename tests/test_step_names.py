@@ -1,0 +1,200 @@
+"""The names the train step gives its own work (PERF.md section 3): model
+scopes and kernel names in the lowered step, the program's host spans on the
+profiler's clock, and a compile-cache key that tells two builds apart when
+only those names differ."""
+
+import ast
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import LMTrainContext, TransformerConfig
+from ray_tpu.parallel import MeshSpec, build_mesh
+from ray_tpu.util import tracing
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SCOPES = ("embed", "layers", "layer/attn_proj", "layer/attn_core", "layer/mlp", "final_norm", "lm_head",
+          "loss", "optimizer")
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+CFG = TransformerConfig.tiny(
+    n_heads=2, n_kv_heads=1, d_model=256, d_ff=256, max_seq_len=128,
+    remat=True, remat_policy="qkv_attn",
+)
+
+
+@pytest.fixture(scope="module", params=[(1, MeshSpec(data=1), "dp"), (4, MeshSpec(data=1, fsdp=4), "fsdp")],
+                ids=["dp1", "fsdp4"])
+def lowered_for_tpu(request):
+    """The step cross-lowered for TPU from the virtual CPU devices, with its
+    locations printed: the op names a device trace will carry."""
+    n_devices, spec, strategy = request.param
+    mesh = build_mesh(spec, devices=jax.devices()[:n_devices])
+    ctx = LMTrainContext(CFG, mesh=mesh, strategy=strategy)
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
+    traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
+    return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_lowered_step_names_every_model_scope(lowered_for_tpu, scope):
+    assert f"/{scope}/" in lowered_for_tpu or f"({scope})" in lowered_for_tpu
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_lowered_step_names_the_three_kernels(lowered_for_tpu, kernel):
+    # (under shard_map the body is a function of its own, and MLIR locations nest per function:
+    # `layer/attn_core` is then on the caller's line, not the kernel's)
+    assert any(f"/{kernel}/" in line and "pallas_call" in line for line in lowered_for_tpu.splitlines())
+    assert "layer/attn_core/" in lowered_for_tpu
+
+
+def test_recomputed_ops_carry_the_scope_under_the_remat_marker(lowered_for_tpu):
+    """MLIR locations nest per function, so the whole path (`transpose(jvp())/while/...`)
+    is on no one line here; the recorded trace in benchmarks/tests holds those."""
+    assert '"checkpoint/rematted_computation/layer/mlp/' in lowered_for_tpu
+    assert '"layer/mlp/' in lowered_for_tpu and "transpose(" in lowered_for_tpu
+
+
+# -- tracing.annotate -------------------------------------------------------------------
+
+
+def test_annotate_without_jax_imports_nothing_and_records_nothing():
+    code = (
+        "import sys\n"
+        "from ray_tpu.util import tracing\n"
+        "with tracing.annotate('train_step/dispatch'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules and tracing.drain_spans() == []\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "RAY_TPU_TRACE"}
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env, capture_output=True, text=True)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_annotate_records_a_span_with_ray_tpu_trace_on():
+    code = (
+        "import sys\n"
+        "from ray_tpu.util import tracing\n"
+        "with tracing.span('outer') as outer:\n"
+        "    with tracing.annotate('train_step/make_batch'):\n"
+        "        pass\n"
+        "inner, top = tracing.drain_spans()\n"
+        "assert 'jax' not in sys.modules\n"
+        "assert (inner['name'], top['name']) == ('train_step/make_batch', 'outer')\n"
+        "assert inner['parent_span_id'] == top['span_id'] and inner['end'] >= inner['start']\n"
+    )
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=dict(os.environ, RAY_TPU_TRACE="1"),
+                          capture_output=True, text=True)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_annotate_opens_a_trace_annotation_once_jax_is_imported(monkeypatch):
+    opened = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(self.name)
+
+        def __exit__(self, *exc):
+            opened.append("/" + self.name)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    with tracing.annotate("init_state"):
+        opened.append("body")
+    assert opened == ["init_state", "body", "/init_state"]
+
+
+def test_train_step_is_split_into_make_batch_and_dispatch(monkeypatch):
+    import numpy as np
+
+    names = []
+    real = tracing.annotate
+    monkeypatch.setattr(tracing, "annotate", lambda name: names.append(name) or real(name))
+    ctx = LMTrainContext(CFG, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+    state = ctx.init_state(seed=0)
+    toks = np.zeros((2, 128), np.int32)
+    state, _ = ctx.train_step(state, {"tokens": toks, "targets": toks})
+    assert names == ["init_state", "train_step/make_batch", "train_step/dispatch"]
+    names.clear()
+    ctx.train_step(state, ctx.make_batch({"tokens": toks, "targets": toks}))  # already on the device
+    assert names == ["train_step/dispatch"]
+
+
+def test_backend_start_records_import_and_device_open_spans():
+    from ray_tpu.train.backend import _init_jax_distributed
+
+    tracing.drain_spans()
+    was = tracing.is_enabled()
+    tracing.enable_tracing()
+    try:
+        out = _init_jax_distributed("", 1, 0, None)
+    finally:
+        if not was:
+            tracing.disable_tracing()
+    names = [s["name"] for s in tracing.drain_spans()]
+    assert names == ["train::backend::import_jax", "train::backend::device_open"]
+    assert out["global_devices"] == len(jax.devices())
+
+
+def test_span_catalog_sees_annotate_calls():
+    from ray_tpu._private.analysis import span_names
+
+    calls = [n for n in ast.walk(ast.parse(
+        "with tracing.annotate('a/b'): pass\nwith span('c'): pass\nwith annotate(name): pass\nfoo('d')\n"))
+        if isinstance(n, ast.Call)]
+    assert sorted(filter(None, map(span_names._span_call_name, calls))) == ["a/b", "c"]
+    catalog = span_names.load_catalog(os.path.join(ROOT, "ray_tpu/_private/analysis/span_names.txt"))
+    assert {"init_state", "train_step/make_batch", "train_step/dispatch",
+            "train::backend::import_jax", "train::backend::device_open"} <= set(catalog)
+
+
+# -- the compile cache's key --------------------------------------------------------------
+
+
+def test_apply_default_puts_metadata_in_the_cache_key(monkeypatch, tmp_path):
+    from ray_tpu._private import compile_cache
+
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+    monkeypatch.delenv(compile_cache.METADATA_ENV, raising=False)
+    monkeypatch.setitem(sys.modules, "jax", None)  # as in a worker at entry: jax not imported yet
+    compile_cache.apply_default()
+    assert os.environ[compile_cache.METADATA_ENV] == "1"
+    monkeypatch.setenv(compile_cache.METADATA_ENV, "0")  # the operator's own choice stands
+    compile_cache.apply_default()
+    assert os.environ[compile_cache.METADATA_ENV] == "0"
+
+
+@pytest.mark.parametrize("keyed, entries", [("1", 2), ("0", 1)], ids=["metadata_in_key", "jax_default"])
+def test_two_builds_that_differ_only_by_a_scope_share_a_cache_entry_only_by_jax_default(tmp_path, keyed, entries):
+    """The trap: `jax.named_scope` changes debug info only, and JAX's default
+    key strips debug info, so build B would run build A's executable, whose
+    profile has A's names.  `apply_default` keys the metadata in."""
+    code = (
+        "import os, sys\n"
+        "from ray_tpu._private import compile_cache\n"
+        "compile_cache.apply_default()\n"
+        "import jax, jax.numpy as jnp, numpy as np\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
+        "jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)\n"
+        "def build(scope):\n"
+        "    def f(x):\n"
+        "        with jax.named_scope(scope):\n"
+        "            return jnp.tanh(x) @ x\n"
+        "    return jax.jit(f)\n"
+        "x = np.ones((64, 64), np.float32)\n"
+        "for scope in ('layer/mlp', 'layer/attn_proj'):\n"
+        "    build(scope)(x).block_until_ready()\n"
+        "print(len([n for n in os.listdir(os.environ['JAX_COMPILATION_CACHE_DIR']) if n.startswith('jit_f-') and n.endswith('-cache')]))\n"
+    )
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path), JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY=keyed)
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert int(proc.stdout.strip().splitlines()[-1]) == entries
