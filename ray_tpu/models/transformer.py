@@ -28,7 +28,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import dot_product_attention
+from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, dot_product_attention
 from ray_tpu.ops.rotary import apply_rope
 from ray_tpu.parallel.sharding import Rules, with_logical_constraint
 
@@ -49,9 +49,11 @@ class TransformerConfig:
     tie_embeddings: bool = False
     remat: bool = True
     # Remat granularity: None = full per-layer recompute (min memory);
-    # "attn" = save the attention kernel output (skips re-running the flash
-    # kernel in backward); "qkv_attn" = additionally save post-rope q/k/v
-    # (skips qkv matmul + rope recompute).  More saved = more HBM.
+    # "attn" = save what attention's backward needs of its forward, the
+    # output and the flash kernel's log-sum-exp (f32 [B, H, S]), so the
+    # kernel's forward runs once per layer; "qkv_attn" = additionally save
+    # post-rope q/k/v (skips qkv matmul + rope recompute).  More saved =
+    # more HBM.
     remat_policy: Optional[str] = None
     attention_impl: Optional[str] = None  # None=auto, see ops.attention
     # Microbatches per pipeline-stage schedule when the rules shard the
@@ -258,7 +260,6 @@ def _layer(
                 mesh=mesh if rules is not None else None,
                 batch_axes=batch_axes, head_axis=head_ax,
             )
-        attn = checkpoint_name(attn, "attn")
     with jax.named_scope("layer/attn_proj"):
         attn_out = jnp.einsum("bshd,hde->bse", attn, layer_params["attn"]["wo"].astype(dt))
         x = x + constrain(attn_out, ("act_batch", "act_seq", "act_embed"))
@@ -276,14 +277,18 @@ def _layer(
 def _remat_policy(config: TransformerConfig):
     """Validated checkpoint policy for the configured remat granularity
     (shared by the scan and pipeline paths)."""
+    # The attention op names its own residuals (ops/attention.py): a policy
+    # that keeps the output without the log-sum-exp would still re-run the
+    # kernel's forward in the backward pass.
     if config.remat_policy == "attn":
-        return jax.checkpoint_policies.save_only_these_names("attn")
+        return jax.checkpoint_policies.save_only_these_names(ATTN_OUT, ATTN_LSE)
     if config.remat_policy == "qkv_attn":
-        return jax.checkpoint_policies.save_only_these_names("q", "k", "v", "attn")
+        return jax.checkpoint_policies.save_only_these_names(
+            "q", "k", "v", ATTN_OUT, ATTN_LSE
+        )
     if config.remat_policy is None:
-        # Save nothing per layer (full recompute in bwd) — the minimum-
-        # memory mode long-context configs rely on (at 16k the qkv_attn
-        # stash alone is ~5 GB on the bench model, past v5e HBM).
+        # Save nothing per layer: the backward re-runs the whole layer, the
+        # flash forward included.  The minimum-memory mode.
         return None
     raise ValueError(
         f"unknown remat_policy {config.remat_policy!r}; "

@@ -17,8 +17,19 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e30
+
+# `checkpoint_name`s of what attention's backward needs besides q, k and v.
+# Each form names them where they are residuals of ITS backward, because a
+# name outside a `custom_vjp` does not reach the residual inside: the XLA
+# forms and ring attention name their result, the flash kernel names the
+# `out` and the log-sum-exp of its forward rule.  A remat policy that keeps
+# the attention output keeps both names (models/transformer.py), or the
+# backward re-runs the whole forward for the one it lacks.
+ATTN_OUT = "attn"
+ATTN_LSE = "attn_lse"
 
 
 def _repeat_kv(k: jax.Array, n_heads: int) -> jax.Array:
@@ -147,12 +158,14 @@ def dot_product_attention(
     """
 
     def reference(q, k, v):
-        return reference_attention(q, k, v, causal=causal, scale=scale)
+        out = reference_attention(q, k, v, causal=causal, scale=scale)
+        return checkpoint_name(out, ATTN_OUT)
 
     def blockwise(q, k, v):
-        return blockwise_attention(
+        out = blockwise_attention(
             q, k, v, causal=causal, scale=scale, block_size=block_size
         )
+        return checkpoint_name(out, ATTN_OUT)
 
     def pallas(q, k, v):
         from ray_tpu.ops.pallas import flash_attention as fa

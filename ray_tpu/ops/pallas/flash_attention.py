@@ -36,9 +36,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
+
+from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, _repeat_kv
 
 NEG_INF = -1e30
 _LANES = 128  # VPU lane count: row-scalar scratch is kept lane-broadcast
@@ -347,13 +350,19 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_bl
     out, lse = _flash_fwd(
         q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k
     )
+    # Named so that a remat policy can keep them (ops/attention.py); any
+    # other policy runs this forward again in the backward pass.  The
+    # log-sum-exp is saved lane-dense, [B, H, S]: as the kernels'
+    # [B, H, S, 1] operand its rows pad to 128 lanes.
+    out = checkpoint_name(out, ATTN_OUT)
+    lse = checkpoint_name(lse[..., 0], ATTN_LSE)
     return out, (q, k, v, out, lse)
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, res, g):
     q, k, v, out, lse = res
     return _flash_bwd(
-        q, k, v, out, lse, g,
+        q, k, v, out, lse[..., None], g,
         causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k,
     )
 
@@ -380,8 +389,6 @@ def flash_attention(
     would exceed the ~16MB VMEM scoped budget."""
     h = q.shape[2]
     if k.shape[2] != h:
-        from ray_tpu.ops.attention import _repeat_kv
-
         k = _repeat_kv(k, h)
         v = _repeat_kv(v, h)
     scale = scale if scale is not None else q.shape[-1] ** -0.5

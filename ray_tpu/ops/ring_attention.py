@@ -18,9 +18,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import NEG_INF, _repeat_kv
+from ray_tpu.ops.attention import ATTN_OUT, NEG_INF, _repeat_kv
 
 
 def _partial_attention(q, k, v, q_offset, k_offset, causal, scale):
@@ -130,10 +131,11 @@ def ring_attention_sharded(
 
     qspec, kspec = fit(q), fit(k)
     body = functools.partial(ring_attention, axis_name=seq_axis, causal=causal)
-    return jax.shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(qspec, kspec, kspec),
         out_specs=qspec,
         check_vma=False,
     )(q, k, v)
+    return checkpoint_name(out, ATTN_OUT)

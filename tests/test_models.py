@@ -5,13 +5,15 @@ reference's DDP-vs-FSDP wrapper tests (ray: python/ray/train/tests/
 test_torch_fsdp.py) — same model, different sharding rules, loss must agree.
 """
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ray_tpu.models import LMTrainContext, TransformerConfig, forward, init_params
-from ray_tpu.parallel import MeshSpec, build_mesh
+from ray_tpu.parallel import MeshSpec, build_mesh, resolve_rules
 
 
 CFG = TransformerConfig.tiny()
@@ -82,8 +84,6 @@ def test_sequence_parallel_forward():
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
     ref = forward(params, toks, cfg)
 
-    from ray_tpu.parallel import resolve_rules
-
     mesh = build_mesh(MeshSpec(data=2, seq=4))
     rules = resolve_rules("sp")
     with mesh:
@@ -99,8 +99,6 @@ def test_sp_actually_runs_ring_attention():
     params = init_params(cfg, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
 
-    from ray_tpu.parallel import resolve_rules
-
     mesh = build_mesh(MeshSpec(data=2, seq=4))
     rules = resolve_rules("sp")
     with mesh:
@@ -112,6 +110,96 @@ def test_sp_actually_runs_ring_attention():
     hlo = compiled.as_text()
     assert "collective-permute" in hlo, "ring attention not dispatched"
     assert hlo.count("all-gather") == 0, "sequence is being all-gathered"
+
+
+# -- remat policies and the residuals attention names ------------------------
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, through scan, cond, remat, shard_map, pjit."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _grad_jaxpr(cfg, strategy=None, spec=None, seq=128):
+    """jaxpr of the gradient of the model's forward, traced on shapes alone."""
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    toks = jnp.zeros((4, seq), jnp.int32)
+    kw = {}
+    if strategy is not None:
+        mesh = build_mesh(spec, devices=jax.devices()[: spec.size()])
+        kw = dict(rules=resolve_rules(strategy), mesh=mesh)
+    grad = jax.grad(lambda p: forward(p, toks, cfg, **kw).sum())
+    return jax.make_jaxpr(grad)(params).jaxpr
+
+
+@pytest.mark.parametrize(
+    "strategy,spec", [(None, None), ("fsdp", MeshSpec(data=1, fsdp=4))], ids=["nomesh", "fsdp4"]
+)
+@pytest.mark.parametrize("policy,fwd_per_bwd", [(None, 2), ("attn", 1), ("qkv_attn", 1)])
+def test_flash_forward_runs_once_when_the_policy_saves_attention(
+    policy, fwd_per_bwd, strategy, spec
+):
+    """The kernel names its backward's residuals (out, log-sum-exp), so a
+    policy that saves them leaves ONE forward kernel per backward kernel in
+    the gradient; full recompute re-runs it.  (Each kernel counts twice here,
+    once per `platform_dependent` branch; the ratio is what matters.)"""
+    cfg = TransformerConfig.tiny(attention_impl="pallas", remat=True, remat_policy=policy)
+    kernels = collections.Counter(
+        e.params["name"]
+        for e in _eqns(_grad_jaxpr(cfg, strategy, spec))
+        if e.primitive.name == "pallas_call"
+    )
+    assert kernels["flash_bwd_dq"] == kernels["flash_bwd_dkv"] > 0
+    assert kernels["flash_fwd"] == fwd_per_bwd * kernels["flash_bwd_dq"], kernels
+
+
+def test_saved_flash_residuals_give_the_gradients_of_no_remat():
+    """Backward from the saved out/lse == backward with nothing rematted, to
+    the tolerance test_ops_attention.py holds the kernel's gradients to."""
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, CFG.vocab_size)
+    grads = []
+    for kw in (dict(remat=True, remat_policy="qkv_attn"), dict(remat=False)):
+        cfg = TransformerConfig.tiny(attention_impl="pallas", **kw)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        grads.append(jax.jit(jax.grad(lambda p: forward(p, toks, cfg).mean()))(params))
+    for a, b in zip(*map(jax.tree_util.tree_leaves, grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "impl,strategy,spec",
+    [("reference", None, None), ("blockwise", None, None), ("reference", "sp", MeshSpec(data=2, seq=4))],
+    ids=["reference", "blockwise", "ring"],
+)
+def test_xla_and_ring_attention_save_their_output_under_attn(impl, strategy, spec):
+    """Off the kernel the op names its own result: under "attn" the forward
+    scan hands the backward ONE [L, B, S, H, D] stack and the backward does
+    not evaluate the named value again; under full recompute it does."""
+    found = {}
+    for policy in (None, "attn"):
+        cfg = TransformerConfig.tiny(attention_impl=impl, remat=True, remat_policy=policy)
+        eqns = list(_eqns(_grad_jaxpr(cfg, strategy, spec, seq=64)))
+        layers_fwd = next(
+            e for e in eqns if e.primitive.name == "scan" and e.params["length"] == cfg.n_layers
+        )
+        stacks = [v.aval.shape for v in layers_fwd.outvars[layers_fwd.params["num_carry"]:]]
+        found[policy] = dict(
+            attn_stacks=stacks.count((cfg.n_layers, 4, 64, cfg.n_heads, cfg.head_dim)),
+            named=sum(e.primitive.name == "name" and e.params["name"] == "attn" for e in eqns),
+            dots=sum(e.primitive.name == "dot_general" for e in eqns),
+        )
+    assert (found[None]["attn_stacks"], found[None]["named"]) == (0, 2)
+    assert (found["attn"]["attn_stacks"], found["attn"]["named"]) == (1, 1)
+    if impl == "reference" and strategy is None:
+        # the probs @ v einsum is the one matmul the saved output spares (the
+        # blockwise scan and the ring loop re-run whole for their own residuals)
+        assert found[None]["dots"] - found["attn"]["dots"] == 1
 
 
 @pytest.mark.slow  # pp_fsdp compile cost; sharding twins stay via sp tests

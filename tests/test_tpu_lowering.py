@@ -9,6 +9,10 @@ it sits inside a shard_map ("Mosaic kernels cannot be automatically
 partitioned").
 """
 
+import collections
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -24,14 +28,22 @@ CFG = TransformerConfig.tiny(
 )
 
 
-def _lowered_text(n_devices, spec, strategy, platforms=None):
+def _lowered_text(n_devices, spec, strategy, platforms=None, cfg=CFG):
     mesh = build_mesh(spec, devices=jax.devices()[:n_devices])
-    ctx = LMTrainContext(CFG, mesh=mesh, strategy=strategy)
+    ctx = LMTrainContext(cfg, mesh=mesh, strategy=strategy)
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
     traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
     kw = {"lowering_platforms": platforms} if platforms else {}
     return traced.lower(**kw).as_text()
+
+
+def _mosaic_kernels(text):
+    """Mosaic calls of a lowered step, counted by the kernel's name."""
+    return collections.Counter(
+        re.search(r'kernel_name = "(\w+)"', line).group(1)
+        for line in text.splitlines() if "@tpu_custom_call" in line
+    )
 
 
 @pytest.mark.parametrize(
@@ -47,8 +59,17 @@ def _lowered_text(n_devices, spec, strategy, platforms=None):
 )
 def test_train_step_lowers_for_tpu_with_kernel(n_devices, spec, strategy):
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",))
-    # forward, its remat recompute, and the two backward kernels
-    assert text.count("tpu_custom_call") >= 3
+    # `qkv_attn` saves the residuals the kernel names, so the forward kernel
+    # is in the step once: a second `flash_fwd` is the backward re-running it.
+    assert _mosaic_kernels(text) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+
+
+def test_full_recompute_reruns_the_forward_kernel():
+    """`remat_policy=None` saves nothing per layer, the kernel's residuals
+    included: the backward's recompute holds the forward kernel again."""
+    cfg = dataclasses.replace(CFG, remat_policy=None)
+    text = _lowered_text(1, MeshSpec(data=1), "dp", platforms=("tpu",), cfg=cfg)
+    assert _mosaic_kernels(text) == {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
 
 
 def test_cpu_lowering_has_no_kernel():
