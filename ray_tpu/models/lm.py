@@ -16,9 +16,11 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ray_tpu.models.moe import router_losses
 from ray_tpu.models.transformer import (
     TransformerConfig,
     forward,
+    forward_with_router_stats,
     init_params,
     param_axes,
 )
@@ -81,21 +83,28 @@ class LMTrainContext:
         # propagation: XLA happily replicates adam moments (measured with
         # pp_fsdp), silently forfeiting the ZeRO optimizer-state sharding
         # that is fsdp's whole memory win.  Optax states mirror the param
-        # tree, so match moment leaves to param leaves by shape; ambiguous
-        # shapes (same shape, different sharding) fall back to propagation.
+        # tree, so a moment leaf is matched to its parameter by tree PATH:
+        # the tail of its path is the parameter's (shapes cannot tell an
+        # expert layer's `w_gate` from its `w_up`).  Leaves that mirror no
+        # parameter (step counts) are replicated.
         self.repl = NamedSharding(self.mesh, P())
-        shape_to_sharding: dict = {}
-        for pleaf, psh in zip(
-            jax.tree_util.tree_leaves(abstract_params),
-            jax.tree_util.tree_leaves(self.param_shardings),
-        ):
-            prev = shape_to_sharding.get(pleaf.shape, psh)
-            shape_to_sharding[pleaf.shape] = psh if prev == psh else None
+        by_path = {
+            path: (pleaf.shape, psh)
+            for (path, pleaf), psh in zip(
+                jax.tree_util.tree_flatten_with_path(abstract_params)[0],
+                jax.tree_util.tree_leaves(self.param_shardings),
+            )
+        }
+
+        def pin(path, leaf):
+            for start in range(len(path)):
+                shape, sharding = by_path.get(path[start:], (None, None))
+                if shape == leaf.shape:
+                    return sharding
+            return self.repl if leaf.ndim == 0 else None
+
         abstract_opt = jax.eval_shape(self.optimizer.init, abstract_params)
-        self.opt_shardings = jax.tree_util.tree_map(
-            lambda l: self.repl if l.ndim == 0 else shape_to_sharding.get(l.shape),
-            abstract_opt,
-        )
+        self.opt_shardings = jax.tree_util.tree_map_with_path(pin, abstract_opt)
         self.batch_sharding = NamedSharding(
             self.mesh, logical_to_spec(("act_batch", "act_seq"), self.rules)
         )
@@ -116,13 +125,27 @@ class LMTrainContext:
             },
         )
 
-        def _train_step(state, batch):
-            def loss_fn(params):
-                logits = forward(params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
-                with jax.named_scope("loss"):
-                    return cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
+        def _loss(params, batch):
+            """(the objective that is differentiated, its terms).  Dense: the
+            cross entropy and no terms.  With experts: cross entropy +
+            `router_aux_loss_coef` * load balancing + `router_z_loss_coef` *
+            z-loss (formulas in models/moe.py), and the terms unweighted."""
+            logits, router_stats = forward_with_router_stats(
+                params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
+            with jax.named_scope("loss"):
+                ce = cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
+                if router_stats is None:
+                    return ce, {}
+                terms = router_losses(router_stats, cfg)
+                loss = (ce + cfg.router_aux_loss_coef * terms["moe_lb_loss"]
+                        + cfg.router_z_loss_coef * terms["moe_z_loss"])
+                return loss, {"ce_loss": ce, **terms}
 
-            loss, grads = jax.value_and_grad(loss_fn)(state["params"])
+        self._loss = _loss
+
+        def _train_step(state, batch):
+            (loss, terms), grads = jax.value_and_grad(_loss, has_aux=True)(
+                state["params"], batch)
             with jax.named_scope("optimizer"):
                 updates, opt_state = opt.update(grads, state["opt_state"], state["params"])
                 params = optax.apply_updates(state["params"], updates)
@@ -131,6 +154,7 @@ class LMTrainContext:
                 "loss": loss,
                 "grad_norm": grad_norm,
                 "step": state["step"] + 1,
+                **terms,
             }
             return (
                 {"params": params, "opt_state": opt_state, "step": state["step"] + 1},

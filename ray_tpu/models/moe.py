@@ -1,39 +1,65 @@
-"""Mixture-of-Experts block: expert-parallel FFN for the transformer.
+"""Mixture-of-experts FFN for the transformer layer: dropless, sort-and-group.
 
-No counterpart in the reference (SURVEY §2.4: EP absent) — built TPU-first:
-experts live on a leading `expert` dim sharded over the `expert` mesh axis
-(ep_rules, parallel/sharding.py); routing is top-k softmax gating and the
-token shuffle compiles to all-to-alls over ICI when XLA partitions the
-gather/scatter by expert.
+No counterpart in the reference (SURVEY §2.4: EP absent).  A configuration
+with `n_experts` set gets this block where a dense one has its SwiGLU
+(`transformer._layer`, scope `layer/mlp`); `TransformerConfig` is the one
+description of the model, this module reads `d_model`, `d_ff` (ONE expert's
+width), `n_experts`, `experts_per_token`, `norm_topk_prob`, `dtype`.
 
-Dense-compute formulation (einsum over a one-hot dispatch mask rather than
-ragged gather): identical math to token-dropping MoE with capacity, and
-every op is a static-shape matmul the MXU likes.
+The layer, on T tokens with K choices each out of E experts:
+
+- `moe/router`: logits `h @ router` and their softmax in float32 (precision
+  HIGHEST: on a TPU a float32 matmul is otherwise bf16 passes, and routing is
+  discrete), `lax.top_k`, and the statistics the router losses are made of.
+- `moe/dispatch`: a stable sort of the T*K assignments by expert, the E group
+  sizes, a gather of the token rows into expert order.
+- `moe/experts`: gate and up as grouped matmuls over the E ragged groups,
+  `silu(gate) * up`, down as a third (`ops/grouped_matmul.py`: Pallas
+  kernels when lowered for TPU, an XLA form of the same schedule elsewhere).
+- `moe/combine`: rows back into token order, times the gate values, summed
+  over each token's K rows.
+
+No capacity: every assignment is computed whatever the imbalance.  Both row
+movements are gathers in both directions (the backward of a permutation is
+the inverse permutation, which XLA cannot know of a plain gather and would
+scatter-add).
+
+Across chips (`rules` + a `mesh` of more than one device) dispatch, experts
+and combine run under `shard_map`: tokens stay on their batch shard and are
+replicated over the `expert` axis, each rank of that axis holds E/ranks
+experts and computes their groups only, and a `psum` over the axis adds the
+partial results.  The simplest correct form; an all-to-all that moves only
+the routed rows waits for a cell on four chips (PERF.md section 7).
+
+Router losses, as published for OLMoE (arXiv:2409.02060; the first also as
+Hugging Face's `load_balancing_loss_func` computes it), from per-layer
+statistics that the layer scan emits:
+
+- load balancing = `E * sum_k sum_e f[k, e] * P[e]`, with `f[k, e]` the share
+  of tokens whose k-th choice is expert e and `P[e]` the mean router
+  probability of e, both over the tokens of ALL layers taken together (every
+  layer has the same tokens, so the mean over layers of the per-layer
+  means).  `f` carries no gradient, `P` does.  K at perfect balance.
+- z-loss = mean over tokens and layers of `logsumexp(router logits)**2`.
+
+The training objective adds them times `router_aux_loss_coef` and
+`router_z_loss_coef` (`models/lm.py`).
 """
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.sharding import Rules, with_logical_constraint
-
-
-@dataclasses.dataclass(frozen=True)
-class MoEConfig:
-    n_experts: int = 8
-    top_k: int = 2
-    d_model: int = 64
-    d_ff: int = 128
-    # tokens each expert processes per batch = capacity_factor * T * k / E
-    capacity_factor: float = 1.25
-    router_aux_coef: float = 0.01  # load-balance loss weight (Switch-style)
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.parallel.sharding import Rules, _fit_spec, logical_to_spec
 
 
-def moe_param_axes(cfg: MoEConfig) -> Dict:
+def moe_param_axes(config: Any) -> Dict:
+    """Logical axes of ONE layer's expert leaves (the stack adds `layers`)."""
     return {
         "router": ("embed", "expert"),
         "w_gate": ("expert", "embed", "mlp"),
@@ -42,84 +68,179 @@ def moe_param_axes(cfg: MoEConfig) -> Dict:
     }
 
 
-def init_moe_params(cfg: MoEConfig, key: jax.Array, dtype=jnp.float32) -> Dict:
+def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
+                    out_scale: Optional[float] = None) -> Dict:
+    """Seeded normal expert weights in `config.param_dtype`; `leading` is the
+    layer stack's shape, `out_scale` the stack's depth-scaled down projection."""
+    c = config
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    scale = cfg.d_model ** -0.5
+    scale = c.d_model ** -0.5
+    E, D, F = c.n_experts, c.d_model, c.d_ff
+
+    def init(k, shape, s):
+        return (jax.random.normal(k, leading + shape, jnp.float32) * s).astype(c.param_dtype)
+
     return {
-        "router": (jax.random.normal(k1, (cfg.d_model, cfg.n_experts)) * scale).astype(dtype),
-        "w_gate": (jax.random.normal(k2, (cfg.n_experts, cfg.d_model, cfg.d_ff)) * scale).astype(dtype),
-        "w_up": (jax.random.normal(k3, (cfg.n_experts, cfg.d_model, cfg.d_ff)) * scale).astype(dtype),
-        "w_down": (jax.random.normal(k4, (cfg.n_experts, cfg.d_ff, cfg.d_model)) * scale).astype(dtype),
+        "router": init(k1, (D, E), scale),
+        "w_gate": init(k2, (E, D, F), scale),
+        "w_up": init(k3, (E, D, F), scale),
+        "w_down": init(k4, (E, F, D), scale if out_scale is None else out_scale),
     }
 
 
-@jax.named_scope("layer/mlp")  # the transformer layer's FFN scope (PERF.md section 3)
+# -- rows into expert order and back: gathers in both directions -------------------
+
+
+@jax.custom_vjp
+def _to_expert_order(tokens, order, inverse):
+    """tokens [T, D] -> rows [T*K, D]: row i is the token of the i-th
+    assignment in expert order (`order` indexes the flattened [T, K])."""
+    return tokens[order // (order.shape[0] // tokens.shape[0])]
+
+
+def _to_expert_order_fwd(tokens, order, inverse):
+    return _to_expert_order(tokens, order, inverse), (inverse, tokens.shape[0])
+
+
+def _to_expert_order_bwd(res, g):
+    inverse, n_tokens = res
+    return g[inverse].reshape(n_tokens, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+
+
+@jax.custom_vjp
+def _to_token_order(rows, order, inverse):
+    """rows [T*K, D] in expert order -> [T*K, D] in (token, choice) order."""
+    return rows[inverse]
+
+
+def _to_token_order_fwd(rows, order, inverse):
+    return rows[inverse], order
+
+
+def _to_token_order_bwd(order, g):
+    return g[order], None, None
+
+
+_to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
+
+
+# -- the layer ---------------------------------------------------------------------
+
+
+def _route(params: Dict, tokens: jax.Array, config: Any):
+    """Router of one layer on tokens [T, D]: (expert_idx [T, K] int32, gates
+    [T, K] float32, statistics for the router losses)."""
+    c = config
+    E, K = c.n_experts, c.experts_per_token
+    logits = jnp.dot(tokens.astype(jnp.float32), params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)  # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, expert_idx = jax.lax.top_k(probs, K)
+    if c.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    stats = {
+        # f[k, e]: share of tokens whose k-th choice is e (no gradient)
+        "choice_share": jnp.mean(jax.nn.one_hot(expert_idx, E, dtype=jnp.float32), axis=0),
+        "mean_prob": jnp.mean(probs, axis=0),  # P[e]
+        "z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
+    }
+    return expert_idx, gates, stats
+
+
+def _experts(tokens, expert_idx, gates, w_gate, w_up, w_down, n_experts, first_expert=None):
+    """Dispatch, grouped matmuls and combine for the experts `first_expert ..
+    first_expert + w_gate.shape[0]` of `n_experts` (None: all of them, on one
+    device).  tokens [T, D], expert_idx / gates [T, K]; returns those
+    experts' part of the output, [T, D].  Assignments to other experts sort
+    behind the last group, where a grouped matmul writes nothing defined:
+    those rows are zeroed going in (which zeroes their gradient coming back)
+    and coming out."""
+    T, D = tokens.shape
+    n_local = w_gate.shape[0]
+    with jax.named_scope("moe/dispatch"):
+        flat = expert_idx.reshape(-1)
+        if first_expert is not None:
+            flat = (flat - first_expert) % n_experts  # this rank's experts first
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=jnp.int32))
+        # (a one-hot sum: `bincount` is a scatter-add, 0.6 ms a call on the v5e)
+        group_sizes = jnp.sum(jax.nn.one_hot(flat, n_local, dtype=jnp.int32), axis=0)
+        rows = _to_expert_order(tokens, order, inverse)
+        if first_expert is not None:
+            mine = (jnp.arange(rows.shape[0], dtype=jnp.int32) < jnp.sum(group_sizes))[:, None]
+            rows = jnp.where(mine, rows, 0)
+    with jax.named_scope("moe/experts"):
+        gate = grouped_matmul(rows, w_gate, group_sizes)
+        up = grouped_matmul(rows, w_up, group_sizes)
+        out = grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
+    with jax.named_scope("moe/combine"):
+        if first_expert is not None:
+            out = jnp.where(mine, out, 0)
+        out = _to_token_order(out, order, inverse).reshape(T, -1, D)
+        return jnp.sum(out * gates[..., None].astype(out.dtype), axis=1)
+
+
 def moe_ffn(
     params: Dict,
     x: jax.Array,
-    cfg: MoEConfig,
+    config: Any,
     *,
     rules: Optional[Rules] = None,
     mesh=None,
-) -> Tuple[jax.Array, jax.Array]:
-    """x [B, S, D] → (y [B, S, D], aux_loss scalar).
-
-    Dispatch: top-k router → per-expert capacity-limited one-hot combine
-    tensor → einsum dispatch/experts/combine.  With ep_rules the expert dim
-    of params+intermediates shards over the `expert` axis and XLA inserts
-    the token all-to-alls.
-    """
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x [B, S, D] (the normed hidden state) -> (y [B, S, D], this layer's
+    router statistics: `choice_share` [K, E], `mean_prob` [E], `z` []).
+    `_layer` calls it inside its `layer/mlp` scope (PERF.md section 3)."""
     B, S, D = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    T = B * S
-    capacity = max(int(cfg.capacity_factor * T * K / E), K)
+    with jax.named_scope("moe/router"):
+        expert_idx, gates, stats = _route(params, x.reshape(B * S, D), config)
+    expert_idx = expert_idx.reshape(B, S, -1)
+    gates = gates.reshape(B, S, -1)
+    weights = [params[k].astype(x.dtype) for k in ("w_gate", "w_up", "w_down")]
 
-    def constrain(h, axes):
-        if rules is None:
-            return h
-        return with_logical_constraint(h, axes, rules, mesh)
+    across_devices = rules is not None and mesh is not None and mesh.size > 1
+    expert_ax = None  # the mesh axis the experts are split over, if it is a real split
+    if across_devices:
+        ax = rules.get("expert")
+        if ax in mesh.axis_names and mesh.shape[ax] > 1 and config.n_experts % mesh.shape[ax] == 0:
+            expert_ax = ax
 
-    tokens = x.reshape(T, D)
-    logits = tokens @ params["router"].astype(x.dtype)  # [T, E]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    def body(xb, idx, g, w_gate, w_up, w_down):
+        b = xb.shape[0]
+        first = None
+        if expert_ax is not None:
+            first = jax.lax.axis_index(expert_ax) * w_gate.shape[0]
+        y = _experts(xb.reshape(b * S, D), idx.reshape(b * S, -1), g.reshape(b * S, -1),
+                     w_gate, w_up, w_down, config.n_experts, first)
+        if expert_ax is not None:
+            y = jax.lax.psum(y, expert_ax)
+        return y.reshape(b, S, D)
 
-    # Top-k expert choice per token.
-    gate_vals, expert_idx = jax.lax.top_k(probs, K)  # [T, K]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(axis=-1, keepdims=True), 1e-9
-    )
+    if not across_devices:
+        return body(x, expert_idx, gates, *weights), stats
+    tok_spec = _fit_spec(x.shape, logical_to_spec(("act_batch", None, None), rules), mesh)
+    w_spec = P(expert_ax, None, None)
+    y = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(tok_spec, tok_spec, tok_spec, w_spec, w_spec, w_spec),
+        out_specs=tok_spec, check_vma=False,
+    )(x, expert_idx, gates, *weights)
+    return y, stats
 
-    # Capacity: position of each token within its chosen expert's queue;
-    # tokens past capacity drop (standard Switch behavior).
-    onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)  # [T, K, E]
-    position_in_expert = (
-        jnp.cumsum(onehot.reshape(T * K, E), axis=0).reshape(T, K, E) - 1.0
-    )
-    within_cap = position_in_expert < capacity
-    onehot = onehot * within_cap
 
-    # combine [T, E, C]: weight of each token at its slot in each expert.
-    pos = jnp.einsum("tke,tke->tk", position_in_expert, onehot).astype(jnp.int32)
-    slot_onehot = jax.nn.one_hot(pos, capacity, dtype=jnp.float32)  # [T,K,C]
-    combine = jnp.einsum(
-        "tk,tke,tkc->tec", gate_vals.astype(jnp.float32), onehot, slot_onehot
-    )
-    dispatch = (combine > 0).astype(x.dtype)  # [T, E, C]
-
-    # Expert compute: [E, C, D] batched matmuls, expert dim sharded.
-    expert_in = jnp.einsum("td,tec->ecd", tokens, dispatch)
-    expert_in = constrain(expert_in, ("act_expert", None, "act_embed"))
-    g = jnp.einsum("ecd,edf->ecf", expert_in, params["w_gate"].astype(x.dtype))
-    u = jnp.einsum("ecd,edf->ecf", expert_in, params["w_up"].astype(x.dtype))
-    h = jax.nn.silu(g) * u
-    expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_down"].astype(x.dtype))
-    expert_out = constrain(expert_out, ("act_expert", None, "act_embed"))
-
-    y = jnp.einsum("ecd,tec->td", expert_out, combine.astype(x.dtype))
-
-    # Switch load-balance aux loss: E * sum_e(frac_tokens_e * frac_probs_e).
-    frac_tokens = onehot[:, 0, :].mean(axis=0)  # top-1 assignment share
-    frac_probs = probs.mean(axis=0)
-    aux = cfg.router_aux_coef * E * jnp.sum(frac_tokens * frac_probs)
-
-    return y.reshape(B, S, D).astype(x.dtype), aux
+def router_losses(stats: Dict[str, jax.Array], config: Any) -> Dict[str, jax.Array]:
+    """The router losses and the load figure of a step, from the statistics
+    the layer scan stacked ([L, ...] each); formulas in the module docstring.
+    Unweighted: `lm.py` applies the two coefficients."""
+    share = jnp.mean(stats["choice_share"], axis=0)  # f over all layers' tokens, [K, E]
+    prob = jnp.mean(stats["mean_prob"], axis=0)  # P, [E]
+    load = jnp.sum(stats["choice_share"], axis=1)  # [L, E], sums to K per layer
+    return {
+        "moe_lb_loss": config.n_experts * jnp.sum(share * prob[None, :]),
+        "moe_z_loss": jnp.mean(stats["z"]),
+        # tokens at the busiest expert over the mean, in the worst layer
+        "moe_load_max_over_mean": jnp.max(jnp.max(load, axis=1) / jnp.mean(load, axis=1)),
+    }

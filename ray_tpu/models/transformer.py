@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, dot_product_attention
 from ray_tpu.ops.rotary import apply_rope
 from ray_tpu.parallel.sharding import Rules, with_logical_constraint
@@ -61,6 +62,27 @@ class TransformerConfig:
     # None derives min(4 * n_stages, local batch) — ~20% GPipe bubble
     # without slicing microbatches below MXU-efficient sizes.
     pp_microbatches: Optional[int] = None
+    # Sparse experts (models/moe.py).  `n_experts` None = a dense SwiGLU of
+    # width `d_ff`; set, every layer's FFN is `n_experts` SwiGLU experts of
+    # width `d_ff` each, `experts_per_token` of them per token by the top-k
+    # of a softmax over all, dropless.  `norm_topk_prob`: renormalise the
+    # chosen gate values to sum to one.  The two coefficients weigh the
+    # load-balancing loss and the router z-loss in the training objective.
+    n_experts: Optional[int] = None
+    experts_per_token: int = 0
+    norm_topk_prob: bool = False
+    router_aux_loss_coef: float = 0.0
+    router_z_loss_coef: float = 0.0
+    # RMSNorm with a learned scale over the whole projected q and k, before
+    # RoPE (OLMoE, OLMo 2).
+    qk_norm: bool = False
+
+    def __post_init__(self):
+        if self.n_experts is not None and not 0 < self.experts_per_token <= self.n_experts:
+            raise ValueError(
+                f"n_experts={self.n_experts} needs 0 < experts_per_token <= n_experts, "
+                f"got {self.experts_per_token}"
+            )
 
     @property
     def head_dim(self) -> int:
@@ -100,7 +122,11 @@ class TransformerConfig:
         e = self.vocab_size * self.d_model
         attn = self.d_model * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)
         mlp = 3 * self.d_model * self.d_ff
+        if self.n_experts is not None:
+            mlp = self.n_experts * mlp + self.d_model * self.n_experts  # + router
         norms = 2 * self.d_model
+        if self.qk_norm:
+            norms += self.head_dim * (self.n_heads + self.n_kv_heads)
         out = 0 if self.tie_embeddings else self.vocab_size * self.d_model
         return e + self.n_layers * (attn + mlp + norms) + self.d_model + out
 
@@ -127,6 +153,11 @@ def param_axes(config: TransformerConfig) -> Dict:
         },
         "final_norm": (None,),
     }
+    if config.n_experts is not None:
+        axes["layers"]["mlp"] = {k: L + v for k, v in moe_param_axes(config).items()}
+    if config.qk_norm:
+        axes["layers"]["attn"]["q_norm"] = L + ("heads", "head_dim")
+        axes["layers"]["attn"]["k_norm"] = L + ("kv_heads", "head_dim")
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -147,20 +178,31 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
     proj_scale = c.d_model ** -0.5
     out_scale = (2 * c.n_layers * c.d_model) ** -0.5  # GPT-2-style depth scaling
 
+    # Keys are drawn in this order, whatever the model has: a dense model's
+    # weights for a seed do not move when a kind of layer is added here.
+    embed = {"tokens": norm_init(next(k), (c.vocab_size, c.d_model), emb_scale)}
+    attn = {
+        "wq": norm_init(next(k), (L, c.d_model, c.n_heads, hd), proj_scale),
+        "wk": norm_init(next(k), (L, c.d_model, c.n_kv_heads, hd), proj_scale),
+        "wv": norm_init(next(k), (L, c.d_model, c.n_kv_heads, hd), proj_scale),
+        "wo": norm_init(next(k), (L, c.n_heads, hd, c.d_model), out_scale),
+    }
+    if c.qk_norm:
+        attn["q_norm"] = jnp.ones((L, c.n_heads, hd), pd)
+        attn["k_norm"] = jnp.ones((L, c.n_kv_heads, hd), pd)
+    if c.n_experts is not None:
+        mlp = init_moe_params(c, next(k), leading=(L,), out_scale=out_scale)
+    else:
+        mlp = {
+            "w_gate": norm_init(next(k), (L, c.d_model, c.d_ff), proj_scale),
+            "w_up": norm_init(next(k), (L, c.d_model, c.d_ff), proj_scale),
+            "w_down": norm_init(next(k), (L, c.d_ff, c.d_model), out_scale),
+        }
     params = {
-        "embed": {"tokens": norm_init(next(k), (c.vocab_size, c.d_model), emb_scale)},
+        "embed": embed,
         "layers": {
-            "attn": {
-                "wq": norm_init(next(k), (L, c.d_model, c.n_heads, hd), proj_scale),
-                "wk": norm_init(next(k), (L, c.d_model, c.n_kv_heads, hd), proj_scale),
-                "wv": norm_init(next(k), (L, c.d_model, c.n_kv_heads, hd), proj_scale),
-                "wo": norm_init(next(k), (L, c.n_heads, hd, c.d_model), out_scale),
-            },
-            "mlp": {
-                "w_gate": norm_init(next(k), (L, c.d_model, c.d_ff), proj_scale),
-                "w_up": norm_init(next(k), (L, c.d_model, c.d_ff), proj_scale),
-                "w_down": norm_init(next(k), (L, c.d_ff, c.d_model), out_scale),
-            },
+            "attn": attn,
+            "mlp": mlp,
             "ln1": jnp.ones((L, c.d_model), pd),
             "ln2": jnp.ones((L, c.d_model), pd),
         },
@@ -171,9 +213,9 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
     return params
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float, axis=-1) -> jax.Array:
     xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf), axis=axis, keepdims=True)
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
 
 
@@ -207,6 +249,8 @@ def _layer(
     rules: Optional[Rules],
     mesh=None,
 ):
+    """One decoder layer: (x, this layer's router statistics; None when the
+    FFN is dense)."""
     c = config
 
     def constrain(h, axes):
@@ -226,6 +270,10 @@ def _layer(
         vv = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wv"].astype(dt))
         q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
         kk = constrain(kk, ("act_batch", "act_seq", "act_kv_heads", "act_head_dim"))
+        if c.qk_norm:
+            # over the WHOLE projection: heads * head_dim is one vector per position
+            q = rms_norm(q, layer_params["attn"]["q_norm"], c.norm_eps, axis=(-2, -1))
+            kk = rms_norm(kk, layer_params["attn"]["k_norm"], c.norm_eps, axis=(-2, -1))
         q = apply_rope(q, positions, theta=c.rope_theta)
         kk = apply_rope(kk, positions, theta=c.rope_theta)
         q = checkpoint_name(q, "q")
@@ -264,14 +312,18 @@ def _layer(
         attn_out = jnp.einsum("bshd,hde->bse", attn, layer_params["attn"]["wo"].astype(dt))
         x = x + constrain(attn_out, ("act_batch", "act_seq", "act_embed"))
 
+    router_stats = None
     with jax.named_scope("layer/mlp"):
         h = rms_norm(x, layer_params["ln2"], c.norm_eps)
-        gate = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_gate"].astype(dt))
-        up = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_up"].astype(dt))
-        ff = constrain(jax.nn.silu(gate) * up, ("act_batch", "act_seq", "act_mlp"))
-        down = jnp.einsum("bsf,fe->bse", ff, layer_params["mlp"]["w_down"].astype(dt))
+        if c.n_experts is not None:
+            down, router_stats = moe_ffn(layer_params["mlp"], h, c, rules=rules, mesh=mesh)
+        else:
+            gate = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_gate"].astype(dt))
+            up = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_up"].astype(dt))
+            ff = constrain(jax.nn.silu(gate) * up, ("act_batch", "act_seq", "act_mlp"))
+            down = jnp.einsum("bsf,fe->bse", ff, layer_params["mlp"]["w_down"].astype(dt))
         x = x + constrain(down, ("act_batch", "act_seq", "act_embed"))
-    return x
+    return x, router_stats
 
 
 def _remat_policy(config: TransformerConfig):
@@ -318,6 +370,11 @@ def _run_layers_pipelined(
     from ray_tpu.parallel.pipeline import pipeline_apply
 
     c = config
+    if c.n_experts is not None:
+        raise ValueError(
+            "strategy 'pp' runs dense layers only: the router statistics of "
+            "an expert layer do not come out of the pipeline schedule"
+        )
     n_stages = mesh.shape[axis]
     per_stage = c.n_layers // n_stages
 
@@ -344,7 +401,7 @@ def _run_layers_pipelined(
 
     def stage_fn(stage_params, h):
         def body(carry, lp):
-            return _layer(carry, lp, positions, c, None, None), None
+            return _layer(carry, lp, positions, c, None, None)
 
         out, _ = jax.lax.scan(body, h, stage_params)
         return out
@@ -370,6 +427,20 @@ def forward(
 
     `rules` come with the `mesh` they refer to: ring attention, the pipeline
     schedule and the flash kernel's shard_map are all built from it."""
+    return forward_with_router_stats(params, tokens, config, rules=rules, mesh=mesh)[0]
+
+
+def forward_with_router_stats(
+    params: Dict,
+    tokens: jax.Array,
+    config: TransformerConfig,
+    *,
+    rules: Optional[Rules] = None,
+    mesh=None,
+):
+    """`forward` plus what a training objective needs beside the logits:
+    (logits, router statistics stacked over the layers, `[L, ...]` each, as
+    `moe.router_losses` takes them; None for a dense model)."""
     c = config
     if rules is not None and mesh is None:
         raise ValueError("forward(rules=...) needs the mesh the rules refer to")
@@ -435,6 +506,7 @@ def forward(
     # `layers` names what the loop over the stack itself costs (each layer's
     # weights sliced out of the stack, gradients and residuals stacked back);
     # the regions of `_layer` are named inside it.
+    router_stats = None
     with jax.named_scope("layers"):
         if pp_axis is not None:
             x = _run_layers_pipelined(
@@ -448,10 +520,7 @@ def forward(
             if c.remat:
                 layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
 
-            def scan_body(carry, layer_params):
-                return layer_fn(carry, layer_params), None
-
-            x, _ = jax.lax.scan(scan_body, x, params["layers"])
+            x, router_stats = jax.lax.scan(layer_fn, x, params["layers"])
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], c.norm_eps)
     with jax.named_scope("lm_head"):
@@ -463,4 +532,4 @@ def forward(
             logits = with_logical_constraint(
                 logits, ("act_batch", "act_seq", "act_vocab"), rules, mesh
             )
-    return logits
+    return logits, router_stats

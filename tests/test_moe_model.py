@@ -1,0 +1,347 @@
+"""The sparse-expert decoder (OLMoE's block) against its plain float32
+reference (`benchmarks/lib/reference_moe.py`), at tiny widths on the CPU:
+logits, the training objective, every gradient leaf, each loss term, dropless
+under total imbalance, no gate renormalisation, the bf16 tolerance shown to
+bite, expert parallelism on a CPU mesh, optimizer-state shardings by path,
+the grouped-matmul kernels, and the dense model's program left as it was.
+
+Weights are seeded through the program's own `init_state`; the norm scales,
+which start at one, are then drawn at random so a scale that is never applied
+cannot pass.
+"""
+
+import dataclasses
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)  # `benchmarks.lib` resolves from this checkout
+
+from benchmarks.lib import reference_moe  # noqa: E402
+from ray_tpu.models import LMTrainContext, TransformerConfig  # noqa: E402
+from ray_tpu.models import moe  # noqa: E402
+from ray_tpu.ops import grouped_matmul as gmm  # noqa: E402
+from ray_tpu.ops.pallas import grouped_matmul as kernels  # noqa: E402
+from ray_tpu.parallel import MeshSpec, build_mesh  # noqa: E402
+
+BASE = dict(
+    vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4, d_ff=32, max_seq_len=32,
+    dtype=jnp.float32, param_dtype=jnp.float32, remat=False, n_experts=8, experts_per_token=2,
+    qk_norm=True, router_aux_loss_coef=0.01, router_z_loss_coef=0.001,
+)
+NORM_LEAVES = ("ln1", "ln2", "q_norm", "k_norm", "final_norm")
+
+
+def reference_config(cfg: TransformerConfig) -> dict:
+    """The published key names `reference_moe` reads, from a TransformerConfig."""
+    return {
+        "num_hidden_layers": cfg.n_layers, "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.norm_eps,
+        "num_experts": cfg.n_experts, "num_experts_per_tok": cfg.experts_per_token,
+        "norm_topk_prob": cfg.norm_topk_prob, "router_aux_loss_coef": cfg.router_aux_loss_coef,
+        "router_z_loss_coef": cfg.router_z_loss_coef,
+    }
+
+
+def one_device_ctx(cfg):
+    return LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+
+
+def seeded_params(ctx, seed=0):
+    params = ctx.init_state(seed)["params"]
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def draw(path, leaf):
+        if path[-1].key in NORM_LEAVES:
+            return (leaf * (1.0 + 0.3 * jax.random.normal(next(keys), leaf.shape))).astype(leaf.dtype)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(draw, params)
+
+
+def _state_with(ctx, params):
+    """A fresh state holding a COPY of `params` on ctx's mesh (the step donates its state)."""
+    copy = jax.device_put(jax.tree_util.tree_map(np.asarray, params), ctx.param_shardings)
+    return dict(ctx.init_state(0), params=copy)
+
+
+def batch_of(cfg, seed=0, batch=2, seq=32):
+    rng = np.random.default_rng(seed)
+    toks = rng.integers(0, cfg.vocab_size, (batch, seq + 1)).astype(np.int32)
+    return {"tokens": jnp.asarray(toks[:, :-1]), "targets": jnp.asarray(toks[:, 1:])}
+
+
+def rel_rms(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """Program and reference on the same float32 weights and batch: logits,
+    objective, terms and gradients of both."""
+    cfg = TransformerConfig(**BASE)
+    ctx = one_device_ctx(cfg)
+    params, batch = seeded_params(ctx), batch_of(cfg)
+    rcfg = reference_config(cfg)
+    (loss, terms), grads = jax.jit(jax.value_and_grad(ctx._loss, has_aux=True))(params, batch)
+    (ref_loss, ref_terms), ref_grads = jax.value_and_grad(
+        lambda p: reference_moe.objective(rcfg, p, batch["tokens"], batch["targets"]), has_aux=True)(params)
+    chosen = []
+    ref_logits = reference_moe.logits(rcfg, params, batch["tokens"], last=32, record=chosen)
+    return dict(cfg=cfg, ctx=ctx, params=params, batch=batch, rcfg=rcfg, logits=ctx.apply(params, batch["tokens"]),
+                ref_logits=ref_logits, loss=loss, terms=terms, grads=grads, ref_loss=ref_loss,
+                ref_terms=ref_terms, ref_grads=ref_grads, chosen=chosen)
+
+
+# -- forward, objective, gradients ------------------------------------------------------
+
+
+def test_logits_equal_the_reference(tiny):
+    """Same arithmetic in another order (sort-and-group against a mask per
+    expert): float32 on both sides, rtol 1e-4 of the logits' scale."""
+    want = np.asarray(tiny["ref_logits"])
+    np.testing.assert_allclose(np.asarray(tiny["logits"]), want, rtol=1e-4, atol=1e-4 * np.abs(want).max())
+
+
+def test_objective_equals_the_reference(tiny):
+    np.testing.assert_allclose(float(tiny["loss"]), float(tiny["ref_loss"]), rtol=1e-5)
+
+
+@pytest.mark.parametrize("term", ["ce_loss", "moe_lb_loss", "moe_z_loss"])
+def test_each_loss_term_equals_the_reference(tiny, term):
+    np.testing.assert_allclose(float(tiny["terms"][term]), float(tiny["ref_terms"][term]), rtol=1e-5)
+
+
+def test_router_losses_are_in_the_objective_with_their_coefficients(tiny):
+    t, cfg = tiny["terms"], tiny["cfg"]
+    want = float(t["ce_loss"]) + cfg.router_aux_loss_coef * float(t["moe_lb_loss"]) \
+        + cfg.router_z_loss_coef * float(t["moe_z_loss"])
+    np.testing.assert_allclose(float(tiny["loss"]), want, rtol=1e-6)
+    assert float(t["moe_lb_loss"]) >= cfg.experts_per_token - 1e-4  # K at perfect balance, more otherwise
+
+
+def test_load_max_over_mean_is_the_busiest_expert_of_the_worst_layer(tiny):
+    cfg = tiny["cfg"]
+    worst = 0.0
+    for chosen in tiny["chosen"]:  # the reference's own routing, [N, S, K] per layer
+        load = np.bincount(np.asarray(chosen).reshape(-1), minlength=cfg.n_experts)
+        worst = max(worst, load.max() / load.mean())
+    np.testing.assert_allclose(float(tiny["terms"]["moe_load_max_over_mean"]), worst, rtol=1e-6)
+
+
+LEAVES = ["embed/tokens", "layers/attn/wq", "layers/attn/wk", "layers/attn/wv", "layers/attn/wo",
+          "layers/attn/q_norm", "layers/attn/k_norm", "layers/mlp/router", "layers/mlp/w_gate",
+          "layers/mlp/w_up", "layers/mlp/w_down", "layers/ln1", "layers/ln2", "final_norm", "lm_head"]
+
+
+def test_the_leaf_list_is_every_leaf(tiny):
+    paths = {"/".join(k.key for k in path) for path, _ in jax.tree_util.tree_flatten_with_path(tiny["params"])[0]}
+    assert paths == set(LEAVES)
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_every_gradient_leaf_equals_the_reference(tiny, leaf):
+    got, want = tiny["grads"], tiny["ref_grads"]
+    for key in leaf.split("/"):
+        got, want = got[key], want[key]
+    want = np.asarray(want)
+    assert np.abs(want).max() > 0
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3, atol=2e-5 * np.abs(want).max())
+
+
+def test_train_step_reports_the_terms_and_differentiates_the_objective(tiny):
+    ctx = tiny["ctx"]
+    _, metrics = ctx.train_step(_state_with(ctx, tiny["params"]), tiny["batch"])
+    assert set(metrics) == {"loss", "grad_norm", "step", "ce_loss", "moe_lb_loss", "moe_z_loss",
+                            "moe_load_max_over_mean"}
+    np.testing.assert_allclose(float(metrics["loss"]), float(tiny["ref_loss"]), rtol=1e-5)
+    ref_norm = math.sqrt(sum(float(jnp.sum(g ** 2)) for g in jax.tree_util.tree_leaves(tiny["ref_grads"])))
+    np.testing.assert_allclose(float(metrics["grad_norm"]), ref_norm, rtol=1e-3)
+
+
+# -- dropless, and the gates as published ---------------------------------------------------
+
+
+def test_dropless_under_total_imbalance_equals_the_reference():
+    """A router biased so EVERY token of every layer picks the same K
+    experts: nothing is dropped, the logits still equal the reference."""
+    cfg = TransformerConfig(**BASE)
+    ctx = one_device_ctx(cfg)
+    params = seeded_params(ctx)
+    # one hidden coordinate large and positive for every token, and a router
+    # that reads it into the first K experts
+    params["embed"]["tokens"] = params["embed"]["tokens"].at[:, 0].set(8.0)
+    params["layers"]["ln2"] = params["layers"]["ln2"].at[:, 0].set(1.0)
+    params["layers"]["mlp"]["router"] = params["layers"]["mlp"]["router"].at[:, 0, :cfg.experts_per_token].add(4.0)
+    batch = batch_of(cfg)
+    chosen = []
+    want = reference_moe.logits(reference_config(cfg), params, batch["tokens"], last=32, record=chosen)
+    for layer in chosen:
+        assert set(np.asarray(layer).reshape(-1).tolist()) == set(range(cfg.experts_per_token))
+    got = ctx.apply(params, batch["tokens"])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4 * float(jnp.abs(want).max()))
+
+
+def test_dropless_layer_has_no_zero_rows_when_one_expert_takes_every_token():
+    cfg = TransformerConfig(**dict(BASE, experts_per_token=1))
+    params = moe.init_moe_params(cfg, jax.random.PRNGKey(0))
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (4, 64, cfg.d_model))) + 0.1
+    params["router"] = jnp.zeros_like(params["router"]).at[:, 3].set(1.0)  # all 256 tokens to expert 3
+    y, stats = jax.jit(lambda p, h: moe.moe_ffn(p, h, cfg))(params, x)
+    np.testing.assert_allclose(np.asarray(stats["choice_share"])[0], np.eye(cfg.n_experts)[3], atol=0)
+    assert np.all(np.abs(np.asarray(y)).max(axis=-1) > 0)  # the old capacity of 1.25*T*K/E rows would zero 216 of 256
+    gate = jax.nn.softmax(x.reshape(-1, cfg.d_model).astype(jnp.float32) @ params["router"], axis=-1)[:, 3:4]
+    h = x.reshape(-1, cfg.d_model)
+    want = gate * ((jax.nn.silu(h @ params["w_gate"][3]) * (h @ params["w_up"][3])) @ params["w_down"][3])
+    np.testing.assert_allclose(np.asarray(y).reshape(want.shape), np.asarray(want), rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("program_norm,reference_norm,equal", [(False, False, True), (True, True, True),
+                                                               (True, False, False)],
+                         ids=["as-published", "both-renormalise", "program-renormalises"])
+def test_norm_topk_prob_false_means_no_renormalisation(program_norm, reference_norm, equal):
+    """`norm_topk_prob` false: the K gate values are NOT renormalised.  A
+    program that renormalises is far outside what the comparison allows."""
+    cfg = TransformerConfig(**dict(BASE, norm_topk_prob=program_norm))
+    ctx = one_device_ctx(cfg)
+    params, batch = seeded_params(ctx), batch_of(cfg)
+    rcfg = dict(reference_config(cfg), norm_topk_prob=reference_norm)
+    err = rel_rms(ctx.apply(params, batch["tokens"]),
+                  reference_moe.logits(rcfg, params, batch["tokens"], last=32))
+    assert (err < 1e-4) if equal else (err > 1e-2), err
+
+
+# -- bf16 against the float32 reference: the benchmark's tolerance, shown to bite --------------
+
+
+@pytest.fixture(scope="module")
+def bf16_case():
+    """OLMoE's routing (64 experts, top-8) at a toy width, three layers, the
+    program in bf16 from bf16 weights as the benchmark's cells run it."""
+    cfg = TransformerConfig(**dict(
+        BASE, vocab_size=512, d_model=256, n_layers=3, n_heads=2, n_kv_heads=2, d_ff=128, max_seq_len=128,
+        n_experts=64, experts_per_token=8, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    ctx = one_device_ctx(cfg)
+    params = seeded_params(ctx)
+    tokens = batch_of(cfg, batch=2, seq=128)["tokens"]
+    want = reference_moe.logits(reference_config(cfg), params, tokens, last=128)
+    return cfg, params, tokens, want
+
+
+def test_bf16_program_is_inside_the_tolerance_of_the_independent_reference(bf16_case):
+    cfg, params, tokens, want = bf16_case
+    err = rel_rms(one_device_ctx(cfg).apply(params, tokens), want)
+    assert err <= reference_moe.tolerance(cfg.n_layers), err
+
+
+def test_a_program_that_routes_to_seven_experts_is_outside_the_tolerance(bf16_case):
+    """The same weights, one expert fewer per token: leaving out part of the
+    mathematics does not fit inside what bf16 is allowed."""
+    cfg, params, tokens, want = bf16_case
+    degraded = dataclasses.replace(cfg, experts_per_token=7)
+    err = rel_rms(one_device_ctx(degraded).apply(params, tokens), want)
+    assert err > reference_moe.tolerance(cfg.n_layers), err
+
+
+# -- across devices -----------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def ep_ctx():
+    cfg = TransformerConfig(**BASE)
+    return LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=2, expert=4)), strategy="ep")
+
+
+def test_ep_train_step_equals_the_single_device_step(tiny, ep_ctx):
+    batch = tiny["batch"]
+    one = tiny["ctx"]
+    _, want = one.train_step(_state_with(one, tiny["params"]), batch)
+    _, got = ep_ctx.train_step(_state_with(ep_ctx, tiny["params"]), batch)
+    for key in ("loss", "ce_loss", "moe_lb_loss", "moe_z_loss", "moe_load_max_over_mean", "grad_norm"):
+        np.testing.assert_allclose(float(got[key]), float(want[key]), rtol=2e-4, err_msg=key)
+
+
+def test_every_moment_leaf_has_its_parameters_sharding_under_ep(ep_ctx):
+    """Pinned by tree path: `w_gate` and `w_up` share a shape, and a shape
+    cannot say whose moment a leaf is."""
+    state = ep_ctx.init_state(0)
+    params = {path: leaf.sharding for path, leaf in jax.tree_util.tree_flatten_with_path(state["params"])[0]}
+    expert_sharded = moments = 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state["opt_state"])[0]:
+        tail = next((path[i:] for i in range(len(path)) if path[i:] in params), None)
+        if tail is None:
+            assert leaf.ndim == 0  # a step count
+            continue
+        moments += 1
+        assert leaf.sharding.is_equivalent_to(params[tail], leaf.ndim), (path, leaf.sharding, params[tail])
+        expert_sharded += "expert" in str(leaf.sharding.spec)
+    assert moments == 2 * len(params) and expert_sharded == 2 * 4  # mu and nu of router, w_gate, w_up, w_down
+
+
+# -- the grouped matmul: Pallas kernels (interpreted here) against the XLA form ----------------
+
+GROUPS = {"ragged": [700, 0, 300, 28, 1020], "one-takes-all": [0, 0, 2048, 0, 0], "tile-aligned": [512, 512, 512, 256, 256],
+          "short-of-m": [100, 0, 0, 200, 0], "straddling": [513, 511, 0, 1000, 24]}
+
+
+@pytest.mark.parametrize("sizes", GROUPS.values(), ids=GROUPS.keys())
+def test_grouped_matmul_kernels_equal_a_loop_over_the_groups(sizes):
+    """`moe_gmm`, its transposed form and `moe_tgmm` (through the custom_vjp)
+    and the XLA form, against one dense matmul per group."""
+    rng = np.random.default_rng(0)
+    m, k, n = 2048, 256, 128
+    lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((len(sizes), k, n)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
+    gs, total = jnp.asarray(sizes, jnp.int32), sum(sizes)
+    bounds = np.concatenate([[0], np.cumsum(sizes)])
+
+    def loop(l, r):
+        return jnp.concatenate([jnp.dot(l[a:b], r[g], precision="highest")
+                                for g, (a, b) in enumerate(zip(bounds, bounds[1:]))])
+
+    def objective(f):
+        return lambda l, r: jnp.sum(f(l, r)[:total] * ct[:total])
+
+    want, want_grads = loop(lhs, rhs), jax.grad(objective(loop), argnums=(0, 1))(lhs, rhs)
+    for f in (kernels.grouped_matmul, gmm.grouped_matmul_xla):
+        got = f(lhs, rhs, gs)[:total]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-3)
+        d_lhs, d_rhs = jax.grad(objective(lambda l, r: f(l, r, gs)), argnums=(0, 1))(lhs, rhs)
+        np.testing.assert_allclose(np.asarray(d_lhs)[:total], np.asarray(want_grads[0])[:total], rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(np.asarray(d_rhs), np.asarray(want_grads[1]), rtol=1e-4, atol=1e-3)
+
+
+# -- the dense model is as it was -------------------------------------------------------------
+
+
+def _equations(jaxpr) -> int:
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    total += _equations(inner)
+    return total
+
+
+def test_the_dense_step_is_the_parents_program():
+    """`TransformerConfig.tiny()` has no experts and no QK-norm: its train
+    step traces to the same 709 equations as at the parent of PR 26 (counted
+    there with this function), with nothing of the expert layer in it."""
+    ctx = one_device_ctx(TransformerConfig.tiny())
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    jaxpr = jax.make_jaxpr(ctx._train_step)(state, {"tokens": toks, "targets": toks})
+    assert _equations(jaxpr.jaxpr) == 709
+    text = str(jaxpr)
+    assert "moe" not in text and "top_k" not in text

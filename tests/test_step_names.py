@@ -60,6 +60,55 @@ def test_recomputed_ops_carry_the_scope_under_the_remat_marker(lowered_for_tpu):
     assert '"layer/mlp/' in lowered_for_tpu and "transpose(" in lowered_for_tpu
 
 
+# -- the expert layer's names (PERF.md section 3, PR 26) -------------------------------------
+
+MOE_SCOPES = ("moe/router", "moe/dispatch", "moe/experts", "moe/combine")
+MOE_KERNELS = ("moe_gmm", "moe_tgmm")
+MOE_CFG = TransformerConfig.tiny(
+    n_heads=2, n_kv_heads=2, d_model=256, d_ff=128, max_seq_len=128, remat=True, remat_policy="qkv_attn",
+    n_experts=8, experts_per_token=2, qk_norm=True, router_aux_loss_coef=0.01, router_z_loss_coef=0.001,
+)
+
+
+@pytest.fixture(scope="module", params=[(1, MeshSpec(data=1), "dp"), (4, MeshSpec(data=1, expert=4), "ep")],
+                ids=["dp1", "ep4"])
+def moe_lowered_for_tpu(request):
+    """The expert model's step cross-lowered for TPU: 2,048 token-expert rows
+    of width 256 -> 128, shapes the grouped-matmul kernels take."""
+    n_devices, spec, strategy = request.param
+    mesh = build_mesh(spec, devices=jax.devices()[:n_devices])
+    ctx = LMTrainContext(MOE_CFG, mesh=mesh, strategy=strategy)
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
+    traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
+    return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", MOE_SCOPES)
+def test_lowered_expert_step_names_the_four_regions_inside_the_mlp_scope(moe_lowered_for_tpu, scope):
+    """Nested INSIDE `layer/mlp`, so `mlp_time_pct` stays the whole FFN block
+    and `benchmarks/lib/trace_moe.py` splits it."""
+    # (under shard_map the body is a function of its own, and MLIR locations nest per function:
+    # there `layer/mlp` is on the caller's line and the region's name starts the body's)
+    assert f"layer/mlp/{scope}/" in moe_lowered_for_tpu or (
+        "shard_map" in moe_lowered_for_tpu and f'"{scope}/' in moe_lowered_for_tpu
+        and "layer/mlp/shard_map" in moe_lowered_for_tpu)
+
+
+@pytest.mark.parametrize("kernel", MOE_KERNELS)
+def test_lowered_expert_step_names_the_grouped_matmul_kernels(moe_lowered_for_tpu, kernel):
+    calls = [line for line in moe_lowered_for_tpu.splitlines() if "@tpu_custom_call" in line
+             and f'kernel_name = "{kernel}"' in line]
+    assert calls
+    assert any(f"/{kernel}/" in line and "pallas_call" in line for line in moe_lowered_for_tpu.splitlines())
+
+
+def test_lowered_expert_step_keeps_the_flash_kernels_and_the_loss_scope(moe_lowered_for_tpu):
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert f'kernel_name = "{name}"' in moe_lowered_for_tpu
+    assert "/loss/" in moe_lowered_for_tpu or "(loss)" in moe_lowered_for_tpu
+
+
 # -- tracing.annotate -------------------------------------------------------------------
 
 
