@@ -54,7 +54,9 @@ class TransformerConfig:
     # output and the flash kernel's log-sum-exp (f32 [B, H, S]), so the
     # kernel's forward runs once per layer; "qkv_attn" = additionally save
     # post-rope q/k/v (skips qkv matmul + rope recompute).  More saved =
-    # more HBM.
+    # more HBM.  XLA's own rematerialization may still duplicate work when
+    # the step compiles over libtpu's limit; `_dense_ffn`'s tie is why the
+    # dense FFN's matmuls are no longer among it.
     remat_policy: Optional[str] = None
     attention_impl: Optional[str] = None  # None=auto, see ops.attention
     # Microbatches per pipeline-stage schedule when the rules shard the
@@ -241,6 +243,42 @@ def _ring_axis(rules: Optional[Rules], mesh, q: jax.Array) -> Optional[str]:
     return _fitting_axis(rules.get("act_seq"), mesh, q.shape[1])
 
 
+def _swiglu(constrain, h, w_gate, w_up, w_down):
+    gate = jnp.einsum("bse,ef->bsf", h, w_gate)
+    up = jnp.einsum("bse,ef->bsf", h, w_up)
+    ff = constrain(jax.nn.silu(gate) * up, ("act_batch", "act_seq", "act_mlp"))
+    return jnp.einsum("bsf,fe->bse", ff, w_down)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dense_ffn(constrain, h, w_gate, w_up, w_down):
+    """The dense SwiGLU FFN, whose backward finishes as one unit.
+
+    The forward is `_swiglu` as written and the backward its ordinary
+    `jax.vjp` (same matmuls, dtypes and residuals under every remat policy:
+    the residuals carry no checkpoint name), except that the four cotangents
+    leave through ONE `optimization_barrier`.  Without it XLA's scheduler
+    lets the weight-gradient matmuls drift past the start of attention's
+    backward, so the [tokens, d_ff] buffers (`gate`, `up`, `silu*up` and
+    their cotangents) stay alive beside attention's.  In a step that
+    compiles over libtpu's rematerialization limit that excess is what
+    `HloRematerialization` buys back by computing `gate` and the `d_ff`
+    cotangent a second time per layer (PERF.md section 6, PR 25 and PR 27).
+    `constrain` places `silu*up` as the layer's sharding rules say."""
+    return _swiglu(constrain, h, w_gate, w_up, w_down)
+
+
+def _dense_ffn_fwd(constrain, h, w_gate, w_up, w_down):
+    return jax.vjp(functools.partial(_swiglu, constrain), h, w_gate, w_up, w_down)
+
+
+def _dense_ffn_bwd(constrain, swiglu_vjp, d_out):
+    return jax.lax.optimization_barrier(swiglu_vjp(d_out))
+
+
+_dense_ffn.defvjp(_dense_ffn_fwd, _dense_ffn_bwd)
+
+
 def _layer(
     x: jax.Array,
     layer_params: Dict,
@@ -318,17 +356,18 @@ def _layer(
         if c.n_experts is not None:
             down, router_stats = moe_ffn(layer_params["mlp"], h, c, rules=rules, mesh=mesh)
         else:
-            gate = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_gate"].astype(dt))
-            up = jnp.einsum("bse,ef->bsf", h, layer_params["mlp"]["w_up"].astype(dt))
-            ff = constrain(jax.nn.silu(gate) * up, ("act_batch", "act_seq", "act_mlp"))
-            down = jnp.einsum("bsf,fe->bse", ff, layer_params["mlp"]["w_down"].astype(dt))
+            mlp = layer_params["mlp"]
+            down = _dense_ffn(
+                constrain, h, mlp["w_gate"].astype(dt), mlp["w_up"].astype(dt), mlp["w_down"].astype(dt)
+            )
         x = x + constrain(down, ("act_batch", "act_seq", "act_embed"))
     return x, router_stats
 
 
 def _remat_policy(config: TransformerConfig):
     """Validated checkpoint policy for the configured remat granularity
-    (shared by the scan and pipeline paths)."""
+    (shared by the scan and pipeline paths).  It says what JAX recomputes;
+    over libtpu's limit XLA's pass may duplicate more (see `_dense_ffn`)."""
     # The attention op names its own residuals (ops/attention.py): a policy
     # that keeps the output without the log-sum-exp would still re-run the
     # kernel's forward in the backward pass.

@@ -115,27 +115,38 @@ def test_sp_actually_runs_ring_attention():
 # -- remat policies and the residuals attention names ------------------------
 
 
+def _sub_jaxprs(eqn):
+    """The jaxprs an equation carries: scan, cond, remat, shard_map, pjit."""
+    for param in eqn.params.values():
+        for sub in param if isinstance(param, (tuple, list)) else (param,):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
 def _eqns(jaxpr):
-    """Every equation of a jaxpr, through scan, cond, remat, shard_map, pjit."""
+    """Every equation of a jaxpr, through the jaxprs its equations carry."""
     for eqn in jaxpr.eqns:
         yield eqn
-        for param in eqn.params.values():
-            for sub in param if isinstance(param, (tuple, list)) else (param,):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
+        for sub in _sub_jaxprs(eqn):
+            yield from _eqns(sub)
 
 
-def _grad_jaxpr(cfg, strategy=None, spec=None, seq=128):
-    """jaxpr of the gradient of the model's forward, traced on shapes alone."""
+def _mesh_kw(strategy, spec):
+    """`forward`'s rules= and mesh= for a strategy on the CPU devices."""
+    if strategy is None:
+        return {}
+    mesh = build_mesh(spec, devices=jax.devices()[: spec.size()])
+    return dict(rules=resolve_rules(strategy), mesh=mesh)
+
+
+def _model_jaxpr(cfg, strategy=None, spec=None, seq=128, grad=True):
+    """jaxpr of the model's forward, or of its gradient, traced on shapes alone."""
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     toks = jnp.zeros((4, seq), jnp.int32)
-    kw = {}
-    if strategy is not None:
-        mesh = build_mesh(spec, devices=jax.devices()[: spec.size()])
-        kw = dict(rules=resolve_rules(strategy), mesh=mesh)
-    grad = jax.grad(lambda p: forward(p, toks, cfg, **kw).sum())
-    return jax.make_jaxpr(grad)(params).jaxpr
+    kw = _mesh_kw(strategy, spec)
+    fn = lambda p: forward(p, toks, cfg, **kw).sum()  # noqa: E731
+    return jax.make_jaxpr(jax.grad(fn) if grad else fn)(params).jaxpr
 
 
 @pytest.mark.parametrize(
@@ -152,7 +163,7 @@ def test_flash_forward_runs_once_when_the_policy_saves_attention(
     cfg = TransformerConfig.tiny(attention_impl="pallas", remat=True, remat_policy=policy)
     kernels = collections.Counter(
         e.params["name"]
-        for e in _eqns(_grad_jaxpr(cfg, strategy, spec))
+        for e in _eqns(_model_jaxpr(cfg, strategy, spec))
         if e.primitive.name == "pallas_call"
     )
     assert kernels["flash_bwd_dq"] == kernels["flash_bwd_dkv"] > 0
@@ -184,7 +195,7 @@ def test_xla_and_ring_attention_save_their_output_under_attn(impl, strategy, spe
     found = {}
     for policy in (None, "attn"):
         cfg = TransformerConfig.tiny(attention_impl=impl, remat=True, remat_policy=policy)
-        eqns = list(_eqns(_grad_jaxpr(cfg, strategy, spec, seq=64)))
+        eqns = list(_eqns(_model_jaxpr(cfg, strategy, spec, seq=64)))
         layers_fwd = next(
             e for e in eqns if e.primitive.name == "scan" and e.params["length"] == cfg.n_layers
         )
@@ -200,6 +211,79 @@ def test_xla_and_ring_attention_save_their_output_under_attn(impl, strategy, spe
         # the probs @ v einsum is the one matmul the saved output spares (the
         # blockwise scan and the ring loop re-run whole for their own residuals)
         assert found[None]["dots"] - found["attn"]["dots"] == 1
+
+
+# -- the dense FFN's backward finishes as one unit ---------------------------
+
+MESHES = pytest.mark.parametrize(
+    "strategy,spec", [(None, None), ("fsdp", MeshSpec(data=1, fsdp=4))], ids=["nomesh", "fsdp4"]
+)
+REMAT = {
+    "noremat": dict(remat=False),
+    "full": dict(remat=True, remat_policy=None),
+    "attn": dict(remat=True, remat_policy="attn"),
+    "qkv_attn": dict(remat=True, remat_policy="qkv_attn"),
+}
+MOE = dict(n_experts=4, experts_per_token=2, router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
+
+
+@MESHES
+@pytest.mark.parametrize("remat", ["noremat", "attn", "qkv_attn"])
+def test_tied_ffn_gives_the_plain_ffns_loss_and_gradients(remat, strategy, spec, monkeypatch):
+    """`_dense_ffn` orders its backward, nothing else: the loss and every
+    gradient leaf equal those of `_swiglu` called directly."""
+    from ray_tpu.models import transformer
+
+    cfg = TransformerConfig.tiny(**REMAT[remat])
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = _batch(jax.random.PRNGKey(1), b=4)
+    kw = _mesh_kw(strategy, spec)
+
+    def loss(p):
+        logp = jax.nn.log_softmax(forward(p, batch["tokens"], cfg, **kw))
+        return -jnp.take_along_axis(logp, batch["targets"][..., None], -1).mean()
+
+    tied = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(transformer, "_dense_ffn", transformer._swiglu)
+    plain = jax.jit(jax.value_and_grad(loss))(params)
+    for a, b in zip(*map(jax.tree_util.tree_leaves, (tied, plain))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5, rtol=1e-5)
+
+
+def _barriers(jaxpr, in_backward=False):
+    """(operand count, inside a reverse scan?) of every optimization_barrier."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "optimization_barrier":
+            found.append((len(eqn.invars), in_backward))
+        below = in_backward or (eqn.primitive.name == "scan" and eqn.params["reverse"])
+        for sub in _sub_jaxprs(eqn):
+            found += _barriers(sub, below)
+    return found
+
+
+@MESHES
+@pytest.mark.parametrize("remat", ["noremat", "full", "qkv_attn"])
+def test_dense_backward_ties_its_four_cotangents_once_per_layer_body(remat, strategy, spec):
+    """(dh, dW_gate, dW_up, dW_down) pass ONE barrier in the backward scan's
+    layer body; the forward holds none."""
+    cfg = TransformerConfig.tiny(**REMAT[remat])
+    assert _barriers(_model_jaxpr(cfg, strategy, spec)) == [(4, True)]
+    assert _barriers(_model_jaxpr(cfg, strategy, spec, grad=False)) == []
+
+
+@pytest.mark.parametrize("remat", ["noremat", "qkv_attn"])
+def test_expert_model_bypasses_the_tie(remat):
+    """`n_experts` set takes the `moe_ffn` branch: no barrier anywhere, and
+    the train step traces to the equations it had at the parent of PR 27
+    (counted there with `_eqns`)."""
+    cfg = TransformerConfig.tiny(**REMAT[remat], **MOE)
+    assert _barriers(_model_jaxpr(cfg)) == []
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    step = jax.make_jaxpr(ctx._train_step)(state, {"tokens": toks, "targets": toks})
+    assert sum(1 for _ in _eqns(step.jaxpr)) == {"noremat": 2786, "qkv_attn": 3563}[remat]
 
 
 @pytest.mark.slow  # pp_fsdp compile cost; sharding twins stay via sp tests

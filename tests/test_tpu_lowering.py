@@ -62,6 +62,9 @@ def test_train_step_lowers_for_tpu_with_kernel(n_devices, spec, strategy):
     # `qkv_attn` saves the residuals the kernel names, so the forward kernel
     # is in the step once: a second `flash_fwd` is the backward re-running it.
     assert _mosaic_kernels(text) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    # The dense FFN's four cotangents reach the TPU compiler through one
+    # barrier (`_dense_ffn`); the checkpoint's own barrier is far wider.
+    assert len(re.findall(r"%\d+:4 = stablehlo.optimization_barrier", text)) == 1
 
 
 def test_full_recompute_reruns_the_forward_kernel():
