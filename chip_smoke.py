@@ -3,7 +3,7 @@
 
 Drives the main path once, the way a user would: `ray_tpu.init()`, then
 `JaxTrainer.fit()` with ONE train worker that owns the host's chips.  Inside
-the worker the loop builds bench.py's 0.94B decoder at its full width (d_model
+the worker the loop builds a 0.94B decoder at its full width (d_model
 2048, 16 layers, 16 heads of 128, d_ff 5504, vocab 32000, bf16 params,
 remat_policy "qkv_attn"; weights random from a seed), an `LMTrainContext` on a
 mesh of the worker's chips, and takes a compile step plus a few steady steps on
@@ -45,8 +45,8 @@ from typing import Any, Dict, List, Optional
 REPO_ROOT = os.path.dirname(os.path.abspath(__file__))
 DEADLINE_S = 1100  # the contract allows 1200 s, compilation included
 
-# bench.py's configuration (make_cfg(1024)).  The dtype is a name here and
-# becomes a jnp dtype inside the worker: the driver builds nothing from jax.
+# The dtype is a name here and becomes a jnp dtype inside the worker: the
+# driver builds nothing from jax.
 BENCH_MODEL: Dict[str, Any] = dict(
     vocab_size=32000, d_model=2048, n_layers=16, n_heads=16, n_kv_heads=16,
     d_ff=5504, max_seq_len=1024, param_dtype="bfloat16", remat=True,

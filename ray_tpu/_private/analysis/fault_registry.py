@@ -32,11 +32,8 @@ from ray_tpu._private.analysis.common import Violation, parse_file
 PASS = "fault-registry"
 
 # A proc= selector matches by substring against tags of these shapes:
-# main | head | zygote | worker:<wid>[:actor:<Class>] | daemon:<node_id> |
-# io_shard:<idx> (the head's connection-fabric shard processes).
-_VALID_PROC_PREFIXES = (
-    "main", "head", "worker", "daemon", "zygote", "actor:", "io_shard",
-)
+# main | head | zygote | worker:<wid>[:actor:<Class>] | daemon:<node_id>.
+_VALID_PROC_PREFIXES = ("main", "head", "worker", "daemon", "zygote", "actor:")
 
 CATALOG_HEADER = (
     "# Generated fault-point catalog — do not edit by hand.\n"

@@ -5,7 +5,8 @@ per batch of reply/pub/done/refop/pdone/log frames) only holds while the
 hot streaming modules route their sends through batching conns.  A future
 PR adding `some_conn.send(...)` on one of these paths silently regresses
 it back to one syscall + one receiver wakeup per frame — exactly the
-steady-state cost PROFILE_r5.md measured.
+steady-state cost a profile of the head under load measured (sandbox,
+1 vCPU).
 
 This pass catalogs every `.send(...)` call on a conn-ish receiver inside
 the hot modules.  Each existing site is a REVIEWED allowlist entry (most
@@ -42,10 +43,6 @@ HOT_MODULES = frozenset(
         "ray_tpu/_private/node_daemon.py",
         "ray_tpu/_private/peer.py",
         "ray_tpu/_private/driver_client.py",
-        # io-shard fabric: every owned conn and the head-ward ctl channel
-        # are coalesced streams; an unbatched send here regresses the
-        # whole slice of conns the shard owns.
-        "ray_tpu/_private/io_shard.py",
     }
 )
 

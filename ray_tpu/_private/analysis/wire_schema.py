@@ -47,7 +47,6 @@ WIRE_MODULES = frozenset(
         "ray_tpu/_private/runtime.py",
         "ray_tpu/_private/worker_proc.py",
         "ray_tpu/_private/peer.py",
-        "ray_tpu/_private/io_shard.py",
         "ray_tpu/_private/node_daemon.py",
         "ray_tpu/_private/driver_client.py",
         "ray_tpu/_private/pubsub.py",

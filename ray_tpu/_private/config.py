@@ -252,7 +252,7 @@ _DEFS: Dict[str, tuple] = {
     "wire_native": (
         1, int,
         "1 = encode the hot control-frame kinds (task push, done, refop, "
-        "metrics/refs/prof pushes, shard forwards) with the struct-framed "
+        "metrics/refs/prof pushes) with the struct-framed "
         "native codec (wire_native.py: marshal data tuples, no pickle, "
         "~14x cheaper per TaskSpec); 0 = pickle every frame (the v2 "
         "behavior).  Negotiated by the protocol-version fence; kinds "
@@ -396,27 +396,6 @@ _DEFS: Dict[str, tuple] = {
         "transfer/spill/restore/free records merged into the chrome "
         "timeline)",
     ),
-    "head_io_shards": (
-        0, int,
-        "number of io-shard processes the head fans its connection fabric "
-        "across: each shard owns a slice of the worker/daemon/driver conns "
-        "(handed off by conn-hash after the auth handshake), runs its own "
-        "epoll loop + protocol-v2 decode/encode, and forwards only decoded "
-        "control messages to the head over one batched channel; 0 = the "
-        "classic in-process io loop (single-core behavior unchanged) "
-        "(ray: the gRPC server thread pools in gcs_server)",
-    ),
-    "io_shard_restart_s": (
-        0.5, float,
-        "backoff before the head respawns a dead io shard; its conns fail "
-        "over immediately (peers reconnect and hash onto live shards)",
-    ),
-    "io_shard_pending_send_s": (
-        30.0, float,
-        "how long an io shard buffers head->conn sends for a conn whose "
-        "fd handoff has not arrived yet (the two ride different channels "
-        "and may reorder) before dropping them as dead-conn traffic",
-    ),
     "zygote_fork_grace_s": (
         20.0, float,
         "how long a zygote-forked worker handle with no pid attribution "
@@ -529,7 +508,6 @@ WIRING_ENV: Dict[str, str] = {
     "RAY_TPU_NODE_ID": "this node's id (set by the spawning daemon)",
     "RAY_TPU_NODE_CONFIG": "JSON node spec for a starting node daemon",
     "RAY_TPU_HEAD_CONFIG": "JSON head spec for `ray_tpu head` boot",
-    "RAY_TPU_IO_SHARD_CONFIG": "JSON shard spec for a forked io shard",
     "RAY_TPU_PEER_HOST": "host the worker's direct-call listener binds",
     "RAY_TPU_HOST_IP": "this host's routable IP (parallel bootstrap)",
     "RAY_TPU_STORE_DIR": "shm store directory handed to spawned processes",

@@ -30,12 +30,10 @@ Spec grammar (clauses joined by ';'):
                                match=^done hits "done" but not "pdone")
               | 'proc=<s>'     fire only in processes whose tag contains s
                                (tags: 'main', 'head', 'worker:<wid>',
-                               'daemon:<node_id>', 'zygote',
-                               'io_shard:<idx>'; a worker hosting an
-                               actor appends ':actor:<Class>', so
-                               proc=actor:Replica scopes a kill to serve
-                               replicas and proc=io_shard:1 to one head
-                               io shard)
+                               'daemon:<node_id>', 'zygote'; a worker
+                               hosting an actor appends ':actor:<Class>',
+                               so proc=actor:Replica scopes a kill to
+                               serve replicas)
 
 Actions at the point:
     drop   -> point() returns "drop"; the site skips the operation while

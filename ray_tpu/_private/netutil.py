@@ -6,39 +6,12 @@ right before a request on the same conn) trips Nagle + delayed-ACK and
 turns a sub-millisecond round trip into ~40ms — the reference disables
 Nagle on its RPC sockets for the same reason (grpc sets TCP_NODELAY by
 default).
-
-The fd-passing pair (send_conn_fd / recv_conn_fd) is the io-shard
-handoff primitive: after the auth handshake, the head ships a live
-connection's file descriptor to an io-shard process over an AF_UNIX
-channel (SCM_RIGHTS, the same mechanism multiprocessing.resource_sharer
-uses) so the shard takes over the socket without the peer noticing —
-same TCP conn, new owning process.
 """
 
 from __future__ import annotations
 
 import os
 import socket
-
-
-def send_conn_fd(channel, fd: int, dest_pid: int) -> None:
-    """Ship a connection's fd over an AF_UNIX Connection (SCM_RIGHTS).
-    The caller still owns (and must close) its copy of `fd`; the receiver
-    gets an independent duplicate."""
-    from multiprocessing import reduction
-
-    reduction.send_handle(channel, fd, dest_pid)
-
-
-def recv_conn_fd(channel):
-    """Receive a handed-off connection fd and rebuild a live
-    multiprocessing Connection around it (read+write: the io-shard side
-    of the handoff owns both directions of the socket)."""
-    from multiprocessing import reduction
-    from multiprocessing.connection import Connection
-
-    fd = reduction.recv_handle(channel)
-    return Connection(fd)
 
 
 def send_fd(channel, fd: int, dest_pid: int) -> None:

@@ -1,7 +1,7 @@
 """Core-runtime microbenchmarks (ray: python/ray/_private/ray_perf.py:93).
 
-Same workload shapes as the reference's `ray microbenchmark` so the numbers
-in BENCH_core_r*.json are comparable with BASELINE.md's table:
+Same workload shapes as the reference's `ray microbenchmark` so the counts
+it prints are comparable with BASELINE.md's table:
 
   single_client_tasks_sync      submit f.remote(); get() one at a time
   single_client_tasks_async     submit a window of tasks, get in batches
@@ -101,10 +101,9 @@ def timeit(name: str, fn: Callable[[], int], warmup: int = 1, repeat: int = 3):
 
 
 def host_shape() -> Dict:
-    """Self-describing host header for every BENCH json: cpu count, load
+    """Self-describing host header for every report: cpu count, load
     average at the run, and the cgroup cpu quota when one applies — a
-    1-vCPU artifact must SAY it is one (BENCH_shard_r1's honesty note,
-    promoted into the data)."""
+    1-vCPU artifact must SAY it is one."""
     import os as _os
 
     shape: Dict = {"nproc": _os.cpu_count()}
@@ -141,7 +140,7 @@ def _enable_local_persistence() -> None:
     the mutation journal active on the local runtime, exactly as a
     standalone head runs them — so journal_appends_per_op /
     journal_fsyncs_per_op measure the real durability tax on the hot
-    path (the honesty requirement: BENCH_core medians must stay within
+    path (the honesty requirement: the core medians must stay within
     noise of the journal-less tree)."""
     import os as _os
     import threading as _threading
@@ -445,7 +444,7 @@ def refs_ab(out_path=None, rounds: int = 3, budget_pct: float = 3.0):
     isolated toggle is the honest way to attribute cost to THIS leg.)
 
         python -m ray_tpu._private.ray_perf --refs-ab \
-            [--json BENCH_refs_r1.json]
+            [--json out.json]
     """
     import os as _os
     import statistics
@@ -533,7 +532,7 @@ def prof_ab(out_path=None, rounds: int = 3, budget_pct: float = 5.0):
     measurement (profiler on at default HZ must cost <5%).
 
         python -m ray_tpu._private.ray_perf --prof-ab \
-            [--json BENCH_prof_r1.json]
+            [--json out.json]
     """
     import os as _os
     import statistics
@@ -614,7 +613,7 @@ def telemetry_ab(out_path=None, rounds: int = 3, budget_pct: float = 3.0):
     budget (<3% by the ISSUE 6 acceptance bar) and writes the artifact.
 
         python -m ray_tpu._private.ray_perf --telemetry-ab \
-            [--json BENCH_telemetry_r1.json]
+            [--json out.json]
     """
     import os as _os
     import statistics
@@ -699,100 +698,6 @@ def telemetry_ab(out_path=None, rounds: int = 3, budget_pct: float = 3.0):
         f"telemetry plane costs {overhead_pct}% on multi_client_tasks_async "
         f"(budget {budget_pct}%): off={runs['off']} on={runs['on']}"
     )
-    return report
-
-
-# ---------------------------------------------------------------------------
-# io-shard sweep: the head-fabric scaling acceptance artifact
-
-
-def shard_sweep(out_path=None, shard_counts=(0, 1, 2, 4), rounds: int = 3):
-    """multi_client_tasks_async across RAY_TPU_HEAD_IO_SHARDS values:
-    fresh cluster per point, median-of-N (the honesty rule), per-shard
-    wire counters captured from the telemetry sink — the deterministic
-    proof that decode work actually runs on shard pids.
-
-        python -m ray_tpu._private.ray_perf --shard-sweep \
-            [--json BENCH_shard_r1.json]
-
-    Honesty note baked into the artifact: on a 1-vCPU host every shard
-    process shares one core with the head, so throughput gains are
-    bounded by core count — the sweep's job THERE is to show sharding
-    costs ~nothing and moves the decode work out; scaling shows up on
-    multi-core hosts (the reference envelope is 64 cores)."""
-    import os as _os
-    import statistics
-
-    from ray_tpu._private import config as _config
-
-    saved = _os.environ.get("RAY_TPU_HEAD_IO_SHARDS")
-    sweep = []
-    try:
-        for n in shard_counts:
-            _os.environ["RAY_TPU_HEAD_IO_SHARDS"] = str(n)
-            _config._reset_for_tests()
-            ray_tpu.init(num_cpus=max(_os.cpu_count() or 1, 16))
-            runs = []
-            shard_stats = {}
-            try:
-                for _ in range(rounds):
-                    runs.append(_multi_client_once())
-                from ray_tpu._private.runtime import get_runtime
-
-                rt = get_runtime()
-                time.sleep(1.3)  # let a final metrics push land
-                for key, snap in sorted(rt.telemetry.processes.items()):
-                    if not key.startswith("io_shard"):
-                        continue
-                    w = snap.get("wire") or {}
-                    shard_stats[key] = {
-                        "pid": snap.get("pid"),
-                        "logical_frames": w.get("logical_frames", 0),
-                        "physical_writes": w.get("physical_writes", 0),
-                        "bytes_written": w.get("bytes_written", 0),
-                        "conns": int(
-                            (snap.get("internal") or {}).get("io_shard_conns", 0)
-                        ),
-                    }
-            finally:
-                ray_tpu.shutdown()
-            rec = {
-                "io_shards": n,
-                "ops_per_s": round(statistics.median(runs), 1),
-                "runs": runs,
-                "shard_wire_stats": shard_stats,
-            }
-            sweep.append(rec)
-            print(json.dumps(rec), flush=True)
-    finally:
-        if saved is None:
-            _os.environ.pop("RAY_TPU_HEAD_IO_SHARDS", None)
-        else:
-            _os.environ["RAY_TPU_HEAD_IO_SHARDS"] = saved
-        _config._reset_for_tests()
-    report = {
-        "name": "multi_client_tasks_async_shard_sweep",
-        "host": host_shape(),
-        "host_nproc": _os.cpu_count(),
-        "note": (
-            "median-of-%d per point, fresh cluster per point.  HONESTY: "
-            "on a %s-vCPU host every io shard shares cores with the head "
-            "process, so ops/s gains are bounded by core count — the "
-            "meaningful claims here are (a) sharding at 0 extra cores "
-            "costs within host noise and (b) shard_wire_stats proves the "
-            "per-conn decode work (logical_frames/physical_writes) runs "
-            "on shard pids, off the head's loop.  Throughput SCALING with "
-            "shard count is a multi-core-host claim (reference envelope: "
-            "32k tasks/s on 64 cores, SURVEY.md §6)."
-            % (rounds, _os.cpu_count())
-        ),
-        "sweep": sweep,
-    }
-    print(json.dumps(report, indent=1), flush=True)
-    if out_path:
-        with open(out_path, "w") as f:
-            json.dump(report, f, indent=1)
-            f.write("\n")
     return report
 
 
@@ -883,7 +788,7 @@ def broadcast_relay_ab(rt, nids, mb: int = 100, rounds: int = 3) -> Dict:
     """INTERLEAVED relay on/off A/B of the cold broadcast (same cluster,
     same payload size, alternating rounds): the acceptance measurement
     for the pipelined transfer plan.  The OFF side is the classic
-    staggered admission (BENCH_objmem_r1's regime); the ON side hands
+    staggered admission (log2(N) whole-object rounds); the ON side hands
     out chain/tree plans with mid-flight relays.  Counter leg: the ON
     rounds must land EXACTLY one sealed copy (pull|relay) per receiving
     node per round — pipelining must not multiply copies or re-read the
@@ -938,7 +843,7 @@ def broadcast_relay_ab(rt, nids, mb: int = 100, rounds: int = 3) -> Dict:
             "rounds with one concurrent chain — cannot show in wall "
             "clock; the relay counters + plan shape are the claim this "
             "artifact proves, the multi-host wall-clock claim needs "
-            "multi-host hardware (same residual class as BENCH_shard_r2)"
+            "multi-host hardware"
         ),
         "rounds": rounds,
         "relay_on_s": times["on"],
@@ -1119,7 +1024,7 @@ def object_plane_bench(out_path=None):
     deltas.
 
         python -m ray_tpu._private.ray_perf --object-plane \
-            [--json BENCH_objmem_r2.json]
+            [--json out.json]
     """
     import os as _os
 
@@ -1165,7 +1070,7 @@ def object_plane_bench(out_path=None):
         "name": "object_plane_fastpath",
         "note": (
             "relay A/B is interleaved on/off on one cluster (off = the "
-            "classic staggered rounds, BENCH_objmem_r1's regime); "
+            "classic staggered rounds); "
             "broadcast gb_per_s = size*fanout/wall; copy_stats are "
             "object_copies/object_copy_bytes counter deltas (cluster "
             "aggregate for broadcasts, this process for puts)"
@@ -1190,21 +1095,8 @@ def main(argv=None):
         return refs_ab(out_path)
     if "--prof-ab" in argv:
         return prof_ab(out_path)
-    if "--shard-sweep" in argv:
-        return shard_sweep(out_path)
     if "--object-plane" in argv:
         return object_plane_bench(out_path)
-    if "--io-shards" in argv:
-        # Whole-suite override: run every bench with a sharded head
-        # fabric (the env form reaches the Runtime this process boots).
-        import os as _os2
-
-        _os2.environ["RAY_TPU_HEAD_IO_SHARDS"] = argv[
-            argv.index("--io-shards") + 1
-        ]
-        from ray_tpu._private import config as _config2
-
-        _config2._reset_for_tests()
     import os as _os
 
     # Logical-CPU headroom: the benches measure control-plane throughput,

@@ -950,20 +950,6 @@ class Runtime:
             threading.Thread(
                 target=self._snapshot_loop, daemon=True, name="raytpu-snapshot"
             ).start()
-        # Head io-shard fabric (io_shard.py; ray: the gcs_server gRPC
-        # thread pools): N processes each owning a slice of the
-        # worker/daemon/driver conns, decoding protocol-v2 frames there
-        # and forwarding only decoded control messages here.  State
-        # mutation stays in THIS process (the journaled single-writer
-        # path); 0 shards = the classic in-process loop, unchanged.
-        self._io_shards: Dict[int, Any] = {}
-        self._conn_to_shard: Dict[Any, int] = {}
-        self._shard_conn_seq = 0
-        self._shard_listener = None
-        self._shard_listener_path = None
-        n_shards = _config.get("head_io_shards")
-        if n_shards > 0:
-            self._start_io_shards(n_shards)
         self._accept_thread = threading.Thread(
             target=self._accept_loop, daemon=True, name="raytpu-accept"
         )
@@ -1113,12 +1099,6 @@ class Runtime:
                 "head_pending_fences": float(len(self._pending_fences)),
                 "head_live_workers": float(
                     sum(1 for h in self.workers.values() if h.state != "dead")
-                ),
-                "head_io_shards_live": float(
-                    sum(1 for s in self._io_shards.values() if s.alive)
-                ),
-                "head_sharded_conns": float(
-                    sum(len(s.conns) for s in self._io_shards.values())
                 ),
                 "journal_entries": float(
                     self._journal.entries if self._journal else 0
@@ -2438,342 +2418,6 @@ class Runtime:
                 pass
 
     # ------------------------------------------------------------------
-    # io-shard fabric (io_shard.py): spawn/supervise shard processes, hand
-    # conns off after the auth handshake, route their traffic both ways.
-
-    def _start_io_shards(self, n: int) -> None:
-        import tempfile
-
-        from multiprocessing.connection import Listener as _Listener
-
-        from ray_tpu._private import io_shard as _io_shard
-
-        # AF_UNIX (required for SCM_RIGHTS fd passing) + pid-unique path:
-        # a restarted head in the same session binds a fresh socket.
-        path = os.path.join(
-            tempfile.gettempdir(), f"raytpu-shards-{os.getpid():x}.sock"
-        )
-        try:
-            os.unlink(path)
-        except OSError:
-            pass
-        self._shard_listener = _Listener(
-            address=path, family="AF_UNIX", authkey=self._authkey
-        )
-        self._shard_listener_path = path
-        threading.Thread(
-            target=self._shard_accept_loop, daemon=True,
-            name="raytpu-shard-accept",
-        ).start()
-        for i in range(n):
-            self._io_shards[i] = _io_shard.spawn_shard_process(
-                i, path, self._authkey, self.session_name
-            )
-        # Bounded wait for the fabric: conns handshaken before a shard is
-        # live stay head-direct for their lifetime, so give the shards a
-        # beat to hello before the first worker wave connects.  Falling
-        # through on timeout degrades to the in-process loop, never fails.
-        deadline = time.monotonic() + 15.0
-        while time.monotonic() < deadline:
-            if all(h.alive for h in self._io_shards.values()):
-                break
-            if any(h.proc.poll() is not None for h in self._io_shards.values()):
-                break  # a shard died at spawn; supervision will respawn it
-            time.sleep(0.02)
-
-    def _shard_accept_loop(self) -> None:
-        """Accept the per-shard channel pair (plain hello each: batched
-        ctl for messages, raw fd channel for SCM_RIGHTS handoffs); a shard
-        with both channels up goes live and starts receiving handoffs."""
-        from ray_tpu._private import wire
-
-        while not self._shutdown:
-            try:
-                conn = self._shard_listener.accept()
-            except (OSError, EOFError):
-                if self._shutdown:
-                    return
-                continue
-            except Exception:
-                continue  # authkey challenge failed: a stranger, not a shard
-            try:
-                hello = conn.recv()
-            except (EOFError, OSError):
-                continue
-            if not (isinstance(hello, tuple) and len(hello) >= 3):
-                conn.close()
-                continue
-            kind, idx, pid = hello[0], hello[1], hello[2]
-            sh = self._io_shards.get(idx)
-            if sh is None or sh.respawn_at:
-                conn.close()  # unknown or already-declared-dead shard
-                continue
-            if kind == "io_shard":
-                sh.ctl_conn = wire.batching(wire.wrap(conn))
-                sh.pid = pid
-            elif kind == "io_shard_fd":
-                sh.fd_conn = conn
-                sh.pid = pid
-            else:
-                conn.close()
-                continue
-            if sh.ctl_conn is not None and sh.fd_conn is not None and not sh.alive:
-                sh.alive = True
-                with self.lock:
-                    self._conn_to_shard[sh.ctl_conn] = idx
-                    self._conns_version += 1
-                self.events.emit(
-                    "INFO", "io_shard", "io shard online", shard=idx, pid=pid
-                )
-
-    def _pick_io_shard(self, peer_id: str):
-        """Conn-hash over the LIVE shards (a dead shard's slice rehashes
-        onto survivors at reconnect); None = keep the conn head-direct."""
-        shards = self._io_shards
-        if not shards:
-            return None
-        live = [h for _i, h in sorted(shards.items()) if h.alive]
-        if not live:
-            return None
-        import zlib
-
-        return live[zlib.crc32(str(peer_id).encode()) % len(live)]
-
-    def _shard_route(self, conn, kind: str, peer_id: str):
-        """(registree, shard): the ShardConnProxy to put in the conn maps
-        when a live shard will own this conn, else (conn, None).  The
-        caller registers the returned object, then (shard path) calls
-        _complete_handoff to actually ship the fd."""
-        from ray_tpu._private import io_shard as _io_shard
-
-        sh = self._pick_io_shard(peer_id)
-        if sh is None:
-            return conn, None
-        with self.lock:
-            self._shard_conn_seq += 1
-            conn_id = f"sc{self._shard_conn_seq}"
-        return _io_shard.ShardConnProxy(sh, conn_id, kind, str(peer_id)), sh
-
-    def _complete_handoff(self, sh, proxy, conn) -> None:
-        """Ship a registered conn's fd to its shard.  Order matters: flush
-        the real conn (handshake frames queued on its BatchingConn must
-        hit the wire before anything the shard writes), dispatch frames
-        decoded during the handshake but not yet delivered (the shard can
-        only read the socket after the fd lands), then send the fd and
-        close this process's copy."""
-        try:
-            _wire.flush_conn(conn)
-        except (OSError, ValueError):
-            pass  # dead socket: adopt anyway; the shard reports EOF at once
-        sh.conns[proxy.conn_id] = proxy
-        leftovers = []
-        try:
-            while conn.pending_frames():
-                leftovers.append(conn.recv())
-        except (EOFError, OSError):
-            pass
-        if leftovers:
-            self._dispatch_sharded_msgs(proxy, leftovers)
-        try:
-            sh.adopt(proxy.conn_id, proxy.kind, proxy.peer_id, conn.fileno())
-        except (OSError, ValueError):
-            # Shard died mid-handoff: fail its conns over (this one's peer
-            # reconnects through the normal window).
-            self._on_io_shard_death(sh.idx)
-        try:
-            conn.close()
-        except OSError:
-            pass
-
-    def _dispatch_sharded_msgs(self, proxy, msgs: List[tuple]) -> None:
-        """Route a sharded conn's decoded messages through the same
-        handlers the in-process loop uses, resolved by the proxy's
-        registered identity (per-conn order is the shard_fwd list order —
-        the invariant tests/test_io_shard.py pins)."""
-        if proxy.kind == "daemon":
-            nid = self._conn_to_daemon.get(proxy)
-            if nid is None:
-                return
-            for m in msgs:
-                self._handle_daemon_msg(nid, m)
-        elif proxy.kind == "driver":
-            did = self._conn_to_driver.get(proxy)
-            if did is None:
-                return
-            for m in msgs:
-                try:
-                    self._handle_msg(did, m)
-                except Exception:
-                    import traceback
-
-                    traceback.print_exc()
-        else:  # "ready" — a worker conn
-            wid = self._conn_to_worker.get(proxy)
-            if wid is None:
-                return
-            self._handle_msgs(wid, msgs)
-
-    def _sharded_conn_eof(self, proxy) -> None:
-        """A shard reported a handed-off conn's EOF: the SOCKET died, so
-        run the same death path the in-process loop runs."""
-        proxy._closed = True
-        if proxy.kind == "daemon":
-            nid = self._conn_to_daemon.get(proxy)
-            if nid is not None:
-                self._daemon_conn_eof(proxy, nid)
-        elif proxy.kind == "driver":
-            did = self._conn_to_driver.get(proxy)
-            if did is not None:
-                self._driver_conn_eof(proxy, did)
-        else:
-            wid = self._conn_to_worker.get(proxy)
-            if wid is not None:
-                self._worker_conn_eof(proxy, wid)
-
-    def _sharded_conn_orphaned(self, proxy) -> None:
-        """A SHARD died, not the peer: every owned conn's fd closed at
-        once while the peers live on.  Unlike a per-conn EOF this is a
-        transient reset — the same class drivers already get a grace for
-        — so give each peer its reconnect window instead of declaring a
-        crash and churning every actor the dead shard happened to carry.
-        Peers reconnect within seconds and re-handshake onto live shards;
-        one that never comes back falls to the usual detectors (deferred
-        crash below, daemon heartbeat timeout)."""
-        from ray_tpu._private import config as _config
-
-        proxy._closed = True
-        window = _config.get("reconnect_window_s")
-        if proxy.kind == "daemon":
-            nid = self._conn_to_daemon.get(proxy)
-            if nid is None:
-                return
-            hb_timeout = _config.get("health_check_timeout_ms")
-            if window > 0 and hb_timeout > 0:
-                # Drop the conn binding only: the daemon's re-hello
-                # rebinds it (the node record survives); the heartbeat
-                # timeout catches a daemon that never returns.
-                with self.lock:
-                    self._conn_to_daemon.pop(proxy, None)
-                    self._conns_version += 1
-            else:
-                self._daemon_conn_eof(proxy, nid)
-        elif proxy.kind == "driver":
-            did = self._conn_to_driver.get(proxy)
-            if did is not None:
-                self._driver_conn_eof(proxy, did)  # has its own grace
-        else:
-            wid = self._conn_to_worker.get(proxy)
-            if wid is None:
-                return
-            if window <= 0:
-                self._worker_conn_eof(proxy, wid)  # classic mode: EOF = death
-                return
-            with self.lock:
-                self._conn_to_worker.pop(proxy, None)
-                self._conns_version += 1
-                h = self.workers.get(wid)
-                if h is not None and h.conn is proxy:
-                    # Back to the pre-ready buffering state: sends queue
-                    # in pending_sends and drain at the re-handshake.
-                    h.conn = None
-                # Crash only if the reconnect never lands (the handshake
-                # clears this on arrival).
-                self._deferred_crashes[wid] = time.monotonic() + min(
-                    window, 8.0
-                )
-
-    def _handle_shard_msg(self, idx: int, msg: tuple) -> None:
-        sh = self._io_shards.get(idx)
-        if sh is None or not (isinstance(msg, tuple) and msg):
-            return
-        if msg[0] == "shard_fwd":
-            proxy = sh.conns.get(msg[1])
-            if proxy is not None:
-                # Bodies arrive raw (native untouched, pickled ones
-                # shard-validated + re-encoded): decode here — the ONLY
-                # decode native bodies ever get.  wire.recv faults fired
-                # on the shard; firing again here would double-drop.
-                msgs = []
-                for body in msg[2]:
-                    try:
-                        msgs.append(_wire.decode_body(body))
-                    except Exception:
-                        import traceback
-
-                        traceback.print_exc()
-                if msgs:
-                    self._dispatch_sharded_msgs(proxy, msgs)
-        elif msg[0] == "shard_eof":
-            proxy = sh.conns.pop(msg[1], None)
-            if proxy is not None:
-                self._sharded_conn_eof(proxy)
-        elif msg[0] == "metrics_push":
-            self.telemetry.ingest(f"io_shard:{idx}", msg[1])
-
-    def _on_io_shard_death(self, idx: int) -> None:
-        """Fail over a dead shard: every conn it owned is dead (the fds
-        died with the process), so run each one's EOF path — peers see
-        the same socket EOF and reconnect onto live shards.  Idempotent
-        (respawn_at doubles as the death-processed marker)."""
-        from ray_tpu._private import config as _config
-
-        sh = self._io_shards.get(idx)
-        if sh is None:
-            return
-        with self.lock:
-            if sh.respawn_at:
-                return  # death already processed
-            sh.alive = False
-            sh.respawn_at = time.monotonic() + _config.get("io_shard_restart_s")
-            if sh.ctl_conn is not None:
-                self._conn_to_shard.pop(sh.ctl_conn, None)
-                self._conns_version += 1
-        for c in (sh.ctl_conn, sh.fd_conn):
-            try:
-                if c is not None:
-                    c.close()
-            except OSError:
-                pass
-        try:
-            sh.proc.terminate()  # a hung-but-alive shard must actually die
-        except OSError:
-            pass
-        self.telemetry.forget(f"io_shard:{idx}")
-        self.events.emit(
-            "WARNING", "io_shard", "io shard died; failing over its conns",
-            shard=idx, conns=len(sh.conns),
-        )
-        for conn_id in list(sh.conns):
-            proxy = sh.conns.pop(conn_id, None)
-            if proxy is not None:
-                self._sharded_conn_orphaned(proxy)
-
-    def _supervise_io_shards(self, now: float) -> None:
-        """io-loop tick: respawn dead shards after the backoff (their
-        conns already failed over; reconnecting peers hash onto the
-        refreshed live set)."""
-        from ray_tpu._private import io_shard as _io_shard
-
-        for idx, sh in list(self._io_shards.items()):
-            if self._shutdown:
-                return
-            if sh.proc.poll() is None:
-                continue  # running (or still starting pre-hello)
-            if not sh.respawn_at:
-                # Died without the ctl EOF landing yet (spawn failure,
-                # pre-hello crash): process the death now.
-                self._on_io_shard_death(idx)
-                continue
-            if now >= sh.respawn_at:
-                self._io_shards[idx] = _io_shard.spawn_shard_process(
-                    idx, self._shard_listener_path, self._authkey,
-                    self.session_name,
-                )
-                self.events.emit(
-                    "INFO", "io_shard", "io shard respawned", shard=idx
-                )
-
-    # ------------------------------------------------------------------
     # IO threads
 
     def _accept_loop(self):
@@ -2922,12 +2566,9 @@ class Runtime:
                 conn.close()
                 return
             shared = bool(second[2]) if second[0] == "driver_store" else False
-            # Shard the conn AFTER the two-way hello exchange above: the
-            # proxy enters the maps, the real socket ships to its shard.
-            reg, sh = self._shard_route(conn, "driver", did)
             with self.lock:
                 old = self.drivers.get(did)
-                if old is not None and old is not reg:
+                if old is not None and old is not conn:
                     # Reconnect over a LIVE head (transient TCP reset): the
                     # old conn's pending EOF must clean only itself — not
                     # declare the reconnected driver dead (the EOF handler
@@ -2938,20 +2579,18 @@ class Runtime:
                         old.close()
                     except OSError:
                         pass
-                self.drivers[did] = reg
+                self.drivers[did] = conn
                 self._driver_death_grace.pop(did, None)  # reconnect won
                 self.driver_nodes[did] = (
                     self.head_node_id if shared else f"drvnode-{did}"
                 )
                 self.driver_refs.setdefault(did, {})
-                self._conn_to_driver[reg] = did
+                self._conn_to_driver[conn] = did
                 self._conns_version += 1
                 # Attached drivers are this build's jobs (ray:
                 # gcs_job_manager): the journaled transition lets a
                 # restarted head know which owners were already live.
                 self.state.set_job_state(did, "RUNNING", pid=_pid)
-            if sh is not None:
-                self._complete_handoff(sh, reg, conn)
             return
         if first[0] == "daemon":
             # Node daemon registration: ("daemon", node_id, cfg, pid).
@@ -2961,7 +2600,6 @@ class Runtime:
                 self.clock_offsets[f"daemon:{node_id}"] = (
                     time.time() - cfg["clock"]
                 )
-            reg, sh = self._shard_route(conn, "daemon", node_id)
             with self.lock:
                 if node_id not in self.state.nodes:
                     self.state.register_node(
@@ -2973,8 +2611,8 @@ class Runtime:
                 ep = cfg.get("object_endpoint")
                 if ep:
                     self.node_object_endpoints[node_id] = tuple(ep)
-                self.node_daemons[node_id] = reg
-                self._conn_to_daemon[reg] = node_id
+                self.node_daemons[node_id] = conn
+                self._conn_to_daemon[conn] = node_id
                 self.node_daemon_pids[node_id] = int(_pid)
                 self._conns_version += 1
                 # Lifecycle: a provider-launched node registering flips
@@ -2995,8 +2633,6 @@ class Runtime:
                 # reconnected daemon out before its first heartbeat.
                 self._daemon_heartbeats[node_id] = time.monotonic()
                 self._dispatch()
-            if sh is not None:
-                self._complete_handoff(sh, reg, conn)
             return
         if first[0] == "zygote":
             # Fork server up: route subsequent local spawns through it.
@@ -3031,17 +2667,13 @@ class Runtime:
             # stamp (includes one-way latency — ms on loopback, fine for
             # ordering spans across processes in the merged timeline).
             self.clock_offsets[wid] = time.time() - first[6]
-        # Shard routing decided up front: the conn maps register the proxy
-        # (reg) while handshake-time direct traffic keeps using the real
-        # conn; the fd ships only after registration completes.
-        reg, sh = self._shard_route(conn, "ready", wid)
         adopted = False
         with self.lock:
             if len(first) > 4 and first[4]:
                 self.worker_peer_endpoints[wid] = tuple(first[4])
             h = self.workers.get(wid)
             if h is None:
-                h = self._adopt_worker(reg, first)
+                h = self._adopt_worker(conn, first)
                 if h is None:
                     conn.close()
                     return
@@ -3049,8 +2681,6 @@ class Runtime:
             else:
                 h.pid = first[2]
         if adopted:
-            if sh is not None:
-                self._complete_handoff(sh, reg, conn)
             return
         # Flush messages queued while the worker was starting OFF the
         # runtime lock (pipe I/O under the global lock stalls the whole
@@ -3064,11 +2694,10 @@ class Runtime:
             with self.lock:
                 pending = h.pending_sends
                 if not pending:
-                    h.conn = reg
+                    h.conn = conn
                     # The reconnect landed: cancel any pending EOF-grace
-                    # crash (set when this worker's shard died, or by the
-                    # daemon-report defer) — firing it now would kill the
-                    # healed worker.
+                    # crash (set by the daemon-report defer) — firing it
+                    # now would kill the healed worker.
                     self._deferred_crashes.pop(wid, None)
                     if h.state == "starting":
                         h.state = "idle"
@@ -3079,7 +2708,7 @@ class Runtime:
                         self.idle_pool.setdefault(
                             (h.node_id, h.env_key), []
                         ).append(wid)
-                    self._conn_to_worker[reg] = wid
+                    self._conn_to_worker[conn] = wid
                     self._conns_version += 1
                     self._grant_parked_leases(wid)
                     break
@@ -3109,10 +2738,6 @@ class Runtime:
             # relayed work the dead conn lost (see _redrive_worker_relays).
             with self.lock:
                 self._redrive_worker_relays(h, wid, set(announced))
-        if sh is not None:
-            # Publication done: post-handoff sends route through the
-            # proxy (the shard buffers them until the fd lands below).
-            self._complete_handoff(sh, reg, conn)
         with self.lock:
             self._dispatch()
 
@@ -3235,8 +2860,7 @@ class Runtime:
         relayed tasks it still holds (queued or executing).  In-flight
         work the head attributes to this worker that the worker does NOT
         hold was lost with the dead conn — a task push that never
-        arrived, or a done/result frame that died in the socket (an
-        io-shard death loses both shapes while the worker lives on).
+        arrived, or a done/result frame that died in the socket.
 
         Plain tasks are provably not running anywhere (the worker doesn't
         have them), so they retry on their budget or fail loudly — never
@@ -3304,7 +2928,6 @@ class Runtime:
         import selectors
 
         from ray_tpu._private import config as _cfg
-        from ray_tpu._private.io_shard import ShardConnProxy as _ShardConnProxy
 
         sel = selectors.DefaultSelector()
         registered: set = set()
@@ -3408,9 +3031,6 @@ class Runtime:
                                 except OSError:
                                     pass
                                 self._on_daemon_death(nid)
-                # Off the runtime lock: a respawn is a subprocess spawn.
-                if self._io_shards:
-                    self._supervise_io_shards(now)
                 # Dead-holder ref reclaim rides the same tick (its own
                 # lock dance inside; decrefs may fan daemon deletes).
                 if self._dead_refs:
@@ -3449,18 +3069,11 @@ class Runtime:
             if self._conns_version != registered_version:
                 with self.lock:
                     registered_version = self._conns_version
-                    # Sharded conns are ShardConnProxy stand-ins: the
-                    # owning shard epolls the real socket; here we epoll
-                    # only direct conns plus each shard's ctl channel.
-                    current = {
-                        c
-                        for c in (
-                            set(self._conn_to_worker)
-                            | set(self._conn_to_daemon)
-                            | set(self._conn_to_driver)
-                        )
-                        if not isinstance(c, _ShardConnProxy)
-                    } | set(self._conn_to_shard)
+                    current = (
+                        set(self._conn_to_worker)
+                        | set(self._conn_to_daemon)
+                        | set(self._conn_to_driver)
+                    )
                 for conn in registered - current:  # removals FIRST (fd reuse)
                     try:
                         sel.unregister(conn)
@@ -3479,42 +3092,11 @@ class Runtime:
                 readable = [key.fileobj for key, _ in sel.select(timeout=0.05)]
             except OSError:
                 continue
-            # Shard ctl channels first (they multiplex daemon traffic
-            # too), then daemon conns: an OOM-kill report must be applied
+            # Daemon conns first: an OOM-kill report must be applied
             # before the victim worker's own conn EOF (same select round)
             # so the crash classifies as OOM, not a generic worker death.
-            readable.sort(
-                key=lambda c: (
-                    c not in self._conn_to_shard,
-                    c not in self._conn_to_daemon,
-                )
-            )
+            readable.sort(key=lambda c: c not in self._conn_to_daemon)
             for conn in readable:
-                sidx = self._conn_to_shard.get(conn)
-                if sidx is not None:
-                    # One recv here drains a whole shard_fwd batch — many
-                    # conns' decoded traffic per physical read; the
-                    # per-conn syscall fan-in lives in the shard process.
-                    smsgs = []
-                    seof = False
-                    try:
-                        smsgs.append(conn.recv())
-                        while len(smsgs) < 256 and conn.poll(0):
-                            smsgs.append(conn.recv())
-                        while conn.pending_frames():
-                            smsgs.append(conn.recv())
-                    except (EOFError, OSError):
-                        seof = True
-                    for sm in smsgs:
-                        try:
-                            self._handle_shard_msg(sidx, sm)
-                        except Exception:
-                            import traceback
-
-                            traceback.print_exc()
-                    if seof:
-                        self._on_io_shard_death(sidx)
-                    continue
                 nid = self._conn_to_daemon.get(conn)
                 if nid is not None:
                     # Drain the whole readable run INCLUDING decoded batch
@@ -3594,9 +3176,7 @@ class Runtime:
             # thread's blocking wait).
             _wire.flush_dirty()
 
-    # Conn-EOF paths, shared by the in-process io loop and the shard
-    # fabric (a shard_eof report — or a shard death, which closes every
-    # owned fd — must land on exactly the same death handling).
+    # Conn-EOF paths of the io loop.
 
     def _daemon_conn_eof(self, conn, nid: str) -> None:
         with self.lock:
@@ -6687,27 +6267,8 @@ class Runtime:
             except Exception:
                 pass
         # The kill/shutdown frames above are queued on batching conns:
-        # push them out before the fds die with the process.  Sharded
-        # worker kills ride each shard's ctl channel; the trailing
-        # shutdown frame (same FIFO stream) makes the shard deliver them
-        # before exiting.
+        # push them out before the fds die with the process.
         _wire.flush_dirty()
-        for sh in getattr(self, "_io_shards", {}).values():
-            try:
-                if sh.ctl_conn is not None:
-                    sh.ctl_conn.send(("shutdown",))
-                    sh.ctl_conn.flush()
-            except (OSError, ValueError):
-                pass
-            try:
-                sh.proc.terminate()
-            except OSError:
-                pass
-        if getattr(self, "_shard_listener", None) is not None:
-            try:
-                self._shard_listener.close()
-            except OSError:
-                pass
         try:
             self.listener.close()
         except OSError:

@@ -182,7 +182,7 @@ def install(tag: Optional[str] = None) -> None:
         _proc_tag = tag
     # Sampling-profiler autostart (RAY_TPU_PROF_HZ > 0): every process
     # entry funnels through install(), so the always-hot mode covers
-    # head, workers, daemons, and io shards with one knob.  Re-checked
+    # head, workers and daemons with one knob.  Re-checked
     # per call — forked children re-install under their own tag and the
     # parent's sampler thread did not survive the fork.
     try:
@@ -403,9 +403,9 @@ class TelemetrySink:
                     "proc": s.get("proc"),
                     "age_s": round(time.time() - s.get("t", 0.0), 3),
                     "metrics": len(s.get("metrics") or ()),
-                    # Per-process internal gauges ride along (io-shard conn
-                    # counts, head queue depths): `ray_tpu status` reads
-                    # them per process, not just as cluster sums.
+                    # Per-process internal gauges ride along (head queue
+                    # depths): `ray_tpu status` reads them per process,
+                    # not just as cluster sums.
                     **(
                         {"internal": dict(s["internal"])}
                         if isinstance(s.get("internal"), dict)

@@ -24,11 +24,11 @@ This module gives every control connection:
     not serialized here at all.
 
 Protocol v2 adds the BATCH frame: one physical write carrying N
-schema-validated sub-frames.  PROFILE_r5.md showed the head's steady
-state is raw syscall traffic — one posix.write and one epoll wakeup per
-logical control message (the reference amortizes this for free through
-gRPC stream buffering and its batched syncer/pubsub messages,
-src/ray/ray_syncer/ + pubsub/publisher.h).  `BatchingConn` is the sender
+schema-validated sub-frames.  A profile of the head under load (sandbox,
+1 vCPU) showed its steady state is raw syscall traffic — one posix.write
+and one epoll wakeup per logical control message (the reference amortizes
+this for free through gRPC stream buffering and its batched syncer/pubsub
+messages, src/ray/ray_syncer/ + pubsub/publisher.h).  `BatchingConn` is the sender
 side: messages queue into a pending buffer and flush on
 
   (a) size      — pending bytes reach RAY_TPU_WIRE_BATCH_BYTES (~64KB);
@@ -113,8 +113,7 @@ SCHEMAS: Dict[str, Tuple[int, Optional[int], tuple]] = {
     # clock-offset estimate for merging this process's spans/task events
     # into one cluster timeline; the optional 7th is the executor's
     # relayed-work announcement (task ids still held) — the head
-    # re-drives in-flight work missing from it, the conn-death recovery
-    # the io-shard fabric leans on.
+    # re-drives in-flight work missing from it.
     "ready": (3, 7, (str, int)),
     "actor_announce": (1, 1, (list,)),
     "env_failed": (2, 2, (str, str)),
@@ -152,18 +151,6 @@ SCHEMAS: Dict[str, Tuple[int, Optional[int], tuple]] = {
     # cumulative since start) — the worker leg of `ray_tpu profile`.
     # Droppable like metrics_push: a lost push costs freshness only.
     "prof_push": (1, 1, (dict,)),
-    # head io-shard fabric (io_shard.py): the internal channel between the
-    # head process and its io-shard processes.  shard_fwd carries a conn's
-    # raw sub-frame BODIES in arrival order (native bodies untouched —
-    # the head's decode is the only decode; pickled bodies were decoded/
-    # validated on the shard pid and re-encoded): the per-conn ordering
-    # invariant across the shard boundary is the list order.  shard_send
-    # is the reverse path — ONE head-encoded body the shard writes to the
-    # conn without decoding; shard_eof reports a handed-off conn's death.
-    "shard_fwd": (2, 2, (str, list)),
-    "shard_eof": (1, 2, (str,)),
-    "shard_send": (2, 2, (str, bytes)),
-    "shard_close": (1, 1, (str,)),
     # cross-process pubsub (pubsub.py remote delivery)
     "subscribe": (2, 3, (str,)),
     "unsubscribe": (2, 2, (str,)),
@@ -431,19 +418,23 @@ def decode(buf) -> Any:
     return objs[0]
 
 
-def split_frame_bodies(buf) -> List[memoryview]:
-    """Parse a physical frame into its raw sub-frame BODIES, in order,
-    without decoding any of them.  Structural validation only: truncated
-    batches reject whole (the shape a mid-batch sender crash leaves
-    behind).  The io shards use this to forward native bodies raw —
-    decode happens exactly once, head-side."""
+def decode_frames(buf) -> List[Any]:
+    """Decode a physical frame into its validated sub-frames, in order.
+
+    A single frame yields [obj].  For a batch, the framing is checked
+    whole first (a truncated batch rejects whole: the shape a mid-batch
+    sender crash leaves behind), then EVERY sub-frame is decoded and
+    schema-validated before any is returned: one malformed sub-frame
+    rejects the whole batch at the boundary (no partial dispatch).
+    Bodies may be pickled or native (v3) — decode_body dispatches per
+    body."""
     if len(buf) < 4:
         raise ProtocolError("short control frame")
     magic, version = struct.unpack_from("<2sH", buf, 0)
     _check_version(magic, version)
     view = memoryview(buf)
     if magic == MAGIC:
-        return [view[4:]]
+        return [decode_body(view[4:])]
     if len(buf) < _BATCH_HEADER.size:
         raise ProtocolError("truncated batch frame (short header)")
     _m, _v, count = _BATCH_HEADER.unpack_from(buf, 0)
@@ -469,18 +460,7 @@ def split_frame_bodies(buf) -> List[memoryview]:
             f"batch frame has {len(buf) - off} trailing bytes after "
             f"{count} sub-frames"
         )
-    return bodies
-
-
-def decode_frames(buf) -> List[Any]:
-    """Decode a physical frame into its validated sub-frames, in order.
-
-    A single frame yields [obj].  For a batch, EVERY sub-frame is
-    decoded and schema-validated before any is returned: one malformed
-    sub-frame rejects the whole batch at the boundary (no partial
-    dispatch).  Bodies may be pickled or native (v3) — decode_body
-    dispatches per body."""
-    return [decode_body(b) for b in split_frame_bodies(buf)]
+    return [decode_body(b) for b in bodies]
 
 
 # ---------------------------------------------------------------------------
@@ -720,18 +700,6 @@ class TypedConn:
         them, so an epoll/wait would strand a buffered tail."""
         return len(self._rbuf)
 
-    def recv_bodies(self) -> List[bytes]:
-        """One physical frame's raw sub-frame bodies, NO decode (io-shard
-        forward path: native bodies ship head-ward untouched).  Must not
-        be mixed with recv() on the same conn while decoded sub-frames
-        are buffered — the interleaving would reorder the stream."""
-        if self._rbuf:
-            raise RuntimeError(
-                "recv_bodies() with decoded sub-frames pending would "
-                "reorder the stream"
-            )
-        return [bytes(b) for b in split_frame_bodies(self._c.recv_bytes())]
-
     # raw passthrough (object-transfer body, recv_into via fileno)
     def send_bytes(self, b) -> None:
         self._c.send_bytes(b)
@@ -906,9 +874,6 @@ class BatchingConn:
 
     def recv(self) -> Any:
         return self._c.recv()
-
-    def recv_bodies(self) -> List[bytes]:
-        return self._c.recv_bodies()
 
     def pending_frames(self) -> int:
         return self._c.pending_frames()

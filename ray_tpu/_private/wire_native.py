@@ -59,8 +59,8 @@ KIND_IDS = {
     "refs_push": 9,
     "prof_push": 10,
     "spans": 11,
-    "shard_fwd": 12,
-    "shard_send": 13,
+    # ids 12 and 13 are retired (never reused): a body carrying one is
+    # refused as any unknown kind is.
     "reply": 14,
     "heartbeat": 15,
     "direct_seal": 16,
@@ -217,19 +217,6 @@ def encode(obj: Any) -> Optional[bytes]:
     except ValueError:
         return None  # a field marshal can't take: pickle fallback
     return bytes((kid, MARSHAL_VERSION)) + body
-
-
-def kind_of(body) -> Optional[str]:
-    """Peek a body's control kind WITHOUT decoding: native bodies carry it
-    in byte 0; pickled bodies (0x80...) return None — the caller must
-    decode to learn the kind.  Used by the io shards to forward native
-    bodies raw and by fault/stat scoping."""
-    if not body:
-        return None
-    b0 = body[0]
-    if b0 == 0x80:
-        return None
-    return _ID_KINDS.get(b0)
 
 
 def is_native(body) -> bool:

@@ -88,8 +88,7 @@ class WorkerRuntime:
         # Relayed tasks received but not yet replied (queued + executing):
         # the reconnect hello announces these so the head can re-drive
         # exactly what the dead conn lost — a task push that never
-        # arrived, or a done frame that died in the socket (an io-shard
-        # death loses both shapes while this process lives on).  Dict ops
+        # arrived, or a done frame that died in the socket.  Dict ops
         # are GIL-atomic; insertion order mirrors arrival order.
         self.relayed_pending: Dict[str, None] = {}
         # Oneways that failed during a head bounce, flushed on reconnect.
@@ -1168,7 +1167,7 @@ def worker_main(address, authkey: bytes, worker_id: str, session_name: str, env_
             # this executor still holds (queued or running).  The head
             # re-drives exactly the in-flight work NOT in this list — it
             # was lost with the dead conn (reconciliation handshake,
-            # executor leg; the shard fabric's conn-death recovery).
+            # executor leg).
             lambda c: c.send(
                 ("ready", worker_id, os.getpid(), node_id, peer_endpoint,
                  rt.actor_announcement(), _time.time(),

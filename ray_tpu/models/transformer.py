@@ -101,25 +101,6 @@ class TransformerConfig:
         base.update(kw)
         return TransformerConfig(**base)
 
-    @staticmethod
-    def llama_1b(**kw) -> "TransformerConfig":
-        base = dict(
-            vocab_size=32000, d_model=2048, n_layers=16, n_heads=16,
-            n_kv_heads=16, d_ff=5504, max_seq_len=2048,
-        )
-        base.update(kw)
-        return TransformerConfig(**base)
-
-    @staticmethod
-    def llama_7b(**kw) -> "TransformerConfig":
-        """The north-star 7B config (BASELINE.json)."""
-        base = dict(
-            vocab_size=32000, d_model=4096, n_layers=32, n_heads=32,
-            n_kv_heads=32, d_ff=11008, max_seq_len=4096,
-        )
-        base.update(kw)
-        return TransformerConfig(**base)
-
     def num_params(self) -> int:
         e = self.vocab_size * self.d_model
         attn = self.d_model * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)

@@ -36,25 +36,6 @@ def _init_maybe_attached(args):
     return get_worker_runtime()
 
 
-def _io_shard_rows(procs) -> dict:
-    """Head io-shard fabric as `status` rows: one entry per shard process
-    with its pushed conn-count gauge (io_shard.py metrics push)."""
-    rows = {}
-    for key, rec in (procs or {}).items():
-        if not str(rec.get("proc", "")).startswith("io_shard"):
-            continue
-        internal = rec.get("internal") or {}
-        rows[key] = {
-            "pid": rec.get("pid"),
-            "conns": int(internal.get("io_shard_conns", 0)),
-            "pending_handoff_sends": int(
-                internal.get("io_shard_pending_handoff_sends", 0)
-            ),
-            "age_s": rec.get("age_s"),
-        }
-    return rows
-
-
 def cmd_status(args) -> int:
     import ray_tpu
     from ray_tpu.util import state as state_api
@@ -85,7 +66,6 @@ def cmd_status(args) -> int:
             "demand": state_api.demand_summary(),
             "telemetry_processes": tele.get("processes", {}),
             "telemetry": tele.get("internal", {}),
-            "io_shards": _io_shard_rows(tele.get("processes")),
         }
     else:
         tele = state_api.telemetry_summary()
@@ -97,7 +77,6 @@ def cmd_status(args) -> int:
             "demand": state_api.demand_summary(),
             "metrics": state_api.cluster_metrics(),
             "telemetry_processes": tele.get("processes", {}),
-            "io_shards": _io_shard_rows(tele.get("processes")),
         }
     print(json.dumps(out, indent=1, default=str))
     return 0
@@ -422,19 +401,6 @@ def cmd_stop(args) -> int:
     return 0
 
 
-def cmd_bench(args) -> int:
-    import os
-    import subprocess
-
-    import ray_tpu
-
-    bench = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(ray_tpu.__file__))),
-        "bench.py",
-    )
-    return subprocess.call([sys.executable, bench])
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="ray_tpu", description=__doc__)
     sub = p.add_subparsers(dest="cmd", required=True)
@@ -536,9 +502,6 @@ def main(argv=None) -> int:
     lg.add_argument("--tail", type=int, default=0, help="last N lines only")
     lg.add_argument("--address", help="head.json path (attached mode)")
     lg.set_defaults(fn=cmd_logs)
-
-    be = sub.add_parser("bench", help="run the train benchmark (bench.py)")
-    be.set_defaults(fn=cmd_bench)
 
     sta = sub.add_parser("start", help="start a standalone head process")
     sta.add_argument("--head", action="store_true")

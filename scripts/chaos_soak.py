@@ -102,12 +102,6 @@ import ray_tpu  # noqa: E402
 #     sees a torn stream — EOF or a truncated batch decode_frames rejects
 #     whole, never a partial dispatch), and a small probabilistic delay
 #     stretches flush windows to keep batch/ordering races warm.
-#   * the head runs with RAY_TPU_HEAD_IO_SHARDS=2 (ISSUE 8): one io
-#     shard is crash-killed mid-forward at its t=12 (each incarnation —
-#     a respawned shard under a still-armed spec dies again), so the
-#     soak exercises BOTH fabric hazards: conns failing over to the
-#     surviving shard and the head's shard respawn path, all while the
-#     head itself bounces.  Zero lost results still required.
 #   * ISSUE 12 (RELAY_SPEC below, scoped to the relay node):
 #     transfer.chunk_relay crash-kills the relay daemon MID-RELAY of a
 #     live broadcast (serving chunks of a pull still in flight on its
@@ -123,7 +117,6 @@ DEFAULT_SPEC = (
     "wire.send:crash@proc=daemon:soak-d1,at=18,times=1;"
     "wire.send:crash@proc=actor:AnonSoak,at=29,times=1;"
     "wire.send:crash@proc=actor:Replica,at=29,times=1;"
-    "shard.forward:crash@proc=io_shard:1,at=12,times=1;"
     "gcs.journal_append:crash@proc=head,at=24,times=1;"
     "gcs.save:crash@proc=head,at=30,times=1"
 )
@@ -554,7 +547,6 @@ def run_soak(
             "RAY_TPU_TRACE",
             "RAY_TPU_FLIGHT_DIR",
             "RAY_TPU_METRICS_PUSH_MS",
-            "RAY_TPU_HEAD_IO_SHARDS",
             "RAY_TPU_PROF_HZ",
             "RAY_TPU_OBJECT_TRANSFER_CHUNK_BYTES",
             "RAY_TPU_RELAY_FANOUT",
@@ -571,11 +563,6 @@ def run_soak(
     # daemon nodes — the shared 1-vCPU box can't afford the node count a
     # bushier tree would need to exercise the relay path.
     os.environ.setdefault("RAY_TPU_RELAY_FANOUT", "1")
-    # ISSUE 8: the soak runs the SHARDED head fabric — every head
-    # incarnation fans its conns across 2 io shards, and the spec kills
-    # one shard mid-forward (its conns must fail over with zero lost
-    # results while head kills overlap).
-    os.environ.setdefault("RAY_TPU_HEAD_IO_SHARDS", "2")
     # FULL telemetry plane on across every process of the soak cluster
     # (ISSUE 6 acceptance: the soak passes with push + spans + flight
     # recorder enabled, and every fault-plane kill leaves a flight dump
@@ -586,8 +573,8 @@ def run_soak(
     os.environ["RAY_TPU_FLIGHT_DIR"] = flight_dir
     os.environ.setdefault("RAY_TPU_METRICS_PUSH_MS", "1000")
     # ISSUE 10: the sampling profiler runs HOT through the whole soak in
-    # every process (head, workers, daemon, io shards autostart via
-    # telemetry.install) — head/shard kills must not wedge it, and every
+    # every process (head, workers, daemon autostart via
+    # telemetry.install) — head kills must not wedge it, and every
     # crash dump carries the victim's last collapsed-stack snapshot.
     os.environ.setdefault("RAY_TPU_PROF_HZ", "25")
     watchdog_dir = os.path.join(workdir, "watchdog")
@@ -609,7 +596,7 @@ def run_soak(
         "seed": seed,
         "spec": spec,
         "duration_s": duration,
-        "kills": {"head": 0, "daemon": 0, "io_shard": 0},
+        "kills": {"head": 0, "daemon": 0},
         "lock_watchdog": {"enabled": watch_locks, "reports": []},
         "result": "FAIL",
     }
@@ -913,22 +900,6 @@ def run_soak(
         assert dumps, (
             "fault-plane kills fired but produced no flight-recorder dumps"
         )
-        # ISSUE 8 acceptance: the io-shard kill clause fired (its flight
-        # dump is attached), and the soak still drained with zero lost
-        # results — the shard's conns failed over and the head respawned
-        # the shard while the storm ran.
-        from ray_tpu._private import telemetry as _telemetry
-
-        shard_dumps = [
-            d
-            for d in _telemetry.collect_dumps(flight_dir)
-            if str(d.get("proc", "")).startswith("io_shard")
-        ]
-        report["kills"]["io_shard"] = len(shard_dumps)
-        assert shard_dumps, (
-            "shard.forward kill clause never fired — no io-shard flight "
-            "dump found (is the sharded fabric actually on?)"
-        )
         # ISSUE 12 acceptance: the broadcast workload ran through the
         # storm with every sum exact, AND the transfer.chunk_relay clause
         # provably crash-killed a daemon MID-RELAY of a live broadcast
@@ -936,6 +907,8 @@ def run_soak(
         # back to sealed sources / re-planned with zero lost results, and
         # the ledger's leak sweep (asserted above) covered the broadcast
         # objects too.
+        from ray_tpu._private import telemetry as _telemetry
+
         relay_kill_dumps = [
             d
             for d in _telemetry.collect_dumps(flight_dir)

@@ -2,8 +2,8 @@
 
 ray: release/serve_tests/workloads/serve_micro_benchmark.py — handle-path
 and HTTP-path throughput/latency on a trivial deployment (measures the
-runtime, not the model).  Writes one JSON line; CI/driver can redirect to
-BENCH_serve_r3.json.  Numbers are host-bound: record nproc with them.
+runtime, not the model).  Writes one JSON line.  Numbers are host-bound:
+record nproc with them.
 
 Run: python scripts/serve_bench.py [--requests 300] [--concurrency 8]
 """

@@ -262,8 +262,6 @@ def differential_codec_cases(rng: random.Random) -> List[tuple]:
         ("refs_push", {"o1": {"count": 1}}),
         ("prof_push", {"stack;frame": 7}),
         ("spans", [("submit", 1.0, 2.0, {"t": "1"})]),
-        ("shard_fwd", "conn-1", [b"b1", b"b2"]),
-        ("shard_send", "conn-1", b"payload"),
         ("reply", 42, True, {"r": [1, "x", (2.5, None)]}),
         ("reply", 43, False, "error text"),
         ("heartbeat",),

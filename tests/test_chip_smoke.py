@@ -15,8 +15,8 @@ sys.path.insert(0, REPO_ROOT)
 
 import chip_smoke  # noqa: E402
 
-# 128-aligned like the bench shape, so a TPU lowering of this very loop would
-# pick the kernel; lowered for CPU it must hold none.
+# 128-aligned like chip_smoke.BENCH_MODEL, so a TPU lowering of this very
+# loop would pick the kernel; lowered for CPU it must hold none.
 TINY = dict(
     vocab_size=256, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
     d_ff=256, max_seq_len=128, dtype="float32", remat=True,

@@ -356,7 +356,8 @@ def test_committed_catalog_matches_tree():
 # pass 4: hot-send
 
 
-def test_hot_send_flags_direct_conn_send_in_hot_modules(tmp_path):
+@pytest.mark.parametrize("rel", sorted(hot_send.HOT_MODULES))
+def test_hot_send_flags_direct_conn_send_in_hot_modules(tmp_path, rel):
     """A direct conn send added to a hot streaming module is a finding
     (until reviewed into the allowlist); the same code outside the hot
     module set is not."""
@@ -370,13 +371,11 @@ def test_hot_send_flags_direct_conn_send_in_hot_modules(tmp_path):
     """
     import textwrap
 
-    p = tmp_path / "peer.py"
+    p = tmp_path / os.path.basename(rel)
     p.write_text(textwrap.dedent(src))
-    found = hot_send.scan_file(str(p), "ray_tpu/_private/peer.py")
+    found = hot_send.scan_file(str(p), rel)
     assert len(found) == 1
-    assert found[0].key == (
-        "hot-send:ray_tpu/_private/peer.py:S.stream:self.conn.send"
-    )
+    assert found[0].key == f"hot-send:{rel}:S.stream:self.conn.send"
     assert hot_send.scan_file(str(p), "ray_tpu/rllib/policy_client.py") == []
 
 
@@ -712,76 +711,6 @@ def test_gcs_mutation_detects_direct_table_writes(tmp_path):
     assert tables == {
         "self.state.actors", "self.state.named_actors", "self.state.jobs"
     }
-
-
-def test_gcs_mutation_forward_only_flags_any_state_write(tmp_path):
-    """io_shard.py is FORWARDING ONLY: any write-shaped access on a
-    state/gcs-ish owner fails there — any table name (not just the
-    journaled set), attribute rebinding included — while reads and
-    non-state receivers stay clean."""
-    from ray_tpu._private.analysis import gcs_mutation
-
-    p = _write(
-        tmp_path,
-        "fix_shard.py",
-        """
-        class _ShardServer:
-            def bad_subscript(self, rt, oid):
-                rt.state.object_locations[oid] = set()  # seeded: any table
-
-            def bad_rebind(self, rt):
-                rt.state.actors = {}  # seeded: attribute write
-
-            def bad_pop(self, rt, k):
-                rt.gcs.kv.pop(k, None)  # seeded: mutating method
-
-            def bad_journaled(self, rt, k, v):
-                rt.state.jobs[k] = v  # seeded: fires in BOTH modes
-
-            def fine_reads(self, rt, aid):
-                return rt.state.actors.get(aid)
-
-            def fine_own_maps(self, conn_id):
-                self.owned[conn_id] = object()
-                self.pending_sends.pop(conn_id, None)
-        """,
-    )
-    found = gcs_mutation.scan_file(p, "ray_tpu/_private/io_shard.py")
-    assert len(found) == 4, [v.key for v in found]
-    assert all("FORWARDING ONLY" in v.message for v in found)
-    # The same file under a normal module path only flags journaled-table
-    # subscript writes (the forward-only strictness is the shard's alone).
-    relaxed = gcs_mutation.scan_file(p, "fix_shard.py")
-    assert len(relaxed) == 1, [v.key for v in relaxed]
-    assert "state.jobs" in relaxed[0].key
-
-
-def test_committed_io_shard_module_is_forward_only_clean():
-    """The real io_shard.py passes its own stricter rule (no state/gcs
-    writes at all) — the structural single-writer guarantee the shard
-    fabric's safety argument rests on."""
-    from ray_tpu._private.analysis import gcs_mutation
-
-    path = os.path.join(REPO, "ray_tpu", "_private", "io_shard.py")
-    assert gcs_mutation.scan_file(path, "ray_tpu/_private/io_shard.py") == []
-
-
-def test_hot_send_covers_io_shard_module(tmp_path):
-    """io_shard.py is a hot-send module: a new direct conn send there is
-    a lint finding until reviewed (the shard owns whole slices of the
-    cluster's conns — one silent unbatched send regresses them all)."""
-    from ray_tpu._private.analysis import hot_send
-
-    p = _write(
-        tmp_path,
-        "fix_shard_send.py",
-        """
-        def sneaky(conn, msg):
-            conn.send(msg)  # seeded violation
-        """,
-    )
-    assert len(hot_send.scan_file(p, "ray_tpu/_private/io_shard.py")) == 1
-    assert hot_send.scan_file(p, "ray_tpu/other.py") == []
 
 
 def test_journal_coverage_flags_unjournaled_mutator(tmp_path):

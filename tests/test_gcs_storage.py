@@ -362,6 +362,10 @@ def test_function_exports_survive_head_death_via_journal_only(tmp_path):
     size_after_first = rt._journal.size_bytes()
     rt.state.export_function("fn-under-test", b"the-blob")
     assert rt._journal.size_bytes() == size_after_first
+    # The kill lands after the group-commit linger (a kill inside it
+    # loses that window by contract; on a loaded box the flusher thread
+    # may not have run before rt2 replays).
+    rt._journal.flush()
     # Hard death: no shutdown, no final snapshot.
     rt._shutdown = True
     rt.listener.close()
@@ -395,6 +399,7 @@ def test_runtime_restores_anonymous_actor_from_journal_only(tmp_path):
         ActorInfo(actor_id="anon1", name=None, max_restarts=3, creation_spec=spec)
     )
     rt.state.set_actor_state("anon1", ALIVE, worker_id="w9", node_id="n1")
+    rt._journal.flush()  # past the linger window, as above
     # Hard death: no shutdown, no final snapshot — only the journal knows.
     rt._shutdown = True
     rt.listener.close()

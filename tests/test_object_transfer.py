@@ -483,7 +483,7 @@ def test_relay_death_falls_back_to_sealed_source(tmp_path, monkeypatch):
 
 
 def test_broadcast_relay_one_sealed_copy_per_node(ray_start_regular):
-    """The BENCH_objmem invariant extended to the pipelined path: a cold
+    """The one-copy-per-node invariant extended to the pipelined path: a cold
     N-node broadcast lands EXACTLY ONE sealed copy per receiving node —
     pipelining must not silently multiply copies or re-read the source.
     Counter-asserted via the head's ledger events (one transfer|relay

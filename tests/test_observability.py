@@ -190,8 +190,6 @@ def test_config_knob_table():
         config._reset_for_tests()
         config.set_system_config({"scheduler_spread_threshold": 0.25})
         assert config.get("scheduler_spread_threshold") == 0.25
-        with pytest.raises(ValueError, match="unknown config"):
-            config.set_system_config({"bogus": 1})
 
         # malformed env falls back to default
         config._reset_for_tests()
@@ -203,6 +201,22 @@ def test_config_knob_table():
         assert all("doc" in row for row in desc.values())
     finally:
         os.environ.pop("RAY_TPU_SCHEDULER_SPREAD_THRESHOLD", None)
+        config._reset_for_tests()
+
+
+@pytest.mark.parametrize(
+    "key", ["bogus", "head_io_shards", "io_shard_restart_s"]
+)
+def test_unknown_system_config_key_is_refused(key):
+    """A key that is not in the knob table is an error, never silently
+    ignored: a misspelt name, or one that was retired with its code."""
+    from ray_tpu._private import config
+
+    config._reset_for_tests()
+    try:
+        with pytest.raises(ValueError, match="unknown config"):
+            config.set_system_config({key: 1})
+    finally:
         config._reset_for_tests()
 
 

@@ -336,15 +336,19 @@ def test_serve_request_renders_single_span_tree(monkeypatch):
 
         from ray_tpu.util.state import list_spans
 
+        # The three spans close in three processes (replica, router's
+        # host, proxy), each shipped on its own telemetry tick: wait for
+        # all of them, not for the first to land.
+        wanted = {"serve::request", "serve::route", "serve::replica"}
         deadline = time.time() + 15
-        tree = []
+        tree, names = [], set()
         while time.time() < deadline:
             spans = list_spans(limit=5000)
             tree = [s for s in spans if s["trace_id"] == rid]
-            if any(s["name"] == "serve::replica" for s in tree):
+            names = {s["name"] for s in tree}
+            if wanted <= names:
                 break
             time.sleep(0.3)
-        names = {s["name"] for s in tree}
         assert "serve::request" in names, names
         assert "serve::route" in names, names
         assert "serve::replica" in names, names

@@ -516,6 +516,18 @@ def test_restartable_replicas_keep_direct_path(serve_instance):
 
     h = serve.run(Durable.bind())
     assert ray_tpu.get(h.remote(0), timeout=30) == 1
+    # serve.run returns once both replica HANDLES exist; the second actor
+    # may still be starting, and a call to a replica that is not ALIVE yet
+    # is relayed by design ("pending" from resolve_actor).  This test is
+    # about steady state, so wait for it.
+    from ray_tpu.util import state as state_api
+
+    deadline = time.monotonic() + 30
+    while any(
+        a["state"] not in ("ALIVE", "DEAD") for a in state_api.list_actors()
+    ):
+        assert time.monotonic() < deadline, state_api.list_actors()
+        time.sleep(0.05)
 
     @ray_tpu.remote
     def drive(handle, n):

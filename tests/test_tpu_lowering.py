@@ -21,7 +21,7 @@ from ray_tpu.models import LMTrainContext, TransformerConfig
 from ray_tpu.parallel import MeshSpec, build_mesh
 
 # 128-aligned sequence and head_dim: the shapes the auto dispatch gives to
-# the kernel.  remat on, like the bench configuration.
+# the kernel.  remat on, like every cell's job (benchmarks/configs/*.json).
 CFG = TransformerConfig.tiny(
     n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, max_seq_len=128,
     remat=True, remat_policy="qkv_attn",

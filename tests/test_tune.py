@@ -42,9 +42,14 @@ def test_grid_and_random_search(tune_cluster):
 
 def test_asha_stops_bad_trials(tune_cluster):
     def trainable(config):
+        import time
+
         for step in range(1, 21):
             # lr quality is baked into the score slope
             tune.report({"score": config["lr"] * step, "training_iteration": step})
+            # Reports drained after the trainable returned never reach the
+            # scheduler: a step must outlast a poll round to be stoppable.
+            time.sleep(0.05)
 
     # Serial execution, best-first order: ASHA's rungs retain completed
     # trials' scores, so the later bad trials deterministically fall below
@@ -73,12 +78,19 @@ def test_asha_stops_bad_trials(tune_cluster):
 
 def test_stop_criteria_and_checkpoint(tune_cluster):
     def trainable(config):
+        import time
+
         ckpt = tune.get_checkpoint()
         start = ckpt.to_dict()["step"] + 1 if ckpt else 0
         for step in range(start, 100):
             tune.report(
                 {"step": step}, checkpoint=Checkpoint.from_dict({"step": step})
             )
+            # A step takes time: without a pause all 100 reports can land
+            # between one poll and the completion check of the same round,
+            # and reports drained after the trainable returned are recorded
+            # without consulting the stop criterion.
+            time.sleep(0.05)
 
     tuner = tune.Tuner(
         trainable,

@@ -210,11 +210,15 @@ def test_send_fault_drop_hits_individual_messages(pipe_pair):
     assert receiver.pending_frames() == 0
 
 
-def test_flush_fault_drop_loses_whole_batch(pipe_pair):
+def test_flush_fault_drop_loses_whole_batch(pipe_pair, monkeypatch):
     """wire.flush is the physical-write hazard: a drop there loses the
     whole coalesced run (one physical message now), and the sender moves
     on cleanly."""
     sender, receiver = pipe_pair
+    # Explicit flushes only: if this thread loses the CPU for longer than
+    # the 200 us linger between the two sends, the background sweep
+    # flushes "lost-1" alone, takes the nth=1 drop, and "lost-2" arrives.
+    monkeypatch.setattr(wire, "_note_dirty", lambda bc: None)
     faults.configure("wire.flush:drop@nth=1")
     try:
         sender.send(("refop", "add", "lost-1"))

@@ -11,6 +11,7 @@ that ever produced a non-ProtocolError outcome.  Entries never leave:
 each is a decoder bug class that shipped once.
 """
 
+import marshal
 import os
 import random
 import sys
@@ -34,11 +35,15 @@ _HDR = bytes.fromhex("52540300")
 # exception out of wire.decode_frames.
 REGRESSION_CORPUS = [
     # marshal allocation bomb (fuzz seed 3, frame 3760): an 11-byte native
-    # body — kind 13 shard_send, marshal v4, then tuple opcode '(' with a
-    # declared count of 0x20100000 — made marshal.loads zero out a ~4 GB
-    # tuple before noticing the stream was empty.  58 s of kernel time on
-    # the decode path from 11 bytes.
+    # body — kind 13 (since retired), marshal v4, then tuple opcode '('
+    # with a declared count of 0x20100000 — made marshal.loads zero out a
+    # ~4 GB tuple before noticing the stream was empty.  58 s of kernel
+    # time on the decode path from 11 bytes.  The second entry is the same
+    # bomb under a kind that still decodes (14, reply), so the per-header
+    # length check keeps its case.
     ("marshal-tuple-bomb", bytes.fromhex("525403000d042800100020")),
+    ("marshal-tuple-bomb-live-kind",
+     bytes.fromhex("525403000e042800100020")),
     # pickle BYTEARRAY8 bomb (fuzz seed 3, byte-flip class): declares a
     # 2^40-byte bytearray, which pickle.loads allocates AND zero-fills
     # before checking the buffer holds it.
@@ -56,9 +61,17 @@ REGRESSION_CORPUS = [
     # individually, but 60 nested headers sum to gigabytes — caught by
     # the cumulative allocation budget, not the per-header check.
     ("marshal-nested-bomb",
-     _HDR + bytes([wire_native.KIND_IDS["shard_send"],
+     _HDR + bytes([wire_native.KIND_IDS["reply"],
                    wire_native.MARSHAL_VERSION])
      + (b"(" + (500).to_bytes(4, "little")) * 60 + b"N" * 500),
+    # native ids 12 and 13 are retired: a well-formed body under either
+    # is an unknown kind, refused like any other.
+    ("retired-native-kind-12",
+     _HDR + bytes([12, wire_native.MARSHAL_VERSION])
+     + marshal.dumps(("conn-1", [b"b1", b"b2"]), 2)),
+    ("retired-native-kind-13",
+     _HDR + bytes([13, wire_native.MARSHAL_VERSION])
+     + marshal.dumps(("conn-1", b"payload"), 2)),
     # corrupt pickled bodies that once leaked UnpicklingError / EOFError /
     # AttributeError out of the recv loop instead of ProtocolError.
     ("pickle-garbage", _HDR + b"\x80\x05garbage"),
