@@ -12,12 +12,28 @@ The layer, on T tokens with K choices each out of E experts:
   HIGHEST: on a TPU a float32 matmul is otherwise bf16 passes, and routing is
   discrete), `lax.top_k`, and the statistics the router losses are made of.
 - `moe/dispatch`: a stable sort of the T*K assignments by expert, the E group
-  sizes, a gather of the token rows into expert order.
+  sizes, a gather of the token rows into expert order, and the T*K gate values
+  into the same order (by a sort: `_permuted`).
 - `moe/experts`: gate and up as grouped matmuls over the E ragged groups,
-  `silu(gate) * up`, down as a third (`ops/grouped_matmul.py`: Pallas
-  kernels when lowered for TPU, an XLA form of the same schedule elsewhere).
-- `moe/combine`: rows back into token order, times the gate values, summed
-  over each token's K rows.
+  `silu(gate) * up` times the row's gate value, down as a third
+  (`ops/grouped_matmul.py`: Pallas kernels when lowered for TPU, an XLA form
+  of the same schedule elsewhere).
+- `moe/combine`: rows back into token order, summed over each token's K rows.
+
+The gate value of an assignment scales its row where the row is `d_ff` wide,
+BEFORE the down projection, and not the `d_model`-wide row that comes out of
+it as the published order has it: a linear map commutes with a scalar per
+row, so the layer is the same function (in bf16 one rounding moves from
+after `w_down` to before it).  What differs is what the backward has to be
+given.  The gradient of a gate value is then a row sum over `d_ff` inside the
+`silu` backward pass, of operands the `w_down` backward needs anyway; applied
+after the un-permute it is `<dy, out>`, which needs the down projection's
+output in token order, and a layer checkpointed without its FFN (`qkv_attn`)
+would run the third grouped matmul and the un-permute gather again for a
+[T, K] gradient.  As it is the recompute ends at `silu(gate) * up`, and
+combine is linear with dispatch as its transpose: its backward is dispatch's
+gather, `dy[order // K]` (`tests/test_moe_model.py` counts the recompute;
+PERF.md section 6, PR 29).
 
 No capacity: every assignment is computed whatever the imbalance.  Both row
 movements are gathers in both directions (the backward of a permutation is
@@ -48,6 +64,7 @@ The training objective adds them times `router_aux_loss_coef` and
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -89,42 +106,67 @@ def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
 
 
 # -- rows into expert order and back: gathers in both directions -------------------
+#
+# `order` lists the T*K assignments (indices into the flattened [T, K]) by
+# expert, `inverse` is its inverse permutation.  The two row movements are
+# each other's transpose, and say so: autodiff's own transpose of a gather is
+# a scatter-add, and of the sum over K a [T*K, D] broadcast written out
+# before it is gathered.
 
 
-@jax.custom_vjp
-def _to_expert_order(tokens, order, inverse):
-    """tokens [T, D] -> rows [T*K, D]: row i is the token of the i-th
-    assignment in expert order (`order` indexes the flattened [T, K])."""
-    return tokens[order // (order.shape[0] // tokens.shape[0])]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_expert_order(tokens, order, inverse, k):
+    """tokens [T, D] -> rows [T*k, D]: row i is the token of the i-th
+    assignment in expert order."""
+    return tokens[order // k]
 
 
-def _to_expert_order_fwd(tokens, order, inverse):
-    return _to_expert_order(tokens, order, inverse), (inverse, tokens.shape[0])
+def _to_expert_order_fwd(tokens, order, inverse, k):
+    return _to_expert_order(tokens, order, inverse, k), (order, inverse)
 
 
-def _to_expert_order_bwd(res, g):
-    inverse, n_tokens = res
-    return g[inverse].reshape(n_tokens, -1, g.shape[-1]).sum(axis=1), None, None
+def _to_expert_order_bwd(k, res, g):
+    return _to_token_order(g, *res, k), None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_token_order(rows, order, inverse, k):
+    """rows [T*k, D] in expert order -> [T, D]: each token's k rows, added."""
+    return rows[inverse].reshape(-1, k, rows.shape[-1]).sum(axis=1)
+
+
+def _to_token_order_fwd(rows, order, inverse, k):
+    return _to_token_order(rows, order, inverse, k), (order, inverse)
+
+
+def _to_token_order_bwd(k, res, g):
+    return _to_expert_order(g, *res, k), None, None
 
 
 _to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+_to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
 
 
 @jax.custom_vjp
-def _to_token_order(rows, order, inverse):
-    """rows [T*K, D] in expert order -> [T*K, D] in (token, choice) order."""
-    return rows[inverse]
+def _permuted(x, dest, source):
+    """x [n] -> y [n] with y[dest[j]] = x[j], which is x[source] (`dest` and
+    `source` are inverse permutations), as a sort of x by the key `dest`: on
+    the v5e a sort of 65,536 pairs takes 0.07 ms where a gather of single
+    elements takes 0.48 and a scatter 0.31 (same section).  Its transpose is
+    the same sort by `source`."""
+    return jax.lax.sort((dest, x), num_keys=1)[1]
 
 
-def _to_token_order_fwd(rows, order, inverse):
-    return rows[inverse], order
+def _permuted_fwd(x, dest, source):
+    return _permuted(x, dest, source), (dest, source)
 
 
-def _to_token_order_bwd(order, g):
-    return g[order], None, None
+def _permuted_bwd(res, g):
+    dest, source = res
+    return _permuted(g, source, dest), None, None
 
 
-_to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
+_permuted.defvjp(_permuted_fwd, _permuted_bwd)
 
 
 # -- the layer ---------------------------------------------------------------------
@@ -154,33 +196,37 @@ def _experts(tokens, expert_idx, gates, w_gate, w_up, w_down, n_experts, first_e
     """Dispatch, grouped matmuls and combine for the experts `first_expert ..
     first_expert + w_gate.shape[0]` of `n_experts` (None: all of them, on one
     device).  tokens [T, D], expert_idx / gates [T, K]; returns those
-    experts' part of the output, [T, D].  Assignments to other experts sort
-    behind the last group, where a grouped matmul writes nothing defined:
-    those rows are zeroed going in (which zeroes their gradient coming back)
-    and coming out."""
-    T, D = tokens.shape
+    experts' part of the output, [T, D].  The gate values go to their rows in
+    expert order and multiply them in the pass that makes `silu(gate) * up`,
+    so nothing behind `w_down` is a residual of the backward (module
+    docstring).  Assignments to other experts sort behind the last group,
+    where a grouped matmul writes nothing defined: those rows and their gate
+    values are zeroed going in (which zeroes their gradients coming back),
+    the rows also coming out."""
+    k = expert_idx.shape[1]
     n_local = w_gate.shape[0]
     with jax.named_scope("moe/dispatch"):
         flat = expert_idx.reshape(-1)
         if first_expert is not None:
             flat = (flat - first_expert) % n_experts  # this rank's experts first
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=jnp.int32))
+        # (a sort too: `.at[order].set(iota)` is a scatter, 0.3 ms a call on the v5e against 0.07)
+        inverse = jnp.argsort(order, stable=False).astype(jnp.int32)
         # (a one-hot sum: `bincount` is a scatter-add, 0.6 ms a call on the v5e)
         group_sizes = jnp.sum(jax.nn.one_hot(flat, n_local, dtype=jnp.int32), axis=0)
-        rows = _to_expert_order(tokens, order, inverse)
+        rows = _to_expert_order(tokens, order, inverse, k)
+        g_row = _permuted(gates.reshape(-1), inverse, order).astype(rows.dtype)[:, None]
         if first_expert is not None:
             mine = (jnp.arange(rows.shape[0], dtype=jnp.int32) < jnp.sum(group_sizes))[:, None]
-            rows = jnp.where(mine, rows, 0)
+            rows, g_row = jnp.where(mine, rows, 0), jnp.where(mine, g_row, 0)
     with jax.named_scope("moe/experts"):
         gate = grouped_matmul(rows, w_gate, group_sizes)
         up = grouped_matmul(rows, w_up, group_sizes)
-        out = grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
+        out = grouped_matmul(jax.nn.silu(gate) * up * g_row, w_down, group_sizes)
     with jax.named_scope("moe/combine"):
         if first_expert is not None:
             out = jnp.where(mine, out, 0)
-        out = _to_token_order(out, order, inverse).reshape(T, -1, D)
-        return jnp.sum(out * gates[..., None].astype(out.dtype), axis=1)
+        return _to_token_order(out, order, inverse, k)
 
 
 def moe_ffn(

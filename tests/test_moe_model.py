@@ -1,18 +1,22 @@
 """The sparse-expert decoder (OLMoE's block) against its plain float32
 reference (`benchmarks/lib/reference_moe.py`), at tiny widths on the CPU:
 logits, the training objective, every gradient leaf, each loss term, dropless
-under total imbalance, no gate renormalisation, the bf16 tolerance shown to
-bite, expert parallelism on a CPU mesh, optimizer-state shardings by path,
-the grouped-matmul kernels, and the dense model's program left as it was.
+under total imbalance (the router's gradient too), no gate renormalisation,
+the bf16 tolerance shown to bite, what the checkpointed layer's backward
+recomputes of the expert block, expert parallelism on a CPU mesh,
+optimizer-state shardings by path, the grouped-matmul kernels, and the dense
+model's program left as it was.
 
 Weights are seeded through the program's own `init_state`; the norm scales,
 which start at one, are then drawn at random so a scale that is never applied
 cannot pass.
 """
 
+import collections
 import dataclasses
 import math
 import os
+import re
 import sys
 
 import jax
@@ -24,7 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)  # `benchmarks.lib` resolves from this checkout
 
-from benchmarks.lib import reference_moe  # noqa: E402
+from benchmarks.lib import reference_moe, trace_moe, trace_scopes  # noqa: E402
 from ray_tpu.models import LMTrainContext, TransformerConfig  # noqa: E402
 from ray_tpu.models import moe  # noqa: E402
 from ray_tpu.ops import grouped_matmul as gmm  # noqa: E402
@@ -169,10 +173,9 @@ def test_train_step_reports_the_terms_and_differentiates_the_objective(tiny):
 # -- dropless, and the gates as published ---------------------------------------------------
 
 
-def test_dropless_under_total_imbalance_equals_the_reference():
-    """A router biased so EVERY token of every layer picks the same K
-    experts: nothing is dropped, the logits still equal the reference."""
-    cfg = TransformerConfig(**BASE)
+def totally_imbalanced(cfg):
+    """(ctx, params, batch, reference logits) with a router biased so EVERY
+    token of every layer picks the same K experts, as the reference routes."""
     ctx = one_device_ctx(cfg)
     params = seeded_params(ctx)
     # one hidden coordinate large and positive for every token, and a router
@@ -185,8 +188,28 @@ def test_dropless_under_total_imbalance_equals_the_reference():
     want = reference_moe.logits(reference_config(cfg), params, batch["tokens"], last=32, record=chosen)
     for layer in chosen:
         assert set(np.asarray(layer).reshape(-1).tolist()) == set(range(cfg.experts_per_token))
+    return ctx, params, batch, want
+
+
+def test_dropless_under_total_imbalance_equals_the_reference():
+    """Nothing is dropped: the logits still equal the reference."""
+    ctx, params, batch, want = totally_imbalanced(TransformerConfig(**BASE))
     got = ctx.apply(params, batch["tokens"])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True], ids=["as-published", "renormalised"])
+def test_router_gradient_under_total_imbalance_equals_the_reference(norm_topk_prob):
+    """The gate values reach the output through the rows that enter `w_down`,
+    in expert order: their gradient comes back through the inverse
+    permutation.  Held where two groups hold every row and the other six are empty."""
+    cfg = TransformerConfig(**dict(BASE, norm_topk_prob=norm_topk_prob))
+    ctx, params, batch, _ = totally_imbalanced(cfg)
+    got = jax.jit(jax.grad(lambda p: ctx._loss(p, batch)[0]))(params)["layers"]["mlp"]["router"]
+    want = np.asarray(jax.grad(lambda p: reference_moe.objective(
+        reference_config(cfg), p, batch["tokens"], batch["targets"])[0])(params)["layers"]["mlp"]["router"])
+    assert np.abs(want).max() > 0
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3, atol=2e-5 * np.abs(want).max())
 
 
 def test_dropless_layer_has_no_zero_rows_when_one_expert_takes_every_token():
@@ -250,6 +273,53 @@ def test_a_program_that_routes_to_seven_experts_is_outside_the_tolerance(bf16_ca
     assert err > reference_moe.tolerance(cfg.n_layers), err
 
 
+# -- what the checkpointed layer's backward asks the expert block for again -------------------
+
+
+def test_the_recompute_holds_two_grouped_products_and_no_un_permute():
+    """The gate value scales the rows that ENTER `w_down`, so no residual of
+    the layer's backward lies behind the down projection: under `qkv_attn`
+    (which saves nothing of the FFN) the recompute runs `gate` and `up` and
+    stops, where the forward runs three grouped products and the un-permute
+    gather.  Counted in the compiled loss-and-gradient (the CPU form: one
+    `dot` per grouped product), by scope and direction from `op_name`; a
+    gate multiply after the un-permute gives 3 and 1."""
+    cfg = TransformerConfig(**dict(BASE, remat=True, remat_policy="qkv_attn"))
+    ctx = one_device_ctx(cfg)
+    params = jax.eval_shape(lambda: ctx.init_state(0)["params"])
+    toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    text = jax.jit(jax.value_and_grad(ctx._loss, has_aux=True)).lower(
+        params, {"tokens": toks, "targets": toks}).compile().as_text()
+    ops = collections.Counter()  # by the benchmark's own reading of an `op_name` (PERF.md section 3)
+    for opcode, path in re.findall(r' (dot|gather)\(.*op_name="([^"]*)"', text):
+        ops[trace_moe.classify(path), trace_scopes.classify(path)[1], opcode] += 1
+    assert ops["moe/experts", "fwd", "dot"] == 3 and ops["moe/combine", "fwd", "gather"] == 1  # the counter sees them
+    assert ops["moe/experts", "recompute", "dot"] == 2
+    assert ops["moe/combine", "recompute", "gather"] == 0
+
+
+MOVEMENTS = {  # the module's form, the same written as plain indexing, the operand's shape
+    "to_expert_order": (lambda x, o, i: moe._to_expert_order(x, o, i, 4), lambda x, o, i: x[o // 4], (16, 8)),
+    "to_token_order": (lambda x, o, i: moe._to_token_order(x, o, i, 4),
+                       lambda x, o, i: x[i].reshape(16, 4, 8).sum(axis=1), (64, 8)),
+    "permuted": (lambda x, o, i: moe._permuted(x, i, o), lambda x, o, i: x[o], (64,)),
+}
+
+
+@pytest.mark.parametrize("name", MOVEMENTS)
+def test_each_movement_and_its_declared_transpose_equal_plain_indexing(name):
+    """Rows to expert order, rows back (summed over K) and the gate values'
+    sort: value and gradient are those of the gather each stands for."""
+    ours, plain, shape = MOVEMENTS[name]
+    rng = np.random.default_rng(0)
+    order = jnp.asarray(rng.permutation(64), jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal(plain(x, order, inverse).shape), jnp.float32)
+    for f in (lambda x, g: g(x, order, inverse), lambda x, g: jax.grad(lambda v: jnp.sum(g(v, order, inverse) * ct))(x)):
+        np.testing.assert_allclose(np.asarray(f(x, ours)), np.asarray(f(x, plain)), rtol=1e-6, atol=1e-6)
+
+
 # -- across devices -----------------------------------------------------------------------
 
 
@@ -266,6 +336,50 @@ def test_ep_train_step_equals_the_single_device_step(tiny, ep_ctx):
     _, got = ep_ctx.train_step(_state_with(ep_ctx, tiny["params"]), batch)
     for key in ("loss", "ce_loss", "moe_lb_loss", "moe_z_loss", "moe_load_max_over_mean", "grad_norm"):
         np.testing.assert_allclose(float(got[key]), float(want[key]), rtol=2e-4, err_msg=key)
+
+
+def test_rows_behind_the_last_group_reach_no_output_and_no_gradient(monkeypatch):
+    """A rank of the `expert` axis computes its own experts' groups, and a
+    grouped matmul defines nothing behind the last of them (the XLA form
+    happens to write zeros there, a kernel need not).  With a grouped matmul
+    that writes a large number there, in its output and in the gradient of
+    its rows, the rank's output and every gradient, the gate values' too,
+    are what they are with zeros."""
+    cfg = TransformerConfig(**BASE)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    weights = moe.init_moe_params(cfg, keys[0])
+    tokens = jax.random.normal(keys[1], (64, cfg.d_model))
+    idx, gates, _ = moe._route(weights, tokens, cfg)
+    first, n_local = 2, 2  # the second of four ranks
+    local = [weights[k][first:first + n_local] for k in ("w_gate", "w_up", "w_down")]
+    ct = jax.random.normal(keys[2], tokens.shape)
+
+    def with_behind(fill):
+        def behind(x, sizes):
+            return jnp.where((jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None], x, fill)
+
+        @jax.custom_vjp
+        def grouped(lhs, rhs, sizes):
+            return behind(gmm.grouped_matmul_xla(lhs, rhs, sizes), sizes)
+
+        def fwd(lhs, rhs, sizes):
+            out, pullback = jax.vjp(lambda l, r: gmm.grouped_matmul_xla(l, r, sizes), lhs, rhs)
+            return behind(out, sizes), (pullback, sizes)
+
+        def bwd(res, g):
+            d_lhs, d_rhs = res[0](g)
+            return behind(d_lhs, res[1]), d_rhs, None
+
+        grouped.defvjp(fwd, bwd)
+        monkeypatch.setattr(moe, "grouped_matmul", grouped)
+        return jax.value_and_grad(
+            lambda t, g, w: jnp.sum(moe._experts(t, idx, g, *w, cfg.n_experts, first) * ct), argnums=(0, 1, 2),
+        )(tokens, gates, local)
+
+    want, got = with_behind(0.0), with_behind(1e3)
+    assert float(jnp.abs(want[1][1]).max()) > 0  # the gate values of this rank's assignments have a gradient
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
 def test_every_moment_leaf_has_its_parameters_sharding_under_ep(ep_ctx):

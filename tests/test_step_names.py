@@ -103,6 +103,19 @@ def test_lowered_expert_step_names_the_grouped_matmul_kernels(moe_lowered_for_tp
     assert any(f"/{kernel}/" in line and "pallas_call" in line for line in moe_lowered_for_tpu.splitlines())
 
 
+def test_lowered_expert_step_recomputes_two_of_the_three_grouped_matmuls(moe_lowered_for_tpu):
+    """Per layer `moe_gmm` runs 3 times forward, 3 times for the rows'
+    gradients and, under `qkv_attn`, twice in the recompute: gate and up.
+    The gate value scales the rows that enter `w_down` (`models/moe.py`), so
+    no residual needs the down projection again (9 with the gate multiply
+    behind it).  With 3 `moe_tgmm` and the 3 flash kernels: the 14
+    `tpu_custom_calls` of `olmoe-1chip.seq4k`'s HLO facts (PERF.md section 6, PR 29)."""
+    kernels = [line for line in moe_lowered_for_tpu.splitlines() if "@tpu_custom_call" in line]
+    assert sum('kernel_name = "moe_gmm"' in line for line in kernels) == 8
+    assert sum('kernel_name = "moe_tgmm"' in line for line in kernels) == 3
+    assert len(kernels) == 14
+
+
 def test_lowered_expert_step_keeps_the_flash_kernels_and_the_loss_scope(moe_lowered_for_tpu):
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert f'kernel_name = "{name}"' in moe_lowered_for_tpu
