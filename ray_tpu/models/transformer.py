@@ -1,4 +1,12 @@
-"""Flagship model: Llama-family decoder-only transformer, TPU-first.
+"""Flagship model: a pre-norm decoder-only transformer, TPU-first.
+
+What one `TransformerConfig` expresses: a stack of pre-norm RMSNorm layers,
+each a MIXER and an FFN around a residual stream.  The mixer is causal
+softmax attention (GQA, rotary embedding or none, optional QK-norm, the
+published softmax scale) or a Mamba-2 selective state-space layer
+(`layer_types`); the FFN is a dense SwiGLU or a dropless top-k mixture of
+SwiGLU experts (`n_experts`).  Mistral, InternLM2, OLMoE and the Granite 4.0-H
+hybrids run through it at their published widths (benchmarks/configs/).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -10,20 +18,45 @@ drive.  Design:
 - Every parameter leaf has a *logical axes* annotation (`param_axes`), mapped
   to mesh axes by ray_tpu.parallel.sharding rules — one model, every
   parallelism strategy (DP/FSDP/TP/SP via rules, not rewrites).
-- Layers are stacked on a leading `layers` axis and run under `lax.scan`
-  (one compiled layer body, O(1) compile time in depth) with optional
-  `jax.checkpoint` rematerialization for HBM.
+- Layers are stacked per KIND on a leading `layers` axis (`params["layers"]`
+  the attention layers, `params["mamba_layers"]` the Mamba-2 ones) and the
+  stack runs as ONE `lax.scan` per maximal run of one kind (one compiled body
+  per kind, O(1) compile time in depth), with optional `jax.checkpoint`
+  rematerialization for HBM.  A homogeneous model is the one-run case.
 - Attention dispatches to the pallas flash kernel when lowered for TPU
   (under shard_map when there is a mesh), the XLA forms otherwise
   (ray_tpu.ops.attention), or ring attention when the mesh has a nontrivial
   `seq` axis.
+
+The hybrid (Granite 4.0-H, `modeling_granitemoehybrid.py`; Mamba-2 / SSD,
+arXiv:2405.21060), with x [B, S, d], every RMSNorm with a learned scale and
+`norm_eps`, no bias anywhere but the convolution's:
+
+- model: `h0 = embed[tokens] * embedding_multiplier`; the layers;
+  `logits = (RMSNorm(h) @ head) / logits_scaling`.
+- every layer: `h = h + residual_multiplier * mixer(RMSNorm_1(h))`, then
+  `h = h + residual_multiplier * SwiGLU(RMSNorm_2(h))`.
+- attention layer: q/k/v projections, rotary embedding only when
+  `rope_theta` is set, causal softmax of `q k^T * attention_scale`
+  (`head_dim ** -0.5` when None), output projection.
+- Mamba-2 layer, `d_inner = ssm_heads * ssm_head_dim`, state N = `ssm_state`,
+  one group: `in_proj: d -> [z: d_inner | xBC: d_inner + 2N | dt: ssm_heads]`;
+  `xBC = silu(causal_depthwise_conv1d(xBC, width ssm_conv, with bias))`,
+  split into x [S, heads, head_dim], B [S, N], C [S, N];
+  `dt = softplus(dt + dt_bias)` per head; `A = -exp(A_log)` per head (a
+  scalar).  Per head, with state H_t in R^{head_dim x N}:
+  `H_t = exp(dt_t A) H_{t-1} + dt_t x_t (outer) B_t`, `y_t = H_t C_t + D x_t`
+  (`ops/ssm.py`, in its chunked form).  Then
+  `y = RMSNorm(y * silu(z))` over all d_inner channels and
+  `out_proj: d_inner -> d`.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+import math
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +64,12 @@ import jax.numpy as jnp
 from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, dot_product_attention
 from ray_tpu.ops.rotary import apply_rope
+from ray_tpu.ops.ssm import causal_conv1d, ssd_chunked
 from ray_tpu.parallel.sharding import Rules, with_logical_constraint
+
+
+# The kinds of layer, and the subtree of the parameters that stacks each.
+LAYER_KINDS = {"attention": "layers", "mamba": "mamba_layers"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,7 +81,7 @@ class TransformerConfig:
     n_kv_heads: int = 32
     d_ff: int = 11008
     max_seq_len: int = 4096
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0  # None = no rotary embedding ("nope")
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -54,7 +92,10 @@ class TransformerConfig:
     # output and the flash kernel's log-sum-exp (f32 [B, H, S]), so the
     # kernel's forward runs once per layer; "qkv_attn" = additionally save
     # post-rope q/k/v (skips qkv matmul + rope recompute).  More saved =
-    # more HBM.  XLA's own rematerialization may still duplicate work when
+    # more HBM.  A Mamba-2 layer names nothing: under every policy it keeps
+    # its input and recomputes the rest (saving z, the convolved x|B|C and dt
+    # as well costs 1.25 GiB at granite-h-micro's cell, which pushes XLA into
+    # duplicating the lm_head's forward: PERF.md section 6, PR 30).  XLA's own rematerialization may still duplicate work when
     # the step compiles over libtpu's limit; `_dense_ffn`'s tie is why the
     # dense FFN's matmuls are no longer among it.
     remat_policy: Optional[str] = None
@@ -78,8 +119,39 @@ class TransformerConfig:
     # RMSNorm with a learned scale over the whole projected q and k, before
     # RoPE (OLMoE, OLMo 2).
     qk_norm: bool = False
+    # The mixer of each layer, "attention" or "mamba", one entry per layer;
+    # None = attention everywhere.  The Mamba-2 sizes are read only when some
+    # layer is "mamba": heads x head size = the mixer's inner width, the
+    # state size N per head, the width of the causal depthwise convolution.
+    layer_types: Optional[Tuple[str, ...]] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    # Published multipliers (Granite's muP form), 1.0 each = absent: on the
+    # embeddings, on each block's output before it joins the residual
+    # stream, and a divisor of the logits.  `attention_scale` multiplies
+    # q k^T before the softmax; None = head_dim ** -0.5.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_scale: Optional[float] = None
 
     def __post_init__(self):
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            unknown = set(self.layer_types) - set(LAYER_KINDS)
+            if unknown or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types needs n_layers={self.n_layers} entries out of {LAYER_KINDS}, "
+                    f"got {len(self.layer_types)} with {sorted(unknown)} unknown"
+                )
+            if self.n_experts is not None:
+                raise ValueError("a stack with layer_types runs a dense FFN only (no n_experts)")
+            if "mamba" in self.layer_types and not (
+                self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
+            ):
+                raise ValueError("a mamba layer needs ssm_heads, ssm_head_dim and ssm_state")
         if self.n_experts is not None and not 0 < self.experts_per_token <= self.n_experts:
             raise ValueError(
                 f"n_experts={self.n_experts} needs 0 < experts_per_token <= n_experts, "
@@ -89,6 +161,23 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def layer_runs(self) -> Tuple[Tuple[str, int, int], ...]:
+        """The stack as maximal runs of one kind: (kind, first, count), where
+        `first` counts layers of that kind, i.e. indexes the kind's own
+        parameter stack.  A homogeneous model is one run."""
+        kinds = self.layer_types or ("attention",) * self.n_layers
+        runs, seen = [], dict.fromkeys(LAYER_KINDS, 0)
+        for kind in kinds:
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, seen[kind], 1])
+            seen[kind] += 1
+        return tuple(tuple(r) for r in runs)
+
+    def n_layers_of(self, kind: str) -> int:
+        return sum(count for k, _, count in self.layer_runs() if k == kind)
 
     # -- presets ---------------------------------------------------------
     @staticmethod
@@ -109,14 +198,29 @@ class TransformerConfig:
             mlp = self.n_experts * mlp + self.d_model * self.n_experts  # + router
         norms = 2 * self.d_model
         if self.qk_norm:
-            norms += self.head_dim * (self.n_heads + self.n_kv_heads)
+            attn += self.head_dim * (self.n_heads + self.n_kv_heads)
+        inner, conv = self.ssm_heads * self.ssm_head_dim, self._ssm_conv_channels
+        ssm = (self.d_model * (2 * inner + 2 * self.ssm_state + self.ssm_heads)  # in_proj
+               + conv * (self.ssm_conv + 1) + 3 * self.ssm_heads + inner  # conv, dt_bias/A_log/D, norm
+               + inner * self.d_model)  # out_proj
+        mixers = self.n_layers_of("attention") * attn + self.n_layers_of("mamba") * ssm
         out = 0 if self.tie_embeddings else self.vocab_size * self.d_model
-        return e + self.n_layers * (attn + mlp + norms) + self.d_model + out
+        return e + mixers + self.n_layers * (mlp + norms) + self.d_model + out
+
+    @property
+    def _ssm_conv_channels(self) -> int:
+        """x | B | C, the channels the convolution runs over (one group)."""
+        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_state
 
 
 def param_axes(config: TransformerConfig) -> Dict:
     """Pytree of logical-axes tuples, congruent with init_params output."""
     L = ("layers",)
+    mlp = {
+        "w_gate": L + ("embed", "mlp"),
+        "w_up": L + ("embed", "mlp"),
+        "w_down": L + ("mlp", "embed"),
+    }
     axes = {
         "embed": {"tokens": ("vocab", "embed")},
         "layers": {
@@ -126,11 +230,7 @@ def param_axes(config: TransformerConfig) -> Dict:
                 "wv": L + ("embed", "kv_heads", "head_dim"),
                 "wo": L + ("heads", "head_dim", "embed"),
             },
-            "mlp": {
-                "w_gate": L + ("embed", "mlp"),
-                "w_up": L + ("embed", "mlp"),
-                "w_down": L + ("mlp", "embed"),
-            },
+            "mlp": mlp,
             "ln1": L + (None,),
             "ln2": L + (None,),
         },
@@ -141,6 +241,27 @@ def param_axes(config: TransformerConfig) -> Dict:
     if config.qk_norm:
         axes["layers"]["attn"]["q_norm"] = L + ("heads", "head_dim")
         axes["layers"]["attn"]["k_norm"] = L + ("kv_heads", "head_dim")
+    if config.n_layers_of("mamba"):
+        # The mixer's inner width carries no logical axis: `fsdp` shards the
+        # two projections over `embed`, and under `tp` the scan's heads are
+        # REPLICATED over `tensor` (its FFN still shards), not refused.
+        axes["mamba_layers"] = {
+            "ssm": {
+                "in_proj": L + ("embed", None),
+                "conv_w": L + (None, None),
+                "conv_b": L + (None,),
+                "dt_bias": L + (None,),
+                "A_log": L + (None,),
+                "D": L + (None,),
+                "norm": L + (None,),
+                "out_proj": L + (None, "embed"),
+            },
+            "mlp": dict(mlp),
+            "ln1": L + (None,),
+            "ln2": L + (None,),
+        }
+    if not config.n_layers_of("attention"):
+        del axes["layers"]
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -156,10 +277,17 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
         return (jax.random.normal(kk, shape, jnp.float32) * scale).astype(pd)
 
     hd = c.head_dim
-    L = c.n_layers
+    L = c.n_layers_of("attention")
     emb_scale = c.d_model ** -0.5
     proj_scale = c.d_model ** -0.5
     out_scale = (2 * c.n_layers * c.d_model) ** -0.5  # GPT-2-style depth scaling
+
+    def dense_mlp(leading):
+        return {
+            "w_gate": norm_init(next(k), (leading, c.d_model, c.d_ff), proj_scale),
+            "w_up": norm_init(next(k), (leading, c.d_model, c.d_ff), proj_scale),
+            "w_down": norm_init(next(k), (leading, c.d_ff, c.d_model), out_scale),
+        }
 
     # Keys are drawn in this order, whatever the model has: a dense model's
     # weights for a seed do not move when a kind of layer is added here.
@@ -176,11 +304,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
     if c.n_experts is not None:
         mlp = init_moe_params(c, next(k), leading=(L,), out_scale=out_scale)
     else:
-        mlp = {
-            "w_gate": norm_init(next(k), (L, c.d_model, c.d_ff), proj_scale),
-            "w_up": norm_init(next(k), (L, c.d_model, c.d_ff), proj_scale),
-            "w_down": norm_init(next(k), (L, c.d_ff, c.d_model), out_scale),
-        }
+        mlp = dense_mlp(L)
     params = {
         "embed": embed,
         "layers": {
@@ -193,6 +317,33 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
     }
     if not c.tie_embeddings:
         params["lm_head"] = norm_init(next(k), (c.d_model, c.vocab_size), emb_scale)
+    M = c.n_layers_of("mamba")
+    if M:
+        # Mamba-2's own initial values (arXiv:2405.21060; `mamba_ssm`):
+        # A = -(1..heads), D = 1, and a step dt = softplus(dt_bias) drawn
+        # log-uniform in [1e-3, 1e-1] (dt_bias is its inverse softplus).
+        heads, inner = c.ssm_heads, c.ssm_heads * c.ssm_head_dim
+        in_proj = norm_init(next(k), (M, c.d_model, 2 * inner + 2 * c.ssm_state + heads), proj_scale)
+        conv_w = norm_init(next(k), (M, c._ssm_conv_channels, c.ssm_conv), c.ssm_conv ** -0.5)
+        dt = jnp.exp(jax.random.uniform(next(k), (M, heads), jnp.float32)
+                     * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
+        params["mamba_layers"] = {
+            "ssm": {
+                "in_proj": in_proj,
+                "conv_w": conv_w,
+                "conv_b": jnp.zeros((M, c._ssm_conv_channels), pd),
+                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pd),
+                "A_log": jnp.broadcast_to(jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32)), (M, heads)).astype(pd),
+                "D": jnp.ones((M, heads), pd),
+                "norm": jnp.ones((M, inner), pd),
+                "out_proj": norm_init(next(k), (M, inner, c.d_model), out_scale),
+            },
+            "mlp": dense_mlp(M),
+            "ln1": jnp.ones((M, c.d_model), pd),
+            "ln2": jnp.ones((M, c.d_model), pd),
+        }
+    if not L:
+        del params["layers"]
     return params
 
 
@@ -268,15 +419,10 @@ def _layer(
     rules: Optional[Rules],
     mesh=None,
 ):
-    """One decoder layer: (x, this layer's router statistics; None when the
+    """One attention layer: (x, this layer's router statistics; None when the
     FFN is dense)."""
     c = config
-
-    def constrain(h, axes):
-        if rules is None:
-            return h
-        return with_logical_constraint(h, axes, rules, mesh)
-
+    constrain = _constrainer(rules, mesh)
     dt = c.dtype
     from jax.ad_checkpoint import checkpoint_name
 
@@ -293,8 +439,9 @@ def _layer(
             # over the WHOLE projection: heads * head_dim is one vector per position
             q = rms_norm(q, layer_params["attn"]["q_norm"], c.norm_eps, axis=(-2, -1))
             kk = rms_norm(kk, layer_params["attn"]["k_norm"], c.norm_eps, axis=(-2, -1))
-        q = apply_rope(q, positions, theta=c.rope_theta)
-        kk = apply_rope(kk, positions, theta=c.rope_theta)
+        if c.rope_theta is not None:
+            q = apply_rope(q, positions, theta=c.rope_theta)
+            kk = apply_rope(kk, positions, theta=c.rope_theta)
         q = checkpoint_name(q, "q")
         kk = checkpoint_name(kk, "k")
         vv = checkpoint_name(vv, "v")
@@ -314,6 +461,8 @@ def _layer(
             # counterpart).
             from ray_tpu.ops.ring_attention import ring_attention_sharded
 
+            if c.attention_scale is not None:
+                raise ValueError("ring attention takes no attention_scale")
             attn = ring_attention_sharded(
                 q, kk, vv, mesh,
                 seq_axis=ring_axis,
@@ -323,14 +472,35 @@ def _layer(
             )
         else:
             attn = dot_product_attention(
-                q, kk, vv, causal=True, impl=c.attention_impl,
+                q, kk, vv, causal=True, scale=c.attention_scale, impl=c.attention_impl,
                 mesh=mesh if rules is not None else None,
                 batch_axes=batch_axes, head_axis=head_ax,
             )
     with jax.named_scope("layer/attn_proj"):
         attn_out = jnp.einsum("bshd,hde->bse", attn, layer_params["attn"]["wo"].astype(dt))
-        x = x + constrain(attn_out, ("act_batch", "act_seq", "act_embed"))
+        x = x + _scaled(c, constrain(attn_out, ("act_batch", "act_seq", "act_embed")))
+    return _ffn_half(x, layer_params, c, constrain, rules, mesh)
 
+
+def _constrainer(rules: Optional[Rules], mesh):
+    """`(activation, logical axes) -> activation`, placed as the rules say
+    (the identity without rules)."""
+    if rules is None:
+        return lambda h, axes: h
+    return lambda h, axes: with_logical_constraint(h, axes, rules, mesh)
+
+
+def _scaled(config: TransformerConfig, block_out: jax.Array) -> jax.Array:
+    """A block's output as it joins the residual stream."""
+    if config.residual_multiplier == 1.0:
+        return block_out
+    return block_out * jnp.asarray(config.residual_multiplier, block_out.dtype)
+
+
+def _ffn_half(x, layer_params, config, constrain, rules, mesh):
+    """The second half of every layer, whatever its mixer: (x + FFN(ln2(x)),
+    router statistics or None)."""
+    c, dt = config, config.dtype
     router_stats = None
     with jax.named_scope("layer/mlp"):
         h = rms_norm(x, layer_params["ln2"], c.norm_eps)
@@ -341,8 +511,79 @@ def _layer(
             down = _dense_ffn(
                 constrain, h, mlp["w_gate"].astype(dt), mlp["w_up"].astype(dt), mlp["w_down"].astype(dt)
             )
-        x = x + constrain(down, ("act_batch", "act_seq", "act_embed"))
+        x = x + _scaled(c, constrain(down, ("act_batch", "act_seq", "act_embed")))
     return x, router_stats
+
+
+def _mamba_layer(
+    x: jax.Array,
+    layer_params: Dict,
+    positions: jax.Array,
+    config: TransformerConfig,
+    rules: Optional[Rules],
+    mesh=None,
+):
+    """One Mamba-2 layer (module docstring): (x, None).  Its regions sit
+    INSIDE the two mixer scopes every layer has, so `layer/attn_proj` stays
+    "the mixer's projections" and `layer/attn_core` "the mixer's core":
+    `ssm/proj` (ln1, in_proj, out_proj, the residual add), `ssm/conv`
+    (convolution + SiLU, softplus, the gated RMSNorm), `ssm/scan` (the SSD,
+    named in `ops/ssm.py`)."""
+    del positions  # a recurrence needs none
+    c = config
+    constrain = _constrainer(rules, mesh)
+    dt = c.dtype
+    ssm = layer_params["ssm"]
+    heads, inner, n = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_state
+    with jax.named_scope("layer/attn_proj"):
+        with jax.named_scope("ssm/proj"):
+            h = rms_norm(x, layer_params["ln1"], c.norm_eps)
+            zxbcdt = jnp.einsum("bse,ef->bsf", h, ssm["in_proj"].astype(dt))
+            z, xbc, step = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], axis=-1)
+        with jax.named_scope("ssm/conv"):
+            xbc = jax.nn.silu(causal_conv1d(xbc, ssm["conv_w"], ssm["conv_b"]))
+            step = jax.nn.softplus(step.astype(jnp.float32) + ssm["dt_bias"].astype(jnp.float32))
+            xs, b_in, c_out = jnp.split(xbc, [inner, inner + n], axis=-1)
+    with jax.named_scope("layer/attn_core"):
+        y = ssd_chunked(
+            xs.reshape(*xs.shape[:2], heads, c.ssm_head_dim), step,
+            -jnp.exp(ssm["A_log"].astype(jnp.float32)), b_in, c_out, ssm["D"],
+        )
+    with jax.named_scope("layer/attn_proj"):
+        with jax.named_scope("ssm/conv"):
+            y = y.reshape(*y.shape[:2], inner)
+            gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+            y = rms_norm(gated, ssm["norm"], c.norm_eps).astype(dt)  # one group: over all of d_inner
+        with jax.named_scope("ssm/proj"):
+            out = jnp.einsum("bsf,fe->bse", y, ssm["out_proj"].astype(dt))
+            x = x + _scaled(c, constrain(out, ("act_batch", "act_seq", "act_embed")))
+    return _ffn_half(x, layer_params, c, constrain, rules, mesh)
+
+
+_LAYER_FNS = {"attention": _layer, "mamba": _mamba_layer}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _split_runs(stack: Dict, bounds: Tuple[Tuple[int, int], ...]):
+    """One kind's parameter stack cut into its runs, (first, count) each: a
+    tuple of trees of [count, ...] leaves.  The backward CONCATENATES the
+    runs' gradients: the transpose of a slice is a pad to the stack's length,
+    and a sum of pads would hold the whole stack's gradient once per run."""
+    return tuple(
+        jax.tree_util.tree_map(lambda a: jax.lax.slice_in_dim(a, first, first + count), stack)
+        for first, count in bounds
+    )
+
+
+def _split_runs_fwd(stack, bounds):
+    return _split_runs(stack, bounds), None
+
+
+def _split_runs_bwd(bounds, _, d_runs):
+    return (jax.tree_util.tree_map(lambda *parts: jnp.concatenate(parts, axis=0), *d_runs),)
+
+
+_split_runs.defvjp(_split_runs_fwd, _split_runs_bwd)
 
 
 def _remat_policy(config: TransformerConfig):
@@ -394,6 +635,11 @@ def _run_layers_pipelined(
         raise ValueError(
             "strategy 'pp' runs dense layers only: the router statistics of "
             "an expert layer do not come out of the pipeline schedule"
+        )
+    if c.layer_types is not None:
+        raise ValueError(
+            "strategy 'pp' runs a homogeneous stack only: the stages of a "
+            "stack with layer_types would hold unequal layers"
         )
     n_stages = mesh.shape[axis]
     per_stage = c.n_layers // n_stages
@@ -466,6 +712,8 @@ def forward_with_router_stats(
         raise ValueError("forward(rules=...) needs the mesh the rules refer to")
     with jax.named_scope("embed"):
         x = params["embed"]["tokens"].astype(c.dtype)[tokens]
+        if c.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(c.embedding_multiplier, c.dtype)
         if rules is not None:
             x = with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"), rules, mesh)
     positions = jnp.arange(tokens.shape[1])
@@ -534,19 +782,37 @@ def forward_with_router_stats(
                 rules=rules, fsdp_axis=pp_fsdp_axis,
             )
         else:
-            layer_fn = functools.partial(
-                _layer, positions=positions, config=c, rules=rules, mesh=mesh
-            )
-            if c.remat:
-                layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
-
-            x, router_stats = jax.lax.scan(layer_fn, x, params["layers"])
+            # One scan per maximal run of one kind of layer, over that run's
+            # slice of the kind's stack; a homogeneous model is one run over
+            # its whole stack.
+            runs = c.layer_runs()
+            stacks = {}
+            for kind, subtree in LAYER_KINDS.items():
+                bounds = tuple((first, count) for k, first, count in runs if k == kind)
+                if len(bounds) == 1:
+                    stacks[kind] = iter([params[subtree]])
+                elif bounds:
+                    stacks[kind] = iter(_split_runs(params[subtree], bounds))
+            for kind, _, _ in runs:
+                layer_fn = functools.partial(
+                    _LAYER_FNS[kind], positions=positions, config=c, rules=rules, mesh=mesh
+                )
+                if c.remat:
+                    layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
+                x, router_stats = jax.lax.scan(layer_fn, x, next(stacks[kind]))
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], c.norm_eps)
     with jax.named_scope("lm_head"):
         head = (
             params["embed"]["tokens"].T if c.tie_embeddings else params["lm_head"]
         ).astype(c.dtype)
+        if c.logits_scaling != 1.0:
+            # logits / logits_scaling, applied to the [B, S, d] rows that enter
+            # the head and not to the [B, S, vocab] logits that leave it: the
+            # same function (a linear map commutes with a scalar; a power of
+            # two, as published, does not even move a rounding) without a
+            # pass over the float32 logits.
+            x = x / jnp.asarray(c.logits_scaling, x.dtype)
         logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
         if rules is not None:
             logits = with_logical_constraint(

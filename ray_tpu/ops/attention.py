@@ -147,8 +147,9 @@ def dot_product_attention(
 
     impl: None (auto) | "reference" | "blockwise" | "pallas".
     Auto gives a program LOWERED FOR TPU the pallas flash kernel when the
-    shapes are tile-aligned; every other lowering, and every other shape,
-    gets the XLA forms (blockwise scan beyond block_size, else reference).
+    shapes are tile-aligned (sequence lengths a multiple of 128, head size a
+    multiple of 64); every other lowering, and every other shape, gets the
+    XLA forms (blockwise scan beyond block_size, else reference).
     The choice rides `jax.lax.platform_dependent`, so it follows the
     platform a step is compiled for, not the process's default backend.
 
@@ -179,7 +180,7 @@ def dot_product_attention(
 
     if impl is None:
         xla = blockwise if q.shape[1] > block_size else reference
-        if q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[-1] % 128 == 0:
+        if q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[-1] % 64 == 0:
             return jax.lax.platform_dependent(q, k, v, tpu=pallas, default=xla)
         return xla(q, k, v)
     forms = {"reference": reference, "blockwise": blockwise, "pallas": pallas}

@@ -30,6 +30,10 @@ Rules = Dict[str, MeshAxis]
 #   embed/heads/kv_heads/head_dim/mlp/vocab/expert — parameter dims
 #   layers — scan-over-layers leading axis (sharded over `pipeline` by
 #            pp_rules; unsharded elsewhere)
+#   A Mamba-2 mixer's leaves (models/transformer.py `mamba_layers`) use
+#   `embed` on the model side of their two projections and no name on the
+#   mixer's inner width: fsdp shards them, tp replicates them (the scan's
+#   heads are not split over `tensor`).
 #   act_batch/act_seq/act_embed/act_heads/act_kv_heads/act_head_dim/
 #   act_mlp/act_vocab — activation dims
 

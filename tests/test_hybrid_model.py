@@ -1,0 +1,349 @@
+"""The hybrid decoder (Granite 4.0-H's stack: Mamba-2 and NoPE attention layers
+in one model, muP multipliers, tied head) against its plain float32 reference
+(`benchmarks/lib/reference_hybrid.py`: the recurrence token by token), at tiny
+widths on the CPU: logits, the objective, every gradient leaf, the chunked
+scan against the recurrence across chunk lengths and under a decay that
+underflows, causality across chunk boundaries, each multiplier, no rotary
+embedding, the dense and expert programs left as they were, `fsdp=4` on the
+CPU mesh, and what `pp` and an expert hybrid are told.
+
+TOLERANCE: program and reference are both float32 and compute the same
+function in another order (chunked masked products against a pass over
+tokens), so they agree to float32 rounding accumulated over S = 96 steps:
+1e-4 of each tensor's scale (measured: 9e-7 at the scan's output).  A bf16
+decay (8 bits: `exp(dt*A)` off by up to 0.4% per step, compounding along the
+chunk) misses it by 20 times at the scan's output;
+`test_a_bf16_decay_is_outside_the_tolerance` shows that.
+
+Weights are seeded through the program's own `init_state`; the norm scales
+and `D` (which start at one) and the convolution's bias (zero) are then drawn
+at random so a leaf that is never applied cannot pass.
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)  # `benchmarks.lib` resolves from this checkout
+
+from benchmarks.lib import reference_hybrid  # noqa: E402
+from ray_tpu.models import LMTrainContext, TransformerConfig  # noqa: E402
+from ray_tpu.models import transformer  # noqa: E402
+from ray_tpu.ops import ssm  # noqa: E402
+from ray_tpu.parallel import MeshSpec, build_mesh  # noqa: E402
+
+SEQ = 96  # three chunks of 32, and not a multiple of 64
+KINDS = ("mamba", "mamba", "attention", "mamba")  # two runs of one kind around a run of the other
+BASE = dict(
+    vocab_size=128, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, d_ff=96, max_seq_len=SEQ,
+    dtype=jnp.float32, param_dtype=jnp.float32, remat=False, tie_embeddings=True, rope_theta=None,
+    layer_types=KINDS, ssm_heads=2, ssm_head_dim=16, ssm_state=8, ssm_conv=4,
+    embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=8.0, attention_scale=1 / 16,
+)
+RTOL = 1e-4
+REDRAWN = ("ln1", "ln2", "final_norm", "norm", "D", "conv_b")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def chunk_of_32():
+    """The program's chunk for this module: S = 96 crosses two boundaries."""
+    patch = pytest.MonkeyPatch()
+    patch.setattr(ssm, "CHUNK", 32)
+    yield
+    patch.undo()
+
+
+def reference_config(cfg: TransformerConfig) -> dict:
+    """The published key names `reference_hybrid` reads, from a TransformerConfig."""
+    return {
+        "num_hidden_layers": cfg.n_layers, "layer_types": list(cfg.layer_types), "rms_norm_eps": cfg.norm_eps,
+        "attention_multiplier": cfg.attention_scale, "embedding_multiplier": cfg.embedding_multiplier,
+        "residual_multiplier": cfg.residual_multiplier, "logits_scaling": cfg.logits_scaling,
+        "position_embedding_type": "nope" if cfg.rope_theta is None else "rope", "rope_theta": cfg.rope_theta,
+        "tie_word_embeddings": cfg.tie_embeddings,
+    }
+
+
+def one_device_ctx(cfg):
+    return LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+
+
+def seeded_params(ctx, seed=0):
+    params = ctx.init_state(seed)["params"]
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def draw(path, leaf):
+        if path[-1].key in REDRAWN:
+            return (leaf + 0.3 * jax.random.normal(next(keys), leaf.shape)).astype(leaf.dtype)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(draw, params)
+
+
+def batch_of(cfg, seed=0, batch=2, seq=SEQ):
+    rng = np.random.default_rng(seed)
+    toks = rng.integers(0, cfg.vocab_size, (batch, seq + 1)).astype(np.int32)
+    return {"tokens": jnp.asarray(toks[:, :-1]), "targets": jnp.asarray(toks[:, 1:])}
+
+
+def close(got, want, rtol=RTOL):
+    want = np.asarray(want)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=rtol, atol=rtol * np.abs(want).max())
+
+
+@pytest.fixture(scope="module")
+def tiny(chunk_of_32):
+    """Program and reference on the same float32 weights and batch."""
+    cfg = TransformerConfig(**BASE)
+    ctx = one_device_ctx(cfg)
+    params, batch = seeded_params(ctx), batch_of(cfg)
+    rcfg = reference_config(cfg)
+    (loss, _), grads = jax.jit(jax.value_and_grad(ctx._loss, has_aux=True))(params, batch)
+    ref_loss, ref_grads = jax.value_and_grad(
+        lambda p: reference_hybrid.objective(rcfg, p, batch["tokens"], batch["targets"]))(params)
+    return dict(cfg=cfg, ctx=ctx, params=params, batch=batch, rcfg=rcfg,
+                logits=ctx.apply(params, batch["tokens"]),
+                ref_logits=reference_hybrid.logits(rcfg, params, batch["tokens"], last=SEQ),
+                loss=loss, grads=grads, ref_loss=ref_loss, ref_grads=ref_grads)
+
+
+# -- forward, objective, gradients ------------------------------------------------------
+
+
+def test_the_stack_is_three_runs_and_the_parameters_two_stacks(tiny):
+    cfg, params = tiny["cfg"], tiny["params"]
+    assert cfg.layer_runs() == (("mamba", 0, 2), ("attention", 0, 1), ("mamba", 2, 1))
+    assert params["layers"]["attn"]["wq"].shape[0] == 1 and params["mamba_layers"]["ssm"]["in_proj"].shape[0] == 3
+    assert sum(leaf.size for leaf in jax.tree_util.tree_leaves(params)) == cfg.num_params()
+
+
+def test_logits_equal_the_reference(tiny):
+    close(tiny["logits"], tiny["ref_logits"])
+
+
+def test_objective_equals_the_reference(tiny):
+    np.testing.assert_allclose(float(tiny["loss"]), float(tiny["ref_loss"]), rtol=1e-5)
+
+
+LEAVES = ["embed/tokens", "final_norm",
+          "layers/attn/wq", "layers/attn/wk", "layers/attn/wv", "layers/attn/wo",
+          "layers/mlp/w_gate", "layers/mlp/w_up", "layers/mlp/w_down", "layers/ln1", "layers/ln2",
+          "mamba_layers/ssm/in_proj", "mamba_layers/ssm/conv_w", "mamba_layers/ssm/conv_b",
+          "mamba_layers/ssm/dt_bias", "mamba_layers/ssm/A_log", "mamba_layers/ssm/D",
+          "mamba_layers/ssm/norm", "mamba_layers/ssm/out_proj",
+          "mamba_layers/mlp/w_gate", "mamba_layers/mlp/w_up", "mamba_layers/mlp/w_down",
+          "mamba_layers/ln1", "mamba_layers/ln2"]
+
+
+def test_the_leaf_list_is_every_leaf(tiny):
+    paths = {"/".join(k.key for k in path) for path, _ in jax.tree_util.tree_flatten_with_path(tiny["params"])[0]}
+    assert paths == set(LEAVES)
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_every_gradient_leaf_equals_the_reference(tiny, leaf):
+    got, want = tiny["grads"], tiny["ref_grads"]
+    for key in leaf.split("/"):
+        got, want = got[key], want[key]
+    assert np.abs(np.asarray(want)).max() > 0  # the leaf is used
+    close(got, want)
+
+
+# -- the scan alone ---------------------------------------------------------------------
+
+
+def _recurrence(x, dt, A, B, C, D):
+    """The recurrence of `ops/ssm.py`'s docstring, token by token."""
+    def step(state, inp):
+        xt, dtt, bt, ct = inp
+        state = jnp.exp(dtt * A)[..., None, None] * state + (dtt[..., None] * xt)[..., None] * bt[:, None, None, :]
+        return state, jnp.einsum("bhpn,bn->bhp", state, ct) + D[:, None] * xt
+
+    b, _, h, p = x.shape
+    _, y = jax.lax.scan(step, jnp.zeros((b, h, p, B.shape[-1])),
+                        (x.swapaxes(0, 1), dt.swapaxes(0, 1), B.swapaxes(0, 1), C.swapaxes(0, 1)))
+    return y.swapaxes(0, 1)
+
+
+def _scan_inputs(seed=0, b=2, s=SEQ, h=3, p=16, n=8, a_scale=1.0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return (jax.random.normal(k[0], (b, s, h, p)), jax.nn.softplus(jax.random.normal(k[1], (b, s, h))),
+            -a_scale * jnp.exp(jax.random.normal(k[2], (h,))), jax.random.normal(k[3], (b, s, n)),
+            jax.random.normal(k[4], (b, s, n)), jax.random.normal(k[5], (h,)))
+
+
+@pytest.mark.parametrize("chunk", [16, 32, SEQ])
+@pytest.mark.parametrize("a_scale", [1.0, 200.0], ids=["mild", "underflowing"])
+def test_ssd_chunked_equals_the_recurrence(chunk, a_scale):
+    """`underflowing`: dt*A near -200 a step, so `exp(cum)` is 0 in float32
+    after one position and a quotient of exponentials would be 0/0."""
+    args = _scan_inputs(a_scale=a_scale)
+    with jax.default_matmul_precision("highest"):
+        want = _recurrence(*args)
+        got = ssm.ssd_chunked(*args, chunk=chunk)
+        d_got = jax.grad(lambda x, dt: jnp.sum(ssm.ssd_chunked(x, dt, *args[2:], chunk=chunk) ** 2),
+                         argnums=(0, 1))(*args[:2])
+        d_want = jax.grad(lambda x, dt: jnp.sum(_recurrence(x, dt, *args[2:]) ** 2), argnums=(0, 1))(*args[:2])
+    assert np.all(np.isfinite(np.asarray(got)))
+    close(got, want)
+    for g, w in zip(d_got, d_want):
+        assert np.all(np.isfinite(np.asarray(g)))
+        close(g, w)
+
+
+def test_a_bf16_decay_is_outside_the_tolerance():
+    """The scan with `dt` and `A` rounded to bf16 (8 bits: each `exp(dt*A)` off
+    by up to 0.4%, compounding along the chunk): what the tolerance exists to
+    catch, measured 2e-3 of the output's scale against 9e-7 in float32."""
+    args = _scan_inputs()
+    rounded = [v.astype(jnp.bfloat16).astype(jnp.float32) for v in args[1:3]]
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(_recurrence(*args))
+        got = np.asarray(ssm.ssd_chunked(args[0], *rounded, *args[3:]))
+    assert np.abs(got - want).max() > 10 * RTOL * np.abs(want).max()
+
+
+def test_ssd_chunked_refuses_a_ragged_last_chunk():
+    with pytest.raises(ValueError, match="multiple of the chunk"):
+        ssm.ssd_chunked(*_scan_inputs(s=40), chunk=32)
+
+
+def test_causal_conv1d_is_four_shifted_adds():
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 10, 5))
+    w = jax.random.normal(jax.random.PRNGKey(1), (5, 4))
+    b = jax.random.normal(jax.random.PRNGKey(2), (5,))
+    got = np.asarray(ssm.causal_conv1d(x, w, b))
+    xp = np.concatenate([np.zeros((2, 3, 5), np.float32), np.asarray(x)], axis=1)
+    want = np.asarray(b) + sum(xp[:, k: k + 10] * np.asarray(w)[:, k] for k in range(4))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("t", [0, 31, 32, 70])
+def test_a_token_changes_outputs_from_its_position_on_only(tiny, t):
+    """Convolution, scan and attention together: the state crosses chunk
+    boundaries forward and nothing leaks backward."""
+    tokens = np.asarray(tiny["batch"]["tokens"]).copy()
+    changed = tokens.copy()
+    changed[:, t] = (changed[:, t] + 1) % tiny["cfg"].vocab_size
+    a, b = (np.asarray(tiny["ctx"].apply(tiny["params"], jnp.asarray(tk))) for tk in (tokens, changed))
+    assert np.array_equal(a[:, :t], b[:, :t])
+    assert np.abs(a[:, t:] - b[:, t:]).max(axis=-1).min() > 0  # every later position of every row moved
+
+
+# -- the published facts each matter ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("field", ["embedding_multiplier", "residual_multiplier", "logits_scaling", "attention_scale"])
+def test_each_multiplier_matters(tiny, field):
+    cfg = dataclasses.replace(tiny["cfg"], **{field: None if field == "attention_scale" else 1.0})
+    got = one_device_ctx(cfg).apply(tiny["params"], tiny["batch"]["tokens"])
+    want = np.asarray(tiny["ref_logits"])
+    diff = np.abs(np.asarray(got) - want).max() / np.abs(want).max()
+    assert diff > 10 * RTOL
+
+
+def test_no_rope_theta_means_no_rotary_embedding(tiny, monkeypatch):
+    calls = []
+    real = transformer.apply_rope
+    monkeypatch.setattr(transformer, "apply_rope", lambda *a, **k: calls.append(1) or real(*a, **k))
+    tokens = tiny["batch"]["tokens"]
+    transformer.forward(tiny["params"], tokens, tiny["cfg"])
+    assert not calls
+    transformer.forward(tiny["params"], tokens, dataclasses.replace(tiny["cfg"], rope_theta=10000.0))
+    assert len(calls) == 2  # q and k of the one attention layer's one traced body
+
+
+def test_what_the_config_refuses():
+    with pytest.raises(ValueError, match="layer_types"):
+        TransformerConfig(**dict(BASE, layer_types=KINDS[:3]))
+    with pytest.raises(ValueError, match="layer_types"):
+        TransformerConfig(**dict(BASE, layer_types=("mamba", "window", "attention", "mamba")))
+    with pytest.raises(ValueError, match="dense FFN"):
+        TransformerConfig(**dict(BASE, n_experts=4, experts_per_token=2))
+    with pytest.raises(ValueError, match="ssm_heads"):
+        TransformerConfig(**dict(BASE, ssm_heads=0))
+
+
+def test_pp_refuses_a_hybrid_stack():
+    cfg = TransformerConfig(**dict(BASE, n_layers=4))
+    mesh = build_mesh(MeshSpec(data=1, pipeline=2), devices=jax.devices()[:2])
+    ctx = LMTrainContext(cfg, mesh=mesh, strategy="pp")
+    with pytest.raises(ValueError, match="homogeneous stack"):
+        jax.eval_shape(ctx._loss, jax.eval_shape(ctx._init, jax.random.PRNGKey(0))["params"], batch_of(cfg))
+
+
+# -- the programs that were there are as they were ---------------------------------------------
+
+
+def _equations(jaxpr) -> int:
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    total += _equations(inner)
+    return total
+
+
+EXPERT = dict(n_heads=4, n_kv_heads=4, d_ff=32, n_experts=8, experts_per_token=2, qk_norm=True,
+              router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
+
+
+@pytest.mark.parametrize("kw, equations", [({}, 710), (EXPERT, 2904)], ids=["dense", "expert"])
+def test_a_dense_and_an_expert_step_trace_to_the_parents_program(kw, equations):
+    """Counted at the parent of PR 30 with this function (the dense count is
+    `tests/test_moe_model.py`'s 709 + 1): the new fields' defaults add no
+    equation, no slice of the stack and nothing of the scan."""
+    ctx = one_device_ctx(TransformerConfig.tiny(**kw))
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    jaxpr = jax.make_jaxpr(ctx._train_step)(state, {"tokens": toks, "targets": toks})
+    assert _equations(jaxpr.jaxpr) == equations
+    text = str(jaxpr)
+    assert "ssm" not in text and "mamba" not in text
+
+
+DENSE_SEED0 = {"embed": [0.12550178170204163, -0.1132921501994133],
+               "lm_head": [-0.08073218911886215, -0.1908739060163498],
+               "w_down": [0.048702314496040344, -0.0014959044056013227]}
+
+
+def test_a_dense_models_weights_for_a_seed_did_not_move():
+    """The key order of `init_params`: the first and last leaves a dense
+    model draws, against values recorded at the parent of PR 30."""
+    params = transformer.init_params(TransformerConfig.tiny(), jax.random.PRNGKey(0))
+    assert "mamba_layers" not in params
+    np.testing.assert_allclose(np.asarray(params["embed"]["tokens"][0, :2]), DENSE_SEED0["embed"], rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(params["lm_head"][0, :2]), DENSE_SEED0["lm_head"], rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(params["layers"]["mlp"]["w_down"][1, 0, :2]), DENSE_SEED0["w_down"],
+                               rtol=1e-6)
+
+
+
+
+# -- across devices --------------------------------------------------------------------------
+
+
+def test_fsdp4_step_equals_the_single_device_step(tiny):
+    mesh = build_mesh(MeshSpec(data=1, fsdp=4), devices=jax.devices()[:4])
+    ctx = LMTrainContext(tiny["cfg"], mesh=mesh, strategy="fsdp")
+    in_proj = ctx.param_shardings["mamba_layers"]["ssm"]["in_proj"].spec
+    out_proj = ctx.param_shardings["mamba_layers"]["ssm"]["out_proj"].spec
+    assert in_proj[1] == "fsdp" and out_proj[2] == "fsdp"  # the model side of both projections
+    batch = batch_of(tiny["cfg"], batch=4)
+    losses = []
+    for c in (ctx, tiny["ctx"]):
+        copy = jax.device_put(jax.tree_util.tree_map(np.asarray, tiny["params"]), c.param_shardings)
+        state = dict(c.init_state(0), params=copy)
+        _, metrics = c.train_step(state, jax.tree_util.tree_map(np.asarray, batch))
+        losses.append((float(metrics["loss"]), float(metrics["grad_norm"])))
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)

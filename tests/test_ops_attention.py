@@ -67,6 +67,45 @@ def test_pallas_flash_grad():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
 
 
+def test_pallas_flash_at_head_size_64_with_a_callers_scale():
+    """Granite 4.0-H's attention: heads of 64, GQA 4:1, and the published
+    softmax scale 1/64 (not 64 ** -0.5): forward and all three gradients of
+    the kernels in interpret mode against `reference_attention`."""
+    from ray_tpu.ops.pallas.flash_attention import flash_attention
+
+    q, k, v = _qkv(jax.random.PRNGKey(7), b=1, s=256, h=4, kv=1, d=64)
+    q, k = 4.0 * q, 4.0 * k  # logits of order one at this scale, so the softmax is not flat
+    scale = 1 / 64
+
+    def run(fn):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))), argnums=(0, 1, 2))(q, k, v)
+
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True, scale=scale, block_q=128, block_k=64,  # noqa: E731
+                                            bwd_block_q=64, bwd_block_k=128)
+    ref = lambda q, k, v: reference_attention(q, k, v, causal=True, scale=scale)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5)
+    (_, g_flash), (_, g_ref) = run(flash), run(ref)
+    for a, b in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+    # the scale is the caller's: the default one gives another function
+    other = reference_attention(q, k, v, causal=True)
+    assert np.abs(np.asarray(other) - np.asarray(ref(q, k, v))).max() > 1e-2
+
+
+def test_auto_dispatch_gives_head_size_64_to_the_kernel_when_lowered_for_tpu():
+    """ONE rule for every model: sequence lengths a multiple of 128, head size
+    a multiple of 64.  Head size 32 stays with the XLA forms."""
+    from ray_tpu.ops.attention import dot_product_attention
+
+    def lowered(d):
+        q = jax.ShapeDtypeStruct((1, 256, 2, d), jnp.bfloat16)
+        fn = jax.jit(lambda q, k, v: dot_product_attention(q, k, v, scale=1 / 64))
+        return fn.trace(q, q, q).lower(lowering_platforms=("tpu",)).as_text()
+
+    assert 'kernel_name = "flash_fwd"' in lowered(64) and 'kernel_name = "flash_fwd"' in lowered(128)
+    assert "tpu_custom_call" not in lowered(32)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_pallas_flash_grad_noncausal_and_mixed_blocks(causal):
     """Backward kernels with bwd tile sizes differing from fwd tiles."""

@@ -122,6 +122,48 @@ def test_lowered_expert_step_keeps_the_flash_kernels_and_the_loss_scope(moe_lowe
     assert "/loss/" in moe_lowered_for_tpu or "(loss)" in moe_lowered_for_tpu
 
 
+# -- the Mamba-2 mixer's names (PERF.md section 3, PR 30) -----------------------------------
+
+SSM_SCOPES = {"ssm/proj": "layer/attn_proj", "ssm/conv": "layer/attn_proj", "ssm/scan": "layer/attn_core"}
+HYBRID_CFG = TransformerConfig.tiny(
+    n_layers=4, n_heads=4, n_kv_heads=1, d_model=256, d_ff=256, max_seq_len=128, remat=True, remat_policy="qkv_attn",
+    tie_embeddings=True, rope_theta=None, layer_types=("mamba", "mamba", "attention", "mamba"),
+    ssm_heads=8, ssm_head_dim=64, ssm_state=128, embedding_multiplier=12.0, residual_multiplier=0.22,
+    logits_scaling=8.0, attention_scale=1 / 64,
+)
+
+
+@pytest.fixture(scope="module")
+def hybrid_lowered_for_tpu():
+    ctx = LMTrainContext(HYBRID_CFG, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
+    return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", SSM_SCOPES)
+def test_lowered_hybrid_step_names_the_mixers_regions_inside_the_two_mixer_scopes(hybrid_lowered_for_tpu, scope):
+    """Forward and recompute: `layer/attn_proj` and `layer/attn_core` stay
+    the mixer's projections and the mixer's core in every cell, and
+    `benchmarks/lib/trace_ssm.py` splits them."""
+    outer = SSM_SCOPES[scope]
+    assert f'"{outer}/{scope}/' in hybrid_lowered_for_tpu
+    assert f'"checkpoint/rematted_computation/{outer}/{scope}/' in hybrid_lowered_for_tpu
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_lowered_hybrid_step_keeps_every_model_scope(hybrid_lowered_for_tpu, scope):
+    assert f"/{scope}/" in hybrid_lowered_for_tpu or f"({scope})" in hybrid_lowered_for_tpu
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_lowered_hybrid_step_holds_each_flash_kernel_exactly_once(hybrid_lowered_for_tpu, kernel):
+    calls = [line for line in hybrid_lowered_for_tpu.splitlines() if "@tpu_custom_call" in line
+             and f'kernel_name = "{kernel}"' in line]
+    assert len(calls) == 1  # one attention layer, its forward saved (`qkv_attn`)
+
+
 # -- tracing.annotate -------------------------------------------------------------------
 
 

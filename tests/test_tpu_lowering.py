@@ -80,3 +80,28 @@ def test_cpu_lowering_has_no_kernel():
     (and no interpret-mode kernel either: auto dispatch is XLA attention)."""
     text = _lowered_text(1, MeshSpec(data=1), "dp")
     assert "tpu_custom_call" not in text
+
+
+# Granite 4.0-H's shapes in small: 5 Mamba-2 layers and ONE attention layer at
+# head size 64 (d 256 / 4 heads, GQA 4:1) with the published scale and no
+# rotary embedding, in three runs of one kind.
+HYBRID = TransformerConfig.tiny(
+    n_layers=6, n_heads=4, n_kv_heads=1, d_model=256, d_ff=256, max_seq_len=128, remat=True, remat_policy="qkv_attn",
+    tie_embeddings=True, rope_theta=None, layer_types=("mamba", "mamba", "mamba", "attention", "mamba", "mamba"),
+    ssm_heads=8, ssm_head_dim=64, ssm_state=128, embedding_multiplier=12.0, residual_multiplier=0.22,
+    logits_scaling=8.0, attention_scale=1 / 64,
+)
+
+
+@pytest.mark.parametrize(
+    "n_devices,spec,strategy",
+    [(1, MeshSpec(data=1), "dp"), (4, MeshSpec(data=1, fsdp=4), "fsdp"), (4, MeshSpec(data=2, tensor=2), "tp")],
+    ids=["dp1", "fsdp4", "tp4"],
+)
+def test_hybrid_step_lowers_for_tpu_with_each_flash_kernel_once(n_devices, spec, strategy):
+    """One attention layer, so each kernel exactly once, at head size 64;
+    the scan is XLA's to partition (under `tp` its heads are replicated)."""
+    text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=HYBRID)
+    assert _mosaic_kernels(text) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    call = next(line for line in text.splitlines() if "@tpu_custom_call" in line and 'kernel_name = "flash_fwd"' in line)
+    assert re.search(r"tensor<\d+x\d+x128x64xf32>", call)  # q: [batch, heads, seq, 64] (float32 in this tiny config)
