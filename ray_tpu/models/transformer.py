@@ -64,7 +64,7 @@ import jax.numpy as jnp
 from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, dot_product_attention
 from ray_tpu.ops.rotary import apply_rope
-from ray_tpu.ops.ssm import causal_conv1d, ssd_chunked
+from ray_tpu.ops.ssm import causal_conv1d_silu, ssd_chunked
 from ray_tpu.parallel.sharding import Rules, with_logical_constraint
 
 
@@ -527,13 +527,13 @@ def _mamba_layer(
     INSIDE the two mixer scopes every layer has, so `layer/attn_proj` stays
     "the mixer's projections" and `layer/attn_core` "the mixer's core":
     `ssm/proj` (ln1, in_proj, out_proj, the residual add), `ssm/conv`
-    (convolution + SiLU, softplus, the gated RMSNorm), `ssm/scan` (the SSD,
-    named in `ops/ssm.py`)."""
+    (convolution + SiLU, one unit with its own backward: on TPU the kernels
+    `ssm_conv_fwd` / `ssm_conv_bwd`; softplus; the gated RMSNorm, float32 over
+    the scan's bf16 output), `ssm/scan` (the SSD, named in `ops/ssm.py`)."""
     del positions  # a recurrence needs none
-    c = config
+    c, dt, ssm = config, config.dtype, layer_params["ssm"]
     constrain = _constrainer(rules, mesh)
-    dt = c.dtype
-    ssm = layer_params["ssm"]
+    sharded = {} if rules is None else dict(mesh=mesh, batch_axes=rules.get("act_batch"))
     heads, inner, n = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_state
     with jax.named_scope("layer/attn_proj"):
         with jax.named_scope("ssm/proj"):
@@ -541,7 +541,7 @@ def _mamba_layer(
             zxbcdt = jnp.einsum("bse,ef->bsf", h, ssm["in_proj"].astype(dt))
             z, xbc, step = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], axis=-1)
         with jax.named_scope("ssm/conv"):
-            xbc = jax.nn.silu(causal_conv1d(xbc, ssm["conv_w"], ssm["conv_b"]))
+            xbc = causal_conv1d_silu(xbc, ssm["conv_w"], ssm["conv_b"], **sharded)
             step = jax.nn.softplus(step.astype(jnp.float32) + ssm["dt_bias"].astype(jnp.float32))
             xs, b_in, c_out = jnp.split(xbc, [inner, inner + n], axis=-1)
     with jax.named_scope("layer/attn_core"):

@@ -1,6 +1,16 @@
 """Mamba-2's selective state-space scan (SSD, arXiv:2405.21060) in its chunked
-form, and the causal depthwise convolution that feeds it.  Plain `jax.numpy`,
-differentiated by JAX; no counterpart in the reference (SURVEY.md §5.7).
+form, and the causal depthwise convolution + SiLU that feeds it; no
+counterpart in the reference (SURVEY.md §5.7).  The scan is plain `jax.numpy`,
+differentiated by JAX.  The convolution is one `custom_vjp`
+(`causal_conv1d_silu`) with a backward written by hand: JAX's own transpose of
+K shifted multiply-adds is one full-size float32 cotangent array PER TAP (572
+MB written and read back a layer at the benchmark's [8192, 4352]), where the
+hand-written one is the same K taps run anti-causally over `dy * silu'(pre)`
+plus three reductions, no float32 [B, S, C] array anywhere.  For a step
+lowered for TPU at tile-aligned shapes both directions are Pallas kernels
+(`ops/pallas/ssm_conv.py`: every full-size array crosses HBM once, in bf16);
+every other shape and platform takes the plain form below, chosen from the
+shapes alone.
 
 The recurrence, per batch row and per head h, with a state `H_t` of shape
 [P, N] (head size x state size), a positive step `dt_t`, a negative scalar
@@ -35,7 +45,11 @@ The [B, S/chunk, H, chunk, chunk] decay masks and scores are written to HBM
 in this form (0.5 GB in float32 at one 8,192-token sequence, 64 heads and a
 chunk of 256).  A Pallas kernel that keeps them in VMEM is the first thing a
 `perf_opt` issue on the scan would write; `ssm/scan`, the scope around all of
-this, is what its gain would be read by (PERF.md section 3).
+this, is what its gain would be read by (PERF.md section 3).  The scan's three
+terms are summed in the matmuls' own [b, c, h, t, p] order and rounded to the
+inputs' dtype THERE; the relayout to [b, S, h, p] moves the rounded array
+behind an `optimization_barrier` (without it XLA hoists the consumer's
+float32 convert across the relayout and copies float32, twice).
 
 Sharding: nothing here names a mesh axis.  The heads are not sharded over
 `tensor` (the model replicates the mixer's weights under `tp`); batch and
@@ -48,25 +62,119 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.parallel.sharding import _fit_spec
 
 # The published chunk length (`mamba_chunk_size`); the program's own constant.
 CHUNK = 256
 
 
-def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """Causal depthwise convolution along the sequence: x [B, S, C], w [C, K],
-    b [C] -> [B, S, C] with `y_t = b + sum_k w[:, k] * x_{t - (K-1) + k}` and
-    zeros before the sequence's start (so `w[:, K-1]` multiplies `x_t`, as
-    `torch.nn.Conv1d(groups=C, padding=K-1)` cut to S outputs has it).
-    K shifted multiply-adds in float32: one elementwise pass."""
+def _shifted(x: jax.Array, j: int) -> jax.Array:
+    """`x_{t-j}` along axis 1, zeros where t - j falls outside the sequence
+    (j > 0 looks back, j < 0 ahead): a view in x's own dtype, no padded copy."""
+    if j == 0:
+        return x
+    zeros = jnp.zeros_like(x[:, : abs(j)])
+    if j > 0:
+        return jnp.concatenate([zeros, x[:, : x.shape[1] - j]], axis=1)
+    return jnp.concatenate([x[:, -j:], zeros], axis=1)
+
+
+def _conv_pre(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """The convolution before its activation, float32: b + sum_k w[:, k] * x_{t-(K-1)+k}."""
     k = w.shape[1]
-    s = x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
     wf = w.astype(jnp.float32)
     out = b.astype(jnp.float32)
     for i in range(k):
-        out = out + padded[:, i: i + s] * wf[:, i]
-    return out.astype(x.dtype)
+        out = out + _shifted(x, k - 1 - i).astype(jnp.float32) * wf[:, i]
+    return out
+
+
+def _dsilu(pre: jax.Array) -> jax.Array:
+    """d silu(pre) / d pre."""
+    sig = jax.nn.sigmoid(pre)
+    return sig * (1.0 + pre * (1.0 - sig))
+
+
+def _conv_silu_plain(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    return jax.nn.silu(_conv_pre(x, w, b)).astype(x.dtype)
+
+
+def _conv_silu_bwd_plain(x, w, b, dy):
+    k = w.shape[1]
+    f32 = jnp.float32
+    dpre = dy.astype(f32) * _dsilu(_conv_pre(x, w, b))
+    wf = w.astype(f32)
+    dx = sum(_shifted(dpre, -(k - 1 - i)) * wf[:, i] for i in range(k)).astype(x.dtype)
+    dw = jnp.stack([jnp.sum(dpre * _shifted(x, k - 1 - i).astype(f32), axis=(0, 1)) for i in range(k)], axis=1)
+    return dx, dw, jnp.sum(dpre, axis=(0, 1))
+
+
+def _kernels():
+    """`ops/pallas/ssm_conv.py`, imported at first use like the other ops' kernels."""
+    from ray_tpu.ops.pallas import ssm_conv
+
+    return ssm_conv
+
+
+def _kernels_take(x: jax.Array, w: jax.Array) -> bool:
+    return _kernels().supported(x.shape[1], x.shape[2], w.shape[1])
+
+
+def _conv_silu_kernel(x, w, b):
+    return _kernels().conv_fwd(x.swapaxes(1, 2), w, b).swapaxes(1, 2)
+
+
+def _conv_silu_bwd_kernel(x, w, b, dy):
+    dx, dw, db = _kernels().conv_bwd(x.swapaxes(1, 2), w, b, dy.swapaxes(1, 2))
+    return dx.swapaxes(1, 2), dw, db
+
+
+def _by_platform(kernel, plain, x, w, *rest):
+    """Like attention, the form follows the platform a step is LOWERED for,
+    not the process's backend: the kernels for TPU at shapes they take, the
+    plain form everywhere else."""
+    if _kernels_take(x, w):
+        return jax.lax.platform_dependent(x, w, *rest, tpu=kernel, default=plain)
+    return plain(x, w, *rest)
+
+
+@jax.custom_vjp
+def _conv_silu_vjp(x, w, b):
+    return _by_platform(_conv_silu_kernel, _conv_silu_plain, x, w, b)
+
+
+def _conv_silu_fwd(x, w, b):
+    return _by_platform(_conv_silu_kernel, _conv_silu_plain, x, w, b), (x, w, b)
+
+
+def _conv_silu_bwd(res, dy):
+    x, w, b = res
+    dx, dw, db = _by_platform(_conv_silu_bwd_kernel, _conv_silu_bwd_plain, x, w, b, dy)
+    return dx, dw.astype(w.dtype), db.astype(b.dtype)
+
+
+_conv_silu_vjp.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def causal_conv1d_silu(x: jax.Array, w: jax.Array, b: jax.Array, mesh=None, batch_axes=None) -> jax.Array:
+    """SiLU of the causal depthwise convolution along the sequence: x [B, S, C],
+    w [C, K], b [C] -> [B, S, C] with `pre_t = b + sum_k w[:, k] * x_{t-(K-1)+k}`
+    and zeros before the sequence's start (so `w[:, K-1]` multiplies `x_t`, as
+    `torch.nn.Conv1d(groups=C, padding=K-1)` cut to S outputs has it), and
+    `y = pre * sigmoid(pre)`.  K shifted multiply-adds and the activation in
+    float32, rounded to x's dtype once.  One differentiable unit whose backward
+    is written by hand (module docstring).
+
+    mesh / batch_axes say how x is sharded (w and b are replicated).  GSPMD
+    partitions the plain form by itself; a Mosaic kernel it cannot, so with a
+    mesh the kernels run under shard_map over the batch axes, each device on
+    its own rows with the whole sequence."""
+    if mesh is None or not _kernels_take(x, w):
+        return _conv_silu_vjp(x, w, b)
+    spec = _fit_spec(x.shape, P(batch_axes, None, None), mesh)
+    return jax.shard_map(_conv_silu_vjp, mesh=mesh, in_specs=(spec, P(), P()), out_specs=spec, check_vma=False)(x, w, b)
 
 
 def ssd_chunked(
@@ -109,7 +217,7 @@ def ssd_chunked(
         decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
         cb = jnp.einsum("bctn,bcsn->bcts", Cc, Bc, preferred_element_type=f32)
         scores = (cb[:, :, None] * decay).astype(dtype)  # [b, c, h, t, s]
-        y = jnp.einsum("bchts,bcshp->bcthp", scores, dtx.astype(dtype), preferred_element_type=f32)
+        y = jnp.einsum("bchts,bcshp->bchtp", scores, dtx.astype(dtype), preferred_element_type=f32)
 
         # each chunk's contribution to the state at its own end: [b, c, h, p, n]
         to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # [b, c, l, h]
@@ -129,7 +237,9 @@ def ssd_chunked(
         entering = entering.transpose(1, 0, 2, 3, 4)  # [b, c, h, p, n]
 
         # the entering state read out at every position of the chunk
-        read = jnp.einsum("bctn,bchpn->bcthp", Cc.astype(f32), entering, preferred_element_type=f32)
-        y = y + jnp.exp(cum)[..., None] * read
-        y = y + D.astype(f32)[:, None] * xc.astype(f32)
-        return y.astype(dtype).reshape(b, s, h, p)
+        read = jnp.einsum("bctn,bchpn->bchtp", Cc.astype(f32), entering, preferred_element_type=f32)
+        y = y + jnp.exp(cum_h)[..., None] * read
+        y = y + D.astype(f32)[:, None, None] * xc.astype(f32).transpose(0, 1, 3, 2, 4)
+        # summed and rounded in the matmuls' own order; the relayout moves the ROUNDED array (module docstring)
+        y = y.astype(dtype).transpose(0, 1, 3, 2, 4).reshape(b, s, h * p)
+        return jax.lax.optimization_barrier(y).reshape(b, s, h, p)

@@ -37,6 +37,7 @@ from benchmarks.lib import reference_hybrid  # noqa: E402
 from ray_tpu.models import LMTrainContext, TransformerConfig  # noqa: E402
 from ray_tpu.models import transformer  # noqa: E402
 from ray_tpu.ops import ssm  # noqa: E402
+from ray_tpu.ops.pallas import ssm_conv  # noqa: E402
 from ray_tpu.parallel import MeshSpec, build_mesh  # noqa: E402
 
 SEQ = 96  # three chunks of 32, and not a multiple of 64
@@ -156,6 +157,17 @@ def test_every_gradient_leaf_equals_the_reference(tiny, leaf):
     close(got, want)
 
 
+def _primitives(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _primitives(inner)
+
+
 # -- the scan alone ---------------------------------------------------------------------
 
 
@@ -215,14 +227,101 @@ def test_ssd_chunked_refuses_a_ragged_last_chunk():
         ssm.ssd_chunked(*_scan_inputs(s=40), chunk=32)
 
 
-def test_causal_conv1d_is_four_shifted_adds():
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 10, 5))
-    w = jax.random.normal(jax.random.PRNGKey(1), (5, 4))
-    b = jax.random.normal(jax.random.PRNGKey(2), (5,))
-    got = np.asarray(ssm.causal_conv1d(x, w, b))
-    xp = np.concatenate([np.zeros((2, 3, 5), np.float32), np.asarray(x)], axis=1)
-    want = np.asarray(b) + sum(xp[:, k: k + 10] * np.asarray(w)[:, k] for k in range(4))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+def _four_shifted_adds(x, w, b):
+    """SiLU of `b + sum_k w[:, k] * x_{t-(K-1)+k}` over a zero-padded copy, float32:
+    the form `causal_conv1d_silu` had before PR 31, for NumPy or `jax.numpy`."""
+    xp = np if isinstance(x, np.ndarray) else jnp
+    k, s = w.shape[1], x.shape[1]
+    padded = xp.concatenate([xp.zeros_like(x[:, : k - 1]), x], axis=1)
+    pre = b + sum(padded[:, i: i + s] * w[:, i] for i in range(k))
+    return pre / (1.0 + xp.exp(-pre))
+
+
+# (S, C): the first three are shapes only the plain form takes (S no multiple of 128); the
+# last two the Pallas pair takes when a step is lowered for TPU, and here, on the CPU, the
+# plain form behind `platform_dependent` (the kernels themselves: the next test)
+CONV_SHAPES = [(10, 5), (64, 128), (70, 256), (256, 16), (4096, 32)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("s, c", CONV_SHAPES)
+def test_causal_conv1d_is_four_shifted_adds(s, c, dtype):
+    """Forward and the three gradients of the hand-written backward against
+    the four shifted adds and `jax.grad` of them, on the same (rounded) inputs
+    in float32.  The first and last K - 1 positions are where a shift that
+    wraps, or a halo taken from the wrong side, would show."""
+    k = 4
+    keys = jax.random.split(jax.random.PRNGKey(s + c), 4)
+    x, dy = (jax.random.normal(key, (2, s, c)).astype(dtype) for key in (keys[0], keys[3]))
+    w = (0.5 * jax.random.normal(keys[1], (c, k))).astype(dtype)
+    b = (0.5 * jax.random.normal(keys[2], (c,))).astype(dtype)
+    assert ssm_conv.supported(s, c, k) == (s >= 256)
+    got, vjp = jax.vjp(ssm.causal_conv1d_silu, x, w, b)
+    grads = vjp(dy)
+    assert got.dtype == dtype and [g.dtype for g in grads] == [dtype] * 3
+    f32 = [np.asarray(v.astype(jnp.float32)) for v in (x, w, b)]
+    want = _four_shifted_adds(*f32)
+    want_grads = jax.grad(lambda *a: jnp.sum(_four_shifted_adds(*a) * dy.astype(jnp.float32)), argnums=(0, 1, 2))(
+        *map(jnp.asarray, f32))
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2  # bf16: one rounding of each result (2**-8 of its scale)
+
+    def same(a, b_, name):
+        a, b_ = np.asarray(a.astype(jnp.float32)), np.asarray(b_)
+        np.testing.assert_allclose(a, b_, rtol=tol, atol=tol * np.abs(b_).max(), err_msg=name)
+
+    same(got[:, : k - 1], want[:, : k - 1], "y, the first K-1 positions")
+    same(got[:, -(k - 1):], want[:, -(k - 1):], "y, the last K-1 positions")
+    same(got, want, "y")
+    same(grads[0][:, : k - 1], want_grads[0][:, : k - 1], "dx, the first K-1 positions")
+    same(grads[0][:, -(k - 1):], want_grads[0][:, -(k - 1):], "dx, the last K-1 positions")
+    for g, wg, name in zip(grads, want_grads, ("dx", "dw", "db")):
+        same(g, wg, name)
+
+
+@pytest.mark.parametrize("s, c, rows, lanes, dtype", [
+    (512, 48, 16, 128, jnp.float32), (512, 48, 32, 256, jnp.float32),
+    (4096, 32, None, None, jnp.float32), (4096, 32, None, None, jnp.bfloat16),
+], ids=["3x4-blocks", "2x2-blocks", "default-blocks", "default-blocks-bfloat16"])
+def test_each_conv_kernel_equals_the_four_shifted_adds_across_its_halos(s, c, rows, lanes, dtype):
+    """`ssm_conv_fwd` / `ssm_conv_bwd` in interpret mode: with small blocks
+    every block but the first and last has a neighbour on both sides; the
+    default blocks cut 4,096 positions into two."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    x, dy = (jax.random.normal(key, (2, s, c)).astype(dtype) for key in (keys[0], keys[3]))
+    w, b = jax.random.normal(keys[1], (c, 4)).astype(dtype), jax.random.normal(keys[2], (c,)).astype(dtype)
+    want, vjp = jax.vjp(_four_shifted_adds, *(v.astype(jnp.float32) for v in (x, w, b)))
+    blocks = dict(rows=rows, lanes=lanes, interpret=True)
+    got = ssm_conv.conv_fwd(x.swapaxes(1, 2), w, b, **blocks).swapaxes(1, 2)
+    dx, dw, db = ssm_conv.conv_bwd(x.swapaxes(1, 2), w, b, dy.swapaxes(1, 2), **blocks)
+    assert got.dtype == dx.dtype == dtype and dw.dtype == db.dtype == jnp.float32
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    close(got.astype(jnp.float32), want, rtol=tol)
+    for g, wg in zip((dx.swapaxes(1, 2), dw, db), vjp(dy.astype(jnp.float32))):
+        close(g.astype(jnp.float32), wg, rtol=tol)
+
+
+def test_a_mamba_layer_reaches_the_convolution_through_one_hand_written_backward(tiny):
+    """The defect PR 31 removed cannot return by an innocent edit: the traced
+    layer, forward and differentiated, pads nothing along the sequence (the
+    padded float32 copy; the transpose of a shifted slice is a pad, one
+    cotangent array per tap) and calls the convolution once, as the
+    `custom_vjp` whose backward is `ops/ssm.py`'s own."""
+    cfg = tiny["cfg"]
+    layer = jax.tree_util.tree_map(lambda a: a[0], tiny["params"]["mamba_layers"])
+    x = jnp.zeros((2, SEQ, cfg.d_model))
+
+    def run(p, x):
+        return transformer._mamba_layer(x, p, None, cfg, None)[0]
+
+    forward = jax.make_jaxpr(run)(layer, x).jaxpr
+    backward = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(run(p, x)), argnums=(0, 1)))(layer, x).jaxpr
+    for jaxpr, calls in ((forward, [ssm._conv_silu_bwd, transformer._dense_ffn_bwd]), (backward, [])):
+        eqns = list(_primitives(jaxpr))
+        along_sequence = [e for e in eqns if e.primitive.name == "pad" and len(e.params["padding_config"]) == 3
+                          and tuple(e.params["padding_config"][1]) != (0, 0, 0)]
+        assert not along_sequence
+        # the mixer's one call, then the FFN's; differentiated, each is its own forward and backward, inlined
+        assert [e.params["bwd"].f for e in eqns if e.primitive.name == "custom_vjp_call"] == calls
 
 
 @pytest.mark.parametrize("t", [0, 31, 32, 70])
@@ -283,15 +382,7 @@ def test_pp_refuses_a_hybrid_stack():
 
 
 def _equations(jaxpr) -> int:
-    total = 0
-    for eqn in jaxpr.eqns:
-        total += 1
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (list, tuple)) else [value]:
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    total += _equations(inner)
-    return total
+    return sum(1 for _ in _primitives(jaxpr))
 
 
 EXPERT = dict(n_heads=4, n_kv_heads=4, d_ff=32, n_experts=8, experts_per_token=2, qk_norm=True,
@@ -331,6 +422,26 @@ def test_a_dense_models_weights_for_a_seed_did_not_move():
 
 
 # -- across devices --------------------------------------------------------------------------
+
+
+def test_conv_kernels_under_shard_map_equal_the_single_device_result():
+    """At shapes the kernels take, a mesh puts the convolution under shard_map
+    (GSPMD cannot partition a Mosaic call; on the CPU its body is the plain
+    form): batch over `data`, replicated over `tensor`, and the weight
+    gradients summed over the shards."""
+    mesh = build_mesh(MeshSpec(data=2, tensor=2), devices=jax.devices()[:4])
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    x, dy = (jax.random.normal(key, (4, 256, 32)) for key in (keys[0], keys[3]))
+    w, b = jax.random.normal(keys[1], (32, 4)), jax.random.normal(keys[2], (32,))
+    assert ssm_conv.supported(256, 32, 4)
+
+    def objective(x, w, b, **sharded):
+        return jnp.sum(ssm.causal_conv1d_silu(x, w, b, **sharded) * dy)
+
+    want = jax.grad(objective, argnums=(0, 1, 2))(x, w, b)
+    got = jax.jit(jax.grad(lambda *a: objective(*a, mesh=mesh, batch_axes=("data", "fsdp")), argnums=(0, 1, 2)))(x, w, b)
+    for g, wg in zip(got, want):
+        close(g, wg, rtol=1e-5)
 
 
 def test_fsdp4_step_equals_the_single_device_step(tiny):

@@ -5,6 +5,7 @@ only those names differ."""
 
 import ast
 import os
+import re
 import subprocess
 import sys
 
@@ -162,6 +163,26 @@ def test_lowered_hybrid_step_holds_each_flash_kernel_exactly_once(hybrid_lowered
     calls = [line for line in hybrid_lowered_for_tpu.splitlines() if "@tpu_custom_call" in line
              and f'kernel_name = "{kernel}"' in line]
     assert len(calls) == 1  # one attention layer, its forward saved (`qkv_attn`)
+
+
+# What PR 31 added to the Mamba-2 layer, as (the op's own name, the scope it must sit in): the
+# convolution's kernels and the barrier that pins the scan's output to bf16 before its relayout.
+SSM_OPS = {"ssm_conv_fwd/pallas_call": "layer/attn_proj/ssm/conv", "ssm_conv_bwd/pallas_call": "layer/attn_proj/ssm/conv",
+           "optimization_barrier": "layer/attn_core/ssm/scan"}
+
+
+@pytest.mark.parametrize("op", SSM_OPS)
+def test_lowered_hybrid_step_keeps_the_mixers_new_ops_inside_its_scopes(hybrid_lowered_for_tpu, op):
+    """Every location of these ops in a layer's mixer is under the `ssm/*`
+    name of `SSM_SCOPES`, forward and recompute (the backward kernel: in the
+    backward), so `unscoped_time_pct` and the identity of PERF.md section 3 hold."""
+    outer, _, scope = SSM_OPS[op].partition("/ssm/")
+    assert SSM_SCOPES["ssm/" + scope] == outer
+    paths = [path for path in re.findall(r'#loc\d+ = loc\("([^"]+)"', hybrid_lowered_for_tpu)
+             if path.endswith(op) and "layer/attn_" in path]
+    assert paths and all(f"{SSM_OPS[op]}/" in path for path in paths)
+    directions = {path.split("layer/")[0] for path in paths}
+    assert directions >= ({"checkpoint/"} if "bwd" in op else {"", "checkpoint/rematted_computation/"})
 
 
 # -- tracing.annotate -------------------------------------------------------------------

@@ -99,9 +99,14 @@ HYBRID = TransformerConfig.tiny(
     ids=["dp1", "fsdp4", "tp4"],
 )
 def test_hybrid_step_lowers_for_tpu_with_each_flash_kernel_once(n_devices, spec, strategy):
-    """One attention layer, so each kernel exactly once, at head size 64;
-    the scan is XLA's to partition (under `tp` its heads are replicated)."""
+    """One attention layer, so each flash kernel exactly once, at head size 64;
+    the scan is XLA's to partition (under `tp` its heads are replicated).  The
+    Mamba-2 layers lie in two runs, each one scan body: per run the
+    convolution's forward kernel twice (forward, and the recompute of a layer
+    that keeps its input only) and its backward kernel once, under shard_map
+    on a mesh of more than one device like the flash kernels."""
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=HYBRID)
-    assert _mosaic_kernels(text) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+    assert _mosaic_kernels(text) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                                     "ssm_conv_fwd": 4, "ssm_conv_bwd": 2}
     call = next(line for line in text.splitlines() if "@tpu_custom_call" in line and 'kernel_name = "flash_fwd"' in line)
     assert re.search(r"tensor<\d+x\d+x128x64xf32>", call)  # q: [batch, heads, seq, 64] (float32 in this tiny config)
