@@ -146,6 +146,16 @@ def _detect_tpu_chips() -> int:
     return len(groups)
 
 
+def _exited(pid: Optional[int]) -> bool:
+    """No such process, or a zombie: whatever it held (device files, host
+    memory) is released, reaped or not."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            return f.read().rsplit(")", 1)[1].split()[0] in ("Z", "X")
+    except (OSError, IndexError):
+        return True
+
+
 class _PopenHandle:
     """subprocess.Popen adapter exposing the mp.Process surface the runtime
     uses (terminate/join/is_alive/pid)."""
@@ -6280,6 +6290,16 @@ class Runtime:
                 h.proc.join(remaining)
             except Exception:
                 pass
+        # A worker that held TPU chips releases them while it EXITS (a reset
+        # per chip, its pinned host memory): seconds on a four-chip host,
+        # during which the next process to open the chips fails with "Device
+        # or resource busy".  So shutdown returns when this host's workers
+        # are gone, not when they were told to go.
+        deadline = time.monotonic() + 30.0
+        for h in list(self.workers.values()):
+            if isinstance(h.proc, (_PopenHandle, _ZygoteProcHandle)):
+                while not _exited(h.proc.pid) and time.monotonic() < deadline:
+                    time.sleep(0.05)
         self.store.destroy()
         global _runtime
         _runtime = None

@@ -9,6 +9,7 @@ from ray_tpu.models.lm import (
     LMTrainContext,
     cross_entropy_loss,
     default_optimizer,
+    head_cross_entropy,
 )
 from ray_tpu.models.transformer import (
     TransformerConfig,
@@ -23,6 +24,7 @@ __all__ = [
     "cross_entropy_loss",
     "default_optimizer",
     "forward",
+    "head_cross_entropy",
     "init_params",
     "param_axes",
 ]

@@ -18,11 +18,13 @@ import optax
 
 from ray_tpu.models.moe import router_losses
 from ray_tpu.models.transformer import (
+    LOGITS_AXES,
     TransformerConfig,
+    _constrainer,
     forward,
-    forward_with_router_stats,
     init_params,
     param_axes,
+    trunk,
 )
 from ray_tpu.parallel.mesh import build_mesh
 from ray_tpu.parallel.sharding import (
@@ -39,13 +41,81 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 def cross_entropy_loss(
     logits: jax.Array, targets: jax.Array, mask: Optional[jax.Array] = None
 ) -> jax.Array:
-    """Mean next-token cross entropy. logits [B,S,V] f32, targets [B,S]."""
+    """Mean next-token cross entropy of logits in hand: logits [B,S,V] (any
+    float dtype; the softmax runs in theirs), targets [B,S], mask [B,S] or
+    None.  The plain form: differentiated by JAX it keeps a [B,S,V]
+    log-softmax and scatters into a [B,S,V] zero tensor.  A training step
+    uses `head_cross_entropy`, which this is the reference of."""
     logp = jax.nn.log_softmax(logits, axis=-1)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     if mask is None:
         return -jnp.mean(ll)
     mask = mask.astype(jnp.float32)
     return -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def head_cross_entropy(constrain, x, head, targets, mask):
+    """`cross_entropy_loss(einsum(x, head).astype(f32), targets, mask)` as ONE
+    function with its backward written by hand: x [B,S,d] and head [d,V] in
+    the model's dtype (`transformer.trunk`'s), targets [B,S], mask [B,S] or
+    None -> the float32 scalar.
+
+    The only [B,S,V] arrays that reach HBM are in x's dtype: the logits (the
+    matmul's result, rounded as `forward` rounds it) and, in the backward,
+    their cotangent.  Row max, log-sum-exp, the softmax and the loss are
+    float32 inside the passes that read them; the target's logit is picked by
+    compare-and-select against the column index (a gather's transpose is a
+    scatter into a [B,S,V] zero tensor).  Residuals: x, head, the logits, the
+    float32 [B,S] log-sum-exp.  `constrain` places the logits and their
+    cotangent as the sharding rules say (`transformer._constrainer`).  The
+    matmuls are named `lm_head`, the passes `loss`, in both directions."""
+    return _head_cross_entropy_fwd(constrain, x, head, targets, mask)[0]
+
+
+def _target_columns(logits, targets):
+    """bool [B,S,V]: the target's column in each row."""
+    return jax.lax.broadcasted_iota(targets.dtype, logits.shape, 2) == targets[..., None]
+
+
+def _row_weights(targets, mask):
+    """float32 [B,S]: what a row's negative log-likelihood weighs in the loss."""
+    if mask is None:
+        return jnp.full(targets.shape, 1.0 / targets.size, jnp.float32)
+    mask = mask.astype(jnp.float32)
+    return mask / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def _head_cross_entropy_fwd(constrain, x, head, targets, mask):
+    with jax.named_scope("lm_head"):
+        logits = constrain(jnp.einsum("bse,ev->bsv", x, head), LOGITS_AXES)
+    with jax.named_scope("loss"):
+        wide = logits.astype(jnp.float32)
+        row_max = jnp.max(wide, axis=-1)
+        lse = row_max + jnp.log(jnp.sum(jnp.exp(wide - row_max[..., None]), axis=-1))
+        target_logit = jnp.sum(jnp.where(_target_columns(logits, targets), wide, 0.0), axis=-1)
+        loss = jnp.sum((lse - target_logit) * _row_weights(targets, mask))
+    return loss, (x, head, logits, lse, targets, mask)
+
+
+def _head_cross_entropy_bwd(constrain, residuals, g):
+    x, head, logits, lse, targets, mask = residuals
+    with jax.named_scope("loss"):
+        # Not behind a barrier: XLA fuses this pass into the operand of both
+        # matmuls below (the softmax computed twice, in registers) and never
+        # writes the cotangent, which measured 3.9 ms a step faster than
+        # writing it once (granite-h-micro's cell, PERF.md section 6, PR 34).
+        softmax = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+        dlogits = jnp.where(_target_columns(logits, targets), softmax - 1.0, softmax)
+        dlogits = dlogits * (g * _row_weights(targets, mask))[..., None]
+        dlogits = constrain(dlogits.astype(logits.dtype), LOGITS_AXES)
+    with jax.named_scope("lm_head"):
+        dx = jnp.einsum("bsv,ev->bse", dlogits, head)
+        dhead = jnp.einsum("bse,bsv->ev", x, dlogits)
+    return dx, dhead, None, None
+
+
+head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
 
 
 def default_optimizer(
@@ -130,10 +200,11 @@ class LMTrainContext:
             cross entropy and no terms.  With experts: cross entropy +
             `router_aux_loss_coef` * load balancing + `router_z_loss_coef` *
             z-loss (formulas in models/moe.py), and the terms unweighted."""
-            logits, router_stats = forward_with_router_stats(
+            x, head, router_stats = trunk(
                 params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
+            ce = head_cross_entropy(
+                _constrainer(rules, self.mesh), x, head, batch["targets"], batch.get("mask"))
             with jax.named_scope("loss"):
-                ce = cross_entropy_loss(logits, batch["targets"], batch.get("mask"))
                 if router_stats is None:
                     return ce, {}
                 terms = router_losses(router_stats, cfg)

@@ -68,6 +68,9 @@ from ray_tpu.ops.ssm import causal_conv1d_silu, ssd_chunked
 from ray_tpu.parallel.sharding import Rules, with_logical_constraint
 
 
+# The logical axes of the logits (and of their cotangent).
+LOGITS_AXES = ("act_batch", "act_seq", "act_vocab")
+
 # The kinds of layer, and the subtree of the parameters that stacks each.
 LAYER_KINDS = {"attention": "layers", "mamba": "mamba_layers"}
 
@@ -94,10 +97,12 @@ class TransformerConfig:
     # post-rope q/k/v (skips qkv matmul + rope recompute).  More saved =
     # more HBM.  A Mamba-2 layer names nothing: under every policy it keeps
     # its input and recomputes the rest (saving z, the convolved x|B|C and dt
-    # as well costs 1.25 GiB at granite-h-micro's cell, which pushes XLA into
-    # duplicating the lm_head's forward: PERF.md section 6, PR 30).  XLA's own rematerialization may still duplicate work when
-    # the step compiles over libtpu's limit; `_dense_ffn`'s tie is why the
-    # dense FFN's matmuls are no longer among it.
+    # as well costs 1.25 GiB at granite-h-micro's cell, which that step has
+    # to spare now that the loss path holds one bf16 [tokens, vocab] array:
+    # PERF.md section 7).  XLA's own rematerialization duplicates work when a
+    # step compiles over libtpu's limit (none of the cells does since
+    # `head_cross_entropy`); `_dense_ffn`'s tie keeps the dense FFN's matmuls
+    # out of it.
     remat_policy: Optional[str] = None
     attention_impl: Optional[str] = None  # None=auto, see ops.attention
     # Microbatches per pipeline-stage schedule when the rules shard the
@@ -689,14 +694,19 @@ def forward(
     rules: Optional[Rules] = None,
     mesh=None,
 ) -> jax.Array:
-    """Token ids [B, S] -> logits [B, S, vocab] (f32).
+    """Token ids [B, S] -> logits [B, S, vocab] (f32): `trunk`, then the
+    head's matmul in the model's dtype, widened.  (The training objective
+    never forms these: `lm.head_cross_entropy` takes the trunk's output.)
 
     `rules` come with the `mesh` they refer to: ring attention, the pipeline
     schedule and the flash kernel's shard_map are all built from it."""
-    return forward_with_router_stats(params, tokens, config, rules=rules, mesh=mesh)[0]
+    x, head, _ = trunk(params, tokens, config, rules=rules, mesh=mesh)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
+        return _constrainer(rules, mesh)(logits, LOGITS_AXES)
 
 
-def forward_with_router_stats(
+def trunk(
     params: Dict,
     tokens: jax.Array,
     config: TransformerConfig,
@@ -704,9 +714,11 @@ def forward_with_router_stats(
     rules: Optional[Rules] = None,
     mesh=None,
 ):
-    """`forward` plus what a training objective needs beside the logits:
-    (logits, router statistics stacked over the layers, `[L, ...]` each, as
-    `moe.router_losses` takes them; None for a dense model)."""
+    """Everything up to the head's matmul: (the rows that enter the head,
+    [B, S, d] in `config.dtype`, normed and divided by `logits_scaling`; the
+    head [d, vocab] in `config.dtype`, the embedding table transposed when
+    tied; router statistics stacked over the layers, `[L, ...]` each, as
+    `moe.router_losses` takes them, None for a dense model)."""
     c = config
     if rules is not None and mesh is None:
         raise ValueError("forward(rules=...) needs the mesh the rules refer to")
@@ -811,11 +823,6 @@ def forward_with_router_stats(
             # the head and not to the [B, S, vocab] logits that leave it: the
             # same function (a linear map commutes with a scalar; a power of
             # two, as published, does not even move a rounding) without a
-            # pass over the float32 logits.
+            # pass over the logits.
             x = x / jnp.asarray(c.logits_scaling, x.dtype)
-        logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
-        if rules is not None:
-            logits = with_logical_constraint(
-                logits, ("act_batch", "act_seq", "act_vocab"), rules, mesh
-            )
-    return logits, router_stats
+    return x, head, router_stats

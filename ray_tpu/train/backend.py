@@ -58,6 +58,33 @@ def _pick_coordinator(port: int) -> str:
     return pick_coordinator_address(port)
 
 
+def _wait_for_chips(timeout_s: float = 60.0) -> float:
+    """Block until the chips this host hands out (`/dev/vfio/<group>`) can be
+    opened; returns the seconds waited.  A process that held them releases
+    them while it EXITS (a reset per chip, its pinned host memory): seconds
+    on a four-chip host, during which libtpu's open gets EBUSY and fails the
+    whole backend ("Couldn't open iommu group").  A job that starts right
+    after another one ended must outwait that, not die of it.  Opening and
+    closing a group file claims nothing.  Anything but EBUSY is left for
+    libtpu to report."""
+    import errno
+    import glob
+    import os
+    import time
+
+    start = time.monotonic()
+    for path in glob.glob("/dev/vfio/[0-9]*"):
+        while True:
+            try:
+                os.close(os.open(path, os.O_RDWR))
+            except OSError as e:
+                if e.errno == errno.EBUSY and time.monotonic() - start < timeout_s:
+                    time.sleep(0.25)
+                    continue
+            break
+    return time.monotonic() - start
+
+
 def _init_jax_distributed(coordinator: str, world_size: int, rank: int, platform):
     import os
 
@@ -78,6 +105,12 @@ def _init_jax_distributed(coordinator: str, world_size: int, rank: int, platform
             num_processes=world_size,
             process_id=rank,
         )
+    if platform == "tpu":
+        waited = _wait_for_chips()
+        if waited > 1.0:
+            import sys
+
+            print(f"[ray_tpu] waited {waited:.1f} s for another process to release the chips", file=sys.stderr)
     with tracing.span("train::backend::device_open"):
         global_devices = len(jax.devices())
     return {

@@ -389,11 +389,12 @@ EXPERT = dict(n_heads=4, n_kv_heads=4, d_ff=32, n_experts=8, experts_per_token=2
               router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
 
 
-@pytest.mark.parametrize("kw, equations", [({}, 710), (EXPERT, 2904)], ids=["dense", "expert"])
+@pytest.mark.parametrize("kw, equations", [({}, 700), (EXPERT, 2894)], ids=["dense", "expert"])
 def test_a_dense_and_an_expert_step_trace_to_the_parents_program(kw, equations):
     """Counted at the parent of PR 30 with this function (the dense count is
-    `tests/test_moe_model.py`'s 709 + 1): the new fields' defaults add no
-    equation, no slice of the stack and nothing of the scan."""
+    `tests/test_moe_model.py`'s 709 + 1 - 10; 710 / 2904 before PR 34's
+    `head_cross_entropy`): the new fields' defaults add no equation, no slice
+    of the stack and nothing of the scan."""
     ctx = one_device_ctx(TransformerConfig.tiny(**kw))
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)

@@ -21,9 +21,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCOPES = ("embed", "layers", "layer/attn_proj", "layer/attn_core", "layer/mlp", "final_norm", "lm_head",
           "loss", "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+VOCAB = 384  # unlike every other width below, so a `[batch, 128, VOCAB]` type is the logits' or their cotangent's
 CFG = TransformerConfig.tiny(
     n_heads=2, n_kv_heads=1, d_model=256, d_ff=256, max_seq_len=128,
-    remat=True, remat_policy="qkv_attn",
+    remat=True, remat_policy="qkv_attn", vocab_size=VOCAB,
 )
 
 
@@ -68,6 +69,7 @@ MOE_KERNELS = ("moe_gmm", "moe_tgmm")
 MOE_CFG = TransformerConfig.tiny(
     n_heads=2, n_kv_heads=2, d_model=256, d_ff=128, max_seq_len=128, remat=True, remat_policy="qkv_attn",
     n_experts=8, experts_per_token=2, qk_norm=True, router_aux_loss_coef=0.01, router_z_loss_coef=0.001,
+    vocab_size=VOCAB,
 )
 
 
@@ -130,7 +132,7 @@ HYBRID_CFG = TransformerConfig.tiny(
     n_layers=4, n_heads=4, n_kv_heads=1, d_model=256, d_ff=256, max_seq_len=128, remat=True, remat_policy="qkv_attn",
     tie_embeddings=True, rope_theta=None, layer_types=("mamba", "mamba", "attention", "mamba"),
     ssm_heads=8, ssm_head_dim=64, ssm_state=128, embedding_multiplier=12.0, residual_multiplier=0.22,
-    logits_scaling=8.0, attention_scale=1 / 64,
+    logits_scaling=8.0, attention_scale=1 / 64, vocab_size=VOCAB,
 )
 
 
@@ -183,6 +185,51 @@ def test_lowered_hybrid_step_keeps_the_mixers_new_ops_inside_its_scopes(hybrid_l
     assert paths and all(f"{SSM_OPS[op]}/" in path for path in paths)
     directions = {path.split("layer/")[0] for path in paths}
     assert directions >= ({"checkpoint/"} if "bwd" in op else {"", "checkpoint/rematted_computation/"})
+
+
+# -- the head and the cross entropy (PERF.md section 3, PR 34) -------------------------------
+
+
+def _paths_of_tokens_by_vocab_ops(text):
+    """The path of every op of the step's main function that takes or gives
+    a `[batch, 128, VOCAB]` tensor."""
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, flags=re.M))
+    paths, in_main = [], False
+    for line in text.splitlines():
+        if line.lstrip().startswith("func.func"):
+            in_main = "public @main" in line
+        elif in_main and re.search(rf"tensor<\d+x128x{VOCAB}x", line) and " = " in line:
+            ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
+            paths.append(locs[ref.group(1)] if ref else line)
+    return paths
+
+
+def _head_and_loss_are_named_in_both_directions(lowered):
+    """`head_cross_entropy`'s backward is written by hand, so its names are
+    too: every op over `[tokens, vocab]` sits under `lm_head` (the three
+    matmuls) or `loss` (the passes), forward and `transpose(` side, and none
+    is a scatter (the plain form's `take_along_axis`, transposed)."""
+    paths = _paths_of_tokens_by_vocab_ops(lowered)
+    assert paths and all(re.search(r"[(/](lm_head|loss)[)/]", path) for path in paths), paths
+    assert not any("scatter" in path for path in paths)
+    for name, ops in {"lm_head": 1, "loss": 3}.items():
+        fwd = [p for p in paths if f"jvp({name})" in p and "transpose(" not in p]
+        bwd = [p for p in paths if f"transpose(jvp({name}))" in p]
+        assert len(fwd) >= ops and len(bwd) >= ops
+    matmuls = sorted(p.split("/")[-2] for p in paths if p.endswith("dot_general"))
+    assert matmuls == ["bse,bsv->ev", "bse,ev->bsv", "bsv,ev->bse"]
+
+
+def test_dense_steps_head_and_loss_ops_carry_their_names_in_both_directions(lowered_for_tpu):
+    _head_and_loss_are_named_in_both_directions(lowered_for_tpu)
+
+
+def test_expert_steps_head_and_loss_ops_carry_their_names_in_both_directions(moe_lowered_for_tpu):
+    _head_and_loss_are_named_in_both_directions(moe_lowered_for_tpu)
+
+
+def test_hybrid_steps_head_and_loss_ops_carry_their_names_in_both_directions(hybrid_lowered_for_tpu):
+    _head_and_loss_are_named_in_both_directions(hybrid_lowered_for_tpu)
 
 
 # -- tracing.annotate -------------------------------------------------------------------

@@ -1,0 +1,113 @@
+"""The train step COMPILED for a described TPU v5e (libtpu, no chip), read as
+optimized HLO: which arrays reach HBM.  The lowered StableHLO cannot say (a
+float32 value inside a fusion and a float32 buffer look alike there); after
+fusion, an instruction outside every fused computation is a buffer.
+
+The topology is described inside a fixture and only here (one process may
+hold libtpu; see `.claude/skills/verify/SKILL.md` item 3 for the same compile
+by hand)."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import LMTrainContext, TransformerConfig
+from ray_tpu.parallel import MeshSpec, build_mesh
+
+B, S, V = 8, 128, 384  # V unlike every other width of the configs below
+COMMON = dict(max_seq_len=128, remat=True, remat_policy="qkv_attn", vocab_size=V, dtype=jnp.bfloat16)
+CONFIGS = {
+    "dense": dict(n_heads=2, n_kv_heads=1, d_model=256, d_ff=256),
+    "expert": dict(n_heads=2, n_kv_heads=2, d_model=256, d_ff=128, n_experts=8, experts_per_token=2, qk_norm=True,
+                   router_aux_loss_coef=0.01, router_z_loss_coef=0.001),
+    "hybrid": dict(n_layers=4, n_heads=4, n_kv_heads=1, d_model=256, d_ff=256, tie_embeddings=True, rope_theta=None,
+                   layer_types=("mamba", "mamba", "attention", "mamba"), ssm_heads=8, ssm_head_dim=64, ssm_state=128,
+                   embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=8.0, attention_scale=1 / 64),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module", params=list(CONFIGS))
+def compiled_step(request, topo):
+    """Optimized HLO of the step on one described chip.  The persistent
+    compile cache is off around it: an entry written without a chip cannot be
+    read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cfg = TransformerConfig.tiny(**COMMON, **CONFIGS[request.param])
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=topo.devices[:1]), strategy="dp")
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=ctx.batch_sharding)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with ctx.mesh:
+            return ctx._train_step.lower(state, {"tokens": toks, "targets": toks}).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _buffers(hlo):
+    """(name, shape, opcode, op_name) of every instruction outside a fused
+    computation and outside the scalar regions of reduces and scatters."""
+    found, inside = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        if inside is None or inside.startswith(("fused_computation", "region_")):
+            continue
+        inst = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(", line)
+        if inst:
+            path = re.search(r'op_name="([^"]*)"', line)
+            found.append((inst.group(1), inst.group(2), inst.group(3), path.group(1) if path else ""))
+    return found
+
+
+TOKENS_BY_VOCAB = re.compile(rf"(\w+)\[(?:1,)?(?:{B},{S}|{B * S}),{V}\]")  # group 1: the element type
+
+
+def test_the_reader_sees_buffers_and_not_fused_values(compiled_step):
+    buffers = _buffers(compiled_step)
+    assert len(buffers) > 100 and any(op == "fusion" for _, _, op, _ in buffers)
+    assert "f32" in TOKENS_BY_VOCAB.findall(compiled_step)  # the float32 softmax exists: inside fusions
+
+
+def test_no_float32_tokens_by_vocab_array_reaches_hbm(compiled_step):
+    """The parent's step held three: the float32 log-softmax, the
+    scatter-add's zero tensor and its reshape copy."""
+    wide = [(name, shape) for name, shape, _, _ in _buffers(compiled_step) if "f32" in TOKENS_BY_VOCAB.findall(shape)]
+    assert wide == []
+
+
+def test_every_tokens_by_vocab_array_is_the_models_dtype_and_named_lm_head_or_loss(compiled_step):
+    arrays = [(name, shape, path) for name, shape, op, path in _buffers(compiled_step)
+              if TOKENS_BY_VOCAB.search(shape) and op not in ("get-tuple-element", "bitcast")]
+    assert arrays
+    for name, shape, path in arrays:
+        assert set(TOKENS_BY_VOCAB.findall(shape)) == {"bf16"}, (name, shape)
+        assert re.search(r"[(/](lm_head|loss)[)/]", path), (name, path)
+
+
+def test_nothing_under_lm_head_or_loss_scatters(compiled_step):
+    assert "scatter" in compiled_step  # the embedding's backward
+    for line in compiled_step.splitlines():
+        path = re.search(r'op_name="([^"]*)"', line)
+        if path and re.search(r"[(/](lm_head|loss)[)/]", path.group(1)):
+            assert "scatter" not in path.group(1) and " scatter(" not in line

@@ -46,17 +46,17 @@ def _mosaic_kernels(text):
     )
 
 
-@pytest.mark.parametrize(
-    "n_devices,spec,strategy",
-    [
-        (1, MeshSpec(data=1), "dp"),
-        (4, MeshSpec(data=4), "dp"),
-        (4, MeshSpec(data=1, fsdp=4), "fsdp"),
-        (4, MeshSpec(data=1, fsdp=2, tensor=2), "fsdp_tp"),
-        (4, MeshSpec(data=2, tensor=2), "tp"),
-    ],
-    ids=["dp1", "dp4", "fsdp4", "fsdp_tp4", "tp4"],
-)
+MESHES = [
+    (1, MeshSpec(data=1), "dp"),
+    (4, MeshSpec(data=4), "dp"),
+    (4, MeshSpec(data=1, fsdp=4), "fsdp"),
+    (4, MeshSpec(data=1, fsdp=2, tensor=2), "fsdp_tp"),
+    (4, MeshSpec(data=2, tensor=2), "tp"),
+]
+MESH_IDS = ["dp1", "dp4", "fsdp4", "fsdp_tp4", "tp4"]
+
+
+@pytest.mark.parametrize("n_devices,spec,strategy", MESHES, ids=MESH_IDS)
 def test_train_step_lowers_for_tpu_with_kernel(n_devices, spec, strategy):
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",))
     # `qkv_attn` saves the residuals the kernel names, so the forward kernel
@@ -65,6 +65,24 @@ def test_train_step_lowers_for_tpu_with_kernel(n_devices, spec, strategy):
     # The dense FFN's four cotangents reach the TPU compiler through one
     # barrier (`_dense_ffn`); the checkpoint's own barrier is far wider.
     assert len(re.findall(r"%\d+:4 = stablehlo.optimization_barrier", text)) == 1
+
+
+@pytest.mark.parametrize("n_devices,spec,strategy", MESHES, ids=MESH_IDS)
+def test_logits_cotangent_is_placed_like_the_logits_and_nothing_scatters_into_them(n_devices, spec, strategy):
+    """`head_cross_entropy` writes its own backward, so GSPMD has no forward
+    op to copy the cotangent's placement from: the same constraint is on both
+    (without it `fsdp` could gather `[tokens, vocab]`).  In bf16, as the
+    cells run: three matmuls over the logits' shape, and no scatter into it
+    (the plain cross entropy's `take_along_axis`, transposed)."""
+    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16, vocab_size=384)
+    text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=cfg)
+    placed = re.findall(r"sdy.sharding_constraint %\S+ (<@mesh, \[.*?\]>) : tensor<8x128x384xbf16>", text)
+    assert len(placed) == 2 and placed[0] == placed[1]
+    if strategy in ("tp", "fsdp_tp"):
+        assert '{"tensor"}' in placed[0]  # the vocabulary over `tensor`
+    assert len([line for line in text.splitlines() if "stablehlo.dot_general" in line and "8x128x384xbf16" in line]) == 3
+    assert not [line for line in text.splitlines() if "stablehlo.scatter" in line and "8x128x384x" in line]
+    assert "8x128x384xf32>) -> tensor<8x128x384xbf16>" in text  # the cotangent is narrowed before the matmuls
 
 
 def test_full_recompute_reruns_the_forward_kernel():
