@@ -95,9 +95,15 @@ def init(
             log_to_driver=log_to_driver,
         )
         return
-    runtime = rt.init_runtime(
-        num_cpus=num_cpus, resources=resources, namespace=namespace
-    )
+    from ray_tpu.util import tracing
+
+    # A lifecycle span (util/tracing.py): recorded whether tracing is on or
+    # not, and `runtime::shutdown` joins its trace.
+    with tracing.span("runtime::init", lifecycle=True) as ctx:
+        runtime = rt.init_runtime(
+            num_cpus=num_cpus, resources=resources, namespace=namespace
+        )
+    runtime.trace_id = ctx["trace_id"]
     # Honor the flag in LOCAL driver mode too (the runtime's default comes
     # from the log_to_driver config knob).
     runtime.log_to_driver = bool(log_to_driver) and runtime.log_to_driver

@@ -43,7 +43,9 @@ CATALOG_HEADER = (
 )
 
 
-_SPAN_CALLS = ("span", "annotate")
+# `record_span` takes a finished interval; `boot_phase` is worker_main's
+# closure that closes one phase of the worker's boot.
+_SPAN_CALLS = ("span", "annotate", "record_span", "boot_phase")
 
 
 def _span_call_name(call: ast.Call) -> Optional[str]:

@@ -76,13 +76,20 @@ class CoreClient:
             spec.parent_task_id = current_task_id()
         from ray_tpu.util import tracing
 
-        if tracing.is_enabled() and spec.trace_ctx is None:
+        if spec.trace_ctx is not None:
+            return
+        if tracing.is_enabled():
             # The submit span's context rides the spec, so the executor's
             # run span parents to it across the process boundary.
             with tracing.span(
                 f"submit::{spec.name}", attrs={"task_id": spec.task_id}
             ) as ctx:
                 spec.trace_ctx = dict(ctx)
+        else:
+            # Tracing off: there is an ambient context only inside a
+            # lifecycle span (a fit()), whose spans on the executor's side
+            # parent to it across the hop; no span is recorded for the task.
+            spec.trace_ctx = tracing.current_context()
 
     def submit(self, spec: TaskSpec) -> List[ObjectRef]:
         self._stamp_parent(spec)

@@ -517,8 +517,7 @@ WIRING_ENV: Dict[str, str] = {
     "RAY_TPU_ZYGOTE_FD": "inherited zygote control-pipe fd number",
     "RAY_TPU_ARENA_FD": "inherited shm arena fd number",
     # early-boot / dev toggles read before config import is safe
-    "RAY_TPU_TRACE": "1 = per-op wall-clock tracing to stderr",
-    "RAY_TPU_BOOT_TRACE": "1 = worker boot-phase timing to stderr",
+    "RAY_TPU_TRACE": "1 = record per-task and per-step spans (util/tracing.py)",
     "RAY_TPU_DEBUG_LOCKS": "1 = slow-lock diagnostics in the runtime",
     "RAY_TPU_FAULTHANDLER": "1 = arm faulthandler in spawned workers",
     "RAY_TPU_PDEATHSIG": "0 = skip parent-death signal on Linux children",

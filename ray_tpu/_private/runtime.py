@@ -843,6 +843,9 @@ class Runtime:
         self.listener = Listener((bind_host, listen_port), backlog=128)
         self.address = self.listener.address
         self._shutdown = False
+        # Trace id of `runtime::init` (set by ray_tpu.init()); the spans of
+        # shutdown() join it, so a run's record finds both.
+        self.trace_id: Optional[str] = None
         self._conn_to_worker: Dict[Any, str] = {}
         self._conns_version = 0
         # Multi-host plane: per-node daemon processes owning remote worker
@@ -6220,89 +6223,105 @@ class Runtime:
         if self._shutdown:
             return
         self._shutdown = True
-        atexit.unregister(self.shutdown)
-        set_ref_hooks(None, None)
-        if self._autoscaler is not None:
-            try:
-                self._autoscaler.stop()
-            except Exception:
-                pass
-        if getattr(self, "_snapshot_storage", None) is not None:
-            self._snapshot_storage.close()
-        if getattr(self, "_journal", None) is not None:
-            self._journal.close()
-        if getattr(self, "_ready_spill", None) is not None:
-            self._ready_spill.close()
-        if getattr(self, "_mem_monitor", None) is not None:
-            self._mem_monitor.stop()
-        # Final log drain: crash output written moments ago must reach the
-        # ring buffers/stdout before the session dies.
-        try:
-            self._log_monitor.flush()
-            self._log_monitor.stop()
-        except Exception:
-            pass
-        try:
-            if _wire.stats_enabled():
-                # Final per-process counters into the event log (workers'
-                # snapshots were folded in live via their wire_stats
-                # reports — see _handle_msg).
-                self.events.emit(
-                    "INFO", "wire", "head wire stats", **_wire.stats()
-                )
-            self.events.emit("INFO", "runtime", "session shutting down")
-            self.events.close()
-        except Exception:
-            pass
-        for nid in list(self.node_daemons):
-            self._daemon_send(nid, ("shutdown",))
-        for proc in self._daemon_procs.values():
-            try:
-                proc.terminate()
-            except OSError:
-                pass
-        if self._zygote_proc is not None:
-            try:
-                self._zygote_proc.terminate()
-            except OSError:
-                pass
-        for h in list(self.workers.values()):
-            try:
-                if h.conn is not None:
-                    h.conn.send(("kill",))
-            except OSError:
-                pass
-            try:
-                h.proc.terminate()
-            except Exception:
-                pass
-        # The kill/shutdown frames above are queued on batching conns:
-        # push them out before the fds die with the process.
-        _wire.flush_dirty()
-        try:
-            self.listener.close()
-        except OSError:
-            pass
-        deadline = time.monotonic() + 2.0
-        for h in list(self.workers.values()):
-            remaining = max(0.0, deadline - time.monotonic())
-            try:
-                h.proc.join(remaining)
-            except Exception:
-                pass
-        # A worker that held TPU chips releases them while it EXITS (a reset
-        # per chip, its pinned host memory): seconds on a four-chip host,
-        # during which the next process to open the chips fails with "Device
-        # or resource busy".  So shutdown returns when this host's workers
-        # are gone, not when they were told to go.
-        deadline = time.monotonic() + 30.0
-        for h in list(self.workers.values()):
-            if isinstance(h.proc, (_PopenHandle, _ZygoteProcHandle)):
-                while not _exited(h.proc.pid) and time.monotonic() < deadline:
-                    time.sleep(0.05)
-        self.store.destroy()
+        from ray_tpu.util import tracing
+
+        # Lifecycle spans (util/tracing.py, always recorded): the inside
+        # twin of a caller's clock around `ray_tpu.shutdown()`, one child per
+        # stage that can take over 0.1 s.
+        with tracing.span(
+            "runtime::shutdown", parent={"trace_id": self.trace_id}, lifecycle=True
+        ):
+            self._shutdown_stages(tracing)
         global _runtime
         _runtime = None
+
+    def _shutdown_stages(self, tracing) -> None:
+        atexit.unregister(self.shutdown)
+        set_ref_hooks(None, None)
+        with tracing.span("runtime::shutdown::services", lifecycle=True):
+            if self._autoscaler is not None:
+                try:
+                    self._autoscaler.stop()
+                except Exception:
+                    pass
+            if getattr(self, "_snapshot_storage", None) is not None:
+                self._snapshot_storage.close()
+            if getattr(self, "_journal", None) is not None:
+                self._journal.close()
+            if getattr(self, "_ready_spill", None) is not None:
+                self._ready_spill.close()
+            if getattr(self, "_mem_monitor", None) is not None:
+                self._mem_monitor.stop()
+        # Final log drain: crash output written moments ago must reach the
+        # ring buffers/stdout before the session dies.
+        with tracing.span("runtime::shutdown::log_drain", lifecycle=True):
+            try:
+                self._log_monitor.flush()
+                self._log_monitor.stop()
+            except Exception:
+                pass
+            try:
+                if _wire.stats_enabled():
+                    # Final per-process counters into the event log (workers'
+                    # snapshots were folded in live via their wire_stats
+                    # reports — see _handle_msg).
+                    self.events.emit(
+                        "INFO", "wire", "head wire stats", **_wire.stats()
+                    )
+                self.events.emit("INFO", "runtime", "session shutting down")
+                self.events.close()
+            except Exception:
+                pass
+        with tracing.span("runtime::shutdown::signal_processes", lifecycle=True):
+            for nid in list(self.node_daemons):
+                self._daemon_send(nid, ("shutdown",))
+            for proc in self._daemon_procs.values():
+                try:
+                    proc.terminate()
+                except OSError:
+                    pass
+            if self._zygote_proc is not None:
+                try:
+                    self._zygote_proc.terminate()
+                except OSError:
+                    pass
+            for h in list(self.workers.values()):
+                try:
+                    if h.conn is not None:
+                        h.conn.send(("kill",))
+                except OSError:
+                    pass
+                try:
+                    h.proc.terminate()
+                except Exception:
+                    pass
+            # The kill/shutdown frames above are queued on batching conns:
+            # push them out before the fds die with the process.
+            _wire.flush_dirty()
+            try:
+                self.listener.close()
+            except OSError:
+                pass
+        with tracing.span("runtime::shutdown::workers_exit", lifecycle=True):
+            deadline = time.monotonic() + 2.0
+            for h in list(self.workers.values()):
+                remaining = max(0.0, deadline - time.monotonic())
+                try:
+                    h.proc.join(remaining)
+                except Exception:
+                    pass
+            # A worker that held TPU chips releases them while it EXITS (a
+            # reset per chip, its pinned host memory): seconds on a four-chip
+            # host, during which the next process to open the chips fails
+            # with "Device or resource busy".  So shutdown returns when this
+            # host's workers are gone, not when they were told to go.
+            deadline = time.monotonic() + 30.0
+            for h in list(self.workers.values()):
+                if isinstance(h.proc, (_PopenHandle, _ZygoteProcHandle)):
+                    while not _exited(h.proc.pid) and time.monotonic() < deadline:
+                        time.sleep(0.05)
+        with tracing.span("runtime::shutdown::store", lifecycle=True):
+            self.store.destroy()
 
 
 _PARKED = object()

@@ -85,6 +85,9 @@ class WorkerRuntime:
         # Batched task-event reporter (installed by worker_main): the
         # direct transport records lease-dispatch RUNNING events here.
         self.task_event_sink = None
+        # worker_main's boot phases [(span name, start, end), ...], until
+        # the first traced task turns them into spans (_execute).
+        self.boot_spans = None
         # Relayed tasks received but not yet replied (queued + executing):
         # the reconnect hello announces these so the head can re-drive
         # exactly what the dead conn lost — a task push that never
@@ -705,16 +708,30 @@ def _execute(rt: WorkerRuntime, spec: TaskSpec, blob: Optional[bytes]):
 
     _ctx_token = _current_task.set(spec.task_id)
     stack = contextlib.ExitStack()
-    if getattr(spec, "trace_ctx", None) is not None and tracing.is_enabled():
+    trace_ctx = getattr(spec, "trace_ctx", None)
+    if trace_ctx is not None:
+        if rt.boot_spans:
+            # The first traced work this worker gets (an actor's creation,
+            # inside a fit()): its boot phases join that trace.  A worker
+            # taken from the warm pool booted BEFORE the parent span began.
+            booted, rt.boot_spans = rt.boot_spans, None
+            for name, start, end in booted:
+                tracing.record_span(
+                    name, start, end, parent=trace_ctx,
+                    attrs={"worker_id": rt.worker_id}, lifecycle=True,
+                )
         # Adopt the submitter's context: this run span parents to its
         # submit span, and anything WE submit parents to this run
-        # (ray: tracing_helper.py execute-side wrapper).
+        # (ray: tracing_helper.py execute-side wrapper).  With tracing off
+        # the context is adopted and no span recorded.
         stack.enter_context(
             tracing.span(
                 f"run::{spec.name}",
-                parent=spec.trace_ctx,
+                parent=trace_ctx,
                 attrs={"task_id": spec.task_id, "worker_id": rt.worker_id},
             )
+            if tracing.is_enabled()
+            else tracing.adopt(trace_ctx)
         )
     try:
         if spec.is_actor_creation:
@@ -812,17 +829,16 @@ def worker_main(address, authkey: bytes, worker_id: str, session_name: str, env_
     from ray_tpu._private import compile_cache
 
     compile_cache.apply_default()
-    if os.environ.get("RAY_TPU_BOOT_TRACE"):
-        import time as _t
+    # The worker's boot as (span name, start, end), recorded as lifecycle
+    # spans once a traced task says whose they are (_execute).
+    boot_spans: list = []
+    boot_t = [time.time()]
 
-        _boot_t0 = _t.monotonic()
+    def boot_phase(name: str) -> None:
+        now = time.time()
+        boot_spans.append((name, boot_t[0], now))
+        boot_t[0] = now
 
-        def _tr(label):
-            print(f"BOOT {label} +{1000*(_t.monotonic()-_boot_t0):.1f}ms", flush=True)
-    else:
-        def _tr(label):
-            pass
-    _tr("start")
     if os.environ.get("RAY_TPU_FAULTHANDLER"):
         import faulthandler
         import signal as _sig
@@ -874,14 +890,14 @@ def worker_main(address, authkey: bytes, worker_id: str, session_name: str, env_
     watchdog.start()
     conn = wire.batching(wire.connect(address, authkey))
     watchdog.cancel()
-    _tr("connected")
+    boot_phase("worker::boot::connect")
     from ray_tpu._private.netutil import set_nodelay
 
     set_nodelay(conn)
     conn_lock = lock_watchdog.make_lock("worker_main.conn_lock")
     rt = WorkerRuntime(conn, conn_lock, session_name, worker_id, authkey=authkey)
     _runtime = rt
-    _tr("runtime")
+    boot_phase("worker::boot::runtime")
 
     # Install ObjectRef refcount hooks: proxy to owner (oneway, FIFO with the
     # task's own completion message so no use-after-free races).
@@ -1132,7 +1148,7 @@ def worker_main(address, authkey: bytes, worker_id: str, session_name: str, env_
     except OSError:
         peer_server, peer_endpoint = None, None  # no direct path; head relays
     rt.direct = DirectTransport(rt)
-    _tr("peer_server")
+    boot_phase("worker::boot::peer_server")
 
     def try_reconnect() -> bool:
         """Head conn lost: in head-split mode (reconnect window > 0) retry
@@ -1321,7 +1337,6 @@ def worker_main(address, authkey: bytes, worker_id: str, session_name: str, env_
                 pass
             sys.exit(1)
 
-    _tr("pre_ready")
     with conn_lock:
         # The trailing time.time() is the clock-offset sample the head
         # uses to merge this process's spans into the cluster timeline.
@@ -1330,6 +1345,8 @@ def worker_main(address, authkey: bytes, worker_id: str, session_name: str, env_
              None, time.time())
         )
     wire.flush_conn(conn)
+    boot_phase("worker::boot::ready")
+    rt.boot_spans = boot_spans
     ready_sent.set()  # telemetry oneways may ride this conn from here on
 
     while True:

@@ -10,6 +10,7 @@ of params/batch makes XLA emit reduce-scatter/all-reduce over ICI.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -34,6 +35,7 @@ from ray_tpu.parallel.sharding import (
     resolve_rules,
     tree_shardings,
 )
+from ray_tpu.train.run_record import DISPATCH, MAKE_BATCH, StepClock
 from ray_tpu.util import tracing
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -145,6 +147,7 @@ class LMTrainContext:
         self.mesh = mesh if mesh is not None else build_mesh()
         self.rules = resolve_rules(strategy)
         self.optimizer = optimizer or default_optimizer()
+        self._step_clock = StepClock()
 
         raw_shardings = tree_shardings(param_axes(config), self.rules, self.mesh)
         abstract_params = jax.eval_shape(lambda: init_params(config, jax.random.PRNGKey(0)))
@@ -255,7 +258,8 @@ class LMTrainContext:
 
     # -- public API -------------------------------------------------------
     def init_state(self, seed: int = 0) -> Dict[str, Any]:
-        with tracing.annotate("init_state"), self.mesh:
+        # A lifecycle span too: once per run, and in every run's record.
+        with tracing.annotate("init_state", lifecycle=True), self.mesh:
             return self._init(jax.random.PRNGKey(seed))
 
     def make_batch(self, batch) -> Dict[str, jax.Array]:
@@ -274,11 +278,19 @@ class LMTrainContext:
         return jax.tree_util.tree_map(put, batch)
 
     def train_step(self, state, batch) -> Tuple[Dict, Dict]:
+        # Always on: the step's period, entry to entry, for the run record's
+        # stalled steps (train/run_record.py); no span unless tracing is on.
+        clock = self._step_clock
+        clock.enter()
         if not all(isinstance(x, jax.Array) for x in jax.tree_util.tree_leaves(batch)):
+            t0 = time.perf_counter()
             with tracing.annotate("train_step/make_batch"):
                 batch = self.make_batch(batch)
+            clock.mark(MAKE_BATCH, t0)
+        t0 = time.perf_counter()
         with tracing.annotate("train_step/dispatch"), self.mesh:
             state, metrics = self._train_step(state, batch)
+        clock.mark(DISPATCH, t0)
         return state, metrics
 
     def apply(self, params, tokens) -> jax.Array:

@@ -19,6 +19,7 @@ from ray_tpu.train import session
 from ray_tpu.train.backend import Backend, BackendConfig, JaxConfig
 from ray_tpu.train.backend_executor import BackendExecutor, TrainingFailedError
 from ray_tpu.train.data_parallel_trainer import DataParallelTrainer, JaxTrainer
+from ray_tpu.train.run_record import last_run_record
 from ray_tpu.train.session import (
     get_checkpoint,
     get_dataset_shard,
@@ -47,6 +48,7 @@ __all__ = [
     "get_dataset_shard",
     "get_world_rank",
     "get_world_size",
+    "last_run_record",
     "report",
     "session",
 ]

@@ -52,13 +52,34 @@ class JaxConfig(BackendConfig):
         return _JaxBackend
 
 
-def _pick_coordinator(port: int) -> str:
+def _import_jax(platform: Optional[str]):
+    """`import jax` as the lifecycle span `train::backend::import_jax`, in
+    whichever call reaches it first in this worker (seconds in a fresh one,
+    nothing after), and the run record's compile listener with it."""
+    import os
+    import sys
+
+    from ray_tpu.train import run_record
+    from ray_tpu.util import tracing
+
+    if platform:
+        os.environ["JAX_PLATFORMS"] = platform
+    with tracing.span("train::backend::import_jax", lifecycle=True,
+                      attrs={"already_imported": "jax" in sys.modules}):
+        import jax
+    run_record.install_jax_listener()
+    return jax
+
+
+def _pick_coordinator(port: int, platform: Optional[str] = None) -> str:
+    # `ray_tpu.parallel` imports jax: rank 0 pays its import here, not later.
+    _import_jax(platform)
     from ray_tpu.parallel.bootstrap import pick_coordinator_address
 
     return pick_coordinator_address(port)
 
 
-def _wait_for_chips(timeout_s: float = 60.0) -> float:
+def _wait_for_chips(timeout_s: float = 60.0, pattern: str = "/dev/vfio/[0-9]*", opener=None) -> float:
     """Block until the chips this host hands out (`/dev/vfio/<group>`) can be
     opened; returns the seconds waited.  A process that held them releases
     them while it EXITS (a reset per chip, its pinned host memory): seconds
@@ -66,17 +87,18 @@ def _wait_for_chips(timeout_s: float = 60.0) -> float:
     whole backend ("Couldn't open iommu group").  A job that starts right
     after another one ended must outwait that, not die of it.  Opening and
     closing a group file claims nothing.  Anything but EBUSY is left for
-    libtpu to report."""
+    libtpu to report.  (`pattern` and `opener` are the test's handles.)"""
     import errno
     import glob
     import os
     import time
 
+    opener = opener or os.open
     start = time.monotonic()
-    for path in glob.glob("/dev/vfio/[0-9]*"):
+    for path in glob.glob(pattern):
         while True:
             try:
-                os.close(os.open(path, os.O_RDWR))
+                os.close(opener(path, os.O_RDWR))
             except OSError as e:
                 if e.errno == errno.EBUSY and time.monotonic() - start < timeout_s:
                     time.sleep(0.25)
@@ -86,32 +108,27 @@ def _wait_for_chips(timeout_s: float = 60.0) -> float:
 
 
 def _init_jax_distributed(coordinator: str, world_size: int, rank: int, platform):
-    import os
-
+    from ray_tpu.train import run_record
     from ray_tpu.util import tracing
 
-    if platform:
-        os.environ["JAX_PLATFORMS"] = platform
-    # The two waits every chip worker pays before its loop's first line,
-    # split for `ray_tpu timeline` (RAY_TPU_TRACE=1).
-    with tracing.span("train::backend::import_jax"):
-        import jax
-
+    # What every chip worker pays before its loop's first line, by name:
+    # lifecycle spans, in every run's record (train/run_record.py).
+    jax = _import_jax(platform)
     if platform:
         jax.config.update("jax_platforms", platform)
     if world_size > 1:
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=world_size,
-            process_id=rank,
-        )
+        with tracing.span("train::backend::distributed_init", lifecycle=True):
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=world_size,
+                process_id=rank,
+            )
     if platform == "tpu":
-        waited = _wait_for_chips()
-        if waited > 1.0:
-            import sys
-
-            print(f"[ray_tpu] waited {waited:.1f} s for another process to release the chips", file=sys.stderr)
-    with tracing.span("train::backend::device_open"):
+        attrs = {}
+        with tracing.span("train::backend::chip_wait", attrs=attrs, lifecycle=True):
+            attrs["waited_s"] = _wait_for_chips()
+        run_record.counters()["chip_wait"].inc(attrs["waited_s"])
+    with tracing.span("train::backend::device_open", lifecycle=True):
         global_devices = len(jax.devices())
     return {
         "rank": rank,
@@ -122,8 +139,9 @@ def _init_jax_distributed(coordinator: str, world_size: int, rank: int, platform
 
 class _JaxBackend(Backend):
     def on_start(self, worker_group: WorkerGroup, backend_config: JaxConfig):
-        coordinator = worker_group.execute_single(
-            0, _pick_coordinator, backend_config.coordinator_port, timeout=60
+        # One worker is its own coordinator: nothing to pick.
+        coordinator = "" if worker_group.num_workers == 1 else worker_group.execute_single(
+            0, _pick_coordinator, backend_config.coordinator_port, backend_config.platform, timeout=60
         )
         # All workers join the XLA coordination service (the analogue of the
         # reference broadcasting rank-0's addr then init_process_group).

@@ -18,7 +18,9 @@ import ray_tpu
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.train.backend import BackendConfig
+from ray_tpu.train.run_record import RunRecord
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
 
 
 class TrainingFailedError(RuntimeError):
@@ -32,12 +34,38 @@ class RemeshScaleUp(Exception):
     restart at the original world size from the latest checkpoint."""
 
 
+def _record_creation_stages(spawn_ctx, worker, host) -> None:
+    """Attach to the spawn what the head already stamped on rank 0's creation
+    task (TaskRecord.stages: pending, queued, lease, wire, running, ...), as
+    one child span `train::worker_group::creation_task`; nothing where the
+    head's task events are out of reach."""
+    try:
+        from ray_tpu.util.state import list_tasks
+
+        event = next(
+            (t for t in list_tasks(limit=1 << 20)
+             if t.get("creation") and t.get("actor_id") == worker._id), None)
+    except Exception:  # noqa: BLE001: an observation; the start goes on without it
+        return
+    if event is None or not event.get("stages"):
+        return
+    stamps = [v for v in event["stages"].values() if isinstance(v, (int, float))]
+    tracing.record_span(
+        "train::worker_group::creation_task", min(stamps), max(stamps), parent=spawn_ctx,
+        attrs={"pid": host["pid"], **{f"{k}_s": v for k, v in (event.get("durations") or {}).items()}},
+        lifecycle=True,
+    )
+
+
 class BackendExecutor:
     def __init__(
         self,
         backend_config: BackendConfig,
         scaling_config: Optional[ScalingConfig] = None,
+        record: Optional[RunRecord] = None,
     ):
+        # The fit()'s run record: every poll reply is folded into it.
+        self.record = record
         self.backend_config = backend_config
         self.backend = backend_config.backend_cls()()
         self.scaling = scaling_config or ScalingConfig()
@@ -62,49 +90,68 @@ class BackendExecutor:
         gangs restart at the gang's current — possibly shrunk — size)."""
         if self.worker_group is not None:
             return
+        # Lifecycle spans (util/tracing.py): recorded in every run.
+        with tracing.span("train::executor::start", lifecycle=True):
+            self._start(num_workers)
+
+    def _start(self, num_workers: Optional[int]) -> None:
         sc = self.scaling
         n = sc.num_workers if num_workers is None else num_workers
         if sc.num_workers > 1:
-            # Gang-reserve the workers' resources (ray: Train reserves a PG
-            # per trial via Tune — base_trainer.py:52 path).
-            if self._pg is None:
-                from ray_tpu.util.placement_group import placement_group
-
-                bundles = [sc.worker_resources() for _ in range(sc.num_workers)]
-                self._pg = placement_group(
-                    bundles, strategy=sc.placement_strategy
-                )
-            if not self._pg.wait(timeout_seconds=self.pg_wait_timeout_s):
-                info = self.pg_info() or {}
-                placed = set(info.get("bundle_nodes") or {})
-                unplaced = [
-                    i
-                    for i in range(len(self._pg.bundle_specs))
-                    if i not in placed
-                ]
-                raise TrainingFailedError(
-                    f"placement group {self._pg.id} not ready after "
-                    f"{self.pg_wait_timeout_s:.0f}s: "
-                    f"state={info.get('state') or 'UNKNOWN'}, unplaceable "
-                    f"bundles {unplaced} of {self._pg.bundle_specs}; the "
-                    "cluster cannot satisfy the reservation — check node "
-                    "resources"
-                    + (
-                        " and mesh_coord labels"
-                        if sc.placement_strategy == "MESH"
-                        else ""
-                    )
-                )
-            self._elastic = sc.placement_strategy == "MESH"
-            info = self.pg_info() or {}
-            self._generation = info.get("generation", 0)
-            if self._elastic:
-                n = min(n, info.get("size", n))
+            with tracing.span("train::executor::placement_group", lifecycle=True):
+                n = self._reserve_gang(n)
         self.num_started_workers = n
-        self.worker_group = WorkerGroup(
-            n, sc.worker_resources(), placement_group=self._pg
-        )
-        self.backend.on_start(self.worker_group, self.backend_config)
+        with tracing.span("train::worker_group::spawn", attrs={"num_workers": n}, lifecycle=True) as ctx:
+            self.worker_group = WorkerGroup(
+                n, sc.worker_resources(), placement_group=self._pg
+            )
+            # Spawned = the first call answered: the actors' creation (lease,
+            # a fork or a warm worker, __init__) is behind it.
+            hosts = ray_tpu.get(
+                [w.host_info.remote() for w in self.worker_group.workers], timeout=60
+            )
+        _record_creation_stages(ctx, self.worker_group.workers[0], hosts[0])
+        with tracing.span("train::backend::on_start", lifecycle=True):
+            self.backend.on_start(self.worker_group, self.backend_config)
+
+    def _reserve_gang(self, n: int) -> int:
+        """Gang-reserve the workers' resources (ray: Train reserves a PG per
+        trial via Tune — base_trainer.py:52 path); returns the world size."""
+        sc = self.scaling
+        if self._pg is None:
+            from ray_tpu.util.placement_group import placement_group
+
+            bundles = [sc.worker_resources() for _ in range(sc.num_workers)]
+            self._pg = placement_group(
+                bundles, strategy=sc.placement_strategy
+            )
+        if not self._pg.wait(timeout_seconds=self.pg_wait_timeout_s):
+            info = self.pg_info() or {}
+            placed = set(info.get("bundle_nodes") or {})
+            unplaced = [
+                i
+                for i in range(len(self._pg.bundle_specs))
+                if i not in placed
+            ]
+            raise TrainingFailedError(
+                f"placement group {self._pg.id} not ready after "
+                f"{self.pg_wait_timeout_s:.0f}s: "
+                f"state={info.get('state') or 'UNKNOWN'}, unplaceable "
+                f"bundles {unplaced} of {self._pg.bundle_specs}; the "
+                "cluster cannot satisfy the reservation — check node "
+                "resources"
+                + (
+                    " and mesh_coord labels"
+                    if sc.placement_strategy == "MESH"
+                    else ""
+                )
+            )
+        self._elastic = sc.placement_strategy == "MESH"
+        info = self.pg_info() or {}
+        self._generation = info.get("generation", 0)
+        if self._elastic:
+            n = min(n, info.get("size", n))
+        return n
 
     def stop_workers(self):
         """Tear down the worker group KEEPING the placement group — the
@@ -121,15 +168,16 @@ class BackendExecutor:
             self.worker_group = None
 
     def shutdown(self):
-        self.stop_workers()
-        if self._pg is not None:
-            from ray_tpu.util.placement_group import remove_placement_group
+        with tracing.span("train::executor::shutdown", lifecycle=True):
+            self.stop_workers()
+            if self._pg is not None:
+                from ray_tpu.util.placement_group import remove_placement_group
 
-            try:
-                remove_placement_group(self._pg)
-            except Exception:
-                pass
-            self._pg = None
+                try:
+                    remove_placement_group(self._pg)
+                except Exception:
+                    pass
+                self._pg = None
 
     # -- elastic re-mesh ---------------------------------------------------
     def pg_info(self) -> Optional[Dict[str, Any]]:
@@ -201,8 +249,24 @@ class BackendExecutor:
 
         dataset_shards: {name: [per-rank Dataset shard]} — rank i receives
         shard i under session.get_dataset_shard(name)."""
+        with tracing.span("train::executor::run_training", lifecycle=True):
+            return self._run_training(
+                train_fn, config, resume_checkpoint, on_report, poll_interval, dataset_shards
+            )
+
+    def _run_training(self, train_fn, config, resume_checkpoint, on_report, poll_interval, dataset_shards):
         wg = self.worker_group
         assert wg is not None, "call start() first"
+
+        def deliver(polls):
+            for i, p in enumerate(polls):
+                if self.record is not None:
+                    self.record.add_poll(i, p)
+                for rep in p["reports"]:
+                    all_reports[i].append(rep)
+                    if on_report is not None:
+                        on_report(i, rep)
+
         done_refs = [
             w.run_train_fn.remote(
                 train_fn,
@@ -243,11 +307,7 @@ class BackendExecutor:
                 raise TrainingFailedError(
                     f"train worker died during poll: {e}"
                 ) from e
-            for i, p in enumerate(polls):
-                for rep in p["reports"]:
-                    all_reports[i].append(rep)
-                    if on_report is not None:
-                        on_report(i, rep)
+            deliver(polls)
             # completion/errors via the run refs (non-blocking check)
             ready, _ = ray_tpu.wait(done_refs, num_returns=len(done_refs), timeout=0)
             for i, r in enumerate(done_refs):
@@ -259,6 +319,15 @@ class BackendExecutor:
                         error = e
                         break
         if error is not None:
+            if self.record is not None:
+                # What the workers still hold for the record (the failed train
+                # function's own span, its last compiles): an observation,
+                # and the failure is raised with or without it.
+                try:
+                    for i, p in enumerate(ray_tpu.get([w.poll.remote() for w in wg.workers], timeout=5)):
+                        self.record.add_poll(i, p)
+                except Exception:  # noqa: BLE001
+                    pass
             raise TrainingFailedError(str(error)) from error
         # final drain
         try:
@@ -267,9 +336,5 @@ class BackendExecutor:
             raise TrainingFailedError(
                 f"train worker died during final report drain: {e}"
             ) from e
-        for i, p in enumerate(polls):
-            for rep in p["reports"]:
-                all_reports[i].append(rep)
-                if on_report is not None:
-                    on_report(i, rep)
+        deliver(polls)
         return all_reports

@@ -20,6 +20,7 @@ from typing import Any, Callable, Dict, Optional
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import CheckpointConfig, FailureConfig, RunConfig, ScalingConfig
 from ray_tpu.air.result import Result
+from ray_tpu.train import run_record
 from ray_tpu.train.backend import BackendConfig, JaxConfig
 from ray_tpu.train.backend_executor import (
     BackendExecutor,
@@ -73,6 +74,15 @@ class DataParallelTrainer:
         import ray_tpu
 
         ray_tpu._auto_init()
+        # The run's record (train/run_record.py): every lifecycle span under
+        # this root shares its trace id, in the workers too, tracing on or off.
+        with tracing.span("train::fit", lifecycle=True) as ctx:
+            record = run_record.begin(ctx)
+            result = self._fit(record)
+        result.run_record = record.to_dict()
+        return result
+
+    def _fit(self, record: run_record.RunRecord) -> Result:
         failure = self.run_config.failure_config or FailureConfig()
         ckpt_cfg = self.run_config.checkpoint_config or CheckpointConfig()
         attempts_left = failure.max_failures
@@ -87,7 +97,7 @@ class DataParallelTrainer:
         # ONE executor for the whole fit: its placement group is the elastic
         # gang and must survive group restarts (re-mesh respawns workers
         # into the SAME re-planned reservation).
-        executor = BackendExecutor(self.backend_config, self.scaling_config)
+        executor = BackendExecutor(self.backend_config, self.scaling_config, record=record)
         num_workers = self.scaling_config.num_workers
         # In-flight re-mesh episode (stage durations + span context); the
         # "resume" stage closes at the first report of the restarted run.

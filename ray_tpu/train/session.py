@@ -9,6 +9,7 @@ the worker actor and are drained by the driver's BackendExecutor poll loop.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Dict, List, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
@@ -38,8 +39,10 @@ class TrainSession:
         self.error: Optional[BaseException] = None
 
     def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None):
+        # "t" stamps the report beside the payload: the driver takes the
+        # seconds to its `on_report` from it (train/run_record.py).
         with self._lock:
-            self._reports.append({"metrics": dict(metrics), "checkpoint": checkpoint})
+            self._reports.append({"metrics": dict(metrics), "checkpoint": checkpoint, "t": time.time()})
 
     def drain(self) -> List[Dict[str, Any]]:
         with self._lock:

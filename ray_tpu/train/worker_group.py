@@ -11,7 +11,9 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.train import run_record
 from ray_tpu.train.session import TrainSession, init_session
+from ray_tpu.util import tracing
 
 
 @ray_tpu.remote(max_concurrency=2)
@@ -22,6 +24,7 @@ class TrainWorker:
         self.rank = rank
         self.world_size = world_size
         self.session: Optional[TrainSession] = None
+        self._spans_seen = 0  # `tracing.lifecycle_count()` at the last poll() that found spans
 
     # -- backend hooks ----------------------------------------------------
     def run_fn(self, fn: Callable, *args, **kwargs):
@@ -48,26 +51,39 @@ class TrainWorker:
             resume_checkpoint=resume_ckpt,
             dataset_shards=dataset_shards,
         )
-        try:
-            import inspect
+        with tracing.span("train::worker::run_train_fn", attrs={"rank": self.rank}, lifecycle=True) as ctx:
+            # A compile on a thread the train function starts parents here.
+            run_record.set_fallback_parent(ctx)
+            try:
+                import inspect
 
-            sig = inspect.signature(train_fn)
-            if len(sig.parameters) == 0:
-                train_fn()
-            else:
-                train_fn(config or {})
-            self.session.done = True
-            return {"ok": True}
-        except BaseException as e:  # report, don't kill the actor
-            self.session.done = True
-            self.session.error = e
-            raise
+                sig = inspect.signature(train_fn)
+                if len(sig.parameters) == 0:
+                    train_fn()
+                else:
+                    train_fn(config or {})
+                self.session.done = True
+                return {"ok": True}
+            except BaseException as e:  # report, don't kill the actor
+                self.session.done = True
+                self.session.error = e
+                raise
+            finally:
+                run_record.flush_traces()
 
     def poll(self) -> Dict[str, Any]:
-        """Drain buffered session.report() payloads (driver poll loop)."""
-        if self.session is None:
-            return {"reports": [], "done": False}
-        return {"reports": self.session.drain(), "done": self.session.done}
+        """Drain buffered session.report() payloads (driver poll loop), and
+        with them what this worker has for the run's record: its lifecycle
+        spans of the caller's trace not sent yet, and its stalled steps."""
+        spans, recorded = [], tracing.lifecycle_count()
+        ctx = tracing.current_context()
+        if ctx and recorded != self._spans_seen:  # nothing to scan in a steady step
+            spans = tracing.lifecycle_spans(ctx["trace_id"], since=self._spans_seen)
+            self._spans_seen = recorded
+        out = {"reports": [], "done": False, "spans": spans, "stalls": run_record.drain_stalls()}
+        if self.session is not None:
+            out["reports"], out["done"] = self.session.drain(), self.session.done
+        return out
 
 
 class WorkerGroup:

@@ -1,21 +1,22 @@
-"""Distributed tracing: OTel-compatible spans with context in task specs.
+"""Distributed tracing: spans with their context in task specs.
 
 ray: python/ray/util/tracing/tracing_helper.py — the reference wraps
-remote calls in OpenTelemetry spans and propagates the context INSIDE the
-task spec (`_DictPropagator.inject_current_context`, :160), so a task's
-execute span parents to its submitter's span across processes.  Same
-design here:
+remote calls in spans and propagates the context INSIDE the task spec
+(`_DictPropagator.inject_current_context`, :160), so a task's execute span
+parents to its submitter's span across processes.  Same design here:
 
-  * opt-in (`RAY_TPU_TRACE=1` or `enable_tracing()`), zero overhead off;
+  * two classes of span, one mechanism.  Per-task and per-step spans are
+    opt-in (`RAY_TPU_TRACE=1` or `enable_tracing()`), zero overhead off.
+    LIFECYCLE spans (`lifecycle=True`: a job's start, a compile, a
+    shutdown; a few dozen per run, none on a hot path) are recorded
+    always, and besides the buffer land in a bounded per-process store
+    that `lifecycle_spans()` reads, from which `ray_tpu.train` builds a
+    run's record;
   * the ACTIVE trace context lives in a contextvar; submission injects it
     into `spec.trace_ctx` as a W3C-traceparent-style dict, execution
     adopts it, so nested submits chain naturally;
-  * spans always record to an in-process buffer that workers flush to the
-    head (state API / timeline); when the `opentelemetry` API package is
-    importable the same spans ALSO open real OTel spans — with no SDK
-    installed those are no-ops, with a user-configured SDK they export
-    wherever the user pointed it (the lazy-proxy pattern of the
-    reference's _OpenTelemetryProxy:33).
+  * spans record to an in-process buffer that workers flush to the head
+    (state API / timeline).
 """
 
 from __future__ import annotations
@@ -25,19 +26,21 @@ import os
 import sys
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional
 
 _enabled = os.environ.get("RAY_TPU_TRACE", "") not in ("", "0")
 _current: "contextvars.ContextVar[Optional[Dict[str, str]]]" = contextvars.ContextVar(
     "raytpu_trace_ctx", default=None
 )
-_buffer: List[Dict[str, Any]] = []
 _buffer_lock = threading.Lock()
-_MAX_BUFFER = 10000
-
-_otel_tracer = None
-_otel_checked = False
+_buffer: Deque[Dict[str, Any]] = deque(maxlen=10000)
+# Every lifecycle span of this process, newest last: not drained by the
+# flush to the head, so a run's record can still be put together after
+# `ray_tpu.shutdown()`.
+_lifecycle: Deque[Dict[str, Any]] = deque(maxlen=4096)
+_lifecycle_total = 0
 
 
 def enable_tracing() -> None:
@@ -56,31 +59,46 @@ def is_enabled() -> bool:
     return _enabled
 
 
-def _otel():
-    """Lazy OTel API tracer; None when the package is absent."""
-    global _otel_tracer, _otel_checked
-    if not _otel_checked:
-        _otel_checked = True
-        try:
-            from opentelemetry import trace as _t
-
-            _otel_tracer = _t.get_tracer("ray_tpu")
-        except Exception:
-            _otel_tracer = None
-    return _otel_tracer
-
-
 def _new_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
 
 
+def current_context() -> Optional[Dict[str, str]]:
+    """The ambient trace context of this thread, or None.  With tracing off
+    there is one only inside a lifecycle span (or a task that adopted one),
+    which is when submission still injects it into `spec.trace_ctx`."""
+    return _current.get()
+
+
+@contextmanager
+def adopt(ctx: Optional[Dict[str, str]]):
+    """Make `ctx` (a spec's trace_ctx) ambient without recording a span:
+    how a task run with tracing off still parents the lifecycle spans
+    opened inside it to its submitter's."""
+    token = _current.set(ctx)
+    try:
+        yield ctx
+    finally:
+        _current.reset(token)
+
+
+def _keep(rec: Dict[str, Any], lifecycle: bool) -> None:
+    global _lifecycle_total
+    with _buffer_lock:
+        _buffer.append(rec)
+        if lifecycle:
+            _lifecycle.append(rec)
+            _lifecycle_total += 1
+
+
 @contextmanager
 def span(name: str, parent: Optional[Dict[str, str]] = None,
-         attrs: Optional[Dict[str, Any]] = None):
+         attrs: Optional[Dict[str, Any]] = None, lifecycle: bool = False):
     """Record one span.  `parent` (e.g. a spec's trace_ctx) wins over the
     ambient context; the new span becomes ambient for the duration, so
-    anything submitted inside parents to it."""
-    if not _enabled:
+    anything submitted inside parents to it.  Yields the span's context;
+    a key added to `attrs` inside the block is recorded with it."""
+    if not (_enabled or lifecycle):
         yield None
         return
     up = parent if parent is not None else _current.get()
@@ -94,28 +112,17 @@ def span(name: str, parent: Optional[Dict[str, str]] = None,
         "span_id": ctx["span_id"],
         "parent_span_id": (up or {}).get("span_id"),
         "start": time.time(),
-        "attrs": dict(attrs or {}),
+        "attrs": attrs if attrs is not None else {},
         "pid": os.getpid(),
     }
     token = _current.set(ctx)
-    otel = _otel()
-    om = otel.start_as_current_span(name) if otel is not None else None
-    if om is not None:
-        om.__enter__()
     try:
         yield ctx
     finally:
-        if om is not None:
-            try:
-                om.__exit__(None, None, None)
-            except Exception:
-                pass
         _current.reset(token)
         rec["end"] = time.time()
-        with _buffer_lock:
-            _buffer.append(rec)
-            while len(_buffer) > _MAX_BUFFER:
-                _buffer.pop(0)
+        rec["attrs"] = dict(rec["attrs"])
+        _keep(rec, lifecycle)
         # Completed spans also land in the process's flight-recorder ring
         # (telemetry.py): a crash dump shows what this process was doing
         # in its last seconds, span by span.
@@ -139,6 +146,7 @@ def record_span(
     parent: Optional[Dict[str, str]] = None,
     attrs: Optional[Dict[str, Any]] = None,
     ctx: Optional[Dict[str, str]] = None,
+    lifecycle: bool = False,
 ) -> Optional[Dict[str, str]]:
     """Record an ALREADY-FINISHED span with explicit epoch timestamps.
 
@@ -148,13 +156,13 @@ def record_span(
     pins the span's own ids so sibling spans recorded earlier can already
     have parented to it; returns the span's context for further chaining.
     """
-    if not _enabled:
+    if not (_enabled or lifecycle):
         return None
     c = {
         "trace_id": (ctx or parent or {}).get("trace_id") or _new_id(16),
         "span_id": (ctx or {}).get("span_id") or _new_id(8),
     }
-    rec = {
+    _keep({
         "name": name,
         "trace_id": c["trace_id"],
         "span_id": c["span_id"],
@@ -163,26 +171,24 @@ def record_span(
         "end": end,
         "attrs": dict(attrs or {}),
         "pid": os.getpid(),
-    }
-    with _buffer_lock:
-        _buffer.append(rec)
-        while len(_buffer) > _MAX_BUFFER:
-            _buffer.pop(0)
+    }, lifecycle)
     return c
 
 
 @contextmanager
-def annotate(name: str):
+def annotate(name: str, lifecycle: bool = False):
     """A span of the PROGRAM's own host work on the profiler's clock.
 
     Opens a `jax.profiler.TraceAnnotation` if and only if jax is already
     imported (this module never imports it: the driver and the head stay
     off jax), so under a profiler session the span lands on `/host:CPU`
-    next to the device planes; with tracing enabled the same interval is
-    also recorded through `record_span`, so `ray_tpu timeline` shows it.
-    With neither on it costs the annotation's one `TraceMe` check."""
+    next to the device planes; with tracing enabled (or `lifecycle`) the
+    same interval is also recorded through `record_span`, so `ray_tpu
+    timeline` shows it.  With neither on it costs the annotation's one
+    `TraceMe` check."""
     jax = sys.modules.get("jax")
-    start = time.time() if _enabled else 0.0
+    on = _enabled or lifecycle
+    start = time.time() if on else 0.0
     try:
         if jax is None:
             yield
@@ -190,15 +196,32 @@ def annotate(name: str):
             with jax.profiler.TraceAnnotation(name):
                 yield
     finally:
-        if _enabled:
-            record_span(name, start, time.time(), parent=_current.get())
+        if on:
+            record_span(name, start, time.time(), parent=_current.get(), lifecycle=lifecycle)
 
 
 def drain_spans() -> List[Dict[str, Any]]:
     """Take the buffered spans (worker flush loops ship them to the head)."""
     with _buffer_lock:
-        out, _buffer[:] = _buffer[:], []
+        out = list(_buffer)
+        _buffer.clear()
     return out
+
+
+def lifecycle_count() -> int:
+    """How many lifecycle spans this process has recorded so far."""
+    return _lifecycle_total
+
+
+def lifecycle_spans(trace_id: Optional[str] = None, since: int = 0) -> List[Dict[str, Any]]:
+    """This process's lifecycle spans (of one trace, if given), oldest
+    first, recorded after the first `since` of them: a poller passes the
+    `lifecycle_count()` it last saw.  Not drained: bounded, the oldest
+    fall out."""
+    with _buffer_lock:
+        kept = list(_lifecycle)
+        first = _lifecycle_total - len(kept)  # the count before the oldest one kept
+    return [s for s in kept[max(since - first, 0):] if trace_id is None or s["trace_id"] == trace_id]
 
 
 def apply_clock_offset(

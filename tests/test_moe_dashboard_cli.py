@@ -136,4 +136,5 @@ def test_cli_status_and_timeline(tmp_path, monkeypatch):
         env={**os.environ, "PYTHONPATH": REPO_ROOT},
     )
     assert out2.returncode == 0, out2.stderr[-500:]
-    assert json.loads(tl_path.read_text()) == []  # fresh runtime: no tasks
+    # fresh runtime: no tasks, only the runtime's own start (a lifecycle span: always recorded)
+    assert [e["name"] for e in json.loads(tl_path.read_text())] == ["runtime::init"]

@@ -289,7 +289,7 @@ def test_train_step_is_split_into_make_batch_and_dispatch(monkeypatch):
 
     names = []
     real = tracing.annotate
-    monkeypatch.setattr(tracing, "annotate", lambda name: names.append(name) or real(name))
+    monkeypatch.setattr(tracing, "annotate", lambda name, **kw: names.append(name) or real(name, **kw))
     ctx = LMTrainContext(CFG, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
     state = ctx.init_state(seed=0)
     toks = np.zeros((2, 128), np.int32)
@@ -301,18 +301,18 @@ def test_train_step_is_split_into_make_batch_and_dispatch(monkeypatch):
 
 
 def test_backend_start_records_import_and_device_open_spans():
+    """Lifecycle spans: recorded with tracing OFF, into the buffer the flush
+    to the head drains and into the store a run's record is built from."""
     from ray_tpu.train.backend import _init_jax_distributed
 
     tracing.drain_spans()
-    was = tracing.is_enabled()
-    tracing.enable_tracing()
-    try:
-        out = _init_jax_distributed("", 1, 0, None)
-    finally:
-        if not was:
-            tracing.disable_tracing()
-    names = [s["name"] for s in tracing.drain_spans()]
-    assert names == ["train::backend::import_jax", "train::backend::device_open"]
+    kept = len(tracing.lifecycle_spans())
+    assert not tracing.is_enabled()
+    out = _init_jax_distributed("", 1, 0, None)
+    want = ["train::backend::import_jax", "train::backend::device_open"]  # no chip_wait off the TPU
+    assert [s["name"] for s in tracing.drain_spans()] == want
+    spans = tracing.lifecycle_spans()[kept:]
+    assert [s["name"] for s in spans] == want and spans[0]["attrs"] == {"already_imported": True}
     assert out["global_devices"] == len(jax.devices())
 
 
@@ -324,8 +324,9 @@ def test_span_catalog_sees_annotate_calls():
         if isinstance(n, ast.Call)]
     assert sorted(filter(None, map(span_names._span_call_name, calls))) == ["a/b", "c"]
     catalog = span_names.load_catalog(os.path.join(ROOT, "ray_tpu/_private/analysis/span_names.txt"))
-    assert {"init_state", "train_step/make_batch", "train_step/dispatch",
-            "train::backend::import_jax", "train::backend::device_open"} <= set(catalog)
+    assert {"init_state", "train_step/make_batch", "train_step/dispatch", "train::fit", "jax::compile",
+            "train::backend::import_jax", "train::backend::chip_wait", "train::backend::device_open",
+            "worker::boot::connect", "runtime::shutdown"} <= set(catalog)
 
 
 # -- the compile cache's key --------------------------------------------------------------
