@@ -74,6 +74,12 @@ LOGITS_AXES = ("act_batch", "act_seq", "act_vocab")
 # The kinds of layer, and the subtree of the parameters that stacks each.
 LAYER_KINDS = {"attention": "layers", "mamba": "mamba_layers"}
 
+# `checkpoint_name`s of a Mamba-2 layer's residuals (`_remat_policy`):
+# `in_proj`'s output before its split into z, x|B|C and dt, and the residual
+# stream after the mixer, as it enters the FFN half.
+SSM_IN_PROJ = "ssm_in_proj"
+SSM_MIXED = "ssm_mixed"
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -95,14 +101,16 @@ class TransformerConfig:
     # output and the flash kernel's log-sum-exp (f32 [B, H, S]), so the
     # kernel's forward runs once per layer; "qkv_attn" = additionally save
     # post-rope q/k/v (skips qkv matmul + rope recompute).  More saved =
-    # more HBM.  A Mamba-2 layer names nothing: under every policy it keeps
-    # its input and recomputes the rest (saving z, the convolved x|B|C and dt
-    # as well costs 1.25 GiB at granite-h-micro's cell, which that step has
-    # to spare now that the loss path holds one bf16 [tokens, vocab] array:
-    # PERF.md section 7).  XLA's own rematerialization duplicates work when a
-    # step compiles over libtpu's limit (none of the cells does since
-    # `head_cross_entropy`); `_dense_ffn`'s tie keeps the dense FFN's matmuls
-    # out of it.
+    # more HBM.  A Mamba-2 layer names what its backward needs of its two
+    # projections (`SSM_IN_PROJ`, `SSM_MIXED`) and "qkv_attn" saves those
+    # too, so under it no mixer's input projection is recomputed, whatever
+    # the layer's kind; under "attn" and None a Mamba-2 layer keeps its input
+    # only.  Convolution, scan and gated norm run again under every policy:
+    # the scan's backward needs the last two, and keeping the convolved x|B|C
+    # cost more than its kernel's second run (PERF.md section 6, PR 36).
+    # XLA's own rematerialization duplicates work when a step compiles over
+    # libtpu's limit (none of the cells does since `head_cross_entropy`);
+    # `_dense_ffn`'s tie keeps the dense FFN's matmuls out of it.
     remat_policy: Optional[str] = None
     attention_impl: Optional[str] = None  # None=auto, see ops.attention
     # Microbatches per pipeline-stage schedule when the rules shard the
@@ -534,7 +542,17 @@ def _mamba_layer(
     `ssm/proj` (ln1, in_proj, out_proj, the residual add), `ssm/conv`
     (convolution + SiLU, one unit with its own backward: on TPU the kernels
     `ssm_conv_fwd` / `ssm_conv_bwd`; softplus; the gated RMSNorm, float32 over
-    the scan's bf16 output), `ssm/scan` (the SSD, named in `ops/ssm.py`)."""
+    the scan's bf16 output), `ssm/scan` (the SSD, named in `ops/ssm.py`).
+
+    Two residuals carry a `checkpoint_name`, for `_remat_policy` to save:
+    `SSM_IN_PROJ`, the one array `in_proj` gives, and `SSM_MIXED`, the
+    residual stream after `out_proj`.  With both kept the backward runs
+    neither projection's forward again: `in_proj`'s consumers start from the
+    saved array, and `out_proj`'s forward fed only the FFN half, which starts
+    from the saved stream (its backward needs `y`, so convolution, scan and
+    gated norm still run again; `ln1` too, for `in_proj`'s weight gradient)."""
+    from jax.ad_checkpoint import checkpoint_name
+
     del positions  # a recurrence needs none
     c, dt, ssm = config, config.dtype, layer_params["ssm"]
     constrain = _constrainer(rules, mesh)
@@ -544,6 +562,7 @@ def _mamba_layer(
         with jax.named_scope("ssm/proj"):
             h = rms_norm(x, layer_params["ln1"], c.norm_eps)
             zxbcdt = jnp.einsum("bse,ef->bsf", h, ssm["in_proj"].astype(dt))
+            zxbcdt = checkpoint_name(zxbcdt, SSM_IN_PROJ)
             z, xbc, step = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], axis=-1)
         with jax.named_scope("ssm/conv"):
             xbc = causal_conv1d_silu(xbc, ssm["conv_w"], ssm["conv_b"], **sharded)
@@ -562,6 +581,7 @@ def _mamba_layer(
         with jax.named_scope("ssm/proj"):
             out = jnp.einsum("bsf,fe->bse", y, ssm["out_proj"].astype(dt))
             x = x + _scaled(c, constrain(out, ("act_batch", "act_seq", "act_embed")))
+            x = checkpoint_name(x, SSM_MIXED)
     return _ffn_half(x, layer_params, c, constrain, rules, mesh)
 
 
@@ -593,16 +613,20 @@ _split_runs.defvjp(_split_runs_fwd, _split_runs_bwd)
 
 def _remat_policy(config: TransformerConfig):
     """Validated checkpoint policy for the configured remat granularity
-    (shared by the scan and pipeline paths).  It says what JAX recomputes;
-    over libtpu's limit XLA's pass may duplicate more (see `_dense_ffn`)."""
+    (shared by the scan and pipeline paths, and by both kinds of layer: a
+    name that a layer's kind does not carry matches nothing in it).  It says
+    what JAX recomputes; over libtpu's limit XLA's pass may duplicate more
+    (see `_dense_ffn`)."""
     # The attention op names its own residuals (ops/attention.py): a policy
     # that keeps the output without the log-sum-exp would still re-run the
     # kernel's forward in the backward pass.
     if config.remat_policy == "attn":
         return jax.checkpoint_policies.save_only_these_names(ATTN_OUT, ATTN_LSE)
     if config.remat_policy == "qkv_attn":
+        # No mixer's input projection is recomputed: attention's q, k, v; a
+        # Mamba-2 layer's two named residuals (`_mamba_layer`).
         return jax.checkpoint_policies.save_only_these_names(
-            "q", "k", "v", ATTN_OUT, ATTN_LSE
+            "q", "k", "v", ATTN_OUT, ATTN_LSE, SSM_IN_PROJ, SSM_MIXED
         )
     if config.remat_policy is None:
         # Save nothing per layer: the backward re-runs the whole layer, the

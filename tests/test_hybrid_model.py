@@ -157,6 +157,43 @@ def test_every_gradient_leaf_equals_the_reference(tiny, leaf):
     close(got, want)
 
 
+@pytest.mark.parametrize("remat_policy", ["qkv_attn", "attn", None])
+def test_every_gradient_leaf_under_each_remat_policy_equals_the_unchecked_steps(tiny, remat_policy):
+    """What a policy saves changes which forward values the backward reads,
+    never a value: same matmuls, dtypes and order (float32 rounding where XLA
+    fuses the two programs differently)."""
+    ctx = one_device_ctx(dataclasses.replace(tiny["cfg"], remat=True, remat_policy=remat_policy))
+    (loss, _), grads = jax.jit(jax.value_and_grad(ctx._loss, has_aux=True))(tiny["params"], tiny["batch"])
+    np.testing.assert_allclose(float(loss), float(tiny["loss"]), rtol=1e-6)
+    got, want = (dict(jax.tree_util.tree_flatten_with_path(g)[0]) for g in (grads, tiny["grads"]))
+    assert len(got) == len(LEAVES)
+    for path, leaf in got.items():
+        close(leaf, want[path], rtol=1e-5)
+
+
+def test_qkv_attn_saves_a_mamba_layers_two_named_residuals_and_nothing_wide_in_float32(tiny):
+    """One Mamba-2 layer in the model's dtype of the cells (bf16) under the
+    policy: beside its arguments it keeps `in_proj`'s one [B, S, 2·inner + 2·N
+    + heads] array and the [B, S, d] stream after `out_proj`, and no float32
+    [B, S, features] array; under `attn` nothing of the layer's own."""
+    from jax._src.ad_checkpoint import saved_residuals  # the list `jax.ad_checkpoint.print_saved_residuals` prints
+
+    cfg = dataclasses.replace(tiny["cfg"], dtype=jnp.bfloat16, remat=True, remat_policy="qkv_attn")
+    layer = jax.tree_util.tree_map(lambda a: a[0], tiny["params"]["mamba_layers"])
+    x = jnp.zeros((2, SEQ, cfg.d_model), jnp.bfloat16)
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+
+    def saved(config):
+        run = jax.checkpoint(lambda p, x: transformer._mamba_layer(x, p, None, config, None)[0],
+                             policy=transformer._remat_policy(config))
+        return [(aval.shape, aval.dtype) for aval, why in saved_residuals(run, layer, x)
+                if "from the argument" not in why]
+
+    assert sorted(saved(cfg)) == [
+        ((2, SEQ, cfg.d_model), jnp.bfloat16), ((2, SEQ, 2 * inner + 2 * cfg.ssm_state + cfg.ssm_heads), jnp.bfloat16)]
+    assert saved(dataclasses.replace(cfg, remat_policy="attn")) == []
+
+
 def _primitives(jaxpr):
     """Every equation of a jaxpr and of the jaxprs in its parameters."""
     for eqn in jaxpr.eqns:

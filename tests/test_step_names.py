@@ -4,6 +4,9 @@ profiler's clock, and a compile-cache key that tells two builds apart when
 only those names differ."""
 
 import ast
+import collections
+import dataclasses
+import functools
 import os
 import re
 import subprocess
@@ -136,13 +139,19 @@ HYBRID_CFG = TransformerConfig.tiny(
 )
 
 
-@pytest.fixture(scope="module")
-def hybrid_lowered_for_tpu():
-    ctx = LMTrainContext(HYBRID_CFG, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+@functools.lru_cache(maxsize=None)
+def _hybrid_step_lowered_for_tpu(remat_policy):
+    cfg = dataclasses.replace(HYBRID_CFG, remat_policy=remat_policy)
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
     traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
     return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+@pytest.fixture(scope="module")
+def hybrid_lowered_for_tpu():
+    return _hybrid_step_lowered_for_tpu(HYBRID_CFG.remat_policy)
 
 
 @pytest.mark.parametrize("scope", SSM_SCOPES)
@@ -187,13 +196,46 @@ def test_lowered_hybrid_step_keeps_the_mixers_new_ops_inside_its_scopes(hybrid_l
     assert directions >= ({"checkpoint/"} if "bwd" in op else {"", "checkpoint/rematted_computation/"})
 
 
+def _locations(lowered):
+    """{`#locN`: the path it names} of a lowered step's text."""
+    return dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', lowered, flags=re.M))
+
+
+def _recomputed_matmuls(lowered):
+    """How many `dot_general`s of the lowered step sit under each region of a
+    layer's recompute: {the scope path after `rematted_computation/`: count}."""
+    locs = _locations(lowered)
+    counts = collections.Counter()
+    for ref in re.findall(r"stablehlo\.dot_general.*loc\((#loc\d+)\)\s*$", lowered, flags=re.M):
+        region = re.search(r"^checkpoint/rematted_computation/(layer/\w+(?:/ssm/\w+)?)/", locs.get(ref, ""))
+        if region:
+            counts[region.group(1)] += 1
+    return counts
+
+
+@pytest.mark.parametrize("remat_policy, projections, attention", [("qkv_attn", 0, 1), ("attn", 4, 4), (None, 4, 4)])
+def test_only_qkv_attn_keeps_a_mamba_layers_projections_out_of_the_recompute(remat_policy, projections, attention):
+    """PR 36: `_mamba_layer` names `in_proj`'s output and the residual stream
+    after `out_proj`, and `qkv_attn` saves both, so neither matmul runs again
+    in the backward (two runs of Mamba-2 layers here: 2 x 2 otherwise).  The
+    scan's own forward and the FFN's `gate` + `up` + `down` are recomputed
+    under every policy, and so is `ln1`, which `in_proj`'s weight gradient
+    reads (`test_lowered_hybrid_step_names_the_mixers_regions...[ssm/proj]`).
+    The one attention layer recomputes `wo` alone when q, k, v are saved."""
+    lowered = _hybrid_step_lowered_for_tpu(remat_policy)
+    counts = _recomputed_matmuls(lowered)
+    assert counts["layer/attn_proj/ssm/proj"] == projections and counts["layer/attn_proj"] == attention
+    assert counts["layer/attn_core/ssm/scan"] == 8 and counts["layer/mlp"] == 6
+    assert '"checkpoint/rematted_computation/layer/attn_proj/ssm/proj/' in lowered
+
+
 # -- the head and the cross entropy (PERF.md section 3, PR 34) -------------------------------
 
 
 def _paths_of_tokens_by_vocab_ops(text):
     """The path of every op of the step's main function that takes or gives
     a `[batch, 128, VOCAB]` tensor."""
-    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, flags=re.M))
+    locs = _locations(text)
     paths, in_main = [], False
     for line in text.splitlines():
         if line.lstrip().startswith("func.func"):

@@ -120,9 +120,10 @@ def test_hybrid_step_lowers_for_tpu_with_each_flash_kernel_once(n_devices, spec,
     """One attention layer, so each flash kernel exactly once, at head size 64;
     the scan is XLA's to partition (under `tp` its heads are replicated).  The
     Mamba-2 layers lie in two runs, each one scan body: per run the
-    convolution's forward kernel twice (forward, and the recompute of a layer
-    that keeps its input only) and its backward kernel once, under shard_map
-    on a mesh of more than one device like the flash kernels."""
+    convolution's forward kernel twice (forward, and the recompute: `qkv_attn`
+    keeps `in_proj`'s output, not the convolution's, which measured slower
+    when kept; PERF.md section 6, PR 36) and its backward kernel once, under
+    shard_map on a mesh of more than one device like the flash kernels."""
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=HYBRID)
     assert _mosaic_kernels(text) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
                                      "ssm_conv_fwd": 4, "ssm_conv_bwd": 2}
