@@ -35,7 +35,7 @@ from ray_tpu.parallel.sharding import (
     resolve_rules,
     tree_shardings,
 )
-from ray_tpu.train.run_record import DISPATCH, MAKE_BATCH, StepClock
+from ray_tpu.train.run_record import DISPATCH, MAKE_BATCH, StepClock, note_step_counters
 from ray_tpu.util import tracing
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -291,6 +291,9 @@ class LMTrainContext:
         with tracing.annotate("train_step/dispatch"), self.mesh:
             state, metrics = self._train_step(state, batch)
         clock.mark(DISPATCH, t0)
+        if "moe_held_rows_mean" in metrics:
+            # counters of the run's record (train/run_record.py): kept as device scalars, fetched at a poll
+            note_step_counters({k: metrics[k] for k in ("moe_held_rows_mean", "moe_held_rows_max")})
         return state, metrics
 
     def apply(self, params, tokens) -> jax.Array:

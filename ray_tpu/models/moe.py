@@ -1,16 +1,26 @@
 """Mixture-of-experts FFN for the transformer layer: dropless, sort-and-group.
 
-No counterpart in the reference (SURVEY §2.4: EP absent).  A configuration
-with `n_experts` set gets this block where a dense one has its SwiGLU
-(`transformer._layer`, scope `layer/mlp`); `TransformerConfig` is the one
-description of the model, this module reads `d_model`, `d_ff` (ONE expert's
-width), `n_experts`, `experts_per_token`, `norm_topk_prob`, `dtype`.
+No counterpart in the reference (SURVEY §2.4: EP absent).  A layer whose FFN
+kind is "experts" gets this block where a dense one has its SwiGLU
+(`transformer._ffn_half`, scope `layer/mlp`); `TransformerConfig` is the one
+description of the model, this module reads `d_model`, `expert_width` (ONE
+expert's width), `n_experts`, `experts_per_token`, `norm_topk_prob`,
+`router_activation`, `routed_scaling_factor`, `n_shared_experts`,
+`n_experts_held` / `first_expert_held`, `dtype`.
 
 The layer, on T tokens with K choices each out of E experts:
 
-- `moe/router`: logits `h @ router` and their softmax in float32 (precision
-  HIGHEST: on a TPU a float32 matmul is otherwise bf16 passes, and routing is
-  discrete), `lax.top_k`, and the statistics the router losses are made of.
+- `moe/router`: logits `h @ router` in float32 (precision HIGHEST: on a TPU a
+  float32 matmul is otherwise bf16 passes, and routing is discrete), the
+  scores, `lax.top_k`, and the statistics the router losses are made of.  Two
+  routers.  "softmax" (OLMoE): the scores are the softmax over all E, the top
+  K of them are the gate values, renormalised to sum to one under
+  `norm_topk_prob`.  "sigmoid" (DeepSeek-V3's, Kimi Linear's): the scores are
+  `sigmoid(logits)`, each expert's own; the CHOICE is the top K of
+  `score + router_bias` (the stored `e_score_correction_bias`, which takes
+  part in the choice alone: it reaches no gate value and gets no gradient),
+  the gate values are the chosen SCORES, renormalised under `norm_topk_prob`,
+  times `routed_scaling_factor`.
 - `moe/dispatch`: a stable sort of the T*K assignments by expert, the E group
   sizes, a gather of the token rows into expert order, and the T*K gate values
   into the same order (by a sort: `_permuted`).
@@ -19,6 +29,21 @@ The layer, on T tokens with K choices each out of E experts:
   (`ops/grouped_matmul.py`: Pallas kernels when lowered for TPU, an XLA form
   of the same schedule elsewhere).
 - `moe/combine`: rows back into token order, summed over each token's K rows.
+- `moe/shared` (with `n_shared_experts`): one SwiGLU of `n_shared_experts`
+  experts' width that every token goes through, added to the routed result.
+
+Held experts (`n_experts_held`): the layer is TOLD which experts it holds,
+`first_expert_held .. + n_experts_held` of the E the router scores, as one
+rank of an expert-parallel deployment is.  The router keeps its width E and
+its K choices; the weights are `[n_experts_held, ...]`; the layer computes
+the held experts' part for the tokens routed to them, plus the shared
+expert, and what the absent experts would have added is left out.  That is
+`_experts(first_expert=...)`, the form a mesh's `expert` axis uses, without
+its `psum`: nothing stands in for the absent ranks.  `_experts` still sorts
+and gathers all T*K assignments (the rows of absent experts sort behind the
+last held group, where the grouped matmuls visit no tile); a dispatch sized
+by the held rows waits for a `perf_opt` (PERF.md section 7).  The rows each
+held expert got go out with the statistics (`held_rows`).
 
 The gate value of an assignment scales its row where the row is `d_ff` wide,
 BEFORE the down projection, and not the `d_model`-wide row that comes out of
@@ -77,12 +102,17 @@ from ray_tpu.parallel.sharding import Rules, _fit_spec, logical_to_spec
 
 def moe_param_axes(config: Any) -> Dict:
     """Logical axes of ONE layer's expert leaves (the stack adds `layers`)."""
-    return {
+    axes = {
         "router": ("embed", "expert"),
         "w_gate": ("expert", "embed", "mlp"),
         "w_up": ("expert", "embed", "mlp"),
         "w_down": ("expert", "mlp", "embed"),
     }
+    if config.router_activation == "sigmoid":
+        axes["router_bias"] = (None,)
+    if config.n_shared_experts:
+        axes["shared"] = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
+    return axes
 
 
 def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
@@ -92,17 +122,32 @@ def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
     c = config
     k1, k2, k3, k4 = jax.random.split(key, 4)
     scale = c.d_model ** -0.5
-    E, D, F = c.n_experts, c.d_model, c.d_ff
+    down_scale = scale if out_scale is None else out_scale
+    E, D, F = c.n_experts, c.d_model, c.expert_width
+    held = E if c.n_experts_held is None else c.n_experts_held  # the router scores all E
 
     def init(k, shape, s):
         return (jax.random.normal(k, leading + shape, jnp.float32) * s).astype(c.param_dtype)
 
-    return {
+    params = {
         "router": init(k1, (D, E), scale),
-        "w_gate": init(k2, (E, D, F), scale),
-        "w_up": init(k3, (E, D, F), scale),
-        "w_down": init(k4, (E, F, D), scale if out_scale is None else out_scale),
+        "w_gate": init(k2, (held, D, F), scale),
+        "w_up": init(k3, (held, D, F), scale),
+        "w_down": init(k4, (held, F, D), down_scale),
     }
+    if c.router_activation == "sigmoid":
+        # zero, and the job leaves it so: its published update follows the
+        # experts' load, outside the gradient (a recipe, not a key of a config)
+        params["router_bias"] = jnp.zeros(leading + (E,), c.param_dtype)
+    if c.n_shared_experts:
+        ks = jax.random.split(jax.random.fold_in(key, 1), 3)
+        Fs = c.n_shared_experts * F
+        params["shared"] = {
+            "w_gate": init(ks[0], (D, Fs), scale),
+            "w_up": init(ks[1], (D, Fs), scale),
+            "w_down": init(ks[2], (Fs, D), down_scale),
+        }
+    return params
 
 
 # -- rows into expert order and back: gathers in both directions -------------------
@@ -179,10 +224,18 @@ def _route(params: Dict, tokens: jax.Array, config: Any):
     E, K = c.n_experts, c.experts_per_token
     logits = jnp.dot(tokens.astype(jnp.float32), params["router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, expert_idx = jax.lax.top_k(probs, K)
+    if c.router_activation == "sigmoid":
+        probs = jax.nn.sigmoid(logits)  # each expert's own score
+        bias = jax.lax.stop_gradient(params["router_bias"].astype(jnp.float32))
+        _, expert_idx = jax.lax.top_k(probs + bias, K)  # the bias takes part in the choice alone
+        gates = jnp.take_along_axis(probs, expert_idx, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, expert_idx = jax.lax.top_k(probs, K)
     if c.norm_topk_prob:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if c.routed_scaling_factor != 1.0:
+        gates = gates * c.routed_scaling_factor
     stats = {
         # f[k, e]: share of tokens whose k-th choice is e (no gradient)
         "choice_share": jnp.mean(jax.nn.one_hot(expert_idx, E, dtype=jnp.float32), axis=0),
@@ -195,8 +248,8 @@ def _route(params: Dict, tokens: jax.Array, config: Any):
 def _experts(tokens, expert_idx, gates, w_gate, w_up, w_down, n_experts, first_expert=None):
     """Dispatch, grouped matmuls and combine for the experts `first_expert ..
     first_expert + w_gate.shape[0]` of `n_experts` (None: all of them, on one
-    device).  tokens [T, D], expert_idx / gates [T, K]; returns those
-    experts' part of the output, [T, D].  The gate values go to their rows in
+    device).  tokens [T, D], expert_idx / gates [T, K]; returns (those
+    experts' part of the output, [T, D]; the rows each of them got, int32).  The gate values go to their rows in
     expert order and multiply them in the pass that makes `silu(gate) * up`,
     so nothing behind `w_down` is a residual of the backward (module
     docstring).  Assignments to other experts sort behind the last group,
@@ -226,7 +279,7 @@ def _experts(tokens, expert_idx, gates, w_gate, w_up, w_down, n_experts, first_e
     with jax.named_scope("moe/combine"):
         if first_expert is not None:
             out = jnp.where(mine, out, 0)
-        return _to_token_order(out, order, inverse, k)
+        return _to_token_order(out, order, inverse, k), group_sizes
 
 
 def moe_ffn(
@@ -238,9 +291,11 @@ def moe_ffn(
     mesh=None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x [B, S, D] (the normed hidden state) -> (y [B, S, D], this layer's
-    router statistics: `choice_share` [K, E], `mean_prob` [E], `z` []).
-    `_layer` calls it inside its `layer/mlp` scope (PERF.md section 3)."""
+    router statistics: `choice_share` [K, E], `mean_prob` [E], `z` [], and
+    with held experts `held_rows` [n_experts_held]).  `_ffn_half` calls it
+    inside its `layer/mlp` scope (PERF.md section 3)."""
     B, S, D = x.shape
+    held = config.n_experts_held is not None
     with jax.named_scope("moe/router"):
         expert_idx, gates, stats = _route(params, x.reshape(B * S, D), config)
     expert_idx = expert_idx.reshape(B, S, -1)
@@ -253,28 +308,43 @@ def moe_ffn(
         ax = rules.get("expert")
         if ax in mesh.axis_names and mesh.shape[ax] > 1 and config.n_experts % mesh.shape[ax] == 0:
             expert_ax = ax
+    if held and across_devices:
+        raise ValueError("n_experts_held is one rank's share of the experts: it runs on one "
+                         "device, not beside a mesh that shards tokens or experts")
 
     def body(xb, idx, g, w_gate, w_up, w_down):
         b = xb.shape[0]
-        first = None
+        first = config.first_expert_held if held else None
         if expert_ax is not None:
             first = jax.lax.axis_index(expert_ax) * w_gate.shape[0]
-        y = _experts(xb.reshape(b * S, D), idx.reshape(b * S, -1), g.reshape(b * S, -1),
-                     w_gate, w_up, w_down, config.n_experts, first)
+        y, rows = _experts(xb.reshape(b * S, D), idx.reshape(b * S, -1), g.reshape(b * S, -1),
+                           w_gate, w_up, w_down, config.n_experts, first)
         if expert_ax is not None:
             y = jax.lax.psum(y, expert_ax)
-        return y.reshape(b, S, D)
+        return y.reshape(b, S, D), rows
+
+    def with_shared(y):
+        if not config.n_shared_experts:
+            return y
+        with jax.named_scope("moe/shared"):
+            w = params["shared"]
+            gate = jnp.einsum("bse,ef->bsf", x, w["w_gate"].astype(x.dtype))
+            up = jnp.einsum("bse,ef->bsf", x, w["w_up"].astype(x.dtype))
+            return y + jnp.einsum("bsf,fe->bse", jax.nn.silu(gate) * up, w["w_down"].astype(x.dtype))
 
     if not across_devices:
-        return body(x, expert_idx, gates, *weights), stats
+        y, rows = body(x, expert_idx, gates, *weights)
+        if held:
+            stats["held_rows"] = rows.astype(jnp.float32)
+        return with_shared(y), stats
     tok_spec = _fit_spec(x.shape, logical_to_spec(("act_batch", None, None), rules), mesh)
     w_spec = P(expert_ax, None, None)
     y = jax.shard_map(
-        body, mesh=mesh,
+        lambda *a: body(*a)[0], mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec, w_spec, w_spec, w_spec),
         out_specs=tok_spec, check_vma=False,
     )(x, expert_idx, gates, *weights)
-    return y, stats
+    return with_shared(y), stats
 
 
 def router_losses(stats: Dict[str, jax.Array], config: Any) -> Dict[str, jax.Array]:
@@ -284,9 +354,15 @@ def router_losses(stats: Dict[str, jax.Array], config: Any) -> Dict[str, jax.Arr
     share = jnp.mean(stats["choice_share"], axis=0)  # f over all layers' tokens, [K, E]
     prob = jnp.mean(stats["mean_prob"], axis=0)  # P, [E]
     load = jnp.sum(stats["choice_share"], axis=1)  # [L, E], sums to K per layer
-    return {
+    out = {
         "moe_lb_loss": config.n_experts * jnp.sum(share * prob[None, :]),
         "moe_z_loss": jnp.mean(stats["z"]),
         # tokens at the busiest expert over the mean, in the worst layer
         "moe_load_max_over_mean": jnp.max(jnp.max(load, axis=1) / jnp.mean(load, axis=1)),
     }
+    if "held_rows" in stats:
+        # rows a held expert multiplied this step: the mean over held experts
+        # and layers, and the busiest held expert of any layer
+        out["moe_held_rows_mean"] = jnp.mean(stats["held_rows"])
+        out["moe_held_rows_max"] = jnp.max(stats["held_rows"])
+    return out

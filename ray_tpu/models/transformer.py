@@ -1,12 +1,17 @@
 """Flagship model: a pre-norm decoder-only transformer, TPU-first.
 
 What one `TransformerConfig` expresses: a stack of pre-norm RMSNorm layers,
-each a MIXER and an FFN around a residual stream.  The mixer is causal
-softmax attention (GQA, rotary embedding or none, optional QK-norm, the
-published softmax scale) or a Mamba-2 selective state-space layer
-(`layer_types`); the FFN is a dense SwiGLU or a dropless top-k mixture of
-SwiGLU experts (`n_experts`).  Mistral, InternLM2, OLMoE and the Granite 4.0-H
-hybrids run through it at their published widths (benchmarks/configs/).
+each a (MIXER, FFN) pair around a residual stream.  The mixer
+(`layer_types`) is causal softmax attention (GQA, rotary embedding or none,
+optional QK-norm, the published softmax scale), a Mamba-2 selective
+state-space layer, Kimi Delta Attention ("kda", a gated delta rule with a
+decay per channel) or latent attention without rotary embedding ("mla": keys
+and values expanded from one low-rank latent, q/k heads wider than v heads);
+the FFN (`ffn_types`) is a dense SwiGLU or a dropless top-k mixture of SwiGLU
+experts (`n_experts`; softmax or sigmoid router, an optional shared expert,
+all experts or one rank's share of them: models/moe.py).  Mistral, InternLM2,
+OLMoE, the Granite 4.0-H hybrids and Kimi Linear run through it at their
+published widths (benchmarks/configs/).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -18,10 +23,13 @@ drive.  Design:
 - Every parameter leaf has a *logical axes* annotation (`param_axes`), mapped
   to mesh axes by ray_tpu.parallel.sharding rules — one model, every
   parallelism strategy (DP/FSDP/TP/SP via rules, not rewrites).
-- Layers are stacked per KIND on a leading `layers` axis (`params["layers"]`
-  the attention layers, `params["mamba_layers"]` the Mamba-2 ones) and the
-  stack runs as ONE `lax.scan` per maximal run of one kind (one compiled body
-  per kind, O(1) compile time in depth), with optional `jax.checkpoint`
+- Layers are stacked per (mixer, FFN) PAIR on a leading `layers` axis
+  (`params["layers"]` the attention layers, `params["mamba_layers"]` the
+  Mamba-2 ones, `kda_layers`, `mla_layers`; a mixer that the model pairs with
+  BOTH kinds of FFN has one stack for each, `kda_layers_dense` and
+  `kda_layers_experts`: `TransformerConfig.stack_name`) and the stack runs as
+  ONE `lax.scan` per maximal run of one pair (one compiled body per pair,
+  O(1) compile time in depth), with optional `jax.checkpoint`
   rematerialization for HBM.  A homogeneous model is the one-run case.
 - Attention dispatches to the pallas flash kernel when lowered for TPU
   (under shard_map when there is a mesh), the XLA forms otherwise
@@ -49,6 +57,26 @@ arXiv:2405.21060), with x [B, S, d], every RMSNorm with a learned scale and
   (`ops/ssm.py`, in its chunked form).  Then
   `y = RMSNorm(y * silu(z))` over all d_inner channels and
   `out_proj: d_inner -> d`.
+
+Kimi Linear (arXiv:2510.26692; `model_type: kimi_linear`), no bias and no
+rotary embedding anywhere, H heads of size D = `kda_head_dim` in a KDA layer:
+
+- KDA layer: `[q | k | v] = silu(causal_depthwise_conv1d(x W_qkv))`, width
+  `kda_conv`, three convolutions over H*D channels each (one call over the
+  3*H*D); per head `q <- q / |q|_2 * D^-0.5`, `k <- k / |k|_2`; the log decay
+  `g = -exp(A_log[h]) * softplus((x W_f_down) W_f_up + dt_bias)` per channel,
+  float32; `beta = sigmoid(x W_beta)` per head; the recurrence of
+  `ops/kda.py` (state [D, D] per head, float32) in its chunked form;
+  `o <- RMSNorm_head(o) * sigmoid((x W_g_down) W_g_up)` (norm over each
+  head's D with one learned scale [D]; both gates low-rank, d -> D -> H*D);
+  `W_o: H*D -> d`.
+- MLA layer, `n_heads` heads: `q = x W_q -> [H, nope + rope]`;
+  `[c | k_pe] = x W_kva -> [kv_lora_rank | rope]`; `c <- RMSNorm(c)`;
+  `[k_nope | v] = c W_kvb -> [H, nope | v_head_dim]`; `k = [k_nope | k_pe]`,
+  the one `k_pe` shared by the heads and, the model being NoPE, not rotated;
+  causal softmax of `q k^T * (nope + rope)^-0.5`; `W_o: H * v_head_dim -> d`.
+- FFN of an "experts" layer: the sigmoid router, the shared expert and the
+  held experts of `models/moe.py`.
 """
 
 from __future__ import annotations
@@ -64,6 +92,7 @@ import jax.numpy as jnp
 from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, dot_product_attention
 from ray_tpu.ops.rotary import apply_rope
+from ray_tpu.ops.kda import kda_chunked
 from ray_tpu.ops.ssm import causal_conv1d_silu, ssd_chunked
 from ray_tpu.parallel.sharding import Rules, with_logical_constraint
 
@@ -71,14 +100,24 @@ from ray_tpu.parallel.sharding import Rules, with_logical_constraint
 # The logical axes of the logits (and of their cotangent).
 LOGITS_AXES = ("act_batch", "act_seq", "act_vocab")
 
-# The kinds of layer, and the subtree of the parameters that stacks each.
-LAYER_KINDS = {"attention": "layers", "mamba": "mamba_layers"}
+# The kinds of mixer, and the subtree of the parameters that stacks each
+# (`TransformerConfig.stack_name`); the kinds of FFN.
+LAYER_KINDS = {"attention": "layers", "mamba": "mamba_layers", "kda": "kda_layers", "mla": "mla_layers"}
+FFN_KINDS = ("dense", "experts")
 
 # `checkpoint_name`s of a Mamba-2 layer's residuals (`_remat_policy`):
 # `in_proj`'s output before its split into z, x|B|C and dt, and the residual
 # stream after the mixer, as it enters the FFN half.
 SSM_IN_PROJ = "ssm_in_proj"
 SSM_MIXED = "ssm_mixed"
+# Of a KDA layer's: the fused q|k|v projection before its convolution, the
+# two low-rank gates' narrow halves with beta's logits (one array), and the
+# residual stream after the mixer.  Of an MLA layer's, beside attention's own
+# q, k, v: the residual stream after the mixer.
+KDA_QKV = "kda_qkv"
+KDA_LOW = "kda_low"
+KDA_MIXED = "kda_mixed"
+MLA_MIXED = "mla_mixed"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,20 +158,34 @@ class TransformerConfig:
     # without slicing microbatches below MXU-efficient sizes.
     pp_microbatches: Optional[int] = None
     # Sparse experts (models/moe.py).  `n_experts` None = a dense SwiGLU of
-    # width `d_ff`; set, every layer's FFN is `n_experts` SwiGLU experts of
-    # width `d_ff` each, `experts_per_token` of them per token by the top-k
-    # of a softmax over all, dropless.  `norm_topk_prob`: renormalise the
-    # chosen gate values to sum to one.  The two coefficients weigh the
+    # width `d_ff`; set, the FFN of every layer (or of the layers `ffn_types`
+    # says) is `n_experts` SwiGLU experts of width `moe_d_ff` each (None =
+    # `d_ff`), `experts_per_token` of them per token by the top-k of the
+    # router's scores, dropless.  `router_activation`: "softmax" over all
+    # experts, or "sigmoid" per expert with a stored bias that takes part in
+    # the choice alone.  `norm_topk_prob`: renormalise the chosen gate values
+    # to sum to one; `routed_scaling_factor` then multiplies them.
+    # `n_shared_experts`: one more SwiGLU, that many experts wide, which every
+    # token goes through.  `n_experts_held`: the layer holds only the experts
+    # `first_expert_held .. + n_experts_held` of the `n_experts` its router
+    # scores, one rank's share of an expert-parallel deployment, and computes
+    # their part of the result alone.  The two coefficients weigh the
     # load-balancing loss and the router z-loss in the training objective.
     n_experts: Optional[int] = None
     experts_per_token: int = 0
     norm_topk_prob: bool = False
     router_aux_loss_coef: float = 0.0
     router_z_loss_coef: float = 0.0
+    moe_d_ff: Optional[int] = None
+    router_activation: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    n_shared_experts: int = 0
+    n_experts_held: Optional[int] = None
+    first_expert_held: int = 0
     # RMSNorm with a learned scale over the whole projected q and k, before
     # RoPE (OLMoE, OLMo 2).
     qk_norm: bool = False
-    # The mixer of each layer, "attention" or "mamba", one entry per layer;
+    # The mixer of each layer, one of `LAYER_KINDS`, one entry per layer;
     # None = attention everywhere.  The Mamba-2 sizes are read only when some
     # layer is "mamba": heads x head size = the mixer's inner width, the
     # state size N per head, the width of the causal depthwise convolution.
@@ -141,6 +194,21 @@ class TransformerConfig:
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_conv: int = 4
+    # The FFN of each layer, "dense" or "experts", one entry per layer; None =
+    # experts everywhere when `n_experts` is set, dense everywhere otherwise.
+    ffn_types: Optional[Tuple[str, ...]] = None
+    # Kimi Delta Attention, read only when some layer is "kda": heads, the
+    # one head size of q, k and v (also the width of the two low-rank gates),
+    # the width of the three causal depthwise convolutions.
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    # Latent attention, read only when some layer is "mla" (`n_heads` heads):
+    # the latent's width, the two parts of a q/k head, the size of a v head.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # Published multipliers (Granite's muP form), 1.0 each = absent: on the
     # embeddings, on each block's output before it joins the residual
     # stream, and a divisor of the logits.  `attention_scale` multiplies
@@ -159,38 +227,88 @@ class TransformerConfig:
                     f"layer_types needs n_layers={self.n_layers} entries out of {LAYER_KINDS}, "
                     f"got {len(self.layer_types)} with {sorted(unknown)} unknown"
                 )
-            if self.n_experts is not None:
-                raise ValueError("a stack with layer_types runs a dense FFN only (no n_experts)")
             if "mamba" in self.layer_types and not (
                 self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
             ):
                 raise ValueError("a mamba layer needs ssm_heads, ssm_head_dim and ssm_state")
+            if "kda" in self.layer_types and not (self.kda_heads > 0 and self.kda_head_dim > 0):
+                raise ValueError("a kda layer needs kda_heads and kda_head_dim")
+            if "mla" in self.layer_types and not (
+                self.kv_lora_rank > 0 and self.qk_nope_head_dim > 0 and self.v_head_dim > 0
+            ):
+                raise ValueError("an mla layer needs kv_lora_rank, qk_nope_head_dim and v_head_dim")
+        if self.ffn_types is not None:
+            object.__setattr__(self, "ffn_types", tuple(self.ffn_types))
+            unknown = set(self.ffn_types) - set(FFN_KINDS)
+            if unknown or len(self.ffn_types) != self.n_layers:
+                raise ValueError(
+                    f"ffn_types needs n_layers={self.n_layers} entries out of {FFN_KINDS}, "
+                    f"got {len(self.ffn_types)} with {sorted(unknown)} unknown"
+                )
+            if "experts" in self.ffn_types and self.n_experts is None:
+                raise ValueError("an experts layer needs n_experts")
         if self.n_experts is not None and not 0 < self.experts_per_token <= self.n_experts:
             raise ValueError(
                 f"n_experts={self.n_experts} needs 0 < experts_per_token <= n_experts, "
                 f"got {self.experts_per_token}"
+            )
+        if self.router_activation not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_activation {self.router_activation!r}")
+        if self.n_experts_held is not None and not (
+            self.n_experts is not None and self.n_experts_held > 0 and self.first_expert_held >= 0
+            and self.first_expert_held + self.n_experts_held <= self.n_experts
+        ):
+            raise ValueError(
+                f"n_experts_held={self.n_experts_held} from {self.first_expert_held} "
+                f"is no share of n_experts={self.n_experts}"
             )
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
-    def layer_runs(self) -> Tuple[Tuple[str, int, int], ...]:
-        """The stack as maximal runs of one kind: (kind, first, count), where
-        `first` counts layers of that kind, i.e. indexes the kind's own
+    @property
+    def expert_width(self) -> int:
+        """ONE expert's width."""
+        return self.d_ff if self.moe_d_ff is None else self.moe_d_ff
+
+    def layer_pairs(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, FFN) of each layer."""
+        mixers = self.layer_types or ("attention",) * self.n_layers
+        ffns = self.ffn_types or ("dense" if self.n_experts is None else "experts",) * self.n_layers
+        return tuple(zip(mixers, ffns))
+
+    def stack_name(self, mixer: str, ffn: str) -> str:
+        """The subtree of the parameters that stacks the layers of one pair:
+        the mixer's own (`LAYER_KINDS`) when the model pairs that mixer with
+        one kind of FFN, `<the mixer's>_<ffn>` when with both."""
+        both = len({f for m, f in self.layer_pairs() if m == mixer}) > 1
+        return f"{LAYER_KINDS[mixer]}_{ffn}" if both else LAYER_KINDS[mixer]
+
+    def stacks(self) -> Dict[str, Tuple[str, str, int]]:
+        """stack name -> (mixer, FFN, layers in it), every pair the model has,
+        in the order of `LAYER_KINDS` x `FFN_KINDS`."""
+        pairs = self.layer_pairs()
+        return {
+            self.stack_name(m, f): (m, f, pairs.count((m, f)))
+            for m in LAYER_KINDS for f in FFN_KINDS if (m, f) in pairs
+        }
+
+    def layer_runs(self) -> Tuple[Tuple[str, str, int, int], ...]:
+        """The stack as maximal runs of one pair: (mixer, FFN, first, count),
+        where `first` counts layers of that pair, i.e. indexes the pair's own
         parameter stack.  A homogeneous model is one run."""
-        kinds = self.layer_types or ("attention",) * self.n_layers
-        runs, seen = [], dict.fromkeys(LAYER_KINDS, 0)
-        for kind in kinds:
-            if runs and runs[-1][0] == kind:
-                runs[-1][2] += 1
+        runs, seen = [], {}
+        for pair in self.layer_pairs():
+            if runs and tuple(runs[-1][:2]) == pair:
+                runs[-1][3] += 1
             else:
-                runs.append([kind, seen[kind], 1])
-            seen[kind] += 1
+                runs.append([*pair, seen.get(pair, 0), 1])
+            seen[pair] = seen.get(pair, 0) + 1
         return tuple(tuple(r) for r in runs)
 
-    def n_layers_of(self, kind: str) -> int:
-        return sum(count for k, _, count in self.layer_runs() if k == kind)
+    def n_layers_of(self, mixer: Optional[str] = None, ffn: Optional[str] = None) -> int:
+        return sum(1 for m, f in self.layer_pairs() if mixer in (None, m) and ffn in (None, f))
 
     # -- presets ---------------------------------------------------------
     @staticmethod
@@ -204,21 +322,32 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
     def num_params(self) -> int:
-        e = self.vocab_size * self.d_model
-        attn = self.d_model * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)
-        mlp = 3 * self.d_model * self.d_ff
-        if self.n_experts is not None:
-            mlp = self.n_experts * mlp + self.d_model * self.n_experts  # + router
-        norms = 2 * self.d_model
+        """Every stored parameter, whatever pairs of mixer and FFN the layers are."""
+        d = self.d_model
+        attn = d * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)
         if self.qk_norm:
             attn += self.head_dim * (self.n_heads + self.n_kv_heads)
         inner, conv = self.ssm_heads * self.ssm_head_dim, self._ssm_conv_channels
-        ssm = (self.d_model * (2 * inner + 2 * self.ssm_state + self.ssm_heads)  # in_proj
+        ssm = (d * (2 * inner + 2 * self.ssm_state + self.ssm_heads)  # in_proj
                + conv * (self.ssm_conv + 1) + 3 * self.ssm_heads + inner  # conv, dt_bias/A_log/D, norm
-               + inner * self.d_model)  # out_proj
-        mixers = self.n_layers_of("attention") * attn + self.n_layers_of("mamba") * ssm
-        out = 0 if self.tie_embeddings else self.vocab_size * self.d_model
-        return e + mixers + self.n_layers * (mlp + norms) + self.d_model + out
+               + inner * d)  # out_proj
+        kh, kd = self.kda_heads, self.kda_head_dim
+        kda = (4 * d * kh * kd  # q, k, v, o
+               + 2 * (d * kd + kd * kh * kd) + d * kh  # the two low-rank gates, beta
+               + 3 * kh * kd * self.kda_conv + kh + kh * kd + kd)  # convolutions, A_log, dt_bias, norm
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        mla = (d * self.n_heads * qk + d * (self.kv_lora_rank + self.qk_rope_head_dim) + self.kv_lora_rank
+               + self.kv_lora_rank * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
+               + self.n_heads * self.v_head_dim * d)
+        mixer = {"attention": attn, "mamba": ssm, "kda": kda, "mla": mla}
+        ffn = {"dense": 3 * d * self.d_ff}
+        if self.n_experts is not None:
+            held = self.n_experts if self.n_experts_held is None else self.n_experts_held
+            ffn["experts"] = (d * self.n_experts + (held + self.n_shared_experts) * 3 * d * self.expert_width
+                              + (self.n_experts if self.router_activation == "sigmoid" else 0))
+        layers = sum(mixer[m] + ffn[f] + 2 * d for m, f in self.layer_pairs())
+        out = 0 if self.tie_embeddings else self.vocab_size * d
+        return self.vocab_size * d + layers + d + out
 
     @property
     def _ssm_conv_channels(self) -> int:
@@ -226,55 +355,75 @@ class TransformerConfig:
         return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_state
 
 
+def _mixer_axes(config: TransformerConfig, mixer: str) -> Dict:
+    """{subtree name: logical axes of ONE stack's mixer leaves}."""
+    L = ("layers",)
+    if mixer == "attention":
+        attn = {
+            "wq": L + ("embed", "heads", "head_dim"),
+            "wk": L + ("embed", "kv_heads", "head_dim"),
+            "wv": L + ("embed", "kv_heads", "head_dim"),
+            "wo": L + ("heads", "head_dim", "embed"),
+        }
+        if config.qk_norm:
+            attn["q_norm"] = L + ("heads", "head_dim")
+            attn["k_norm"] = L + ("kv_heads", "head_dim")
+        return {"attn": attn}
+    if mixer == "mamba":
+        # The mixer's inner width carries no logical axis: `fsdp` shards the
+        # two projections over `embed`, and under `tp` the scan's heads are
+        # REPLICATED over `tensor` (its FFN still shards), not refused.
+        return {"ssm": {
+            "in_proj": L + ("embed", None),
+            "conv_w": L + (None, None),
+            "conv_b": L + (None,),
+            "dt_bias": L + (None,),
+            "A_log": L + (None,),
+            "D": L + (None,),
+            "norm": L + (None,),
+            "out_proj": L + (None, "embed"),
+        }}
+    if mixer == "kda":  # as Mamba-2: the heads of a recurrence are replicated under `tp`
+        return {"kda": {
+            "wqkv": L + ("embed", None),
+            "conv_w": L + (None, None),
+            "f_down": L + ("embed", None),
+            "f_up": L + (None, None),
+            "g_down": L + ("embed", None),
+            "g_up": L + (None, None),
+            "w_beta": L + ("embed", None),
+            "A_log": L + (None,),
+            "dt_bias": L + (None,),
+            "norm": L + (None,),
+            "wo": L + (None, "embed"),
+        }}
+    return {"mla": {
+        "wq": L + ("embed", "heads", "head_dim"),
+        "w_kva": L + ("embed", None),
+        "kv_norm": L + (None,),
+        "w_kvb": L + (None, "heads", "head_dim"),
+        "wo": L + ("heads", "head_dim", "embed"),
+    }}
+
+
+def _is_axes(t) -> bool:
+    return isinstance(t, tuple)
+
+
 def param_axes(config: TransformerConfig) -> Dict:
     """Pytree of logical-axes tuples, congruent with init_params output."""
     L = ("layers",)
-    mlp = {
+    dense = {
         "w_gate": L + ("embed", "mlp"),
         "w_up": L + ("embed", "mlp"),
         "w_down": L + ("mlp", "embed"),
     }
-    axes = {
-        "embed": {"tokens": ("vocab", "embed")},
-        "layers": {
-            "attn": {
-                "wq": L + ("embed", "heads", "head_dim"),
-                "wk": L + ("embed", "kv_heads", "head_dim"),
-                "wv": L + ("embed", "kv_heads", "head_dim"),
-                "wo": L + ("heads", "head_dim", "embed"),
-            },
-            "mlp": mlp,
-            "ln1": L + (None,),
-            "ln2": L + (None,),
-        },
-        "final_norm": (None,),
-    }
-    if config.n_experts is not None:
-        axes["layers"]["mlp"] = {k: L + v for k, v in moe_param_axes(config).items()}
-    if config.qk_norm:
-        axes["layers"]["attn"]["q_norm"] = L + ("heads", "head_dim")
-        axes["layers"]["attn"]["k_norm"] = L + ("kv_heads", "head_dim")
-    if config.n_layers_of("mamba"):
-        # The mixer's inner width carries no logical axis: `fsdp` shards the
-        # two projections over `embed`, and under `tp` the scan's heads are
-        # REPLICATED over `tensor` (its FFN still shards), not refused.
-        axes["mamba_layers"] = {
-            "ssm": {
-                "in_proj": L + ("embed", None),
-                "conv_w": L + (None, None),
-                "conv_b": L + (None,),
-                "dt_bias": L + (None,),
-                "A_log": L + (None,),
-                "D": L + (None,),
-                "norm": L + (None,),
-                "out_proj": L + (None, "embed"),
-            },
-            "mlp": dict(mlp),
-            "ln1": L + (None,),
-            "ln2": L + (None,),
-        }
-    if not config.n_layers_of("attention"):
-        del axes["layers"]
+    axes = {"embed": {"tokens": ("vocab", "embed")}, "final_norm": (None,)}
+    for name, (mixer, ffn, _) in config.stacks().items():
+        mlp = dict(dense)
+        if ffn == "experts":
+            mlp = jax.tree_util.tree_map(lambda t: L + t, moe_param_axes(config), is_leaf=_is_axes)
+        axes[name] = {**_mixer_axes(config, mixer), "mlp": mlp, "ln1": L + (None,), "ln2": L + (None,)}
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -283,80 +432,113 @@ def param_axes(config: TransformerConfig) -> Dict:
 def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
     """Initialize the parameter pytree (truncated-normal / scaled init)."""
     c = config
-    k = iter(jax.random.split(key, 16))
     pd = c.param_dtype
 
     def norm_init(kk, shape, scale):
         return (jax.random.normal(kk, shape, jnp.float32) * scale).astype(pd)
 
     hd = c.head_dim
-    L = c.n_layers_of("attention")
     emb_scale = c.d_model ** -0.5
     proj_scale = c.d_model ** -0.5
     out_scale = (2 * c.n_layers * c.d_model) ** -0.5  # GPT-2-style depth scaling
 
-    def dense_mlp(leading):
-        return {
-            "w_gate": norm_init(next(k), (leading, c.d_model, c.d_ff), proj_scale),
-            "w_up": norm_init(next(k), (leading, c.d_model, c.d_ff), proj_scale),
-            "w_down": norm_init(next(k), (leading, c.d_ff, c.d_model), out_scale),
-        }
+    def log_uniform(kk, shape, low, high):
+        return jnp.exp(jax.random.uniform(kk, shape, jnp.float32) * (math.log(high) - math.log(low)) + math.log(low))
 
-    # Keys are drawn in this order, whatever the model has: a dense model's
-    # weights for a seed do not move when a kind of layer is added here.
-    embed = {"tokens": norm_init(next(k), (c.vocab_size, c.d_model), emb_scale)}
-    attn = {
-        "wq": norm_init(next(k), (L, c.d_model, c.n_heads, hd), proj_scale),
-        "wk": norm_init(next(k), (L, c.d_model, c.n_kv_heads, hd), proj_scale),
-        "wv": norm_init(next(k), (L, c.d_model, c.n_kv_heads, hd), proj_scale),
-        "wo": norm_init(next(k), (L, c.n_heads, hd, c.d_model), out_scale),
-    }
-    if c.qk_norm:
-        attn["q_norm"] = jnp.ones((L, c.n_heads, hd), pd)
-        attn["k_norm"] = jnp.ones((L, c.n_kv_heads, hd), pd)
-    if c.n_experts is not None:
-        mlp = init_moe_params(c, next(k), leading=(L,), out_scale=out_scale)
-    else:
-        mlp = dense_mlp(L)
-    params = {
-        "embed": embed,
-        "layers": {
-            "attn": attn,
-            "mlp": mlp,
-            "ln1": jnp.ones((L, c.d_model), pd),
-            "ln2": jnp.ones((L, c.d_model), pd),
-        },
-        "final_norm": jnp.ones((c.d_model,), pd),
-    }
-    if not c.tie_embeddings:
-        params["lm_head"] = norm_init(next(k), (c.d_model, c.vocab_size), emb_scale)
-    M = c.n_layers_of("mamba")
-    if M:
-        # Mamba-2's own initial values (arXiv:2405.21060; `mamba_ssm`):
-        # A = -(1..heads), D = 1, and a step dt = softplus(dt_bias) drawn
-        # log-uniform in [1e-3, 1e-1] (dt_bias is its inverse softplus).
-        heads, inner = c.ssm_heads, c.ssm_heads * c.ssm_head_dim
-        in_proj = norm_init(next(k), (M, c.d_model, 2 * inner + 2 * c.ssm_state + heads), proj_scale)
-        conv_w = norm_init(next(k), (M, c._ssm_conv_channels, c.ssm_conv), c.ssm_conv ** -0.5)
-        dt = jnp.exp(jax.random.uniform(next(k), (M, heads), jnp.float32)
-                     * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3))
-        params["mamba_layers"] = {
-            "ssm": {
+    def inv_softplus(dt):
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(pd)
+
+    def mixer_params(mixer, n, k):
+        """One stack's mixer leaves, `n` layers, keys drawn from `k` in a fixed order."""
+        if mixer == "attention":
+            attn = {
+                "wq": norm_init(next(k), (n, c.d_model, c.n_heads, hd), proj_scale),
+                "wk": norm_init(next(k), (n, c.d_model, c.n_kv_heads, hd), proj_scale),
+                "wv": norm_init(next(k), (n, c.d_model, c.n_kv_heads, hd), proj_scale),
+                "wo": norm_init(next(k), (n, c.n_heads, hd, c.d_model), out_scale),
+            }
+            if c.qk_norm:
+                attn["q_norm"] = jnp.ones((n, c.n_heads, hd), pd)
+                attn["k_norm"] = jnp.ones((n, c.n_kv_heads, hd), pd)
+            return {"attn": attn}
+        if mixer == "mamba":
+            # Mamba-2's own initial values (arXiv:2405.21060; `mamba_ssm`):
+            # A = -(1..heads), D = 1, and a step dt = softplus(dt_bias) drawn
+            # log-uniform in [1e-3, 1e-1] (dt_bias is its inverse softplus).
+            heads, inner = c.ssm_heads, c.ssm_heads * c.ssm_head_dim
+            in_proj = norm_init(next(k), (n, c.d_model, 2 * inner + 2 * c.ssm_state + heads), proj_scale)
+            conv_w = norm_init(next(k), (n, c._ssm_conv_channels, c.ssm_conv), c.ssm_conv ** -0.5)
+            dt = log_uniform(next(k), (n, heads), 1e-3, 1e-1)
+            return {"ssm": {
                 "in_proj": in_proj,
                 "conv_w": conv_w,
-                "conv_b": jnp.zeros((M, c._ssm_conv_channels), pd),
-                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pd),
-                "A_log": jnp.broadcast_to(jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32)), (M, heads)).astype(pd),
-                "D": jnp.ones((M, heads), pd),
-                "norm": jnp.ones((M, inner), pd),
-                "out_proj": norm_init(next(k), (M, inner, c.d_model), out_scale),
-            },
-            "mlp": dense_mlp(M),
-            "ln1": jnp.ones((M, c.d_model), pd),
-            "ln2": jnp.ones((M, c.d_model), pd),
-        }
-    if not L:
-        del params["layers"]
+                "conv_b": jnp.zeros((n, c._ssm_conv_channels), pd),
+                "dt_bias": inv_softplus(dt),
+                "A_log": jnp.broadcast_to(jnp.log(jnp.arange(1, heads + 1, dtype=jnp.float32)), (n, heads)).astype(pd),
+                "D": jnp.ones((n, heads), pd),
+                "norm": jnp.ones((n, inner), pd),
+                "out_proj": norm_init(next(k), (n, inner, c.d_model), out_scale),
+            }}
+        if mixer == "kda":
+            # The published kernels' initial values (`fla.layers.kda`): A drawn
+            # uniform in [1, 16] per head, dt = softplus(dt_bias) log-uniform
+            # in [1e-3, 1e-1] per channel; the convolutions as Mamba-2's here.
+            heads, dim = c.kda_heads, c.kda_head_dim
+            inner = heads * dim
+            return {"kda": {
+                "wqkv": norm_init(next(k), (n, c.d_model, 3 * inner), proj_scale),
+                "conv_w": norm_init(next(k), (n, 3 * inner, c.kda_conv), c.kda_conv ** -0.5),
+                "f_down": norm_init(next(k), (n, c.d_model, dim), proj_scale),
+                "f_up": norm_init(next(k), (n, dim, inner), dim ** -0.5),
+                "g_down": norm_init(next(k), (n, c.d_model, dim), proj_scale),
+                "g_up": norm_init(next(k), (n, dim, inner), dim ** -0.5),
+                "w_beta": norm_init(next(k), (n, c.d_model, heads), proj_scale),
+                "A_log": jnp.log(jax.random.uniform(next(k), (n, heads), jnp.float32, 1.0, 16.0)).astype(pd),
+                "dt_bias": inv_softplus(log_uniform(next(k), (n, inner), 1e-3, 1e-1)),
+                "norm": jnp.ones((n, dim), pd),
+                "wo": norm_init(next(k), (n, inner, c.d_model), out_scale),
+            }}
+        qk, rank = c.qk_nope_head_dim + c.qk_rope_head_dim, c.kv_lora_rank
+        return {"mla": {
+            "wq": norm_init(next(k), (n, c.d_model, c.n_heads, qk), proj_scale),
+            "w_kva": norm_init(next(k), (n, c.d_model, rank + c.qk_rope_head_dim), proj_scale),
+            "kv_norm": jnp.ones((n, rank), pd),
+            "w_kvb": norm_init(next(k), (n, rank, c.n_heads, c.qk_nope_head_dim + c.v_head_dim), rank ** -0.5),
+            "wo": norm_init(next(k), (n, c.n_heads, c.v_head_dim, c.d_model), out_scale),
+        }}
+
+    def stack_params(mixer, ffn, n, k):
+        mixed = mixer_params(mixer, n, k)
+        if ffn == "experts":
+            mlp = init_moe_params(c, next(k), leading=(n,), out_scale=out_scale)
+        else:
+            mlp = {
+                "w_gate": norm_init(next(k), (n, c.d_model, c.d_ff), proj_scale),
+                "w_up": norm_init(next(k), (n, c.d_model, c.d_ff), proj_scale),
+                "w_down": norm_init(next(k), (n, c.d_ff, c.d_model), out_scale),
+            }
+        return {**mixed, "mlp": mlp, "ln1": jnp.ones((n, c.d_model), pd), "ln2": jnp.ones((n, c.d_model), pd)}
+
+    # The embedding, the stack `layers` (attention with its one kind of FFN),
+    # the head and the stack `mamba_layers` draw their keys from ONE sequence
+    # in this order, whatever the model has, and every other stack from a
+    # sequence of its own: a model's weights for a seed do not move when a
+    # kind of layer is added here.
+    stacks = c.stacks()
+    k = iter(jax.random.split(key, 16))
+    params = {"embed": {"tokens": norm_init(next(k), (c.vocab_size, c.d_model), emb_scale)}}
+    _, ffn, n = stacks.get("layers", ("attention", "dense" if c.n_experts is None else "experts", 0))
+    first = stack_params("attention", ffn, n, k)
+    if n:
+        params["layers"] = first
+    params["final_norm"] = jnp.ones((c.d_model,), pd)
+    if not c.tie_embeddings:
+        params["lm_head"] = norm_init(next(k), (c.d_model, c.vocab_size), emb_scale)
+    for i, (name, (mixer, ffn, n)) in enumerate(stacks.items()):
+        if name == "mamba_layers":
+            params[name] = stack_params(mixer, ffn, n, k)
+        elif name != "layers":
+            params[name] = stack_params(mixer, ffn, n, iter(jax.random.split(jax.random.fold_in(key, i + 1), 16)))
     return params
 
 
@@ -431,9 +613,11 @@ def _layer(
     config: TransformerConfig,
     rules: Optional[Rules],
     mesh=None,
+    ffn: Optional[str] = None,
 ):
     """One attention layer: (x, this layer's router statistics; None when the
-    FFN is dense)."""
+    FFN is dense).  `ffn` is the layer's kind of FFN (None: what the
+    configuration's every layer has)."""
     c = config
     constrain = _constrainer(rules, mesh)
     dt = c.dtype
@@ -492,7 +676,7 @@ def _layer(
     with jax.named_scope("layer/attn_proj"):
         attn_out = jnp.einsum("bshd,hde->bse", attn, layer_params["attn"]["wo"].astype(dt))
         x = x + _scaled(c, constrain(attn_out, ("act_batch", "act_seq", "act_embed")))
-    return _ffn_half(x, layer_params, c, constrain, rules, mesh)
+    return _ffn_half(x, layer_params, c, constrain, rules, mesh, ffn)
 
 
 def _constrainer(rules: Optional[Rules], mesh):
@@ -510,14 +694,17 @@ def _scaled(config: TransformerConfig, block_out: jax.Array) -> jax.Array:
     return block_out * jnp.asarray(config.residual_multiplier, block_out.dtype)
 
 
-def _ffn_half(x, layer_params, config, constrain, rules, mesh):
+def _ffn_half(x, layer_params, config, constrain, rules, mesh, ffn=None):
     """The second half of every layer, whatever its mixer: (x + FFN(ln2(x)),
-    router statistics or None)."""
+    router statistics or None).  `ffn`: "dense" or "experts" (None: experts
+    where the configuration has any)."""
     c, dt = config, config.dtype
     router_stats = None
+    if ffn is None:
+        ffn = "dense" if c.n_experts is None else "experts"
     with jax.named_scope("layer/mlp"):
         h = rms_norm(x, layer_params["ln2"], c.norm_eps)
-        if c.n_experts is not None:
+        if ffn == "experts":
             down, router_stats = moe_ffn(layer_params["mlp"], h, c, rules=rules, mesh=mesh)
         else:
             mlp = layer_params["mlp"]
@@ -535,8 +722,9 @@ def _mamba_layer(
     config: TransformerConfig,
     rules: Optional[Rules],
     mesh=None,
+    ffn: Optional[str] = None,
 ):
-    """One Mamba-2 layer (module docstring): (x, None).  Its regions sit
+    """One Mamba-2 layer (module docstring): (x, router statistics or None).  Its regions sit
     INSIDE the two mixer scopes every layer has, so `layer/attn_proj` stays
     "the mixer's projections" and `layer/attn_core` "the mixer's core":
     `ssm/proj` (ln1, in_proj, out_proj, the residual add), `ssm/conv`
@@ -582,10 +770,128 @@ def _mamba_layer(
             out = jnp.einsum("bsf,fe->bse", y, ssm["out_proj"].astype(dt))
             x = x + _scaled(c, constrain(out, ("act_batch", "act_seq", "act_embed")))
             x = checkpoint_name(x, SSM_MIXED)
-    return _ffn_half(x, layer_params, c, constrain, rules, mesh)
+    return _ffn_half(x, layer_params, c, constrain, rules, mesh, ffn)
 
 
-_LAYER_FNS = {"attention": _layer, "mamba": _mamba_layer}
+def _l2_normed(x: jax.Array, scale: float = 1.0, eps: float = 1e-6) -> jax.Array:
+    """x / |x|_2 over the last axis (a head), times `scale`, in float32."""
+    xf = x.astype(jnp.float32)
+    return xf * (jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps) * scale)
+
+
+def _kda_layer(
+    x: jax.Array,
+    layer_params: Dict,
+    positions: jax.Array,
+    config: TransformerConfig,
+    rules: Optional[Rules],
+    mesh=None,
+    ffn: Optional[str] = None,
+):
+    """One Kimi Delta Attention layer (module docstring): (x, router
+    statistics or None).  Its regions sit inside the two mixer scopes every
+    layer has, as a Mamba-2 layer's do: `kda/proj` (ln1, the fused q|k|v
+    projection, both low-rank gates, beta, `wo`, the residual add), `kda/conv`
+    (the convolutions + SiLU in one call, on TPU Mamba-2's kernels; the L2
+    norms, the decay's activation, the gated per-head RMSNorm), `kda/scan`
+    (the chunked recurrence, named in `ops/kda.py`).
+
+    Three residuals carry a `checkpoint_name`, for `_remat_policy` to save:
+    `KDA_QKV`, the fused projection's one array; `KDA_LOW`, the narrow halves
+    of the two gates with beta's logits (d -> 2 * head + heads, one array);
+    `KDA_MIXED`, the residual stream after `wo`.  With them kept the backward
+    runs none of the d-wide projections again (the gates' narrow-to-wide
+    halves, the convolution, the recurrence and the gated norm run again)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    del positions  # the decay carries position
+    c, dt, p = config, config.dtype, layer_params["kda"]
+    f32 = jnp.float32
+    constrain = _constrainer(rules, mesh)
+    sharded = {} if rules is None else dict(mesh=mesh, batch_axes=rules.get("act_batch"))
+    heads, dim = c.kda_heads, c.kda_head_dim
+    inner = heads * dim
+    with jax.named_scope("layer/attn_proj"):
+        with jax.named_scope("kda/proj"):
+            h = rms_norm(x, layer_params["ln1"], c.norm_eps)
+            qkv = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["wqkv"].astype(dt)), KDA_QKV)
+            narrow = jnp.concatenate([p["f_down"], p["g_down"], p["w_beta"]], axis=-1).astype(dt)
+            low = checkpoint_name(jnp.einsum("bse,ef->bsf", h, narrow), KDA_LOW)
+            decay_in = jnp.einsum("bsr,rf->bsf", low[..., :dim], p["f_up"].astype(dt))
+            gate_in = jnp.einsum("bsr,rf->bsf", low[..., dim: 2 * dim], p["g_up"].astype(dt))
+        with jax.named_scope("kda/conv"):
+            qkv = causal_conv1d_silu(qkv, p["conv_w"], jnp.zeros((3 * inner,), p["conv_w"].dtype), **sharded)
+            q, k, v = (a.reshape(*a.shape[:2], heads, dim) for a in jnp.split(qkv, 3, axis=-1))
+            q, k = _l2_normed(q, dim ** -0.5), _l2_normed(k)
+            step = jax.nn.softplus(decay_in.astype(f32) + p["dt_bias"].astype(f32))
+            g = step.reshape(*step.shape[:2], heads, dim) * -jnp.exp(p["A_log"].astype(f32))[:, None]
+            beta = jax.nn.sigmoid(low[..., 2 * dim:].astype(f32))
+    with jax.named_scope("layer/attn_core"):
+        o = kda_chunked(q, k, v, g, beta)
+    with jax.named_scope("layer/attn_proj"):
+        with jax.named_scope("kda/conv"):
+            gate = jax.nn.sigmoid(gate_in.astype(f32)).reshape(o.shape)
+            o = (rms_norm(o, p["norm"], c.norm_eps) * gate).astype(dt)  # over each head's own channels
+        with jax.named_scope("kda/proj"):
+            out = jnp.einsum("bsf,fe->bse", o.reshape(*o.shape[:2], inner), p["wo"].astype(dt))
+            x = x + _scaled(c, constrain(out, ("act_batch", "act_seq", "act_embed")))
+            x = checkpoint_name(x, KDA_MIXED)
+    return _ffn_half(x, layer_params, c, constrain, rules, mesh, ffn)
+
+
+def _mla_layer(
+    x: jax.Array,
+    layer_params: Dict,
+    positions: jax.Array,
+    config: TransformerConfig,
+    rules: Optional[Rules],
+    mesh=None,
+    ffn: Optional[str] = None,
+):
+    """One latent-attention layer without rotary embedding (module
+    docstring): (x, router statistics or None).  `mla/proj` names its
+    projections inside `layer/attn_proj`; the core is `dot_product_attention`
+    with q/k heads of `nope + rope` and v heads of `v_head_dim` (the flash
+    kernels take the two sizes).  q, k, v carry attention's own
+    `checkpoint_name`s and the residual stream after `wo` `MLA_MIXED`."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    del positions  # no rotary embedding
+    c, dt, p = config, config.dtype, layer_params["mla"]
+    constrain = _constrainer(rules, mesh)
+    rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
+    with jax.named_scope("layer/attn_proj"), jax.named_scope("mla/proj"):
+        h = rms_norm(x, layer_params["ln1"], c.norm_eps)
+        q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(dt))
+        latent = jnp.einsum("bse,ef->bsf", h, p["w_kva"].astype(dt))
+        kv = jnp.einsum("bsr,rhd->bshd", rms_norm(latent[..., :rank], p["kv_norm"], c.norm_eps),
+                        p["w_kvb"].astype(dt))
+        k_pe = jnp.broadcast_to(latent[..., None, rank:], (*kv.shape[:3], c.qk_rope_head_dim))
+        kk = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+        q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
+        kk = constrain(kk, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
+        q = checkpoint_name(q, "q")
+        kk = checkpoint_name(kk, "k")
+        vv = checkpoint_name(kv[..., nope:], "v")
+    batch_axes = head_ax = None
+    if rules is not None:
+        batch_axes = rules.get("act_batch")
+        head_ax = _fitting_axis(rules.get("act_heads"), mesh, q.shape[2])
+    if _ring_axis(rules, mesh, q) is not None:
+        raise ValueError("an mla layer runs local attention only (no sequence-parallel ring)")
+    with jax.named_scope("layer/attn_core"):
+        attn = dot_product_attention(
+            q, kk, vv, causal=True, scale=q.shape[-1] ** -0.5, impl=c.attention_impl,
+            mesh=mesh if rules is not None else None, batch_axes=batch_axes, head_axis=head_ax,
+        )
+    with jax.named_scope("layer/attn_proj"), jax.named_scope("mla/proj"):
+        out = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(dt))
+        x = x + _scaled(c, constrain(out, ("act_batch", "act_seq", "act_embed")))
+        x = checkpoint_name(x, MLA_MIXED)
+    return _ffn_half(x, layer_params, c, constrain, rules, mesh, ffn)
+
+
+_LAYER_FNS = {"attention": _layer, "mamba": _mamba_layer, "kda": _kda_layer, "mla": _mla_layer}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -623,10 +929,13 @@ def _remat_policy(config: TransformerConfig):
     if config.remat_policy == "attn":
         return jax.checkpoint_policies.save_only_these_names(ATTN_OUT, ATTN_LSE)
     if config.remat_policy == "qkv_attn":
-        # No mixer's input projection is recomputed: attention's q, k, v; a
-        # Mamba-2 layer's two named residuals (`_mamba_layer`).
+        # No mixer's input projection is recomputed: attention's q, k, v
+        # (latent attention's too); a Mamba-2 layer's two named residuals
+        # (`_mamba_layer`); a KDA layer's three (`_kda_layer`); the stream
+        # behind an MLA layer's output projection.
         return jax.checkpoint_policies.save_only_these_names(
-            "q", "k", "v", ATTN_OUT, ATTN_LSE, SSM_IN_PROJ, SSM_MIXED
+            "q", "k", "v", ATTN_OUT, ATTN_LSE, SSM_IN_PROJ, SSM_MIXED,
+            KDA_QKV, KDA_LOW, KDA_MIXED, MLA_MIXED,
         )
     if config.remat_policy is None:
         # Save nothing per layer: the backward re-runs the whole layer, the
@@ -665,10 +974,11 @@ def _run_layers_pipelined(
             "strategy 'pp' runs dense layers only: the router statistics of "
             "an expert layer do not come out of the pipeline schedule"
         )
-    if c.layer_types is not None:
+    if c.layer_types is not None or c.ffn_types is not None:
         raise ValueError(
-            "strategy 'pp' runs a homogeneous stack only: the stages of a "
-            "stack with layer_types would hold unequal layers"
+            "strategy 'pp' runs a homogeneous stack of attention layers only: the "
+            "stages of a stack with layer_types (mamba, kda, mla) or ffn_types "
+            "(dense beside experts) would hold unequal layers"
         )
     n_stages = mesh.shape[axis]
     per_stage = c.n_layers // n_stages
@@ -818,24 +1128,30 @@ def trunk(
                 rules=rules, fsdp_axis=pp_fsdp_axis,
             )
         else:
-            # One scan per maximal run of one kind of layer, over that run's
-            # slice of the kind's stack; a homogeneous model is one run over
-            # its whole stack.
+            # One scan per maximal run of one (mixer, FFN) pair, over that
+            # run's slice of the pair's stack; a homogeneous model is one run
+            # over its whole stack.
             runs = c.layer_runs()
             stacks = {}
-            for kind, subtree in LAYER_KINDS.items():
-                bounds = tuple((first, count) for k, first, count in runs if k == kind)
-                if len(bounds) == 1:
-                    stacks[kind] = iter([params[subtree]])
-                elif bounds:
-                    stacks[kind] = iter(_split_runs(params[subtree], bounds))
-            for kind, _, _ in runs:
+            for name, (mixer, ffn, _) in c.stacks().items():
+                bounds = tuple((first, count) for m, f, first, count in runs if (m, f) == (mixer, ffn))
+                whole = len(bounds) == 1  # one run: the stack as it is, no slices to copy
+                stacks[mixer, ffn] = iter([params[name]] if whole else _split_runs(params[name], bounds))
+            per_run = []
+            for mixer, ffn, _, _ in runs:
                 layer_fn = functools.partial(
-                    _LAYER_FNS[kind], positions=positions, config=c, rules=rules, mesh=mesh
+                    _LAYER_FNS[mixer], positions=positions, config=c, rules=rules, mesh=mesh, ffn=ffn
                 )
                 if c.remat:
                     layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
-                x, router_stats = jax.lax.scan(layer_fn, x, next(stacks[kind]))
+                x, run_stats = jax.lax.scan(layer_fn, x, next(stacks[mixer, ffn]))
+                if run_stats is not None:
+                    per_run.append(run_stats)
+            # the expert layers' statistics, [expert layers, ...] in the stack's order
+            if len(per_run) == 1:
+                router_stats = per_run[0]
+            elif per_run:
+                router_stats = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, axis=0), *per_run)
     with jax.named_scope("final_norm"):
         x = rms_norm(x, params["final_norm"], c.norm_eps)
     with jax.named_scope("lm_head"):

@@ -7,7 +7,8 @@ and is what the pallas kernel (ops/pallas/flash_attention.py) and ring
 attention (ops/ring_attention.py) are built from.
 
 Shapes follow [batch, seq, heads, head_dim] throughout.  GQA is expressed by
-n_kv_heads < n_heads; kv heads are repeated on the fly.
+n_kv_heads < n_heads; kv heads are repeated on the fly.  q and k share one
+head size; v, and with it the output, may have another.
 """
 
 from __future__ import annotations
@@ -88,7 +89,7 @@ def blockwise_attention(
     b, sq, h, d = q.shape
     k = _repeat_kv(k, h)
     v = _repeat_kv(v, h)
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]
     scale = scale if scale is not None else d ** -0.5
     if sk % block_size != 0:
         block_size = sk  # fall back to one block rather than pad
@@ -98,7 +99,7 @@ def blockwise_attention(
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     k_blocks = kf.reshape(b, n_blocks, block_size, h, d).transpose(1, 0, 2, 3, 4)
-    v_blocks = vf.reshape(b, n_blocks, block_size, h, d).transpose(1, 0, 2, 3, 4)
+    v_blocks = vf.reshape(b, n_blocks, block_size, h, dv).transpose(1, 0, 2, 3, 4)
 
     qpos = jnp.arange(sq) + q_offset
 
@@ -122,7 +123,7 @@ def blockwise_attention(
         return (acc_new, m_new, l_new), None
 
     kpos_blocks = (jnp.arange(sk).reshape(n_blocks, block_size))
-    acc0 = jnp.zeros((b, h, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, h, sq, dv), jnp.float32)
     m0 = jnp.full((b, h, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     (acc, m, l), _ = jax.lax.scan(step, (acc0, m0, l0), (k_blocks, v_blocks, kpos_blocks))
@@ -147,8 +148,8 @@ def dot_product_attention(
 
     impl: None (auto) | "reference" | "blockwise" | "pallas".
     Auto gives a program LOWERED FOR TPU the pallas flash kernel when the
-    shapes are tile-aligned (sequence lengths a multiple of 128, head size a
-    multiple of 64); every other lowering, and every other shape, gets the
+    shapes are tile-aligned (sequence lengths a multiple of 128, both head
+    sizes, q/k's and v's, a multiple of 64); every other lowering, and every other shape, gets the
     XLA forms (blockwise scan beyond block_size, else reference).
     The choice rides `jax.lax.platform_dependent`, so it follows the
     platform a step is compiled for, not the process's default backend.
@@ -180,7 +181,8 @@ def dot_product_attention(
 
     if impl is None:
         xla = blockwise if q.shape[1] > block_size else reference
-        if q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[-1] % 64 == 0:
+        if (q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
+                and q.shape[-1] % 64 == 0 and v.shape[-1] % 64 == 0):
             return jax.lax.platform_dependent(q, k, v, tpu=pallas, default=xla)
         return xla(q, k, v)
     forms = {"reference": reference, "blockwise": blockwise, "pallas": pallas}

@@ -1,0 +1,225 @@
+"""Kimi Delta Attention (KDA, "Kimi Linear", arXiv:2510.26692): a gated delta
+rule whose decay is PER CHANNEL of the key, in its chunked form; no
+counterpart in the reference (SURVEY.md §5.7).  Plain `jax.numpy`,
+differentiated by JAX, as `ssd_chunked` (`ops/ssm.py`) was when it arrived; a
+Pallas kernel that keeps the chunk's matrices in VMEM is what a `perf_opt`
+issue would write, and `kda/scan`, the scope around all of this, is what its
+gain would be read by (PERF.md section 3).
+
+The recurrence, per batch row and per head, with a state `S_t` of shape
+[K, V] (key size x value size), a log decay `g_t <= 0` per key channel
+(`alpha_t = exp(g_t)` in (0, 1]^K) and a write strength `beta_t` in (0, 1):
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+which alone defines the layer.  `kda_chunked` computes the same o without a
+pass over tokens.  Write the update as `S_t = Diag(alpha_t) S_{t-1} + k_t
+u_t^T` with the pseudo-value `u_t = beta_t (v_t - (Diag(alpha_t) S_{t-1})^T
+k_t)`.  Inside a chunk of C positions that enters with the state `S_0`, with
+`G_t` the running sum of g from the chunk's start (inclusive) and
+`D[t, s] = exp(G_t - G_s)` (per channel):
+
+- `A[t, s] = beta_t sum_d k_t[d] k_s[d] D[t, s][d]` for s < t, and the rows of
+  U solve the unit lower-triangular system
+  `(I + A) U = beta (V - (K * exp(G)) S_0)`: the system's inverse, once per
+  chunk, gives `Ubar = (I + A)^-1 (beta V)` and `W = (I + A)^-1 (beta K *
+  exp(G))`, neither of which needs the state, and `U = Ubar - W S_0`;
+- `o_t = S_0^T (q_t * exp(G_t)) + sum_{s<=t} (sum_d q_t[d] k_s[d] D[t, s][d]) u_s`;
+- the state that leaves: `exp(G_C) * S_0 + (K * exp(G_C - G))^T U`;
+- one `lax.scan` over the S / C chunk states: the only serial part, two small
+  matmuls a step.
+
+The chunks are worked on `SEGMENT` at a time, one `lax.scan` step each with
+the state as its carry, and a segment is a `jax.checkpoint`: everything above
+is float32 arrays the size of k or larger (at one 16,384-token sequence and
+32 heads of 128, 256 MB each, and JAX's backward holds one cotangent of that
+size per level and operand of `_decayed_lower` until they are summed: 4.6 GB
+in the compiled step), so a segment's temporaries exist while that segment
+runs and its forward runs once more in the backward.
+
+A decay is always the exponential of a DIFFERENCE of running sums that is
+<= 0, never a quotient of exponentials: `exp(G_t) / exp(G_s)` is 0/0 once G
+passes -88 in float32, which one fast channel does inside a chunk.  With a
+decay per channel `D[t, s]` is a vector, so the [C, C] matrices cannot take
+it as a mask on a plain `k k^T` (as `ssd_chunked` does with its scalar decay),
+and a [C, C, K] array of them is 17 GB at the benchmark's shapes.
+`_decayed_lower` builds the lower triangle by halves instead: the rows of a
+block's lower half against the columns of its upper half, both measured from
+the lower half's first row r, `(a_t * exp(G_t - G_r)) . (b_s * exp(G_r - G_s))`
+with both exponents <= 0; then the same inside each half, down to single
+positions.  log2(C) levels, each one batched matmul over operands the size of
+k (`_decayed_lower` says how the levels are laid out).
+
+Precision: everything here is float32 whatever the inputs are, the matmuls'
+operands too, and every matmul runs at `EXACT` (three bf16 passes: a float32
+product to about 16 bits): g, its running sums and their exponentials, the
+decayed operands, A and the system's inverse, the chunk states, the outputs,
+which leave in float32 (the layer rounds once, behind its gated norm).  The
+products are small ([64, 64] .. [64, 128] x [128, 128]; 2% of a step's needed
+FLOPs), and a delta rule is less forgiving than softmax attention, which
+averages its values' roundings away: with bf16 operands the benchmark's
+five-layer model read 1.9-2.5% of relative RMS error against the float32
+reference where the comparison allows 2.68% (22 readings), with these 1.6-2.0%
+(6 readings), for 0.19 s of a 1.64 s step; six passes read the same and cost
+0.26 s (my chip runs, PR 37; PERF.md section 6).
+
+Sharding: nothing here names a mesh axis; batch sharding is GSPMD's to
+propagate through the einsums.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+# The precision of every matmul here (module docstring).
+EXACT = jax.lax.Precision.HIGH
+# The chunk length of the published kernels (`fla.ops.kda`); the program's own constant.
+CHUNK = 64
+# Chunks worked on at once (2,048 positions): what bounds the scan's temporaries, see `kda_chunked`.
+SEGMENT = 32
+
+
+def _decayed_lower(a, b, G, *, diagonal: bool, row_scale=None, inverse: bool = False):
+    """M[..., t, s] = row_scale[t] * sum_d a[t, d] b[s, d] exp(G[t, d] - G[s, d])
+    for s < t (and s == t with `diagonal`), zero above; with `inverse`,
+    (I + M)^-1 instead (M strictly lower, so the system is unit lower
+    triangular).  a, b, G: [..., C, K] float32, C a power of two, G
+    non-increasing along C; row_scale [..., C, 1] or None.
+
+    By halves (module docstring), level by level from single positions up, and
+    every level in FULL [C, K] and [C, C] arrays: the rows that are in a lower
+    half at this level carry `a * exp(G - G_r)`, the rows in an upper half
+    `b * exp(G_r - G)` (r the first row of their pair's lower half; the other
+    rows are zero), one [C, K] x [K, C] product, and a constant mask keeps the
+    entries whose row and column share a pair.  (Blocks of their own size,
+    [size, size] with size 1, 2, 4, .., are arrays whose minor dimensions a TPU
+    pads to (8, 128) tiles.)  The inverse rides the same loop: X holds the
+    inverse of the diagonal blocks of `size`; with this level's blocks M21
+    below them, `X - X M21 X` is the inverse of the blocks of twice the size
+    (a rounding here is one the next level multiplies), so no triangular solve is called: on the v5e XLA's ran 0.5 s
+    a step in 16,384 [64, 64] systems (PERF.md section 6, PR 37)."""
+    f32 = jnp.float32
+    *lead, c, k = a.shape
+    eye = jnp.eye(c, dtype=f32)
+    if diagonal:
+        out = jnp.sum(a * b, axis=-1)[..., None] * eye
+    else:
+        out = jnp.broadcast_to(eye, (*lead, c, c)) if inverse else jnp.zeros((*lead, c, c), f32)
+    pos = np.arange(c)
+    size = 1
+    while size < c:
+        in_lower = ((pos // size) % 2 == 1)[:, None]  # [C, 1]: the row is in a lower half at this level
+        same_pair = pos[:, None] // (2 * size) == pos[None, :] // (2 * size)
+        keep = jnp.asarray(same_pair & in_lower & ~in_lower.T)  # [C, C]: lower-half row, upper-half column, one pair
+        in_lower = jnp.asarray(in_lower)
+        by_pair = G.reshape(*lead, c // (2 * size), 2, size, k)
+        ref = jnp.broadcast_to(by_pair[..., 1:, :1, :], by_pair.shape).reshape(G.shape)  # the lower half's first row
+        rel = G - ref  # <= 0 on the rows of a lower half, >= 0 on those of an upper half
+        lower = jnp.where(in_lower, a * jnp.exp(jnp.where(in_lower, rel, 0.0)), 0.0)
+        upper = jnp.where(in_lower, 0.0, b * jnp.exp(jnp.where(in_lower, 0.0, -rel)))
+        cross = jnp.where(keep, jnp.einsum("...td,...sd->...ts", lower, upper, precision=EXACT), 0.0)
+        if row_scale is not None:
+            cross = cross * row_scale
+        if inverse:
+            out = out - jnp.einsum("...ts,...su,...uv->...tv", out, cross, out, precision=EXACT)
+        else:
+            out = out + cross
+        size *= 2
+    return out
+
+
+def kda_chunked(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    g: jax.Array,
+    beta: jax.Array,
+    chunk: Optional[int] = None,
+) -> jax.Array:
+    """The gated delta rule of the module docstring, chunked.
+
+    q, k [b, S, H, K] (already normalised and scaled as the layer has them);
+    v [b, S, H, V]; g [b, S, H, K] (log decay, <= 0); beta [b, S, H].  Returns
+    o [b, S, H, V] in float32.  `chunk` (None = `CHUNK`, a power of two) is
+    cut to S when S is shorter; S must be a multiple of it."""
+    b, s, h, dk = k.shape
+    dv = v.shape[-1]
+    chunk = min(chunk or CHUNK, s)
+    if s % chunk or chunk & (chunk - 1):
+        raise ValueError(f"kda_chunked: sequence length {s} needs a power-of-two chunk that divides it, got {chunk}")
+    nc = s // chunk
+    per_segment = math.gcd(nc, SEGMENT)
+    f32 = jnp.float32
+
+    def segment(state, inp):
+        """`per_segment` chunks at once: (the state that enters, their q, k, v,
+        g, beta as [b, c, H, l, d]) -> (the state that leaves, o [b, c, H, l, V])."""
+        qc, kc, vc, gc, bc = inp
+        # the running sum from each chunk's start (inclusive), as a product with a triangle of ones:
+        # `cumsum` lowers to a windowed reduction that ran at 24 GB/s on the v5e
+        G = jnp.einsum("ts,bchsk->bchtk", jnp.tril(jnp.ones((chunk, chunk), f32)), gc, precision=EXACT)
+
+        # inside a chunk: nothing here needs the state that enters
+        solve = _decayed_lower(kc, kc, G, diagonal=False, row_scale=bc, inverse=True)  # (I + A)^-1
+        qk = _decayed_lower(qc, kc, G, diagonal=True)
+        from_start = jnp.exp(G)  # [b, c, H, l, K], <= 1
+        rhs = jnp.concatenate([bc * vc, bc * (kc * from_start)], axis=-1)
+        solved = jnp.einsum("bchts,bchsd->bchtd", solve, rhs, precision=EXACT)
+        u_bar, w = solved[..., :dv], solved[..., dv:]
+        q_in = qc * from_start
+        k_out = kc * jnp.exp(G[..., -1:, :] - G)  # each row up to the chunk's end
+        through = from_start[..., -1, :]  # [b, c, H, K]: the whole chunk's decay
+
+        # the serial part: the state that enters each chunk
+        def cross(carry, per_chunk):
+            w_c, u_c, k_c, decay_c = per_chunk
+            u = u_c - jnp.einsum("bhlk,bhkv->bhlv", w_c, carry, precision=EXACT)
+            out = carry * decay_c[..., None] + jnp.einsum("bhlk,bhlv->bhkv", k_c, u, precision=EXACT)
+            return out, carry
+
+        by_chunk = lambda x: jnp.moveaxis(x, 1, 0)
+        state, entering = jax.lax.scan(cross, state, tuple(map(by_chunk, (w, u_bar, k_out, through))))
+        entering = jnp.moveaxis(entering, 0, 1)  # [b, c, H, K, V]
+
+        # every position's output, the segment's chunks at once
+        u = u_bar - jnp.einsum("bchlk,bchkv->bchlv", w, entering, precision=EXACT)
+        o = jnp.einsum("bchlk,bchkv->bchlv", q_in, entering, precision=EXACT)
+        o = o + jnp.einsum("bchts,bchsv->bchtv", qk, u, precision=EXACT)
+        return state, o
+
+    with jax.named_scope("kda/scan"):
+        def segments(x):  # [b, S, H, d] -> [segments, b, c, H, l, d]
+            x = x.reshape(b, nc // per_segment, per_segment, chunk, h, x.shape[-1])
+            return x.transpose(1, 0, 2, 4, 3, 5)
+
+        inputs = tuple(segments(x.astype(f32)) for x in (q, k, v, g, beta[..., None]))
+        # Each segment keeps its inputs and the state that enters it, and runs
+        # again in the backward: the [chunk, chunk] matrices, their decayed
+        # operands and every cotangent of them exist for one segment at a time.
+        _, o = jax.lax.scan(jax.checkpoint(segment), jnp.zeros((b, h, dk, dv), f32), inputs)
+        return o.transpose(1, 0, 2, 4, 3, 5).reshape(b, s, h, dv)
+
+
+def kda_recurrent(q, k, v, g, beta):
+    """The recurrence itself, token by token in float32: what `kda_chunked`
+    equals, and what the tests hold it to.  Same arguments; o in v's dtype."""
+    f32 = jnp.float32
+    b, s, h, dk = k.shape
+
+    def step(state, inp):
+        qt, kt, vt, gt, bt = inp  # [b, H, K], ..., [b, H]
+        state = state * jnp.exp(gt)[..., None]
+        u = bt[..., None] * (vt - jnp.einsum("bhk,bhkv->bhv", kt, state))
+        state = state + kt[..., None] * u[..., None, :]
+        return state, jnp.einsum("bhk,bhkv->bhv", qt, state)
+
+    seq = lambda x: jnp.moveaxis(x.astype(f32), 1, 0)
+    with jax.default_matmul_precision("highest"):
+        _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), f32), tuple(map(seq, (q, k, v, g, beta))))
+    return jnp.moveaxis(o, 0, 1).astype(v.dtype)

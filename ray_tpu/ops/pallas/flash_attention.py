@@ -72,8 +72,8 @@ def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     *, scale: float, causal: bool,
 ):
-    # Blocks: q/o [1, 1, bq, D]; k/v [1, 1, bk, D]; lse [1, 1, bq, 1].
-    # Scratch (carried across the kv grid dim): acc [bq, D] f32,
+    # Blocks: q [1, 1, bq, D]; k [1, 1, bk, D]; v [1, 1, bk, Dv]; o [1, 1, bq, Dv];
+    # lse [1, 1, bq, 1].  Scratch (carried across the kv grid dim): acc [bq, Dv] f32,
     # m/l [bq, LANES] f32 (lane-broadcast row scalars).
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -95,7 +95,7 @@ def _fwd_kernel(
     def _step():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # [bq, D]
         k = k_ref[0, 0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)  # [bk, Dv]
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bq, bk]
@@ -126,7 +126,7 @@ def _fwd_kernel(
 
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k):
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]  # q and k share one head size, v and the output another
     # Kernels work in [B, H, S, D].
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -142,18 +142,18 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
@@ -168,7 +168,8 @@ def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
     *, scale: float, causal: bool,
 ):
-    # q/do/dq [1, 1, bq, D]; k/v [1, 1, bk, D]; lse/delta [1, 1, bq, 1].
+    # q/dq [1, 1, bq, D]; k [1, 1, bk, D]; v [1, 1, bk, Dv]; do [1, 1, bq, Dv];
+    # lse/delta [1, 1, bq, 1].
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -218,7 +219,8 @@ def _bwd_dkv_kernel(
     *, scale: float, causal: bool,
 ):
     # Grid (b, h, kv_tile, q_tile) — q innermost so k/v blocks stay resident.
-    # k/v/dk/dv [1, 1, bk, D]; q/do [1, 1, bq, D]; lse/delta [1, 1, bq, 1].
+    # k/dk [1, 1, bk, D]; v/dv [1, 1, bk, Dv]; q [1, 1, bq, D]; do [1, 1, bq, Dv];
+    # lse/delta [1, 1, bq, 1].
     ki = pl.program_id(2)
     qi = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -252,7 +254,7 @@ def _bwd_dkv_kernel(
         p = jnp.exp(logits - lse)
         dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bk, D]
+        )  # [bk, Dv]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -270,7 +272,7 @@ def _bwd_dkv_kernel(
 
 def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     qt = q.transpose(0, 2, 1, 3)
@@ -290,8 +292,8 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
@@ -310,22 +312,22 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
     )(qt, kt, vt, dot, lse, delta)
     return (
@@ -382,7 +384,10 @@ def flash_attention(
     bwd_block_q: int = 1024,
     bwd_block_k: int = 512,
 ) -> jax.Array:
-    """Flash attention, [B, S, H, D] layout, GQA via repeated kv heads.
+    """Flash attention, [B, S, H, D] layout, GQA via repeated kv heads.  q and
+    k share one head size, v and the output may have another (latent
+    attention's 192 against 128): the kernels take both, and at equal sizes
+    they are the calls they were.
 
     Forward tiles default larger than backward: the bwd kernels hold four
     [bq, bk] f32 intermediates (logits/p/dp/ds) at once, so 1024x1024 there

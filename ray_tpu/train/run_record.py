@@ -16,7 +16,11 @@ Always on, whether `RAY_TPU_TRACE` is set or not:
     busy one (thread and process CPU seconds, involuntary switches, major
     faults, garbage collections);
   * report delivery: seconds from `session.report` in the worker to the
-    driver's `on_report`, per report.
+    driver's `on_report`, per report;
+  * step counters: the newest values of the step metrics a training context
+    names as counters (`LMTrainContext`: the `moe_held_rows_*` of a layer
+    that holds a share of its experts), noted at every step without a
+    fetch and read by the worker's `poll` once the device has them.
 
 `JaxTrainer.fit` attaches the record to `Result.run_record`; the newest
 stays readable through `last_run_record()` after `ray_tpu.shutdown()`, with
@@ -157,6 +161,29 @@ def drain_stalls() -> List[Dict[str, Any]]:
     return out
 
 
+# -- worker side: step counters --------------------------------------------------
+
+_noted: Dict[str, Any] = {}  # name -> the newest step's value, still on the device
+
+
+def note_step_counters(values: Dict[str, Any]) -> None:
+    """Keep the newest step's counters (device scalars; no fetch here: the
+    step that made them may still be running)."""
+    _noted.update(values)
+
+
+def drain_step_counters() -> Dict[str, float]:
+    """The noted counters the device has finished, as floats; the others
+    wait for the next `poll`."""
+    out = {}
+    for name, value in list(_noted.items()):
+        if getattr(value, "is_ready", lambda: True)():
+            out[name] = float(value)
+            if _noted.get(name) is value:
+                del _noted[name]
+    return out
+
+
 # -- worker side: what jax traces, lowers and compiles --------------------------
 
 _TRACE = "/jax/core/compile/jaxpr_trace_duration"
@@ -270,6 +297,7 @@ class RunRecord:
         self._worker_spans: Dict[str, Dict[str, Any]] = {}
         self.stalls: List[Dict[str, Any]] = []
         self.delivery_s: List[float] = []
+        self.step_counters: Dict[str, float] = {}
         self.polls = 0
 
     def add_poll(self, rank: int, reply: Dict[str, Any]) -> None:
@@ -281,6 +309,7 @@ class RunRecord:
             self._worker_spans[s["span_id"]] = s
         for e in reply.get("stalls") or ():
             self.stalls.append(dict(e, rank=rank))
+        self.step_counters.update(reply.get("step_counters") or {})
         for rep in reply["reports"]:
             if "t" in rep:
                 self.delivery_s.append(now - rep["t"])
@@ -299,6 +328,7 @@ class RunRecord:
             "runtime_spans": _by_start(
                 tracing.lifecycle_spans(self.runtime_trace_id) if self.runtime_trace_id else ()),
             "stalls": list(self.stalls),
+            "step_counters": dict(self.step_counters),
             "reports": {"count": len(d), "polls": self.polls,
                         "median_s": statistics.median(d) if d else None,
                         "max_s": max(d) if d else None},

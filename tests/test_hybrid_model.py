@@ -120,7 +120,7 @@ def tiny(chunk_of_32):
 
 def test_the_stack_is_three_runs_and_the_parameters_two_stacks(tiny):
     cfg, params = tiny["cfg"], tiny["params"]
-    assert cfg.layer_runs() == (("mamba", 0, 2), ("attention", 0, 1), ("mamba", 2, 1))
+    assert cfg.layer_runs() == (("mamba", "dense", 0, 2), ("attention", "dense", 0, 1), ("mamba", "dense", 2, 1))
     assert params["layers"]["attn"]["wq"].shape[0] == 1 and params["mamba_layers"]["ssm"]["in_proj"].shape[0] == 3
     assert sum(leaf.size for leaf in jax.tree_util.tree_leaves(params)) == cfg.num_params()
 
@@ -401,8 +401,16 @@ def test_what_the_config_refuses():
         TransformerConfig(**dict(BASE, layer_types=KINDS[:3]))
     with pytest.raises(ValueError, match="layer_types"):
         TransformerConfig(**dict(BASE, layer_types=("mamba", "window", "attention", "mamba")))
-    with pytest.raises(ValueError, match="dense FFN"):
-        TransformerConfig(**dict(BASE, n_experts=4, experts_per_token=2))
+    # a stack with layer_types takes experts too (PR 37): what it refuses is an FFN list it cannot read
+    assert TransformerConfig(**dict(BASE, n_experts=4, experts_per_token=2)).layer_pairs()[0] == ("mamba", "experts")
+    with pytest.raises(ValueError, match="ffn_types"):
+        TransformerConfig(**dict(BASE, ffn_types=("dense", "sparse", "dense", "dense")))
+    with pytest.raises(ValueError, match="n_experts"):
+        TransformerConfig(**dict(BASE, ffn_types=("dense", "experts", "dense", "dense")))
+    with pytest.raises(ValueError, match="kda_heads"):
+        TransformerConfig(**dict(BASE, layer_types=("kda",) * 4))
+    with pytest.raises(ValueError, match="kv_lora_rank"):
+        TransformerConfig(**dict(BASE, layer_types=("mla",) * 4))
     with pytest.raises(ValueError, match="ssm_heads"):
         TransformerConfig(**dict(BASE, ssm_heads=0))
 

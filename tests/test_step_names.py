@@ -196,6 +196,64 @@ def test_lowered_hybrid_step_keeps_the_mixers_new_ops_inside_its_scopes(hybrid_l
     assert directions >= ({"checkpoint/"} if "bwd" in op else {"", "checkpoint/rematted_computation/"})
 
 
+# -- Kimi Linear's names (PERF.md section 3, PR 37) ------------------------------------------
+
+KIMI_SCOPES = {"kda/proj": "layer/attn_proj", "kda/conv": "layer/attn_proj", "mla/proj": "layer/attn_proj",
+               "kda/scan": "layer/attn_core", "moe/shared": "layer/mlp", "moe/router": "layer/mlp",
+               "moe/dispatch": "layer/mlp", "moe/experts": "layer/mlp", "moe/combine": "layer/mlp"}
+KIMI_CFG = TransformerConfig.tiny(
+    n_layers=5, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, max_seq_len=128, remat=True, remat_policy="qkv_attn",
+    rope_theta=None, layer_types=("kda", "kda", "kda", "mla", "kda"), ffn_types=("dense",) + ("experts",) * 4,
+    kda_heads=2, kda_head_dim=128, kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    n_experts=16, n_experts_held=4, experts_per_token=2, moe_d_ff=128, n_shared_experts=1, norm_topk_prob=True,
+    router_activation="sigmoid", routed_scaling_factor=2.446, vocab_size=VOCAB,
+)
+
+
+@pytest.fixture(scope="module")
+def kimi_lowered_for_tpu():
+    ctx = LMTrainContext(KIMI_CFG, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
+    return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("scope", KIMI_SCOPES)
+def test_lowered_kimi_step_names_its_regions_inside_the_three_layer_scopes(kimi_lowered_for_tpu, scope):
+    """Forward and recompute: `layer/attn_proj`, `layer/attn_core` and
+    `layer/mlp` stay what they are in every cell, and
+    `benchmarks/lib/trace_kimi.py` splits them."""
+    outer = KIMI_SCOPES[scope]
+    assert f'"{outer}/{scope}/' in kimi_lowered_for_tpu
+    if scope != "moe/combine":  # (nothing behind `w_down` is a residual: PR 29)
+        assert f'"checkpoint/rematted_computation/{outer}/{scope}/' in kimi_lowered_for_tpu
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_lowered_kimi_step_keeps_every_model_scope(kimi_lowered_for_tpu, scope):
+    assert f"/{scope}/" in kimi_lowered_for_tpu or f"({scope})" in kimi_lowered_for_tpu
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_lowered_kimi_step_holds_each_flash_kernel_exactly_once_at_two_head_sizes(kimi_lowered_for_tpu, kernel):
+    calls = [line for line in kimi_lowered_for_tpu.splitlines() if "@tpu_custom_call" in line
+             and f'kernel_name = "{kernel}"' in line]
+    assert len(calls) == 1  # one MLA layer, its forward saved (`qkv_attn`)
+    assert "x192x" in calls[0] and "x128x" in calls[0]  # q/k heads of 192 beside v heads of 128, no padding
+
+
+def test_lowered_kimi_step_runs_no_d_wide_mixer_projection_twice(kimi_lowered_for_tpu):
+    """Under `qkv_attn` a KDA layer's fused q|k|v projection and an MLA
+    layer's q projection run in the forward and never again: the recompute
+    starts from the saved arrays (`_remat_policy`)."""
+    paths = re.findall(r'#loc\d+ = loc\("([^"]+)"', kimi_lowered_for_tpu)
+    recomputed = [p for p in paths if "rematted_computation/layer/attn_proj" in p and p.endswith("dot_general")]
+    assert not [p for p in recomputed if "bse,ef->bsf" in p and "kda/proj" in p and "bsr" not in p], recomputed
+    assert not [p for p in recomputed if "mla/proj" in p and "bse,ehd->bshd" in p], recomputed
+    assert any("kda/proj/bsr,rf->bsf" in p for p in recomputed)  # the gates' narrow-to-wide halves do
+
+
 def _locations(lowered):
     """{`#locN`: the path it names} of a lowered step's text."""
     return dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', lowered, flags=re.M))
