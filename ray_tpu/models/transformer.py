@@ -827,7 +827,7 @@ def _kda_layer(
             g = step.reshape(*step.shape[:2], heads, dim) * -jnp.exp(p["A_log"].astype(f32))[:, None]
             beta = jax.nn.sigmoid(low[..., 2 * dim:].astype(f32))
     with jax.named_scope("layer/attn_core"):
-        o = kda_chunked(q, k, v, g, beta)
+        o = kda_chunked(q, k, v, g, beta, **sharded)
     with jax.named_scope("layer/attn_proj"):
         with jax.named_scope("kda/conv"):
             gate = jax.nn.sigmoid(gate_in.astype(f32)).reshape(o.shape)

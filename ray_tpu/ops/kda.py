@@ -1,10 +1,13 @@
 """Kimi Delta Attention (KDA, "Kimi Linear", arXiv:2510.26692): a gated delta
 rule whose decay is PER CHANNEL of the key, in its chunked form; no
-counterpart in the reference (SURVEY.md §5.7).  Plain `jax.numpy`,
-differentiated by JAX, as `ssd_chunked` (`ops/ssm.py`) was when it arrived; a
-Pallas kernel that keeps the chunk's matrices in VMEM is what a `perf_opt`
-issue would write, and `kda/scan`, the scope around all of this, is what its
-gain would be read by (PERF.md section 3).
+counterpart in the reference (SURVEY.md §5.7).  `kda_chunked` is one
+`jax.custom_vjp`.  Its FORWARD is plain `jax.numpy` (`_plain_forward`, a scan
+over `_segment`) everywhere but on TPU, where at the shapes it takes a Pallas
+kernel keeps a chunk's matrices in VMEM (`ops/pallas/kda.py`, PR 39; the same
+arithmetic at the same precision, see Precision below).  Its BACKWARD is
+plain everywhere: JAX's own differentiation of `_segment`, a segment at a
+time (`_kda_bwd`); no gradient here is derived by hand.  `kda/scan`, the scope
+around all of this, is what the benchmark reads it by (PERF.md section 3).
 
 The recurrence, per batch row and per head, with a state `S_t` of shape
 [K, V] (key size x value size), a log decay `g_t <= 0` per key channel
@@ -31,12 +34,15 @@ k_t)`.  Inside a chunk of C positions that enters with the state `S_0`, with
   matmuls a step.
 
 The chunks are worked on `SEGMENT` at a time, one `lax.scan` step each with
-the state as its carry, and a segment is a `jax.checkpoint`: everything above
-is float32 arrays the size of k or larger (at one 16,384-token sequence and
-32 heads of 128, 256 MB each, and JAX's backward holds one cotangent of that
-size per level and operand of `_decayed_lower` until they are summed: 4.6 GB
-in the compiled step), so a segment's temporaries exist while that segment
-runs and its forward runs once more in the backward.
+the state as its carry: everything above is float32 arrays the size of k or
+larger (at one 16,384-token sequence and 32 heads of 128, 256 MB each, and
+JAX's backward holds one cotangent of that size per level and operand of
+`_decayed_lower` until they are summed: 4.6 GB in the compiled step), so a
+segment's temporaries exist while that segment runs.  The forward keeps the
+state that ENTERS each segment (8 x [b, 32, 128, 128] float32, 16.8 MB a
+layer: the only residual beside the arguments), and the backward walks the
+segments from the last to the first: `jax.vjp` of `_segment` at that state,
+its forward once more and then its backward, the state's cotangent carried.
 
 A decay is always the exponential of a DIFFERENCE of running sums that is
 <= 0, never a quotient of exponentials: `exp(G_t) / exp(G_s)` is 0/0 once G
@@ -62,7 +68,11 @@ averages its values' roundings away: with bf16 operands the benchmark's
 five-layer model read 1.9-2.5% of relative RMS error against the float32
 reference where the comparison allows 2.68% (22 readings), with these 1.6-2.0%
 (6 readings), for 0.19 s of a 1.64 s step; six passes read the same and cost
-0.26 s (my chip runs, PR 37; PERF.md section 6).
+0.26 s (my chip runs, PR 37; PERF.md section 6).  The kernel holds the same
+contract by hand: float32 operands, each product three bf16 passes with
+float32 accumulation, every exponent <= 0; on the chip its o is 1.6e-5 from
+this code's and as far from `kda_recurrent` as this code's is (4.4e-5 against
+4.2e-5; my chip runs, PR 39).
 
 Sharding: nothing here names a mesh axis; batch sharding is GSPMD's to
 propagate through the einsums.
@@ -70,12 +80,16 @@ propagate through the einsums.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.parallel.sharding import _fit_spec
 
 # The precision of every matmul here (module docstring).
 EXACT = jax.lax.Precision.HIGH
@@ -134,6 +148,140 @@ def _decayed_lower(a, b, G, *, diagonal: bool, row_scale=None, inverse: bool = F
     return out
 
 
+def _segment(state, inp):
+    """Some chunks at once, in plain `jax.numpy`: (the state that enters, their
+    q, k, v, g, beta as [b, c, H, l, d]) -> (the state that leaves,
+    o [b, c, H, l, V]).  The forward off TPU, and the function whose `jax.vjp`
+    is the backward everywhere (`_kda_bwd`)."""
+    f32 = jnp.float32
+    qc, kc, vc, gc, bc = (x.astype(f32) for x in inp)  # here, a segment at a time: v stays bf16 until then
+    chunk, dv = qc.shape[-2], vc.shape[-1]
+    # the running sum from each chunk's start (inclusive), as a product with a triangle of ones:
+    # `cumsum` lowers to a windowed reduction that ran at 24 GB/s on the v5e
+    G = jnp.einsum("ts,bchsk->bchtk", jnp.tril(jnp.ones((chunk, chunk), f32)), gc, precision=EXACT)
+
+    # inside a chunk: nothing here needs the state that enters
+    solve = _decayed_lower(kc, kc, G, diagonal=False, row_scale=bc, inverse=True)  # (I + A)^-1
+    qk = _decayed_lower(qc, kc, G, diagonal=True)
+    from_start = jnp.exp(G)  # [b, c, H, l, K], <= 1
+    rhs = jnp.concatenate([bc * vc, bc * (kc * from_start)], axis=-1)
+    solved = jnp.einsum("bchts,bchsd->bchtd", solve, rhs, precision=EXACT)
+    u_bar, w = solved[..., :dv], solved[..., dv:]
+    q_in = qc * from_start
+    k_out = kc * jnp.exp(G[..., -1:, :] - G)  # each row up to the chunk's end
+    through = from_start[..., -1, :]  # [b, c, H, K]: the whole chunk's decay
+
+    # the serial part: the state that enters each chunk
+    def cross(carry, per_chunk):
+        w_c, u_c, k_c, decay_c = per_chunk
+        u = u_c - jnp.einsum("bhlk,bhkv->bhlv", w_c, carry, precision=EXACT)
+        out = carry * decay_c[..., None] + jnp.einsum("bhlk,bhlv->bhkv", k_c, u, precision=EXACT)
+        return out, carry
+
+    by_chunk = lambda x: jnp.moveaxis(x, 1, 0)
+    state, entering = jax.lax.scan(cross, state, tuple(map(by_chunk, (w, u_bar, k_out, through))))
+    entering = jnp.moveaxis(entering, 0, 1)  # [b, c, H, K, V]
+
+    # every position's output, the segment's chunks at once
+    u = u_bar - jnp.einsum("bchlk,bchkv->bchlv", w, entering, precision=EXACT)
+    o = jnp.einsum("bchlk,bchkv->bchlv", q_in, entering, precision=EXACT)
+    o = o + jnp.einsum("bchts,bchsv->bchtv", qk, u, precision=EXACT)
+    return state, o
+
+
+def _segments(x, chunk: int, per_segment: int):
+    """[b, S, H, d] -> [segments, b, c, H, l, d]: what the scans over `_segment` and the kernel walk."""
+    b, s, h, d = x.shape
+    x = x.reshape(b, s // (chunk * per_segment), per_segment, chunk, h, d)
+    return x.transpose(1, 0, 2, 4, 3, 5)
+
+
+def _positions(o):
+    """Back: [segments, b, c, H, l, d] -> [b, S, H, d]."""
+    n, b, c, h, l, d = o.shape
+    return o.transpose(1, 0, 2, 4, 3, 5).reshape(b, n * c * l, h, d)
+
+
+def _per_segment(s: int, chunk: int) -> int:
+    return math.gcd(s // chunk, SEGMENT)
+
+
+def _plain_forward(q, k, v, g, beta):
+    """One `lax.scan` over `_segment`: q, k, v, g, beta as `_segments` gives them ->
+    (o [segments, b, c, H, l, V], the state that enters each segment [segments, b, H, K, V])."""
+    _, b, _, h, _, dk = k.shape
+
+    def step(state, inp):
+        left, o = _segment(state, inp)
+        return left, (o, state)
+
+    return jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32), (q, k, v, g, beta))[1]
+
+
+def _kernels():
+    """`ops/pallas/kda.py`, imported at first use like the other ops' kernels."""
+    from ray_tpu.ops.pallas import kda
+
+    return kda
+
+
+def _kernel_takes(k, v, chunk: int) -> bool:
+    return _kernels().supported(k.shape[-1], v.shape[-1], chunk, _per_segment(k.shape[1], chunk))
+
+
+def _forward(q, k, v, g, beta, chunk: int):
+    """(o [b, S, H, V] float32, the state that enters each segment).  Like
+    attention and the convolution, the form follows the platform a step is
+    LOWERED for, not the process's backend: the kernel for TPU at shapes it
+    takes, the plain form everywhere else."""
+    segments = functools.partial(_segments, chunk=chunk, per_segment=_per_segment(k.shape[1], chunk))
+    plain = lambda q, k, v, g, beta: _plain_forward(q, k, v, g, segments(beta[..., None]))
+    inputs = (*map(segments, (q, k, v, g)), beta)
+    if _kernel_takes(k, v, chunk):
+        o, entering = jax.lax.platform_dependent(*inputs, tpu=_kernels().kda_fwd, default=plain)
+    else:
+        o, entering = plain(*inputs)
+    return _positions(o), entering
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda(q, k, v, g, beta, chunk: int):
+    return _forward(q, k, v, g, beta, chunk)[0]
+
+
+def _kda_fwd(q, k, v, g, beta, chunk: int):
+    o, entering = _forward(q, k, v, g, beta, chunk)
+    return o, (q, k, v, g, beta, entering)
+
+
+def _kda_bwd(chunk: int, res, do):
+    """JAX's own differentiation of `_segment`, a segment at a time from the
+    last to the first: the segment's forward once more at the state that
+    entered it, then its backward, the state's cotangent carried along.  The
+    [chunk, chunk] matrices, their decayed operands and every cotangent of them
+    exist for one segment at a time."""
+    q, k, v, g, beta, entering = res
+    per_segment = _per_segment(k.shape[1], chunk)
+    inputs = tuple(_segments(x, chunk, per_segment) for x in (q, k, v, g, beta[..., None]))
+
+    def step(d_state, xs):
+        state, inp, d_o = xs
+        # a checkpoint, so that what `pull` holds is the segment's arguments and it runs forward and
+        # backward in one piece (the primal outputs are unused: the forward runs once, in `pull`);
+        # without it the step's peak was 0.11 GB higher (AOT build, PR 39)
+        _, pull = jax.vjp(jax.checkpoint(_segment), state, inp)
+        d_state, d_inp = pull((d_state, d_o))
+        return d_state, d_inp
+
+    d_o = _segments(do, chunk, per_segment)
+    _, d_inputs = jax.lax.scan(step, jnp.zeros_like(entering[0]), (entering, inputs, d_o), reverse=True)
+    dq, dk, dv, dg, dbeta = map(_positions, d_inputs)  # each in its argument's dtype: `_segment` casts inside
+    return dq, dk, dv, dg, dbeta[..., 0]
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
+
+
 def kda_chunked(
     q: jax.Array,
     k: jax.Array,
@@ -141,69 +289,34 @@ def kda_chunked(
     g: jax.Array,
     beta: jax.Array,
     chunk: Optional[int] = None,
+    mesh=None,
+    batch_axes=None,
 ) -> jax.Array:
     """The gated delta rule of the module docstring, chunked.
 
     q, k [b, S, H, K] (already normalised and scaled as the layer has them);
     v [b, S, H, V]; g [b, S, H, K] (log decay, <= 0); beta [b, S, H].  Returns
     o [b, S, H, V] in float32.  `chunk` (None = `CHUNK`, a power of two) is
-    cut to S when S is shorter; S must be a multiple of it."""
-    b, s, h, dk = k.shape
-    dv = v.shape[-1]
+    cut to S when S is shorter; S must be a multiple of it.
+
+    mesh / batch_axes say how the arguments are sharded.  GSPMD partitions the
+    plain form by itself; a Mosaic kernel it cannot, so with a mesh the kernel
+    runs under shard_map over the batch axes, each device on its own rows with
+    the whole sequence and every head."""
+    s = k.shape[1]
     chunk = min(chunk or CHUNK, s)
     if s % chunk or chunk & (chunk - 1):
         raise ValueError(f"kda_chunked: sequence length {s} needs a power-of-two chunk that divides it, got {chunk}")
-    nc = s // chunk
-    per_segment = math.gcd(nc, SEGMENT)
-    f32 = jnp.float32
 
-    def segment(state, inp):
-        """`per_segment` chunks at once: (the state that enters, their q, k, v,
-        g, beta as [b, c, H, l, d]) -> (the state that leaves, o [b, c, H, l, V])."""
-        qc, kc, vc, gc, bc = inp
-        # the running sum from each chunk's start (inclusive), as a product with a triangle of ones:
-        # `cumsum` lowers to a windowed reduction that ran at 24 GB/s on the v5e
-        G = jnp.einsum("ts,bchsk->bchtk", jnp.tril(jnp.ones((chunk, chunk), f32)), gc, precision=EXACT)
+    def run(q, k, v, g, beta):  # the scope INSIDE what shard_map wraps: its body starts a name stack of its own
+        with jax.named_scope("kda/scan"):
+            return _kda(q, k, v, g, beta, chunk)
 
-        # inside a chunk: nothing here needs the state that enters
-        solve = _decayed_lower(kc, kc, G, diagonal=False, row_scale=bc, inverse=True)  # (I + A)^-1
-        qk = _decayed_lower(qc, kc, G, diagonal=True)
-        from_start = jnp.exp(G)  # [b, c, H, l, K], <= 1
-        rhs = jnp.concatenate([bc * vc, bc * (kc * from_start)], axis=-1)
-        solved = jnp.einsum("bchts,bchsd->bchtd", solve, rhs, precision=EXACT)
-        u_bar, w = solved[..., :dv], solved[..., dv:]
-        q_in = qc * from_start
-        k_out = kc * jnp.exp(G[..., -1:, :] - G)  # each row up to the chunk's end
-        through = from_start[..., -1, :]  # [b, c, H, K]: the whole chunk's decay
-
-        # the serial part: the state that enters each chunk
-        def cross(carry, per_chunk):
-            w_c, u_c, k_c, decay_c = per_chunk
-            u = u_c - jnp.einsum("bhlk,bhkv->bhlv", w_c, carry, precision=EXACT)
-            out = carry * decay_c[..., None] + jnp.einsum("bhlk,bhlv->bhkv", k_c, u, precision=EXACT)
-            return out, carry
-
-        by_chunk = lambda x: jnp.moveaxis(x, 1, 0)
-        state, entering = jax.lax.scan(cross, state, tuple(map(by_chunk, (w, u_bar, k_out, through))))
-        entering = jnp.moveaxis(entering, 0, 1)  # [b, c, H, K, V]
-
-        # every position's output, the segment's chunks at once
-        u = u_bar - jnp.einsum("bchlk,bchkv->bchlv", w, entering, precision=EXACT)
-        o = jnp.einsum("bchlk,bchkv->bchlv", q_in, entering, precision=EXACT)
-        o = o + jnp.einsum("bchts,bchsv->bchtv", qk, u, precision=EXACT)
-        return state, o
-
-    with jax.named_scope("kda/scan"):
-        def segments(x):  # [b, S, H, d] -> [segments, b, c, H, l, d]
-            x = x.reshape(b, nc // per_segment, per_segment, chunk, h, x.shape[-1])
-            return x.transpose(1, 0, 2, 4, 3, 5)
-
-        inputs = tuple(segments(x.astype(f32)) for x in (q, k, v, g, beta[..., None]))
-        # Each segment keeps its inputs and the state that enters it, and runs
-        # again in the backward: the [chunk, chunk] matrices, their decayed
-        # operands and every cotangent of them exist for one segment at a time.
-        _, o = jax.lax.scan(jax.checkpoint(segment), jnp.zeros((b, h, dk, dv), f32), inputs)
-        return o.transpose(1, 0, 2, 4, 3, 5).reshape(b, s, h, dv)
+    if mesh is None or not _kernel_takes(k, v, chunk):
+        return run(q, k, v, g, beta)
+    rows = _fit_spec(k.shape, P(batch_axes, None, None, None), mesh)
+    return jax.shard_map(run, mesh=mesh, in_specs=(rows, rows, rows, rows, P(*rows[:3])), out_specs=rows,
+                         check_vma=False)(q, k, v, g, beta)
 
 
 def kda_recurrent(q, k, v, g, beta):

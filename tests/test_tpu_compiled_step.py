@@ -7,6 +7,7 @@ The topology is described inside a fixture and only here (one process may
 hold libtpu; see `.claude/skills/verify/SKILL.md` item 3 for the same compile
 by hand)."""
 
+import contextlib
 import os
 import re
 
@@ -40,26 +41,32 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
+@contextlib.contextmanager
+def _no_compile_cache():
+    """The persistent compile cache off: an entry written without a chip cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 @pytest.fixture(scope="module", params=list(CONFIGS))
 def compiled_step(request, topo):
     """Optimized HLO of the step on one described chip.  The persistent
     compile cache is off around it: an entry written without a chip cannot be
     read back."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     cfg = TransformerConfig.tiny(**COMMON, **CONFIGS[request.param])
     ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=topo.devices[:1]), strategy="dp")
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=ctx.batch_sharding)
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        with ctx.mesh:
-            return ctx._train_step.lower(state, {"tokens": toks, "targets": toks}).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    with _no_compile_cache(), ctx.mesh:
+        return ctx._train_step.lower(state, {"tokens": toks, "targets": toks}).compile().as_text()
 
 
 def _buffers(hlo):
@@ -111,3 +118,21 @@ def test_nothing_under_lm_head_or_loss_scatters(compiled_step):
         path = re.search(r'op_name="([^"]*)"', line)
         if path and re.search(r"[(/](lm_head|loss)[)/]", path.group(1)):
             assert "scatter" not in path.group(1) and " scatter(" not in line
+
+
+def test_the_kda_forward_kernel_compiles_at_the_kimi_cells_shapes(topo):
+    """Mosaic takes the kernel at one 16,384-token sequence of 32 heads of 128
+    (interpret mode cannot say: tiling, fast memory and the rolls, transposes
+    and bf16 products the TPU compiler has to accept), as ONE custom call."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.pallas import kda as kernels
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    blocks = jax.ShapeDtypeStruct((8, 1, 32, 32, 64, 128), jnp.float32, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, 16384, 32), jnp.float32, sharding=one_chip)
+    with _no_compile_cache():
+        compiled = jax.jit(kernels.kda_fwd).lower(blocks, blocks, blocks, blocks, beta).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 and "kda_fwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20  # its operands as they come: no relayout copy

@@ -28,14 +28,14 @@ CFG = TransformerConfig.tiny(
 )
 
 
-def _lowered_text(n_devices, spec, strategy, platforms=None, cfg=CFG):
+def _lowered_text(n_devices, spec, strategy, platforms=None, cfg=CFG, debug_info=False):
     mesh = build_mesh(spec, devices=jax.devices()[:n_devices])
     ctx = LMTrainContext(cfg, mesh=mesh, strategy=strategy)
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
     traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
     kw = {"lowering_platforms": platforms} if platforms else {}
-    return traced.lower(**kw).as_text()
+    return traced.lower(**kw).as_text(debug_info=debug_info)
 
 
 def _mosaic_kernels(text):
@@ -129,3 +129,43 @@ def test_hybrid_step_lowers_for_tpu_with_each_flash_kernel_once(n_devices, spec,
                                      "ssm_conv_fwd": 4, "ssm_conv_bwd": 2}
     call = next(line for line in text.splitlines() if "@tpu_custom_call" in line and 'kernel_name = "flash_fwd"' in line)
     assert re.search(r"tensor<\d+x\d+x128x64xf32>", call)  # q: [batch, heads, seq, 64] (float32 in this tiny config)
+
+
+# Kimi Linear's mixers in small, at the head size the KDA kernel takes: two KDA
+# layers (one run, one scan body) and one latent-attention layer.
+KIMI = TransformerConfig.tiny(
+    n_layers=3, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, max_seq_len=128, remat=True, remat_policy="qkv_attn",
+    rope_theta=None, layer_types=("kda", "kda", "mla"), kda_heads=2, kda_head_dim=128,
+    kv_lora_rank=64, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+)
+
+
+@pytest.mark.parametrize(
+    "n_devices,spec,strategy",
+    [(1, MeshSpec(data=1), "dp"), (4, MeshSpec(data=1, fsdp=4), "fsdp"), (4, MeshSpec(data=2, tensor=2), "tp")],
+    ids=["dp1", "fsdp4", "tp4"],
+)
+def test_kimi_step_lowers_for_tpu_with_the_kda_forward_kernel_inside_kda_scan(n_devices, spec, strategy):
+    """The KDA run is one scan body: the recurrence's forward kernel twice
+    (forward, and the recompute: `qkv_attn` keeps the projections, not the
+    recurrence's output) and NO backward kernel (the backward is JAX's own, of
+    the plain segment); under shard_map on a mesh like the others."""
+    text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=KIMI, debug_info=True)
+    kernels = _mosaic_kernels(text)
+    assert kernels["kda_fwd"] == 2 and kernels["flash_fwd"] == 1, kernels
+    assert not [name for name in kernels if name.startswith("kda_") and name != "kda_fwd"]
+    locs = dict(re.findall(r'^(#loc\d+) = loc\((.*)\)$', text, flags=re.M))
+    for line in text.splitlines():
+        if "@tpu_custom_call" in line and 'kernel_name = "kda_fwd"' in line:
+            loc = re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)
+            seen, path = set(), ""
+            while loc and loc not in seen:  # a location names its parents by reference
+                seen.add(loc)
+                path += locs.get(loc, "")
+                nxt = re.search(r"#loc\d+", locs.get(loc, ""))
+                loc = nxt.group(0) if nxt else None
+            assert "kda/scan" in path and "kda_fwd" in path, path
+
+
+def test_kimi_step_lowered_for_the_cpu_holds_no_kernel():
+    assert "tpu_custom_call" not in _lowered_text(1, MeshSpec(data=1), "dp", cfg=KIMI)
