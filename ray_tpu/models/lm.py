@@ -120,6 +120,24 @@ def _head_cross_entropy_bwd(constrain, residuals, g):
 head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
 
 
+# A step counter of the run's record, among the step's metrics like `moe_held_rows_*`.
+WINDOW_TILES = "attn_window_tiles_visited_pct"
+
+
+def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
+    """`WINDOW_TILES`: the key tiles the windowed flash forward visits over
+    those a causal call would, the mean over the windowed layers, from the
+    block sizes in use at this length (what the kernels would do: off TPU the
+    XLA forms run and visit no tile).  Known when the step is traced; nothing
+    for a model without a window or a length no tile divides."""
+    from ray_tpu.ops.pallas.flash_attention import window_tiles_visited_pct
+
+    visited = [window_tiles_visited_pct(seq, w) for w in config.layer_windows or () if w is not None]
+    if not visited or None in visited:
+        return {}
+    return {WINDOW_TILES: sum(visited) / len(visited)}
+
+
 def default_optimizer(
     learning_rate: float = 3e-4, weight_decay: float = 0.1, **kw
 ) -> optax.GradientTransformation:
@@ -209,7 +227,7 @@ class LMTrainContext:
                 _constrainer(rules, self.mesh), x, head, batch["targets"], batch.get("mask"))
             with jax.named_scope("loss"):
                 if router_stats is None:
-                    return ce, {}
+                    return ce, _window_counters(cfg, batch["tokens"].shape[1])
                 terms = router_losses(router_stats, cfg)
                 loss = (ce + cfg.router_aux_loss_coef * terms["moe_lb_loss"]
                         + cfg.router_z_loss_coef * terms["moe_z_loss"])
@@ -294,6 +312,8 @@ class LMTrainContext:
         if "moe_held_rows_mean" in metrics:
             # counters of the run's record (train/run_record.py): kept as device scalars, fetched at a poll
             note_step_counters({k: metrics[k] for k in ("moe_held_rows_mean", "moe_held_rows_max")})
+        if WINDOW_TILES in metrics:
+            note_step_counters({WINDOW_TILES: metrics[WINDOW_TILES]})
         return state, metrics
 
     def apply(self, params, tokens) -> jax.Array:

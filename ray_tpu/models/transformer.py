@@ -9,9 +9,16 @@ decay per channel) or latent attention without rotary embedding ("mla": keys
 and values expanded from one low-rank latent, q/k heads wider than v heads);
 the FFN (`ffn_types`) is a dense SwiGLU or a dropless top-k mixture of SwiGLU
 experts (`n_experts`; softmax or sigmoid router, an optional shared expert,
-all experts or one rank's share of them: models/moe.py).  Mistral, InternLM2,
-OLMoE, the Granite 4.0-H hybrids and Kimi Linear run through it at their
-published widths (benchmarks/configs/).
+all experts or one rank's share of them: models/moe.py).  Four more mixers
+make SambaY's decoder-hybrid-decoder (below): a Mamba-1 selective scan
+("s6"), differential attention ("diff_attention", per layer a causal window
+or none), a Gated Memory Unit ("gmu") that reads one s6 layer's scan output,
+and differential cross-attention ("diff_cross") over one diff_attention
+layer's keys and values.  Norms are RMSNorm or LayerNorm with bias
+(`norm_kind`), and the differential layers' projections may carry biases
+(`attn_bias`).  Mistral, InternLM2, OLMoE, the Granite 4.0-H hybrids, Kimi
+Linear and Phi-4-mini-flash run through it at their published widths
+(benchmarks/configs/).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -77,6 +84,48 @@ rotary embedding anywhere, H heads of size D = `kda_head_dim` in a KDA layer:
   causal softmax of `q k^T * (nope + rope)^-0.5`; `W_o: H * v_head_dim -> d`.
 - FFN of an "experts" layer: the sigmoid router, the shared expert and the
   held experts of `models/moe.py`.
+
+SambaY (Phi-4-mini-flash-reasoning, `model_type: phi4flash`, arXiv:2507.06607;
+differential attention, arXiv:2410.05258; Mamba, arXiv:2312.00752): no
+positional encoding anywhere; every norm a LayerNorm (mean and variance,
+learned scale AND bias, `norm_eps`); layer l: `h = x + Mixer_l(LN1(x))`,
+`y = h + W_down(silu(W_gate u) * (W_up u))` with `u = LN2(h)`, no bias in the
+FFN; `logits = LN_f(y) @ embed.T`.  The mixers, u = LN1(x):
+
+- "s6", Mamba-1 with `s6_inner` channels, state N = `s6_state`, `s6_dt_rank`:
+  `[x | z] = u W_in` (no bias); `x = silu(conv1d_causal_depthwise(x, width
+  s6_conv, with bias))`; `[dt_low | B | C] = x W_x` (dt_rank | N | N);
+  `dt = softplus(dt_low W_dt + b_dt)` [S, inner]; `A = -exp(A_log)` [inner, N];
+  `h_t[c, n] = exp(dt_t[c] A[c, n]) h_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c]`;
+  `y_t[c] = sum_n C_t[n] h_t[c, n] + D[c] x_t[c]` (`ops/selective_scan.py`,
+  in its chunked form); `out = (y * silu(z)) W_out`.  State, `dt * A`, its
+  exponentials and the softplus in float32.  At the layer `s6_memory_layer`,
+  `M = y` (with the D skip, before the gate) is handed on as well.
+- "gmu": `out = (M * silu(u W_1)) W_2`, `W_1: d -> s6_inner`, `W_2: s6_inner
+  -> d`, no bias.
+- "diff_attention": `[q | k | v] = u W_qkv + b_qkv`, heads of `head_dim`;
+  heads pair up adjacently: q pair p = q heads (2p, 2p+1) = (q1, q2); with
+  G = n_heads / n_kv_heads, kv pair j = p // G: k heads (2j, 2j+1) = (k1, k2),
+  `v = [v_2j | v_2j+1]` of width 2 * head_dim.
+  `a1 = softmax(q1 k1^T / sqrt(head_dim) + mask) v`, `a2` likewise from
+  (q2, k2); `lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`, four
+  learned vectors of head_dim a layer, `lambda_init = 0.8 - 0.6 exp(-0.3 l)`
+  with l the layer's PUBLISHED index (`layer_ids`);
+  `o = RMSNorm(a1 - lambda a2) * (1 - lambda_init)` over 2 * head_dim (one
+  learned scale a layer), reshaped to two heads; `out = o W_o + b_o`.  The
+  mask is causal, and with a window w (`layer_windows`) query i sees keys
+  i - w + 1 .. i.  At the layer `kv_source_layer`, k and v (after bias) are
+  handed on as well.
+- "diff_cross": its own `q = u W_q + b_q`, lambda, norm and `W_o`; k and v
+  are the `kv_source_layer`'s; full causal.
+
+What crosses layers (M; k and v) is RETURNED by the layer that makes it,
+carried by `trunk` beside the stream and given to the later runs as an
+argument: under `jax.checkpoint` an input of the reading layer, not
+recomputed by it, and its cotangents sum over the readers.  `tp`, `pp` and
+the sequence-parallel ring refuse the differential kinds by name (`pp` any
+`layer_types`); s6 and gmu replicate their inner width under `tp`, as
+Mamba-2 does.
 """
 
 from __future__ import annotations
@@ -88,11 +137,13 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, dot_product_attention
 from ray_tpu.ops.rotary import apply_rope
 from ray_tpu.ops.kda import kda_chunked
+from ray_tpu.ops.selective_scan import selective_scan
 from ray_tpu.ops.ssm import causal_conv1d_silu, ssd_chunked
 from ray_tpu.parallel.sharding import Rules, with_logical_constraint
 
@@ -102,8 +153,16 @@ LOGITS_AXES = ("act_batch", "act_seq", "act_vocab")
 
 # The kinds of mixer, and the subtree of the parameters that stacks each
 # (`TransformerConfig.stack_name`); the kinds of FFN.
-LAYER_KINDS = {"attention": "layers", "mamba": "mamba_layers", "kda": "kda_layers", "mla": "mla_layers"}
+LAYER_KINDS = {
+    "attention": "layers", "mamba": "mamba_layers", "kda": "kda_layers", "mla": "mla_layers",
+    "s6": "s6_layers", "diff_attention": "diff_layers", "gmu": "gmu_layers", "diff_cross": "cross_layers",
+}
 FFN_KINDS = ("dense", "experts")
+# The kinds whose layer function takes what earlier layers handed on (and
+# per-layer data) beside its parameters, and returns what it hands on.
+CROSS_KINDS = ("s6", "diff_attention", "gmu", "diff_cross")
+# What crosses layers, by name: an s6 layer's scan output; a diff_attention layer's keys and values.
+MEMORY, SHARED_K, SHARED_V = "memory", "shared_k", "shared_v"
 
 # `checkpoint_name`s of a Mamba-2 layer's residuals (`_remat_policy`):
 # `in_proj`'s output before its split into z, x|B|C and dt, and the residual
@@ -118,6 +177,15 @@ KDA_QKV = "kda_qkv"
 KDA_LOW = "kda_low"
 KDA_MIXED = "kda_mixed"
 MLA_MIXED = "mla_mixed"
+# Of an s6 layer's: `W_in`'s output before its split into x and z, and the
+# stream after `W_out`.  Of a GMU's: `W_1`'s output and the stream after
+# `W_2`.  Of the two differential kinds', beside attention's own q, k, v,
+# output and log-sum-exp (both maps are one call): the stream after `W_o`.
+S6_IN_PROJ = "s6_in_proj"
+S6_MIXED = "s6_mixed"
+GMU_GATE = "gmu_gate"
+GMU_MIXED = "gmu_mixed"
+DIFF_MIXED = "diff_mixed"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -217,6 +285,34 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     attention_scale: Optional[float] = None
+    # "rms": RMSNorm with a learned scale; "layer": LayerNorm (mean and
+    # variance) with a learned scale and bias (`ln1_b`, `ln2_b`,
+    # `final_norm_b` beside the scales).  `attn_bias`: biases on the
+    # projections of the differential kinds (`b_qkv` / `b_q`, `b_o`); the
+    # other kinds of attention have none and refuse it.
+    norm_kind: str = "rms"
+    attn_bias: bool = False
+    # Mamba-1 (S6), read only when some layer is "s6" or "gmu": the mixer's
+    # inner width, the state size N per channel, the width of the causal
+    # depthwise convolution, the rank of dt's projection (0 = ceil(d / 16)).
+    # `s6_memory_layer`: the index IN THIS STACK of the s6 layer whose scan
+    # output every later gmu layer reads.
+    s6_inner: int = 0
+    s6_state: int = 16
+    s6_conv: int = 4
+    s6_dt_rank: int = 0
+    s6_memory_layer: Optional[int] = None
+    # Differential attention ("diff_attention", "diff_cross"; `n_heads` /
+    # `n_kv_heads` heads of `head_dim`, both even).  `kv_source_layer`: the
+    # index in this stack of the diff_attention layer whose k and v every
+    # later diff_cross layer reads.  `layer_windows`: per layer the causal
+    # window of its attention (None = full causal), read by "attention" and
+    # "diff_attention" layers; None = no layer has one.  `layer_ids`: the
+    # PUBLISHED index of each layer (None = its index here), which
+    # differential attention's lambda_init is a function of.
+    kv_source_layer: Optional[int] = None
+    layer_windows: Optional[Tuple[Optional[int], ...]] = None
+    layer_ids: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -237,6 +333,14 @@ class TransformerConfig:
                 self.kv_lora_rank > 0 and self.qk_nope_head_dim > 0 and self.v_head_dim > 0
             ):
                 raise ValueError("an mla layer needs kv_lora_rank, qk_nope_head_dim and v_head_dim")
+            self._check_cross_kinds()
+        for name in ("layer_windows", "layer_ids"):
+            if getattr(self, name) is not None:
+                object.__setattr__(self, name, tuple(getattr(self, name)))
+                if len(getattr(self, name)) != self.n_layers:
+                    raise ValueError(f"{name} needs n_layers={self.n_layers} entries")
+        if self.norm_kind not in ("rms", "layer"):
+            raise ValueError(f"unknown norm_kind {self.norm_kind!r}; expected 'rms' or 'layer'")
         if self.ffn_types is not None:
             object.__setattr__(self, "ffn_types", tuple(self.ffn_types))
             unknown = set(self.ffn_types) - set(FFN_KINDS)
@@ -263,9 +367,44 @@ class TransformerConfig:
                 f"is no share of n_experts={self.n_experts}"
             )
 
+    def _check_cross_kinds(self):
+        """What the kinds that read another layer's values need of the stack."""
+        kinds = self.layer_types
+        if ("s6" in kinds or "gmu" in kinds) and not (self.s6_inner > 0 and self.s6_state > 0):
+            raise ValueError("an s6 or gmu layer needs s6_inner and s6_state")
+        if ("diff_attention" in kinds or "diff_cross" in kinds) and (
+            self.n_heads % 2 or self.n_kv_heads % 2 or self.n_heads % self.n_kv_heads
+        ):
+            raise ValueError("differential attention pairs adjacent heads: n_heads and n_kv_heads "
+                             "must be even, n_heads a multiple of n_kv_heads")
+        for reader, maker, source, name in (("gmu", "s6", self.s6_memory_layer, "s6_memory_layer"),
+                                            ("diff_cross", "diff_attention", self.kv_source_layer, "kv_source_layer")):
+            if source is not None and not (0 <= source < self.n_layers and kinds[source] == maker):
+                raise ValueError(f"{name}={source} is no {maker} layer of this stack")
+            if reader in kinds and (source is None or kinds.index(reader) < source):
+                raise ValueError(f"a {reader} layer needs {name}, a {maker} layer before it")
+        if self.attn_bias and ("attention" in kinds or "mla" in kinds):
+            raise ValueError("attn_bias is the differential kinds' alone: 'attention' and 'mla' layers have no bias")
+
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def dt_rank(self) -> int:
+        return self.s6_dt_rank or -(-self.d_model // 16)
+
+    def layer_variant(self, i: int) -> Tuple[Optional[int], bool]:
+        """What of layer i is STATIC beside its pair, so that a run of one
+        compiled body cannot span a change of it: (its attention's window,
+        whether it hands values on to later layers)."""
+        window = None if self.layer_windows is None else self.layer_windows[i]
+        return window, i in (self.s6_memory_layer, self.kv_source_layer)
+
+    def lambda_inits(self) -> Tuple[float, ...]:
+        """Differential attention's `lambda_init` of each layer, from its published index."""
+        ids = self.layer_ids or tuple(range(self.n_layers))
+        return tuple(0.8 - 0.6 * math.exp(-0.3 * l) for l in ids)
 
     @property
     def expert_width(self) -> int:
@@ -295,17 +434,25 @@ class TransformerConfig:
         }
 
     def layer_runs(self) -> Tuple[Tuple[str, str, int, int], ...]:
-        """The stack as maximal runs of one pair: (mixer, FFN, first, count),
-        where `first` counts layers of that pair, i.e. indexes the pair's own
-        parameter stack.  A homogeneous model is one run."""
-        runs, seen = [], {}
-        for pair in self.layer_pairs():
-            if runs and tuple(runs[-1][:2]) == pair:
+        """The stack as maximal runs of one pair (and one `layer_variant`):
+        (mixer, FFN, first, count), where `first` counts layers of that pair,
+        i.e. indexes the pair's own parameter stack.  A homogeneous model is
+        one run."""
+        runs, seen, last = [], {}, None
+        for i, pair in enumerate(self.layer_pairs()):
+            key = (pair, self.layer_variant(i))
+            if key == last:
                 runs[-1][3] += 1
             else:
                 runs.append([*pair, seen.get(pair, 0), 1])
             seen[pair] = seen.get(pair, 0) + 1
+            last = key
         return tuple(tuple(r) for r in runs)
+
+    def run_starts(self) -> Tuple[int, ...]:
+        """The index in the stack of each run's first layer."""
+        counts = [count for _, _, _, count in self.layer_runs()]
+        return tuple(sum(counts[:i]) for i in range(len(counts)))
 
     def n_layers_of(self, mixer: Optional[str] = None, ffn: Optional[str] = None) -> int:
         return sum(1 for m, f in self.layer_pairs() if mixer in (None, m) and ffn in (None, f))
@@ -339,15 +486,25 @@ class TransformerConfig:
         mla = (d * self.n_heads * qk + d * (self.kv_lora_rank + self.qk_rope_head_dim) + self.kv_lora_rank
                + self.kv_lora_rank * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
                + self.n_heads * self.v_head_dim * d)
-        mixer = {"attention": attn, "mamba": ssm, "kda": kda, "mla": mla}
+        s6_in, rank, hd = self.s6_inner, self.dt_rank, self.head_dim
+        s6 = (d * 2 * s6_in + s6_in * (self.s6_conv + 1)  # W_in, convolution
+              + s6_in * (rank + 2 * self.s6_state) + rank * s6_in + s6_in  # W_x, W_dt, b_dt
+              + s6_in * self.s6_state + s6_in + s6_in * d)  # A_log, D, W_out
+        q_wide, kv_wide = self.n_heads * hd, 2 * self.n_kv_heads * hd
+        bias = (q_wide + d) if self.attn_bias else 0
+        cross = 2 * d * q_wide + bias + 4 * hd + 2 * hd  # W_q, W_o, the four lambda vectors, the norm
+        diff = cross + d * kv_wide + (kv_wide if self.attn_bias else 0)
+        mixer = {"attention": attn, "mamba": ssm, "kda": kda, "mla": mla,
+                 "s6": s6, "diff_attention": diff, "gmu": 2 * d * s6_in, "diff_cross": cross}
         ffn = {"dense": 3 * d * self.d_ff}
         if self.n_experts is not None:
             held = self.n_experts if self.n_experts_held is None else self.n_experts_held
             ffn["experts"] = (d * self.n_experts + (held + self.n_shared_experts) * 3 * d * self.expert_width
                               + (self.n_experts if self.router_activation == "sigmoid" else 0))
-        layers = sum(mixer[m] + ffn[f] + 2 * d for m, f in self.layer_pairs())
+        norm = d * (2 if self.norm_kind == "layer" else 1)  # scale, and LayerNorm's bias
+        layers = sum(mixer[m] + ffn[f] + 2 * norm for m, f in self.layer_pairs())
         out = 0 if self.tie_embeddings else self.vocab_size * d
-        return self.vocab_size * d + layers + d + out
+        return self.vocab_size * d + layers + norm + out
 
     @property
     def _ssm_conv_channels(self) -> int:
@@ -397,6 +554,30 @@ def _mixer_axes(config: TransformerConfig, mixer: str) -> Dict:
             "norm": L + (None,),
             "wo": L + (None, "embed"),
         }}
+    if mixer == "s6":  # as Mamba-2: fsdp shards the projections over `embed`, tp replicates the inner width
+        return {"s6": {
+            "in_proj": L + ("embed", None),
+            "conv_w": L + (None, None),
+            "conv_b": L + (None,),
+            "x_proj": L + (None, None),
+            "dt_proj": L + (None, None),
+            "dt_bias": L + (None,),
+            "A_log": L + (None, None),
+            "D": L + (None,),
+            "out_proj": L + (None, "embed"),
+        }}
+    if mixer == "gmu":
+        return {"gmu": {"w1": L + ("embed", None), "w2": L + (None, "embed")}}
+    if mixer in ("diff_attention", "diff_cross"):  # heads carry no logical axis: tp is refused (`_diff_core`)
+        diff = {
+            ("wqkv" if mixer == "diff_attention" else "wq"): L + ("embed", None),
+            "wo": L + (None, "embed"),
+            **{name: L + (None,) for name in _LAMBDAS + ("subln",)},
+        }
+        if config.attn_bias:
+            diff["bqkv" if mixer == "diff_attention" else "bq"] = L + (None,)
+            diff["bo"] = L + (None,)
+        return {"diff": diff}
     return {"mla": {
         "wq": L + ("embed", "heads", "head_dim"),
         "w_kva": L + ("embed", None),
@@ -404,6 +585,10 @@ def _mixer_axes(config: TransformerConfig, mixer: str) -> Dict:
         "w_kvb": L + (None, "heads", "head_dim"),
         "wo": L + ("heads", "head_dim", "embed"),
     }}
+
+
+# Differential attention's four learned vectors of `head_dim`.
+_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
 
 
 def _is_axes(t) -> bool:
@@ -419,11 +604,16 @@ def param_axes(config: TransformerConfig) -> Dict:
         "w_down": L + ("mlp", "embed"),
     }
     axes = {"embed": {"tokens": ("vocab", "embed")}, "final_norm": (None,)}
+    biased = config.norm_kind == "layer"
+    if biased:
+        axes["final_norm_b"] = (None,)
     for name, (mixer, ffn, _) in config.stacks().items():
         mlp = dict(dense)
         if ffn == "experts":
             mlp = jax.tree_util.tree_map(lambda t: L + t, moe_param_axes(config), is_leaf=_is_axes)
         axes[name] = {**_mixer_axes(config, mixer), "mlp": mlp, "ln1": L + (None,), "ln2": L + (None,)}
+        if biased:
+            axes[name].update(ln1_b=L + (None,), ln2_b=L + (None,))
     if not config.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -498,6 +688,43 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
                 "norm": jnp.ones((n, dim), pd),
                 "wo": norm_init(next(k), (n, inner, c.d_model), out_scale),
             }}
+        if mixer == "s6":
+            # Mamba's own initial values (arXiv:2312.00752; `mamba_ssm`): A =
+            # -(1..N) in every channel, D = 1, a step dt = softplus(dt_bias)
+            # drawn log-uniform in [1e-3, 1e-1]; the convolution as Mamba-2's here.
+            inner, rank, state = c.s6_inner, c.dt_rank, c.s6_state
+            return {"s6": {
+                "in_proj": norm_init(next(k), (n, c.d_model, 2 * inner), proj_scale),
+                "conv_w": norm_init(next(k), (n, inner, c.s6_conv), c.s6_conv ** -0.5),
+                "conv_b": jnp.zeros((n, inner), pd),
+                "x_proj": norm_init(next(k), (n, inner, rank + 2 * state), inner ** -0.5),
+                "dt_proj": norm_init(next(k), (n, rank, inner), rank ** -0.5),
+                "dt_bias": inv_softplus(log_uniform(next(k), (n, inner), 1e-3, 1e-1)),
+                "A_log": jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, state + 1, dtype=jnp.float32)), (n, inner, state)).astype(pd),
+                "D": jnp.ones((n, inner), pd),
+                "out_proj": norm_init(next(k), (n, inner, c.d_model), out_scale),
+            }}
+        if mixer == "gmu":
+            return {"gmu": {
+                "w1": norm_init(next(k), (n, c.d_model, c.s6_inner), proj_scale),
+                "w2": norm_init(next(k), (n, c.s6_inner, c.d_model), out_scale),
+            }}
+        if mixer in ("diff_attention", "diff_cross"):
+            # The four lambda vectors normal * 0.1 (arXiv:2410.05258), the norm's scale 1, biases 0.
+            q_wide = c.n_heads * hd
+            first = "qkv" if mixer == "diff_attention" else "q"
+            wide = q_wide + (2 * c.n_kv_heads * hd if mixer == "diff_attention" else 0)
+            diff = {
+                "w" + first: norm_init(next(k), (n, c.d_model, wide), proj_scale),
+                "wo": norm_init(next(k), (n, q_wide, c.d_model), out_scale),
+                **{name: norm_init(next(k), (n, hd), 0.1) for name in _LAMBDAS},
+                "subln": jnp.ones((n, 2 * hd), pd),
+            }
+            if c.attn_bias:
+                diff["b" + first] = jnp.zeros((n, wide), pd)
+                diff["bo"] = jnp.zeros((n, c.d_model), pd)
+            return {"diff": diff}
         qk, rank = c.qk_nope_head_dim + c.qk_rope_head_dim, c.kv_lora_rank
         return {"mla": {
             "wq": norm_init(next(k), (n, c.d_model, c.n_heads, qk), proj_scale),
@@ -517,7 +744,10 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
                 "w_up": norm_init(next(k), (n, c.d_model, c.d_ff), proj_scale),
                 "w_down": norm_init(next(k), (n, c.d_ff, c.d_model), out_scale),
             }
-        return {**mixed, "mlp": mlp, "ln1": jnp.ones((n, c.d_model), pd), "ln2": jnp.ones((n, c.d_model), pd)}
+        norms = {"ln1": jnp.ones((n, c.d_model), pd), "ln2": jnp.ones((n, c.d_model), pd)}
+        if c.norm_kind == "layer":
+            norms.update(ln1_b=jnp.zeros((n, c.d_model), pd), ln2_b=jnp.zeros((n, c.d_model), pd))
+        return {**mixed, "mlp": mlp, **norms}
 
     # The embedding, the stack `layers` (attention with its one kind of FFN),
     # the head and the stack `mamba_layers` draw their keys from ONE sequence
@@ -532,6 +762,8 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
     if n:
         params["layers"] = first
     params["final_norm"] = jnp.ones((c.d_model,), pd)
+    if c.norm_kind == "layer":
+        params["final_norm_b"] = jnp.zeros((c.d_model,), pd)
     if not c.tie_embeddings:
         params["lm_head"] = norm_init(next(k), (c.d_model, c.vocab_size), emb_scale)
     for i, (name, (mixer, ffn, n)) in enumerate(stacks.items()):
@@ -546,6 +778,22 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float, axis=-1) -> jax.Array:
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=axis, keepdims=True)
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
+
+
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm over the last axis: statistics in float32, the scale and the
+    bias applied in x's dtype, as `rms_norm` applies its scale."""
+    xf = x.astype(jnp.float32)
+    centred = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+    return (centred * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype) + bias.astype(x.dtype)
+
+
+def _norm(config: TransformerConfig, x: jax.Array, params: Dict, name: str) -> jax.Array:
+    """The stream's norm called `name` in `params`, of the configured kind."""
+    if config.norm_kind == "layer":
+        return layer_norm(x, params[name], params[name + "_b"], config.norm_eps)
+    return rms_norm(x, params[name], config.norm_eps)
 
 
 def _fitting_axis(axis, mesh, dim: int) -> Optional[str]:
@@ -614,10 +862,12 @@ def _layer(
     rules: Optional[Rules],
     mesh=None,
     ffn: Optional[str] = None,
+    window: Optional[int] = None,
 ):
     """One attention layer: (x, this layer's router statistics; None when the
     FFN is dense).  `ffn` is the layer's kind of FFN (None: what the
-    configuration's every layer has)."""
+    configuration's every layer has); `window` its causal window (None: full
+    causal), which the sequence-parallel ring does not take."""
     c = config
     constrain = _constrainer(rules, mesh)
     dt = c.dtype
@@ -626,7 +876,7 @@ def _layer(
     # The scopes name each region in the compiled step's op metadata, which
     # is what a device trace can tell fusions apart by (PERF.md section 3).
     with jax.named_scope("layer/attn_proj"):
-        h = rms_norm(x, layer_params["ln1"], c.norm_eps)
+        h = _norm(c, x, layer_params, "ln1")
         q = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wq"].astype(dt))
         kk = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wk"].astype(dt))
         vv = jnp.einsum("bse,ehd->bshd", h, layer_params["attn"]["wv"].astype(dt))
@@ -660,6 +910,8 @@ def _layer(
 
             if c.attention_scale is not None:
                 raise ValueError("ring attention takes no attention_scale")
+            if window is not None:
+                raise ValueError("ring attention takes no window (layer_windows)")
             attn = ring_attention_sharded(
                 q, kk, vv, mesh,
                 seq_axis=ring_axis,
@@ -672,6 +924,7 @@ def _layer(
                 q, kk, vv, causal=True, scale=c.attention_scale, impl=c.attention_impl,
                 mesh=mesh if rules is not None else None,
                 batch_axes=batch_axes, head_axis=head_ax,
+                **({} if window is None else {"window": window}),
             )
     with jax.named_scope("layer/attn_proj"):
         attn_out = jnp.einsum("bshd,hde->bse", attn, layer_params["attn"]["wo"].astype(dt))
@@ -703,7 +956,7 @@ def _ffn_half(x, layer_params, config, constrain, rules, mesh, ffn=None):
     if ffn is None:
         ffn = "dense" if c.n_experts is None else "experts"
     with jax.named_scope("layer/mlp"):
-        h = rms_norm(x, layer_params["ln2"], c.norm_eps)
+        h = _norm(c, x, layer_params, "ln2")
         if ffn == "experts":
             down, router_stats = moe_ffn(layer_params["mlp"], h, c, rules=rules, mesh=mesh)
         else:
@@ -748,7 +1001,7 @@ def _mamba_layer(
     heads, inner, n = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_state
     with jax.named_scope("layer/attn_proj"):
         with jax.named_scope("ssm/proj"):
-            h = rms_norm(x, layer_params["ln1"], c.norm_eps)
+            h = _norm(c, x, layer_params, "ln1")
             zxbcdt = jnp.einsum("bse,ef->bsf", h, ssm["in_proj"].astype(dt))
             zxbcdt = checkpoint_name(zxbcdt, SSM_IN_PROJ)
             z, xbc, step = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], axis=-1)
@@ -813,7 +1066,7 @@ def _kda_layer(
     inner = heads * dim
     with jax.named_scope("layer/attn_proj"):
         with jax.named_scope("kda/proj"):
-            h = rms_norm(x, layer_params["ln1"], c.norm_eps)
+            h = _norm(c, x, layer_params, "ln1")
             qkv = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["wqkv"].astype(dt)), KDA_QKV)
             narrow = jnp.concatenate([p["f_down"], p["g_down"], p["w_beta"]], axis=-1).astype(dt)
             low = checkpoint_name(jnp.einsum("bse,ef->bsf", h, narrow), KDA_LOW)
@@ -861,7 +1114,7 @@ def _mla_layer(
     constrain = _constrainer(rules, mesh)
     rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
     with jax.named_scope("layer/attn_proj"), jax.named_scope("mla/proj"):
-        h = rms_norm(x, layer_params["ln1"], c.norm_eps)
+        h = _norm(c, x, layer_params, "ln1")
         q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(dt))
         latent = jnp.einsum("bse,ef->bsf", h, p["w_kva"].astype(dt))
         kv = jnp.einsum("bsr,rhd->bshd", rms_norm(latent[..., :rank], p["kv_norm"], c.norm_eps),
@@ -891,7 +1144,163 @@ def _mla_layer(
     return _ffn_half(x, layer_params, c, constrain, rules, mesh, ffn)
 
 
-_LAYER_FNS = {"attention": _layer, "mamba": _mamba_layer, "kda": _kda_layer, "mla": _mla_layer}
+def _s6_layer(x, layer_params, data, shared, *, positions, config, rules, mesh=None, ffn=None, window=None, emit=False):
+    """One Mamba-1 layer (module docstring): (x, router statistics or None,
+    what it hands on).  Its regions sit inside the two mixer scopes every
+    layer has: `s6/proj` (ln1, `W_in`, `W_x`, `W_dt` with the softplus, `W_out`,
+    the residual add), `s6/conv` (convolution + SiLU, on TPU Mamba-2's kernels;
+    the gate `y * silu(z)`), `s6/scan` (named in `ops/selective_scan.py`).
+    With `emit` the scan's output goes on as `MEMORY`.
+
+    `S6_IN_PROJ` (`W_in`'s one array) and `S6_MIXED` (the stream after
+    `W_out`) carry a `checkpoint_name`: with both kept no d-wide projection
+    runs again (`W_x`, `W_dt`, the convolution, the scan and the gate do)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    del positions, data, shared, window  # a recurrence needs none of them
+    c, dt, p = config, config.dtype, layer_params["s6"]
+    f32 = jnp.float32
+    constrain = _constrainer(rules, mesh)
+    sharded = {} if rules is None else dict(mesh=mesh, batch_axes=rules.get("act_batch"))
+    rank, n = c.dt_rank, c.s6_state
+    with jax.named_scope("layer/attn_proj"):
+        with jax.named_scope("s6/proj"):
+            h = _norm(c, x, layer_params, "ln1")
+            xz = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["in_proj"].astype(dt)), S6_IN_PROJ)
+            xs, z = jnp.split(xz, 2, axis=-1)
+        with jax.named_scope("s6/conv"):
+            xs = causal_conv1d_silu(xs, p["conv_w"], p["conv_b"], **sharded)
+        with jax.named_scope("s6/proj"):
+            low = jnp.einsum("bsf,fr->bsr", xs, p["x_proj"].astype(dt))
+            step = jnp.einsum("bsr,rf->bsf", low[..., :rank], p["dt_proj"].astype(dt), preferred_element_type=f32)
+            step = jax.nn.softplus(step + p["dt_bias"].astype(f32))
+    with jax.named_scope("layer/attn_core"):
+        y = selective_scan(xs, step, -jnp.exp(p["A_log"].astype(f32)), low[..., rank: rank + n],
+                           low[..., rank + n:], p["D"])
+    with jax.named_scope("layer/attn_proj"):
+        with jax.named_scope("s6/conv"):
+            gated = (y.astype(f32) * jax.nn.silu(z.astype(f32))).astype(dt)
+        with jax.named_scope("s6/proj"):
+            out = jnp.einsum("bsf,fe->bse", gated, p["out_proj"].astype(dt))
+            x = x + _scaled(c, constrain(out, ("act_batch", "act_seq", "act_embed")))
+            x = checkpoint_name(x, S6_MIXED)
+    return (*_ffn_half(x, layer_params, c, constrain, rules, mesh, ffn), {MEMORY: y} if emit else {})
+
+
+def _gmu_layer(x, layer_params, data, shared, *, positions, config, rules, mesh=None, ffn=None, window=None, emit=False):
+    """One Gated Memory Unit (module docstring): the memory an s6 layer
+    handed on, gated by this layer's own projection of the stream.  All of it
+    is `gmu` inside `layer/attn_proj` (the layer has no core).  `GMU_GATE`
+    (`W_1`'s output) and `GMU_MIXED` (the stream after `W_2`) carry a
+    `checkpoint_name`."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    del positions, data, window, emit
+    c, dt, p = config, config.dtype, layer_params["gmu"]
+    f32 = jnp.float32
+    constrain = _constrainer(rules, mesh)
+    with jax.named_scope("layer/attn_proj"), jax.named_scope("gmu"):
+        h = _norm(c, x, layer_params, "ln1")
+        gate = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["w1"].astype(dt)), GMU_GATE)
+        gated = (shared[MEMORY].astype(f32) * jax.nn.silu(gate.astype(f32))).astype(dt)
+        out = jnp.einsum("bsf,fe->bse", gated, p["w2"].astype(dt))
+        x = x + _scaled(c, constrain(out, ("act_batch", "act_seq", "act_embed")))
+        x = checkpoint_name(x, GMU_MIXED)
+    return (*_ffn_half(x, layer_params, c, constrain, rules, mesh, ffn), {})
+
+
+def diff_head_maps(n_heads: int, n_kv_heads: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Differential attention's pairing as two gathers, one entry per q head
+    i: the k head its map scores against, `2 * (i // 2 // G) + i % 2`, and
+    the PAIR of v heads (one value of twice the width) it averages,
+    `i // 2 // G`, with G = n_heads / n_kv_heads (module docstring)."""
+    i = np.arange(n_heads)
+    kv_pair = i // 2 // (n_heads // n_kv_heads)
+    return 2 * kv_pair + i % 2, kv_pair
+
+
+def _diff_core(q, k, v, p, lambda_init, config, rules, mesh, window):
+    """Both softmax maps of every head pair and their combination: q
+    [B, S, H, D], k and v [B, S, Hkv, D] -> [B, S, H * D] in the model's dtype.
+    One attention call over H maps with q/k heads of D and values of 2 * D
+    (the flash kernels' two head sizes), the heads gathered to their pairing
+    around it, under `diff/window` or `diff/full`; then `diff/combine`, in
+    float32 from the call's output: `a1 - lambda a2`, the RMSNorm over 2 * D,
+    the scale `1 - lambda_init`."""
+    c, f32 = config, jnp.float32
+    b, s, heads, hd = q.shape
+    if rules is not None and (_fitting_axis(rules.get("act_heads"), mesh, heads) is not None
+                              or _ring_axis(rules, mesh, q) is not None):
+        raise ValueError(
+            "differential attention ('diff_attention', 'diff_cross') runs with its heads and its "
+            "sequence whole: strategy 'tp' and the sequence-parallel ring do not take its pairing")
+    k_of, v_of = diff_head_maps(heads, k.shape[2])
+    with jax.named_scope("layer/attn_core"):
+        with jax.named_scope("diff/full" if window is None else "diff/window"):
+            keys = jnp.take(k, k_of, axis=2)
+            values = jnp.take(v.reshape(b, s, v.shape[2] // 2, 2 * hd), v_of, axis=2)
+            maps = dot_product_attention(
+                q, keys, values, causal=True, scale=hd ** -0.5, impl=c.attention_impl,
+                mesh=mesh if rules is not None else None,
+                batch_axes=None if rules is None else rules.get("act_batch"), head_axis=None,
+                **({} if window is None else {"window": window}),
+            )
+        with jax.named_scope("diff/combine"):
+            maps = maps.astype(f32).reshape(b, s, heads // 2, 2, 2 * hd)
+            lam = (jnp.exp(jnp.sum(p["lambda_q1"].astype(f32) * p["lambda_k1"].astype(f32)))
+                   - jnp.exp(jnp.sum(p["lambda_q2"].astype(f32) * p["lambda_k2"].astype(f32))) + lambda_init)
+            o = maps[..., 0, :] - lam * maps[..., 1, :]
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + c.norm_eps)
+            o = o * (p["subln"].astype(f32) * (1.0 - lambda_init))
+            return o.astype(c.dtype).reshape(b, s, heads * hd)
+
+
+def _diff_layer(x, layer_params, data, shared, *, positions, config, rules, mesh=None, ffn=None, window=None,
+                emit=False, cross=False):
+    """One differential attention layer, or with `cross` one differential
+    cross-attention layer (module docstring): (x, router statistics or None,
+    what it hands on).  `diff/proj` names its projections inside
+    `layer/attn_proj`; the core is `_diff_core`.  q (and a self layer's k and
+    v) carry attention's own `checkpoint_name`s, the stream after `W_o`
+    `DIFF_MIXED`.  With `emit` k and v (after the bias) go on as `SHARED_K`
+    and `SHARED_V`; a cross layer reads those."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    del positions  # no positional encoding
+    c, dt, p = config, config.dtype, layer_params["diff"]
+    constrain = _constrainer(rules, mesh)
+    hd, q_wide = c.head_dim, c.n_heads * c.head_dim
+    handed = {}
+    with jax.named_scope("layer/attn_proj"), jax.named_scope("diff/proj"):
+        h = _norm(c, x, layer_params, "ln1")
+        first = "q" if cross else "qkv"
+        proj = jnp.einsum("bse,ef->bsf", h, p["w" + first].astype(dt))
+        if c.attn_bias:
+            proj = proj + p["b" + first].astype(dt)
+        heads_of = lambda a: a.reshape(*a.shape[:2], a.shape[-1] // hd, hd)  # noqa: E731
+        q = checkpoint_name(heads_of(proj[..., :q_wide]), "q")
+        if cross:
+            kk, vv = shared[SHARED_K], shared[SHARED_V]
+        else:
+            kk, vv = (heads_of(a) for a in jnp.split(proj[..., q_wide:], 2, axis=-1))
+            kk, vv = checkpoint_name(kk, "k"), checkpoint_name(vv, "v")
+            if emit:
+                handed = {SHARED_K: kk, SHARED_V: vv}
+    o = _diff_core(q, kk, vv, p, data["lambda_init"], c, rules, mesh, window)
+    with jax.named_scope("layer/attn_proj"), jax.named_scope("diff/proj"):
+        out = jnp.einsum("bsf,fe->bse", o, p["wo"].astype(dt))
+        if c.attn_bias:
+            out = out + p["bo"].astype(dt)
+        x = x + _scaled(c, constrain(out, ("act_batch", "act_seq", "act_embed")))
+        x = checkpoint_name(x, DIFF_MIXED)
+    return (*_ffn_half(x, layer_params, c, constrain, rules, mesh, ffn), handed)
+
+
+_LAYER_FNS = {
+    "attention": _layer, "mamba": _mamba_layer, "kda": _kda_layer, "mla": _mla_layer,
+    "s6": _s6_layer, "diff_attention": _diff_layer, "gmu": _gmu_layer,
+    "diff_cross": functools.partial(_diff_layer, cross=True),
+}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -929,21 +1338,26 @@ def _remat_policy(config: TransformerConfig):
     if config.remat_policy == "attn":
         return jax.checkpoint_policies.save_only_these_names(ATTN_OUT, ATTN_LSE)
     if config.remat_policy == "qkv_attn":
-        # No mixer's input projection is recomputed: attention's q, k, v
-        # (latent attention's too); a Mamba-2 layer's two named residuals
-        # (`_mamba_layer`); a KDA layer's three (`_kda_layer`); the stream
-        # behind an MLA layer's output projection.
+        # No d-wide mixer projection is recomputed: attention's q, k, v
+        # (latent and differential attention's too, both maps of the latter
+        # in the one output and log-sum-exp); a Mamba-2 layer's two named
+        # residuals (`_mamba_layer`); a KDA layer's three (`_kda_layer`); an
+        # s6 layer's two (`_s6_layer`); a GMU's two (`_gmu_layer`); the stream
+        # behind an MLA or differential layer's output projection.
         return jax.checkpoint_policies.save_only_these_names(
             "q", "k", "v", ATTN_OUT, ATTN_LSE, SSM_IN_PROJ, SSM_MIXED,
             KDA_QKV, KDA_LOW, KDA_MIXED, MLA_MIXED,
+            S6_IN_PROJ, S6_MIXED, GMU_GATE, GMU_MIXED, DIFF_MIXED,
         )
     if config.remat_policy is None:
         # Save nothing per layer: the backward re-runs the whole layer, the
         # flash forward included.  The minimum-memory mode.
         return None
     raise ValueError(
-        f"unknown remat_policy {config.remat_policy!r}; "
-        "expected None, 'attn', or 'qkv_attn'"
+        f"unknown remat_policy {config.remat_policy!r}; expected None (save nothing), 'attn' "
+        f"(saves {ATTN_OUT!r}, {ATTN_LSE!r}) or 'qkv_attn' (those and 'q', 'k', 'v', "
+        f"{SSM_IN_PROJ!r}, {SSM_MIXED!r}, {KDA_QKV!r}, {KDA_LOW!r}, {KDA_MIXED!r}, {MLA_MIXED!r}, "
+        f"{S6_IN_PROJ!r}, {S6_MIXED!r}, {GMU_GATE!r}, {GMU_MIXED!r}, {DIFF_MIXED!r})"
     )
 
 
@@ -974,11 +1388,12 @@ def _run_layers_pipelined(
             "strategy 'pp' runs dense layers only: the router statistics of "
             "an expert layer do not come out of the pipeline schedule"
         )
-    if c.layer_types is not None or c.ffn_types is not None:
+    if c.layer_types is not None or c.ffn_types is not None or c.layer_windows is not None:
         raise ValueError(
             "strategy 'pp' runs a homogeneous stack of attention layers only: the "
-            "stages of a stack with layer_types (mamba, kda, mla) or ffn_types "
-            "(dense beside experts) would hold unequal layers"
+            "stages of a stack with layer_types (mamba, kda, mla, s6, diff_attention, "
+            "gmu, diff_cross) or ffn_types (dense beside experts) would hold unequal "
+            "layers, and a value one layer hands to a later one does not cross stages"
         )
     n_stages = mesh.shape[axis]
     per_stage = c.n_layers // n_stages
@@ -1124,7 +1539,7 @@ def trunk(
     with jax.named_scope("layers"):
         if pp_axis is not None:
             x = _run_layers_pipelined(
-                params["layers"], x, positions, c, mesh, pp_axis,
+                params.get("layers"), x, positions, c, mesh, pp_axis,  # None: a stack it refuses by name
                 rules=rules, fsdp_axis=pp_fsdp_axis,
             )
         else:
@@ -1138,13 +1553,33 @@ def trunk(
                 whole = len(bounds) == 1  # one run: the stack as it is, no slices to copy
                 stacks[mixer, ffn] = iter([params[name]] if whole else _split_runs(params[name], bounds))
             per_run = []
-            for mixer, ffn, _, _ in runs:
+            shared = {}  # what layers have handed on so far (`CROSS_KINDS`), by name
+            for (mixer, ffn, _, count), start in zip(runs, c.run_starts()):
+                window, emits = c.layer_variant(start)
+                static = {} if window is None else {"window": window}
+                if mixer in CROSS_KINDS:
+                    static["emit"] = emits
                 layer_fn = functools.partial(
-                    _LAYER_FNS[mixer], positions=positions, config=c, rules=rules, mesh=mesh, ffn=ffn
+                    _LAYER_FNS[mixer], positions=positions, config=c, rules=rules, mesh=mesh, ffn=ffn, **static
                 )
                 if c.remat:
                     layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
-                x, run_stats = jax.lax.scan(layer_fn, x, next(stacks[mixer, ffn]))
+                if mixer in CROSS_KINDS:
+                    # The layer takes its per-layer data and what was handed
+                    # on as ARGUMENTS (inputs of its checkpoint, constants of
+                    # the scan, their cotangents summed over the readers) and
+                    # returns what it hands on (a run that does is one layer:
+                    # `layer_variant`).
+                    def body(carry, xs, layer_fn=layer_fn, shared=dict(shared)):
+                        carry, stats, handed = layer_fn(carry, *xs, shared)
+                        return carry, (stats, handed)
+
+                    data = {"lambda_init": jnp.asarray(c.lambda_inits()[start: start + count], jnp.float32)}
+                    x, (run_stats, handed) = jax.lax.scan(body, x, (next(stacks[mixer, ffn]), data))
+                    if emits:
+                        shared.update({name: value[0] for name, value in handed.items()})
+                else:
+                    x, run_stats = jax.lax.scan(layer_fn, x, next(stacks[mixer, ffn]))
                 if run_stats is not None:
                     per_run.append(run_stats)
             # the expert layers' statistics, [expert layers, ...] in the stack's order
@@ -1153,7 +1588,7 @@ def trunk(
             elif per_run:
                 router_stats = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, axis=0), *per_run)
     with jax.named_scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        x = _norm(c, x, params, "final_norm")
     with jax.named_scope("lm_head"):
         head = (
             params["embed"]["tokens"].T if c.tie_embeddings else params["lm_head"]

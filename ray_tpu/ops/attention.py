@@ -9,6 +9,12 @@ attention (ops/ring_attention.py) are built from.
 Shapes follow [batch, seq, heads, head_dim] throughout.  GQA is expressed by
 n_kv_heads < n_heads; kv heads are repeated on the fly.  q and k share one
 head size; v, and with it the output, may have another.
+
+A `window` w (None = none; needs `causal`) keeps of the causal keys the last
+w, the query's own position counted: query i sees keys i - w + 1 .. i.  All
+three forms take it: `reference` and `blockwise` as a second term of their
+masks, the flash kernels as tiles never visited (ops/pallas/flash_attention.py).
+Ring attention does not (models/transformer.py refuses the pairing by name).
 """
 
 from __future__ import annotations
@@ -33,6 +39,15 @@ ATTN_OUT = "attn"
 ATTN_LSE = "attn_lse"
 
 
+def _seen(qpos: jax.Array, kpos: jax.Array, window: Optional[int]) -> jax.Array:
+    """bool [q, k]: the keys a causal query sees, the last `window` of them
+    (its own position counted) when there is one."""
+    seen = qpos[:, None] >= kpos[None, :]
+    if window is not None:
+        seen = seen & (qpos[:, None] - kpos[None, :] < window)
+    return seen
+
+
 def _repeat_kv(k: jax.Array, n_heads: int) -> jax.Array:
     """[B, S, Hkv, D] -> [B, S, H, D] by repeating groups (GQA)."""
     n_kv = k.shape[2]
@@ -50,6 +65,7 @@ def reference_attention(
     causal: bool = True,
     scale: Optional[float] = None,
     q_offset: int = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """O(S^2) materialized-scores attention. Ground truth for tests.
 
@@ -62,9 +78,7 @@ def reference_attention(
     logits = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
     if causal:
         sk = k.shape[1]
-        qpos = jnp.arange(sq)[:, None] + q_offset
-        kpos = jnp.arange(sk)[None, :]
-        logits = jnp.where(qpos >= kpos, logits, NEG_INF)
+        logits = jnp.where(_seen(jnp.arange(sq) + q_offset, jnp.arange(sk), window), logits, NEG_INF)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
@@ -78,6 +92,7 @@ def blockwise_attention(
     scale: Optional[float] = None,
     block_size: int = 512,
     q_offset: int = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash-attention semantics in pure JAX: scan over KV blocks with an
     online softmax, never materializing the [S, S] score matrix.  XLA keeps
@@ -108,8 +123,7 @@ def blockwise_attention(
         kb, vb, kpos = blk
         logits = jnp.einsum("bqhd,bkhd->bhqk", qf, kb)
         if causal:
-            mask = qpos[:, None] >= kpos[None, :]
-            logits = jnp.where(mask[None, None], logits, NEG_INF)
+            logits = jnp.where(_seen(qpos, kpos, window)[None, None], logits, NEG_INF)
         m_blk = jnp.max(logits, axis=-1)
         m_new = jnp.maximum(m, m_blk)
         # Guard: a fully-masked row has logits == m_new == NEG_INF; exp(0)=1
@@ -143,6 +157,7 @@ def dot_product_attention(
     mesh=None,
     batch_axes=None,
     head_axis: Optional[str] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Dispatching attention entry point used by models/.
 
@@ -154,18 +169,24 @@ def dot_product_attention(
     The choice rides `jax.lax.platform_dependent`, so it follows the
     platform a step is compiled for, not the process's default backend.
 
+    window: see the module docstring; every form takes it.
+
     mesh / batch_axes / head_axis say how q, k, v are sharded.  GSPMD
     partitions the XLA forms by itself; a Mosaic kernel it cannot, so with
     a mesh the kernel runs under shard_map over those axes.
     """
 
+    if window is not None and not causal:
+        raise ValueError("a window needs causal=True")
+    windowed = {} if window is None else {"window": window}
+
     def reference(q, k, v):
-        out = reference_attention(q, k, v, causal=causal, scale=scale)
+        out = reference_attention(q, k, v, causal=causal, scale=scale, **windowed)
         return checkpoint_name(out, ATTN_OUT)
 
     def blockwise(q, k, v):
         out = blockwise_attention(
-            q, k, v, causal=causal, scale=scale, block_size=block_size
+            q, k, v, causal=causal, scale=scale, block_size=block_size, **windowed
         )
         return checkpoint_name(out, ATTN_OUT)
 
@@ -173,10 +194,10 @@ def dot_product_attention(
         from ray_tpu.ops.pallas import flash_attention as fa
 
         if mesh is None:
-            return fa.flash_attention(q, k, v, causal=causal, scale=scale)
+            return fa.flash_attention(q, k, v, causal=causal, scale=scale, **windowed)
         return fa.flash_attention_sharded(
             q, k, v, mesh, batch_axes=batch_axes, head_axis=head_axis,
-            causal=causal, scale=scale,
+            causal=causal, scale=scale, **windowed,
         )
 
     if impl is None:

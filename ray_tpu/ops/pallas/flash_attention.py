@@ -16,6 +16,15 @@ materialization, O(S) memory):
 
 Causal masking skips fully-masked tile pairs via pl.when predication.
 
+A `window` w (static; None = none) keeps of the causal keys the last w, the
+query's own position counted: query i sees keys i - w + 1 .. i.  A windowed
+call's grids do not span the other sequence: the innermost dimension counts
+only the tiles a tile of the outer one can see (`_visible`), its index maps
+start at the first of them, the two boundary tiles are masked inside and a
+step past the last visible tile is predicated off, in all three kernels.  At
+`window=None` nothing of this is traced: grids, index maps and kernel bodies
+are the ones they were.
+
 Which form of a kernel runs is decided by the platform the enclosing program
 is LOWERED for (`jax.lax.platform_dependent`), never by the process-global
 default backend: a TPU lowering gets the Mosaic kernel, a CPU lowering gets
@@ -65,12 +74,69 @@ def _pallas_call(kernel, *, name: str, **kwargs):
     return call
 
 
+# -- windows ---------------------------------------------------------------
+
+
+def _first_visible(i, own: int, other: int, window: int, *, keys: bool):
+    """First tile of the OTHER sequence that tile `i` (of `own` positions)
+    can see under a causal window: with `keys`, i is a query tile and the
+    answer a key tile (of `other` positions); without, the reverse.  `i` may
+    be a traced grid index or a Python int."""
+    if keys:  # the first query's oldest key, i * own - (window - 1), clamped at 0
+        start = i * own - (window - 1)
+        return (jnp.maximum(start, 0) if isinstance(start, jax.Array) else max(start, 0)) // other
+    return (i * own) // other  # the first key's own position is the first query that sees it
+
+
+def _visible(n_own: int, n_other: int, own: int, other: int, window: int, *, keys: bool) -> list:
+    """How many tiles of the other sequence each tile sees; the most of them
+    is the innermost grid dimension of a windowed call."""
+    counts = []
+    for i in range(n_own):
+        first = _first_visible(i, own, other, window, keys=keys)
+        # the last query's own position / the last key's newest query
+        last_pos = (i + 1) * own - 1 if keys else (i + 1) * own - 1 + window - 1
+        counts.append(min(last_pos // other, n_other - 1) - first + 1)
+    return counts
+
+
+def _window_mask(logits, qpos, kpos, window):
+    seen = qpos >= kpos
+    if window is not None:
+        seen = seen & (qpos - kpos < window)
+    return jnp.where(seen, logits, NEG_INF)
+
+
+def window_tiles_visited_pct(seq: int, window: int) -> Optional[float]:
+    """Area of the key tiles the windowed FORWARD kernel visits, as % of what
+    the causal call visits at ITS tiles, from the block sizes in use
+    (`flash_attention`'s defaults and `_window_blocks`); None at a length no
+    tile divides (the kernels do not run there)."""
+    full = _fit_block(seq, 1024)
+    if full is None:
+        return None
+    causal = full * full * sum(qi + 1 for qi in range(seq // full))
+    bq, bk, _, _ = (_fit_block(seq, b) for b in _window_blocks(window, (1024, 1024, 1024, 512)))
+    visited = sum(_visible(seq // bq, seq // bk, bq, bk, window, keys=True))
+    return 100.0 * visited * bq * bk / causal
+
+
+def _window_blocks(window: int, blocks):
+    """A windowed call's tiles: none larger than the window (rounded down to
+    128 lanes, at least 128).  At 1024 x 1024 a window of 512 visits two key
+    tiles a query tile, 2,048 keys a query where 512 are needed; at 512 x 512
+    two tiles are 1,024.  Smaller tiles waste less and feed the MXU less
+    (PERF.md section 7)."""
+    cap = max(128, window // 128 * 128)
+    return tuple(min(b, cap) for b in blocks)
+
+
 # -- forward ---------------------------------------------------------------
 
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, scale: float, causal: bool,
+    *, scale: float, causal: bool, window: Optional[int] = None,
 ):
     # Blocks: q [1, 1, bq, D]; k [1, 1, bk, D]; v [1, 1, bk, Dv]; o [1, 1, bq, Dv];
     # lse [1, 1, bq, 1].  Scratch (carried across the kv grid dim): acc [bq, Dv] f32,
@@ -82,6 +148,8 @@ def _fwd_kernel(
     bk = k_ref.shape[2]
     q_start = qi * bq
     k_start = ki * bk
+    if window is not None:  # the grid counts from the first visible key tile
+        k_start = (_first_visible(qi, bq, bk, window, keys=True) + ki) * bk
 
     @pl.when(ki == 0)
     def _init():
@@ -102,7 +170,7 @@ def _fwd_kernel(
         if causal:
             qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            logits = jnp.where(qpos >= kpos, logits, NEG_INF)
+            logits = _window_mask(logits, qpos, kpos, window)
         m_prev = m_ref[:, :1]  # [bq, 1]
         l_prev = l_ref[:, :1]
         m_blk = jnp.max(logits, axis=-1, keepdims=True)  # [bq, 1]
@@ -124,7 +192,20 @@ def _fwd_kernel(
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l_safe)
 
 
-def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k):
+def _inner_tile(n_own, n_other, own, other, window, *, keys):
+    """(innermost grid extent, index of the other sequence's tile at grid
+    step (i, j)) of a call whose outer tiles are `own` wide: every tile and
+    the step itself without a window; with one, the visible tiles alone,
+    counted from the first, the index held inside the sequence (a step past
+    the last visible tile is predicated off in the kernel)."""
+    if window is None:
+        return n_other, lambda i, j: j
+    first = functools.partial(_first_visible, own=own, other=other, window=window, keys=keys)
+    return (max(_visible(n_own, n_other, own, other, window, keys=keys)),
+            lambda i, j: jnp.minimum(first(i) + j, n_other - 1))
+
+
+def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[-1]  # q and k share one head size, v and the output another
     # Kernels work in [B, H, S, D].
@@ -133,16 +214,17 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k):
     vt = v.transpose(0, 2, 1, 3)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    grid = (b, h, sq // block_q, sk // block_k)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal)
+    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True)
+    grid = (b, h, sq // block_q, n_k)
+    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, window=window)
     out, lse = _pallas_call(
         kernel,
         name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, k_tile(qi, ki), 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, k_tile(qi, ki), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -166,7 +248,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k):
 
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
-    *, scale: float, causal: bool,
+    *, scale: float, causal: bool, window: Optional[int] = None,
 ):
     # q/dq [1, 1, bq, D]; k [1, 1, bk, D]; v [1, 1, bk, Dv]; do [1, 1, bq, Dv];
     # lse/delta [1, 1, bq, 1].
@@ -177,6 +259,8 @@ def _bwd_dq_kernel(
     bk = k_ref.shape[2]
     q_start = qi * bq
     k_start = ki * bk
+    if window is not None:  # as the forward: counted from the first visible key tile
+        k_start = (_first_visible(qi, bq, bk, window, keys=True) + ki) * bk
 
     @pl.when(ki == 0)
     def _init():
@@ -198,7 +282,7 @@ def _bwd_dq_kernel(
         if causal:
             qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            logits = jnp.where(qpos >= kpos, logits, NEG_INF)
+            logits = _window_mask(logits, qpos, kpos, window)
         p = jnp.exp(logits - lse)  # masked -> exp(-inf) = 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -216,7 +300,7 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref,
-    *, scale: float, causal: bool,
+    *, scale: float, causal: bool, window: Optional[int] = None, q_tiles: Optional[int] = None,
 ):
     # Grid (b, h, kv_tile, q_tile) — q innermost so k/v blocks stay resident.
     # k/dk [1, 1, bk, D]; v/dv [1, 1, bk, Dv]; q [1, 1, bq, D]; do [1, 1, bq, Dv];
@@ -228,6 +312,8 @@ def _bwd_dkv_kernel(
     bq = q_ref.shape[2]
     k_start = ki * bk
     q_start = qi * bq
+    if window is not None:  # counted from the first query tile that sees this key tile
+        q_start = (_first_visible(ki, bk, bq, window, keys=False) + qi) * bq
 
     @pl.when(qi == 0)
     def _init():
@@ -235,6 +321,8 @@ def _bwd_dkv_kernel(
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
     run = (q_start + bq - 1 >= k_start) if causal else True
+    if window is not None:  # neither past the newest query of the window nor past the sequence
+        run = run & (q_start <= k_start + bk - 1 + window - 1) & (q_start < q_tiles * bq)
 
     @pl.when(run)
     def _step():
@@ -250,7 +338,7 @@ def _bwd_dkv_kernel(
         if causal:
             qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            logits = jnp.where(qpos >= kpos, logits, NEG_INF)
+            logits = _window_mask(logits, qpos, kpos, window)
         p = jnp.exp(logits - lse)
         dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -270,11 +358,13 @@ def _bwd_dkv_kernel(
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
+def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=None):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
+    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True)
+    n_q, q_tile = _inner_tile(sk // block_k, sq // block_q, block_k, block_q, window, keys=False)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -284,15 +374,15 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     ).transpose(0, 2, 1)[..., None]  # [B, H, Sq, 1]
 
-    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal)
+    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, window=window)
     dq = _pallas_call(
         dq_kernel,
         name="flash_bwd_dq",
-        grid=(b, h, sq // block_q, sk // block_k),
+        grid=(b, h, sq // block_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, k_tile(qi, ki), 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, k_tile(qi, ki), 0)),
             pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -304,18 +394,19 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
     )(qt, kt, vt, dot, lse, delta)
 
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal)
+    dkv_kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, causal=causal, window=window, q_tiles=sq // block_q)
     dk, dv = _pallas_call(
         dkv_kernel,
         name="flash_bwd_dkv",
-        grid=(b, h, sk // block_k, sq // block_q),
+        grid=(b, h, sk // block_k, n_q),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, q_tile(ki, qi), 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
             pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, ki, qi: (bi, hi, q_tile(ki, qi), 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, q_tile(ki, qi), 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, q_tile(ki, qi), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
@@ -340,17 +431,17 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k):
 # -- custom_vjp wiring -----------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window):
     out, _ = _flash_fwd(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k
+        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k, window=window
     )
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k):
+def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window):
     out, lse = _flash_fwd(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k
+        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k, window=window
     )
     # Named so that a remat policy can keep them (ops/attention.py); any
     # other policy runs this forward again in the backward pass.  The
@@ -361,11 +452,11 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_bl
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, res, g):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window, res, g):
     q, k, v, out, lse = res
     return _flash_bwd(
         q, k, v, out, lse[..., None], g,
-        causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k,
+        causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k, window=window,
     )
 
 
@@ -383,6 +474,7 @@ def flash_attention(
     block_k: int = 1024,
     bwd_block_q: int = 1024,
     bwd_block_k: int = 512,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention, [B, S, H, D] layout, GQA via repeated kv heads.  q and
     k share one head size, v and the output may have another (latent
@@ -391,7 +483,18 @@ def flash_attention(
 
     Forward tiles default larger than backward: the bwd kernels hold four
     [bq, bk] f32 intermediates (logits/p/dp/ds) at once, so 1024x1024 there
-    would exceed the ~16MB VMEM scoped budget."""
+    would exceed the ~16MB VMEM scoped budget.
+
+    `window` (static; needs `causal` and equal sequence lengths): query i
+    sees keys i - window + 1 .. i, and a tile no query of the call sees is
+    never visited (module docstring).  A windowed call gets tiles no larger
+    than its window (`_window_blocks`): 512 x 512 in all three kernels at a
+    window of 512, two key tiles a query tile."""
+    if window is not None:
+        if not causal or q.shape[1] != k.shape[1] or window < 1:
+            raise ValueError("flash_attention: a window needs causal=True, equal sequence lengths and window >= 1")
+        block_q, block_k, bwd_block_q, bwd_block_k = _window_blocks(
+            window, (block_q, block_k, bwd_block_q, bwd_block_k))
     h = q.shape[2]
     if k.shape[2] != h:
         k = _repeat_kv(k, h)
@@ -414,7 +517,7 @@ def flash_attention(
             f"k={k.shape[1]} (requested blocks {block_q}/{block_k}, bwd "
             f"{bwd_block_q}/{bwd_block_k})"
         )
-    return _flash(q, k, v, causal, scale, *blocks)
+    return _flash(q, k, v, causal, scale, *blocks, window)
 
 
 def flash_attention_sharded(
@@ -427,6 +530,7 @@ def flash_attention_sharded(
     head_axis: Optional[str] = "tensor",
     causal: bool = True,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """flash_attention on global [B, S, H, D] arrays sharded over a mesh.
 
@@ -439,7 +543,7 @@ def flash_attention_sharded(
 
     spec = P(batch_axes, None, head_axis, None)
     qspec, kspec = _fit_spec(q.shape, spec, mesh), _fit_spec(k.shape, spec, mesh)
-    body = functools.partial(flash_attention, causal=causal, scale=scale)
+    body = functools.partial(flash_attention, causal=causal, scale=scale, window=window)
     return jax.shard_map(
         body,
         mesh=mesh,
@@ -451,7 +555,9 @@ def flash_attention_sharded(
 
 def _fit_block(s: int, requested: int) -> Optional[int]:
     """Tile size that divides s: the request itself if it divides, else the
-    largest 128-multiple <= requested that does; None if neither exists."""
+    largest 128-multiple <= requested that does; None if neither exists.  A
+    windowed call's request is already cut to its window (`_window_blocks`),
+    so that a tile outside the window is one the grid can leave out."""
     requested = min(requested, s)
     if s % requested == 0:
         return requested
