@@ -1,13 +1,16 @@
 """Kimi Delta Attention (KDA, "Kimi Linear", arXiv:2510.26692): a gated delta
 rule whose decay is PER CHANNEL of the key, in its chunked form; no
 counterpart in the reference (SURVEY.md §5.7).  `kda_chunked` is one
-`jax.custom_vjp`.  Its FORWARD is plain `jax.numpy` (`_plain_forward`, a scan
-over `_segment`) everywhere but on TPU, where at the shapes it takes a Pallas
-kernel keeps a chunk's matrices in VMEM (`ops/pallas/kda.py`, PR 39; the same
-arithmetic at the same precision, see Precision below).  Its BACKWARD is
-plain everywhere: JAX's own differentiation of `_segment`, a segment at a
-time (`_kda_bwd`); no gradient here is derived by hand.  `kda/scan`, the scope
-around all of this, is what the benchmark reads it by (PERF.md section 3).
+`jax.custom_vjp`.  Both directions are plain `jax.numpy` everywhere but on
+TPU: the forward `_plain_forward`, a scan over `_segment`; the backward
+`_plain_backward`, JAX's own differentiation of `_segment`, a segment at a
+time (no gradient in THIS file is derived by hand).  For TPU, at the shapes
+they take, two Pallas kernels keep a chunk's matrices in VMEM
+(`ops/pallas/kda.py`: `kda_fwd`, PR 39, the same arithmetic at the same
+precision; `kda_bwd`, PR 41, the cotangents of that arithmetic derived by
+hand, at the same precision, and held to `_plain_backward` by the tests; see
+Precision below).  `kda/scan`, the scope around all of this, is what the
+benchmark reads it by (PERF.md section 3).
 
 The recurrence, per batch row and per head, with a state `S_t` of shape
 [K, V] (key size x value size), a log decay `g_t <= 0` per key channel
@@ -40,9 +43,15 @@ JAX's backward holds one cotangent of that size per level and operand of
 `_decayed_lower` until they are summed: 4.6 GB in the compiled step), so a
 segment's temporaries exist while that segment runs.  The forward keeps the
 state that ENTERS each segment (8 x [b, 32, 128, 128] float32, 16.8 MB a
-layer: the only residual beside the arguments), and the backward walks the
-segments from the last to the first: `jax.vjp` of `_segment` at that state,
-its forward once more and then its backward, the state's cotangent carried.
+layer: the only residual beside the arguments), and the plain backward walks
+the segments from the last to the first: `jax.vjp` of `_segment` at that
+state, its forward once more and then its backward, the state's cotangent
+carried.  The backward kernel walks the same way 128 positions at a time and
+recomputes a pair of chunks from the state that entered it, so where the
+forward runs for a backward (`_kda_fwd`) the kernel also writes the state that
+enters each PAIR of chunks (128 x [b, 32, 128, 128] float32, 268 MB a layer,
+alive from the layer's recompute to the end of its backward); the plain form
+has no use for them and gives zeros in their place.
 
 A decay is always the exponential of a DIFFERENCE of running sums that is
 <= 0, never a quotient of exponentials: `exp(G_t) / exp(G_s)` is 0/0 once G
@@ -68,11 +77,14 @@ averages its values' roundings away: with bf16 operands the benchmark's
 five-layer model read 1.9-2.5% of relative RMS error against the float32
 reference where the comparison allows 2.68% (22 readings), with these 1.6-2.0%
 (6 readings), for 0.19 s of a 1.64 s step; six passes read the same and cost
-0.26 s (my chip runs, PR 37; PERF.md section 6).  The kernel holds the same
-contract by hand: float32 operands, each product three bf16 passes with
-float32 accumulation, every exponent <= 0; on the chip its o is 1.6e-5 from
-this code's and as far from `kda_recurrent` as this code's is (4.4e-5 against
-4.2e-5; my chip runs, PR 39).
+0.26 s (my chip runs, PR 37; PERF.md section 6).  The kernels hold the same
+contract by hand, in both directions: float32 operands, each product three
+bf16 passes with float32 accumulation, every exponent <= 0; on the chip the
+forward's o is 1.6e-5 from this code's and as far from `kda_recurrent` as this
+code's is (4.4e-5 against 4.2e-5; my chip runs, PR 39), the backward's five
+cotangents 2.1e-5 to 2.5e-5 from this code's and within a twentieth of as far
+from the recurrence's gradient (dq 6.7e-5 against 6.4e-5, dg 1.58e-4 against
+1.54e-4; my chip runs, PR 41).
 
 Sharding: nothing here names a mesh axis; batch sharding is GSPMD's to
 propagate through the einsums.
@@ -229,19 +241,33 @@ def _kernel_takes(k, v, chunk: int) -> bool:
     return _kernels().supported(k.shape[-1], v.shape[-1], chunk, _per_segment(k.shape[1], chunk))
 
 
-def _forward(q, k, v, g, beta, chunk: int):
-    """(o [b, S, H, V] float32, the state that enters each segment).  Like
-    attention and the convolution, the form follows the platform a step is
-    LOWERED for, not the process's backend: the kernel for TPU at shapes it
-    takes, the plain form everywhere else."""
+def _forward(q, k, v, g, beta, chunk: int, pair_states: bool = False):
+    """(o [b, S, H, V] float32, the state that enters each segment, and with
+    `pair_states` at shapes the kernel takes the state that enters each PAIR
+    of chunks [segments, b, c / 2, H, K, V], which the backward kernel starts
+    from; else None).  Like attention and the convolution, the form follows the
+    platform a step is LOWERED for, not the process's backend: the kernel for
+    TPU at shapes it takes, the plain form everywhere else (its backward has no
+    use for the pairs' states: zeros of their shape, because both branches of a
+    dispatch return the same shapes)."""
     segments = functools.partial(_segments, chunk=chunk, per_segment=_per_segment(k.shape[1], chunk))
-    plain = lambda q, k, v, g, beta: _plain_forward(q, k, v, g, segments(beta[..., None]))
+    takes = _kernel_takes(k, v, chunk)
+    pair_states = pair_states and takes
+
+    def plain(q, k, v, g, beta):
+        o, entering = _plain_forward(q, k, v, g, segments(beta[..., None]))
+        if not pair_states:
+            return o, entering
+        n, b, c = k.shape[:3]
+        return o, entering, jnp.zeros((n, b, c // 2, *entering.shape[2:]), jnp.float32)
+
     inputs = (*map(segments, (q, k, v, g)), beta)
-    if _kernel_takes(k, v, chunk):
-        o, entering = jax.lax.platform_dependent(*inputs, tpu=_kernels().kda_fwd, default=plain)
+    if takes:
+        kernel = functools.partial(_kernels().kda_fwd, pair_states=pair_states)
+        o, entering, *pairs = jax.lax.platform_dependent(*inputs, tpu=kernel, default=plain)
     else:
-        o, entering = plain(*inputs)
-    return _positions(o), entering
+        o, entering, *pairs = plain(*inputs)
+    return _positions(o), entering, (pairs[0] if pairs else None)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -250,19 +276,17 @@ def _kda(q, k, v, g, beta, chunk: int):
 
 
 def _kda_fwd(q, k, v, g, beta, chunk: int):
-    o, entering = _forward(q, k, v, g, beta, chunk)
-    return o, (q, k, v, g, beta, entering)
+    o, entering, pairs = _forward(q, k, v, g, beta, chunk, pair_states=True)
+    return o, (q, k, v, g, beta, entering, pairs)
 
 
-def _kda_bwd(chunk: int, res, do):
+def _plain_backward(q, k, v, g, beta, entering, d_o):
     """JAX's own differentiation of `_segment`, a segment at a time from the
     last to the first: the segment's forward once more at the state that
     entered it, then its backward, the state's cotangent carried along.  The
     [chunk, chunk] matrices, their decayed operands and every cotangent of them
-    exist for one segment at a time."""
-    q, k, v, g, beta, entering = res
-    per_segment = _per_segment(k.shape[1], chunk)
-    inputs = tuple(_segments(x, chunk, per_segment) for x in (q, k, v, g, beta[..., None]))
+    exist for one segment at a time.  Arguments and cotangents as `_segments`
+    gives them (beta [segments, b, c, H, l, 1])."""
 
     def step(d_state, xs):
         state, inp, d_o = xs
@@ -273,10 +297,30 @@ def _kda_bwd(chunk: int, res, do):
         d_state, d_inp = pull((d_state, d_o))
         return d_state, d_inp
 
-    d_o = _segments(do, chunk, per_segment)
-    _, d_inputs = jax.lax.scan(step, jnp.zeros_like(entering[0]), (entering, inputs, d_o), reverse=True)
-    dq, dk, dv, dg, dbeta = map(_positions, d_inputs)  # each in its argument's dtype: `_segment` casts inside
-    return dq, dk, dv, dg, dbeta[..., 0]
+    return jax.lax.scan(step, jnp.zeros_like(entering[0]), (entering, (q, k, v, g, beta), d_o), reverse=True)[1]
+
+
+def _kda_bwd(chunk: int, res, do):
+    """(dq, dk, dv, dg, dbeta), each in its argument's dtype.  For TPU at the
+    shapes the kernel takes, `kda_bwd` from the state that entered each pair of
+    chunks; everywhere else `_plain_backward` from the state that entered each
+    segment.  Both read the arrays the forward read, as `_segments` gives them."""
+    q, k, v, g, beta, entering, pairs = res
+    segments = functools.partial(_segments, chunk=chunk, per_segment=_per_segment(k.shape[1], chunk))
+
+    def plain(q, k, v, g, beta, entering, pairs, d_o):
+        *d_inputs, dbeta = _plain_backward(q, k, v, g, segments(beta[..., None]), entering, d_o)
+        return (*d_inputs, _positions(dbeta)[..., 0])  # each in its argument's dtype: `_segment` casts inside
+
+    def kernel(q, k, v, g, beta, entering, pairs, d_o):
+        return _kernels().kda_bwd(q, k, v, g, beta, pairs, d_o)
+
+    inputs = (*map(segments, (q, k, v, g)), beta, entering, pairs, segments(do))
+    if pairs is None:
+        *d_inputs, dbeta = plain(*inputs)
+    else:
+        *d_inputs, dbeta = jax.lax.platform_dependent(*inputs, tpu=kernel, default=plain)
+    return (*map(_positions, d_inputs), dbeta)
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)

@@ -136,3 +136,31 @@ def test_the_kda_forward_kernel_compiles_at_the_kimi_cells_shapes(topo):
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 and "kda_fwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20  # its operands as they come: no relayout copy
+
+
+def test_the_kda_backward_kernel_compiles_at_the_kimi_cells_shapes(topo):
+    """The same of `kda_bwd` (PR 41), v and its cotangent in bf16 as the layer
+    has them, from the states the forward writes with `pair_states` (which
+    compiles as one call too): four pairs' levels kept in VMEM from their
+    forward to their backward, nine float32 transposes a pair."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.pallas import kda as kernels
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    blocks = jax.ShapeDtypeStruct((8, 1, 32, 32, 64, 128), jnp.float32, sharding=one_chip)
+    values = jax.ShapeDtypeStruct(blocks.shape, jnp.bfloat16, sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((1, 16384, 32), jnp.float32, sharding=one_chip)
+    pairs = jax.ShapeDtypeStruct((8, 1, 16, 32, 128, 128), jnp.float32, sharding=one_chip)
+    with _no_compile_cache():
+        backward = jax.jit(kernels.kda_bwd).lower(blocks, blocks, values, blocks, beta, pairs, blocks).compile()
+        with_pairs = jax.jit(lambda *a: kernels.kda_fwd(*a, pair_states=True))
+        forward = with_pairs.lower(blocks, blocks, values, blocks, beta).compile()
+    for compiled, name in ((backward, "kda_bwd"), (forward, "kda_fwd")):
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 and name in text
+        # no relayout copy of an operand (268 MB each); the backward's dbeta leaves as rows, 2 MB turned once
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
+    got = [(s.shape, s.dtype) for s in jax.tree.leaves(backward.out_info)]
+    like = lambda x: (x.shape, x.dtype)
+    assert got == [like(blocks), like(blocks), like(values), like(blocks), ((1, 16384, 32), jnp.float32)]

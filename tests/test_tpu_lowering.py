@@ -145,18 +145,20 @@ KIMI = TransformerConfig.tiny(
     [(1, MeshSpec(data=1), "dp"), (4, MeshSpec(data=1, fsdp=4), "fsdp"), (4, MeshSpec(data=2, tensor=2), "tp")],
     ids=["dp1", "fsdp4", "tp4"],
 )
-def test_kimi_step_lowers_for_tpu_with_the_kda_forward_kernel_inside_kda_scan(n_devices, spec, strategy):
+def test_kimi_step_lowers_for_tpu_with_the_kda_kernels_inside_kda_scan(n_devices, spec, strategy):
     """The KDA run is one scan body: the recurrence's forward kernel twice
     (forward, and the recompute: `qkv_attn` keeps the projections, not the
-    recurrence's output) and NO backward kernel (the backward is JAX's own, of
-    the plain segment); under shard_map on a mesh like the others."""
+    recurrence's output) and its backward kernel once (PR 41; before it the
+    backward was JAX's own, of the plain segment, on TPU too); under shard_map
+    on a mesh like the others, and each under `kda/scan` and its own name."""
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=KIMI, debug_info=True)
     kernels = _mosaic_kernels(text)
-    assert kernels["kda_fwd"] == 2 and kernels["flash_fwd"] == 1, kernels
-    assert not [name for name in kernels if name.startswith("kda_") and name != "kda_fwd"]
+    assert kernels["kda_fwd"] == 2 and kernels["kda_bwd"] == 1 and kernels["flash_fwd"] == 1, kernels
+    assert not [name for name in kernels if name.startswith("kda_") and name not in ("kda_fwd", "kda_bwd")]
     locs = dict(re.findall(r'^(#loc\d+) = loc\((.*)\)$', text, flags=re.M))
     for line in text.splitlines():
-        if "@tpu_custom_call" in line and 'kernel_name = "kda_fwd"' in line:
+        kernel = re.search(r'kernel_name = "(kda_fwd|kda_bwd)"', line)
+        if "@tpu_custom_call" in line and kernel:
             loc = re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)
             seen, path = set(), ""
             while loc and loc not in seen:  # a location names its parents by reference
@@ -164,7 +166,9 @@ def test_kimi_step_lowers_for_tpu_with_the_kda_forward_kernel_inside_kda_scan(n_
                 path += locs.get(loc, "")
                 nxt = re.search(r"#loc\d+", locs.get(loc, ""))
                 loc = nxt.group(0) if nxt else None
-            assert "kda/scan" in path and "kda_fwd" in path, path
+            assert "kda/scan" in path and kernel.group(1) in path, path
+            if kernel.group(1) == "kda_bwd":  # in the layer's backward, not its recompute: the reader's `bwd`
+                assert "rematted_computation" not in path, path
 
 
 def test_kimi_step_lowered_for_the_cpu_holds_no_kernel():
