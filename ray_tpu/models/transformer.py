@@ -1176,7 +1176,7 @@ def _s6_layer(x, layer_params, data, shared, *, positions, config, rules, mesh=N
             step = jax.nn.softplus(step + p["dt_bias"].astype(f32))
     with jax.named_scope("layer/attn_core"):
         y = selective_scan(xs, step, -jnp.exp(p["A_log"].astype(f32)), low[..., rank: rank + n],
-                           low[..., rank + n:], p["D"])
+                           low[..., rank + n:], p["D"], **sharded)
     with jax.named_scope("layer/attn_proj"):
         with jax.named_scope("s6/conv"):
             gated = (y.astype(f32) * jax.nn.silu(z.astype(f32))).astype(dt)

@@ -1,7 +1,14 @@
 """Mamba-1's selective scan (S6, arXiv:2312.00752) in a chunked form; no
-counterpart in the reference (SURVEY.md §5.7).  Plain `jax.numpy`,
-differentiated by JAX.  `s6/scan`, the scope around all of this, is what the
-benchmark reads it by (PERF.md section 3).
+counterpart in the reference (SURVEY.md §5.7).  `selective_scan` is one
+`jax.custom_vjp`.  Its forward is plain `jax.numpy` everywhere but on TPU
+(`_plain_forward`, a scan over `_chunk_body`); for TPU, at the shapes it
+takes, one Pallas kernel walks the recurrence itself with the state in vector
+registers (`ops/pallas/selective_scan.py`: `s6_scan_fwd`, PR 42, the same
+arithmetic at the same precision).  Its backward is JAX's own differentiation
+of `_chunk_body` on every platform, a chunk at a time from the state that
+entered it, which either forward writes (no gradient in this file is derived
+by hand).  `s6/scan`, the scope around all of this, is what the benchmark
+reads it by (PERF.md section 3).
 
 The recurrence, per batch row, per channel c of the mixer's inner width and
 per state n, with a positive step `dt_t[c]`, a negative `A[c, n]` and one
@@ -17,23 +24,26 @@ into a [chunk, chunk] mask on `C B^T`; here that mask would be
 [chunk, chunk, channels, N].  The work is elementwise, on [positions, N,
 channels] arrays, behind a serial chain of S positions.
 
-`selective_scan` cuts the sequence into chunks of `chunk` positions and runs
+The plain form cuts the sequence into chunks of `chunk` positions and runs
 one `lax.scan` over them with the state [b, N, channels] as its carry.  Inside
 a chunk the recurrence is a first-order linear one, `h_t = a_t h_{t-1} + u_t`,
 and `jax.lax.associative_scan` solves it in log2(chunk) levels with the
 operator `(a, u) . (a', u') = (a a', a' u + u')`.  The entering state joins
-the first position's `u`.
+the first position's `u`.  (The kernel has no levels: a position at a time,
+which is fewer operations when nothing has to leave the registers.)
 
 A cumulative decay is only ever a PRODUCT of factors in (0, 1], never a
 quotient: `exp(cum_t) / exp(cum_s)` is inf/inf or 0/0 once the running sum of
 `dt * A` passes -88 in float32, which `dt * |A|` = 0.1 * 16 a position does in
 55 positions.  A product that underflows to 0 is the right answer to float32.
 
-The chunk body is a `jax.checkpoint`: the backward keeps the state that
-ENTERS each chunk ([S / chunk, b, N, channels] float32; 84 MB a layer at one
-8,192-token sequence, 5,120 channels, N 16 and a chunk of 32) and runs a
-chunk's forward again before its backward; the [S, channels, N] states (2.7 GB
-there) never exist at once.
+What the backward keeps is the state that ENTERS each chunk ([S / chunk, b,
+N, channels] float32; 84 MB a layer at one 8,192-token sequence, 5,120
+channels, N 16 and a chunk of 32), beside the inputs: it runs a chunk's
+forward again (`_chunk_body`, behind a `jax.checkpoint`) before its backward,
+in one reverse `lax.scan` that carries the state's cotangent; the [S,
+channels, N] states (2.7 GB there) never exist at once, and the serial pass
+over the chunks is not run a second time.
 
 Layout: [.., N, channels], the channels in the lanes (N = 16 there would pad
 to 128).
@@ -43,24 +53,29 @@ n are float32 whatever the inputs are; y leaves in x's dtype.
 
 The chunk is 32 positions because XLA's fusions fall off a cliff above it on
 the v5e: at the benchmark's shapes (1 x 8,192 x 5,120 channels, N 16) a
-layer's forward takes 9.2 / 10.3 / 10.3 ms at chunks of 8 / 16 / 32 and 10.7 /
-52.9 / 83.0 / 94.5 ms at 64 / 128 / 256 / 512, forward + backward 38.0 / 36.0 /
-37.9 ms against 116 / 244 / 309 / 391 (my chip runs, PR 40: the levels of a
-[32, 16, 5120] float32 chunk, 10 MB, stay fused; larger ones are written out
-level by level).  A Pallas kernel that keeps a chunk's levels in VMEM is what
-a `perf_opt` issue on this scan would write: its bytes need 1.5 ms a layer and
-direction.
+layer's plain forward takes 9.2 / 10.3 / 10.3 ms at chunks of 8 / 16 / 32 and
+10.7 / 52.9 / 83.0 / 94.5 ms at 64 / 128 / 256 / 512, forward + backward 38.0 /
+36.0 / 37.9 ms against 116 / 244 / 309 / 391 (my chip runs, PR 40: the levels
+of a [32, 16, 5120] float32 chunk, 10 MB, stay fused; larger ones are written
+out level by level).  The kernel's forward takes 1.56 ms there (my chip runs,
+PR 42); the backward is still the plain chunk's, so the chunk stays.
 
-Sharding: nothing here names a mesh axis; batch sharding is GSPMD's to
-propagate through the elementwise work.
+Sharding: the plain form names no mesh axis; batch sharding is GSPMD's to
+propagate through the elementwise work.  A Mosaic kernel GSPMD cannot
+partition, so on a mesh `selective_scan` runs under shard_map over the batch
+axes where the kernel takes the shapes.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.parallel.sharding import _fit_spec
 
 # Positions a chunk holds: log2 levels of the associative scan inside, S / CHUNK serial steps outside
 # (module docstring: why 32).
@@ -88,6 +103,98 @@ def _chunk_body(A_t, D, carry, inp):
     return h[:, -1], y
 
 
+def _chunks(arr, chunk: int):
+    """[b, S, F] -> [S / chunk, b, chunk, F]: what the scans over `_chunk_body` walk."""
+    b, s, f = arr.shape
+    return arr.reshape(b, s // chunk, chunk, f).swapaxes(0, 1)
+
+
+def _positions(arr):
+    """Back: [S / chunk, b, chunk, F] -> [b, S, F]."""
+    nc, b, chunk, f = arr.shape
+    return arr.swapaxes(0, 1).reshape(b, nc * chunk, f)
+
+
+def _plain_forward(x, dt, A_t, B, C, D, chunk: int):
+    """One `lax.scan` over `_chunk_body`: (y [b, S, C] in x's dtype, the state
+    that enters each chunk [S / chunk, b, N, C] float32).  The forward off TPU
+    and at shapes the kernel refuses."""
+    body = jax.checkpoint(lambda carry, inp: _chunk_body(A_t, D, carry, inp))
+
+    def step(carry, inp):
+        left, y = body(carry, inp)
+        return left, (y, carry)
+
+    start = jnp.zeros((x.shape[0], *A_t.shape), jnp.float32)
+    _, (y, entering) = jax.lax.scan(step, start, tuple(_chunks(t, chunk) for t in (x, dt, B, C)))
+    return _positions(y).astype(x.dtype), entering
+
+
+def _kernel():
+    """`ops/pallas/selective_scan.py`, imported at first use like the other ops' kernels."""
+    from ray_tpu.ops.pallas import selective_scan
+
+    return selective_scan
+
+
+def _kernel_takes(x, B, chunk: int) -> bool:
+    return _kernel().supported(x.shape[2], B.shape[-1], x.shape[1], chunk)
+
+
+def _forward(x, dt, A, B, C, D, chunk: int):
+    """(y, the state that enters each chunk).  Like
+    attention and the convolution, the form follows the platform a step is
+    LOWERED for, not the process's backend: the kernel for TPU at shapes it
+    takes, the plain form everywhere else."""
+    f32 = jnp.float32
+    inputs = (x, dt.astype(f32), A.astype(f32).T, B, C, D)
+    plain = functools.partial(_plain_forward, chunk=chunk)
+    if _kernel_takes(x, B, chunk):
+        kernel = functools.partial(_kernel().s6_scan_fwd, chunk=chunk)
+        return jax.lax.platform_dependent(*inputs, tpu=kernel, default=plain)
+    return plain(*inputs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _scan(x, dt, A, B, C, D, chunk: int):
+    return _forward(x, dt, A, B, C, D, chunk)[0]
+
+
+def _scan_fwd(x, dt, A, B, C, D, chunk: int):
+    y, entering = _forward(x, dt, A, B, C, D, chunk)
+    return y, (x, dt, A, B, C, D, entering)
+
+
+def _scan_bwd(chunk: int, res, dy):
+    """JAX's own differentiation of `_chunk_body`, a chunk at a time from the
+    last to the first: the chunk's forward once more FROM THE STATE THAT
+    ENTERED IT, which the forward wrote (kernel or plain), then its backward,
+    the state's cotangent carried along with the sums for A and D.  The serial
+    pass over the chunk states is not run again.  Cotangents in their
+    arguments' dtypes."""
+    x, dt, A, B, C, D, entering = res
+    f32 = jnp.float32
+    A_t, D32 = A.astype(f32).T, D.astype(f32)
+
+    def step(carry, xs):
+        d_state, d_A, d_D = carry
+        state, inp, dy = xs
+        # a checkpoint, so that what `pull` holds is the chunk's arguments and forward and backward run in one piece
+        _, pull = jax.vjp(jax.checkpoint(_chunk_body), A_t, D32, state, inp)
+        g_A, g_D, d_state, d_inp = pull((d_state, dy.astype(f32)))
+        return (d_state, d_A + g_A, d_D + g_D), d_inp
+
+    chunks = functools.partial(_chunks, chunk=chunk)
+    start = (jnp.zeros_like(entering[0]), jnp.zeros_like(A_t), jnp.zeros_like(D32))
+    inputs = (chunks(x), chunks(dt.astype(f32)), chunks(B), chunks(C))
+    (_, d_A, d_D), d_inputs = jax.lax.scan(step, start, (entering, inputs, chunks(dy)), reverse=True)
+    dx, d_dt, dB, dC = map(_positions, d_inputs)
+    return dx, d_dt.astype(dt.dtype), d_A.T.astype(A.dtype), dB, dC, d_D.astype(D.dtype)
+
+
+_scan.defvjp(_scan_fwd, _scan_bwd)
+
+
 def selective_scan(
     x: jax.Array,
     dt: jax.Array,
@@ -96,29 +203,34 @@ def selective_scan(
     C: jax.Array,
     D: jax.Array,
     chunk: Optional[int] = None,
+    mesh=None,
+    batch_axes=None,
 ) -> jax.Array:
     """The selective scan of the module docstring, chunked.
 
     x [b, S, C]; dt [b, S, C] (after softplus, positive); A [C, N]
     (negative); B, C [b, S, N] (one group); D [C].  Returns y [b, S, C] in
     x's dtype.  `chunk` (None = `CHUNK`) is cut to S when S is shorter; S must
-    be a multiple of it."""
-    b, s, c = x.shape
-    n = B.shape[-1]
+    be a multiple of it.
+
+    mesh / batch_axes say how the arguments are sharded (A and D are
+    replicated).  GSPMD partitions the plain form by itself; a Mosaic kernel it
+    cannot, so with a mesh the kernel runs under shard_map over the batch axes,
+    each device on its own rows with the whole sequence and every channel."""
+    s = x.shape[1]
     chunk = min(chunk or CHUNK, s)
     if s % chunk:
         raise ValueError(f"selective_scan: sequence length {s} is not a multiple of the chunk {chunk}")
-    nc = s // chunk
-    f32 = jnp.float32
-    with jax.named_scope("s6/scan"):
-        def chunks(arr):  # [b, S, F] -> [nc, b, chunk, F]
-            return arr.reshape(b, nc, chunk, arr.shape[-1]).swapaxes(0, 1)
 
-        A_t = A.astype(f32).T  # [N, C]
-        body = jax.checkpoint(lambda carry, inp: _chunk_body(A_t, D, carry, inp))
-        _, y = jax.lax.scan(
-            body, jnp.zeros((b, n, c), f32), (chunks(x), chunks(dt.astype(f32)), chunks(B), chunks(C)))
-        return y.swapaxes(0, 1).reshape(b, s, c).astype(x.dtype)
+    def run(x, dt, A, B, C, D):  # the scope INSIDE what shard_map wraps: its body starts a name stack of its own
+        with jax.named_scope("s6/scan"):
+            return _scan(x, dt, A, B, C, D, chunk)
+
+    if mesh is None or not _kernel_takes(x, B, chunk):
+        return run(x, dt, A, B, C, D)
+    rows = _fit_spec(x.shape, P(batch_axes, None, None), mesh)
+    return jax.shard_map(run, mesh=mesh, in_specs=(rows, rows, P(), rows, rows, P()), out_specs=rows,
+                         check_vma=False)(x, dt, A, B, C, D)
 
 
 def selective_scan_recurrent(

@@ -164,3 +164,27 @@ def test_the_kda_backward_kernel_compiles_at_the_kimi_cells_shapes(topo):
     got = [(s.shape, s.dtype) for s in jax.tree.leaves(backward.out_info)]
     like = lambda x: (x.shape, x.dtype)
     assert got == [like(blocks), like(blocks), like(values), like(blocks), ((1, 16384, 32), jnp.float32)]
+
+
+def test_the_selective_scan_kernel_compiles_at_the_phi4_cells_shapes(topo):
+    """Mosaic takes `s6_scan_fwd` (PR 42) at one 8,192-token sequence of 5,120
+    channels and 16 states, x in bf16 and in float32, as ONE custom call whose
+    full-size operands come as they are (the only copies are B and C turned
+    into columns, 0.5 MB each)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.pallas import selective_scan as kernels
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    assert kernels.supported(5120, 16, 8192, 32)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        args = (like((1, 8192, 5120), dtype), like((1, 8192, 5120), jnp.float32), like((16, 5120), jnp.float32),
+                like((1, 8192, 16), dtype), like((1, 8192, 16), dtype), like((5120,), jnp.float32))
+        with _no_compile_cache():
+            compiled = jax.jit(kernels.s6_scan_fwd).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 and "s6_scan_fwd" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
+        got = [(s.shape, s.dtype) for s in jax.tree.leaves(compiled.out_info)]
+        assert got == [((1, 8192, 5120), dtype), ((256, 1, 16, 5120), jnp.float32)]

@@ -46,6 +46,22 @@ def _mosaic_kernels(text):
     )
 
 
+def _kernel_paths(text, names):
+    """(kernel's name, its location's whole path) of every Mosaic call named by the regex `names`."""
+    locs = dict(re.findall(r'^(#loc\d+) = loc\((.*)\)$', text, flags=re.M))
+    for line in text.splitlines():
+        kernel = re.search(rf'kernel_name = "({names})"', line)
+        if "@tpu_custom_call" in line and kernel:
+            loc = re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)
+            seen, path = set(), ""
+            while loc and loc not in seen:  # a location names its parents by reference
+                seen.add(loc)
+                path += locs.get(loc, "")
+                nxt = re.search(r"#loc\d+", locs.get(loc, ""))
+                loc = nxt.group(0) if nxt else None
+            yield kernel.group(1), path
+
+
 MESHES = [
     (1, MeshSpec(data=1), "dp"),
     (4, MeshSpec(data=4), "dp"),
@@ -155,21 +171,45 @@ def test_kimi_step_lowers_for_tpu_with_the_kda_kernels_inside_kda_scan(n_devices
     kernels = _mosaic_kernels(text)
     assert kernels["kda_fwd"] == 2 and kernels["kda_bwd"] == 1 and kernels["flash_fwd"] == 1, kernels
     assert not [name for name in kernels if name.startswith("kda_") and name not in ("kda_fwd", "kda_bwd")]
-    locs = dict(re.findall(r'^(#loc\d+) = loc\((.*)\)$', text, flags=re.M))
-    for line in text.splitlines():
-        kernel = re.search(r'kernel_name = "(kda_fwd|kda_bwd)"', line)
-        if "@tpu_custom_call" in line and kernel:
-            loc = re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)
-            seen, path = set(), ""
-            while loc and loc not in seen:  # a location names its parents by reference
-                seen.add(loc)
-                path += locs.get(loc, "")
-                nxt = re.search(r"#loc\d+", locs.get(loc, ""))
-                loc = nxt.group(0) if nxt else None
-            assert "kda/scan" in path and kernel.group(1) in path, path
-            if kernel.group(1) == "kda_bwd":  # in the layer's backward, not its recompute: the reader's `bwd`
-                assert "rematted_computation" not in path, path
+    for kernel, path in _kernel_paths(text, "kda_fwd|kda_bwd"):
+        assert "kda/scan" in path and kernel in path, path
+        if kernel == "kda_bwd":  # in the layer's backward, not its recompute: the reader's `bwd`
+            assert "rematted_computation" not in path, path
 
 
 def test_kimi_step_lowered_for_the_cpu_holds_no_kernel():
     assert "tpu_custom_call" not in _lowered_text(1, MeshSpec(data=1), "dp", cfg=KIMI)
+
+
+# SambaY's Mamba-1 layers in small, at widths the scan's kernel takes (512 channels, 16 states; one
+# block of 128 positions): two s6 layers are one run, one scan body.
+S6 = TransformerConfig.tiny(
+    n_layers=2, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, max_seq_len=128, remat=True, remat_policy="qkv_attn",
+    rope_theta=None, layer_types=("s6", "s6"), s6_inner=512, s6_state=16, s6_dt_rank=16,
+)
+
+
+@pytest.mark.parametrize(
+    "n_devices,spec,strategy",
+    [(1, MeshSpec(data=1), "dp"), (4, MeshSpec(data=4), "dp"), (4, MeshSpec(data=1, fsdp=4), "fsdp")],
+    ids=["dp1", "dp4", "fsdp4"],
+)
+def test_s6_step_lowers_for_tpu_with_the_scan_kernel_inside_s6_scan(n_devices, spec, strategy, monkeypatch):
+    """The forward kernel twice in the run's body (forward, and the recompute:
+    `qkv_attn` keeps the projections, not the scan's output) and no backward
+    kernel (PR 42: the backward is JAX's own, of `_chunk_body`); under
+    shard_map on a mesh like the convolution's, under `s6/scan` and its own
+    name, which is how the benchmark's readers find its time."""
+    from ray_tpu.ops.pallas import selective_scan as kernels
+
+    monkeypatch.setattr(kernels, "_BLOCK_S", 128)
+    text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=S6, debug_info=True)
+    found = _mosaic_kernels(text)
+    assert found["s6_scan_fwd"] == 2 and found["ssm_conv_fwd"] == 2 and found["ssm_conv_bwd"] == 1, found
+    assert not [name for name in found if name.startswith("s6_") and name != "s6_scan_fwd"]
+    paths = [path for _, path in _kernel_paths(text, "s6_scan_fwd")]
+    assert len(paths) == 2 and all("s6/scan" in path and "s6_scan_fwd" in path for path in paths), paths
+
+
+def test_s6_step_lowered_for_the_cpu_holds_no_kernel():
+    assert "tpu_custom_call" not in _lowered_text(1, MeshSpec(data=1), "dp", cfg=S6)
