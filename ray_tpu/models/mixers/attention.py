@@ -1,0 +1,110 @@
+"""Causal softmax attention ("attention"), x [B, S, d], u = ln1(x):
+
+q/k/v projections (GQA: `n_heads` q heads, `n_kv_heads` k and v heads of
+`head_dim`), no bias; optionally (`qk_norm`) an RMSNorm with a learned scale
+over the WHOLE projected q and k (OLMoE, OLMo 2); rotary embedding only when
+`rope_theta` is set; causal softmax of `q k^T * attention_scale`
+(`head_dim ** -0.5` when None), with a window w (`layer_windows`) query i
+seeing keys i - w + 1 .. i; output projection.
+
+The core dispatches to the pallas flash kernel when lowered for TPU (under
+shard_map when there is a mesh), the XLA forms otherwise
+(ray_tpu.ops.attention), or ring attention when the mesh has a nontrivial
+`seq` axis.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.models.mixers.base import (
+    Leaf, Mixer, constrainer, fitting_axis, joined, normal, ones, out_scale, proj_scale, refuse_attn_bias,
+    ring_axis, rms_norm, stream_norm,
+)
+from ray_tpu.ops.attention import dot_product_attention
+from ray_tpu.ops.rotary import apply_rope
+
+
+def leaves(config):
+    c, hd = config, config.head_dim
+    q, kv = ("embed", "heads", "head_dim"), ("embed", "kv_heads", "head_dim")
+    out = {
+        "wq": Leaf((c.d_model, c.n_heads, hd), q, normal(proj_scale(c))),
+        "wk": Leaf((c.d_model, c.n_kv_heads, hd), kv, normal(proj_scale(c))),
+        "wv": Leaf((c.d_model, c.n_kv_heads, hd), kv, normal(proj_scale(c))),
+        "wo": Leaf((c.n_heads, hd, c.d_model), ("heads", "head_dim", "embed"), normal(out_scale(c))),
+    }
+    if c.qk_norm:
+        out["q_norm"] = ones((c.n_heads, hd), ("heads", "head_dim"))
+        out["k_norm"] = ones((c.n_kv_heads, hd), ("kv_heads", "head_dim"))
+    return out
+
+
+def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, data=None, shared=None, emit=False):
+    """The attention half of a layer.  The sequence-parallel ring takes no
+    `window` and no `attention_scale`."""
+    del data, shared, emit
+    c, dt, p = config, config.dtype, layer_params["attn"]
+    constrain = constrainer(rules, mesh)
+
+    # The scopes name each region in the compiled step's op metadata, which
+    # is what a device trace can tell fusions apart by (PERF.md section 3).
+    with jax.named_scope("layer/attn_proj"):
+        h = stream_norm(c, x, layer_params, "ln1")
+        q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(dt))
+        kk = jnp.einsum("bse,ehd->bshd", h, p["wk"].astype(dt))
+        vv = jnp.einsum("bse,ehd->bshd", h, p["wv"].astype(dt))
+        q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
+        kk = constrain(kk, ("act_batch", "act_seq", "act_kv_heads", "act_head_dim"))
+        if c.qk_norm:
+            # over the WHOLE projection: heads * head_dim is one vector per position
+            q = rms_norm(q, p["q_norm"], c.norm_eps, axis=(-2, -1))
+            kk = rms_norm(kk, p["k_norm"], c.norm_eps, axis=(-2, -1))
+        if c.rope_theta is not None:
+            q = apply_rope(q, positions, theta=c.rope_theta)
+            kk = apply_rope(kk, positions, theta=c.rope_theta)
+        q = checkpoint_name(q, "q")
+        kk = checkpoint_name(kk, "k")
+        vv = checkpoint_name(vv, "v")
+    batch_axes = head_ax = None
+    if rules is not None:
+        batch_axes = rules.get("act_batch")
+        head_ax = fitting_axis(rules.get("act_heads"), mesh, q.shape[2])
+        if head_ax is not None and kk.shape[2] % mesh.shape[head_ax] != 0:
+            head_ax = None  # GQA kv heads don't divide: replicate heads
+    seq_axis = ring_axis(rules, mesh, q)
+    with jax.named_scope("layer/attn_core"):
+        if seq_axis is not None:
+            # Sequence parallelism: activations are seq-sharded, so full
+            # attention would force XLA to all-gather the sequence.  Ring
+            # attention keeps KV rotating over ICI instead
+            # (ops/ring_attention.py; SURVEY.md §5.7 — novel, no reference
+            # counterpart).
+            from ray_tpu.ops.ring_attention import ring_attention_sharded
+
+            if c.attention_scale is not None:
+                raise ValueError("ring attention takes no attention_scale")
+            if window is not None:
+                raise ValueError("ring attention takes no window (layer_windows)")
+            attn = ring_attention_sharded(
+                q, kk, vv, mesh,
+                seq_axis=seq_axis,
+                batch_axes=batch_axes,
+                head_axis=head_ax,
+                causal=True,
+            )
+        else:
+            attn = dot_product_attention(
+                q, kk, vv, causal=True, scale=c.attention_scale, impl=c.attention_impl,
+                mesh=mesh if rules is not None else None,
+                batch_axes=batch_axes, head_axis=head_ax,
+                **({} if window is None else {"window": window}),
+            )
+    with jax.named_scope("layer/attn_proj"):
+        attn_out = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(dt))
+        return joined(c, x, attn_out, constrain), {}
+
+
+MIXER = Mixer("attention", "layers", "attn", leaves, refuse_attn_bias, mix)

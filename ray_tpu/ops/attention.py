@@ -14,7 +14,7 @@ A `window` w (None = none; needs `causal`) keeps of the causal keys the last
 w, the query's own position counted: query i sees keys i - w + 1 .. i.  All
 three forms take it: `reference` and `blockwise` as a second term of their
 masks, the flash kernels as tiles never visited (ops/pallas/flash_attention.py).
-Ring attention does not (models/transformer.py refuses the pairing by name).
+Ring attention does not (models/mixers/attention.py refuses the pairing by name).
 """
 
 from __future__ import annotations
@@ -33,7 +33,7 @@ NEG_INF = -1e30
 # name outside a `custom_vjp` does not reach the residual inside: the XLA
 # forms and ring attention name their result, the flash kernel names the
 # `out` and the log-sum-exp of its forward rule.  A remat policy that keeps
-# the attention output keeps both names (models/transformer.py), or the
+# the attention output keeps both names (models/transformer.py `_remat_policy`), or the
 # backward re-runs the whole forward for the one it lacks.
 ATTN_OUT = "attn"
 ATTN_LSE = "attn_lse"

@@ -30,7 +30,7 @@ Rules = Dict[str, MeshAxis]
 #   embed/heads/kv_heads/head_dim/mlp/vocab/expert — parameter dims
 #   layers — scan-over-layers leading axis (sharded over `pipeline` by
 #            pp_rules; unsharded elsewhere)
-#   A Mamba-2 mixer's leaves (models/transformer.py `mamba_layers`) use
+#   A Mamba-2 mixer's leaves (models/mixers/mamba2.py, stack `mamba_layers`) use
 #   `embed` on the model side of their two projections and no name on the
 #   mixer's inner width: fsdp shards them, tp replicates them (the scan's
 #   heads are not split over `tensor`).
@@ -149,6 +149,65 @@ def resolve_rules(strategy: Union[str, Rules]) -> Rules:
         except KeyError:
             raise ValueError(f"unknown strategy {strategy!r}; options {sorted(PRESETS)}")
     return dict(strategy)
+
+
+def pipeline_axes(rules: Optional[Rules], mesh: Optional[Mesh], n_layers: int) -> Optional[Tuple[str, Optional[str]]]:
+    """(the mesh axis the layer stack pipelines over, the one fsdp-at-rest
+    param axis or None) when the rules shard the LAYER STACK over a real (>1)
+    mesh axis, so that the model runs the GPipe microbatch schedule instead
+    of a plain scan (each stage device holds n_layers/P layers); None
+    otherwise.
+
+    Explicit pp intent: misconfigurations are ERRORS, not silent fallbacks:
+    replicated layers instead of pipelining would only surface as OOM/low
+    MFU at scale."""
+    if rules is None or rules.get("layers") is None:
+        return None
+    ax = rules["layers"]
+    ax = ax[0] if isinstance(ax, tuple) else ax
+    size = mesh.shape[ax] if ax in mesh.axis_names else 1
+    if size <= 1:
+        return None
+    if n_layers % size != 0:
+        raise ValueError(
+            f"strategy 'pp': n_layers={n_layers} not divisible by "
+            f"pipeline axis size {size}"
+        )
+    sharded_params = [
+        k for k in ("embed", "heads", "kv_heads", "head_dim", "mlp",
+                    "vocab", "expert")
+        if rules.get(k) is not None
+    ]
+    # fsdp-at-rest composes with pp (strategy "pp_fsdp"): the sharded param
+    # axes are all-gathered per stage per step inside the schedule.  TP-style
+    # axes (which also shard activations) do NOT: gathering them would
+    # silently undo the tensor split.
+    act_axes = set()
+    for k, v in rules.items():
+        if k.startswith("act_") and k != "act_batch" and v is not None:
+            act_axes.update(v if isinstance(v, tuple) else (v,))
+    pp_fsdp_axes = set()
+    bad = []
+    for k in sharded_params:
+        v = rules[k]
+        if isinstance(v, tuple) or v == ax or v in act_axes:
+            bad.append(k)
+        else:
+            pp_fsdp_axes.add(v)
+    if bad:
+        raise ValueError(
+            "strategy 'pp' composes with data sharding and ONE "
+            "fsdp-at-rest param axis (strategy 'pp_fsdp'); param "
+            f"dims {bad} shard over activation/tensor axes the "
+            "pipeline schedule cannot gather away"
+        )
+    if len(pp_fsdp_axes) > 1:
+        raise ValueError(
+            "strategy 'pp' composes with at most ONE fsdp-at-rest "
+            f"param axis, got {sorted(pp_fsdp_axes)} across "
+            f"{sharded_params}"
+        )
+    return ax, (pp_fsdp_axes.pop() if pp_fsdp_axes else None)
 
 
 def logical_to_spec(logical_axes: Sequence[Optional[str]], rules: Rules) -> P:

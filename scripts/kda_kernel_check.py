@@ -14,7 +14,7 @@ recurrence keeps one [32, 128, 128] state a token for its backward, 4.3 GB at
 2,048.  Then the time of one layer's backward, both ways.
 
 Inputs have the statistics of the cell's own weights at initialisation
-(`models/transformer.py`): q, k L2-normalised per head (q times 128^-0.5), v
+(`models/mixers/kda.py`): q, k L2-normalised per head (q times 128^-0.5), v
 the SiLU of a normal in bf16, g = -A softplus(x + dt_bias) with A uniform in
 [1, 16] per head and softplus(dt_bias) log-uniform in [1e-3, 1e-1] per channel,
 beta a sigmoid.  One JSON line per seed, then one per timing.  Exit 1 if the

@@ -9,7 +9,7 @@ unrounded; the bf16 outputs beside it), and the time of one layer's forward both
     chiprun -- python3 scripts/s6_kernel_check.py [--seeds 3] [--blocks 512x256 1024x128 ...]
 
 Inputs have the statistics of the cell's own weights at initialisation
-(`models/transformer.py`): x the SiLU of a normal in bf16, `[dt_low | B | C] =
+(`models/mixers/s6.py`): x the SiLU of a normal in bf16, `[dt_low | B | C] =
 x W_x` in bf16 with `W_x` normal at inner^-0.5, `dt = softplus(dt_low W_dt +
 b_dt)` in float32 with `W_dt` normal at rank^-0.5 and `softplus(b_dt)`
 log-uniform in [1e-3, 1e-1], A = -(1..N) in every channel, D = 1.
@@ -53,7 +53,7 @@ def weights(seed: int):
 
 
 def step_of(low, w_dt, bias):
-    """dt as `_s6_layer` makes it."""
+    """dt as `mixers/s6.py` `mix` makes it."""
     step = jnp.einsum("bsr,rf->bsf", low[..., :RANK], w_dt, preferred_element_type=jnp.float32)
     return jax.nn.softplus(step + bias)
 

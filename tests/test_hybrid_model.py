@@ -36,6 +36,7 @@ if ROOT not in sys.path:
 from benchmarks.lib import reference_hybrid  # noqa: E402
 from ray_tpu.models import LMTrainContext, TransformerConfig  # noqa: E402
 from ray_tpu.models import transformer  # noqa: E402
+from ray_tpu.models.mixers import MIXERS, attention  # noqa: E402
 from ray_tpu.ops import ssm  # noqa: E402
 from ray_tpu.ops.pallas import ssm_conv  # noqa: E402
 from ray_tpu.parallel import MeshSpec, build_mesh  # noqa: E402
@@ -122,7 +123,6 @@ def test_the_stack_is_three_runs_and_the_parameters_two_stacks(tiny):
     cfg, params = tiny["cfg"], tiny["params"]
     assert cfg.layer_runs() == (("mamba", "dense", 0, 2), ("attention", "dense", 0, 1), ("mamba", "dense", 2, 1))
     assert params["layers"]["attn"]["wq"].shape[0] == 1 and params["mamba_layers"]["ssm"]["in_proj"].shape[0] == 3
-    assert sum(leaf.size for leaf in jax.tree_util.tree_leaves(params)) == cfg.num_params()
 
 
 def test_logits_equal_the_reference(tiny):
@@ -184,7 +184,7 @@ def test_qkv_attn_saves_a_mamba_layers_two_named_residuals_and_nothing_wide_in_f
     inner = cfg.ssm_heads * cfg.ssm_head_dim
 
     def saved(config):
-        run = jax.checkpoint(lambda p, x: transformer._mamba_layer(x, p, None, config, None)[0],
+        run = jax.checkpoint(lambda p, x: transformer.layer(MIXERS["mamba"], x, p, None, config, None)[0],
                              policy=transformer._remat_policy(config))
         return [(aval.shape, aval.dtype) for aval, why in saved_residuals(run, layer, x)
                 if "from the argument" not in why]
@@ -348,7 +348,7 @@ def test_a_mamba_layer_reaches_the_convolution_through_one_hand_written_backward
     x = jnp.zeros((2, SEQ, cfg.d_model))
 
     def run(p, x):
-        return transformer._mamba_layer(x, p, None, cfg, None)[0]
+        return transformer.layer(MIXERS["mamba"], x, p, None, cfg, None)[0]
 
     forward = jax.make_jaxpr(run)(layer, x).jaxpr
     backward = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(run(p, x)), argnums=(0, 1)))(layer, x).jaxpr
@@ -387,8 +387,8 @@ def test_each_multiplier_matters(tiny, field):
 
 def test_no_rope_theta_means_no_rotary_embedding(tiny, monkeypatch):
     calls = []
-    real = transformer.apply_rope
-    monkeypatch.setattr(transformer, "apply_rope", lambda *a, **k: calls.append(1) or real(*a, **k))
+    real = attention.apply_rope
+    monkeypatch.setattr(attention, "apply_rope", lambda *a, **k: calls.append(1) or real(*a, **k))
     tokens = tiny["batch"]["tokens"]
     transformer.forward(tiny["params"], tokens, tiny["cfg"])
     assert not calls
@@ -434,37 +434,89 @@ EXPERT = dict(n_heads=4, n_kv_heads=4, d_ff=32, n_experts=8, experts_per_token=2
               router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
 
 
-@pytest.mark.parametrize("kw, equations", [({}, 700), (EXPERT, 2894)], ids=["dense", "expert"])
-def test_a_dense_and_an_expert_step_trace_to_the_parents_program(kw, equations):
+# One tiny model per family of stacks: Granite-like is `BASE`; Kimi-like pairs KDA with BOTH kinds of FFN beside a
+# latent-attention layer (a share of the experts held); SambaY-like has the four kinds that read and hand on values.
+KIMI = dict(
+    n_layers=4, rope_theta=None, layer_types=("kda", "kda", "mla", "kda"),
+    ffn_types=("dense", "experts", "experts", "experts"), kda_heads=2, kda_head_dim=16, kv_lora_rank=16,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, n_experts=8, n_experts_held=4, first_expert_held=2,
+    experts_per_token=2, moe_d_ff=32, n_shared_experts=1, norm_topk_prob=True, router_activation="sigmoid",
+    routed_scaling_factor=2.0, router_aux_loss_coef=0.01)
+SAMBAY = dict(
+    n_layers=6, rope_theta=None, tie_embeddings=True, norm_kind="layer", attn_bias=True,
+    layer_types=("s6", "diff_attention", "s6", "diff_attention", "gmu", "diff_cross"),
+    layer_windows=(None, 16, None, None, None, None), layer_ids=(0, 1, 16, 17, 18, 19),
+    s6_inner=128, s6_state=8, s6_dt_rank=8, s6_memory_layer=2, kv_source_layer=3)
+FAMILIES = {"dense": {}, "expert": EXPERT, "granite": dict(BASE, max_seq_len=128), "kimi": KIMI, "sambay": SAMBAY}
+
+
+@pytest.mark.parametrize("family, equations", [("dense", 700), ("expert", 2894), ("granite", 1977), ("kimi", 15438),
+                                               ("sambay", 4642)])
+def test_a_dense_and_an_expert_step_trace_to_the_parents_program(family, equations):
     """Counted at the parent of PR 30 with this function (the dense count is
     `tests/test_moe_model.py`'s 709 + 1 - 10; 710 / 2904 before PR 34's
     `head_cross_entropy`): the new fields' defaults add no equation, no slice
-    of the stack and nothing of the scan."""
-    ctx = one_device_ctx(TransformerConfig.tiny(**kw))
+    of the stack and nothing of the scan.  The three hybrid steps were counted
+    at the parent of PR 43, before a kind of mixer became one record."""
+    ctx = one_device_ctx(TransformerConfig.tiny(**FAMILIES[family]))
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
     jaxpr = jax.make_jaxpr(ctx._train_step)(state, {"tokens": toks, "targets": toks})
     assert _equations(jaxpr.jaxpr) == equations
-    text = str(jaxpr)
-    assert "ssm" not in text and "mamba" not in text
+    if family in ("dense", "expert"):
+        text = str(jaxpr)
+        assert "ssm" not in text and "mamba" not in text
 
 
-DENSE_SEED0 = {"embed": [0.12550178170204163, -0.1132921501994133],
-               "lm_head": [-0.08073218911886215, -0.1908739060163498],
-               "w_down": [0.048702314496040344, -0.0014959044056013227]}
+# Of `init_params(config, PRNGKey(0))`: the first two values of the embedding and the head, and in every stack of
+# the LAST layer's first and last leaves that draw a key (the mixer's first, the FFN's last).  The dense model's
+# were recorded at the parent of PR 30, the others' at the parent of PR 43.
+SEED0 = {
+    "dense": {"embed/tokens": [0.12550178170204163, -0.1132921501994133],
+              "layers/attn/wq": [-0.08601520210504532, -0.02267324924468994],
+              "layers/mlp/w_down": [0.048702314496040344, -0.0014959044056013227],
+              "lm_head": [-0.08073218911886215, -0.1908739060163498]},
+    "granite": {"embed/tokens": [0.12550178170204163, -0.1132921501994133],
+                "layers/attn/wq": [-0.30530697107315063, -0.25446006655693054],
+                "layers/mlp/w_down": [-0.0027661342173814774, -0.06458717584609985],
+                "mamba_layers/ssm/in_proj": [0.24149473011493683, 0.045193642377853394],
+                "mamba_layers/mlp/w_down": [0.06934718042612076, -0.09658616781234741]},
+    "kimi": {"embed/tokens": [0.12550178170204163, -0.1132921501994133],
+             "lm_head": [0.14543741941452026, -0.12132174521684647],
+             "kda_layers_dense/kda/wqkv": [0.024863429367542267, -0.09627213329076767],
+             "kda_layers_dense/mlp/w_down": [0.021224258467555046, -0.04864843562245369],
+             "kda_layers_experts/kda/wqkv": [0.052536532282829285, -0.0427650548517704],
+             "kda_layers_experts/mlp/shared/w_down": [0.05621757358312607, -0.011826579459011555],
+             "kda_layers_experts/mlp/w_down": [0.0067697735503315926, 0.0608651265501976],
+             "mla_layers/mla/wq": [0.21022771298885345, 0.08323755860328674],
+             "mla_layers/mlp/shared/w_down": [0.08691354095935822, -0.0094215152785182],
+             "mla_layers/mlp/w_down": [0.029987668618559837, 0.06039942055940628]},
+    "sambay": {"embed/tokens": [0.12550178170204163, -0.1132921501994133],
+               "s6_layers/s6/in_proj": [-0.09402994066476822, -0.14637696743011475],
+               "s6_layers/mlp/w_down": [-0.009793443605303764, 0.003301437944173813],
+               "diff_layers/diff/wqkv": [-0.1579943746328354, -0.179226815700531],
+               "diff_layers/mlp/w_down": [0.01664189249277115, -0.053962916135787964],
+               "gmu_layers/gmu/w1": [0.21022771298885345, 0.08323755860328674],
+               "gmu_layers/mlp/w_down": [0.012459908612072468, -0.022547056898474693],
+               "cross_layers/diff/wq": [0.10819514095783234, -0.15941713750362396],
+               "cross_layers/mlp/w_down": [0.022529320791363716, -0.012618785724043846]},
+}
 
 
-def test_a_dense_models_weights_for_a_seed_did_not_move():
-    """The key order of `init_params`: the first and last leaves a dense
-    model draws, against values recorded at the parent of PR 30."""
-    params = transformer.init_params(TransformerConfig.tiny(), jax.random.PRNGKey(0))
-    assert "mamba_layers" not in params
-    np.testing.assert_allclose(np.asarray(params["embed"]["tokens"][0, :2]), DENSE_SEED0["embed"], rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(params["lm_head"][0, :2]), DENSE_SEED0["lm_head"], rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(params["layers"]["mlp"]["w_down"][1, 0, :2]), DENSE_SEED0["w_down"],
-                               rtol=1e-6)
-
-
+@pytest.mark.parametrize("family", ["dense", "granite", "kimi", "sambay"])
+def test_a_dense_models_weights_for_a_seed_did_not_move(family):
+    """The key order of `init_params`, in every kind of stack: which stacks
+    share the first key sequence, and the order of the draws inside each."""
+    params = transformer.init_params(TransformerConfig.tiny(**FAMILIES[family]), jax.random.PRNGKey(0))
+    if family == "dense":
+        assert "mamba_layers" not in params
+    assert {path.split("/")[0] for path in SEED0[family]} == set(params) - {"final_norm", "final_norm_b"}
+    for path, want in SEED0[family].items():
+        leaf = params
+        for name in path.split("/"):
+            leaf = leaf[name]
+        leaf = leaf if path.split("/")[0] in ("embed", "lm_head") else leaf[-1]
+        np.testing.assert_allclose(np.asarray(leaf).reshape(-1)[:2], want, rtol=1e-6, err_msg=path)
 
 
 # -- across devices --------------------------------------------------------------------------

@@ -6,13 +6,16 @@ test_torch_fsdp.py) — same model, different sharding rules, loss must agree.
 """
 
 import collections
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import LMTrainContext, TransformerConfig, forward, init_params
+from ray_tpu.models import LMTrainContext, TransformerConfig, forward, init_params, param_axes
+from ray_tpu.models.mixers import MIXERS
+from ray_tpu.models.transformer import FFN_KINDS
 from ray_tpu.parallel import MeshSpec, build_mesh, resolve_rules
 
 
@@ -31,10 +34,33 @@ def test_forward_shapes():
     assert logits.dtype == jnp.float32
 
 
-def test_param_count_matches_config():
-    params = init_params(CFG, jax.random.PRNGKey(0))
-    n = sum(x.size for x in jax.tree_util.tree_leaves(params))
-    assert n == CFG.num_params()
+# What every kind of mixer and of FFN needs of a configuration, at sizes that differ where the leaves' do.
+SIZES = dict(
+    norm_kind="layer", qk_norm=True, rope_theta=None, ssm_heads=2, ssm_head_dim=16, ssm_state=8, kda_heads=2,
+    kda_head_dim=8, kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=32, s6_inner=96,
+)
+EXPERTS = dict(n_experts=4, experts_per_token=2, moe_d_ff=48, n_shared_experts=1, router_activation="sigmoid")
+
+
+@pytest.mark.parametrize("ffn", FFN_KINDS)
+@pytest.mark.parametrize("mixer", list(MIXERS))
+def test_param_count_matches_config(mixer, ffn):
+    """One declaration, three readers, for every (mixer, FFN) pair the
+    registry admits: the leaves `init_params` makes sum to `num_params()`, and
+    `param_axes` is the same tree with one logical axis per dimension.  A
+    kind that reads another layer's values stands behind the kind that hands
+    them on."""
+    makers = [m for m in MIXERS.values() if set(m.hands) & set(MIXERS[mixer].reads)]
+    kinds = (*(m.name for m in makers), mixer, mixer)
+    cfg = TransformerConfig.tiny(
+        n_layers=len(kinds), layer_types=kinds, ffn_types=(ffn,) * len(kinds), attn_bias=MIXERS[mixer].subtree == "diff",
+        **{m.source: i for i, m in enumerate(makers)}, **SIZES, **(EXPERTS if ffn == "experts" else {}))
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    assert sum(math.prod(a.shape) for a in jax.tree_util.tree_leaves(params)) == cfg.num_params()
+    axes = param_axes(cfg)
+    ranks = jax.tree_util.tree_map(lambda a: a.ndim, params)
+    assert ranks == jax.tree_util.tree_map(len, axes, is_leaf=lambda t: isinstance(t, tuple))
+    assert params[cfg.stack_name(mixer, ffn)][MIXERS[mixer].subtree]  # the pair's own stack, the mixer's own subtree
 
 
 @pytest.mark.parametrize(

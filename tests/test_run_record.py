@@ -240,7 +240,9 @@ assert hit["cache"] == "hit" and hit["retrieval_s"] > 0, hit
 assert sorted(run_record.counters()["compiles"].snapshot().items()) == [((("cache", "hit"),), 1.0), ((("cache", "miss"),), 2.0)]
 assert tracing.drain_spans() and not tracing.is_enabled()
 """
-    env = {k: v for k, v in os.environ.items() if k not in ("RAY_TPU_TRACE", "JAX_COMPILATION_CACHE_DIR")}
+    # (with the metadata in the key, `f` and `g`, built on two lines, are two programs)
+    env = {k: v for k, v in os.environ.items() if k not in (
+        "RAY_TPU_TRACE", "JAX_COMPILATION_CACHE_DIR", "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY")}
     proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env, capture_output=True, text=True)
     assert proc.returncode == 0, proc.stderr[-3000:]
 

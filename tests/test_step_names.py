@@ -273,7 +273,7 @@ def _recomputed_matmuls(lowered):
 
 @pytest.mark.parametrize("remat_policy, projections, attention", [("qkv_attn", 0, 1), ("attn", 4, 4), (None, 4, 4)])
 def test_only_qkv_attn_keeps_a_mamba_layers_projections_out_of_the_recompute(remat_policy, projections, attention):
-    """PR 36: `_mamba_layer` names `in_proj`'s output and the residual stream
+    """PR 36: a Mamba-2 layer (`mixers/mamba2.py`) names `in_proj`'s output and the residual stream
     after `out_proj`, and `qkv_attn` saves both, so neither matmul runs again
     in the backward (two runs of Mamba-2 layers here: 2 x 2 otherwise).  The
     scan's own forward and the FFN's `gate` + `up` + `down` are recomputed
@@ -436,7 +436,10 @@ def test_apply_default_puts_metadata_in_the_cache_key(monkeypatch, tmp_path):
     from ray_tpu._private import compile_cache
 
     monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
-    monkeypatch.delenv(compile_cache.METADATA_ENV, raising=False)
+    # set, then deleted: `monkeypatch` records nothing for a variable that is absent, and what
+    # `apply_default` writes into `os.environ` itself would outlive the test in this worker process
+    monkeypatch.setenv(compile_cache.METADATA_ENV, "")
+    monkeypatch.delenv(compile_cache.METADATA_ENV)
     monkeypatch.setitem(sys.modules, "jax", None)  # as in a worker at entry: jax not imported yet
     compile_cache.apply_default()
     assert os.environ[compile_cache.METADATA_ENV] == "1"
