@@ -120,8 +120,11 @@ def _head_cross_entropy_bwd(constrain, residuals, g):
 head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
 
 
-# A step counter of the run's record, among the step's metrics like `moe_held_rows_*`.
+# The step counters of the run's record (train/run_record.py), among the step's metrics: the key tiles a
+# windowed flash forward visits; what a layer that holds a share of its experts was given (`models/moe.py`
+# `router_losses`: rows per held expert, mean and busiest, and the busiest expert's load over the mean).
 WINDOW_TILES = "attn_window_tiles_visited_pct"
+STEP_COUNTERS = (WINDOW_TILES, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean")
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
@@ -309,11 +312,11 @@ class LMTrainContext:
         with tracing.annotate("train_step/dispatch"), self.mesh:
             state, metrics = self._train_step(state, batch)
         clock.mark(DISPATCH, t0)
-        if "moe_held_rows_mean" in metrics:
-            # counters of the run's record (train/run_record.py): kept as device scalars, fetched at a poll
-            note_step_counters({k: metrics[k] for k in ("moe_held_rows_mean", "moe_held_rows_max")})
-        if WINDOW_TILES in metrics:
-            note_step_counters({WINDOW_TILES: metrics[WINDOW_TILES]})
+        # counters of the run's record: kept as device scalars, fetched at a poll, under the index of
+        # this call (the clock's count of periods closed: 0 at the first)
+        counters = {k: metrics[k] for k in STEP_COUNTERS if k in metrics}
+        if counters:
+            note_step_counters(counters, step=clock.steps)
         return state, metrics
 
     def apply(self, params, tokens) -> jax.Array:

@@ -1,16 +1,21 @@
 """A Mamba-2 selective state-space layer ("mamba"; Granite 4.0-H,
-`modeling_granitemoehybrid.py`; Mamba-2 / SSD, arXiv:2405.21060), x [B, S, d],
-u = ln1(x), no bias anywhere but the convolution's:
+`modeling_granitemoehybrid.py`; Nemotron-H, `modeling_nemotron_h.py`; Mamba-2
+/ SSD, arXiv:2405.21060), x [B, S, d], u = ln1(x), no bias anywhere but the
+convolution's:
 
-`d_inner = ssm_heads * ssm_head_dim`, state N = `ssm_state`, one group:
-`in_proj: d -> [z: d_inner | xBC: d_inner + 2N | dt: ssm_heads]`;
+`d_inner = ssm_heads * ssm_head_dim` (NOT an `expand` times d: Nemotron-3-Nano
+has 64 x 64 = 4096 on a 2688-wide stream), state N = `ssm_state`, G =
+`ssm_groups` groups of B and C (1: Granite; 8: Nemotron-3-Nano):
+`in_proj: d -> [z: d_inner | xBC: d_inner + 2GN | dt: ssm_heads]`;
 `xBC = silu(causal_depthwise_conv1d(xBC, width ssm_conv, with bias))`,
-split into x [S, heads, head_dim], B [S, N], C [S, N];
+split into x [S, heads, head_dim], B [S, G, N], C [S, G, N];
 `dt = softplus(dt + dt_bias)` per head; `A = -exp(A_log)` per head (a
-scalar).  Per head, with state H_t in R^{head_dim x N}:
+scalar).  Per head, which reads group `head // (heads / G)`, with state H_t in
+R^{head_dim x N}:
 `H_t = exp(dt_t A) H_{t-1} + dt_t x_t (outer) B_t`, `y_t = H_t C_t + D x_t`
 (`ops/ssm.py`, in its chunked form).  Then
-`y = RMSNorm(y * silu(z))` over all d_inner channels and
+`y = RMSNorm(y * silu(z))` per group of d_inner / G channels (one group: over
+all of d_inner) with one learned scale [d_inner], and
 `out_proj: d_inner -> d`.
 
 The mixer's inner width carries no logical axis: `fsdp` shards the two
@@ -41,9 +46,9 @@ def leaves(config):
     -(1..heads), D = 1, and a step dt = softplus(dt_bias) drawn log-uniform
     in [1e-3, 1e-1] (dt_bias is its inverse softplus)."""
     c, heads, inner = config, config.ssm_heads, config.ssm_heads * config.ssm_head_dim
-    conv = inner + 2 * c.ssm_state  # x | B | C, the channels the convolution runs over (one group)
+    conv = inner + 2 * c.ssm_groups * c.ssm_state  # x | B | C, the channels the convolution runs over
     return {
-        "in_proj": Leaf((c.d_model, 2 * inner + 2 * c.ssm_state + heads), ("embed", None), normal(proj_scale(c))),
+        "in_proj": Leaf((c.d_model, inner + conv + heads), ("embed", None), normal(proj_scale(c))),
         "conv_w": Leaf((conv, c.ssm_conv), (None, None), normal(c.ssm_conv ** -0.5)),
         "conv_b": zeros((conv,)),
         "dt_bias": Leaf((heads,), (None,), inv_softplus(log_uniform(1e-3, 1e-1))),
@@ -57,6 +62,8 @@ def leaves(config):
 def validate(config) -> None:
     if not (config.ssm_heads > 0 and config.ssm_head_dim > 0 and config.ssm_state > 0):
         raise ValueError("a mamba layer needs ssm_heads, ssm_head_dim and ssm_state")
+    if config.ssm_groups < 1 or config.ssm_heads % config.ssm_groups:
+        raise ValueError(f"ssm_groups={config.ssm_groups} does not divide ssm_heads={config.ssm_heads}")
 
 
 def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, data=None, shared=None, emit=False):
@@ -76,17 +83,19 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     del positions, window, data, shared, emit  # a recurrence needs no positions
     c, dt, ssm = config, config.dtype, layer_params["ssm"]
     constrain, sharded = constrainer(rules, mesh), batch_sharded(rules, mesh)
-    heads, inner, n = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_state
+    heads, inner, n, groups = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_state, c.ssm_groups
     with jax.named_scope("layer/attn_proj"):
         with jax.named_scope("ssm/proj"):
             h = stream_norm(c, x, layer_params, "ln1")
             zxbcdt = jnp.einsum("bse,ef->bsf", h, ssm["in_proj"].astype(dt))
             zxbcdt = checkpoint_name(zxbcdt, SSM_IN_PROJ)
-            z, xbc, step = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], axis=-1)
+            z, xbc, step = jnp.split(zxbcdt, [inner, 2 * inner + 2 * groups * n], axis=-1)
         with jax.named_scope("ssm/conv"):
             xbc = causal_conv1d_silu(xbc, ssm["conv_w"], ssm["conv_b"], **sharded)
             step = jax.nn.softplus(step.astype(jnp.float32) + ssm["dt_bias"].astype(jnp.float32))
-            xs, b_in, c_out = jnp.split(xbc, [inner, inner + n], axis=-1)
+            xs, b_in, c_out = jnp.split(xbc, [inner, inner + groups * n], axis=-1)
+            if groups > 1:  # [B, S, G, N]: heads g * heads / G .. read group g
+                b_in, c_out = (a.reshape(*a.shape[:2], groups, n) for a in (b_in, c_out))
     with jax.named_scope("layer/attn_core"):
         y = ssd_chunked(
             xs.reshape(*xs.shape[:2], heads, c.ssm_head_dim), step,
@@ -96,7 +105,12 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
         with jax.named_scope("ssm/conv"):
             y = y.reshape(*y.shape[:2], inner)
             gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-            y = rms_norm(gated, ssm["norm"], c.norm_eps).astype(dt)  # one group: over all of d_inner
+            if groups > 1:  # each group of inner / G channels has its own statistics, the one scale is [inner]
+                by_group = gated.reshape(*gated.shape[:2], groups, inner // groups)
+                by_group = by_group * jax.lax.rsqrt(jnp.mean(jnp.square(by_group), axis=-1, keepdims=True) + c.norm_eps)
+                y = (by_group.reshape(gated.shape) * ssm["norm"].astype(jnp.float32)).astype(dt)
+            else:
+                y = rms_norm(gated, ssm["norm"], c.norm_eps).astype(dt)  # one group: over all of d_inner
         with jax.named_scope("ssm/proj"):
             out = jnp.einsum("bsf,fe->bse", y, ssm["out_proj"].astype(dt))
             return checkpoint_name(joined(c, x, out, constrain), SSM_MIXED), {}

@@ -6,7 +6,14 @@ kind is "experts" gets this block where a dense one has its SwiGLU
 description of the model, this module reads `d_model`, `expert_width` (ONE
 expert's width), `n_experts`, `experts_per_token`, `norm_topk_prob`,
 `router_activation`, `routed_scaling_factor`, `n_shared_experts`,
-`n_experts_held` / `first_expert_held`, `dtype`.
+`shared_expert_width`, `expert_kind`, `n_experts_held` / `first_expert_held`,
+`dtype`.
+
+An expert, routed or shared, is one of two forms (`expert_kind`): "swiglu",
+`W_down(silu(W_gate u) * (W_up u))`, three matrices (OLMoE, Kimi Linear), or
+"relu2", `W_down relu(W_up u)^2`, two matrices and no gate (Nemotron-H's
+`mlp_hidden_act: relu2`; the leaves are `w_up`, `w_down` alone).  Below,
+"the activation" is `silu(gate) * up` or `relu(up)^2`.
 
 The layer, on T tokens with K choices each out of E experts:
 
@@ -24,13 +31,15 @@ The layer, on T tokens with K choices each out of E experts:
 - `moe/dispatch`: a stable sort of the T*K assignments by expert, the E group
   sizes, a gather of the token rows into expert order, and the T*K gate values
   into the same order (by a sort: `_permuted`).
-- `moe/experts`: gate and up as grouped matmuls over the E ragged groups,
-  `silu(gate) * up` times the row's gate value, down as a third
+- `moe/experts`: gate and up (relu2: up alone) as grouped matmuls over the E
+  ragged groups, the activation times the row's gate value, down as another
   (`ops/grouped_matmul.py`: Pallas kernels when lowered for TPU, an XLA form
   of the same schedule elsewhere).
 - `moe/combine`: rows back into token order, summed over each token's K rows.
-- `moe/shared` (with `n_shared_experts`): one SwiGLU of `n_shared_experts`
-  experts' width that every token goes through, added to the routed result.
+- `moe/shared` (with `n_shared_experts`): one more expert of the same form,
+  `n_shared_experts` experts wide or as wide as the model states
+  (`shared_expert_d_ff`: Nemotron-3-Nano's 3712 beside routed 1856), that
+  every token goes through, added to the routed result.
 
 Held experts (`n_experts_held`): the layer is TOLD which experts it holds,
 `first_expert_held .. + n_experts_held` of the E the router scores, as one
@@ -102,17 +111,19 @@ from ray_tpu.parallel.sharding import Rules, _fit_spec, logical_to_spec
 
 def moe_param_axes(config: Any) -> Dict:
     """Logical axes of ONE layer's expert leaves (the stack adds `layers`)."""
-    axes = {
-        "router": ("embed", "expert"),
-        "w_gate": ("expert", "embed", "mlp"),
-        "w_up": ("expert", "embed", "mlp"),
-        "w_down": ("expert", "mlp", "embed"),
-    }
+    one = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
+    names = expert_leaves(config)
+    axes = {"router": ("embed", "expert"), **{n: ("expert",) + one[n] for n in names}}
     if config.router_activation == "sigmoid":
         axes["router_bias"] = (None,)
-    if config.n_shared_experts:
-        axes["shared"] = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
+    if config.shared_expert_width:
+        axes["shared"] = {n: one[n] for n in names}
     return axes
+
+
+def expert_leaves(config: Any) -> Tuple[str, ...]:
+    """The matrices of one expert, routed or shared, in the order they multiply."""
+    return ("w_up", "w_down") if config.expert_kind == "relu2" else ("w_gate", "w_up", "w_down")
 
 
 def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
@@ -129,24 +140,22 @@ def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
     def init(k, shape, s):
         return (jax.random.normal(k, leading + shape, jnp.float32) * s).astype(c.param_dtype)
 
-    params = {
-        "router": init(k1, (D, E), scale),
-        "w_gate": init(k2, (held, D, F), scale),
-        "w_up": init(k3, (held, D, F), scale),
-        "w_down": init(k4, (held, F, D), down_scale),
-    }
+    def matrices(keys, width, down, experts=()):
+        """One form's matrices, `experts` of them stacked; each leaf keeps its
+        key whatever the form (a two-matrix expert draws none for `w_gate`)."""
+        keys = dict(zip(("w_gate", "w_up", "w_down"), keys))
+        return {name: init(keys[name], experts + (width, D), down) if name == "w_down"
+                else init(keys[name], experts + (D, width), scale) for name in expert_leaves(c)}
+
+    # `routed_branch_init`: a token's K routed outputs are ONE residual branch of the depth-scaled variance, 1 / K each
+    routed_down = down_scale * c.experts_per_token ** -0.5 if c.routed_branch_init else down_scale
+    params = {"router": init(k1, (D, E), scale), **matrices((k2, k3, k4), F, routed_down, (held,))}
     if c.router_activation == "sigmoid":
         # zero, and the job leaves it so: its published update follows the
         # experts' load, outside the gradient (a recipe, not a key of a config)
         params["router_bias"] = jnp.zeros(leading + (E,), c.param_dtype)
-    if c.n_shared_experts:
-        ks = jax.random.split(jax.random.fold_in(key, 1), 3)
-        Fs = c.n_shared_experts * F
-        params["shared"] = {
-            "w_gate": init(ks[0], (D, Fs), scale),
-            "w_up": init(ks[1], (D, Fs), scale),
-            "w_down": init(ks[2], (Fs, D), down_scale),
-        }
+    if c.shared_expert_width:
+        params["shared"] = matrices(jax.random.split(jax.random.fold_in(key, 1), 3), c.shared_expert_width, down_scale)
     return params
 
 
@@ -245,19 +254,29 @@ def _route(params: Dict, tokens: jax.Array, config: Any):
     return expert_idx, gates, stats
 
 
-def _experts(tokens, expert_idx, gates, w_gate, w_up, w_down, n_experts, first_expert=None):
+def _activation(h, into, matmul):
+    """An expert's hidden row before `w_down`, from the matrices `into` it:
+    `silu(gate) * up` of (w_gate, w_up), `relu(up)^2` of (w_up,).  `matmul(h,
+    w)` is the form's product."""
+    if len(into) == 1:
+        return jnp.square(jax.nn.relu(matmul(h, into[0])))
+    return jax.nn.silu(matmul(h, into[0])) * matmul(h, into[1])
+
+
+def _experts(tokens, expert_idx, gates, weights, n_experts, first_expert=None):
     """Dispatch, grouped matmuls and combine for the experts `first_expert ..
-    first_expert + w_gate.shape[0]` of `n_experts` (None: all of them, on one
-    device).  tokens [T, D], expert_idx / gates [T, K]; returns (those
+    first_expert + weights[0].shape[0]` of `n_experts` (None: all of them, on
+    one device).  tokens [T, D], expert_idx / gates [T, K], `weights` the
+    experts' two or three matrices (`expert_leaves`); returns (those
     experts' part of the output, [T, D]; the rows each of them got, int32).  The gate values go to their rows in
-    expert order and multiply them in the pass that makes `silu(gate) * up`,
+    expert order and multiply them in the pass that makes the activation,
     so nothing behind `w_down` is a residual of the backward (module
     docstring).  Assignments to other experts sort behind the last group,
     where a grouped matmul writes nothing defined: those rows and their gate
     values are zeroed going in (which zeroes their gradients coming back),
     the rows also coming out."""
     k = expert_idx.shape[1]
-    n_local = w_gate.shape[0]
+    n_local = weights[0].shape[0]
     with jax.named_scope("moe/dispatch"):
         flat = expert_idx.reshape(-1)
         if first_expert is not None:
@@ -273,9 +292,8 @@ def _experts(tokens, expert_idx, gates, w_gate, w_up, w_down, n_experts, first_e
             mine = (jnp.arange(rows.shape[0], dtype=jnp.int32) < jnp.sum(group_sizes))[:, None]
             rows, g_row = jnp.where(mine, rows, 0), jnp.where(mine, g_row, 0)
     with jax.named_scope("moe/experts"):
-        gate = grouped_matmul(rows, w_gate, group_sizes)
-        up = grouped_matmul(rows, w_up, group_sizes)
-        out = grouped_matmul(jax.nn.silu(gate) * up * g_row, w_down, group_sizes)
+        hidden = _activation(rows, weights[:-1], lambda h, w: grouped_matmul(h, w, group_sizes))
+        out = grouped_matmul(hidden * g_row, weights[-1], group_sizes)
     with jax.named_scope("moe/combine"):
         if first_expert is not None:
             out = jnp.where(mine, out, 0)
@@ -300,7 +318,7 @@ def moe_ffn(
         expert_idx, gates, stats = _route(params, x.reshape(B * S, D), config)
     expert_idx = expert_idx.reshape(B, S, -1)
     gates = gates.reshape(B, S, -1)
-    weights = [params[k].astype(x.dtype) for k in ("w_gate", "w_up", "w_down")]
+    weights = [params[k].astype(x.dtype) for k in expert_leaves(config)]
 
     across_devices = rules is not None and mesh is not None and mesh.size > 1
     expert_ax = None  # the mesh axis the experts are split over, if it is a real split
@@ -312,25 +330,24 @@ def moe_ffn(
         raise ValueError("n_experts_held is one rank's share of the experts: it runs on one "
                          "device, not beside a mesh that shards tokens or experts")
 
-    def body(xb, idx, g, w_gate, w_up, w_down):
+    def body(xb, idx, g, *weights):
         b = xb.shape[0]
         first = config.first_expert_held if held else None
         if expert_ax is not None:
-            first = jax.lax.axis_index(expert_ax) * w_gate.shape[0]
+            first = jax.lax.axis_index(expert_ax) * weights[0].shape[0]
         y, rows = _experts(xb.reshape(b * S, D), idx.reshape(b * S, -1), g.reshape(b * S, -1),
-                           w_gate, w_up, w_down, config.n_experts, first)
+                           weights, config.n_experts, first)
         if expert_ax is not None:
             y = jax.lax.psum(y, expert_ax)
         return y.reshape(b, S, D), rows
 
     def with_shared(y):
-        if not config.n_shared_experts:
+        if not config.shared_expert_width:
             return y
         with jax.named_scope("moe/shared"):
-            w = params["shared"]
-            gate = jnp.einsum("bse,ef->bsf", x, w["w_gate"].astype(x.dtype))
-            up = jnp.einsum("bse,ef->bsf", x, w["w_up"].astype(x.dtype))
-            return y + jnp.einsum("bsf,fe->bse", jax.nn.silu(gate) * up, w["w_down"].astype(x.dtype))
+            w = [params["shared"][k].astype(x.dtype) for k in expert_leaves(config)]
+            hidden = _activation(x, w[:-1], lambda h, m: jnp.einsum("bse,ef->bsf", h, m))
+            return y + jnp.einsum("bsf,fe->bse", hidden, w[-1])
 
     if not across_devices:
         y, rows = body(x, expert_idx, gates, *weights)
@@ -341,7 +358,7 @@ def moe_ffn(
     w_spec = P(expert_ax, None, None)
     y = jax.shard_map(
         lambda *a: body(*a)[0], mesh=mesh,
-        in_specs=(tok_spec, tok_spec, tok_spec, w_spec, w_spec, w_spec),
+        in_specs=(tok_spec, tok_spec, tok_spec, *[w_spec] * len(weights)),
         out_specs=tok_spec, check_vma=False,
     )(x, expert_idx, gates, *weights)
     return with_shared(y), stats

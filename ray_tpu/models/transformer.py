@@ -8,12 +8,19 @@ layer, Kimi Delta Attention, latent attention without rotary embedding, and
 the four of SambaY's decoder-hybrid-decoder (a Mamba-1 selective scan,
 differential attention, a Gated Memory Unit that reads one scan's output,
 differential cross-attention over one attention layer's keys and values).
-The FFN (`ffn_types`) is a dense SwiGLU or a dropless top-k mixture of SwiGLU
-experts (`n_experts`; softmax or sigmoid router, an optional shared expert,
-all experts or one rank's share of them: models/moe.py).  Norms are RMSNorm
+The FFN (`ffn_types`) is a dense SwiGLU, a dropless top-k mixture of experts
+(`n_experts`; softmax or sigmoid router, an optional shared expert of a width
+of its own, all experts or one rank's share of them, each expert a SwiGLU or
+the two-matrix `W_down relu(W_up u)^2`: models/moe.py), or ABSENT ("none": the
+layer is its mixer alone, no `ln2`, no `mlp` leaves, no `layer/mlp` scope).
+Since a pre-norm pair is two single blocks one after the other, a stack whose
+blocks are a mixer OR an FFN alone (Nemotron-H's `MEMEM*E...`) is pairs too:
+`M E` is (mamba, experts), `* E` (attention, experts), an `M` straight before
+a `*` (mamba, none).  Attention's head size is `d_model // n_heads` unless the
+configuration states one (`attn_head_dim`).  Norms are RMSNorm
 or LayerNorm with bias (`norm_kind`).  Mistral, InternLM2, OLMoE, the Granite
-4.0-H hybrids, Kimi Linear and Phi-4-mini-flash run through it at their
-published widths (benchmarks/configs/).
+4.0-H hybrids, Kimi Linear, Phi-4-mini-flash and Nemotron-3-Nano run through
+it at their published widths (benchmarks/configs/).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -45,9 +52,9 @@ LayerNorm, a bias) and `norm_eps`:
 - `h0 = embed[tokens] * embedding_multiplier`; the layers;
   `logits = (norm(h) @ head) / logits_scaling`, the head the embedding table
   transposed when tied.
-- every layer: `h = h + residual_multiplier * mixer(norm_1(h))`, then
-  `h = h + residual_multiplier * FFN(norm_2(h))`, the dense FFN
-  `W_down(silu(W_gate u) * (W_up u))` without bias.  The multipliers are
+- every layer: `h = h + residual_multiplier * mixer(norm_1(h))`, then (unless
+  the layer has no FFN) `h = h + residual_multiplier * FFN(norm_2(h))`, the
+  dense FFN `W_down(silu(W_gate u) * (W_up u))` without bias.  The multipliers are
   Granite's muP form, 1.0 each = absent.
 
 What crosses layers (an s6 layer's scan output; a diff_attention layer's k
@@ -79,9 +86,10 @@ from ray_tpu.parallel.sharding import Rules, pipeline_axes, with_logical_constra
 # The logical axes of the logits (and of their cotangent).
 LOGITS_AXES = ("act_batch", "act_seq", "act_vocab")
 
-# The kinds of FFN; the kinds of mixer are `MIXERS`, the first of them what
-# a layer is when `layer_types` does not say.
-FFN_KINDS = ("dense", "experts")
+# The kinds of FFN ("none": the layer has no FFN half); the kinds of mixer are
+# `MIXERS`, the first of them what a layer is when `layer_types` does not say.
+# `TransformerConfig.stacks` lists a model's stacks in this order: append.
+FFN_KINDS = ("dense", "experts", "none")
 _DEFAULT_MIXER = next(iter(MIXERS))
 
 
@@ -146,23 +154,40 @@ class TransformerConfig:
     router_activation: str = "softmax"
     routed_scaling_factor: float = 1.0
     n_shared_experts: int = 0
+    # `expert_kind`: "swiglu", `W_down(silu(W_gate u) * W_up u)`, or "relu2",
+    # `W_down relu(W_up u)^2` (two matrices, no gate; Nemotron-H), of routed
+    # and shared experts alike.  `shared_expert_d_ff`: the shared expert's
+    # width where the model states it (None = `n_shared_experts * moe_d_ff`).
+    # `routed_branch_init`: the K experts a token chooses join the stream as
+    # ONE residual branch, so each routed expert's `w_down` starts at the
+    # depth-scaled `out_scale / sqrt(K)` (False: each expert at `out_scale`,
+    # as OLMoE's and Kimi Linear's seeds have it).
     n_experts_held: Optional[int] = None
     first_expert_held: int = 0
+    expert_kind: str = "swiglu"
+    shared_expert_d_ff: Optional[int] = None
+    routed_branch_init: bool = False
     # RMSNorm with a learned scale over the whole projected q and k, before
     # RoPE (OLMoE, OLMo 2).
     qk_norm: bool = False
     # The mixer of each layer, one of `mixers.MIXERS`, one entry per layer;
     # None = attention everywhere.  The Mamba-2 sizes are read only when some
     # layer is "mamba": heads x head size = the mixer's inner width, the
-    # state size N per head, the width of the causal depthwise convolution.
+    # state size N per head, the width of the causal depthwise convolution,
+    # the groups of B and C (heads `g * heads / groups ..` read group g).
     layer_types: Optional[Tuple[str, ...]] = None
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_conv: int = 4
-    # The FFN of each layer, "dense" or "experts", one entry per layer; None =
-    # experts everywhere when `n_experts` is set, dense everywhere otherwise.
+    ssm_groups: int = 1
+    # The FFN of each layer, "dense", "experts" or "none" (the layer is its
+    # mixer alone), one entry per layer; None = experts everywhere when
+    # `n_experts` is set, dense everywhere otherwise.
     ffn_types: Optional[Tuple[str, ...]] = None
+    # The head size of "attention" layers where it is not d_model / n_heads
+    # (Nemotron-H: 32 heads of 128 on a 2688-wide stream).
+    attn_head_dim: Optional[int] = None
     # Kimi Delta Attention, read only when some layer is "kda": heads, the
     # one head size of q, k and v (also the width of the two low-rank gates),
     # the width of the three causal depthwise convolutions.
@@ -249,6 +274,8 @@ class TransformerConfig:
             )
         if self.router_activation not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router_activation {self.router_activation!r}")
+        if self.expert_kind not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown expert_kind {self.expert_kind!r}; expected 'swiglu' or 'relu2'")
         if self.n_experts_held is not None and not (
             self.n_experts is not None and self.n_experts_held > 0 and self.first_expert_held >= 0
             and self.first_expert_held + self.n_experts_held <= self.n_experts
@@ -275,7 +302,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_model // self.n_heads if self.attn_head_dim is None else self.attn_head_dim
 
     def layer_variant(self, i: int) -> Tuple[Optional[int], bool]:
         """What of layer i is STATIC beside its pair, so that a run of one
@@ -295,6 +322,13 @@ class TransformerConfig:
         """ONE expert's width."""
         return self.d_ff if self.moe_d_ff is None else self.moe_d_ff
 
+    @property
+    def shared_expert_width(self) -> int:
+        """The shared expert's width (0: the model has none)."""
+        if self.shared_expert_d_ff is not None:
+            return self.shared_expert_d_ff if self.n_shared_experts else 0
+        return self.n_shared_experts * self.expert_width
+
     def layer_pairs(self) -> Tuple[Tuple[str, str], ...]:
         """(mixer, FFN) of each layer."""
         mixers = self.layer_types or (_DEFAULT_MIXER,) * self.n_layers
@@ -304,7 +338,7 @@ class TransformerConfig:
     def stack_name(self, mixer: str, ffn: str) -> str:
         """The subtree of the parameters that stacks the layers of one pair:
         the mixer's own (`Mixer.stack`) when the model pairs that mixer with
-        one kind of FFN, `<the mixer's>_<ffn>` when with both."""
+        one kind of FFN, `<the mixer's>_<ffn>` when with more."""
         both = len({f for m, f in self.layer_pairs() if m == mixer}) > 1
         return f"{MIXERS[mixer].stack}_{ffn}" if both else MIXERS[mixer].stack
 
@@ -380,7 +414,8 @@ def _model_leaves(config: TransformerConfig) -> Dict:
 def _layer_leaves(config: TransformerConfig, mixer: str, ffn: str) -> Dict:
     """ONE layer of the (mixer, FFN) pair as a tree of `Leaf`s, in the order
     `init_params` draws their keys: the mixer's subtree, the FFN's (None for
-    an expert FFN, which models/moe.py declares), the two norms."""
+    an expert FFN, which models/moe.py declares), the two norms; a layer
+    without an FFN has neither `mlp` nor `ln2`."""
     c, d, m = config, config.d_model, MIXERS[mixer]
     dense = {
         "w_gate": Leaf((d, c.d_ff), ("embed", "mlp"), normal(proj_scale(c))),
@@ -390,6 +425,8 @@ def _layer_leaves(config: TransformerConfig, mixer: str, ffn: str) -> Dict:
     norms = {"ln1": ones((d,)), "ln2": ones((d,))}
     if c.norm_kind == "layer":
         norms.update(ln1_b=zeros((d,)), ln2_b=zeros((d,)))
+    if ffn == "none":
+        return {m.subtree: m.leaves(c), **{name: leaf for name, leaf in norms.items() if name.startswith("ln1")}}
     return {m.subtree: m.leaves(c), "mlp": dense if ffn == "dense" else None, **norms}
 
 
@@ -477,12 +514,15 @@ _dense_ffn.defvjp(_dense_ffn_fwd, _dense_ffn_bwd)
 
 def _ffn_half(x, layer_params, config, constrain, rules, mesh, ffn=None):
     """The second half of every layer, whatever its mixer: (x + FFN(ln2(x)),
-    router statistics or None).  `ffn`: "dense" or "experts" (None: experts
-    where the configuration has any)."""
+    router statistics or None).  `ffn`: "dense", "experts" (None: experts
+    where the configuration has any) or "none": the mixer's result goes on as
+    it is, and nothing is traced under `layer/mlp`."""
     c, dt = config, config.dtype
     router_stats = None
     if ffn is None:
         ffn = "dense" if c.n_experts is None else "experts"
+    if ffn == "none":
+        return x, None
     with jax.named_scope("layer/mlp"):
         h = stream_norm(c, x, layer_params, "ln2")
         if ffn == "experts":
@@ -609,7 +649,7 @@ def _run_layers_pipelined(
         raise ValueError(
             f"strategy 'pp' runs a homogeneous stack of {default} layers only: the "
             f"stages of a stack with layer_types ({', '.join(others)}) or ffn_types "
-            "(dense beside experts) would hold unequal "
+            "(dense beside experts, or none) would hold unequal "
             "layers, and a value one layer hands to a later one does not cross stages"
         )
     n_stages = mesh.shape[axis]

@@ -7,8 +7,13 @@ not defined.  Differentiable in both operands.
 
 Like attention, the form follows the platform a step is LOWERED for
 (`jax.lax.platform_dependent`), not the process's backend: the Pallas kernels
-(`ops/pallas/grouped_matmul.py`) for TPU when the shapes are tile-aligned,
-the XLA form below everywhere else.  Nothing selects between them.  For TPU
+(`ops/pallas/grouped_matmul.py`) for TPU when the shapes are ones they take
+(rows in multiples of 128; k and n each a multiple of 128, tiled, or a
+multiple of 8 from 128 to 2,048, as one whole block: Nemotron-3-Nano's 1856), the
+XLA form below everywhere else.  Nothing selects between them, and a shape
+the kernels refuse is never taken in silence: the XLA form then runs under the
+scope `REFUSED_SCOPE`, which a step lowered for TPU carries in its HLO, the
+shape is counted in `refused_shapes` and logged once.  For TPU
 the kernels were chosen over `jax.lax.ragged_dot` by measurement (PERF.md
 section 6, PR 26).  Off the TPU `ragged_dot` is no candidate: jax lowers it
 there to one masked dense matmul PER GROUP over all rows, G times the work
@@ -18,8 +23,18 @@ benchmark's toy size).
 
 from __future__ import annotations
 
+import logging
+from typing import Dict, Tuple
+
 import jax
 import jax.numpy as jnp
+
+logger = logging.getLogger(__name__)
+
+# (m, k, n) -> times a step was traced with a shape the kernels refuse, so
+# that on a TPU too it runs the XLA form; the scope those calls carry.
+refused_shapes: Dict[Tuple[int, int, int], int] = {}
+REFUSED_SCOPE = "grouped_matmul/kernels_refused"
 
 _XLA_TILE = 64  # rows per tile of the XLA form: at most m + (G - 1) * 64 rows are multiplied
 
@@ -84,7 +99,12 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> ja
     # imported here, as attention imports its kernels: a dense model's process never loads Pallas for this
     from ray_tpu.ops.pallas import grouped_matmul as kernels
 
-    if kernels.supported(lhs.shape[0], rhs.shape[1], rhs.shape[2]):
+    shape = (lhs.shape[0], rhs.shape[1], rhs.shape[2])
+    if kernels.supported(*shape):
         return jax.lax.platform_dependent(
             lhs, rhs, group_sizes, tpu=kernels.grouped_matmul, default=grouped_matmul_xla)
-    return grouped_matmul_xla(lhs, rhs, group_sizes)
+    if shape not in refused_shapes:
+        logger.warning("grouped_matmul: the TPU kernels refuse m, k, n = %s; the XLA form runs on every platform", shape)
+    refused_shapes[shape] = refused_shapes.get(shape, 0) + 1
+    with jax.named_scope(REFUSED_SCOPE):
+        return grouped_matmul_xla(lhs, rhs, group_sizes)

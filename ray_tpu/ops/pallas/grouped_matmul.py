@@ -13,7 +13,10 @@ The rows of `lhs` [m, k] are sorted into G consecutive groups of
 Started from the megablox kernels that ship with jax
 (`jax/experimental/pallas/ops/tpu/megablox/gmm.py`, Apache 2.0, The JAX
 Authors), cut to what the expert layer uses: no group offset, no
-accumulation into an existing output, tiles that divide k and n.  The idea is
+accumulation into an existing output, tiles that divide k and n (`_tile`: a
+power-of-two multiple of 128 where one above 128 divides, else the largest
+multiple of 128 that does, 2688 -> 896; a width that is no multiple of 128,
+1856, as ONE whole block).  The idea is
 theirs: the grid walks row tiles of `tm` rows; a tile that straddles a group
 boundary is visited once per group it holds, with a row mask, and the tile ->
 group map is computed from `group_sizes` in XLA and handed to the kernel as
@@ -46,21 +49,47 @@ from ray_tpu.ops.pallas.flash_attention import _pallas_call
 # 512-wide k or n tiles lose 10-13%, 128^3 is 11x slower; 2048-wide k does
 # not fit VMEM.
 _TM, _TK, _TN = 512, 1024, 1024
+# The dimensions that are no multiple of 128 and still go as ONE block
+# (Nemotron-3-Nano's expert width 1856 = 29 x 64: a block may be a whole
+# dimension whatever its size, and no smaller tile of it is a lane multiple):
+# whole sublane tiles of at least one lane tile, up to what VMEM holds.
+_WHOLE = range(128, 2048 + 1, 8)
+# What Mosaic gives a kernel unasked (16 MiB of the v5e's 128), and what the
+# kernels ask for when their blocks need more: PERF.md section 6, PR 44.
+_VMEM_DEFAULT, _VMEM_ASKED = 16 * 2 ** 20, 64 * 2 ** 20
 
 
 def _tile(dim: int, largest: int) -> int:
-    """The largest power-of-two multiple of 128 up to `largest` that divides dim."""
+    """The tile of one dimension (rows come in multiples of 128, `supported`,
+    so they take the first two rules alone).  The largest power-of-two multiple
+    of 128 up to `largest` that divides dim, where one above 128 does (every
+    width of OLMoE and Kimi Linear: 2048, 1024, 2304 -> 256); else the largest
+    multiple of 128 that does (2688 = 21 x 128 -> 896, where powers of two
+    find only the 128 that PR 26 timed at 11x slower); a dim that is no
+    multiple of 128, whole."""
     t = largest
     while t > 128 and dim % t:
         t //= 2
-    if dim % t:
-        raise ValueError(f"grouped matmul needs dimensions in multiples of 128, got {dim}")
-    return t
+    if t > 128 or dim == 128:
+        return t
+    if dim % 128 == 0:
+        return max(c for c in range(128, largest + 1, 128) if dim % c == 0)
+    if dim not in _WHOLE:
+        raise ValueError(f"grouped matmul needs dimensions in multiples of 128, or of 8 from {_WHOLE[0]} to {_WHOLE[-1]}, "
+                         f"got {dim}")
+    return dim
 
 
 def supported(m: int, k: int, n: int) -> bool:
     """Whether the kernels take these shapes (else the caller uses the XLA form)."""
-    return m % 128 == 0 and k % 128 == 0 and n % 128 == 0
+    return m % 128 == 0 and all(d % 128 == 0 or d in _WHOLE for d in (k, n))
+
+
+def _compiler_params(vmem_bytes: int):
+    """The grid's semantics, and a VMEM limit only where the blocks need more
+    than Mosaic's own (so the kernels of the accepted widths compile as they did)."""
+    limit = {} if vmem_bytes <= _VMEM_DEFAULT else {"vmem_limit_bytes": _VMEM_ASKED}
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", "arbitrary"), **limit)
 
 
 def _group_metadata(group_sizes: jax.Array, m: int, tm: int, visit_empty_groups: bool):
@@ -102,12 +131,13 @@ def _row_mask(step, group_offsets, group_ids, m_tile_ids, tm: int, width: int):
 
 
 def moe_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
-            transpose_rhs: bool = False) -> jax.Array:
+            transpose_rhs: bool = False, tiles=None) -> jax.Array:
     """lhs [m, k] x rhs [G, k, n] (or [G, n, k] with `transpose_rhs`) -> [m, n]
-    in lhs's dtype, accumulated in float32."""
+    in lhs's dtype, accumulated in float32.  `tiles`: (tm, tk, tn) in place of
+    the rule's, for the script that times candidates."""
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tm, tk, tn = _tile(m, _TM), _tile(k, _TK), _tile(n, _TN)
+    tm, tk, tn = tiles or (_tile(m, _TM), _tile(k, _TK), _tile(n, _TN))
     tiles_k = k // tk
     *metadata, n_steps = _group_metadata(group_sizes, m, tm, visit_empty_groups=False)
     contract = (((1,), (1,)), ((), ())) if transpose_rhs else (((1,), (0,)), ((), ()))
@@ -145,8 +175,9 @@ def moe_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
             grid=(n // tn, n_steps, tiles_k),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        # two buffers of each block in lhs's dtype, the float32 accumulator and the stored tile widened
+        compiler_params=_compiler_params(
+            2 * (tm * tk + tk * tn + tm * tn) * lhs.dtype.itemsize + 2 * tm * tn * 4),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=(lhs.size * (n // tn) + k * n * (m // tm + rhs.shape[0]) + m * n)
@@ -154,13 +185,13 @@ def moe_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
     )(*metadata, lhs, rhs)
 
 
-def moe_tgmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+def moe_tgmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *, tiles=None) -> jax.Array:
     """Per group g: lhs_g^T [k, rows_g] x rhs_g [rows_g, n] -> [G, k, n] in
-    rhs's dtype, accumulated in float32; zeros for an empty group."""
+    rhs's dtype, accumulated in float32; zeros for an empty group.  `tiles` as `moe_gmm`'s."""
     m, k = lhs.shape
     n = rhs.shape[1]
     n_groups = group_sizes.shape[0]
-    tm, tk, tn = _tile(m, _TM), _tile(k, _TK), _tile(n, _TN)
+    tm, tk, tn = tiles or (_tile(m, _TM), _tile(k, _TK), _tile(n, _TN))
     *metadata, n_steps = _group_metadata(group_sizes, m, tm, visit_empty_groups=True)
 
     def kernel(group_offsets, group_ids, m_tile_ids, lhs_ref, rhs_ref, out_ref, acc_ref):
@@ -205,8 +236,8 @@ def moe_tgmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Arra
             grid=(n // tn, k // tk, n_steps),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        # two buffers of each block and the float32 accumulator
+        compiler_params=_compiler_params(2 * (tm * tk + tm * tn + tk * tn) * lhs.dtype.itemsize + tk * tn * 4),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,
             bytes_accessed=(lhs.size * (n // tn) + rhs.size * (k // tk) + n_groups * k * n)

@@ -14,7 +14,8 @@ shapes alone.
 
 The recurrence, per batch row and per head h, with a state `H_t` of shape
 [P, N] (head size x state size), a positive step `dt_t`, a negative scalar
-`A` per head and one group of `B_t`, `C_t` in R^N shared by the heads:
+`A` per head and `B_t`, `C_t` in R^N, one group shared by all the heads or G
+groups, head h reading group `h // (H / G)`:
 
     H_t = exp(dt_t * A) * H_{t-1} + dt_t * x_t (outer) B_t
     y_t = H_t C_t + D * x_t
@@ -25,7 +26,8 @@ its running sum inside the chunk:
 
 - within a chunk, the quadratic masked form
   `y_t += sum_{s<=t} exp(cum_t - cum_s) * (C_t . B_s) * dt_s * x_s`:
-  one [chunk, chunk] product `C B^T` per chunk, one decay mask per head;
+  one [chunk, chunk] product `C B^T` per chunk and GROUP (not per head), one
+  decay mask per head;
 - each chunk's own contribution to the state at its end,
   `sum_s exp(cum_last - cum_s) * dt_s * x_s (outer) B_s`;
 - one `lax.scan` over the S / chunk chunk states, each decayed by
@@ -189,11 +191,14 @@ def ssd_chunked(
     """The selective scan of the module docstring, chunked.
 
     x [b, S, H, P]; dt [b, S, H] (after softplus, positive); A [H] (negative);
-    B, C [b, S, N] (one group); D [H].  Returns y [b, S, H, P] in x's dtype.
-    `chunk` (None = `CHUNK`) is cut to S when S is shorter; S must be a
-    multiple of it."""
+    B, C [b, S, N] (one group) or [b, S, G, N] (G groups, G dividing H); D
+    [H].  Returns y [b, S, H, P] in x's dtype.  `chunk` (None = `CHUNK`) is
+    cut to S when S is shorter; S must be a multiple of it."""
     b, s, h, p = x.shape
     n = B.shape[-1]
+    groups = B.shape[2] if B.ndim == 4 else None  # None: the one-group form, as it was before groups
+    if groups is not None and h % groups:
+        raise ValueError(f"ssd_chunked: {groups} groups of B and C do not divide {h} heads")
     chunk = min(chunk or CHUNK, s)
     if s % chunk:
         raise ValueError(f"ssd_chunked: sequence length {s} is not a multiple of the chunk {chunk}")
@@ -203,8 +208,15 @@ def ssd_chunked(
 
     with jax.named_scope("ssm/scan"):
         xc = x.reshape(b, nc, chunk, h, p)
-        Bc = B.reshape(b, nc, chunk, n)
-        Cc = C.reshape(b, nc, chunk, n)
+        Bc = B.reshape(b, nc, chunk, *B.shape[2:])
+        Cc = C.reshape(b, nc, chunk, *C.shape[2:])
+
+        def by_group(a):  # [b, c, l, h, ...] -> [b, c, l, G, h / G, ...]
+            return a.reshape(*a.shape[:3], groups, h // groups, *a.shape[4:])
+
+        def by_head(a):  # [b, c, G, h / G, ...] -> [b, c, h, ...]
+            return a.reshape(b, nc, h, *a.shape[4:])
+
         dtc = dt.astype(f32).reshape(b, nc, chunk, h)
         a = dtc * A.astype(f32)  # [b, c, l, h], <= 0
         cum = jnp.cumsum(a, axis=2)  # inclusive: cum_t = sum_{s<=t} a_s
@@ -215,14 +227,21 @@ def ssd_chunked(
         diff = cum_h[..., :, None] - cum_h[..., None, :]  # [b, c, h, t, s]
         causal = jnp.tril(jnp.ones((chunk, chunk), bool))
         decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
-        cb = jnp.einsum("bctn,bcsn->bcts", Cc, Bc, preferred_element_type=f32)
-        scores = (cb[:, :, None] * decay).astype(dtype)  # [b, c, h, t, s]
+        if groups is None:
+            cb = jnp.einsum("bctn,bcsn->bcts", Cc, Bc, preferred_element_type=f32)
+            scores = (cb[:, :, None] * decay).astype(dtype)  # [b, c, h, t, s]
+        else:  # once per group, broadcast over the group's heads
+            cb = jnp.einsum("bctgn,bcsgn->bcgts", Cc, Bc, preferred_element_type=f32)
+            scores = by_head(cb[:, :, :, None] * decay.reshape(b, nc, groups, h // groups, chunk, chunk)).astype(dtype)
         y = jnp.einsum("bchts,bcshp->bchtp", scores, dtx.astype(dtype), preferred_element_type=f32)
 
         # each chunk's contribution to the state at its own end: [b, c, h, p, n]
         to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # [b, c, l, h]
         dtx_end = (to_end[..., None] * dtx).astype(dtype)
-        states = jnp.einsum("bcshp,bcsn->bchpn", dtx_end, Bc, preferred_element_type=f32)
+        if groups is None:
+            states = jnp.einsum("bcshp,bcsn->bchpn", dtx_end, Bc, preferred_element_type=f32)
+        else:
+            states = by_head(jnp.einsum("bcsgjp,bcsgn->bcgjpn", by_group(dtx_end), Bc, preferred_element_type=f32))
 
         # the serial part: the state that enters each chunk
         chunk_decay = jnp.exp(cum[:, :, -1, :])  # [b, c, h]
@@ -237,7 +256,11 @@ def ssd_chunked(
         entering = entering.transpose(1, 0, 2, 3, 4)  # [b, c, h, p, n]
 
         # the entering state read out at every position of the chunk
-        read = jnp.einsum("bctn,bchpn->bchtp", Cc.astype(f32), entering, preferred_element_type=f32)
+        if groups is None:
+            read = jnp.einsum("bctn,bchpn->bchtp", Cc.astype(f32), entering, preferred_element_type=f32)
+        else:
+            read = by_head(jnp.einsum("bctgn,bcgjpn->bcgjtp", Cc.astype(f32),
+                                      entering.reshape(b, nc, groups, h // groups, p, n), preferred_element_type=f32))
         y = y + jnp.exp(cum_h)[..., None] * read
         y = y + D.astype(f32)[:, None, None] * xc.astype(f32).transpose(0, 1, 3, 2, 4)
         # summed and rounded in the matmuls' own order; the relayout moves the ROUNDED array (module docstring)

@@ -19,8 +19,11 @@ Always on, whether `RAY_TPU_TRACE` is set or not:
     driver's `on_report`, per report;
   * step counters: the newest values of the step metrics a training context
     names as counters (`LMTrainContext`: the `moe_held_rows_*` of a layer
-    that holds a share of its experts), noted at every step without a
-    fetch and read by the worker's `poll` once the device has them.
+    that holds a share of its experts, `moe_load_max_over_mean`), noted at
+    every step without a fetch and read by the worker's `poll` once the
+    device has them; and their values step by step (`step_counter_series`,
+    the last `SERIES_KEPT` steps), so that a reader can take the rows the
+    experts were given in the very steps a profile covers.
 
 `JaxTrainer.fit` attaches the record to `Result.run_record`; the newest
 stays readable through `last_run_record()` after `ray_tpu.shutdown()`, with
@@ -163,13 +166,34 @@ def drain_stalls() -> List[Dict[str, Any]]:
 
 # -- worker side: step counters --------------------------------------------------
 
+SERIES_KEPT = 4096  # steps of the counters' series a record keeps, the newest
+
 _noted: Dict[str, Any] = {}  # name -> the newest step's value, still on the device
+_series: Deque[Any] = deque(maxlen=SERIES_KEPT)  # (step, {name: value}) not drained yet, oldest first
 
 
-def note_step_counters(values: Dict[str, Any]) -> None:
+def note_step_counters(values: Dict[str, Any], step: Optional[int] = None) -> None:
     """Keep the newest step's counters (device scalars; no fetch here: the
-    step that made them may still be running)."""
+    step that made them may still be running), and with `step`, the index
+    of the step that made them, their place in the series."""
     _noted.update(values)
+    if step is not None:
+        _series.append((step, dict(values)))
+
+
+def drain_step_series() -> List[List[Any]]:
+    """[step, {name: float}] of the steps the device has finished, in order;
+    the first unfinished step and those behind it wait for the next `poll`."""
+    out = []
+    while _series and all(map(_fetched, _series[0][1].values())):
+        step, values = _series.popleft()
+        out.append([step, {name: float(v) for name, v in values.items()}])
+    return out
+
+
+def _fetched(value: Any) -> bool:
+    """Whether the device has finished a noted value (a plain number always has)."""
+    return getattr(value, "is_ready", lambda: True)()
 
 
 def drain_step_counters() -> Dict[str, float]:
@@ -177,7 +201,7 @@ def drain_step_counters() -> Dict[str, float]:
     wait for the next `poll`."""
     out = {}
     for name, value in list(_noted.items()):
-        if getattr(value, "is_ready", lambda: True)():
+        if _fetched(value):
             out[name] = float(value)
             if _noted.get(name) is value:
                 del _noted[name]
@@ -298,6 +322,7 @@ class RunRecord:
         self.stalls: List[Dict[str, Any]] = []
         self.delivery_s: List[float] = []
         self.step_counters: Dict[str, float] = {}
+        self.step_series: Deque[List[Any]] = deque(maxlen=SERIES_KEPT)
         self.polls = 0
 
     def add_poll(self, rank: int, reply: Dict[str, Any]) -> None:
@@ -310,6 +335,7 @@ class RunRecord:
         for e in reply.get("stalls") or ():
             self.stalls.append(dict(e, rank=rank))
         self.step_counters.update(reply.get("step_counters") or {})
+        self.step_series.extend(reply.get("step_series") or ())
         for rep in reply["reports"]:
             if "t" in rep:
                 self.delivery_s.append(now - rep["t"])
@@ -329,6 +355,7 @@ class RunRecord:
                 tracing.lifecycle_spans(self.runtime_trace_id) if self.runtime_trace_id else ()),
             "stalls": list(self.stalls),
             "step_counters": dict(self.step_counters),
+            "step_counter_series": list(self.step_series),
             "reports": {"count": len(d), "polls": self.polls,
                         "median_s": statistics.median(d) if d else None,
                         "max_s": max(d) if d else None},

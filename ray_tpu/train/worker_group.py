@@ -82,7 +82,7 @@ class TrainWorker:
             spans = tracing.lifecycle_spans(ctx["trace_id"], since=self._spans_seen)
             self._spans_seen = recorded
         out = {"reports": [], "done": False, "spans": spans, "stalls": run_record.drain_stalls(),
-               "step_counters": run_record.drain_step_counters()}
+               "step_counters": run_record.drain_step_counters(), "step_series": run_record.drain_step_series()}
         if self.session is not None:
             out["reports"], out["done"] = self.session.drain(), self.session.done
         return out

@@ -373,7 +373,7 @@ def test_rows_behind_the_last_group_reach_no_output_and_no_gradient(monkeypatch)
         grouped.defvjp(fwd, bwd)
         monkeypatch.setattr(moe, "grouped_matmul", grouped)
         return jax.value_and_grad(
-            lambda t, g, w: jnp.sum(moe._experts(t, idx, g, *w, cfg.n_experts, first)[0] * ct), argnums=(0, 1, 2),
+            lambda t, g, w: jnp.sum(moe._experts(t, idx, g, w, cfg.n_experts, first)[0] * ct), argnums=(0, 1, 2),
         )(tokens, gates, local)
 
     want, got = with_behind(0.0), with_behind(1e3)
