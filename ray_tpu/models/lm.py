@@ -122,9 +122,10 @@ head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
 
 # The step counters of the run's record (train/run_record.py), among the step's metrics: the key tiles a
 # windowed flash forward visits; what a layer that holds a share of its experts was given (`models/moe.py`
-# `router_losses`: rows per held expert, mean and busiest, and the busiest expert's load over the mean).
+# `router_losses`: rows per held expert, mean and busiest, the busiest expert's load over the mean, and the share
+# of the T*K assignments whose rows the share's buffers moved).
 WINDOW_TILES = "attn_window_tiles_visited_pct"
-STEP_COUNTERS = (WINDOW_TILES, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean")
+STEP_COUNTERS = (WINDOW_TILES, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share")
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:

@@ -48,11 +48,20 @@ its K choices; the weights are `[n_experts_held, ...]`; the layer computes
 the held experts' part for the tokens routed to them, plus the shared
 expert, and what the absent experts would have added is left out.  That is
 `_experts(first_expert=...)`, the form a mesh's `expert` axis uses, without
-its `psum`: nothing stands in for the absent ranks.  `_experts` still sorts
-and gathers all T*K assignments (the rows of absent experts sort behind the
-last held group, where the grouped matmuls visit no tile); a dispatch sized
-by the held rows waits for a `perf_opt` (PERF.md section 7).  The rows each
-held expert got go out with the statistics (`held_rows`).
+its `psum`: nothing stands in for the absent ranks.  The rows each held
+expert got go out with the statistics (`held_rows`).
+
+A share moves the rows it holds, not all T*K (PR 48).  The sort is stable
+with this share's experts first, so the held rows are the first
+`sum(group_sizes)` positions of `order`, and everything behind the sorts (the
+gather of token rows, the gate values, the grouped matmuls, the activation,
+the way back to token order, and the backward of each) works on buffers of a
+static size R: `_rungs` is a short ladder of sizes from twice a uniform
+router's share up to T*K, and `lax.switch` takes the smallest that holds the
+count (`_sized_experts`).  The last rung is the all-experts code, a
+permutation of all T*K rows, so no assignment is ever dropped and the layer
+is the same function at every count; the rung follows from the count alone.
+`rows_moved_share` (the rung over T*K) goes out with the statistics.
 
 The gate value of an assignment scales its row where the row is `d_ff` wide,
 BEFORE the down projection, and not the `d_model`-wide row that comes out of
@@ -223,6 +232,67 @@ def _permuted_bwd(res, g):
 _permuted.defvjp(_permuted_fwd, _permuted_bwd)
 
 
+# -- a share's movements: R rows, of which the first `held` are held ----------------
+#
+# The same pair for a buffer of R <= T*K rows in expert order.  Into expert
+# order is a gather of R rows.  Back is NOT the gather by `inverse` (T*K rows
+# whatever is held): the R rows are gathered into TOKEN order (a sort of R
+# assignments), a token's at most k rows, now neighbours, are added, and each token
+# fetches its sum from its first row: two gathers of R and T rows and one
+# pass, no scatter (scripts/moe_dispatch_check.py times the forms; PERF.md
+# section 6, PR 48).  Rows behind `held` read as zero on both ways, whatever
+# they hold (a grouped matmul writes nothing defined there).
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _rows_of_tokens(tokens, order, inverse, held, k, r):
+    """tokens [T, D] -> rows [r, D]: row i < held is the token of the i-th
+    assignment in expert order, the rows behind are zero."""
+    i = jnp.arange(r, dtype=jnp.int32)
+    token = jnp.where(i < held, order[:r] // k, tokens.shape[0])
+    return tokens.at[token].get(mode="fill", fill_value=0)
+
+
+def _rows_of_tokens_fwd(tokens, order, inverse, held, k, r):
+    return _rows_of_tokens(tokens, order, inverse, held, k, r), (order, inverse, held)
+
+
+def _rows_of_tokens_bwd(k, r, res, g):
+    return _tokens_of_rows(g, *res, k, r), None, None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _tokens_of_rows(rows, order, inverse, held, k, r):
+    """rows [r, D] in expert order -> [T, D]: the rows below `held` of each
+    token, added in float32."""
+    n_tokens = order.shape[0] // k
+    i = jnp.arange(r, dtype=jnp.int32)
+    # by assignment, t * k + j: token order, a token's rows in the order of its choices (the order the sum
+    # over K adds them in); the rows behind the count sort last
+    assignment, perm = jax.lax.sort((jnp.where(i < held, order[:r], order.shape[0]), i), num_keys=1)
+    ids = assignment // k
+    # k - 1 rows more, so that every row has k - 1 neighbours behind it
+    ids_behind = jnp.concatenate([ids, jnp.full((k - 1,), -1, jnp.int32)])
+    in_token_order = rows[jnp.concatenate([perm, jnp.zeros((k - 1,), jnp.int32)])]
+    total = sum(jnp.where((ids_behind[j:j + r] == ids)[:, None], in_token_order[j:j + r], 0).astype(jnp.float32)
+                for j in range(k)).astype(rows.dtype)  # at a token's FIRST row: the sum of its rows
+    n_rows = jnp.sum(inverse.reshape(n_tokens, k) < held, axis=1, dtype=jnp.int32)  # [T]: rows held of each token
+    first = jnp.cumsum(n_rows) - n_rows
+    return total.at[jnp.where(n_rows > 0, first, r)].get(mode="fill", fill_value=0)
+
+
+def _tokens_of_rows_fwd(rows, order, inverse, held, k, r):
+    return _tokens_of_rows(rows, order, inverse, held, k, r), (order, inverse, held)
+
+
+def _tokens_of_rows_bwd(k, r, res, g):
+    return _rows_of_tokens(g, *res, k, r), None, None, None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+
+
 # -- the layer ---------------------------------------------------------------------
 
 
@@ -263,41 +333,130 @@ def _activation(h, into, matmul):
     return jax.nn.silu(matmul(h, into[0])) * matmul(h, into[1])
 
 
+def _by_expert(flat, n_local):
+    """The assignments `flat` [T*K] (expert of each, this share's first) in
+    expert order: (`order`, its inverse permutation, the rows of each of the
+    first `n_local` experts)."""
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    # (a sort too: `.at[order].set(iota)` is a scatter, 0.3 ms a call on the v5e against 0.07)
+    inverse = jnp.argsort(order, stable=False).astype(jnp.int32)
+    # (a one-hot sum: `bincount` is a scatter-add, 0.6 ms a call on the v5e)
+    group_sizes = jnp.sum(jax.nn.one_hot(flat, n_local, dtype=jnp.int32), axis=0)
+    return order, inverse, group_sizes
+
+
 def _experts(tokens, expert_idx, gates, weights, n_experts, first_expert=None):
     """Dispatch, grouped matmuls and combine for the experts `first_expert ..
     first_expert + weights[0].shape[0]` of `n_experts` (None: all of them, on
     one device).  tokens [T, D], expert_idx / gates [T, K], `weights` the
     experts' two or three matrices (`expert_leaves`); returns (those
-    experts' part of the output, [T, D]; the rows each of them got, int32).  The gate values go to their rows in
+    experts' part of the output, [T, D]; the rows each of them got, int32;
+    the share of the T*K assignments whose rows were moved, 1.0 with all
+    experts).  The gate values go to their rows in
     expert order and multiply them in the pass that makes the activation,
     so nothing behind `w_down` is a residual of the backward (module
-    docstring).  Assignments to other experts sort behind the last group,
-    where a grouped matmul writes nothing defined: those rows and their gate
-    values are zeroed going in (which zeroes their gradients coming back),
-    the rows also coming out."""
+    docstring).  A share of the experts goes by `_sized_experts`."""
     k = expert_idx.shape[1]
     n_local = weights[0].shape[0]
+    if first_expert is not None:
+        with jax.named_scope("moe/dispatch"):
+            flat = (expert_idx.reshape(-1) - first_expert) % n_experts  # this rank's experts first
+            order, inverse, group_sizes = _by_expert(flat, n_local)
+            g_sorted = _permuted(gates.reshape(-1), inverse, order)
+        rungs = _rungs(flat.shape[0], n_local, n_experts)
+        # the smallest rung that holds this share's rows
+        rung = jnp.sum(jnp.sum(group_sizes) > jnp.asarray(rungs[:-1], jnp.int32), dtype=jnp.int32)
+        out = _sized_experts(tokens, g_sorted, tuple(weights), order, inverse, group_sizes, rung, k, rungs)
+        return out, group_sizes, jnp.asarray(rungs, jnp.float32)[rung] / flat.shape[0]
     with jax.named_scope("moe/dispatch"):
-        flat = expert_idx.reshape(-1)
-        if first_expert is not None:
-            flat = (flat - first_expert) % n_experts  # this rank's experts first
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        # (a sort too: `.at[order].set(iota)` is a scatter, 0.3 ms a call on the v5e against 0.07)
-        inverse = jnp.argsort(order, stable=False).astype(jnp.int32)
-        # (a one-hot sum: `bincount` is a scatter-add, 0.6 ms a call on the v5e)
-        group_sizes = jnp.sum(jax.nn.one_hot(flat, n_local, dtype=jnp.int32), axis=0)
+        order, inverse, group_sizes = _by_expert(expert_idx.reshape(-1), n_local)
         rows = _to_expert_order(tokens, order, inverse, k)
         g_row = _permuted(gates.reshape(-1), inverse, order).astype(rows.dtype)[:, None]
-        if first_expert is not None:
-            mine = (jnp.arange(rows.shape[0], dtype=jnp.int32) < jnp.sum(group_sizes))[:, None]
-            rows, g_row = jnp.where(mine, rows, 0), jnp.where(mine, g_row, 0)
     with jax.named_scope("moe/experts"):
         hidden = _activation(rows, weights[:-1], lambda h, w: grouped_matmul(h, w, group_sizes))
         out = grouped_matmul(hidden * g_row, weights[-1], group_sizes)
     with jax.named_scope("moe/combine"):
-        if first_expert is not None:
-            out = jnp.where(mine, out, 0)
-        return _to_token_order(out, order, inverse, k), group_sizes
+        return _to_token_order(out, order, inverse, k), group_sizes, 1.0
+
+
+# -- a share of the experts: buffers sized by the rows held ------------------------
+
+_ROW_TILE = 512  # the grouped-matmul kernels' row tile (`ops/pallas/grouped_matmul.py` `_TM`): a rung is whole tiles
+_RUNGS = 4  # at most: every rung is traced, lowered and compiled (PERF.md section 6, PR 48: what a rung costs `setup_s`)
+
+
+def _rungs(assignments: int, n_local: int, n_experts: int) -> Tuple[int, ...]:
+    """The static row counts a share's buffers may have, ascending.  The
+    first is twice a uniform router's share of the T*K `assignments` (so a
+    balanced router, and one out of balance by up to 2, stays in it), in
+    whole row tiles; each next twice the last; the last is T*K itself."""
+    rung = -(-2 * assignments * n_local // n_experts // _ROW_TILE) * _ROW_TILE
+    rungs = []
+    while rung < assignments and len(rungs) < _RUNGS - 1:
+        rungs.append(rung)
+        rung *= 2
+    return (*rungs, assignments)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _rung_forward(r, k, tokens, g_sorted, weights, order, inverse, group_sizes):
+    """The share's part of the output, [T, D], on buffers of `r` rows (at
+    least the rows `group_sizes` hold).  `g_sorted` [T*K] float32: the gate
+    values in expert order.  Assignments to other experts sort behind the
+    last group, where a grouped matmul writes nothing defined: those rows and
+    their gate values are zeroed going in (which zeroes their gradients
+    coming back), the rows also coming out.  `r` = T*K is the all-experts
+    movement, a permutation."""
+    held = jnp.sum(group_sizes)
+    whole = r == order.shape[0]
+    with jax.named_scope("moe/dispatch"):
+        mine = (jnp.arange(r, dtype=jnp.int32) < held)[:, None]
+        if whole:
+            rows = jnp.where(mine, _to_expert_order(tokens, order, inverse, k), 0)
+        else:
+            rows = _rows_of_tokens(tokens, order, inverse, held, k, r)
+        g_row = jnp.where(mine, g_sorted[:r].astype(rows.dtype)[:, None], 0)
+    with jax.named_scope("moe/experts"):
+        hidden = _activation(rows, weights[:-1], lambda h, w: grouped_matmul(h, w, group_sizes))
+        out = grouped_matmul(hidden * g_row, weights[-1], group_sizes)
+    with jax.named_scope("moe/combine"):
+        if whole:
+            return _to_token_order(jnp.where(mine, out, 0), order, inverse, k)
+        return _tokens_of_rows(out, order, inverse, held, k, r)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _sized_experts(tokens, g_sorted, weights, order, inverse, group_sizes, rung, k, rungs):
+    """`_rung_forward` at `rungs[rung]` rows, the smallest of `rungs` that
+    holds the rows of `group_sizes`.  One `custom_vjp` around the switch:
+    differentiated by jax, a switch keeps every branch's residuals, those of
+    the rungs not taken as zeros of their full size; here the backward is a
+    switch of its own on the same rung, each branch the vjp of its forward
+    from the layer's inputs (the activation runs again in it, as it does
+    under `qkv_attn` whatever the form)."""
+    branches = [functools.partial(_rung_forward, r, k) for r in rungs]
+    return jax.lax.switch(rung, branches, tokens, g_sorted, weights, order, inverse, group_sizes)
+
+
+def _sized_experts_fwd(tokens, g_sorted, weights, order, inverse, group_sizes, rung, k, rungs):
+    args = (tokens, g_sorted, weights, order, inverse, group_sizes)
+    return _sized_experts(*args, rung, k, rungs), (args, rung)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _rung_backward(r, k, tokens, g_sorted, weights, order, inverse, group_sizes, g):
+    """The cotangents of tokens, gate values and weights for `g`, that of `_rung_forward`'s output."""
+    forward = functools.partial(_rung_forward, r, k, order=order, inverse=inverse, group_sizes=group_sizes)
+    return jax.vjp(forward, tokens, g_sorted, weights)[1](g)
+
+
+def _sized_experts_bwd(k, rungs, res, g):
+    args, rung = res
+    branches = [functools.partial(_rung_backward, r, k) for r in rungs]
+    return (*jax.lax.switch(rung, branches, *args, g), None, None, None, None)
+
+
+_sized_experts.defvjp(_sized_experts_fwd, _sized_experts_bwd)
 
 
 def moe_ffn(
@@ -310,7 +469,7 @@ def moe_ffn(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x [B, S, D] (the normed hidden state) -> (y [B, S, D], this layer's
     router statistics: `choice_share` [K, E], `mean_prob` [E], `z` [], and
-    with held experts `held_rows` [n_experts_held]).  `_ffn_half` calls it
+    with held experts `held_rows` [n_experts_held] and `rows_moved_share` []).  `_ffn_half` calls it
     inside its `layer/mlp` scope (PERF.md section 3)."""
     B, S, D = x.shape
     held = config.n_experts_held is not None
@@ -335,11 +494,11 @@ def moe_ffn(
         first = config.first_expert_held if held else None
         if expert_ax is not None:
             first = jax.lax.axis_index(expert_ax) * weights[0].shape[0]
-        y, rows = _experts(xb.reshape(b * S, D), idx.reshape(b * S, -1), g.reshape(b * S, -1),
-                           weights, config.n_experts, first)
+        y, rows, moved = _experts(xb.reshape(b * S, D), idx.reshape(b * S, -1), g.reshape(b * S, -1),
+                                  weights, config.n_experts, first)
         if expert_ax is not None:
             y = jax.lax.psum(y, expert_ax)
-        return y.reshape(b, S, D), rows
+        return y.reshape(b, S, D), rows, moved
 
     def with_shared(y):
         if not config.shared_expert_width:
@@ -350,9 +509,10 @@ def moe_ffn(
             return y + jnp.einsum("bsf,fe->bse", hidden, w[-1])
 
     if not across_devices:
-        y, rows = body(x, expert_idx, gates, *weights)
+        y, rows, moved = body(x, expert_idx, gates, *weights)
         if held:
             stats["held_rows"] = rows.astype(jnp.float32)
+            stats["rows_moved_share"] = moved
         return with_shared(y), stats
     tok_spec = _fit_spec(x.shape, logical_to_spec(("act_batch", None, None), rules), mesh)
     w_spec = P(expert_ax, None, None)
@@ -382,4 +542,6 @@ def router_losses(stats: Dict[str, jax.Array], config: Any) -> Dict[str, jax.Arr
         # and layers, and the busiest held expert of any layer
         out["moe_held_rows_mean"] = jnp.mean(stats["held_rows"])
         out["moe_held_rows_max"] = jnp.max(stats["held_rows"])
+        # rows of the rung the share's buffers took over the T*K assignments, mean over layers (1.0: all were moved)
+        out["moe_rows_moved_share"] = jnp.mean(stats["rows_moved_share"])
     return out

@@ -450,14 +450,17 @@ SAMBAY = dict(
 FAMILIES = {"dense": {}, "expert": EXPERT, "granite": dict(BASE, max_seq_len=128), "kimi": KIMI, "sambay": SAMBAY}
 
 
-@pytest.mark.parametrize("family, equations", [("dense", 700), ("expert", 2894), ("granite", 1977), ("kimi", 15438),
+@pytest.mark.parametrize("family, equations", [("dense", 700), ("expert", 2894), ("granite", 1977), ("kimi", 17451),
                                                ("sambay", 4642)])
 def test_a_dense_and_an_expert_step_trace_to_the_parents_program(family, equations):
     """Counted at the parent of PR 30 with this function (the dense count is
     `tests/test_moe_model.py`'s 709 + 1 - 10; 710 / 2904 before PR 34's
     `head_cross_entropy`): the new fields' defaults add no equation, no slice
     of the stack and nothing of the scan.  The three hybrid steps were counted
-    at the parent of PR 43, before a kind of mixer became one record."""
+    at the parent of PR 43, before a kind of mixer became one record; `kimi`,
+    which holds a SHARE of its experts, again at PR 48 (15,438 before it: the
+    share's block is now `moe._sized_experts`, whose backward traces the
+    rung's forward again; the all-experts step, `expert`, did not move)."""
     ctx = one_device_ctx(TransformerConfig.tiny(**FAMILIES[family]))
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)

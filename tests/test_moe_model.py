@@ -338,6 +338,38 @@ def test_ep_train_step_equals_the_single_device_step(tiny, ep_ctx):
         np.testing.assert_allclose(float(got[key]), float(want[key]), rtol=2e-4, err_msg=key)
 
 
+@pytest.mark.parametrize("routing", ["to-one-rank", "leaning"])
+def test_ranks_of_the_expert_axis_in_different_rungs_equal_the_single_device_layer(ep_ctx, small_rungs, routing):
+    """Under `shard_map` each rank of the `expert` axis sizes its buffers by
+    ITS count, so the switch's index differs by rank (PR 48): 64 tokens a
+    data shard, 2 choices, 4 ranks of 2 experts, rungs of 64 and 128 rows.
+    With every token choosing experts 0 and 1, rank 0 holds all 128
+    assignments (the top rung) and the others none (the lowest); with only
+    the FIRST choice fixed rank 0 holds over half and the others share the
+    rest.  Output and every gradient equal the layer's with all experts on
+    one device."""
+    cfg = ep_ctx.config
+    assert moe._rungs(64 * 2, 2, 8) == (64, 128)
+    params = moe.init_moe_params(cfg, jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, cfg.d_model))
+    x = x.at[..., 0].set(4.0)  # one direction every token has: the router's row 0 decides
+    params["router"] = params["router"].at[0].set(jnp.zeros(8).at[:2 if routing == "to-one-rank" else 1].set(8.0))
+    idx = moe._route(params, x.reshape(-1, cfg.d_model), cfg)[0].reshape(2, 128)  # [data shard, assignments]
+    rows = np.stack([np.sum(np.asarray(idx) // 2 == rank, axis=1) for rank in range(4)])  # [rank, data shard]
+    rungs_taken = {int(np.searchsorted([64, 128], count)) for count in rows.reshape(-1)}
+    assert rungs_taken == {0, 1}
+    if routing == "to-one-rank":
+        assert rows[0].tolist() == [128, 128] and not rows[1:].any()
+    ct = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+
+    def value_and_grads(**where):
+        return jax.jit(jax.value_and_grad(lambda p, v: jnp.sum(moe.moe_ffn(p, v, cfg, **where)[0] * ct), argnums=(0, 1)))(params, x)
+
+    want, got = value_and_grads(), value_and_grads(rules=ep_ctx.rules, mesh=ep_ctx.mesh)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-5)
+
+
 def test_rows_behind_the_last_group_reach_no_output_and_no_gradient(monkeypatch):
     """A rank of the `expert` axis computes its own experts' groups, and a
     grouped matmul defines nothing behind the last of them (the XLA form
@@ -397,6 +429,132 @@ def test_every_moment_leaf_has_its_parameters_sharding_under_ep(ep_ctx):
         assert leaf.sharding.is_equivalent_to(params[tail], leaf.ndim), (path, leaf.sharding, params[tail])
         expert_sharded += "expert" in str(leaf.sharding.spec)
     assert moments == 2 * len(params) and expert_sharded == 2 * 4  # mu and nu of router, w_gate, w_up, w_down
+
+
+# -- a share of the experts: buffers sized by the rows held (PR 48) ---------------------------
+
+SHARE = dict(tokens=64, d=32, width=16, k=4, experts=16, held=2)  # T*K = 256 assignments; row tile 8: rungs 64, 128, 256
+COUNTS = {"none": 0, "one": 1, "edge": 64, "edge+1": 65, "all": 256}
+
+
+@pytest.fixture
+def small_rungs(monkeypatch):
+    """The ladder at the tests' sizes: a row tile of 8 where the kernels' is 512 (steered here, not by an option)."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    rungs = moe._rungs(SHARE["tokens"] * SHARE["k"], SHARE["held"], SHARE["experts"])
+    assert rungs == (64, 128, 256)
+    return rungs
+
+
+def _share_case(kind, rows_held, seed=0):
+    """Tokens, a routing with exactly `rows_held` assignments to the held experts, gate values and one share's weights."""
+    c, rng = SHARE, np.random.default_rng(seed)
+    n = c["tokens"] * c["k"]
+    flat = rng.integers(c["held"], c["experts"], size=n)
+    flat[rng.permutation(n)[:rows_held]] = rng.integers(0, c["held"], size=rows_held)
+    into = 1 if kind == "relu2" else 2
+    weights = [jnp.asarray(0.2 * rng.standard_normal((c["held"], c["d"], c["width"])), jnp.float32) for _ in range(into)]
+    weights.append(jnp.asarray(0.2 * rng.standard_normal((c["held"], c["width"], c["d"])), jnp.float32))
+    return (jnp.asarray(rng.standard_normal((c["tokens"], c["d"])), jnp.float32), jnp.asarray(flat.reshape(-1, c["k"]), jnp.int32),
+            jnp.asarray(rng.random((c["tokens"], c["k"])), jnp.float32), weights)
+
+
+def _share_value_and_grads(tokens, idx, gates, weights, wrap):
+    def objective(t, g, w):
+        y, rows, moved = moe._experts(t, idx, g, w, SHARE["experts"], 0)
+        return jnp.sum(jnp.sin(y)), (y, rows, moved)
+
+    if wrap == "qkv_attn":
+        from ray_tpu.models.transformer import _remat_policy
+        objective = jax.checkpoint(objective, policy=_remat_policy(TransformerConfig(**dict(BASE, remat_policy="qkv_attn"))))
+    return jax.jit(jax.value_and_grad(objective, argnums=(0, 1, 2), has_aux=True))(tokens, gates, weights)
+
+
+@pytest.mark.parametrize("wrap", ["plain", "qkv_attn"])
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+@pytest.mark.parametrize("rows_held", COUNTS.values(), ids=COUNTS.keys())
+def test_a_share_sized_by_its_rows_equals_the_full_size_path(small_rungs, monkeypatch, rows_held, kind, wrap):
+    """Output and every gradient (tokens, gate values, each expert matrix) of
+    the share on the rung its count picks, against the same share on ONE rung
+    of all T*K rows (the form before PR 48), at no row held, one, a rung's
+    edge, one more, and every assignment held (none dropped); both kinds of
+    expert; alone and inside a `jax.checkpoint` under `qkv_attn`'s policy."""
+    case = _share_case(kind, rows_held)
+    (_, (y, rows, moved)), grads = _share_value_and_grads(*case, wrap)
+    assert int(rows.sum()) == rows_held
+    assert float(moved) == next(r for r in small_rungs if r >= rows_held) / 256
+    monkeypatch.setattr(moe, "_rungs", lambda assignments, *_: (assignments,))
+    (_, (want_y, _, all_moved)), want_grads = _share_value_and_grads(*case, wrap)
+    assert float(all_moved) == 1.0
+    if rows_held:
+        assert all(float(jnp.abs(v).max()) > 1e-3 for v in jax.tree_util.tree_leaves((want_y, want_grads)))  # the held rows count
+    for got, want in zip(jax.tree_util.tree_leaves((y, grads)), jax.tree_util.tree_leaves((want_y, want_grads))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+SIZED_MOVEMENTS = {  # the module's form, the same written as plain indexing (rows behind `held` zero), the operand's shape
+    "rows_of_tokens": (lambda x, o, i, h: moe._rows_of_tokens(x, o, i, h, 4, 24),
+                       lambda x, o, i, h: jnp.where((jnp.arange(24) < h)[:, None], x[o[:24] // 4], 0), (16, 8)),
+    "tokens_of_rows": (lambda x, o, i, h: moe._tokens_of_rows(x, o, i, h, 4, 24),
+                       lambda x, o, i, h: jnp.zeros((16, 8)).at[o[:24] // 4].add(jnp.where((jnp.arange(24) < h)[:, None], x, 0)),
+                       (24, 8)),
+}
+
+
+@pytest.mark.parametrize("held", [0, 1, 17, 24])
+@pytest.mark.parametrize("name", SIZED_MOVEMENTS)
+def test_each_sized_movement_and_its_declared_transpose_equal_plain_indexing(name, held):
+    """24 rows of the 64 assignments, of which `held` count: value and
+    gradient are those of the masked gather and of the scatter-add it
+    transposes to, whatever the rows behind the count hold."""
+    ours, plain, shape = SIZED_MOVEMENTS[name]
+    rng = np.random.default_rng(0)
+    order = jnp.asarray(rng.permutation(64), jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal(plain(x, order, inverse, held).shape), jnp.float32)
+    for f in (lambda x, g: g(x, order, inverse, held),
+              lambda x, g: jax.grad(lambda v: jnp.sum(g(v, order, inverse, held) * ct))(x)):
+        np.testing.assert_allclose(np.asarray(f(x, ours)), np.asarray(f(x, plain)), rtol=1e-6, atol=1e-6)
+
+
+def test_the_ladder_starts_at_twice_the_uniform_share_and_has_at_most_four_rungs():
+    """The ladder at the two cells' sizes and at a thin share: twice the
+    uniform share in whole row tiles, doubling, T*K last, four at most (that a
+    count takes the first rung that holds it: `moved` in the test above)."""
+    assert moe._rungs(131072, 16, 256) == (16384, 32768, 65536, 131072)  # kimi-linear-ep16-1chip.seq16k
+    assert moe._rungs(49152, 16, 128) == (12288, 24576, 49152)  # nemotron3-nano-ep8-1chip.seq8k
+    assert moe._rungs(65536, 1, 256) == (512, 1024, 2048, 65536)  # never more than four, the last all T*K
+    assert moe._rungs(128, 2, 8) == (128,)  # under one tile: one rung, no switch
+
+
+def test_the_all_experts_form_holds_no_switch(small_rungs):
+    """The switch is the share's alone: the layer with all its experts lowers
+    to no `case` / `conditional` (its program is the parent's), a share to one."""
+    tokens, idx, gates, weights = _share_case("swiglu", 40)
+    everywhere = [jnp.concatenate([w] * 8) for w in weights]  # 16 experts
+    lowered = lambda w, first: jax.jit(lambda t, g, w: moe._experts(t, idx, g, w, 16, first)[0]).lower(tokens, gates, w)  # noqa: E731
+    for text in (lowered(everywhere, None).as_text(), lowered(everywhere, None).compile().as_text()):
+        assert "case" not in text and "conditional" not in text
+    assert len(re.findall(r"stablehlo\.case", lowered(weights, 0).as_text())) == 1
+    assert len(re.findall(r" conditional\(", lowered(weights, 0).compile().as_text())) == 1
+
+
+def test_rows_moved_share_is_one_on_the_top_rung_and_the_lowest_rungs_share_when_nothing_is_held(small_rungs):
+    """`moe_rows_moved_share` of the step's terms, through `moe_ffn` and
+    `router_losses`: 4 of 16 experts held, 4 choices a token, 256 assignments,
+    rungs 128 and 256.  A router whose every choice is a held expert takes the
+    top rung (1.0), one whose choices are all elsewhere the lowest (0.5)."""
+    cfg = TransformerConfig(**dict(BASE, d_model=32, d_ff=16, n_experts=16, experts_per_token=4, n_experts_held=4))
+    assert moe._rungs(256, 4, 16) == (128, 256)
+    params = moe.init_moe_params(cfg, jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32)).at[..., 0].set(4.0)  # one direction every token has
+    for chosen, want_rows, want_share in (([0, 1, 2, 3], 64.0, 1.0), ([4, 5, 6, 7], 0.0, 0.5)):
+        router = params["router"].at[0].set(jnp.zeros(16).at[jnp.asarray(chosen)].set(8.0))
+        _, stats = moe.moe_ffn(dict(params, router=router), x, cfg)
+        terms = moe.router_losses(jax.tree_util.tree_map(lambda v: jnp.stack([v, v]), stats), cfg)  # two expert layers
+        assert float(terms["moe_held_rows_mean"]) == want_rows
+        assert float(terms["moe_rows_moved_share"]) == want_share
 
 
 # -- the grouped matmul: Pallas kernels (interpreted here) against the XLA form ----------------

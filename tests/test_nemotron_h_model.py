@@ -347,7 +347,7 @@ def test_the_eight_shares_of_the_experts_add_up_to_the_uncut_block(tiny):
 
 
 def test_what_the_experts_were_given_reaches_the_run_record(tiny):
-    """`moe_held_rows_*` and the ONE new counter, `moe_load_max_over_mean`, newest
+    """`moe_held_rows_*`, `moe_load_max_over_mean` and (PR 48) `moe_rows_moved_share`, newest
     value and step by step (what `relu2_experts_roofline` counts its rows from)."""
     ctx = LMTrainContext(tiny["cfg"], mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
     run_record.drain_step_counters(), run_record.drain_step_series()
@@ -357,7 +357,8 @@ def test_what_the_experts_were_given_reaches_the_run_record(tiny):
         state, metrics = ctx.train_step(state, batch)
     jax.block_until_ready(metrics)
     newest, series = run_record.drain_step_counters(), run_record.drain_step_series()
-    assert set(newest) == {"moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean"}
+    assert set(newest) == {"moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share"}
+    assert newest["moe_rows_moved_share"] == 1.0  # under one row tile of assignments: one rung, all T*K rows
     assert [step for step, _ in series] == [0, 1, 2] and series[-1][1] == newest
     tokens, k, total = tiny["tokens"].size, 3, 16
     assert 0 < newest["moe_held_rows_mean"] <= newest["moe_held_rows_max"] <= tokens

@@ -188,3 +188,30 @@ def test_the_selective_scan_kernel_compiles_at_the_phi4_cells_shapes(topo):
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
         got = [(s.shape, s.dtype) for s in jax.tree.leaves(compiled.out_info)]
         assert got == [((1, 8192, 5120), dtype), ((256, 1, 16, 5120), jnp.float32)]
+
+
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+def test_a_share_of_the_experts_compiles_with_one_switch_a_direction_of_at_most_four_rungs(topo, kind):
+    """The step of a model that holds 2 of its 16 experts (the two share cells
+    in small: three matrices an expert, and two), compiled for the v5e: the
+    share's row buffers take one of `moe._rungs`' sizes (PR 48), so the scan
+    body holds the forward's `conditional` and the backward's (the
+    recompute's is dead: its residuals are the layer's inputs), one branch a
+    rung, the grouped-matmul kernels in every branch and none refused."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops.grouped_matmul import REFUSED_SCOPE
+
+    rungs = moe._rungs(B * S * 2, 2, 16)
+    assert rungs == (512, 1024, 2048)
+    cfg = TransformerConfig.tiny(**COMMON, n_layers=2, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, moe_d_ff=128, n_experts=16,
+                                 experts_per_token=2, n_experts_held=2, n_shared_experts=1, expert_kind=kind)
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=topo.devices[:1]), strategy="dp")
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=ctx.batch_sharding)
+    with _no_compile_cache(), ctx.mesh:
+        text = ctx._train_step.lower(state, {"tokens": toks, "targets": toks}).compile().as_text()
+    switches = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}", text)
+    assert [len(branches.split(",")) for branches in switches] == [len(rungs)] * 2 and len(rungs) <= 4
+    per_rung = {"swiglu": 8 + 3, "relu2": 5 + 2}[kind]  # moe_gmm + moe_tgmm: forward, the activation again, both gradients
+    assert len(re.findall(r'custom_call_target="tpu_custom_call".*moe_t?gmm', text)) == per_rung * len(rungs)
+    assert REFUSED_SCOPE not in text

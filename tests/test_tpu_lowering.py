@@ -213,3 +213,45 @@ def test_s6_step_lowers_for_tpu_with_the_scan_kernel_inside_s6_scan(n_devices, s
 
 def test_s6_step_lowered_for_the_cpu_holds_no_kernel():
     assert "tpu_custom_call" not in _lowered_text(1, MeshSpec(data=1), "dp", cfg=S6)
+
+
+# A share of the experts in small, at widths the grouped-matmul kernels take: two attention + expert layers (one
+# run, one scan body), 2 of 16 experts held, 2,048 assignments a step: rungs of 512, 1,024 and all 2,048 rows.
+SHARE = dict(n_layers=2, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, moe_d_ff=128, max_seq_len=128, remat=True,
+             remat_policy="qkv_attn", n_experts=16, experts_per_token=2, n_experts_held=2, n_shared_experts=1)
+
+
+@pytest.mark.parametrize("kind,per_rung", [("swiglu", {"moe_gmm": 8, "moe_tgmm": 3}), ("relu2", {"moe_gmm": 5, "moe_tgmm": 2})])
+def test_a_share_of_the_experts_lowers_for_tpu_with_one_switch_a_direction(kind, per_rung):
+    """The two cells that hold a share (`kimi-linear-ep16-1chip.seq16k`: three
+    matrices; `nemotron3-nano-ep8-1chip.seq8k`: two) in small.  The share's
+    buffers take one of at most four static sizes (PR 48), so the scan body
+    holds TWO switches, the forward's and the backward's (under `qkv_attn` the
+    recompute's is dead code: its residuals are the layer's inputs), each
+    rung with the kernels the whole layer had: forward 3 (2) products,
+    again gate and up (up) in the backward, the 3 (2) transposed products and
+    the 3 (2) weight gradients.  No shape is refused to the XLA form."""
+    from ray_tpu.models import moe
+    from ray_tpu.ops.grouped_matmul import REFUSED_SCOPE
+
+    rungs = moe._rungs(8 * 128 * 2, 2, 16)
+    assert rungs == (512, 1024, 2048) and len(rungs) <= 4
+    cfg = TransformerConfig.tiny(**SHARE, expert_kind=kind)
+    text = _lowered_text(1, MeshSpec(data=1), "dp", platforms=("tpu",), cfg=cfg, debug_info=True)
+    kernels = _mosaic_kernels(text)
+    assert {name: kernels[name] for name in per_rung} == {name: n * len(rungs) for name, n in per_rung.items()}, kernels
+    assert REFUSED_SCOPE not in text
+
+    def switches(jaxpr):  # `cond`s of three branches (a `platform_dependent` has two), at any depth
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "cond" and len(eqn.params["branches"]) == len(rungs):
+                yield eqn
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) else [value]:
+                    if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                        yield from switches(getattr(sub, "jaxpr", sub))
+
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+    toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
+    step = jax.make_jaxpr(ctx._train_step)(jax.eval_shape(ctx._init, jax.random.PRNGKey(0)), {"tokens": toks, "targets": toks})
+    assert len(list(switches(step.jaxpr))) == 2
