@@ -99,7 +99,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     with jax.named_scope("layer/attn_core"):
         y = ssd_chunked(
             xs.reshape(*xs.shape[:2], heads, c.ssm_head_dim), step,
-            -jnp.exp(ssm["A_log"].astype(jnp.float32)), b_in, c_out, ssm["D"],
+            -jnp.exp(ssm["A_log"].astype(jnp.float32)), b_in, c_out, ssm["D"], **sharded,
         )
     with jax.named_scope("layer/attn_proj"):
         with jax.named_scope("ssm/conv"):

@@ -1,7 +1,8 @@
 """Mamba-2's selective state-space scan (SSD, arXiv:2405.21060) in its chunked
 form, and the causal depthwise convolution + SiLU that feeds it; no
-counterpart in the reference (SURVEY.md §5.7).  The scan is plain `jax.numpy`,
-differentiated by JAX.  The convolution is one `custom_vjp`
+counterpart in the reference (SURVEY.md §5.7).  The scan is one `custom_vjp`
+whose two directions are Pallas kernels for TPU and plain `jax.numpy`,
+differentiated by JAX, elsewhere (below).  The convolution is one `custom_vjp`
 (`causal_conv1d_silu`) with a backward written by hand: JAX's own transpose of
 K shifted multiply-adds is one full-size float32 cotangent array PER TAP (572
 MB written and read back a layer at the benchmark's [8192, 4352]), where the
@@ -43,23 +44,37 @@ Precision: `dt`, `dt * A`, the running sums, their exponentials and the chunk
 states are float32 whatever the inputs are; the matmul operands are the
 inputs' dtype (bf16 in a training cell) with float32 accumulation.
 
-The [B, S/chunk, H, chunk, chunk] decay masks and scores are written to HBM
-in this form (0.5 GB in float32 at one 8,192-token sequence, 64 heads and a
-chunk of 256).  A Pallas kernel that keeps them in VMEM is the first thing a
-`perf_opt` issue on the scan would write; `ssm/scan`, the scope around all of
-this, is what its gain would be read by (PERF.md section 3).  The scan's three
-terms are summed in the matmuls' own [b, c, h, t, p] order and rounded to the
-inputs' dtype THERE; the relayout to [b, S, h, p] moves the rounded array
+`ssd_chunked` is one `custom_vjp` at the shapes its kernels take (heads of 64
+or 32, states of whole lane tiles, chunks of whole 128-blocks, at least a lane
+tile of heads a group).  In a step lowered for TPU both directions are Mosaic
+kernels (`ops/pallas/ssd.py`: `ssd_fwd`, `ssd_bwd`, PR 49): `C B^T`, the decay
+masks, the scores and their cotangents, all [chunk, chunk], live in VMEM a
+head and chunk at a time, the pass over chunk states in a VMEM scratch along
+the grid's sequential axis, and what crosses HBM is x, y, B, C, their
+cotangents, the [b, S, H] arrays and the state that enters each chunk (67 MB a
+layer at one 8,192-token sequence of 64 heads of 64, state 128).  Everywhere
+else (the CPU, refused shapes) it is `_plain_forward` below, differentiated by
+JAX, where the [B, S/chunk, H, chunk, chunk] masks and scores are arrays of
+their own (0.5 GB in float32 and 0.27 GB in bf16 at those shapes, written and
+read back in each direction: `ssm/scan` took 7.2 ms a layer of which the
+kernels leave 3.7, PERF.md section 6, PR 49).  The plain form sums the scan's
+three terms in the matmuls' own [b, c, h, t, p] order and rounds to the
+inputs' dtype THERE; its relayout to [b, S, h, p] moves the rounded array
 behind an `optimization_barrier` (without it XLA hoists the consumer's
-float32 convert across the relayout and copies float32, twice).
+float32 convert across the relayout and copies float32, twice).  `ssm/scan`,
+the scope around either form, is what the benchmark reads the scan by
+(PERF.md section 3).
 
 Sharding: nothing here names a mesh axis.  The heads are not sharded over
 `tensor` (the model replicates the mixer's weights under `tp`); batch and
-fsdp sharding are GSPMD's to propagate through the einsums.
+fsdp sharding are GSPMD's to propagate through the plain form's einsums, and
+with a mesh the kernels run under `shard_map` over the batch axes, as the
+convolution's do.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -179,6 +194,151 @@ def causal_conv1d_silu(x: jax.Array, w: jax.Array, b: jax.Array, mesh=None, batc
     return jax.shard_map(_conv_silu_vjp, mesh=mesh, in_specs=(spec, P(), P()), out_specs=spec, check_vma=False)(x, w, b)
 
 
+def _plain_forward(x, dt, A, B, C, D, chunk: int):
+    """The chunked scan in plain `jax.numpy`, differentiated by JAX: the path
+    off TPU and at shapes the kernels refuse, and what the tests hold the
+    kernels to.  Its [B, S/chunk, H, chunk, chunk] decay masks and scores are
+    arrays of their own (HBM buffers, where XLA compiles it)."""
+    b, s, h, p = x.shape
+    n = B.shape[-1]
+    groups = B.shape[2] if B.ndim == 4 else None  # None: the one-group form, as it was before groups
+    nc = s // chunk
+    dtype = x.dtype
+    f32 = jnp.float32
+
+    xc = x.reshape(b, nc, chunk, h, p)
+    Bc = B.reshape(b, nc, chunk, *B.shape[2:])
+    Cc = C.reshape(b, nc, chunk, *C.shape[2:])
+
+    def by_group(a):  # [b, c, l, h, ...] -> [b, c, l, G, h / G, ...]
+        return a.reshape(*a.shape[:3], groups, h // groups, *a.shape[4:])
+
+    def by_head(a):  # [b, c, G, h / G, ...] -> [b, c, h, ...]
+        return a.reshape(b, nc, h, *a.shape[4:])
+
+    dtc, cum = _running_sums(dt, A, chunk)  # [b, c, l, h] float32: dt, and the running sum of dt * A (<= 0)
+    dtx = dtc[..., None] * xc.astype(f32)  # dt_s * x_s, float32
+
+    # within a chunk: (C B^T o L) (dt x), L[t, s] = exp(cum_t - cum_s) for s <= t
+    cum_h = cum.transpose(0, 1, 3, 2)  # [b, c, h, l]
+    diff = cum_h[..., :, None] - cum_h[..., None, :]  # [b, c, h, t, s]
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    if groups is None:
+        cb = jnp.einsum("bctn,bcsn->bcts", Cc, Bc, preferred_element_type=f32)
+        scores = (cb[:, :, None] * decay).astype(dtype)  # [b, c, h, t, s]
+    else:  # once per group, broadcast over the group's heads
+        cb = jnp.einsum("bctgn,bcsgn->bcgts", Cc, Bc, preferred_element_type=f32)
+        scores = by_head(cb[:, :, :, None] * decay.reshape(b, nc, groups, h // groups, chunk, chunk)).astype(dtype)
+    y = jnp.einsum("bchts,bcshp->bchtp", scores, dtx.astype(dtype), preferred_element_type=f32)
+
+    # each chunk's contribution to the state at its own end: [b, c, h, p, n]
+    to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # [b, c, l, h]
+    dtx_end = (to_end[..., None] * dtx).astype(dtype)
+    if groups is None:
+        states = jnp.einsum("bcshp,bcsn->bchpn", dtx_end, Bc, preferred_element_type=f32)
+    else:
+        states = by_head(jnp.einsum("bcsgjp,bcsgn->bcgjpn", by_group(dtx_end), Bc, preferred_element_type=f32))
+
+    # the serial part: the state that enters each chunk
+    chunk_decay = jnp.exp(cum[:, :, -1, :])  # [b, c, h]
+
+    def cross(carry, inp):
+        decay_c, state_c = inp
+        return carry * decay_c[..., None, None] + state_c, carry
+
+    _, entering = jax.lax.scan(
+        cross, jnp.zeros((b, h, p, n), f32),
+        (chunk_decay.transpose(1, 0, 2), states.transpose(1, 0, 2, 3, 4)))
+    entering = entering.transpose(1, 0, 2, 3, 4)  # [b, c, h, p, n]
+
+    # the entering state read out at every position of the chunk
+    if groups is None:
+        read = jnp.einsum("bctn,bchpn->bchtp", Cc.astype(f32), entering, preferred_element_type=f32)
+    else:
+        read = by_head(jnp.einsum("bctgn,bcgjpn->bcgjtp", Cc.astype(f32),
+                                  entering.reshape(b, nc, groups, h // groups, p, n), preferred_element_type=f32))
+    y = y + jnp.exp(cum_h)[..., None] * read
+    y = y + D.astype(f32)[:, None, None] * xc.astype(f32).transpose(0, 1, 3, 2, 4)
+    # summed and rounded in the matmuls' own order; the relayout moves the ROUNDED array (module docstring)
+    y = y.astype(dtype).transpose(0, 1, 3, 2, 4).reshape(b, s, h * p)
+    return jax.lax.optimization_barrier(y).reshape(b, s, h, p)
+
+
+def _running_sums(dt, A, chunk: int):
+    """(dt, the inclusive running sum of `dt * A` inside each chunk), both
+    [b, S / chunk, chunk, H] float32: the two per-(position, head) arrays
+    every form of the scan starts from."""
+    b, s, h = dt.shape
+    dtc = dt.astype(jnp.float32).reshape(b, s // chunk, chunk, h)
+    return dtc, jnp.cumsum(dtc * A.astype(jnp.float32), axis=2)  # cum_t = sum_{s<=t} a_s, a <= 0
+
+
+def _scan_kernels():
+    """`ops/pallas/ssd.py`, imported at first use like the other ops' kernels."""
+    from ray_tpu.ops.pallas import ssd
+
+    return ssd
+
+
+# Traced once a process, however many bodies of a loop over layers call them (PERF.md section 6, PR 48: `setup_trace_s`).
+@functools.partial(jax.jit, static_argnums=(6,))
+def _kernel_forward(x, dt, A, B, C, D, chunk: int):
+    """(y, the state that enters each chunk as `ssd_fwd` lays it out)."""
+    dtc, cum = _running_sums(dt, A, chunk)
+    return _scan_kernels().ssd_fwd(x, dtc.reshape(dt.shape), cum.reshape(dt.shape), B, C, D, chunk=chunk)
+
+
+@functools.partial(jax.jit, static_argnums=(8,))
+def _kernel_backward(x, dt, A, B, C, D, entering, dy, chunk: int):
+    """The six cotangents, each in its argument's dtype.  The kernel gives
+    those of x, B, C, D, of dt as a factor of `dt x`, and of the running sum
+    `cum`; `cum` is a running sum of `dt * A` inside each chunk, so its
+    cotangent runs back from each chunk's end and reaches dt through A and A
+    through dt."""
+    f32 = jnp.float32
+    dtc, cum = _running_sums(dt, A, chunk)
+    dx, d_dt, d_cum, dB, dC, dD = _scan_kernels().ssd_bwd(
+        x, dtc.reshape(dt.shape), cum.reshape(dt.shape), B, C, D, entering, dy, chunk=chunk)
+    d_a = jax.lax.cumsum(d_cum.reshape(dtc.shape), axis=2, reverse=True).reshape(dt.shape)
+    d_dt = d_dt + d_a * A.astype(f32)
+    d_A = jnp.sum(d_a * dt.astype(f32), axis=(0, 1))
+    return dx, d_dt.astype(dt.dtype), d_A.astype(A.dtype), dB.astype(B.dtype), dC.astype(C.dtype), dD.astype(D.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _ssd(x, dt, A, B, C, D, chunk: int):
+    return _ssd_fwd(x, dt, A, B, C, D, chunk)[0]
+
+
+def _ssd_fwd(x, dt, A, B, C, D, chunk: int):
+    """Like the convolution, the form follows the platform a step is LOWERED
+    for: the kernels for TPU, the plain form everywhere else (its backward is
+    JAX's own and starts from the arguments: zeros for the states, because
+    both branches of a dispatch return the same shapes)."""
+    b, s, h, p = x.shape
+
+    def plain(x, dt, A, B, C, D):
+        return _plain_forward(x, dt, A, B, C, D, chunk), jnp.zeros((b, s // chunk, B.shape[-1], h * p), jnp.float32)
+
+    with jax.named_scope("ssm/scan"):
+        kernel = functools.partial(_kernel_forward, chunk=chunk)
+        y, entering = jax.lax.platform_dependent(x, dt, A, B, C, D, tpu=kernel, default=plain)
+    return y, (x, dt, A, B, C, D, entering)
+
+
+def _ssd_bwd(chunk: int, res, dy):
+    def plain(x, dt, A, B, C, D, entering, dy):
+        return jax.vjp(functools.partial(_plain_forward, chunk=chunk), x, dt, A, B, C, D)[1](dy)
+
+    with jax.named_scope("ssm/scan"):
+        kernel = functools.partial(_kernel_backward, chunk=chunk)
+        return jax.lax.platform_dependent(*res, dy, tpu=kernel, default=plain)
+
+
+_ssd.defvjp(_ssd_fwd, _ssd_bwd)
+
+
 def ssd_chunked(
     x: jax.Array,
     dt: jax.Array,
@@ -187,82 +347,38 @@ def ssd_chunked(
     C: jax.Array,
     D: jax.Array,
     chunk: Optional[int] = None,
+    mesh=None,
+    batch_axes=None,
 ) -> jax.Array:
     """The selective scan of the module docstring, chunked.
 
     x [b, S, H, P]; dt [b, S, H] (after softplus, positive); A [H] (negative);
     B, C [b, S, N] (one group) or [b, S, G, N] (G groups, G dividing H); D
     [H].  Returns y [b, S, H, P] in x's dtype.  `chunk` (None = `CHUNK`) is
-    cut to S when S is shorter; S must be a multiple of it."""
+    cut to S when S is shorter; S must be a multiple of it.
+
+    At shapes the kernels take (`ops/pallas/ssd.py` `supported`) this is one
+    `custom_vjp` whose two directions are Mosaic kernels in a step lowered for
+    TPU; at every other shape the plain form, differentiated by JAX.
+
+    mesh / batch_axes say how the arguments are sharded (A and D are
+    replicated).  GSPMD partitions the plain form by itself; a Mosaic kernel it
+    cannot, so with a mesh the kernels run under shard_map over the batch axes,
+    each device on its own rows with the whole sequence and every head."""
     b, s, h, p = x.shape
-    n = B.shape[-1]
-    groups = B.shape[2] if B.ndim == 4 else None  # None: the one-group form, as it was before groups
+    groups = B.shape[2] if B.ndim == 4 else None
     if groups is not None and h % groups:
         raise ValueError(f"ssd_chunked: {groups} groups of B and C do not divide {h} heads")
     chunk = min(chunk or CHUNK, s)
     if s % chunk:
         raise ValueError(f"ssd_chunked: sequence length {s} is not a multiple of the chunk {chunk}")
-    nc = s // chunk
-    dtype = x.dtype
-    f32 = jnp.float32
-
-    with jax.named_scope("ssm/scan"):
-        xc = x.reshape(b, nc, chunk, h, p)
-        Bc = B.reshape(b, nc, chunk, *B.shape[2:])
-        Cc = C.reshape(b, nc, chunk, *C.shape[2:])
-
-        def by_group(a):  # [b, c, l, h, ...] -> [b, c, l, G, h / G, ...]
-            return a.reshape(*a.shape[:3], groups, h // groups, *a.shape[4:])
-
-        def by_head(a):  # [b, c, G, h / G, ...] -> [b, c, h, ...]
-            return a.reshape(b, nc, h, *a.shape[4:])
-
-        dtc = dt.astype(f32).reshape(b, nc, chunk, h)
-        a = dtc * A.astype(f32)  # [b, c, l, h], <= 0
-        cum = jnp.cumsum(a, axis=2)  # inclusive: cum_t = sum_{s<=t} a_s
-        dtx = dtc[..., None] * xc.astype(f32)  # dt_s * x_s, float32
-
-        # within a chunk: (C B^T o L) (dt x), L[t, s] = exp(cum_t - cum_s) for s <= t
-        cum_h = cum.transpose(0, 1, 3, 2)  # [b, c, h, l]
-        diff = cum_h[..., :, None] - cum_h[..., None, :]  # [b, c, h, t, s]
-        causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-        decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
-        if groups is None:
-            cb = jnp.einsum("bctn,bcsn->bcts", Cc, Bc, preferred_element_type=f32)
-            scores = (cb[:, :, None] * decay).astype(dtype)  # [b, c, h, t, s]
-        else:  # once per group, broadcast over the group's heads
-            cb = jnp.einsum("bctgn,bcsgn->bcgts", Cc, Bc, preferred_element_type=f32)
-            scores = by_head(cb[:, :, :, None] * decay.reshape(b, nc, groups, h // groups, chunk, chunk)).astype(dtype)
-        y = jnp.einsum("bchts,bcshp->bchtp", scores, dtx.astype(dtype), preferred_element_type=f32)
-
-        # each chunk's contribution to the state at its own end: [b, c, h, p, n]
-        to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # [b, c, l, h]
-        dtx_end = (to_end[..., None] * dtx).astype(dtype)
-        if groups is None:
-            states = jnp.einsum("bcshp,bcsn->bchpn", dtx_end, Bc, preferred_element_type=f32)
-        else:
-            states = by_head(jnp.einsum("bcsgjp,bcsgn->bcgjpn", by_group(dtx_end), Bc, preferred_element_type=f32))
-
-        # the serial part: the state that enters each chunk
-        chunk_decay = jnp.exp(cum[:, :, -1, :])  # [b, c, h]
-
-        def cross(carry, inp):
-            decay_c, state_c = inp
-            return carry * decay_c[..., None, None] + state_c, carry
-
-        _, entering = jax.lax.scan(
-            cross, jnp.zeros((b, h, p, n), f32),
-            (chunk_decay.transpose(1, 0, 2), states.transpose(1, 0, 2, 3, 4)))
-        entering = entering.transpose(1, 0, 2, 3, 4)  # [b, c, h, p, n]
-
-        # the entering state read out at every position of the chunk
-        if groups is None:
-            read = jnp.einsum("bctn,bchpn->bchtp", Cc.astype(f32), entering, preferred_element_type=f32)
-        else:
-            read = by_head(jnp.einsum("bctgn,bcgjpn->bcgjtp", Cc.astype(f32),
-                                      entering.reshape(b, nc, groups, h // groups, p, n), preferred_element_type=f32))
-        y = y + jnp.exp(cum_h)[..., None] * read
-        y = y + D.astype(f32)[:, None, None] * xc.astype(f32).transpose(0, 1, 3, 2, 4)
-        # summed and rounded in the matmuls' own order; the relayout moves the ROUNDED array (module docstring)
-        y = y.astype(dtype).transpose(0, 1, 3, 2, 4).reshape(b, s, h * p)
-        return jax.lax.optimization_barrier(y).reshape(b, s, h, p)
+    if not _scan_kernels().supported(h, p, B.shape[-1], groups or 1, s, chunk):
+        with jax.named_scope("ssm/scan"):
+            return _plain_forward(x, dt, A, B, C, D, chunk)
+    run = functools.partial(_ssd, chunk=chunk)  # names its scope INSIDE what shard_map wraps, whose body starts a name stack
+    if mesh is None:
+        return run(x, dt, A, B, C, D)
+    rows = _fit_spec(x.shape, P(batch_axes, None, None, None), mesh)
+    small, group = P(*rows[:3]), P(*rows[:B.ndim])
+    return jax.shard_map(run, mesh=mesh, in_specs=(rows, small, P(), group, group, P()), out_specs=rows,
+                         check_vma=False)(x, dt, A, B, C, D)

@@ -180,9 +180,11 @@ def test_lowered_hybrid_step_holds_each_flash_kernel_exactly_once(hybrid_lowered
 
 
 # What PR 31 added to the Mamba-2 layer, as (the op's own name, the scope it must sit in): the
-# convolution's kernels and the barrier that pins the scan's output to bf16 before its relayout.
-SSM_OPS = {"ssm_conv_fwd/pallas_call": "layer/attn_proj/ssm/conv", "ssm_conv_bwd/pallas_call": "layer/attn_proj/ssm/conv",
-           "optimization_barrier": "layer/attn_core/ssm/scan"}
+# convolution's kernels.  (The barrier that pinned the scan's output to bf16 before its relayout
+# went with the plain form, PR 49: the scan's kernels sit behind a `jax.jit` of their own, whose
+# ops carry the outer path only once compiled: `test_tpu_compiled_step.py` reads it there.)
+SSM_OPS = {"ssm_conv_fwd/pallas_call": "layer/attn_proj/ssm/conv", "ssm_conv_bwd/pallas_call": "layer/attn_proj/ssm/conv"}
+SSM_KERNELS = {"ssm_conv_fwd": 4, "ssm_conv_bwd": 2, "ssd_fwd": 4, "ssd_bwd": 2}  # calls of a compiled step
 
 
 @pytest.mark.parametrize("op", SSM_OPS)
@@ -197,6 +199,23 @@ def test_lowered_hybrid_step_keeps_the_mixers_new_ops_inside_its_scopes(hybrid_l
     assert paths and all(f"{SSM_OPS[op]}/" in path for path in paths)
     directions = {path.split("layer/")[0] for path in paths}
     assert directions >= ({"checkpoint/"} if "bwd" in op else {"", "checkpoint/rematted_computation/"})
+
+
+@pytest.mark.parametrize("kernel", SSM_KERNELS)
+def test_lowered_hybrid_step_holds_the_mixers_kernels_twice_forward_and_once_backward_a_run(hybrid_lowered_for_tpu, kernel):
+    """Two runs of Mamba-2 layers around the attention layer, as in
+    `granite-h-micro-1chip.seq8k`: a run's scan body holds each forward kernel
+    twice (forward, recompute) and each backward kernel once, so the step's
+    `tpu_custom_calls` (the `[bench] facts` line) is 3 + 6 + 6 = 15 there, 9
+    before the scan had kernels (PR 49); `nemotron3-nano-ep8-1chip.seq8k`,
+    whose four Mamba-2 blocks lie in three runs, went from 75 to 84."""
+    calls = [line for line in hybrid_lowered_for_tpu.splitlines() if "@tpu_custom_call" in line]
+    assert 3 + sum(SSM_KERNELS.values()) == 15
+    if kernel == "ssd_bwd":  # behind a `jax.jit` of its own: lowered once, called from each run's backward
+        assert sum(f'kernel_name = "{kernel}"' in line for line in calls) == 1
+        assert hybrid_lowered_for_tpu.count("call @_kernel_backward(") == SSM_KERNELS[kernel]
+    else:
+        assert sum(f'kernel_name = "{kernel}"' in line for line in calls) == SSM_KERNELS[kernel]
 
 
 # -- Kimi Linear's names (PERF.md section 3, PR 37) ------------------------------------------
@@ -290,11 +309,13 @@ def test_only_qkv_attn_keeps_a_mamba_layers_projections_out_of_the_recompute(rem
     scan's own forward and the FFN's `gate` + `up` + `down` are recomputed
     under every policy, and so is `ln1`, which `in_proj`'s weight gradient
     reads (`test_lowered_hybrid_step_names_the_mixers_regions...[ssm/proj]`).
-    The one attention layer recomputes `wo` alone when q, k, v are saved."""
+    The one attention layer recomputes `wo` alone when q, k, v are saved.
+    Since PR 49 the scan's recompute is the kernel `ssd_fwd`: none of the 8
+    `dot_general`s the plain form had under `ssm/scan` there is left."""
     lowered = _hybrid_step_lowered_for_tpu(remat_policy)
     counts = _recomputed_matmuls(lowered)
     assert counts["layer/attn_proj/ssm/proj"] == projections and counts["layer/attn_proj"] == attention
-    assert counts["layer/attn_core/ssm/scan"] == 8 and counts["layer/mlp"] == 6
+    assert counts["layer/attn_core/ssm/scan"] == 0 and counts["layer/mlp"] == 6
     assert '"checkpoint/rematted_computation/layer/attn_proj/ssm/proj/' in lowered
 
 

@@ -190,6 +190,60 @@ def test_the_selective_scan_kernel_compiles_at_the_phi4_cells_shapes(topo):
         assert got == [((1, 8192, 5120), dtype), ((256, 1, 16, 5120), jnp.float32)]
 
 
+def test_the_hybrid_steps_scan_is_two_kernels_under_ssm_scan_and_no_chunk_by_chunk_array_reaches_hbm(request, compiled_step):
+    """PR 49.  Compiled, the ops behind the scan's own `jax.jit`s carry the
+    whole path: `ssd_fwd` (forward and recompute) and `ssd_bwd` under
+    `layer/attn_core/ssm/scan`, where `benchmarks/lib/trace_ssm.py` reads them;
+    and the plain form's [.., chunk, chunk] masks, scores and cotangents (the
+    chunk is the 128 positions here) are no buffer of the step."""
+    if request.node.callspec.params["compiled_step"] != "hybrid":
+        pytest.skip("the Mamba-2 step alone")
+    calls = [path for _, _, op, path in _buffers(compiled_step) if op == "custom-call" and "/ssd_" in path]
+    assert calls and all("layer/attn_core/ssm/scan/" in path for path in calls)
+    assert any("rematted_computation" in path and "ssd_fwd" in path for path in calls)
+    assert any(path.startswith("jit(_train_step)/transpose(") and "ssd_bwd/pallas_call" in path for path in calls)
+    scan = [(name, shape) for name, shape, op, path in _buffers(compiled_step) if "ssm/scan" in path]
+    # per head [b, chunks, heads, t, s]; the kernels' float32 dB and dC partials [b, programs, S, N] are 128 x 128 here too
+    assert scan and not [(name, shape) for name, shape in scan if re.search(rf"\[\d+,\d+,\d+,{S},{S}\]", shape)]
+    # the plain form's two copies of y a direction (its transpose out of [b, c, h, t, p] and its retile) are gone;
+    # what XLA still copies under the name is ONE array a kernel call, y or dx into the positions-minor layout
+    # its neighbours (the convolution's kernels, [b, C, S]) made it choose
+    wide = [(name, shape) for name, shape, op, path in _buffers(compiled_step) if "ssm/scan" in path and op == "copy"
+            and re.search(rf"bf16\[{B},{S},512\]|bf16\[{B},{S},8,64\]", shape)]
+    assert len(wide) <= len(calls)
+
+
+@pytest.mark.parametrize("groups", [None, 8], ids=["granite", "nemotron"])
+def test_the_ssd_kernels_compile_at_the_two_cells_shapes(topo, groups):
+    """Mosaic takes `ssd_fwd` and `ssd_bwd` (PR 49) at one 8,192-token sequence
+    of 64 heads of 64, state 128, chunk 256, one group of B and C (16 heads a
+    program) and eight (8), each as ONE custom call, beside XLA's running sums
+    and small reductions, and no [.., 256, 256] array among the buffers.  (Alone,
+    x, y and their cotangents are copied between [.., 64, 64] and [.., 4096],
+    whose tiled layouts differ; in a step they come from and go to [b, S, 4096]
+    arrays and the reshapes cancel: `test_the_hybrid_steps_scan_...`.)"""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import ssm as op
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    group = (1, 8192, 128) if groups is None else (1, 8192, groups, 128)
+    args = (shaped((1, 8192, 64, 64), jnp.bfloat16), shaped((1, 8192, 64), jnp.float32), shaped((64,), jnp.float32),
+            shaped(group, jnp.bfloat16), shaped(group, jnp.bfloat16), shaped((64,), jnp.float32))
+    entering = shaped((1, 32, 128, 4096), jnp.float32)
+    with _no_compile_cache():
+        forward = jax.jit(lambda *a: op._kernel_forward(*a, 256)).lower(*args).compile()
+        backward = jax.jit(lambda *a: op._kernel_backward(*a, 256)).lower(*args, entering, args[0]).compile()
+    for compiled, name in ((forward, "ssd_fwd"), (backward, "ssd_bwd")):
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 and name in text
+        buffers = _buffers(text)
+        assert not [shape for _, shape, _, _ in buffers if re.search(r"\[[\d,]*256,256\]", shape)]
+    got = [(s.shape, s.dtype) for s in jax.tree.leaves(backward.out_info)]
+    assert got == [(a.shape, a.dtype) for a in args]
+
+
 @pytest.mark.parametrize("kind", ["swiglu", "relu2"])
 def test_a_share_of_the_experts_compiles_with_one_switch_a_direction_of_at_most_four_rungs(topo, kind):
     """The step of a model that holds 2 of its 16 experts (the two share cells

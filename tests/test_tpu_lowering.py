@@ -133,18 +133,33 @@ HYBRID = TransformerConfig.tiny(
     ids=["dp1", "fsdp4", "tp4"],
 )
 def test_hybrid_step_lowers_for_tpu_with_each_flash_kernel_once(n_devices, spec, strategy):
-    """One attention layer, so each flash kernel exactly once, at head size 64;
-    the scan is XLA's to partition (under `tp` its heads are replicated).  The
-    Mamba-2 layers lie in two runs, each one scan body: per run the
-    convolution's forward kernel twice (forward, and the recompute: `qkv_attn`
-    keeps `in_proj`'s output, not the convolution's, which measured slower
-    when kept; PERF.md section 6, PR 36) and its backward kernel once, under
-    shard_map on a mesh of more than one device like the flash kernels."""
+    """One attention layer, so each flash kernel exactly once, at head size 64
+    (under `tp` the scan's heads are replicated).  The Mamba-2 layers lie in
+    two runs, each one scan body: per run the convolution's forward kernel
+    twice (forward, and the recompute: `qkv_attn` keeps `in_proj`'s output,
+    not the convolution's, which measured slower when kept; PERF.md section 6,
+    PR 36) and its backward kernel once, and the same of the scan's two kernels
+    (PR 49: `ssd_fwd`, `ssd_bwd`), all under shard_map on a mesh of more than
+    one device like the flash kernels.  15 Mosaic calls (9 before PR 49), the
+    count of `granite-h-micro-1chip.seq8k`'s step, which has the same runs."""
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=HYBRID)
-    assert _mosaic_kernels(text) == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
-                                     "ssm_conv_fwd": 4, "ssm_conv_bwd": 2}
+    kernels = _mosaic_kernels(text)
+    # `ssd_bwd` sits behind a `jax.jit` of its own: ONE lowered function, called from both runs' backwards
+    assert kernels.pop("ssd_bwd") == 1 and text.count("call @_kernel_backward(") == 2
+    assert kernels == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1, "ssm_conv_fwd": 4, "ssm_conv_bwd": 2, "ssd_fwd": 4}
     call = next(line for line in text.splitlines() if "@tpu_custom_call" in line and 'kernel_name = "flash_fwd"' in line)
     assert re.search(r"tensor<\d+x\d+x128x64xf32>", call)  # q: [batch, heads, seq, 64] (float32 in this tiny config)
+
+
+@pytest.mark.parametrize(
+    "n_devices,spec,strategy", [(1, MeshSpec(data=1), "dp"), (4, MeshSpec(data=1, fsdp=4), "fsdp")], ids=["dp1", "fsdp4"])
+def test_a_step_with_groups_of_b_and_c_lowers_for_tpu_with_the_same_scan_kernels(n_devices, spec, strategy):
+    """Nemotron-H's Mamba-2 in small: 8 heads in 2 groups of B and C (four
+    heads a program), one run of three layers: the scan's forward kernel
+    twice (forward, recompute) and its backward kernel once."""
+    cfg = dataclasses.replace(HYBRID, n_layers=3, layer_types=("mamba",) * 3, ssm_groups=2)
+    kernels = _mosaic_kernels(_lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=cfg))
+    assert kernels == {"ssm_conv_fwd": 2, "ssm_conv_bwd": 1, "ssd_fwd": 2, "ssd_bwd": 1}
 
 
 # Kimi Linear's mixers in small, at the head size the KDA kernel takes: two KDA
