@@ -22,6 +22,7 @@ from ray_tpu.models.transformer import (
     LOGITS_AXES,
     TransformerConfig,
     _constrainer,
+    check_placement,
     forward,
     init_params,
     param_axes,
@@ -170,6 +171,7 @@ class LMTrainContext:
         self.rules = resolve_rules(strategy)
         self.optimizer = optimizer or default_optimizer()
         self._step_clock = StepClock()
+        check_placement(config, self.rules, self.mesh)  # refuse by name now, not when the step is traced
 
         raw_shardings = tree_shardings(param_axes(config), self.rules, self.mesh)
         abstract_params = jax.eval_shape(lambda: init_params(config, jax.random.PRNGKey(0)))
@@ -230,12 +232,13 @@ class LMTrainContext:
             ce = head_cross_entropy(
                 _constrainer(rules, self.mesh), x, head, batch["targets"], batch.get("mask"))
             with jax.named_scope("loss"):
+                counters = _window_counters(cfg, batch["tokens"].shape[1])
                 if router_stats is None:
-                    return ce, _window_counters(cfg, batch["tokens"].shape[1])
+                    return ce, counters
                 terms = router_losses(router_stats, cfg)
                 loss = (ce + cfg.router_aux_loss_coef * terms["moe_lb_loss"]
                         + cfg.router_z_loss_coef * terms["moe_z_loss"])
-                return loss, {"ce_loss": ce, **terms}
+                return loss, {"ce_loss": ce, **terms, **counters}
 
         self._loss = _loss
 

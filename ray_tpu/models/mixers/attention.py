@@ -2,29 +2,38 @@
 
 q/k/v projections (GQA: `n_heads` q heads, `n_kv_heads` k and v heads of
 `head_dim`), no bias; optionally (`qk_norm`) an RMSNorm with a learned scale
-over the WHOLE projected q and k (OLMoE, OLMo 2); rotary embedding only when
-`rope_theta` is set; causal softmax of `q k^T * attention_scale`
+over the WHOLE projected q and k (True: OLMoE, OLMo 2; scales [heads,
+head_dim]) or over each HEAD of them ("per_head": the Qwen3 family's; one
+scale [head_dim] for q's heads, one for k's); the rotary embedding of the layer
+(`layer_ropes`: ops/rotary.py `Rope`) or else the model's (`rope_theta`; none
+when that is None); causal softmax of `q k^T * attention_scale`
 (`head_dim ** -0.5` when None), with a window w (`layer_windows`) query i
-seeing keys i - w + 1 .. i; output projection.
+seeing keys i - w + 1 .. i; output projection.  In a model that has
+`layer_windows` the core lies under one more scope, `attn/window` or
+`attn/full`, which tells a trace the two kinds of layer apart.
 
 The core dispatches to the pallas flash kernel when lowered for TPU (under
 shard_map when there is a mesh), the XLA forms otherwise
 (ray_tpu.ops.attention), or ring attention when the mesh has a nontrivial
-`seq` axis.
+`seq` axis.  The ring takes no `window`, no rope of a layer's own, no
+`attention_scale` and no per-head norm: `placement` refuses the pairing by
+name when configuration, rules and mesh first meet.
 """
 
 from __future__ import annotations
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.mixers.base import (
-    Leaf, Mixer, constrainer, fitting_axis, joined, normal, ones, out_scale, proj_scale, refuse_attn_bias,
-    ring_axis, rms_norm, stream_norm,
+    Leaf, Mixer, constrainer, fitting_axis, joined, may_ring, normal, ones, out_scale, proj_scale,
+    refuse_attn_bias, ring_axis, rms_norm, stream_norm,
 )
 from ray_tpu.ops.attention import dot_product_attention
-from ray_tpu.ops.rotary import apply_rope
+from ray_tpu.ops.rotary import Rope, apply_rope
 
 
 def leaves(config):
@@ -36,15 +45,37 @@ def leaves(config):
         "wv": Leaf((c.d_model, c.n_kv_heads, hd), kv, normal(proj_scale(c))),
         "wo": Leaf((c.n_heads, hd, c.d_model), ("heads", "head_dim", "embed"), normal(out_scale(c))),
     }
-    if c.qk_norm:
+    if c.qk_norm == "per_head":
+        out["q_norm"] = ones((hd,), ("head_dim",))
+        out["k_norm"] = ones((hd,), ("head_dim",))
+    elif c.qk_norm:
         out["q_norm"] = ones((c.n_heads, hd), ("heads", "head_dim"))
         out["k_norm"] = ones((c.n_kv_heads, hd), ("kv_heads", "head_dim"))
     return out
 
 
-def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, data=None, shared=None, emit=False):
-    """The attention half of a layer.  The sequence-parallel ring takes no
-    `window` and no `attention_scale`."""
+def placement(config, rules, mesh) -> None:
+    """What the sequence-parallel ring does not take (ops/ring_attention.py:
+    full causal blocks at `head_dim ** -0.5`, one rope for the model, heads
+    it may split)."""
+    c = config
+    if not may_ring(rules, mesh):
+        return
+    has = [name for name, there in (
+        ("window (layer_windows)", c.layer_windows is not None and any(w is not None for w in c.layer_windows)),
+        ("rope of a layer's own (layer_ropes)", c.layer_ropes is not None),
+        ("attention_scale", c.attention_scale is not None),
+        ("per-head QK-norm (qk_norm 'per_head')", c.qk_norm == "per_head"),
+    ) if there]
+    if has:
+        raise ValueError("ring attention takes no " + ", no ".join(has)
+                         + f": these rules shard act_seq over {rules.get('act_seq')!r}")
+
+
+def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, data=None, shared=None, emit=False,
+        rope=None):
+    """The attention half of a layer; `rope` is the layer's own rotary
+    embedding (None: the model's `rope_theta`)."""
     del data, shared, emit
     c, dt, p = config, config.dtype, layer_params["attn"]
     constrain = constrainer(rules, mesh)
@@ -58,13 +89,18 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
         vv = jnp.einsum("bse,ehd->bshd", h, p["wv"].astype(dt))
         q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
         kk = constrain(kk, ("act_batch", "act_seq", "act_kv_heads", "act_head_dim"))
-        if c.qk_norm:
+        if c.qk_norm == "per_head":
+            q = rms_norm(q, p["q_norm"], c.norm_eps)
+            kk = rms_norm(kk, p["k_norm"], c.norm_eps)
+        elif c.qk_norm:
             # over the WHOLE projection: heads * head_dim is one vector per position
             q = rms_norm(q, p["q_norm"], c.norm_eps, axis=(-2, -1))
             kk = rms_norm(kk, p["k_norm"], c.norm_eps, axis=(-2, -1))
-        if c.rope_theta is not None:
-            q = apply_rope(q, positions, theta=c.rope_theta)
-            kk = apply_rope(kk, positions, theta=c.rope_theta)
+        if rope is None and c.rope_theta is not None:
+            rope = Rope(c.rope_theta)
+        if rope is not None:
+            q = apply_rope(q, positions, rope)
+            kk = apply_rope(kk, positions, rope)
         q = checkpoint_name(q, "q")
         kk = checkpoint_name(kk, "k")
         vv = checkpoint_name(vv, "v")
@@ -75,7 +111,10 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
         if head_ax is not None and kk.shape[2] % mesh.shape[head_ax] != 0:
             head_ax = None  # GQA kv heads don't divide: replicate heads
     seq_axis = ring_axis(rules, mesh, q)
-    with jax.named_scope("layer/attn_core"):
+    # the layer's kind, named only in a model that has two
+    kind = (contextlib.nullcontext() if c.layer_windows is None
+            else jax.named_scope("attn/full" if window is None else "attn/window"))
+    with jax.named_scope("layer/attn_core"), kind:
         if seq_axis is not None:
             # Sequence parallelism: activations are seq-sharded, so full
             # attention would force XLA to all-gather the sequence.  Ring
@@ -84,10 +123,6 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
             # counterpart).
             from ray_tpu.ops.ring_attention import ring_attention_sharded
 
-            if c.attention_scale is not None:
-                raise ValueError("ring attention takes no attention_scale")
-            if window is not None:
-                raise ValueError("ring attention takes no window (layer_windows)")
             attn = ring_attention_sharded(
                 q, kk, vv, mesh,
                 seq_axis=seq_axis,
@@ -107,4 +142,4 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
         return joined(c, x, attn_out, constrain), {}
 
 
-MIXER = Mixer("attention", "layers", "attn", leaves, refuse_attn_bias, mix)
+MIXER = Mixer("attention", "layers", "attn", leaves, refuse_attn_bias, mix, rotates=True, placement=placement)

@@ -34,7 +34,9 @@ class Mixer:
     first half of a layer (the FFN half is every kind's, `transformer.layer`).
     `window` is the layer's causal window, `data` this layer's row of the
     kind's `data`, `shared` what earlier layers handed on under the names in
-    `reads`; with `emit` the layer returns `hands` by name, and {} otherwise."""
+    `reads`; with `emit` the layer returns `hands` by name, and {} otherwise.
+    A kind that `rotates` also takes `rope=`, the layer's own rotary embedding
+    (`TransformerConfig.layer_ropes`), given only to a layer that has one."""
     name: str  # the kind, as `TransformerConfig.layer_types` spells it
     stack: str  # the subtree of the parameters that stacks its layers (`TransformerConfig.stack_name`)
     subtree: str  # the subtree of one layer that holds the mixer's own leaves
@@ -55,6 +57,10 @@ class Mixer:
     reads: Tuple[str, ...] = ()
     # config -> {name: one float per layer OF THE MODEL}; a layer gets its own as `data[name]`
     data: Callable[[Any], Dict[str, Tuple[float, ...]]] = lambda config: {}
+    rotates: bool = False  # its `mix` takes `rope=` (a layer of another kind may have no entry in `layer_ropes`)
+    # (config, rules, mesh) -> None; raises ValueError on what the kind cannot run UNDER THESE RULES ON THIS MESH,
+    # when the three first meet (`transformer.check_placement`), not when a step is traced
+    placement: Callable[[Any, Optional[Rules], Any], None] = lambda config, rules, mesh: None
 
 
 # -- initializers: (key, stacked shape) -> float32, rounded to `param_dtype` by `init_params` ----
@@ -168,6 +174,12 @@ def ring_axis(rules: Optional[Rules], mesh, q: jax.Array) -> Optional[str]:
     if rules is None:
         return None
     return fitting_axis(rules.get("act_seq"), mesh, q.shape[1])
+
+
+def may_ring(rules: Optional[Rules], mesh) -> bool:
+    """Whether these rules on this mesh run the sequence-parallel ring at
+    SOME length: `ring_axis` before a sequence is known."""
+    return rules is not None and fitting_axis(rules.get("act_seq"), mesh, 0) is not None
 
 
 def refuse_attn_bias(config) -> None:

@@ -49,7 +49,13 @@ the held experts' part for the tokens routed to them, plus the shared
 expert, and what the absent experts would have added is left out.  That is
 `_experts(first_expert=...)`, the form a mesh's `expert` axis uses, without
 its `psum`: nothing stands in for the absent ranks.  The rows each held
-expert got go out with the statistics (`held_rows`).
+expert got go out with the statistics (`held_rows`).  With
+`router_share_init` the E / held blocks of the router start equal, so a
+token's K choices start as the K * held / E best columns of a block, once in
+every share: each share starts with its even part of the rows whatever the
+seed (independent columns put a seed's winners where the draw has them, and
+a share's rows, with them the step, follow the draw; PERF.md section 6,
+PR 50).  Training moves the blocks apart as it moves any weights.
 
 A share moves the rows it holds, not all T*K (PR 48).  The sort is stable
 with this share's experts first, so the held rows are the first
@@ -158,7 +164,10 @@ def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
 
     # `routed_branch_init`: a token's K routed outputs are ONE residual branch of the depth-scaled variance, 1 / K each
     routed_down = down_scale * c.experts_per_token ** -0.5 if c.routed_branch_init else down_scale
-    params = {"router": init(k1, (D, E), scale), **matrices((k2, k3, k4), F, routed_down, (held,))}
+    router = init(k1, (D, E), scale)
+    if c.router_share_init:  # every share's block starts as the first: a token's K choices start K * held / E on each share
+        router = jnp.tile(router[..., :held], E // held)
+    params = {"router": router, **matrices((k2, k3, k4), F, routed_down, (held,))}
     if c.router_activation == "sigmoid":
         # zero, and the job leaves it so: its published update follows the
         # experts' load, outside the gradient (a recipe, not a key of a config)

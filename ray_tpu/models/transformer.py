@@ -18,9 +18,16 @@ blocks are a mixer OR an FFN alone (Nemotron-H's `MEMEM*E...`) is pairs too:
 `M E` is (mamba, experts), `* E` (attention, experts), an `M` straight before
 a `*` (mamba, none).  Attention's head size is `d_model // n_heads` unless the
 configuration states one (`attn_head_dim`).  Norms are RMSNorm
-or LayerNorm with bias (`norm_kind`).  Mistral, InternLM2, OLMoE, the Granite
-4.0-H hybrids, Kimi Linear, Phi-4-mini-flash and Nemotron-3-Nano run through
-it at their published widths (benchmarks/configs/).
+or LayerNorm with bias (`norm_kind`).  What is static in a layer beside its
+pair is the layer's own too (`layer_variant`): its attention's causal window
+(`layer_windows`) and its rotary embedding (`layer_ropes`, each an
+`ops.rotary.Rope`: a base, optionally YaRN's rescaled frequencies and factor
+on cos and sin; a layer without one rotates by the model's `rope_theta`), so
+a stack of three window-1024 layers to one full layer, the full ones with a
+YaRN rope (Mellum 2), is ONE kind of layer in one parameter stack, run as two
+compiled bodies a period.  Mistral, InternLM2, OLMoE, the Granite
+4.0-H hybrids, Kimi Linear, Phi-4-mini-flash, Nemotron-3-Nano and Mellum 2
+run through it at their published widths (benchmarks/configs/).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -61,7 +68,11 @@ What crosses layers (an s6 layer's scan output; a diff_attention layer's k
 and v) is RETURNED by the layer that makes it, carried by `trunk` beside the
 stream and given to the later runs that read it as an argument
 (`Mixer.hands` / `Mixer.reads`).  `tp`, `pp` and the sequence-parallel ring
-refuse the differential kinds by name (`pp` any `layer_types`).
+refuse the differential kinds by name (`pp` any `layer_types`); what a kind
+cannot run under given rules on a given mesh (the ring a window, a layer's
+own rope, a per-head QK-norm; `pp` those and any stack that is not
+homogeneous) is refused when the three first meet (`check_placement`, which
+`LMTrainContext` calls as it is built), not deep inside a trace.
 """
 
 from __future__ import annotations
@@ -69,7 +80,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +91,7 @@ from ray_tpu.models.mixers.base import joined, normal, ones, out_scale, proj_sca
 from ray_tpu.models.mixers.base import constrainer as _constrainer, rms_norm  # noqa: F401
 from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT
+from ray_tpu.ops.rotary import Rope
 from ray_tpu.parallel.sharding import Rules, pipeline_axes, with_logical_constraint
 
 
@@ -161,15 +173,23 @@ class TransformerConfig:
     # `routed_branch_init`: the K experts a token chooses join the stream as
     # ONE residual branch, so each routed expert's `w_down` starts at the
     # depth-scaled `out_scale / sqrt(K)` (False: each expert at `out_scale`,
-    # as OLMoE's and Kimi Linear's seeds have it).
+    # as OLMoE's and Kimi Linear's seeds have it).  `router_share_init`: a
+    # model that holds a share starts every share's block of the router
+    # (`n_experts_held` columns) from ONE draw, so each token's K choices
+    # start `K * n_experts_held / n_experts` on every share, this one too,
+    # whatever the seed; the blocks then train apart (False: `n_experts`
+    # independent columns, whose winners a seed draws among the shares).
     n_experts_held: Optional[int] = None
     first_expert_held: int = 0
     expert_kind: str = "swiglu"
     shared_expert_d_ff: Optional[int] = None
     routed_branch_init: bool = False
-    # RMSNorm with a learned scale over the whole projected q and k, before
-    # RoPE (OLMoE, OLMo 2).
-    qk_norm: bool = False
+    router_share_init: bool = False
+    # RMSNorm with a learned scale on q and k, before RoPE.  True: over the
+    # whole projected q and k (OLMoE, OLMo 2); "per_head": over each head of
+    # them, one scale of `head_dim` for q's heads and one for k's (the Qwen3
+    # family).
+    qk_norm: Union[bool, str] = False
     # The mixer of each layer, one of `mixers.MIXERS`, one entry per layer;
     # None = attention everywhere.  The Mamba-2 sizes are read only when some
     # layer is "mamba": heads x head size = the mixer's inner width, the
@@ -232,10 +252,14 @@ class TransformerConfig:
     # window of its attention (None = full causal), read by "attention" and
     # "diff_attention" layers; None = no layer has one.  `layer_ids`: the
     # PUBLISHED index of each layer (None = its index here), which
-    # differential attention's lambda_init is a function of.
+    # differential attention's lambda_init is a function of.  `layer_ropes`:
+    # per layer its own rotary embedding (`ops.rotary.Rope`; None = the
+    # model's `rope_theta`), read by the kinds that rotate (`Mixer.rotates`:
+    # "attention"); None = no layer has one.
     kv_source_layer: Optional[int] = None
     layer_windows: Optional[Tuple[Optional[int], ...]] = None
     layer_ids: Optional[Tuple[int, ...]] = None
+    layer_ropes: Optional[Tuple[Optional[Rope], ...]] = None
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -250,11 +274,17 @@ class TransformerConfig:
                 if mixer.name in self.layer_types:
                     mixer.validate(self)
             self._check_crossings()
-        for name in ("layer_windows", "layer_ids"):
+        for name in ("layer_windows", "layer_ids", "layer_ropes"):
             if getattr(self, name) is not None:
                 object.__setattr__(self, name, tuple(getattr(self, name)))
                 if len(getattr(self, name)) != self.n_layers:
                     raise ValueError(f"{name} needs n_layers={self.n_layers} entries")
+        for (kind, _), rope in zip(self.layer_pairs(), self.layer_ropes or ()):
+            if rope is not None and not (isinstance(rope, Rope) and MIXERS[kind].rotates):
+                raise ValueError(f"layer_ropes holds {rope!r} at a {kind} layer: an ops.rotary.Rope, at a layer "
+                                 f"of a kind that rotates ({[m.name for m in MIXERS.values() if m.rotates]}), or None")
+        if self.qk_norm not in (False, True, "per_head"):
+            raise ValueError(f"qk_norm is False, True (over the whole projection) or 'per_head', got {self.qk_norm!r}")
         if self.norm_kind not in ("rms", "layer"):
             raise ValueError(f"unknown norm_kind {self.norm_kind!r}; expected 'rms' or 'layer'")
         if self.ffn_types is not None:
@@ -284,6 +314,15 @@ class TransformerConfig:
                 f"n_experts_held={self.n_experts_held} from {self.first_expert_held} "
                 f"is no share of n_experts={self.n_experts}"
             )
+        if self.router_share_init:
+            held = self.n_experts_held
+            if held is None or self.n_experts % held or self.first_expert_held % held or (
+                    self.experts_per_token * held) % self.n_experts:
+                raise ValueError(
+                    f"router_share_init needs n_experts={self.n_experts} in whole shares of n_experts_held={held}, "
+                    f"this share at a multiple of it (first_expert_held={self.first_expert_held}), and "
+                    f"experts_per_token={self.experts_per_token} a multiple of the number of shares"
+                )
 
     def _check_crossings(self):
         """What crosses layers has ONE maker, a layer of the kind that hands
@@ -304,13 +343,14 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads if self.attn_head_dim is None else self.attn_head_dim
 
-    def layer_variant(self, i: int) -> Tuple[Optional[int], bool]:
+    def layer_variant(self, i: int) -> Tuple[Optional[int], Optional[Rope], bool]:
         """What of layer i is STATIC beside its pair, so that a run of one
         compiled body cannot span a change of it: (its attention's window,
-        whether it hands values on to later layers)."""
+        its own rotary embedding, whether it hands values on to later layers)."""
         window = None if self.layer_windows is None else self.layer_windows[i]
+        rope = None if self.layer_ropes is None else self.layer_ropes[i]
         source = MIXERS[_DEFAULT_MIXER if self.layer_types is None else self.layer_types[i]].source
-        return window, source is not None and getattr(self, source) == i
+        return window, rope, source is not None and getattr(self, source) == i
 
     def lambda_inits(self) -> Tuple[float, ...]:
         """Differential attention's `lambda_init` of each layer, from its published index."""
@@ -549,13 +589,16 @@ def layer(
     data: Optional[Dict] = None,
     shared: Optional[Dict] = None,
     emit: bool = False,
+    rope: Optional[Rope] = None,
 ):
     """One layer of any kind, the kind's `mix` and then the FFN half: (x,
     this layer's router statistics, None when the FFN is dense; what it hands
     on to later layers, by name).  `ffn` is the layer's kind of FFN (None:
-    what the configuration's every layer has); the rest is `Mixer.mix`'s."""
+    what the configuration's every layer has); the rest is `Mixer.mix`'s,
+    `rope` given only where the layer has one of its own."""
     x, handed = mixer.mix(x, layer_params, positions, config, rules, mesh,
-                          window=window, data=data, shared=shared, emit=emit)
+                          window=window, data=data, shared=shared, emit=emit,
+                          **({} if rope is None else {"rope": rope}))
     return (*_ffn_half(x, layer_params, config, _constrainer(rules, mesh), rules, mesh, ffn), handed)
 
 
@@ -617,6 +660,41 @@ def _remat_policy(config: TransformerConfig):
     )
 
 
+def _refuse_unequal_stages(config: TransformerConfig) -> None:
+    """What the pipeline schedule cannot run, by name: every stage scans ONE
+    compiled body of the default kind over its share of ONE stack."""
+    c = config
+    if c.n_experts is not None:
+        raise ValueError(
+            "strategy 'pp' runs dense layers only: the router statistics of "
+            "an expert layer do not come out of the pipeline schedule"
+        )
+    if (c.layer_types is not None or c.ffn_types is not None or c.layer_windows is not None
+            or c.layer_ropes is not None or c.qk_norm == "per_head"):
+        default, *others = MIXERS
+        raise ValueError(
+            f"strategy 'pp' runs a homogeneous stack of {default} layers only: the "
+            f"stages of a stack with layer_types ({', '.join(others)}) or ffn_types "
+            "(dense beside experts, or none) would hold unequal "
+            "layers, and a value one layer hands to a later one does not cross stages; "
+            "nor does it take layer_windows, layer_ropes (a window or a rope of a layer's own: "
+            "one body a stage) or a per-head qk_norm"
+        )
+
+
+def check_placement(config: TransformerConfig, rules: Optional[Rules], mesh) -> None:
+    """Refuse by name what this model cannot run under these rules on this
+    mesh, before anything is traced: what each kind of mixer in the stack
+    says of itself (`Mixer.placement`: the ring's limits), and what the
+    pipeline schedule takes when the rules shard the layer stack."""
+    if rules is None:
+        return
+    for kind in dict.fromkeys(m for m, _ in config.layer_pairs()):
+        MIXERS[kind].placement(config, rules, mesh)
+    if pipeline_axes(rules, mesh, config.n_layers) is not None:
+        _refuse_unequal_stages(config)
+
+
 def _run_layers_pipelined(
     layer_params: Dict,
     x: jax.Array,
@@ -639,19 +717,6 @@ def _run_layers_pipelined(
     from ray_tpu.parallel.pipeline import pipeline_apply
 
     c = config
-    if c.n_experts is not None:
-        raise ValueError(
-            "strategy 'pp' runs dense layers only: the router statistics of "
-            "an expert layer do not come out of the pipeline schedule"
-        )
-    if c.layer_types is not None or c.ffn_types is not None or c.layer_windows is not None:
-        default, *others = MIXERS
-        raise ValueError(
-            f"strategy 'pp' runs a homogeneous stack of {default} layers only: the "
-            f"stages of a stack with layer_types ({', '.join(others)}) or ffn_types "
-            "(dense beside experts, or none) would hold unequal "
-            "layers, and a value one layer hands to a later one does not cross stages"
-        )
     n_stages = mesh.shape[axis]
     per_stage = c.n_layers // n_stages
 
@@ -758,10 +823,10 @@ def trunk(
             shared = {}  # what layers have handed on so far (`Mixer.hands`), by name
             for (kind, ffn, _, count), start in zip(runs, c.run_starts()):
                 mixer = MIXERS[kind]
-                window, emit = c.layer_variant(start)
+                window, rope, emit = c.layer_variant(start)
 
                 layer_fn = functools.partial(layer, mixer, positions=positions, config=c, rules=rules, mesh=mesh,
-                                             ffn=ffn, window=window, emit=emit)
+                                             ffn=ffn, window=window, emit=emit, rope=rope)
                 if c.remat:
                     layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
 

@@ -1,0 +1,74 @@
+#!/usr/bin/env python3
+"""What the comparison that decides `correct` would read if float32 were
+forgotten where a configuration states it, at the cell's own sizes:
+
+    chiprun -- python3 scripts/precision_control.py --workload mellum2-ep4-1chip.seq16k --seed 7
+
+Builds the cell's program as the harness does (builder, seeded `init_state`,
+the reference check's own seeded sequences), takes the program's logits once,
+and compares them with the plain reference as it is and with the reference
+computing ONE of the stated-float32 parts in bfloat16 (`reference_mellum.STATED`:
+router, norms' statistics, rope), then all of them.  Prints one line,
+`[control] {"tolerance", "program_vs_reference", "lowered": {part:
+{"program_vs_lowered", "lowered_vs_reference"}}}`, each a list of relative rms
+errors, one a sequence.  A diagnostic for PERF.md (section 6, PR 50: the limit
+of the harness beside both readings); no cell or metric reads it.  `--cpu-toy`
+runs the harness's rehearsal widths on the CPU (no device number).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--cpu-toy", action="store_true")
+    args = ap.parse_args()
+    if args.cpu_toy:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+
+    import jax
+
+    from benchmarks import run as harness
+    from benchmarks.lib import datagen, reference, reference_mellum
+
+    _, config, traffic = harness.load_cell(args.workload)
+    if args.cpu_toy:
+        config = dict(config, **harness.REHEARSAL_CONFIG)
+        traffic = dict(traffic, seq_len=harness.REHEARSAL_SEQ)
+    builder = harness.load_plugin("builders", config["kind"])
+
+    seq, n_ref = traffic["seq_len"], traffic["reference_seqs"]
+    last = seq if seq <= 1024 else 256
+    _, ctx = builder.build(config, seq, jax.devices())
+    params = ctx.init_state(seed=args.seed)["params"]
+    stream = datagen.PackedStream(args.seed + 1_000_003, config["vocab_size"], traffic["stream"])
+    tokens = stream.next_batch(n_ref, seq)["tokens"]
+    got = [jax.device_get(ctx.apply(params, tokens[i: i + 1])[0, -last:]) for i in range(n_ref)]
+
+    def errors(a, b):
+        return [reference.rel_rms_error(x, y) for x, y in zip(a, b)]
+
+    want = reference_mellum.logits(config, params, tokens, last=last)
+    out = {"cell": args.workload, "seed": args.seed, "positions": last,
+           "tolerance": reference.tolerance(config["num_hidden_layers"]),
+           "program_vs_reference": errors(got, want), "lowered": {}}
+    for parts in [(part,) for part in reference_mellum.STATED] + [reference_mellum.STATED]:
+        low = reference_mellum.logits(config, params, tokens, last=last, lowered=parts)
+        out["lowered"]["+".join(parts)] = {"program_vs_lowered": errors(got, low), "lowered_vs_reference": errors(low, want)}
+        del low
+    print("[control] " + json.dumps(out), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

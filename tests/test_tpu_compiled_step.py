@@ -269,3 +269,32 @@ def test_a_share_of_the_experts_compiles_with_one_switch_a_direction_of_at_most_
     per_rung = {"swiglu": 8 + 3, "relu2": 5 + 2}[kind]  # moe_gmm + moe_tgmm: forward, the activation again, both gradients
     assert len(re.findall(r'custom_call_target="tpu_custom_call".*moe_t?gmm', text)) == per_rung * len(rungs)
     assert REFUSED_SCOPE not in text
+
+
+def test_the_mellum_cells_window_kernels_and_grouped_matmuls_compile_at_its_shapes(topo):
+    """PR 50: no new kernel, two shapes no cell had.  One 16,384-token
+    sequence of 32 q heads of 128 over 4 K/V heads under a window of 1,024:
+    tiles of 1024 (512 keys in the dkv kernel), grids that leave the tiles
+    outside the window out, three custom calls forward + backward.  And the
+    grouped matmuls at 2304 x 896 (k tiled by 256, n whole) on the lower rung's
+    65,536 rows of 16 held experts, both ways round, none refused."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.grouped_matmul import REFUSED_SCOPE, grouped_matmul
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    q, kv = shaped((1, 16384, 32, 128)), shaped((1, 16384, 4, 128))
+    grad = jax.grad(lambda q, k, v, do: jnp.sum((fa.flash_attention(q, k, v, window=1024) * do).astype(jnp.float32)),
+                    argnums=(0, 1, 2))
+    with _no_compile_cache():
+        text = jax.jit(grad).lower(q, kv, kv, q).compile().as_text()
+        rows, sizes = shaped((65536, 2304)), shaped((16,), jnp.int32)
+        up = jax.jit(grouped_matmul).lower(rows, shaped((16, 2304, 896)), sizes).compile().as_text()
+        down = jax.jit(grouped_matmul).lower(shaped((65536, 896)), shaped((16, 896, 2304)), sizes).compile().as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    assert len(kernels) == 3 and all(name in text for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    for compiled in (up, down):
+        assert compiled.count('custom_call_target="tpu_custom_call"') == 1 and "moe_gmm" in compiled
+        assert REFUSED_SCOPE not in compiled
