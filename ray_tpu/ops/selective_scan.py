@@ -1,14 +1,15 @@
 """Mamba-1's selective scan (S6, arXiv:2312.00752) in a chunked form; no
 counterpart in the reference (SURVEY.md §5.7).  `selective_scan` is one
-`jax.custom_vjp`.  Its forward is plain `jax.numpy` everywhere but on TPU
-(`_plain_forward`, a scan over `_chunk_body`); for TPU, at the shapes it
-takes, one Pallas kernel walks the recurrence itself with the state in vector
-registers (`ops/pallas/selective_scan.py`: `s6_scan_fwd`, PR 42, the same
-arithmetic at the same precision).  Its backward is JAX's own differentiation
-of `_chunk_body` on every platform, a chunk at a time from the state that
-entered it, which either forward writes (no gradient in this file is derived
-by hand).  `s6/scan`, the scope around all of this, is what the benchmark
-reads it by (PERF.md section 3).
+`jax.custom_vjp`.  Both directions are plain `jax.numpy` everywhere but on TPU
+(`_plain_forward`, a scan over `_chunk_body`; `_plain_backward`, JAX's own
+differentiation of `_chunk_body` a chunk at a time from the state that entered
+it: no gradient in this file is derived by hand); for TPU, at the shapes they
+take, two Pallas kernels walk the recurrence itself and its adjoint with the
+state in vector registers (`ops/pallas/selective_scan.py`: `s6_scan_fwd`,
+PR 42, and `s6_scan_bwd`, PR 51, the same arithmetic at the same precision).
+Either backward starts from the entering states either forward writes.
+`s6/scan`, the scope around all of this, is what the benchmark reads it by
+(PERF.md section 3).
 
 The recurrence, per batch row, per channel c of the mixer's inner width and
 per state n, with a positive step `dt_t[c]`, a negative `A[c, n]` and one
@@ -40,10 +41,12 @@ quotient: `exp(cum_t) / exp(cum_s)` is inf/inf or 0/0 once the running sum of
 What the backward keeps is the state that ENTERS each chunk ([S / chunk, b,
 N, channels] float32; 84 MB a layer at one 8,192-token sequence, 5,120
 channels, N 16 and a chunk of 32), beside the inputs: it runs a chunk's
-forward again (`_chunk_body`, behind a `jax.checkpoint`) before its backward,
-in one reverse `lax.scan` that carries the state's cotangent; the [S,
-channels, N] states (2.7 GB there) never exist at once, and the serial pass
-over the chunks is not run a second time.
+forward again before its backward, from the last chunk to the first with the
+state's cotangent carried along (the plain form: `_chunk_body` behind a
+`jax.checkpoint` in one reverse `lax.scan`; the kernel: the chunk's positions
+once more with their states in VMEM, then the adjoint a position at a time);
+the [S, channels, N] states (2.7 GB there) never exist at once, and the serial
+pass over the chunks is not run a second time.
 
 Layout: [.., N, channels], the channels in the lanes (N = 16 there would pad
 to 128).
@@ -57,13 +60,15 @@ layer's plain forward takes 9.2 / 10.3 / 10.3 ms at chunks of 8 / 16 / 32 and
 10.7 / 52.9 / 83.0 / 94.5 ms at 64 / 128 / 256 / 512, forward + backward 38.0 /
 36.0 / 37.9 ms against 116 / 244 / 309 / 391 (my chip runs, PR 40: the levels
 of a [32, 16, 5120] float32 chunk, 10 MB, stay fused; larger ones are written
-out level by level).  The kernel's forward takes 1.56 ms there (my chip runs,
-PR 42); the backward is still the plain chunk's, so the chunk stays.
+out level by level).  The kernels have no levels and take 1.56 ms forward (my
+chip runs, PR 42) and 5.8 ms backward (my chip runs, PR 51; plain 32.9) there;
+for them the chunk sizes the residual alone (the entering states, 84 MB a
+layer) and the states a program keeps in VMEM (1 MB), so it stays.
 
 Sharding: the plain form names no mesh axis; batch sharding is GSPMD's to
 propagate through the elementwise work.  A Mosaic kernel GSPMD cannot
 partition, so on a mesh `selective_scan` runs under shard_map over the batch
-axes where the kernel takes the shapes.
+axes where the kernels take the shapes, forward and backward.
 """
 
 from __future__ import annotations
@@ -141,18 +146,22 @@ def _kernel_takes(x, B, chunk: int) -> bool:
     return _kernel().supported(x.shape[2], B.shape[-1], x.shape[1], chunk)
 
 
-def _forward(x, dt, A, B, C, D, chunk: int):
-    """(y, the state that enters each chunk).  Like
-    attention and the convolution, the form follows the platform a step is
-    LOWERED for, not the process's backend: the kernel for TPU at shapes it
-    takes, the plain form everywhere else."""
-    f32 = jnp.float32
-    inputs = (x, dt.astype(f32), A.astype(f32).T, B, C, D)
-    plain = functools.partial(_plain_forward, chunk=chunk)
+def _dispatch(kernel: str, plain, x, B, chunk: int, inputs):
+    """One direction of the scan on `inputs`.  Like attention and the
+    convolution, the form follows the platform a step is LOWERED for, not the
+    process's backend: the kernel of that name for TPU at shapes it takes, the
+    plain form everywhere else."""
+    plain = functools.partial(plain, chunk=chunk)
     if _kernel_takes(x, B, chunk):
-        kernel = functools.partial(_kernel().s6_scan_fwd, chunk=chunk)
+        kernel = functools.partial(getattr(_kernel(), kernel), chunk=chunk)
         return jax.lax.platform_dependent(*inputs, tpu=kernel, default=plain)
     return plain(*inputs)
+
+
+def _forward(x, dt, A, B, C, D, chunk: int):
+    """(y, the state that enters each chunk)."""
+    f32 = jnp.float32
+    return _dispatch("s6_scan_fwd", _plain_forward, x, B, chunk, (x, dt.astype(f32), A.astype(f32).T, B, C, D))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -165,16 +174,15 @@ def _scan_fwd(x, dt, A, B, C, D, chunk: int):
     return y, (x, dt, A, B, C, D, entering)
 
 
-def _scan_bwd(chunk: int, res, dy):
+def _plain_backward(x, dt, A_t, B, C, D32, entering, dy, chunk: int):
     """JAX's own differentiation of `_chunk_body`, a chunk at a time from the
     last to the first: the chunk's forward once more FROM THE STATE THAT
     ENTERED IT, which the forward wrote (kernel or plain), then its backward,
     the state's cotangent carried along with the sums for A and D.  The serial
-    pass over the chunk states is not run again.  Cotangents in their
-    arguments' dtypes."""
-    x, dt, A, B, C, D, entering = res
+    pass over the chunk states is not run again.  The backward off TPU and at
+    shapes the kernel refuses: the cotangents of (x, dt, A_t, B, C, D), dx, dB
+    and dC in their arguments' dtypes, the others float32."""
     f32 = jnp.float32
-    A_t, D32 = A.astype(f32).T, D.astype(f32)
 
     def step(carry, xs):
         d_state, d_A, d_D = carry
@@ -189,6 +197,18 @@ def _scan_bwd(chunk: int, res, dy):
     inputs = (chunks(x), chunks(dt.astype(f32)), chunks(B), chunks(C))
     (_, d_A, d_D), d_inputs = jax.lax.scan(step, start, (entering, inputs, chunks(dy)), reverse=True)
     dx, d_dt, dB, dC = map(_positions, d_inputs)
+    return dx, d_dt, d_A, dB, dC, d_D
+
+
+def _scan_bwd(chunk: int, res, dy):
+    """From the entering states either forward wrote: for TPU, at the shapes
+    the kernel takes, the recurrence's own adjoint a position at a time
+    (`s6_scan_bwd`); everywhere else `_plain_backward`.  Cotangents in their
+    arguments' dtypes."""
+    x, dt, A, B, C, D, entering = res
+    f32 = jnp.float32
+    dx, d_dt, d_A, dB, dC, d_D = _dispatch("s6_scan_bwd", _plain_backward, x, B, chunk,
+                                           (x, dt, A.astype(f32).T, B, C, D.astype(f32), entering, dy))
     return dx, d_dt.astype(dt.dtype), d_A.T.astype(A.dtype), dB, dC, d_D.astype(D.dtype)
 
 

@@ -207,16 +207,19 @@ def test_gradients_agree_with_the_reference_leaf_by_leaf(loss_and_grads):
 
 
 def test_the_cut_model_with_the_scan_kernel_interpreted_is_the_plain_run(cut, loss_and_grads, monkeypatch):
-    """The ten layers as a step lowered for TPU has their scans (PR 42): the
-    forward kernel interpreted, under shard_map on the context's mesh, in the
-    forward and in the layer's recompute; the backward starts each chunk from
-    the states the kernel wrote.  Loss and every leaf's gradient are the plain
-    run's.  (No other dispatch of the model takes its kernel at 64 positions.)"""
+    """The ten layers as a step lowered for TPU has their scans (PRs 42, 51):
+    both kernels interpreted, under shard_map on the context's mesh, the
+    forward's in the forward and in the layer's recompute; the backward's
+    starts each chunk from the states the forward's wrote.  Loss and every
+    leaf's gradient are the plain run's.  (No other dispatch of the model takes
+    its kernel at 64 positions.)"""
     from ray_tpu.ops.pallas import selective_scan as kernels
 
-    calls, real = [], kernels.s6_scan_fwd
+    calls, backward_calls, real, real_backward = [], [], kernels.s6_scan_fwd, kernels.s6_scan_bwd
     monkeypatch.setattr(kernels, "_BLOCK_S", SEQ)
     monkeypatch.setattr(kernels, "s6_scan_fwd", lambda *a, **kw: calls.append(kw) or real(*a, interpret=True, **kw))
+    monkeypatch.setattr(kernels, "s6_scan_bwd",
+                        lambda *a, **kw: backward_calls.append(kw) or real_backward(*a, interpret=True, **kw))
     monkeypatch.setattr(jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
     ctx = LMTrainContext(dataclasses.replace(cut["cfg"], remat=True, remat_policy="qkv_attn"),
                          mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
@@ -224,6 +227,7 @@ def test_the_cut_model_with_the_scan_kernel_interpreted_is_the_plain_run(cut, lo
         (loss, _), grads = jax.jit(jax.value_and_grad(ctx._loss, has_aux=True))(
             cut["params"], {"tokens": cut["tokens"], "targets": cut["targets"]})
     assert len(calls) >= 6 and all(kw == {"chunk": 16} for kw in calls)  # three layers, forward and recompute
+    assert len(backward_calls) >= 3 and all(kw == {"chunk": 16} for kw in backward_calls)
     plain_loss, plain = loss_and_grads[:2]
     assert float(loss) == pytest.approx(float(plain_loss), rel=1e-6)
     flat, flat_plain = (dict(jax.tree_util.tree_flatten_with_path(t)[0]) for t in (grads, plain))

@@ -1,8 +1,9 @@
-"""The selective scan's forward kernel (`ops/pallas/selective_scan.py`) in
-interpret mode on the CPU: against the plain chunked form it replaces on TPU
-(`ops/selective_scan.py:_plain_forward`) and the recurrence itself; the states
-it writes, which the backward starts from; the `custom_vjp` around both, whose
-backward is JAX's differentiation of `_chunk_body` on every platform.
+"""The selective scan's two kernels (`ops/pallas/selective_scan.py`) in
+interpret mode on the CPU: against the plain chunked form they replace on TPU
+(`ops/selective_scan.py:_plain_forward`, `_plain_backward`) and the recurrence
+itself with JAX's gradient of it; the states the forward writes, which either
+backward starts from; the `custom_vjp` around both, whose backward off TPU and
+at refused shapes is JAX's differentiation of `_chunk_body`.
 
 Blocks of 128 channels by 64 positions here (the chip's are `_BLOCK_C` by
 `_BLOCK_S`): 256 channels are two programs, 128 positions two blocks of two
@@ -10,12 +11,14 @@ chunks each."""
 
 import functools
 import inspect
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models.transformer import _remat_policy
 from ray_tpu.ops import selective_scan as op
 from ray_tpu.ops.pallas import selective_scan as kernels
 from ray_tpu.parallel import MeshSpec, build_mesh
@@ -45,12 +48,17 @@ def small_blocks(monkeypatch):
     monkeypatch.setattr(kernels, "_BLOCK_S", 64)
 
 
+def _as_lowered_for_tpu(patch):
+    """`selective_scan` as a step lowered for TPU has it, the kernels
+    interpreted: both dispatches take their `tpu` branch."""
+    for name in ("s6_scan_fwd", "s6_scan_bwd"):
+        patch.setattr(kernels, name, functools.partial(getattr(kernels, name), interpret=True))
+    patch.setattr(op.jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+
+
 @pytest.fixture
 def kernel_on_the_cpu(monkeypatch):
-    """`selective_scan` as a step lowered for TPU has it, the kernel
-    interpreted: the dispatch takes its `tpu` branch."""
-    monkeypatch.setattr(kernels, "s6_scan_fwd", functools.partial(kernels.s6_scan_fwd, interpret=True))
-    monkeypatch.setattr(op.jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+    _as_lowered_for_tpu(monkeypatch)
 
 
 @pytest.fixture
@@ -59,6 +67,7 @@ def no_kernel(monkeypatch):
         raise AssertionError("the kernel was called")
 
     monkeypatch.setattr(kernels, "s6_scan_fwd", refuse)
+    monkeypatch.setattr(kernels, "s6_scan_bwd", refuse)
     monkeypatch.setattr(op.jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
 
 
@@ -145,6 +154,125 @@ def test_gradients_through_the_kernel_are_the_plain_forms_and_the_recurrences(ke
         assert rel(g, p) <= 1e-5 and rel(g, w) <= 10 * SCAN_TOL, (name, rel(g, p), rel(g, w))
 
 
+COTANGENTS = ("dx", "ddt", "dA", "dB", "dC", "dD")
+# name: (inputs' keywords, chunk, (channels, positions) a program).  128 positions are two blocks of 64; 256 channels two
+# blocks of 128, whose partial sums for B and C XLA adds, or one of 256.
+CASES = {
+    "f32-one_row-one_block": (dict(s=64), 32, (128, 64)),
+    "f32-two_rows-two_blocks": (dict(b=2, s=128), 32, (128, 64)),
+    "bf16-one_row-four_blocks": (dict(s=256, dtype=jnp.bfloat16), 32, (128, 64)),
+    "bf16-two_rows-three_blocks-chunk_16": (dict(b=2, s=192, dtype=jnp.bfloat16), 16, (128, 64)),
+    "f32-one_row-two_blocks-one_channel_block": (dict(s=128), 32, (256, 64)),
+    "f32-one_row-two_blocks-a_running_sum_past_minus_88": (dict(s=128, step=4.0), 32, (128, 64)),
+}
+
+
+def both(f, args, seed=9):
+    """(y, its six cotangents under a random probe) of f."""
+    y, pull = jax.vjp(f, *args)
+    return (y, *pull(jax.random.normal(jax.random.PRNGKey(seed), y.shape).astype(y.dtype)))
+
+
+@functools.lru_cache(maxsize=None)
+def _case(name):
+    """(the kernels', JAX's of the plain forward, JAX's of the recurrence) y and cotangents: computed once a case, read by a
+    test a cotangent."""
+    kw, chunk, (block_c, block_s) = CASES[name]
+    args = inputs(21, **{"step": 0.1, **kw})
+    plain = both(jax.jit(functools.partial(seed_form, chunk=chunk)), args)
+    recurrence = both(jax.jit(lambda *a: op.selective_scan_recurrent(*a)[0].astype(a[0].dtype)), args)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kernels, "_BLOCK_C", block_c)
+        patch.setattr(kernels, "_BLOCK_S", block_s)
+        _as_lowered_for_tpu(patch)
+        got = both(jax.jit(functools.partial(op.selective_scan, chunk=chunk)), args)
+    return got, plain, recurrence
+
+
+@pytest.mark.parametrize("quantity", COTANGENTS)
+@pytest.mark.parametrize("name", CASES)
+def test_the_backward_kernel_gives_the_plain_forms_cotangents_and_the_recurrences(name, quantity):
+    """`s6_scan_bwd` from the states `s6_scan_fwd` wrote, against JAX's own
+    differentiation of the plain forward and of the token-by-token recurrence.
+    float32 cotangents agree to rounding; one typed bf16 (x's, B's, C's with
+    bf16 inputs) is the same float32 value rounded once, so the few elements
+    that sit on a rounding boundary differ by one bf16 step."""
+    got, plain, recurrence = _case(name)
+    i = 1 + COTANGENTS.index(quantity)
+    assert got[i].shape == plain[i].shape and got[i].dtype == plain[i].dtype == recurrence[i].dtype
+    assert bool(jnp.all(jnp.isfinite(got[i].astype(jnp.float32))))
+    rounded = got[i].dtype == jnp.bfloat16
+    assert rel(got[i], plain[i]) <= (1e-3 if rounded else 1e-5), rel(got[i], plain[i])
+    assert rel(got[i], recurrence[i]) <= (1e-3 if rounded else 10 * SCAN_TOL), rel(got[i], recurrence[i])
+
+
+def test_the_running_sum_of_that_case_passes_minus_88_inside_a_chunk():
+    kw, chunk, _ = CASES["f32-one_row-two_blocks-a_running_sum_past_minus_88"]
+    _, dt, A, *_ = inputs(21, **kw)
+    exponent = (dt[..., None] * A)[..., -1].reshape(1, -1, chunk, dt.shape[-1])
+    assert float(jnp.max(jnp.sum(exponent, axis=2))) < -88  # every chunk of every channel: exp of it is 0 in float32
+
+
+def test_the_backward_kernel_alone_from_given_states_and_blocks_of_its_own():
+    """The kernel by itself against `_plain_backward` from the same entering
+    states; programs of 128 or 256 channels and 64 or 128 positions tile the
+    same result (B's and C's partial sums are added in another order)."""
+    x, dt, A, B, C, D = inputs(23, 0.1, b=2)
+    dy = jax.random.normal(jax.random.PRNGKey(1), x.shape)
+    _, entering = kernels.s6_scan_fwd(x, dt, A.T, B, C, D, interpret=True)
+    want = op._plain_backward(x, dt, A.T, B, C, D, entering, dy, CHUNK)
+    got = kernels.s6_scan_bwd(x, dt, A.T, B, C, D, entering, dy, interpret=True)
+    wide = kernels.s6_scan_bwd(x, dt, A.T, B, C, D, entering, dy, block_c=256, block_s=128, interpret=True)
+    for name, g, w, v in zip(COTANGENTS, got, wide, want):
+        assert g.shape == v.shape and g.dtype == v.dtype, name
+        assert rel(g, v) <= 1e-5 and rel(g, w) <= 1e-6, (name, rel(g, v), rel(g, w))
+
+
+def test_under_a_checkpoint_with_qkv_attns_policy_the_gradients_are_the_same(kernel_on_the_cpu):
+    """Nothing of the scan carries a saved name: the layer's checkpoint runs
+    `s6_scan_fwd` again in the backward, and `s6_scan_bwd` once."""
+    args = inputs(25, 0.1)
+    policy = _remat_policy(types.SimpleNamespace(remat_policy="qkv_attn"))
+    loss = lambda f: (lambda *a: jnp.sum(f(*a) ** 2))
+    want = jax.jit(jax.grad(loss(op.selective_scan), argnums=range(6)))(*args)
+    got = jax.jit(jax.grad(loss(jax.checkpoint(op.selective_scan, policy=policy)), argnums=range(6)))(*args)
+    for name, g, w in zip(NAMES, got, want):
+        assert rel(g, w) <= 1e-6, name
+    text = str(jax.make_jaxpr(jax.grad(loss(jax.checkpoint(op.selective_scan, policy=policy))))(*args))
+    assert text.count("name=s6_scan_fwd") == 2 and text.count("name=s6_scan_bwd") == 1
+
+
+def test_a_step_lowered_for_tpu_holds_one_kernel_a_direction_off_the_cpu_none():
+    """The form follows the platform of the LOWERING (asked for by hand, from
+    a CPU process): `s6_scan_fwd` and `s6_scan_bwd` once each for TPU and no
+    reverse loop over `_chunk_body`'s associative scan; for the CPU the plain
+    form in both directions."""
+    args = inputs(27, 0.1, s=64, dtype=jnp.bfloat16)
+    traced = jax.jit(jax.grad(lambda *a: jnp.sum(op.selective_scan(*a).astype(jnp.float32)), argnums=range(6))).trace(*args)
+    for_tpu = traced.lower(lowering_platforms=("tpu",)).as_text()
+    for kernel in ("s6_scan_fwd", "s6_scan_bwd"):
+        assert for_tpu.count(f'kernel_name = "{kernel}"') == 1
+    assert "stablehlo.while" not in for_tpu
+    for_cpu = traced.lower(lowering_platforms=("cpu",)).as_text()
+    assert "tpu_custom_call" not in for_cpu and for_cpu.count("stablehlo.while") >= 2
+
+
+def test_off_tpu_the_backward_is_the_plain_one_bit_for_bit():
+    """No monkeypatch: through the dispatch as the CPU lowers it, the six
+    cotangents are `_plain_backward`'s (the parent's `_scan_bwd`, JAX's
+    differentiation of `_chunk_body` a chunk at a time) from the plain
+    forward's entering states, to the bit."""
+    x, dt, A, B, C, D = args = inputs(29, 0.1, b=2, dtype=jnp.bfloat16)
+    assert kernels.supported(x.shape[2], B.shape[2], x.shape[1], CHUNK)
+    y, pull = jax.vjp(op.selective_scan, *args)
+    dy = jax.random.normal(jax.random.PRNGKey(2), y.shape).astype(y.dtype)
+    A_t, D32 = A.astype(jnp.float32).T, D.astype(jnp.float32)
+    entering = op._plain_forward(x, dt, A_t, B, C, D, CHUNK)[1]
+    dx, d_dt, d_A, dB, dC, d_D = op._plain_backward(x, dt, A_t, B, C, D32, entering, dy, CHUNK)
+    for name, g, w in zip(NAMES, pull(dy), (dx, d_dt, d_A.T, dB, dC, d_D)):
+        assert g.dtype == w.dtype and bool(jnp.all(g == w)), name
+
+
 def test_off_tpu_the_custom_vjp_is_the_plain_form():
     """No monkeypatch: the CPU's lowering of the dispatch.  y and all six
     gradients are the seed's, bit for bit: the same `_chunk_body` a chunk at a
@@ -210,11 +338,12 @@ def test_on_a_mesh_the_kernel_runs_under_shard_map_over_the_batch(kernel_on_the_
         assert rel(g, w) <= 1e-6, name
 
 
-def _kernel_equations():
+def _kernel_equations(name):
     x, dt, A, B, C, D = inputs(0, 0.1, dtype=jnp.bfloat16)
-    outer = jax.make_jaxpr(functools.partial(kernels.s6_scan_fwd, interpret=False))(x, dt, A.T, B, C, D)
+    more = () if name == "s6_scan_fwd" else (jnp.zeros((x.shape[1] // CHUNK, 1, 16, x.shape[2])), x)
+    outer = jax.make_jaxpr(functools.partial(getattr(kernels, name), interpret=False))(x, dt, A.T, B, C, D, *more)
     call = next(e for e in outer.jaxpr.eqns if e.primitive.name == "pallas_call")
-    assert call.params["name"] == "s6_scan_fwd"  # what a profile and the benchmark's readers find it by
+    assert call.params["name"] == name  # what a profile and the benchmark's readers find it by
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
@@ -225,16 +354,17 @@ def _kernel_equations():
     return list(walk(call.params["jaxpr"]))
 
 
-def test_no_exponential_in_the_kernel_can_take_a_positive_argument_and_nothing_is_a_default_precision_dot():
-    """Every `exp` of the kernel's jaxpr takes the product of a row of dt and
-    A, float32, and nothing else is exponentiated or divided; the kernel has no
-    dot at all (a float32 dot at default precision is one bf16 pass in Mosaic
+@pytest.mark.parametrize("name,exps_a_position", [("s6_scan_fwd", 1), ("s6_scan_bwd", 2)])
+def test_no_exponential_in_the_kernel_can_take_a_positive_argument_and_nothing_is_a_default_precision_dot(name, exps_a_position):
+    """Every `exp` of a kernel's jaxpr takes the product of a row of dt and
+    A, float32, and nothing else is exponentiated or divided; the kernels have
+    no dot at all (a float32 dot at default precision is one bf16 pass in Mosaic
     and exact in interpret mode: PR 39's finding), and the file's text says the
-    same: one `jnp.exp(`, of `dt[...] * A`."""
-    eqns = _kernel_equations()
+    same: one `jnp.exp(`, of `dt[...] * A`, for both."""
+    eqns = _kernel_equations(name)
     made_by = {str(v): e for e in eqns for v in e.outvars}
     exps = [e for e in eqns if e.primitive.name == "exp"]
-    assert len(exps) == CHUNK  # one a position of the unrolled chunk
+    assert len(exps) == exps_a_position * CHUNK  # of the unrolled chunk: the recurrence, and in the backward a_t once more
     for e in exps:
         (arg,) = e.invars
         assert arg.aval.dtype == jnp.float32 and made_by[str(arg)].primitive.name == "mul"

@@ -189,6 +189,30 @@ def test_the_selective_scan_kernel_compiles_at_the_phi4_cells_shapes(topo):
         got = [(s.shape, s.dtype) for s in jax.tree.leaves(compiled.out_info)]
         assert got == [((1, 8192, 5120), dtype), ((256, 1, 16, 5120), jnp.float32)]
 
+def test_the_selective_scans_backward_kernel_compiles_at_the_phi4_cells_shapes(topo):
+    """Mosaic takes `s6_scan_bwd` (PR 51) at the same shapes as ONE custom
+    call: x, dt, y's cotangent and the entering states come as they are, the
+    cotangents leave in their arguments' dtypes, and what is temporary is B's
+    and C's partial sums a channel block (21 MB each) and their columns."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.pallas import selective_scan as kernels
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        args = (like((1, 8192, 5120), dtype), like((1, 8192, 5120), jnp.float32), like((16, 5120), jnp.float32),
+                like((1, 8192, 16), dtype), like((1, 8192, 16), dtype), like((5120,), jnp.float32),
+                like((256, 1, 16, 5120), jnp.float32), like((1, 8192, 5120), dtype))
+        with _no_compile_cache():
+            compiled = jax.jit(kernels.s6_scan_bwd).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 1 and "s6_scan_bwd" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
+        got = [(s.shape, s.dtype) for s in jax.tree.leaves(compiled.out_info)]
+        assert got == [((1, 8192, 5120), dtype), ((1, 8192, 5120), jnp.float32), ((16, 5120), jnp.float32),
+                       ((1, 8192, 16), dtype), ((1, 8192, 16), dtype), ((5120,), jnp.float32)]
+
 
 def test_the_hybrid_steps_scan_is_two_kernels_under_ssm_scan_and_no_chunk_by_chunk_array_reaches_hbm(request, compiled_step):
     """PR 49.  Compiled, the ops behind the scan's own `jax.jit`s carry the

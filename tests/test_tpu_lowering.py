@@ -211,19 +211,20 @@ S6 = TransformerConfig.tiny(
 )
 def test_s6_step_lowers_for_tpu_with_the_scan_kernel_inside_s6_scan(n_devices, spec, strategy, monkeypatch):
     """The forward kernel twice in the run's body (forward, and the recompute:
-    `qkv_attn` keeps the projections, not the scan's output) and no backward
-    kernel (PR 42: the backward is JAX's own, of `_chunk_body`); under
-    shard_map on a mesh like the convolution's, under `s6/scan` and its own
-    name, which is how the benchmark's readers find its time."""
+    `qkv_attn` keeps the projections, not the scan's output) and the backward
+    kernel once (PR 51: from the entering states the forward wrote); under
+    shard_map on a mesh like the convolution's, under `s6/scan` and their own
+    names, which is how the benchmark's readers find their time."""
     from ray_tpu.ops.pallas import selective_scan as kernels
 
     monkeypatch.setattr(kernels, "_BLOCK_S", 128)
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=S6, debug_info=True)
     found = _mosaic_kernels(text)
     assert found["s6_scan_fwd"] == 2 and found["ssm_conv_fwd"] == 2 and found["ssm_conv_bwd"] == 1, found
-    assert not [name for name in found if name.startswith("s6_") and name != "s6_scan_fwd"]
-    paths = [path for _, path in _kernel_paths(text, "s6_scan_fwd")]
-    assert len(paths) == 2 and all("s6/scan" in path and "s6_scan_fwd" in path for path in paths), paths
+    assert {name: count for name, count in found.items() if name.startswith("s6_")} == {"s6_scan_fwd": 2, "s6_scan_bwd": 1}
+    for kernel, count in (("s6_scan_fwd", 2), ("s6_scan_bwd", 1)):
+        paths = [path for _, path in _kernel_paths(text, kernel)]
+        assert len(paths) == count and all("s6/scan" in path and kernel in path for path in paths), paths
 
 
 def test_s6_step_lowered_for_the_cpu_holds_no_kernel():
