@@ -24,6 +24,11 @@ This pass makes both a CI property:
 Dynamically built names (f-strings like `run::{spec.name}`) are out of
 static reach and skipped — they are parameterized families, not fixed
 rows, and the timeline renders them per-instance anyway.
+
+`tracing.scope("...")` is no span call and its names are no rows here: a
+scope names a REGION of a traced function (the device's `named_scope`),
+many sites share one name by design, and what it records is one
+attribute of a `jax::trace` span, never a span of its own.
 """
 
 from __future__ import annotations

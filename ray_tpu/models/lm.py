@@ -90,9 +90,9 @@ def _row_weights(targets, mask):
 
 
 def _head_cross_entropy_fwd(constrain, x, head, targets, mask):
-    with jax.named_scope("lm_head"):
+    with tracing.scope("lm_head"):
         logits = constrain(jnp.einsum("bse,ev->bsv", x, head), LOGITS_AXES)
-    with jax.named_scope("loss"):
+    with tracing.scope("loss"):
         wide = logits.astype(jnp.float32)
         row_max = jnp.max(wide, axis=-1)
         lse = row_max + jnp.log(jnp.sum(jnp.exp(wide - row_max[..., None]), axis=-1))
@@ -103,7 +103,7 @@ def _head_cross_entropy_fwd(constrain, x, head, targets, mask):
 
 def _head_cross_entropy_bwd(constrain, residuals, g):
     x, head, logits, lse, targets, mask = residuals
-    with jax.named_scope("loss"):
+    with tracing.scope("loss"):
         # Not behind a barrier: XLA fuses this pass into the operand of both
         # matmuls below (the softmax computed twice, in registers) and never
         # writes the cotangent, which measured 3.9 ms a step faster than
@@ -112,7 +112,7 @@ def _head_cross_entropy_bwd(constrain, residuals, g):
         dlogits = jnp.where(_target_columns(logits, targets), softmax - 1.0, softmax)
         dlogits = dlogits * (g * _row_weights(targets, mask))[..., None]
         dlogits = constrain(dlogits.astype(logits.dtype), LOGITS_AXES)
-    with jax.named_scope("lm_head"):
+    with tracing.scope("lm_head"):
         dx = jnp.einsum("bsv,ev->bse", dlogits, head)
         dhead = jnp.einsum("bse,bsv->ev", x, dlogits)
     return dx, dhead, None, None
@@ -141,6 +141,12 @@ def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
     if not visited or None in visited:
         return {}
     return {WINDOW_TILES: sum(visited) / len(visited)}
+
+
+# What jax calls the three programs `LMTrainContext` builds (the `fun_name` of their `jax::trace` /
+# `jax::lower` / `jax::compile` spans in the run's record, `jit(...)` stripped), and what a reader of the
+# record may call them.
+PROGRAMS = {"_init": "init", "_forward": "apply", "_train_step": "step"}
 
 
 def default_optimizer(
@@ -231,7 +237,7 @@ class LMTrainContext:
                 params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
             ce = head_cross_entropy(
                 _constrainer(rules, self.mesh), x, head, batch["targets"], batch.get("mask"))
-            with jax.named_scope("loss"):
+            with tracing.scope("loss"):
                 counters = _window_counters(cfg, batch["tokens"].shape[1])
                 if router_stats is None:
                     return ce, counters
@@ -243,9 +249,12 @@ class LMTrainContext:
         self._loss = _loss
 
         def _train_step(state, batch):
-            (loss, terms), grads = jax.value_and_grad(_loss, has_aux=True)(
-                state["params"], batch)
-            with jax.named_scope("optimizer"):
+            # On the host only (no op's name changes): what runs after `_loss` returns, the backward
+            # pass's transposition and partial evaluation, is under a name in the trace's `scopes`.
+            with tracing.scope("autodiff", host_only=True):
+                (loss, terms), grads = jax.value_and_grad(_loss, has_aux=True)(
+                    state["params"], batch)
+            with tracing.scope("optimizer"):
                 updates, opt_state = opt.update(grads, state["opt_state"], state["params"])
                 params = optax.apply_updates(state["params"], updates)
                 grad_norm = optax.global_norm(grads)

@@ -34,6 +34,7 @@ from ray_tpu.models.mixers.base import (
 )
 from ray_tpu.ops.attention import dot_product_attention
 from ray_tpu.ops.rotary import Rope, apply_rope
+from ray_tpu.util import tracing
 
 
 def leaves(config):
@@ -82,7 +83,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
 
     # The scopes name each region in the compiled step's op metadata, which
     # is what a device trace can tell fusions apart by (PERF.md section 3).
-    with jax.named_scope("layer/attn_proj"):
+    with tracing.scope("layer/attn_proj"):
         h = stream_norm(c, x, layer_params, "ln1")
         q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(dt))
         kk = jnp.einsum("bse,ehd->bshd", h, p["wk"].astype(dt))
@@ -113,8 +114,8 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     seq_axis = ring_axis(rules, mesh, q)
     # the layer's kind, named only in a model that has two
     kind = (contextlib.nullcontext() if c.layer_windows is None
-            else jax.named_scope("attn/full" if window is None else "attn/window"))
-    with jax.named_scope("layer/attn_core"), kind:
+            else tracing.scope("attn/full" if window is None else "attn/window"))
+    with tracing.scope("layer/attn_core"), kind:
         if seq_axis is not None:
             # Sequence parallelism: activations are seq-sharded, so full
             # attention would force XLA to all-gather the sequence.  Ring
@@ -137,7 +138,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
                 batch_axes=batch_axes, head_axis=head_ax,
                 **({} if window is None else {"window": window}),
             )
-    with jax.named_scope("layer/attn_proj"):
+    with tracing.scope("layer/attn_proj"):
         attn_out = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(dt))
         return joined(c, x, attn_out, constrain), {}
 

@@ -38,6 +38,7 @@ from ray_tpu.models.mixers.base import (
     stream_norm, zeros,
 )
 from ray_tpu.ops.attention import dot_product_attention
+from ray_tpu.util import tracing
 
 # What the K/V layer hands on: its keys and values.
 SHARED_K, SHARED_V = "shared_k", "shared_v"
@@ -97,8 +98,8 @@ def _core(q, k, v, p, lambda_init, config, rules, mesh, window):
             "differential attention ('diff_attention', 'diff_cross') runs with its heads and its "
             "sequence whole: strategy 'tp' and the sequence-parallel ring do not take its pairing")
     k_of, v_of = diff_head_maps(heads, k.shape[2])
-    with jax.named_scope("layer/attn_core"):
-        with jax.named_scope("diff/full" if window is None else "diff/window"):
+    with tracing.scope("layer/attn_core"):
+        with tracing.scope("diff/full" if window is None else "diff/window"):
             keys = jnp.take(k, k_of, axis=2)
             values = jnp.take(v.reshape(b, s, v.shape[2] // 2, 2 * hd), v_of, axis=2)
             maps = dot_product_attention(
@@ -107,7 +108,7 @@ def _core(q, k, v, p, lambda_init, config, rules, mesh, window):
                 batch_axes=None if rules is None else rules.get("act_batch"), head_axis=None,
                 **({} if window is None else {"window": window}),
             )
-        with jax.named_scope("diff/combine"):
+        with tracing.scope("diff/combine"):
             maps = maps.astype(f32).reshape(b, s, heads // 2, 2, 2 * hd)
             lam = (jnp.exp(jnp.sum(p["lambda_q1"].astype(f32) * p["lambda_k1"].astype(f32)))
                    - jnp.exp(jnp.sum(p["lambda_q2"].astype(f32) * p["lambda_k2"].astype(f32))) + lambda_init)
@@ -126,7 +127,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     constrain = constrainer(rules, mesh)
     hd, q_wide = c.head_dim, c.n_heads * c.head_dim
     cross, handed = bool(shared), {}
-    with jax.named_scope("layer/attn_proj"), jax.named_scope("diff/proj"):
+    with tracing.scope("layer/attn_proj"), tracing.scope("diff/proj"):
         h = stream_norm(c, x, layer_params, "ln1")
         first = "q" if cross else "qkv"
         proj = jnp.einsum("bse,ef->bsf", h, p["w" + first].astype(dt))
@@ -142,7 +143,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
             if emit:
                 handed = {SHARED_K: kk, SHARED_V: vv}
     o = _core(q, kk, vv, p, data["lambda_init"], c, rules, mesh, window)
-    with jax.named_scope("layer/attn_proj"), jax.named_scope("diff/proj"):
+    with tracing.scope("layer/attn_proj"), tracing.scope("diff/proj"):
         out = jnp.einsum("bsf,fe->bse", o, p["wo"].astype(dt))
         if c.attn_bias:
             out = out + p["bo"].astype(dt)

@@ -16,6 +16,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.mixers import s6
 from ray_tpu.models.mixers.base import Leaf, Mixer, constrainer, joined, normal, out_scale, proj_scale, stream_norm
+from ray_tpu.util import tracing
 
 # `W_1`'s output, and the stream after `W_2`.
 GMU_GATE = "gmu_gate"
@@ -36,7 +37,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     del positions, window, data, emit
     c, dt, p = config, config.dtype, layer_params["gmu"]
     f32 = jnp.float32
-    with jax.named_scope("layer/attn_proj"), jax.named_scope("gmu"):
+    with tracing.scope("layer/attn_proj"), tracing.scope("gmu"):
         h = stream_norm(c, x, layer_params, "ln1")
         gate = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["w1"].astype(dt)), GMU_GATE)
         gated = (shared[s6.MEMORY].astype(f32) * jax.nn.silu(gate.astype(f32))).astype(dt)

@@ -28,6 +28,7 @@ from ray_tpu.models.mixers.base import (
 )
 from ray_tpu.ops.kda import kda_chunked
 from ray_tpu.ops.ssm import causal_conv1d_silu
+from ray_tpu.util import tracing
 
 # The fused q|k|v projection before its convolution, the two low-rank gates'
 # narrow halves with beta's logits (d -> 2 * D + H, one array), and the
@@ -86,28 +87,28 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     constrain, sharded = constrainer(rules, mesh), batch_sharded(rules, mesh)
     heads, dim = c.kda_heads, c.kda_head_dim
     inner = heads * dim
-    with jax.named_scope("layer/attn_proj"):
-        with jax.named_scope("kda/proj"):
+    with tracing.scope("layer/attn_proj"):
+        with tracing.scope("kda/proj"):
             h = stream_norm(c, x, layer_params, "ln1")
             qkv = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["wqkv"].astype(dt)), KDA_QKV)
             narrow = jnp.concatenate([p["f_down"], p["g_down"], p["w_beta"]], axis=-1).astype(dt)
             low = checkpoint_name(jnp.einsum("bse,ef->bsf", h, narrow), KDA_LOW)
             decay_in = jnp.einsum("bsr,rf->bsf", low[..., :dim], p["f_up"].astype(dt))
             gate_in = jnp.einsum("bsr,rf->bsf", low[..., dim: 2 * dim], p["g_up"].astype(dt))
-        with jax.named_scope("kda/conv"):
+        with tracing.scope("kda/conv"):
             qkv = causal_conv1d_silu(qkv, p["conv_w"], jnp.zeros((3 * inner,), p["conv_w"].dtype), **sharded)
             q, k, v = (a.reshape(*a.shape[:2], heads, dim) for a in jnp.split(qkv, 3, axis=-1))
             q, k = _l2_normed(q, dim ** -0.5), _l2_normed(k)
             step = jax.nn.softplus(decay_in.astype(f32) + p["dt_bias"].astype(f32))
             g = step.reshape(*step.shape[:2], heads, dim) * -jnp.exp(p["A_log"].astype(f32))[:, None]
             beta = jax.nn.sigmoid(low[..., 2 * dim:].astype(f32))
-    with jax.named_scope("layer/attn_core"):
+    with tracing.scope("layer/attn_core"):
         o = kda_chunked(q, k, v, g, beta, **sharded)
-    with jax.named_scope("layer/attn_proj"):
-        with jax.named_scope("kda/conv"):
+    with tracing.scope("layer/attn_proj"):
+        with tracing.scope("kda/conv"):
             gate = jax.nn.sigmoid(gate_in.astype(f32)).reshape(o.shape)
             o = (rms_norm(o, p["norm"], c.norm_eps) * gate).astype(dt)  # over each head's own channels
-        with jax.named_scope("kda/proj"):
+        with tracing.scope("kda/proj"):
             out = jnp.einsum("bsf,fe->bse", o.reshape(*o.shape[:2], inner), p["wo"].astype(dt))
             return checkpoint_name(joined(c, x, out, constrain), KDA_MIXED), {}
 

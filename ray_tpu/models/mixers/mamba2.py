@@ -34,6 +34,7 @@ from ray_tpu.models.mixers.base import (
     out_scale, proj_scale, rms_norm, stream_norm, zeros,
 )
 from ray_tpu.ops.ssm import causal_conv1d_silu, ssd_chunked
+from ray_tpu.util import tracing
 
 # `in_proj`'s output before its split into z, x|B|C and dt, and the residual
 # stream after the mixer, as it enters the FFN half.
@@ -84,25 +85,25 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     c, dt, ssm = config, config.dtype, layer_params["ssm"]
     constrain, sharded = constrainer(rules, mesh), batch_sharded(rules, mesh)
     heads, inner, n, groups = c.ssm_heads, c.ssm_heads * c.ssm_head_dim, c.ssm_state, c.ssm_groups
-    with jax.named_scope("layer/attn_proj"):
-        with jax.named_scope("ssm/proj"):
+    with tracing.scope("layer/attn_proj"):
+        with tracing.scope("ssm/proj"):
             h = stream_norm(c, x, layer_params, "ln1")
             zxbcdt = jnp.einsum("bse,ef->bsf", h, ssm["in_proj"].astype(dt))
             zxbcdt = checkpoint_name(zxbcdt, SSM_IN_PROJ)
             z, xbc, step = jnp.split(zxbcdt, [inner, 2 * inner + 2 * groups * n], axis=-1)
-        with jax.named_scope("ssm/conv"):
+        with tracing.scope("ssm/conv"):
             xbc = causal_conv1d_silu(xbc, ssm["conv_w"], ssm["conv_b"], **sharded)
             step = jax.nn.softplus(step.astype(jnp.float32) + ssm["dt_bias"].astype(jnp.float32))
             xs, b_in, c_out = jnp.split(xbc, [inner, inner + groups * n], axis=-1)
             if groups > 1:  # [B, S, G, N]: heads g * heads / G .. read group g
                 b_in, c_out = (a.reshape(*a.shape[:2], groups, n) for a in (b_in, c_out))
-    with jax.named_scope("layer/attn_core"):
+    with tracing.scope("layer/attn_core"):
         y = ssd_chunked(
             xs.reshape(*xs.shape[:2], heads, c.ssm_head_dim), step,
             -jnp.exp(ssm["A_log"].astype(jnp.float32)), b_in, c_out, ssm["D"], **sharded,
         )
-    with jax.named_scope("layer/attn_proj"):
-        with jax.named_scope("ssm/conv"):
+    with tracing.scope("layer/attn_proj"):
+        with tracing.scope("ssm/conv"):
             y = y.reshape(*y.shape[:2], inner)
             gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
             if groups > 1:  # each group of inner / G channels has its own statistics, the one scale is [inner]
@@ -111,7 +112,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
                 y = (by_group.reshape(gated.shape) * ssm["norm"].astype(jnp.float32)).astype(dt)
             else:
                 y = rms_norm(gated, ssm["norm"], c.norm_eps).astype(dt)  # one group: over all of d_inner
-        with jax.named_scope("ssm/proj"):
+        with tracing.scope("ssm/proj"):
             out = jnp.einsum("bsf,fe->bse", y, ssm["out_proj"].astype(dt))
             return checkpoint_name(joined(c, x, out, constrain), SSM_MIXED), {}
 

@@ -23,6 +23,7 @@ from ray_tpu.models.mixers.base import (
     ring_axis, rms_norm, stream_norm,
 )
 from ray_tpu.ops.attention import dot_product_attention
+from ray_tpu.util import tracing
 
 # The residual stream after `wo` (q, k, v carry attention's own names).
 MLA_MIXED = "mla_mixed"
@@ -54,7 +55,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     c, dt, p = config, config.dtype, layer_params["mla"]
     constrain = constrainer(rules, mesh)
     rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
-    with jax.named_scope("layer/attn_proj"), jax.named_scope("mla/proj"):
+    with tracing.scope("layer/attn_proj"), tracing.scope("mla/proj"):
         h = stream_norm(c, x, layer_params, "ln1")
         q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(dt))
         latent = jnp.einsum("bse,ef->bsf", h, p["w_kva"].astype(dt))
@@ -73,12 +74,12 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
         head_ax = fitting_axis(rules.get("act_heads"), mesh, q.shape[2])
     if ring_axis(rules, mesh, q) is not None:
         raise ValueError("an mla layer runs local attention only (no sequence-parallel ring)")
-    with jax.named_scope("layer/attn_core"):
+    with tracing.scope("layer/attn_core"):
         attn = dot_product_attention(
             q, kk, vv, causal=True, scale=q.shape[-1] ** -0.5, impl=c.attention_impl,
             mesh=mesh if rules is not None else None, batch_axes=batch_axes, head_axis=head_ax,
         )
-    with jax.named_scope("layer/attn_proj"), jax.named_scope("mla/proj"):
+    with tracing.scope("layer/attn_proj"), tracing.scope("mla/proj"):
         out = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(dt))
         return checkpoint_name(joined(c, x, out, constrain), MLA_MIXED), {}
 

@@ -29,6 +29,7 @@ from ray_tpu.models.mixers.base import (
 )
 from ray_tpu.ops.selective_scan import selective_scan
 from ray_tpu.ops.ssm import causal_conv1d_silu
+from ray_tpu.util import tracing
 
 # What the memory layer hands on: its scan's output.
 MEMORY = "memory"
@@ -78,24 +79,24 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     f32 = jnp.float32
     constrain, sharded = constrainer(rules, mesh), batch_sharded(rules, mesh)
     rank, n = dt_rank(c), c.s6_state
-    with jax.named_scope("layer/attn_proj"):
-        with jax.named_scope("s6/proj"):
+    with tracing.scope("layer/attn_proj"):
+        with tracing.scope("s6/proj"):
             h = stream_norm(c, x, layer_params, "ln1")
             xz = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["in_proj"].astype(dt)), S6_IN_PROJ)
             xs, z = jnp.split(xz, 2, axis=-1)
-        with jax.named_scope("s6/conv"):
+        with tracing.scope("s6/conv"):
             xs = causal_conv1d_silu(xs, p["conv_w"], p["conv_b"], **sharded)
-        with jax.named_scope("s6/proj"):
+        with tracing.scope("s6/proj"):
             low = jnp.einsum("bsf,fr->bsr", xs, p["x_proj"].astype(dt))
             step = jnp.einsum("bsr,rf->bsf", low[..., :rank], p["dt_proj"].astype(dt), preferred_element_type=f32)
             step = jax.nn.softplus(step + p["dt_bias"].astype(f32))
-    with jax.named_scope("layer/attn_core"):
+    with tracing.scope("layer/attn_core"):
         y = selective_scan(xs, step, -jnp.exp(p["A_log"].astype(f32)), low[..., rank: rank + n],
                            low[..., rank + n:], p["D"], **sharded)
-    with jax.named_scope("layer/attn_proj"):
-        with jax.named_scope("s6/conv"):
+    with tracing.scope("layer/attn_proj"):
+        with tracing.scope("s6/conv"):
             gated = (y.astype(f32) * jax.nn.silu(z.astype(f32))).astype(dt)
-        with jax.named_scope("s6/proj"):
+        with tracing.scope("s6/proj"):
             out = jnp.einsum("bsf,fe->bse", gated, p["out_proj"].astype(dt))
             return checkpoint_name(joined(c, x, out, constrain), S6_MIXED), ({MEMORY: y} if emit else {})
 

@@ -122,6 +122,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.parallel.sharding import Rules, _fit_spec, logical_to_spec
+from ray_tpu.util import tracing
 
 
 def moe_param_axes(config: Any) -> Dict:
@@ -368,7 +369,7 @@ def _experts(tokens, expert_idx, gates, weights, n_experts, first_expert=None):
     k = expert_idx.shape[1]
     n_local = weights[0].shape[0]
     if first_expert is not None:
-        with jax.named_scope("moe/dispatch"):
+        with tracing.scope("moe/dispatch"):
             flat = (expert_idx.reshape(-1) - first_expert) % n_experts  # this rank's experts first
             order, inverse, group_sizes = _by_expert(flat, n_local)
             g_sorted = _permuted(gates.reshape(-1), inverse, order)
@@ -377,14 +378,14 @@ def _experts(tokens, expert_idx, gates, weights, n_experts, first_expert=None):
         rung = jnp.sum(jnp.sum(group_sizes) > jnp.asarray(rungs[:-1], jnp.int32), dtype=jnp.int32)
         out = _sized_experts(tokens, g_sorted, tuple(weights), order, inverse, group_sizes, rung, k, rungs)
         return out, group_sizes, jnp.asarray(rungs, jnp.float32)[rung] / flat.shape[0]
-    with jax.named_scope("moe/dispatch"):
+    with tracing.scope("moe/dispatch"):
         order, inverse, group_sizes = _by_expert(expert_idx.reshape(-1), n_local)
         rows = _to_expert_order(tokens, order, inverse, k)
         g_row = _permuted(gates.reshape(-1), inverse, order).astype(rows.dtype)[:, None]
-    with jax.named_scope("moe/experts"):
+    with tracing.scope("moe/experts"):
         hidden = _activation(rows, weights[:-1], lambda h, w: grouped_matmul(h, w, group_sizes))
         out = grouped_matmul(hidden * g_row, weights[-1], group_sizes)
-    with jax.named_scope("moe/combine"):
+    with tracing.scope("moe/combine"):
         return _to_token_order(out, order, inverse, k), group_sizes, 1.0
 
 
@@ -418,17 +419,17 @@ def _rung_forward(r, k, tokens, g_sorted, weights, order, inverse, group_sizes):
     movement, a permutation."""
     held = jnp.sum(group_sizes)
     whole = r == order.shape[0]
-    with jax.named_scope("moe/dispatch"):
+    with tracing.scope("moe/dispatch"):
         mine = (jnp.arange(r, dtype=jnp.int32) < held)[:, None]
         if whole:
             rows = jnp.where(mine, _to_expert_order(tokens, order, inverse, k), 0)
         else:
             rows = _rows_of_tokens(tokens, order, inverse, held, k, r)
         g_row = jnp.where(mine, g_sorted[:r].astype(rows.dtype)[:, None], 0)
-    with jax.named_scope("moe/experts"):
+    with tracing.scope("moe/experts"):
         hidden = _activation(rows, weights[:-1], lambda h, w: grouped_matmul(h, w, group_sizes))
         out = grouped_matmul(hidden * g_row, weights[-1], group_sizes)
-    with jax.named_scope("moe/combine"):
+    with tracing.scope("moe/combine"):
         if whole:
             return _to_token_order(jnp.where(mine, out, 0), order, inverse, k)
         return _tokens_of_rows(out, order, inverse, held, k, r)
@@ -482,7 +483,7 @@ def moe_ffn(
     inside its `layer/mlp` scope (PERF.md section 3)."""
     B, S, D = x.shape
     held = config.n_experts_held is not None
-    with jax.named_scope("moe/router"):
+    with tracing.scope("moe/router"):
         expert_idx, gates, stats = _route(params, x.reshape(B * S, D), config)
     expert_idx = expert_idx.reshape(B, S, -1)
     gates = gates.reshape(B, S, -1)
@@ -512,7 +513,7 @@ def moe_ffn(
     def with_shared(y):
         if not config.shared_expert_width:
             return y
-        with jax.named_scope("moe/shared"):
+        with tracing.scope("moe/shared"):
             w = [params["shared"][k].astype(x.dtype) for k in expert_leaves(config)]
             hidden = _activation(x, w[:-1], lambda h, m: jnp.einsum("bse,ef->bsf", h, m))
             return y + jnp.einsum("bsf,fe->bse", hidden, w[-1])

@@ -93,6 +93,7 @@ from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT
 from ray_tpu.ops.rotary import Rope
 from ray_tpu.parallel.sharding import Rules, pipeline_axes, with_logical_constraint
+from ray_tpu.util import tracing
 
 
 # The logical axes of the logits (and of their cotangent).
@@ -563,7 +564,7 @@ def _ffn_half(x, layer_params, config, constrain, rules, mesh, ffn=None):
         ffn = "dense" if c.n_experts is None else "experts"
     if ffn == "none":
         return x, None
-    with jax.named_scope("layer/mlp"):
+    with tracing.scope("layer/mlp"):
         h = stream_norm(c, x, layer_params, "ln2")
         if ffn == "experts":
             down, router_stats = moe_ffn(layer_params["mlp"], h, c, rules=rules, mesh=mesh)
@@ -772,7 +773,7 @@ def forward(
     `rules` come with the `mesh` they refer to: ring attention, the pipeline
     schedule and the flash kernel's shard_map are all built from it."""
     x, head, _ = trunk(params, tokens, config, rules=rules, mesh=mesh)
-    with jax.named_scope("lm_head"):
+    with tracing.scope("lm_head"):
         logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
         return _constrainer(rules, mesh)(logits, LOGITS_AXES)
 
@@ -793,7 +794,7 @@ def trunk(
     c = config
     if rules is not None and mesh is None:
         raise ValueError("forward(rules=...) needs the mesh the rules refer to")
-    with jax.named_scope("embed"):
+    with tracing.scope("embed"):
         x = params["embed"]["tokens"].astype(c.dtype)[tokens]
         if c.embedding_multiplier != 1.0:
             x = x * jnp.asarray(c.embedding_multiplier, c.dtype)
@@ -805,7 +806,7 @@ def trunk(
     # weights sliced out of the stack, gradients and residuals stacked back);
     # the regions of a layer are named inside it.
     router_stats = None
-    with jax.named_scope("layers"):
+    with tracing.scope("layers"):
         if pp is not None:
             stack = params.get(MIXERS[_DEFAULT_MIXER].stack)  # None: a stack it refuses by name
             x = _run_layers_pipelined(stack, x, positions, c, mesh, pp[0], rules=rules, fsdp_axis=pp[1])
@@ -851,9 +852,9 @@ def trunk(
                 router_stats = per_run[0]
             elif per_run:
                 router_stats = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, axis=0), *per_run)
-    with jax.named_scope("final_norm"):
+    with tracing.scope("final_norm"):
         x = stream_norm(c, x, params, "final_norm")
-    with jax.named_scope("lm_head"):
+    with tracing.scope("lm_head"):
         head = (
             params["embed"]["tokens"].T if c.tie_embeddings else params["lm_head"]
         ).astype(c.dtype)

@@ -29,6 +29,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.util import tracing
+
 logger = logging.getLogger(__name__)
 
 # (m, k, n) -> times a step was traced with a shape the kernels refuse, so
@@ -106,5 +108,5 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> ja
     if shape not in refused_shapes:
         logger.warning("grouped_matmul: the TPU kernels refuse m, k, n = %s; the XLA form runs on every platform", shape)
     refused_shapes[shape] = refused_shapes.get(shape, 0) + 1
-    with jax.named_scope(REFUSED_SCOPE):
+    with tracing.scope(REFUSED_SCOPE):
         return grouped_matmul_xla(lhs, rhs, group_sizes)

@@ -102,6 +102,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.sharding import _fit_spec
+from ray_tpu.util import tracing
 
 # The precision of every matmul here (module docstring).
 EXACT = jax.lax.Precision.HIGH
@@ -353,7 +354,7 @@ def kda_chunked(
         raise ValueError(f"kda_chunked: sequence length {s} needs a power-of-two chunk that divides it, got {chunk}")
 
     def run(q, k, v, g, beta):  # the scope INSIDE what shard_map wraps: its body starts a name stack of its own
-        with jax.named_scope("kda/scan"):
+        with tracing.scope("kda/scan"):
             return _kda(q, k, v, g, beta, chunk)
 
     if mesh is None or not _kernel_takes(k, v, chunk):

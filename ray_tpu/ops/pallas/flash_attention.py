@@ -51,6 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, _repeat_kv
+from ray_tpu.util import tracing
 
 NEG_INF = -1e30
 _LANES = 128  # VPU lane count: row-scalar scratch is kept lane-broadcast
@@ -68,7 +69,7 @@ def _pallas_call(kernel, *, name: str, **kwargs):
     interpreted = pl.pallas_call(kernel, name=name, interpret=True, **kwargs)
 
     def call(*args):
-        with jax.named_scope(name):
+        with tracing.scope(name, kernel=True):
             return jax.lax.platform_dependent(*args, tpu=compiled, cpu=interpreted)
 
     return call

@@ -70,6 +70,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.util import tracing
+
 CHUNK = 64  # the kernel's chunk: two of them are one [128, 128] tile
 _PAIR = 2 * CHUNK
 _LANES = 128  # K and V: one lane tile each
@@ -233,7 +235,7 @@ def kda_fwd(q, k, v, g, beta, *, pair_states=False, interpret=False):
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")),
     )
-    with jax.named_scope("kda_fwd"):
+    with tracing.scope("kda_fwd", kernel=True):
         return call(q, k, v, g, beta)
 
 def _thirds(x):
@@ -457,6 +459,6 @@ def kda_bwd(q, k, v, g, beta, states, d_o, *, interpret=False):
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")),
     )
-    with jax.named_scope("kda_bwd"):
+    with tracing.scope("kda_bwd", kernel=True):
         *d_inputs, dbeta = call(q, k, v, g, beta, states, d_o)
         return (*d_inputs, jnp.moveaxis(dbeta[:, :, 0], 1, 2).astype(beta.dtype))

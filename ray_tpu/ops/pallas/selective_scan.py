@@ -54,6 +54,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.pallas.ssm_conv import _tile
 from ray_tpu.ops.selective_scan import CHUNK
+from ray_tpu.util import tracing
 
 _LANES = 128
 _SUBLANES = 8
@@ -278,7 +279,7 @@ def s6_scan_fwd(x, dt, A_t, B, C, D, *, chunk: int = CHUNK, block_c=None, block_
             flops=9 * x.size * n, transcendentals=x.size * n,
             bytes_accessed=x.size * (2 * x.dtype.itemsize + 4) + 4 * x.size * n // chunk),
     )
-    with jax.named_scope("s6_scan_fwd"):
+    with tracing.scope("s6_scan_fwd", kernel=True):
         return call(x, dt.astype(f32), A_t.astype(f32), columns(B, chunk), columns(C, chunk),
                     D.astype(f32)[None, :])
 
@@ -327,7 +328,7 @@ def s6_scan_bwd(x, dt, A_t, B, C, D, entering, dy, *, chunk: int = CHUNK, block_
             flops=30 * x.size * n, transcendentals=2 * x.size * n,
             bytes_accessed=x.size * (3 * x.dtype.itemsize + 8) + 4 * x.size * n // chunk + 8 * partials.size),
     )
-    with jax.named_scope("s6_scan_bwd"):
+    with tracing.scope("s6_scan_bwd", kernel=True):
         dx, ddt, dA, dD, dB, dC = call(x, dt.astype(f32), dy, A_t.astype(f32), columns(B, chunk),
                                        columns(C, chunk), D.astype(f32)[None, :], entering)
         return (dx, ddt, jnp.sum(dA, axis=0), _rows(dB, chunk).astype(B.dtype), _rows(dC, chunk).astype(C.dtype),

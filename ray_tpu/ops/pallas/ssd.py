@@ -60,6 +60,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.util import tracing
+
 _LANES = 128
 _SUBLANES = 8
 _TILE = 128  # rows and columns of a block of the [chunk, chunk] matrices
@@ -342,7 +344,7 @@ def ssd_fwd(x, dt, cum, B, C, D, *, chunk: int, interpret=False):
         scratch_shapes=[pltpu.VMEM((n, hb * p), jnp.float32)],
         compiler_params=_PARAMS,
     )
-    with jax.named_scope("ssd_fwd"):
+    with tracing.scope("ssd_fwd", kernel=True):
         y, entering = call(*operands)
         return y.reshape(x.shape), entering
 
@@ -374,7 +376,7 @@ def ssd_bwd(x, dt, cum, B, C, D, entering, dy, *, chunk: int, interpret=False):
         scratch_shapes=[pltpu.VMEM((n, hb * p), f32)],
         compiler_params=_PARAMS,
     )
-    with jax.named_scope("ssd_bwd"):
+    with tracing.scope("ssd_bwd", kernel=True):
         dx, ddt, dcum, dcum_row, dB, dC, dD = call(*operands, entering, dy.reshape(b, s, h * p))
         positions = lambda a: a.transpose(0, 2, 1, 3).reshape(b, s, h)  # [b, programs, S, heads] -> [b, S, H]
         by_group = lambda a: a.reshape(b, groups, programs // groups, s, n).sum(axis=2)  # a group's programs

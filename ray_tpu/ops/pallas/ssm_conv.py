@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.ssm import _dsilu
+from ray_tpu.util import tracing
 
 _HALO = 128  # positions of a neighbouring block a block sees: one lane tile
 _SUBLANES = 16  # channels per block come in whole bf16 sublane tiles
@@ -131,7 +132,7 @@ def _call(kernel, name: str, interpret: bool, **kwargs):
     call = pl.pallas_call(kernel, name=name, interpret=interpret, **kwargs)
 
     def named(*args):
-        with jax.named_scope(name):
+        with tracing.scope(name, kernel=True):
             return call(*args)
 
     return named

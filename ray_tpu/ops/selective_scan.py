@@ -81,6 +81,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.sharding import _fit_spec
+from ray_tpu.util import tracing
 
 # Positions a chunk holds: log2 levels of the associative scan inside, S / CHUNK serial steps outside
 # (module docstring: why 32).
@@ -243,7 +244,7 @@ def selective_scan(
         raise ValueError(f"selective_scan: sequence length {s} is not a multiple of the chunk {chunk}")
 
     def run(x, dt, A, B, C, D):  # the scope INSIDE what shard_map wraps: its body starts a name stack of its own
-        with jax.named_scope("s6/scan"):
+        with tracing.scope("s6/scan"):
             return _scan(x, dt, A, B, C, D, chunk)
 
     if mesh is None or not _kernel_takes(x, B, chunk):

@@ -82,6 +82,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.sharding import _fit_spec
+from ray_tpu.util import tracing
 
 # The published chunk length (`mamba_chunk_size`); the program's own constant.
 CHUNK = 256
@@ -321,7 +322,7 @@ def _ssd_fwd(x, dt, A, B, C, D, chunk: int):
     def plain(x, dt, A, B, C, D):
         return _plain_forward(x, dt, A, B, C, D, chunk), jnp.zeros((b, s // chunk, B.shape[-1], h * p), jnp.float32)
 
-    with jax.named_scope("ssm/scan"):
+    with tracing.scope("ssm/scan"):
         kernel = functools.partial(_kernel_forward, chunk=chunk)
         y, entering = jax.lax.platform_dependent(x, dt, A, B, C, D, tpu=kernel, default=plain)
     return y, (x, dt, A, B, C, D, entering)
@@ -331,7 +332,7 @@ def _ssd_bwd(chunk: int, res, dy):
     def plain(x, dt, A, B, C, D, entering, dy):
         return jax.vjp(functools.partial(_plain_forward, chunk=chunk), x, dt, A, B, C, D)[1](dy)
 
-    with jax.named_scope("ssm/scan"):
+    with tracing.scope("ssm/scan"):
         kernel = functools.partial(_kernel_backward, chunk=chunk)
         return jax.lax.platform_dependent(*res, dy, tpu=kernel, default=plain)
 
@@ -373,7 +374,7 @@ def ssd_chunked(
     if s % chunk:
         raise ValueError(f"ssd_chunked: sequence length {s} is not a multiple of the chunk {chunk}")
     if not _scan_kernels().supported(h, p, B.shape[-1], groups or 1, s, chunk):
-        with jax.named_scope("ssm/scan"):
+        with tracing.scope("ssm/scan"):
             return _plain_forward(x, dt, A, B, C, D, chunk)
     run = functools.partial(_ssd, chunk=chunk)  # names its scope INSIDE what shard_map wraps, whose body starts a name stack
     if mesh is None:

@@ -7,6 +7,12 @@ Always on, whether `RAY_TPU_TRACE` is set or not:
     start (placement group, worker spawn, the worker's boot, the backend's
     `on_start`: jax import, the wait for the chips, the chips' open), the
     train function, every trace / lowering / compile jax reports, shutdown.
+    Of the traces the OUTERMOST are spans (`jax::trace`); one nested in
+    another leaves no span, and leaves the seconds its body spent under the
+    program's own scopes (`tracing.scope`: the model's `named_scope`s, which
+    Python enters only while jax traces) in the outer span's `scopes`
+    (`path -> [self seconds, entries]`), beside `kernels` (the names that are
+    kernels' own) and `unscoped_s` (the span less the table).
     The driver's come from its own store, the workers' ride their `poll`
     replies (the same spans also take the flush to the head, for `ray_tpu
     timeline`);
@@ -242,11 +248,18 @@ def flush_traces() -> None:
     """Record this thread's outermost traces.  jax reports a trace when it
     ENDS, the traces nested in it (every jitted `jnp` function the body
     calls: a thousand for a toy model) before it: they are held back until
-    an enclosing one swallows them or a lowering shows the trace is over."""
+    an enclosing one swallows them or a lowering shows the trace is over.
+    A swallowed trace leaves no span; the seconds its body spent under the
+    program's scopes (`tracing.scope`) stay in the thread's table and land,
+    with the outer body's own, on the span of the trace that encloses it."""
     held, _thread.traces = getattr(_thread, "traces", ()), []
     for start, end, fun_name in held:
-        tracing.record_span("jax::trace", start, end, parent=_parent(),
-                            attrs={"fun_name": fun_name}, lifecycle=True)
+        attrs: Dict[str, Any] = {"fun_name": fun_name}
+        taken = tracing.take_scopes(start, end)
+        if taken is not None:
+            attrs["scopes"], attrs["kernels"] = taken
+            attrs["unscoped_s"] = max((end - start) - sum(row[0] for row in taken[0].values()), 0.0)
+        tracing.record_span("jax::trace", start, end, parent=_parent(), attrs=attrs, lifecycle=True)
 
 
 def _on_time_span(event: str, start: float, end: float, **kw) -> None:

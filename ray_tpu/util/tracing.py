@@ -16,7 +16,11 @@ parents to its submitter's span across processes.  Same design here:
     into `spec.trace_ctx` as a W3C-traceparent-style dict, execution
     adopts it, so nested submits chain naturally;
   * spans record to an in-process buffer that workers flush to the head
-    (state API / timeline).
+    (state API / timeline);
+  * SCOPES are not spans: `scope(name)` names a region of a function jax
+    traces (`jax.named_scope` plus a per-thread table of self seconds and
+    entries by path), and the table rides as one attribute on the
+    `jax::trace` span that covers it (`train/run_record.py`).
 """
 
 from __future__ import annotations
@@ -28,7 +32,7 @@ import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 _enabled = os.environ.get("RAY_TPU_TRACE", "") not in ("", "0")
 _current: "contextvars.ContextVar[Optional[Dict[str, str]]]" = contextvars.ContextVar(
@@ -198,6 +202,113 @@ def annotate(name: str, lifecycle: bool = False):
     finally:
         if on:
             record_span(name, start, time.time(), parent=_current.get(), lifecycle=lifecycle)
+
+
+# -- scopes: the names the program gives its regions while jax traces it ----------
+
+_clock = time.time  # the clock of jax's own trace events, so a block can be placed inside one
+_BLOCKS_KEPT = 256  # closed outermost blocks a thread keeps until a span takes them
+_scopes = threading.local()
+
+
+class _ScopeState:
+    """One thread's open scopes and the outermost blocks it has closed."""
+
+    __slots__ = ("stack", "table", "kernels", "closed")
+
+    def __init__(self):
+        self.stack: List[list] = []  # [path, start, seconds under children], outermost first
+        self.table: Dict[str, List[Any]] = {}  # path -> [self seconds, entries], of the block that is open
+        self.kernels: set = set()
+        self.closed: Deque[tuple] = deque(maxlen=_BLOCKS_KEPT)  # (start, end, table, kernels), oldest first
+
+
+class scope:
+    """`with tracing.scope(name):` is how the program names a region.
+
+    It enters `jax.named_scope(name)` if and only if jax is already imported
+    (taken from `sys.modules`, as `annotate` does), so the ops traced inside
+    carry the name to the device, and it accounts for the block on the
+    calling thread: a table `path -> [self seconds, entries]`, `path` the
+    open scopes' names joined by `/`, self seconds the block's less its
+    children's.  Python runs the block only while jax TRACES the function
+    around it; a compiled step never enters it, so the cost is the tracing's:
+    two clock reads and a dictionary update an entry.
+
+    `host_only=True` accounts and opens no `named_scope` (no op's metadata
+    changes): for a region that must have a name on the host and none in the
+    HLO.  `kernel=True` says the name is a kernel's own (the scope around a
+    `pallas_call`), for the readers that tell kernels' bodies from the rest.
+
+    When the outermost scope of a thread closes, its table is kept as one
+    block; `take_scopes` hands the blocks inside an interval to the span that
+    covers them (`train/run_record.py`: the outermost `jax::trace`)."""
+
+    __slots__ = ("name", "kernel", "host_only", "_named")
+
+    def __init__(self, name: str, *, kernel: bool = False, host_only: bool = False):
+        self.name = name
+        self.kernel = kernel
+        self.host_only = host_only
+        self._named = None
+
+    def __enter__(self) -> None:
+        start = _clock()
+        jax = None if self.host_only else sys.modules.get("jax")
+        if jax is not None:
+            self._named = jax.named_scope(self.name)
+            self._named.__enter__()
+        state = getattr(_scopes, "state", None)
+        if state is None:
+            state = _scopes.state = _ScopeState()
+        stack = state.stack
+        stack.append([stack[-1][0] + "/" + self.name if stack else self.name, start, 0.0])
+        if self.kernel:
+            state.kernels.add(self.name)
+
+    def __exit__(self, *exc) -> None:
+        try:
+            if self._named is not None:
+                named, self._named = self._named, None
+                named.__exit__(*exc)
+        finally:
+            state = _scopes.state
+            path, start, under = state.stack.pop()
+            end = _clock()
+            row = state.table.get(path)
+            if row is None:
+                state.table[path] = [end - start - under, 1]
+            else:
+                row[0] += end - start - under
+                row[1] += 1
+            if state.stack:
+                state.stack[-1][2] += end - start
+            else:
+                state.closed.append((start, end, state.table, state.kernels))
+                state.table, state.kernels = {}, set()
+
+
+def take_scopes(start: float, end: float) -> Optional[Tuple[Dict[str, List[Any]], List[str]]]:
+    """(table, kernel names) of what the calling thread's scopes accrued in
+    blocks that lie inside [start, end], summed, or None if there is none.
+    Blocks that ended before `start` belong to no span (an eager call) and
+    are dropped; later ones stay for the span that covers them."""
+    state = getattr(_scopes, "state", None)
+    if state is None:
+        return None
+    table: Dict[str, List[Any]] = {}
+    kernels: set = set()
+    closed = state.closed
+    while closed and closed[0][0] < end:
+        b_start, b_end, rows, names = closed.popleft()
+        if b_start < start or b_end > end:
+            continue
+        kernels |= names
+        for path, (self_s, entries) in rows.items():
+            row = table.setdefault(path, [0.0, 0])
+            row[0] += self_s
+            row[1] += entries
+    return (table, sorted(kernels)) if table else None
 
 
 def drain_spans() -> List[Dict[str, Any]]:

@@ -332,3 +332,180 @@ def test_five_thousand_steps_leave_the_ring_bounded_no_step_span_and_cost_micros
     assert with_clock - without < 20e-6, (with_clock, without)
     assert [s for s in tracing.drain_spans() if s["name"].startswith("train_step/")] == []
     run_record.drain_stalls()
+
+
+# -- scopes: the table of `tracing.scope` and the span it lands on ---------------------
+
+
+class FakeClock:
+    """`tracing._clock`, moved by hand."""
+
+    def __init__(self, now=100.0):
+        self.now = now
+
+    def __call__(self):
+        return self.now
+
+    def tick(self, seconds):
+        self.now += seconds
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    fake = FakeClock()
+    monkeypatch.setattr(tracing, "_clock", fake)
+    monkeypatch.setattr(tracing._scopes, "state", tracing._ScopeState(), raising=False)
+    run_record.flush_traces()
+    yield fake
+    tracing.drain_spans()  # spans at the fake clock's times: not for the next runtime's flush to its head
+
+
+def _host(name, **kw):
+    return tracing.scope(name, host_only=True, **kw)
+
+
+def _nested(clock):
+    with _host("a"):
+        clock.tick(1.0)
+        with _host("b"):
+            clock.tick(2.0)
+            with _host("c", kernel=True):
+                clock.tick(4.0)
+        clock.tick(8.0)
+    return 15.0, {"a": [9.0, 1], "a/b": [2.0, 1], "a/b/c": [4.0, 1]}, ["c"]
+
+
+def _siblings(clock):
+    with _host("a"):
+        with _host("b"):
+            clock.tick(1.0)
+        clock.tick(2.0)
+        with _host("c"):
+            clock.tick(4.0)
+    return 7.0, {"a": [2.0, 1], "a/b": [1.0, 1], "a/c": [4.0, 1]}, []
+
+
+def _re_entered(clock):
+    with _host("a"):
+        for seconds in (1.0, 2.0):
+            with _host("layer/attn_proj"):
+                clock.tick(seconds)
+                with _host("k", kernel=True):
+                    clock.tick(0.5)
+    return 4.0, {"a": [0.0, 1], "a/layer/attn_proj": [3.0, 2], "a/layer/attn_proj/k": [1.0, 2]}, ["k"]
+
+
+def _raises_inside(clock):
+    with pytest.raises(ValueError):
+        with _host("a"):
+            clock.tick(1.0)
+            with _host("b"):
+                clock.tick(2.0)
+                raise ValueError("inside b")
+    assert tracing._scopes.state.stack == []  # the exception closed both
+    return 3.0, {"a": [1.0, 1], "a/b": [2.0, 1]}, []
+
+
+@pytest.mark.parametrize("blocks", [_nested, _siblings, _re_entered, _raises_inside])
+def test_scope_table_self_seconds_sum_to_the_outer_block_on_a_fake_clock(clock, blocks):
+    start = clock()
+    total, table, kernels = blocks(clock)
+    got = tracing.take_scopes(start, clock())
+    assert got == (table, kernels)
+    assert sum(row[0] for row in got[0].values()) == total == clock() - start
+    assert tracing.take_scopes(start, clock()) is None  # taken: the table is empty
+
+
+def test_scope_without_jax_accounts_and_opens_nothing():
+    code = (
+        "import sys, time\n"
+        "from ray_tpu.util import tracing\n"
+        "t0 = time.time()\n"
+        "with tracing.scope('layers'):\n"
+        "    with tracing.scope('moe_gmm', kernel=True):\n"
+        "        time.sleep(0.01)\n"
+        "table, kernels = tracing.take_scopes(t0, time.time())\n"
+        "assert 'jax' not in sys.modules\n"
+        "assert sorted(table) == ['layers', 'layers/moe_gmm'] and kernels == ['moe_gmm'], table\n"
+        "assert table['layers/moe_gmm'][0] >= 0.01 and table['layers'][1] == 1, table\n"
+    )
+    proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=dict(os.environ), capture_output=True, text=True)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def _trace_spans_since(before):
+    run_record.flush_traces()
+    return [s for s in tracing.lifecycle_spans()[before:] if s["name"] == "jax::trace"]
+
+
+def test_an_outermost_trace_span_carries_the_table_and_a_nested_trace_adds_to_it(clock):
+    """jax reports the nested trace first, as it ends; it leaves no span and
+    what its body spent under scopes is in the outer span's table."""
+    before = len(tracing.lifecycle_spans())
+    with _host("eager"):  # before any trace began: no span's
+        clock.tick(0.5)
+    start = clock()
+    clock.tick(0.25)  # under no scope
+    with _host("autodiff"):
+        clock.tick(1.0)
+        inner = clock()
+        with _host("layers"):
+            clock.tick(2.0)
+        run_record._on_time_span(run_record._TRACE, inner, clock(), fun_name="_rung_forward")
+        with _host("kda_fwd", kernel=True):
+            clock.tick(0.5)
+    with _host("optimizer"):
+        clock.tick(0.125)
+    run_record._on_time_span(run_record._TRACE, start, clock(), fun_name="_train_step")
+    (span,) = _trace_spans_since(before)
+    attrs = span["attrs"]
+    assert attrs["fun_name"] == "_train_step" and attrs["kernels"] == ["kda_fwd"]
+    assert attrs["scopes"] == {"autodiff": [1.0, 1], "autodiff/layers": [2.0, 1], "autodiff/kda_fwd": [0.5, 1],
+                               "optimizer": [0.125, 1]}
+    assert attrs["unscoped_s"] == 0.25
+    assert sum(row[0] for row in attrs["scopes"].values()) + attrs["unscoped_s"] == span["end"] - span["start"]
+
+
+def test_a_real_traces_span_adds_up_to_its_duration_and_leaves_other_threads_seconds_out():
+    import threading
+
+    assert run_record.install_jax_listener()
+    before = len(tracing.lifecycle_spans())
+
+    def elsewhere():
+        with _host("other_thread"):
+            time.sleep(0.002)
+
+    @jax.jit
+    def inner(x):
+        with tracing.scope("layer/mlp"):
+            time.sleep(0.004)
+            return jnp.tanh(x)
+
+    def outer(x):
+        with tracing.scope("layers"):
+            time.sleep(0.002)
+            other = threading.Thread(target=elsewhere)
+            other.start()
+            other.join(timeout=10)
+            x = inner(x)
+        time.sleep(0.003)
+        return x
+
+    with _host("before_the_trace"):
+        time.sleep(0.002)
+    jax.jit(outer).trace(jnp.ones((4,)))
+    (span,) = [s for s in _trace_spans_since(before) if s["attrs"]["fun_name"] == "outer"]
+    attrs = span["attrs"]
+    assert sorted(attrs["scopes"]) == ["layers", "layers/layer/mlp"] and attrs["kernels"] == []
+    assert attrs["scopes"]["layers/layer/mlp"][0] >= 0.004 and attrs["scopes"]["layers"][0] >= 0.002
+    assert attrs["unscoped_s"] >= 0.003
+    total = sum(row[0] for row in attrs["scopes"].values()) + attrs["unscoped_s"]
+    assert total == pytest.approx(span["end"] - span["start"], abs=1e-3)
+
+
+def test_a_trace_with_an_empty_table_has_none_of_the_three_keys(clock):
+    before = len(tracing.lifecycle_spans())
+    run_record._on_time_span(run_record._TRACE, clock(), clock() + 0.5, fun_name="no_scopes")
+    (span,) = _trace_spans_since(before)
+    assert span["attrs"] == {"fun_name": "no_scopes"}

@@ -461,6 +461,17 @@ def test_span_catalog_sees_annotate_calls():
             "worker::boot::connect", "runtime::shutdown"} <= set(catalog)
 
 
+def test_span_catalog_takes_no_scope_for_a_span():
+    """Scopes are regions of a traced function, shared by many sites: not spans, not in `span_names.txt`."""
+    from ray_tpu._private.analysis import span_names
+
+    calls = [n for n in ast.walk(ast.parse(
+        "with tracing.scope('layers'): pass\nwith scope('autodiff', host_only=True): pass\n")) if isinstance(n, ast.Call)]
+    assert list(filter(None, map(span_names._span_call_name, calls))) == []
+    catalog = span_names.load_catalog(os.path.join(ROOT, "ray_tpu/_private/analysis/span_names.txt"))
+    assert not {"layers", "autodiff", "optimizer", "layer/attn_proj", "kda_fwd"} & set(catalog)
+
+
 # -- the compile cache's key --------------------------------------------------------------
 
 
@@ -506,3 +517,97 @@ def test_two_builds_that_differ_only_by_a_scope_share_a_cache_entry_only_by_jax_
     proc = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert int(proc.stdout.strip().splitlines()[-1]) == entries
+
+
+# -- tracing.scope: the device's names, and the same names on the host while jax traces -------
+
+
+def test_scope_gives_the_lowered_text_named_scope_gave():
+    """`tracing.scope` IS `jax.named_scope` to the ops traced inside it; its
+    host-only form names nothing."""
+    import contextlib
+
+    def toy(x, named, on_the_host):
+        with named("layers"), named("layer/mlp"):
+            x = jnp.tanh(x) @ x
+        with on_the_host("autodiff"):
+            return x + 1.0
+
+    forms = ((tracing.scope, functools.partial(tracing.scope, host_only=True)),
+             (jax.named_scope, lambda name: contextlib.nullcontext()))
+    ours, jaxs = [jax.jit(functools.partial(toy, named=named, on_the_host=on_the_host)).lower(jnp.ones((8, 8)))
+                  .as_text(debug_info=True) for named, on_the_host in forms]
+    assert "layers/layer/mlp/" in ours and "autodiff" not in ours
+    assert ours == jaxs
+
+
+STEPS = {"dense": (CFG, KERNELS), "experts": (MOE_CFG, KERNELS + MOE_KERNELS),
+         "hybrid": (HYBRID_CFG, KERNELS + ("ssm_conv_fwd", "ssm_conv_bwd")),
+         "kimi": (KIMI_CFG, KERNELS + MOE_KERNELS + ("kda_fwd", "kda_bwd", "ssm_conv_fwd", "ssm_conv_bwd"))}
+
+
+@pytest.fixture(scope="module", params=sorted(STEPS))
+def step_trace_span(request):
+    """(the `jax::trace` span of a tiny step, the kernel names it must say)."""
+    from ray_tpu.train import run_record
+
+    assert run_record.install_jax_listener()
+    cfg, kernels = STEPS[request.param]
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    # As in a fresh process: a body jax has traced (a jitted rung of the experts, the rules of the convolution's
+    # `custom_vjp` at the same shapes) is not entered again, and the steps above have traced these.
+    jax.clear_caches()
+    run_record.flush_traces()
+    before = len(tracing.lifecycle_spans())
+    ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
+    run_record.flush_traces()
+    (span,) = [s for s in tracing.lifecycle_spans()[before:] if s["attrs"].get("fun_name") == "_train_step"]
+    return span, kernels
+
+
+def test_the_steps_trace_span_names_autodiff_optimizer_the_layers_and_each_kernel(step_trace_span):
+    span, kernels = step_trace_span
+    attrs = span["attrs"]
+    paths = set(attrs["scopes"])
+    assert {"autodiff", "optimizer"} <= paths
+    assert all(p == "optimizer" or p.split("/")[0] == "autodiff" for p in paths), sorted(paths)
+    assert any(p.endswith("layer/attn_proj") and "layers" in p.split("/") for p in paths), sorted(paths)
+    assert set(kernels) <= set(attrs["kernels"]), attrs["kernels"]
+    assert all(any(p.rsplit("/", 1)[-1] == k for p in paths) for k in attrs["kernels"])
+    total = sum(row[0] for row in attrs["scopes"].values()) + attrs["unscoped_s"]
+    assert attrs["unscoped_s"] >= 0 and total == pytest.approx(span["end"] - span["start"], abs=1e-3)
+    assert attrs["unscoped_s"] < 0.15 * (span["end"] - span["start"])  # the step's own regions all have a name
+
+
+def test_the_three_programs_carry_the_names_the_readers_rely_on():
+    import numpy as np
+
+    from ray_tpu.models.lm import PROGRAMS
+    from ray_tpu.train import run_record
+
+    assert run_record.install_jax_listener()
+    ctx = LMTrainContext(CFG, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+    assert PROGRAMS == {"_init": "init", "_forward": "apply", "_train_step": "step"}
+    run_record.flush_traces()
+    before = len(tracing.lifecycle_spans())
+    calls = {}
+    state = ctx.init_state(seed=0)
+    calls["init"] = len(tracing.lifecycle_spans())
+    toks = np.zeros((2, 128), np.int32)
+    ctx.apply(state["params"], toks)
+    calls["apply"] = len(tracing.lifecycle_spans())
+    ctx.train_step(state, {"tokens": toks, "targets": toks})
+    run_record.flush_traces()
+    calls["step"] = len(tracing.lifecycle_spans())
+    spans, lo = tracing.lifecycle_spans(), before
+    for fun_name, program in PROGRAMS.items():  # in the order called
+        mine = [(s["name"], s["attrs"]["fun_name"]) for s in spans[lo:calls[program]] if s["name"].startswith("jax::")]
+        assert ("jax::trace", fun_name) in mine, (program, mine)
+        assert ("jax::lower", f"jit({fun_name})") in mine and ("jax::compile", f"jit({fun_name})") in mine
+        assert not [m for m in mine if m[1].strip("jit()") in set(PROGRAMS) - {fun_name}], (program, mine)
+        lo = calls[program]
+    traces = {s["attrs"]["fun_name"]: s["attrs"] for s in spans[before:] if s["name"] == "jax::trace"}
+    assert "scopes" in traces["_forward"] and "scopes" in traces["_train_step"]  # the same `trunk`, the same names
+    assert "autodiff" not in traces["_forward"]["scopes"]
