@@ -62,11 +62,13 @@ with this share's experts first, so the held rows are the first
 `sum(group_sizes)` positions of `order`, and everything behind the sorts (the
 gather of token rows, the gate values, the grouped matmuls, the activation,
 the way back to token order, and the backward of each) works on buffers of a
-static size R: `_rungs` is a short ladder of sizes from twice a uniform
-router's share up to T*K, and `lax.switch` takes the smallest that holds the
-count (`_sized_experts`).  The last rung is the all-experts code, a
-permutation of all T*K rows, so no assignment is ever dropped and the layer
-is the same function at every count; the rung follows from the count alone.
+static size R: `_rungs` is a short ladder of sizes up to T*K, from twice a
+uniform router's share, or from 1.25 times it where the share gets at least
+one assignment a token (PR 53), and `lax.switch` takes the smallest that
+holds the count (`_sized_experts`).  The last rung is the
+all-experts code, a permutation of all T*K rows, so no assignment is ever
+dropped and the layer is the same function at every count; the rung follows
+from the count alone.
 `rows_moved_share` (the rung over T*K) goes out with the statistics.
 
 The gate value of an assignment scales its row where the row is `d_ff` wide,
@@ -373,7 +375,7 @@ def _experts(tokens, expert_idx, gates, weights, n_experts, first_expert=None):
             flat = (expert_idx.reshape(-1) - first_expert) % n_experts  # this rank's experts first
             order, inverse, group_sizes = _by_expert(flat, n_local)
             g_sorted = _permuted(gates.reshape(-1), inverse, order)
-        rungs = _rungs(flat.shape[0], n_local, n_experts)
+        rungs = _rungs(flat.shape[0], n_local, n_experts, k)
         # the smallest rung that holds this share's rows
         rung = jnp.sum(jnp.sum(group_sizes) > jnp.asarray(rungs[:-1], jnp.int32), dtype=jnp.int32)
         out = _sized_experts(tokens, g_sorted, tuple(weights), order, inverse, group_sizes, rung, k, rungs)
@@ -395,16 +397,34 @@ _ROW_TILE = 512  # the grouped-matmul kernels' row tile (`ops/pallas/grouped_mat
 _RUNGS = 4  # at most: every rung is traced, lowered and compiled (PERF.md section 6, PR 48: what a rung costs `setup_s`)
 
 
-def _rungs(assignments: int, n_local: int, n_experts: int) -> Tuple[int, ...]:
-    """The static row counts a share's buffers may have, ascending.  The
-    first is twice a uniform router's share of the T*K `assignments` (so a
-    balanced router, and one out of balance by up to 2, stays in it), in
-    whole row tiles; each next twice the last; the last is T*K itself."""
+# A first rung AT the uniform share would be a coin toss between two step times (`mellum2` holds 1.006 of it); a
+# quarter over it is the capacity factor expert-parallel training gives ONE expert (Switch Transformer, arXiv:2101.03961,
+# section 2.2).  `mellum2`'s rank stays under it for 67 steps and leaves it in 17% of 346 steps' layers (PERF.md section 6, PR 53)
+_BALANCED_RUNG = (5, 4)  # 1.25 as a ratio: the rungs are whole numbers
+# The uniform share, in assignments a token (K * held / experts), from which a share's first rung is that one.  What a
+# lower rung saves grows with the share: from here on the rows moved outnumber the tokens and are a large part of the layer
+_ROWS_A_TOKEN = 1
+
+
+def _rungs(assignments: int, n_local: int, n_experts: int, k: int) -> Tuple[int, ...]:
+    """The static row counts a share's buffers may have, ascending, in whole
+    row tiles.  Twice a uniform router's share of the T*K `assignments` (so a
+    balanced router, and one out of balance by up to 2, stays in it), then
+    each twice the last; the last is T*K itself.  Of a share that gets at
+    least `_ROWS_A_TOKEN` of a token's `k` choices the FIRST rung is
+    `_BALANCED_RUNG` times the uniform share instead (so a four-way share
+    has that rung and T*K: what is over 1.25x its uniform share moves all
+    rows)."""
     rung = -(-2 * assignments * n_local // n_experts // _ROW_TILE) * _ROW_TILE
     rungs = []
     while rung < assignments and len(rungs) < _RUNGS - 1:
         rungs.append(rung)
         rung *= 2
+    if k * n_local >= _ROWS_A_TOKEN * n_experts:
+        times, over = _BALANCED_RUNG
+        balanced = -(-times * assignments * n_local // (over * n_experts) // _ROW_TILE) * _ROW_TILE
+        if balanced < assignments:
+            rungs[:1] = [balanced]  # in the 2x rung's place, or alone where 2x is T*K already
     return (*rungs, assignments)
 
 

@@ -1,15 +1,19 @@
 #!/usr/bin/env python3
 """On the chip: the expert layer's two ROW MOVEMENTS alone (`ray_tpu/models/moe.py`),
-at the shapes of the two cells that hold a share of their experts:
+at the shapes of the three cells that hold a share of their experts:
 
-    chiprun -- python3 scripts/moe_dispatch_check.py [--reps 20] [--cells kimi nemotron]
+    chiprun -- python3 scripts/moe_dispatch_check.py [--reps 20] [--cells kimi nemotron mellum]
 
 `kimi` is `kimi-linear-ep16-1chip.seq16k` (16,384 tokens of d 2304, 8 choices of
 256 experts, 16 held: T*K = 131,072 assignments), `nemotron`
-`nemotron3-nano-ep8-1chip.seq8k` (8,192 tokens of d 2688, 6 of 128, 16 held: 49,152).
-For 0, a uniform router's, a whole lowest rung's and ALL T*K rows held, each
-movement is timed in the form the layer had before PR 48 (every assignment moved:
-a T*K-row gather in, a T*K-row gather by `inverse` and the sum over K out) and in
+`nemotron3-nano-ep8-1chip.seq8k` (8,192 tokens of d 2688, 6 of 128, 16 held: 49,152),
+`mellum` `mellum2-ep4-1chip.seq16k` (16,384 tokens of d 2304, 8 of 64, 16 held: 131,072,
+a uniform share of 32,768 on the rung of 40,960 rows since PR 53).
+For 0, a uniform router's, a whole lowest rung's and ALL T*K rows held, and for the
+uniform router's once more on the NEXT rung (what a rung's step costs a call: 40,960
+against 65,536 rows in `mellum`), each movement is timed in the form the layer had
+before PR 48 (every assignment moved: a T*K-row gather in, a T*K-row gather by
+`inverse` and the sum over K out) and in
 the forms a rung of R rows can take (`moe._rungs` names the sizes; the rung is the
 smallest that holds the count):
 
@@ -42,7 +46,7 @@ import numpy as np
 from ray_tpu.models import moe
 
 # tokens, d_model, choices a token, experts, experts held
-CELLS = {"kimi": (16384, 2304, 8, 256, 16), "nemotron": (8192, 2688, 6, 128, 16)}
+CELLS = {"kimi": (16384, 2304, 8, 256, 16), "nemotron": (8192, 2688, 6, 128, 16), "mellum": (16384, 2304, 8, 64, 16)}
 OUT = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "chiprun_out", "moe_dispatch_check.jsonl")
 
 
@@ -124,17 +128,18 @@ def out_sorted_gather(rows, order, inverse, count, k):
 
 def check_cell(name: str, reps: int) -> bool:
     tokens, d, k, experts, held = CELLS[name]
-    rungs = moe._rungs(tokens * k, held, experts)
+    rungs = moe._rungs(tokens * k, held, experts, k)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((tokens, d)), jnp.bfloat16)
     ok = True
-    for label, rows_held in (("none", 0), ("uniform", tokens * k * held // experts), ("rung", rungs[0]),
-                             ("all", tokens * k)):
+    uniform = tokens * k * held // experts
+    for label, rows_held, up in (("none", 0, 0), ("uniform", uniform, 0), ("uniform, a rung up", uniform, 1), ("rung", rungs[0], 0),
+                                 ("all", tokens * k, 0)):
         idx = routing(rng, tokens, k, experts, held, rows_held)
         order, inverse, sizes = jax.jit(moe._by_expert, static_argnums=(1,))(idx.reshape(-1), held)
         count = int(jnp.sum(sizes))
         assert count == rows_held, (count, rows_held)
-        R = next(r for r in rungs if r >= count)
+        R = rungs[min(next(i for i, r in enumerate(rungs) if r >= count) + up, len(rungs) - 1)]
         y_old = jnp.asarray(rng.standard_normal((tokens * k, d)), jnp.bfloat16)  # what the experts wrote, expert order
         line = {"cell": name, "held": label, "rows_held": count, "rung": R, "rungs": list(rungs), "assignments": tokens * k,
                 "in_old_ms": timed(old_in, x, order, count, k, n=reps),

@@ -122,7 +122,8 @@ def test_the_program_holds_what_the_builder_counts():
     assert cfg.num_params() == builder.total_params(PUBLISHED) == 1_077_059_840
     assert cfg.head_dim == 128 != cfg.d_model // cfg.n_heads
     assert builder.total_params(PUBLISHED, uncut=True) == 12_149_923_072  # the card's "12B"
-    assert moe._rungs(16384 * 8, 16, 64) == (65536, 131072)  # twice a uniform router's 32,768 rows, then all
+    # two assignments a token of a four-way share: 1.25x a uniform router's 32,768 rows (PR 53), then all
+    assert moe._rungs(16384 * 8, 16, 64, 8) == (40960, 131072)
 
 
 @pytest.mark.parametrize("cfg", [config_of(), config_of(n_experts_held=None, router_share_init=False)], ids=["share", "whole"])

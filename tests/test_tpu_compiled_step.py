@@ -268,21 +268,25 @@ def test_the_ssd_kernels_compile_at_the_two_cells_shapes(topo, groups):
     assert got == [(a.shape, a.dtype) for a in args]
 
 
-@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
-def test_a_share_of_the_experts_compiles_with_one_switch_a_direction_of_at_most_four_rungs(topo, kind):
+@pytest.mark.parametrize("kind,held,k,want", [("swiglu", 2, 2, (512, 1024, 2048)), ("relu2", 2, 2, (512, 1024, 2048)),
+                                              ("swiglu", 8, 2, (1536, 2048))],
+                         ids=["swiglu", "relu2", "swiglu-one-assignment-a-token"])
+def test_a_share_of_the_experts_compiles_with_one_switch_a_direction_of_at_most_four_rungs(topo, kind, held, k, want):
     """The step of a model that holds 2 of its 16 experts (the two share cells
     in small: three matrices an expert, and two), compiled for the v5e: the
     share's row buffers take one of `moe._rungs`' sizes (PR 48), so the scan
     body holds the forward's `conditional` and the backward's (the
     recompute's is dead: its residuals are the layer's inputs), one branch a
-    rung, the grouped-matmul kernels in every branch and none refused."""
+    rung, the grouped-matmul kernels in every branch and none refused.  And of
+    one that holds 8 of 16, one assignment a token, whose ladder is 1.25x its
+    uniform 1,024 rows and all (PR 53: the rung `mellum2` takes, in small)."""
     from ray_tpu.models import moe
     from ray_tpu.ops.grouped_matmul import REFUSED_SCOPE
 
-    rungs = moe._rungs(B * S * 2, 2, 16)
-    assert rungs == (512, 1024, 2048)
+    rungs = moe._rungs(B * S * k, held, 16, k)
+    assert rungs == want
     cfg = TransformerConfig.tiny(**COMMON, n_layers=2, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, moe_d_ff=128, n_experts=16,
-                                 experts_per_token=2, n_experts_held=2, n_shared_experts=1, expert_kind=kind)
+                                 experts_per_token=k, n_experts_held=held, n_shared_experts=1, expert_kind=kind)
     ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=topo.devices[:1]), strategy="dp")
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=ctx.batch_sharding)

@@ -237,8 +237,13 @@ SHARE = dict(n_layers=2, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, moe_d_f
              remat_policy="qkv_attn", n_experts=16, experts_per_token=2, n_experts_held=2, n_shared_experts=1)
 
 
-@pytest.mark.parametrize("kind,per_rung", [("swiglu", {"moe_gmm": 8, "moe_tgmm": 3}), ("relu2", {"moe_gmm": 5, "moe_tgmm": 2})])
-def test_a_share_of_the_experts_lowers_for_tpu_with_one_switch_a_direction(kind, per_rung):
+# 2 of 16 at 2 choices is a quarter of an assignment a token, the ladder from twice the uniform 256 rows; 8 of 16 is ONE
+# a token (`mellum2-ep4-1chip.seq16k` has two): 1.25x the uniform 1,024 rows, then all (PR 53)
+@pytest.mark.parametrize("kind,held,k,want,per_rung", [("swiglu", 2, 2, (512, 1024, 2048), {"moe_gmm": 8, "moe_tgmm": 3}),
+                                                       ("relu2", 2, 2, (512, 1024, 2048), {"moe_gmm": 5, "moe_tgmm": 2}),
+                                                       ("swiglu", 8, 2, (1536, 2048), {"moe_gmm": 8, "moe_tgmm": 3})],
+                         ids=["swiglu", "relu2", "swiglu-one-assignment-a-token"])
+def test_a_share_of_the_experts_lowers_for_tpu_with_one_switch_a_direction(kind, held, k, want, per_rung):
     """The two cells that hold a share (`kimi-linear-ep16-1chip.seq16k`: three
     matrices; `nemotron3-nano-ep8-1chip.seq8k`: two) in small.  The share's
     buffers take one of at most four static sizes (PR 48), so the scan body
@@ -246,21 +251,25 @@ def test_a_share_of_the_experts_lowers_for_tpu_with_one_switch_a_direction(kind,
     recompute's is dead code: its residuals are the layer's inputs), each
     rung with the kernels the whole layer had: forward 3 (2) products,
     again gate and up (up) in the backward, the 3 (2) transposed products and
-    the 3 (2) weight gradients.  No shape is refused to the XLA form."""
+    the 3 (2) weight gradients.  No shape is refused to the XLA form.  The
+    same of a share that gets one assignment a token, whose ladder is 1.25x
+    its uniform share and all (PR 53; the rung `mellum2-ep4-1chip.seq16k` takes)."""
     from ray_tpu.models import moe
     from ray_tpu.ops.grouped_matmul import REFUSED_SCOPE
 
-    rungs = moe._rungs(8 * 128 * 2, 2, 16)
-    assert rungs == (512, 1024, 2048) and len(rungs) <= 4
-    cfg = TransformerConfig.tiny(**SHARE, expert_kind=kind)
+    rungs = moe._rungs(8 * 128 * k, held, 16, k)
+    assert rungs == want and len(rungs) <= 4
+    cfg = TransformerConfig.tiny(**dict(SHARE, n_experts_held=held, experts_per_token=k), expert_kind=kind)
     text = _lowered_text(1, MeshSpec(data=1), "dp", platforms=("tpu",), cfg=cfg, debug_info=True)
     kernels = _mosaic_kernels(text)
     assert {name: kernels[name] for name in per_rung} == {name: n * len(rungs) for name, n in per_rung.items()}, kernels
     assert REFUSED_SCOPE not in text
 
-    def switches(jaxpr):  # `cond`s of three branches (a `platform_dependent` has two), at any depth
+    def switches(jaxpr):  # `cond`s whose every branch is a rung's jitted function (a `platform_dependent` is a `cond` too), at any depth
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "cond" and len(eqn.params["branches"]) == len(rungs):
+            if eqn.primitive.name == "cond" and all(
+                    any(str(e.params.get("name")).startswith("_rung_") for e in branch.jaxpr.eqns)
+                    for branch in eqn.params["branches"]):
                 yield eqn
             for value in eqn.params.values():
                 for sub in value if isinstance(value, (list, tuple)) else [value]:
@@ -270,4 +279,4 @@ def test_a_share_of_the_experts_lowers_for_tpu_with_one_switch_a_direction(kind,
     ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
     toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
     step = jax.make_jaxpr(ctx._train_step)(jax.eval_shape(ctx._init, jax.random.PRNGKey(0)), {"tokens": toks, "targets": toks})
-    assert len(list(switches(step.jaxpr))) == 2
+    assert [len(eqn.params["branches"]) for eqn in switches(step.jaxpr)] == [len(rungs)] * 2
