@@ -25,6 +25,8 @@ from ray_tpu.models.transformer import (
     check_placement,
     forward,
     init_params,
+    mtp_forward,
+    mtp_rows,
     param_axes,
     trunk,
 )
@@ -124,9 +126,10 @@ head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
 # The step counters of the run's record (train/run_record.py), among the step's metrics: the key tiles a
 # windowed flash forward visits; what a layer that holds a share of its experts was given (`models/moe.py`
 # `router_losses`: rows per held expert, mean and busiest, the busiest expert's load over the mean, and the share
-# of the T*K assignments whose rows the share's buffers moved).
+# of the T*K assignments whose rows the share's buffers moved); the multi-token-prediction module's cross entropy.
 WINDOW_TILES = "attn_window_tiles_visited_pct"
-STEP_COUNTERS = (WINDOW_TILES, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share")
+STEP_COUNTERS = (WINDOW_TILES, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
+                 "mtp_loss")
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
@@ -141,6 +144,24 @@ def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
     if not visited or None in visited:
         return {}
     return {WINDOW_TILES: sum(visited) / len(visited)}
+
+
+def _mtp_term(params, h, head, batch, config, rules, mesh):
+    """(the multi-token-prediction module's cross entropy, its block's router
+    statistics or None).  With `batch["targets"]` the token after each
+    position, t_{i+1}, the module takes their embeddings beside the trunk's
+    output `h` (`transformer.mtp_rows`) and its rows go through the model's
+    own `head` against the targets shifted once more, t_{i+2}: the mean over
+    the positions that have one (the last has none) and the batch's mask
+    admits.  Head and loss are named inside `mtp`."""
+    targets = batch["targets"]
+    x, stats = mtp_rows(params, h, targets, config, rules=rules, mesh=mesh)
+    after_next = jnp.roll(targets, -1, axis=1)
+    has_one = jnp.broadcast_to(jnp.arange(targets.shape[1]) < targets.shape[1] - 1, targets.shape)
+    if batch.get("mask") is not None:
+        has_one = has_one & (jnp.roll(batch["mask"], -1, axis=1) > 0)
+    with tracing.scope("mtp"):
+        return head_cross_entropy(_constrainer(rules, mesh), x, head, after_next, has_one), stats
 
 
 # What jax calls the three programs `LMTrainContext` builds (the `fun_name` of their `jax::trace` /
@@ -232,19 +253,28 @@ class LMTrainContext:
             """(the objective that is differentiated, its terms).  Dense: the
             cross entropy and no terms.  With experts: cross entropy +
             `router_aux_loss_coef` * load balancing + `router_z_loss_coef` *
-            z-loss (formulas in models/moe.py), and the terms unweighted."""
+            z-loss (formulas in models/moe.py), and the terms unweighted.
+            With `mtp_depth`: + `mtp_loss_weight` * the module's cross entropy
+            (`_mtp_term`), `ce_loss` and `mtp_loss` among the terms, the
+            module's block one more layer of the router statistics."""
+            constrain = _constrainer(rules, self.mesh)
             x, head, router_stats = trunk(
                 params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
-            ce = head_cross_entropy(
-                _constrainer(rules, self.mesh), x, head, batch["targets"], batch.get("mask"))
+            ce = head_cross_entropy(constrain, x, head, batch["targets"], batch.get("mask"))
+            mtp = {}
+            if cfg.mtp_depth:
+                mtp["mtp_loss"], stats = _mtp_term(params, x, head, batch, cfg, rules, self.mesh)
+                if stats is not None:
+                    router_stats = jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b], axis=0), router_stats, stats)
             with tracing.scope("loss"):
                 counters = _window_counters(cfg, batch["tokens"].shape[1])
+                loss = ce + cfg.mtp_loss_weight * mtp["mtp_loss"] if mtp else ce
                 if router_stats is None:
-                    return ce, counters
+                    return loss, {"ce_loss": ce, **mtp, **counters} if mtp else counters
                 terms = router_losses(router_stats, cfg)
-                loss = (ce + cfg.router_aux_loss_coef * terms["moe_lb_loss"]
+                loss = (loss + cfg.router_aux_loss_coef * terms["moe_lb_loss"]
                         + cfg.router_z_loss_coef * terms["moe_z_loss"])
-                return loss, {"ce_loss": ce, **terms, **counters}
+                return loss, {"ce_loss": ce, **mtp, **terms, **counters}
 
         self._loss = _loss
 
@@ -289,6 +319,11 @@ class LMTrainContext:
             return forward(params, tokens, cfg, rules=rules, mesh=self.mesh)
 
         self._forward = jax.jit(_forward)
+
+        def _forward_mtp(params, tokens, next_tokens):
+            return mtp_forward(params, tokens, next_tokens, cfg, rules=rules, mesh=self.mesh)
+
+        self._forward_mtp = jax.jit(_forward_mtp)
 
     # -- public API -------------------------------------------------------
     def init_state(self, seed: int = 0) -> Dict[str, Any]:
@@ -335,3 +370,11 @@ class LMTrainContext:
     def apply(self, params, tokens) -> jax.Array:
         with self.mesh:
             return self._forward(params, tokens)
+
+    def apply_mtp(self, params, tokens, next_tokens) -> jax.Array:
+        """The multi-token-prediction module's logits [B, S, V] for the token
+        after the next (`mtp_depth` 1), from `tokens` and the token after each."""
+        if not self.config.mtp_depth:
+            raise ValueError("apply_mtp needs a model with a multi-token-prediction module (mtp_depth=1)")
+        with self.mesh:
+            return self._forward_mtp(params, tokens, next_tokens)

@@ -4,7 +4,7 @@ What one `TransformerConfig` expresses: a stack of pre-norm layers, each a
 (MIXER, FFN) pair around a residual stream.  The mixer (`layer_types`) is
 one of the kinds of `ray_tpu/models/mixers/`, one module each with its
 mathematics: causal softmax attention, a Mamba-2 selective state-space
-layer, Kimi Delta Attention, latent attention without rotary embedding, and
+layer, Kimi Delta Attention, latent attention (with or without a rotary part), and
 the four of SambaY's decoder-hybrid-decoder (a Mamba-1 selective scan,
 differential attention, a Gated Memory Unit that reads one scan's output,
 differential cross-attention over one attention layer's keys and values).
@@ -27,7 +27,11 @@ a stack of three window-1024 layers to one full layer, the full ones with a
 YaRN rope (Mellum 2), is ONE kind of layer in one parameter stack, run as two
 compiled bodies a period.  Mistral, InternLM2, OLMoE, the Granite
 4.0-H hybrids, Kimi Linear, Phi-4-mini-flash, Nemotron-3-Nano and Mellum 2
-run through it at their published widths (benchmarks/configs/).
+run through it at their published widths (benchmarks/configs/), and
+GLM-4.7-Flash with them: latent attention with a rope on part of each head
+and a low-rank q (`mla_rope`, `q_lora_rank`), and a multi-token-prediction
+module behind the trunk (`mtp_depth`: `mtp_rows`, weighed into the loss by
+models/lm.py).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -217,10 +221,16 @@ class TransformerConfig:
     kda_conv: int = 4
     # Latent attention, read only when some layer is "mla" (`n_heads` heads):
     # the latent's width, the two parts of a q/k head, the size of a v head.
+    # `q_lora_rank`: q is low-rank too, `RMSNorm(u W_qa) W_qb` (None = one
+    # projection).  `mla_rope`: the rotary embedding of the `qk_rope_head_dim`
+    # parts of q and of the one shared k_pe (None = nothing is rotated, Kimi
+    # Linear's NoPE); `rope_theta` and `layer_ropes` are not read by "mla".
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    q_lora_rank: Optional[int] = None
+    mla_rope: Optional[Rope] = None
     # Published multipliers (Granite's muP form), 1.0 each = absent: on the
     # embeddings, on each block's output before it joins the residual
     # stream, and a divisor of the logits.  `attention_scale` multiplies
@@ -261,6 +271,14 @@ class TransformerConfig:
     layer_windows: Optional[Tuple[Optional[int], ...]] = None
     layer_ids: Optional[Tuple[int, ...]] = None
     layer_ropes: Optional[Tuple[Optional[Rope], ...]] = None
+    # Multi-token prediction (DeepSeek-V3, arXiv:2412.19437 section 2.2) in the
+    # training objective: `mtp_depth` modules behind the trunk (0 = none, 1 the
+    # most), each ONE more layer of the stack's last pair with weights of its
+    # own (`params["mtp"]`, `mtp_rows`) that predicts the token after the next
+    # through the model's own embedding and head; `mtp_loss_weight` weighs its
+    # cross entropy in the loss (models/lm.py).
+    mtp_depth: int = 0
+    mtp_loss_weight: float = 0.0
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -284,6 +302,16 @@ class TransformerConfig:
             if rope is not None and not (isinstance(rope, Rope) and MIXERS[kind].rotates):
                 raise ValueError(f"layer_ropes holds {rope!r} at a {kind} layer: an ops.rotary.Rope, at a layer "
                                  f"of a kind that rotates ({[m.name for m in MIXERS.values() if m.rotates]}), or None")
+        if self.mtp_depth not in (0, 1) or self.mtp_loss_weight < 0:
+            raise ValueError(f"mtp_depth is 0 (no multi-token prediction) or 1 (one module: the token after the next) with "
+                             f"mtp_loss_weight >= 0, got mtp_depth={self.mtp_depth}, mtp_loss_weight={self.mtp_loss_weight}")
+        if self.mtp_depth:
+            if self.logits_scaling != 1.0:
+                raise ValueError("mtp_depth takes the rows that enter the head as the trunk's output: logits_scaling must be 1.0")
+            last = MIXERS[self.layer_pairs()[-1][0]]
+            if last.reads or last.source is not None:
+                raise ValueError(f"mtp_depth: the module's block is one more {last.name} layer, a kind that crosses layers "
+                                 f"({last.source or last.reads}); it runs behind the trunk and can neither read nor hand on")
         if self.qk_norm not in (False, True, "per_head"):
             raise ValueError(f"qk_norm is False, True (over the whole projection) or 'per_head', got {self.qk_norm!r}")
         if self.norm_kind not in ("rms", "layer"):
@@ -435,8 +463,11 @@ class TransformerConfig:
         experts = 0
         if self.n_experts is not None:
             experts = size(jax.eval_shape(lambda key: init_moe_params(self, key), jax.ShapeDtypeStruct((2,), jnp.uint32)))
-        return size(_model_leaves(self)) + sum(
-            n * (size(_layer_leaves(self, m, f)) + (experts if f == "experts" else 0)) for m, f, n in self.stacks().values())
+        def layers(m, f, n):
+            return n * (size(_layer_leaves(self, m, f)) + (experts if f == "experts" else 0))
+
+        mtp = size(_mtp_leaves(self)) + layers(*self.layer_pairs()[-1], 1) if self.mtp_depth else 0
+        return size(_model_leaves(self)) + sum(layers(m, f, n) for m, f, n in self.stacks().values()) + mtp
 
 
 def _model_leaves(config: TransformerConfig) -> Dict:
@@ -471,15 +502,31 @@ def _layer_leaves(config: TransformerConfig, mixer: str, ffn: str) -> Dict:
     return {m.subtree: m.leaves(c), "mlp": dense if ffn == "dense" else None, **norms}
 
 
+def _mtp_leaves(config: TransformerConfig) -> Dict:
+    """What the multi-token-prediction module holds beside its block
+    (`params["mtp"]["block"]`, one layer of the stack's last pair): the norms
+    of the next token's embedding and of the trunk's output, the projection
+    of the two side by side back to the stream, the norm before the head."""
+    d = config.d_model
+    return {"enorm": ones((d,)), "hnorm": ones((d,)),
+            "eh_proj": Leaf((2 * d, d), (None, "embed"), normal((2 * d) ** -0.5)), "norm": ones((d,))}
+
+
 def param_axes(config: TransformerConfig) -> Dict:
     """Pytree of logical-axes tuples, congruent with init_params output."""
-    L = ("layers",)
+    def layer_axes(mixer, ffn, leading):
+        axes = jax.tree_util.tree_map(lambda leaf: leading + leaf.axes, _layer_leaves(config, mixer, ffn))
+        if ffn == "experts":
+            axes["mlp"] = jax.tree_util.tree_map(
+                lambda t: leading + t, moe_param_axes(config), is_leaf=lambda t: isinstance(t, tuple))
+        return axes
+
     axes = jax.tree_util.tree_map(lambda leaf: leaf.axes, _model_leaves(config))
     for name, (mixer, ffn, _) in config.stacks().items():
-        axes[name] = jax.tree_util.tree_map(lambda leaf: L + leaf.axes, _layer_leaves(config, mixer, ffn))
-        if ffn == "experts":
-            axes[name]["mlp"] = jax.tree_util.tree_map(
-                lambda t: L + t, moe_param_axes(config), is_leaf=lambda t: isinstance(t, tuple))
+        axes[name] = layer_axes(mixer, ffn, ("layers",))
+    if config.mtp_depth:
+        axes["mtp"] = {**jax.tree_util.tree_map(lambda leaf: leaf.axes, _mtp_leaves(config)),
+                       "block": layer_axes(*config.layer_pairs()[-1], ())}
     return axes
 
 
@@ -514,6 +561,9 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict:
         if name != first:
             own = k if name == second else iter(jax.random.split(jax.random.fold_in(key, i + 1), 16))
             params[name] = draw(_layer_leaves(c, mixer, ffn), own, (n,))
+    if c.mtp_depth:  # a sequence of its own, behind every stack's: `fold_in(key, i + 1)` ends at the stacks' count
+        own = iter(jax.random.split(jax.random.fold_in(key, len(MIXERS) * len(FFN_KINDS) + 1), 16))
+        params["mtp"] = {**draw(_mtp_leaves(c), own), "block": draw(_layer_leaves(c, *c.layer_pairs()[-1]), own)}
     return params
 
 
@@ -694,6 +744,12 @@ def check_placement(config: TransformerConfig, rules: Optional[Rules], mesh) -> 
         MIXERS[kind].placement(config, rules, mesh)
     if pipeline_axes(rules, mesh, config.n_layers) is not None:
         _refuse_unequal_stages(config)
+        if config.mtp_depth:
+            raise ValueError("strategy 'pp' runs no multi-token-prediction module (mtp_depth): its block lies behind the "
+                             "last stage and reads the first stage's embedding table")
+    if config.mtp_depth and config.n_experts_held is not None and mesh is not None and mesh.size > 1:
+        raise ValueError("mtp_depth beside n_experts_held on a mesh of more than one device: the module's block holds one "
+                         "rank's share of the experts as the stack's layers do, and a share runs on one device")
 
 
 def _run_layers_pipelined(
@@ -758,6 +814,18 @@ def _run_layers_pipelined(
     )
 
 
+def _embed(params, tokens, config, rules, mesh):
+    """Token ids [B, S] -> their rows of the table, [B, S, d] in `config.dtype`, placed as the rules say."""
+    c = config
+    with tracing.scope("embed"):
+        x = params["embed"]["tokens"].astype(c.dtype)[tokens]
+        if c.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(c.embedding_multiplier, c.dtype)
+        if rules is not None:
+            x = with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"), rules, mesh)
+    return x
+
+
 def forward(
     params: Dict,
     tokens: jax.Array,
@@ -794,12 +862,7 @@ def trunk(
     c = config
     if rules is not None and mesh is None:
         raise ValueError("forward(rules=...) needs the mesh the rules refer to")
-    with tracing.scope("embed"):
-        x = params["embed"]["tokens"].astype(c.dtype)[tokens]
-        if c.embedding_multiplier != 1.0:
-            x = x * jnp.asarray(c.embedding_multiplier, c.dtype)
-        if rules is not None:
-            x = with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"), rules, mesh)
+    x = _embed(params, tokens, c, rules, mesh)
     positions = jnp.arange(tokens.shape[1])
     pp = pipeline_axes(rules, mesh, c.n_layers)
     # `layers` names what the loop over the stack itself costs (each layer's
@@ -866,3 +929,58 @@ def trunk(
             # pass over the logits.
             x = x / jnp.asarray(c.logits_scaling, x.dtype)
     return x, head, router_stats
+
+
+def mtp_rows(
+    params: Dict,
+    h: jax.Array,
+    next_tokens: jax.Array,
+    config: TransformerConfig,
+    *,
+    rules: Optional[Rules] = None,
+    mesh=None,
+):
+    """The multi-token-prediction module behind the trunk (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2, depth 1), with `h` [B, S, d] the rows
+    `trunk` returns (the main model's output behind its final norm) and
+    `next_tokens` [B, S] the token AFTER each position (the batch's targets):
+
+    `h'_i = [RMSNorm_e(Emb(t_{i+1})) ; RMSNorm_h(h_i)] W_eh` (`[2d, d]`, the
+    embedding's half first), `h1 = Block(h')`, ONE more layer of the stack's
+    last pair and variant, `layer` itself, with the module's own weights
+    (`params["mtp"]["block"]`; the same positions 0..S-1, the same remat),
+    and `RMSNorm_s(h1)`: (the rows that enter the head for the token after
+    the next, [B, S, d]; the block's router statistics with a leading axis of
+    one layer, or None).  The embedding table and the head are the model's
+    own, so their gradients sum over both uses.  The whole module is named
+    `mtp` (`mtp/proj` the two norms and `W_eh`; the block's names inside)."""
+    c, p = config, params["mtp"]
+    kind, ffn = c.layer_pairs()[-1]
+    window, rope, _ = c.layer_variant(c.n_layers - 1)
+    constrain = _constrainer(rules, mesh)
+    with tracing.scope("mtp"):
+        e = _embed(params, next_tokens, c, rules, mesh)
+        with tracing.scope("mtp/proj"):
+            both = jnp.concatenate([rms_norm(e, p["enorm"], c.norm_eps), rms_norm(h, p["hnorm"], c.norm_eps)], axis=-1)
+            x = constrain(jnp.einsum("bsf,fe->bse", both, p["eh_proj"].astype(c.dtype)), ("act_batch", "act_seq", "act_embed"))
+        block = functools.partial(layer, MIXERS[kind], positions=jnp.arange(next_tokens.shape[1]), config=c, rules=rules,
+                                  mesh=mesh, ffn=ffn, window=window, rope=rope)
+        if c.remat:
+            block = jax.checkpoint(block, policy=_remat_policy(c))
+        x, stats, _ = block(x, p["block"])
+        with tracing.scope("mtp/proj"):
+            x = rms_norm(x, p["norm"], c.norm_eps)
+    return x, None if stats is None else jax.tree_util.tree_map(lambda a: a[None], stats)
+
+
+def mtp_forward(params: Dict, tokens: jax.Array, next_tokens: jax.Array, config: TransformerConfig, *,
+                rules: Optional[Rules] = None, mesh=None) -> jax.Array:
+    """Token ids and the token after each, [B, S] both -> the module's logits
+    [B, S, vocab] (f32) for the token after the next: `trunk`, `mtp_rows` and
+    the model's head, as the training objective runs them (it forms no logits:
+    `lm.head_cross_entropy` takes the rows)."""
+    h, head, _ = trunk(params, tokens, config, rules=rules, mesh=mesh)
+    x, _ = mtp_rows(params, h, next_tokens, config, rules=rules, mesh=mesh)
+    with tracing.scope("mtp"), tracing.scope("lm_head"):
+        logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
+        return _constrainer(rules, mesh)(logits, LOGITS_AXES)

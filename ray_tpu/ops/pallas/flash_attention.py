@@ -132,6 +132,25 @@ def _window_blocks(window: int, blocks):
     return tuple(min(b, cap) for b in blocks)
 
 
+_FWD_FULL_TILE_HEADS = 448  # d + dv up to which the forward's 1024 x 1024 tile fits the scoped VMEM
+
+
+def _head_blocks(d: int, dv: int, blocks):
+    """Tiles by head size, the ONE place they follow from it.  The forward
+    kernel at 1024 x 1024 holds, beside its float32 scores (7.5 MB), the
+    tiles of q, k, v and the output twice in their own dtype, q, k and v once
+    more in float32 and the float32 accumulator: 16 KB a unit of `d + dv`,
+    13.5 MB at heads of 192 / 128, 16.5 MB at 256 / 256 against the 16 MB a
+    kernel may scope (libtpu refuses it; PERF.md section 6, PR 54).  Heads
+    whose two sizes sum over 448 halve the forward's key tile; the backward's
+    1024 x 512 fits them as it is.  Heads of 64, 128 and 192 / 128 keep the
+    tiles they had."""
+    block_q, block_k, bwd_block_q, bwd_block_k = blocks
+    if d + dv > _FWD_FULL_TILE_HEADS:
+        block_k = min(block_k, 512)
+    return block_q, block_k, bwd_block_q, bwd_block_k
+
+
 # -- forward ---------------------------------------------------------------
 
 
@@ -496,6 +515,8 @@ def flash_attention(
             raise ValueError("flash_attention: a window needs causal=True, equal sequence lengths and window >= 1")
         block_q, block_k, bwd_block_q, bwd_block_k = _window_blocks(
             window, (block_q, block_k, bwd_block_q, bwd_block_k))
+    block_q, block_k, bwd_block_q, bwd_block_k = _head_blocks(
+        q.shape[-1], v.shape[-1], (block_q, block_k, bwd_block_q, bwd_block_k))
     h = q.shape[2]
     if k.shape[2] != h:
         k = _repeat_kv(k, h)

@@ -326,3 +326,38 @@ def test_the_mellum_cells_window_kernels_and_grouped_matmuls_compile_at_its_shap
     for compiled in (up, down):
         assert compiled.count('custom_call_target="tpu_custom_call"') == 1 and "moe_gmm" in compiled
         assert REFUSED_SCOPE not in compiled
+
+
+def test_the_glm_cells_flash_kernels_at_heads_of_256_and_grouped_matmuls_compile_at_its_shapes(topo):
+    """PR 54: no new kernel, heads no cell had.  One 8,192-token sequence of
+    20 heads of 256 / 256: the forward kernel at its 1024 x 1024 tile scopes
+    16.47 MB of VMEM and libtpu refuses it (its limit is 16 MB), so
+    `_head_blocks` halves the key tile; the backward's 1024 x 512 fits as it
+    is.  Three custom calls forward + backward.  And the grouped matmuls at
+    2048 x 1536 on the lower rung's 10,240 rows of 16 held experts, both ways
+    round, none refused."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.grouped_matmul import REFUSED_SCOPE, grouped_matmul
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    q = shaped((1, 8192, 20, 256))
+
+    def grad(**blocks):
+        return jax.grad(lambda q, k, v, do: jnp.sum((fa.flash_attention(q, k, v, **blocks) * do).astype(jnp.float32)),
+                        argnums=(0, 1, 2))
+
+    with _no_compile_cache():
+        text = jax.jit(grad()).lower(q, q, q, q).compile().as_text()
+        with pytest.raises(Exception, match="vmem"):  # what the tile's choice is for
+            jax.jit(lambda q, k, v: fa._flash(q, k, v, True, 256 ** -0.5, 1024, 1024, 1024, 512, None)).lower(q, q, q).compile()
+        rows, sizes = shaped((10240, 2048)), shaped((16,), jnp.int32)
+        up = jax.jit(grouped_matmul).lower(rows, shaped((16, 2048, 1536)), sizes).compile().as_text()
+        down = jax.jit(grouped_matmul).lower(shaped((10240, 1536)), shaped((16, 1536, 2048)), sizes).compile().as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call"', text)
+    assert len(kernels) == 3 and all(name in text for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    for compiled in (up, down):
+        assert compiled.count('custom_call_target="tpu_custom_call"') == 1 and "moe_gmm" in compiled
+        assert REFUSED_SCOPE not in compiled
