@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ray_tpu.models.mixers import MIXERS
 from ray_tpu.models.moe import router_losses
 from ray_tpu.models.transformer import (
     LOGITS_AXES,
@@ -124,11 +125,13 @@ head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
 
 
 # The step counters of the run's record (train/run_record.py), among the step's metrics: the key tiles a
-# windowed flash forward visits; what a layer that holds a share of its experts was given (`models/moe.py`
-# `router_losses`: rows per held expert, mean and busiest, the busiest expert's load over the mean, and the share
-# of the T*K assignments whose rows the share's buffers moved); the multi-token-prediction module's cross entropy.
+# windowed flash forward visits and the grid steps of a causal one that copy (PR 55); what a layer that holds a
+# share of its experts was given (`models/moe.py` `router_losses`: rows per held expert, mean and busiest, the
+# busiest expert's load over the mean, and the share of the T*K assignments whose rows the share's buffers moved);
+# the multi-token-prediction module's cross entropy.
 WINDOW_TILES = "attn_window_tiles_visited_pct"
-STEP_COUNTERS = (WINDOW_TILES, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
+CAUSAL_STEPS = "attn_causal_steps_copying_pct"
+STEP_COUNTERS = (WINDOW_TILES, CAUSAL_STEPS, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
                  "mtp_loss")
 
 
@@ -144,6 +147,28 @@ def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
     if not visited or None in visited:
         return {}
     return {WINDOW_TILES: sum(visited) / len(visited)}
+
+
+def _causal_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
+    """`CAUSAL_STEPS`: the grid steps of the causal flash forward that make
+    its pipeline copy a key and a value tile, as % of a head's steps
+    (`flash_attention.causal_steps_copying_pct`, from the map the kernel is
+    given), the mean over the layers whose core is a causal attention call
+    without a window (`Mixer.flash_heads`), at the tiles their head sizes
+    give at this length.  Known when the step is traced, as `WINDOW_TILES`,
+    and like it a statement about the kernels at this length: it is noted
+    whichever form the dispatch gives the step (`ops.attention`; off the
+    chip and under the ring no flash kernel runs).  Nothing for a model
+    without such a layer or a length no tile divides."""
+    from ray_tpu.ops.pallas.flash_attention import causal_forward_tiles, causal_steps_copying_pct
+
+    heads = [MIXERS[mixer].flash_heads(config) for i, (mixer, _) in enumerate(config.layer_pairs())
+             if config.layer_variant(i)[0] is None]
+    tiles = [causal_forward_tiles(seq, *sizes) for sizes in heads if sizes is not None]
+    if not tiles or None in tiles:
+        return {}
+    copying = {t: causal_steps_copying_pct(seq, *t, keys=True) for t in set(tiles)}  # once a tiling, not a layer
+    return {CAUSAL_STEPS: sum(copying[t] for t in tiles) / len(tiles)}
 
 
 def _mtp_term(params, h, head, batch, config, rules, mesh):
@@ -256,7 +281,9 @@ class LMTrainContext:
             z-loss (formulas in models/moe.py), and the terms unweighted.
             With `mtp_depth`: + `mtp_loss_weight` * the module's cross entropy
             (`_mtp_term`), `ce_loss` and `mtp_loss` among the terms, the
-            module's block one more layer of the router statistics."""
+            module's block one more layer of the router statistics.  Beside the
+            terms ride the attention kernels' counters, constants of the
+            traced step (`_window_counters`, `_causal_counters`)."""
             constrain = _constrainer(rules, self.mesh)
             x, head, router_stats = trunk(
                 params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
@@ -267,7 +294,8 @@ class LMTrainContext:
                 if stats is not None:
                     router_stats = jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b], axis=0), router_stats, stats)
             with tracing.scope("loss"):
-                counters = _window_counters(cfg, batch["tokens"].shape[1])
+                seq = batch["tokens"].shape[1]
+                counters = {**_window_counters(cfg, seq), **_causal_counters(cfg, seq)}
                 loss = ce + cfg.mtp_loss_weight * mtp["mtp_loss"] if mtp else ce
                 if router_stats is None:
                     return loss, {"ce_loss": ce, **mtp, **counters} if mtp else counters

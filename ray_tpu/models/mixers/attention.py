@@ -143,4 +143,5 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
         return joined(c, x, attn_out, constrain), {}
 
 
-MIXER = Mixer("attention", "layers", "attn", leaves, refuse_attn_bias, mix, rotates=True, placement=placement)
+MIXER = Mixer("attention", "layers", "attn", leaves, refuse_attn_bias, mix, rotates=True, placement=placement,
+              flash_heads=lambda c: (c.head_dim, c.head_dim))

@@ -154,7 +154,11 @@ def _data(config):
     return {"lambda_init": config.lambda_inits()}
 
 
+def _flash_heads(config):
+    return config.head_dim, 2 * config.head_dim  # one call over both maps of a pair (`_core`)
+
+
 MIXER = Mixer("diff_attention", "diff_layers", "diff", lambda c: _leaves(c, cross=False), validate, mix,
-              saved=(DIFF_MIXED,), hands=(SHARED_K, SHARED_V), source="kv_source_layer", data=_data)
+              saved=(DIFF_MIXED,), hands=(SHARED_K, SHARED_V), source="kv_source_layer", data=_data, flash_heads=_flash_heads)
 CROSS = Mixer("diff_cross", "cross_layers", "diff", lambda c: _leaves(c, cross=True), validate, mix,
-              saved=(DIFF_MIXED,), reads=(SHARED_K, SHARED_V), data=_data)
+              saved=(DIFF_MIXED,), reads=(SHARED_K, SHARED_V), data=_data, flash_heads=_flash_heads)

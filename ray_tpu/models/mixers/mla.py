@@ -113,4 +113,5 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
         return checkpoint_name(joined(c, x, out, constrain), MLA_MIXED), {}
 
 
-MIXER = Mixer("mla", "mla_layers", "mla", leaves, validate, mix, saved=(MLA_MIXED,))
+MIXER = Mixer("mla", "mla_layers", "mla", leaves, validate, mix, saved=(MLA_MIXED,),
+              flash_heads=lambda c: (c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim))

@@ -14,7 +14,21 @@ materialization, O(S) memory):
   - dkv kernel, grid (b, h, kv_tile, q_tile): accumulate dv = P^T @ dO and
     dk = (P*(dP - delta))^T @ q_scaled in scratch.
 
-Causal masking skips fully-masked tile pairs via pl.when predication.
+Causal masking skips fully-masked tile pairs via pl.when predication.  A
+skipped step computes nothing, but the grid of a causal call without a window
+is a rectangle and Pallas's pipeline copies every block whose index differs
+from the step before, run or not: under the map `j -> j` an off step of the
+forward and of dq still copied a key and a value tile (512 KB at 1024 x 128
+bf16 twice, 256 KB at the backward's 512), one of dkv a query and a dO tile
+and the log-sum-exp and delta blocks, whose `[.., 1024, 1]` float32 rows pad
+to 128 lanes (1.5 MB in all), and at 16,384 positions 120 of a head's 256
+forward steps and 240 of its 512 backward steps are off.  So the index map
+of the inner axis (`_inner_tile`) stays on the diagonal's tile through the
+off steps: the last visible key tile where they come last (forward, dq), the
+first visible query tile where they come first (dkv).  An off step then
+names the block its neighbour named and nothing is copied for it; the run
+steps, their order and the bodies are the ones they were
+(`causal_steps_copying_pct` counts the steps that still copy).
 
 A `window` w (static; None = none) keeps of the causal keys the last w, the
 query's own position counted: query i sees keys i - w + 1 .. i.  A windowed
@@ -41,7 +55,7 @@ Reference parity note: the reference (Ray) has no attention kernels at all
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +89,20 @@ def _pallas_call(kernel, *, name: str, **kwargs):
     return call
 
 
+# (block_q, block_k, bwd_block_q, bwd_block_k) of a call that names none: `flash_attention`'s defaults, and what the
+# two counters below size their tiles from (why these: `flash_attention`'s docstring)
+DEFAULT_BLOCKS = (1024, 1024, 1024, 512)
+
 # -- windows ---------------------------------------------------------------
+
+
+def _lower(a, b):
+    """min of two tile indices, traced grid indices or Python ints."""
+    return jnp.minimum(a, b) if isinstance(a, jax.Array) or isinstance(b, jax.Array) else min(a, b)
+
+
+def _higher(a, b):
+    return jnp.maximum(a, b) if isinstance(a, jax.Array) or isinstance(b, jax.Array) else max(a, b)
 
 
 def _first_visible(i, own: int, other: int, window: int, *, keys: bool):
@@ -84,8 +111,7 @@ def _first_visible(i, own: int, other: int, window: int, *, keys: bool):
     answer a key tile (of `other` positions); without, the reverse.  `i` may
     be a traced grid index or a Python int."""
     if keys:  # the first query's oldest key, i * own - (window - 1), clamped at 0
-        start = i * own - (window - 1)
-        return (jnp.maximum(start, 0) if isinstance(start, jax.Array) else max(start, 0)) // other
+        return _higher(i * own - (window - 1), 0) // other
     return (i * own) // other  # the first key's own position is the first query that sees it
 
 
@@ -113,13 +139,36 @@ def window_tiles_visited_pct(seq: int, window: int) -> Optional[float]:
     the causal call visits at ITS tiles, from the block sizes in use
     (`flash_attention`'s defaults and `_window_blocks`); None at a length no
     tile divides (the kernels do not run there)."""
-    full = _fit_block(seq, 1024)
+    full = _fit_block(seq, DEFAULT_BLOCKS[0])
     if full is None:
         return None
     causal = full * full * sum(qi + 1 for qi in range(seq // full))
-    bq, bk, _, _ = (_fit_block(seq, b) for b in _window_blocks(window, (1024, 1024, 1024, 512)))
+    bq, bk, _, _ = (_fit_block(seq, b) for b in _window_blocks(window, DEFAULT_BLOCKS))
     visited = sum(_visible(seq // bq, seq // bk, bq, bk, window, keys=True))
     return 100.0 * visited * bq * bk / causal
+
+
+def causal_steps_copying_pct(seq: int, block_q: int, block_k: int, *, keys: bool) -> float:
+    """Share (%) of a head's grid steps that make the pipeline copy, in a
+    causal call without a window at these tiles: the steps whose pair of
+    blocks (the outer axis's own tile, the inner axis's by the map) differs
+    from the step before's, evaluated from the map the kernel is given
+    (`_inner_tile`).  With `keys` the outer axis is the query tiles (forward,
+    dq), without it the key tiles (dkv).  100 under the map `j -> j`; with the
+    map held on the diagonal's tile, the visible pairs alone: 136 of 256 at
+    16,384 positions and 1024 x 1024, 272 of 512 at 1024 x 512."""
+    own, other = (block_q, block_k) if keys else (block_k, block_q)
+    n_inner, tile = _inner_tile(seq // own, seq // other, own, other, None, keys=keys, causal=True)
+    steps = [(i, tile(i, j)) for i in range(seq // own) for j in range(n_inner)]
+    return 100.0 * (1 + sum(a != b for a, b in zip(steps, steps[1:]))) / len(steps)
+
+
+def causal_forward_tiles(seq: int, d: int, dv: int) -> Optional[Tuple[int, int]]:
+    """(block_q, block_k) of the causal FORWARD call at this length and these
+    head sizes, from the block sizes in use (`flash_attention`'s defaults,
+    `_head_blocks`, `_fit_block`); None at a length no tile divides."""
+    tiles = tuple(_fit_block(seq, b) for b in _head_blocks(d, dv, DEFAULT_BLOCKS)[:2])
+    return None if None in tiles else tiles
 
 
 def _window_blocks(window: int, blocks):
@@ -212,17 +261,30 @@ def _fwd_kernel(
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l_safe)
 
 
-def _inner_tile(n_own, n_other, own, other, window, *, keys):
+def _inner_tile(n_own, n_other, own, other, window, *, keys, causal):
     """(innermost grid extent, index of the other sequence's tile at grid
     step (i, j)) of a call whose outer tiles are `own` wide: every tile and
-    the step itself without a window; with one, the visible tiles alone,
-    counted from the first, the index held inside the sequence (a step past
-    the last visible tile is predicated off in the kernel)."""
-    if window is None:
+    the step itself without a window and without a mask; with one, the
+    visible tiles alone, counted from the first, the index held inside the
+    sequence (a step past the last visible tile is predicated off in the
+    kernel).  A causal call without a window keeps every step, and on the
+    steps its kernel predicates off the index stays where the run steps
+    beside them have it, so that the pipeline copies nothing for them: on
+    the last visible key tile (`keys`: the off steps come last), on the first
+    visible query tile (the off steps come first; held inside the sequence
+    for a call with more keys than queries).  The bounds are the ones the
+    kernels' `run` predicates compare with."""
+    if window is not None:
+        first = functools.partial(_first_visible, own=own, other=other, window=window, keys=keys)
+        return (max(_visible(n_own, n_other, own, other, window, keys=keys)),
+                lambda i, j: jnp.minimum(first(i) + j, n_other - 1))
+    if not causal:
         return n_other, lambda i, j: j
-    first = functools.partial(_first_visible, own=own, other=other, window=window, keys=keys)
-    return (max(_visible(n_own, n_other, own, other, window, keys=keys)),
-            lambda i, j: jnp.minimum(first(i) + j, n_other - 1))
+    if keys:  # the last key tile that `_fwd_kernel`'s and `_bwd_dq_kernel`'s `run` let through: k_start <= q_start + bq - 1
+        return n_other, lambda i, j: _lower(j, (i * own + own - 1) // other)
+    # (i * own) // other is the first query tile that `_bwd_dkv_kernel`'s `run` lets through:
+    # q_start + bq - 1 >= k_start, with k_start = i * own and bq = other
+    return n_other, lambda i, j: _lower(_higher(j, (i * own) // other), n_other - 1)
 
 
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None):
@@ -234,7 +296,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None):
     vt = v.transpose(0, 2, 1, 3)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True)
+    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True, causal=causal)
     grid = (b, h, sq // block_q, n_k)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, window=window)
     out, lse = _pallas_call(
@@ -383,8 +445,8 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=N
     sk, dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True)
-    n_q, q_tile = _inner_tile(sk // block_k, sq // block_q, block_k, block_q, window, keys=False)
+    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True, causal=causal)
+    n_q, q_tile = _inner_tile(sk // block_k, sq // block_q, block_k, block_q, window, keys=False, causal=causal)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -490,10 +552,10 @@ def flash_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 1024,
-    block_k: int = 1024,
-    bwd_block_q: int = 1024,
-    bwd_block_k: int = 512,
+    block_q: int = DEFAULT_BLOCKS[0],
+    block_k: int = DEFAULT_BLOCKS[1],
+    bwd_block_q: int = DEFAULT_BLOCKS[2],
+    bwd_block_k: int = DEFAULT_BLOCKS[3],
     window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention, [B, S, H, D] layout, GQA via repeated kv heads.  q and
@@ -504,6 +566,15 @@ def flash_attention(
     Forward tiles default larger than backward: the bwd kernels hold four
     [bq, bk] f32 intermediates (logits/p/dp/ds) at once, so 1024x1024 there
     would exceed the ~16MB VMEM scoped budget.
+
+    Which steps copy: a causal call visits every (q tile, k tile) pair of its
+    rectangular grids and predicates off the pairs above the diagonal; their
+    index maps stay on the diagonal's tile through those steps
+    (`_inner_tile`), so only a step that runs brings in a key and value tile
+    (forward, dq) or a query, dO, log-sum-exp and delta tile (dkv): 136 of a
+    head's 256 forward steps at 16,384 positions, 272 of its 512 backward
+    steps (`causal_steps_copying_pct`).  A non-causal call runs and copies on
+    every step.
 
     `window` (static; needs `causal` and equal sequence lengths): query i
     sees keys i - window + 1 .. i, and a tile no query of the call sees is

@@ -28,8 +28,9 @@ if ROOT not in sys.path:
 from benchmarks.builders import swa_moe_decoder as builder  # noqa: E402
 from benchmarks.lib import reference_mellum as ref  # noqa: E402
 from ray_tpu.models import LMTrainContext, TransformerConfig, moe  # noqa: E402
-from ray_tpu.models import transformer  # noqa: E402
+from ray_tpu.models import lm, transformer  # noqa: E402
 from ray_tpu.models.lm import WINDOW_TILES  # noqa: E402
+from ray_tpu.models.mixers import MIXERS  # noqa: E402
 from ray_tpu.ops import attention as attn_ops  # noqa: E402
 from ray_tpu.ops.pallas import flash_attention as fa  # noqa: E402
 from ray_tpu.ops.pallas import grouped_matmul as gmm_kernels  # noqa: E402
@@ -425,6 +426,65 @@ def test_window_tiles_and_held_rows_reach_the_step_metrics_and_the_run_record(ti
     assert newest[WINDOW_TILES] == pytest.approx(fa.window_tiles_visited_pct(256, 128))
     assert {"moe_held_rows_mean", "moe_load_max_over_mean", "moe_rows_moved_share"} <= set(newest)
     assert newest["moe_held_rows_mean"] * 4 * 8 <= 256 * 4 * 8  # rows held of all layers <= assignments of all layers
+
+
+def test_the_causal_steps_that_copy_reach_the_run_record_and_add_no_equation_to_the_step(tiny):
+    """`attn_causal_steps_copying_pct` (PR 55): the forward's value at the
+    tiles in use, the mean over the layers without a window; a constant of
+    the traced step; absent at a length no tile divides."""
+    assert lm.CAUSAL_STEPS in lm.STEP_COUNTERS
+    cfg = dataclasses.replace(tiny["cfg"], max_seq_len=1280)
+    assert lm._causal_counters(cfg, 16384) == {lm.CAUSAL_STEPS: pytest.approx(100 * 136 / 256)}  # 1024 x 1024
+    assert lm._causal_counters(cfg, 1280) == {lm.CAUSAL_STEPS: pytest.approx(100 * 3 / 4)}  # 640 x 640: 2 q tiles
+    assert lm._causal_counters(cfg, 256) == {lm.CAUSAL_STEPS: 100.0}  # one q tile: every step runs
+    assert lm._causal_counters(cfg, 1100) == {} == lm._window_counters(cfg, 1100)  # no tile divides it: the kernels do not run
+    every = dataclasses.replace(cfg, layer_windows=(128,) * 8)
+    assert lm._causal_counters(every, 1280) == {}  # no layer without a window
+    latent = TransformerConfig.tiny(layer_types=("mla", "attention"), kv_lora_rank=16, qk_nope_head_dim=192, qk_rope_head_dim=64,
+                                    v_head_dim=256)
+    # heads of 256 / 256 halve the forward's key tile (`_head_blocks`): 1024 x 512 at the one layer, 1024 x 1024 at the other
+    assert lm._causal_counters(latent, 8192) == {lm.CAUSAL_STEPS: pytest.approx(100 * (72 / 128 + 36 / 64) / 2)}
+    ctx = one_device_ctx(cfg)
+    run_record.drain_step_counters(), run_record.drain_step_series()
+    state = ctx.init_state(seed=0)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (1, 1280), 0, cfg.vocab_size))
+    batch = {"tokens": tokens, "targets": tokens}
+    state, metrics = ctx.train_step(state, batch)
+    jax.block_until_ready(metrics)
+    newest = run_record.drain_step_counters()
+    assert newest[lm.CAUSAL_STEPS] == pytest.approx(100 * 3 / 4) and newest[WINDOW_TILES] > 0
+    # known when the step is traced: the step's equations are the ones of a step that notes nothing
+    equations = lambda: len(jax.make_jaxpr(ctx._train_step.__wrapped__)(state, ctx.make_batch(batch)).jaxpr.eqns)  # noqa: E731
+    with_counter = equations()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(lm, "_causal_counters", lambda config, seq: {})
+        assert equations() == with_counter
+
+
+@pytest.mark.parametrize("kw", [
+    dict(layer_types=("attention",) * 2),
+    dict(layer_types=("mla", "attention"), kv_lora_rank=16, qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=64),
+    dict(layer_types=("diff_attention", "diff_cross"), kv_source_layer=0, n_heads=4, n_kv_heads=2),
+], ids=["attention", "mla", "differential"])
+def test_flash_heads_are_the_head_sizes_of_the_call_each_kind_makes(kw):
+    """`Mixer.flash_heads` sizes the counter's tiles apart from the call in
+    `mix()`: a kind whose call changes its head sizes fails here instead of
+    noting tiles its kernel does not use."""
+    cfg = TransformerConfig.tiny(n_layers=2, **kw)
+    calls = set()  # (kind, q/k head size, v head size): a run of equal layers is traced once
+
+    def noting(kind):
+        def call(q, k, v, **kwargs):
+            calls.add((kind, q.shape[-1], v.shape[-1]))
+            return attn_ops.dot_product_attention(q, k, v, **kwargs)
+        return call
+
+    with pytest.MonkeyPatch.context() as patch:
+        for kind in set(cfg.layer_types):  # both differential kinds share a module and a core: one name there
+            patch.setattr(sys.modules[MIXERS[kind].mix.__module__], "dot_product_attention", noting(MIXERS[kind].mix.__module__))
+        tokens = jnp.zeros((1, 32), jnp.int32)
+        jax.eval_shape(lambda p: transformer.forward(p, tokens, cfg), transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    assert calls == {(MIXERS[kind].mix.__module__, *MIXERS[kind].flash_heads(cfg)) for kind in cfg.layer_types}
 
 
 def test_the_ring_and_the_pipeline_refuse_what_they_cannot_run_when_the_context_is_built():

@@ -157,3 +157,107 @@ def test_ring_attention_matches_reference(causal):
     ref = reference_attention(q, k, v, causal=causal)
     out = ring_attention_sharded(q, k, v, mesh, causal=causal, head_axis=None)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
+
+
+# -- the index maps of a causal call (PR 55) -------------------------------------------------------
+
+
+def _maps_of_the_parent(monkeypatch):
+    """`_inner_tile` with the causal call's map put back to `j -> j`."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    real = fa._inner_tile
+
+    def parents(*args, keys, causal):
+        n, tile = real(*args, keys=keys, causal=causal)
+        return (n, lambda i, j: j) if args[4] is None else (n, tile)
+
+    monkeypatch.setattr(fa, "_inner_tile", parents)
+
+
+@pytest.mark.parametrize("seq, tiles, d, dv", [(512, (128, 128), 64, 64), (1024, (256, 128), 128, 128),
+                                               (512, (128, 128), 192, 128)])
+def test_a_causal_calls_clamped_maps_change_no_bit_of_out_lse_dq_dk_dv(monkeypatch, seq, tiles, d, dv):
+    """The maps name other blocks on the steps that compute nothing alone."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(seq + d), 4)
+    q = jax.random.normal(kq, (1, seq, 2, d), jnp.float32)
+    k = jax.random.normal(kk, (1, seq, 2, d), jnp.float32)
+    v = jax.random.normal(kv, (1, seq, 2, dv), jnp.float32)
+    g = jax.random.normal(kg, (1, seq, 2, dv), jnp.float32)
+    bq, bk = tiles
+    blocks = dict(causal=True, scale=d ** -0.5, block_q=bq, block_k=bk)
+
+    def everything():
+        out, lse = fa._flash_fwd(q, k, v, **blocks)
+        return (out, lse) + fa._flash_bwd(q, k, v, out, lse, g, **blocks)
+
+    changed = everything()
+    _maps_of_the_parent(monkeypatch)
+    parents = everything()
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), changed, parents):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    ref = reference_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(changed[0]), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("seq, bq, bk", [(16384, 1024, 1024), (16384, 1024, 512), (4096, 1024, 512), (1024, 256, 128),
+                                         (1024, 128, 256), (1024, 1024, 512)])
+def test_a_causal_map_gives_a_run_step_its_own_tile_and_an_off_step_its_neighbours(monkeypatch, seq, bq, bk):
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    n_q, n_k = seq // bq, seq // bk
+    runs = lambda qi, ki: ki * bk <= qi * bq + bq - 1  # noqa: E731  (the kernels' `run`, all three)
+    pairs = sum(runs(qi, ki) for qi in range(n_q) for ki in range(n_k))
+    # forward and dq: key tiles inside, the off steps LAST
+    n, tile = fa._inner_tile(n_q, n_k, bq, bk, None, keys=True, causal=True)
+    assert n == n_k
+    for qi in range(n_q):
+        last = max(ki for ki in range(n_k) if runs(qi, ki))
+        assert [tile(qi, ki) for ki in range(n_k)] == [ki if runs(qi, ki) else last for ki in range(n_k)]
+    # dkv: query tiles inside, the off steps FIRST
+    n, tile = fa._inner_tile(n_k, n_q, bk, bq, None, keys=False, causal=True)
+    assert n == n_q
+    for ki in range(n_k):
+        first = min(qi for qi in range(n_q) if runs(qi, ki))
+        assert [tile(ki, qi) for qi in range(n_q)] == [qi if runs(qi, ki) else first for qi in range(n_q)]
+    # the counter: the visible pairs, from the same maps; a map `j -> j` copies on every step
+    for keys, steps in ((True, n_q * n_k), (False, n_k * n_q)):
+        assert fa.causal_steps_copying_pct(seq, bq, bk, keys=keys) == pytest.approx(100 * pairs / steps)
+    no_mask = fa._inner_tile(n_q, n_k, bq, bk, None, keys=True, causal=False)[1]
+    assert [no_mask(3, j) for j in range(n_k)] == list(range(n_k))
+    _maps_of_the_parent(monkeypatch)
+    assert fa.causal_steps_copying_pct(seq, bq, bk, keys=True) == fa.causal_steps_copying_pct(seq, bq, bk, keys=False) == 100
+
+
+def test_causal_steps_copying_counts_the_visible_pairs_at_the_tiles_in_use():
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    assert fa.causal_steps_copying_pct(16384, 1024, 1024, keys=True) == pytest.approx(100 * 136 / 256)
+    assert fa.causal_steps_copying_pct(16384, 1024, 512, keys=True) == pytest.approx(100 * 272 / 512)  # dq
+    assert fa.causal_steps_copying_pct(16384, 1024, 512, keys=False) == pytest.approx(100 * 272 / 512)  # dkv
+    assert fa.causal_steps_copying_pct(1024, 1024, 1024, keys=True) == 100  # one q tile: every step runs
+    assert fa.causal_steps_copying_pct(1024, 1024, 512, keys=False) == 100
+    assert fa.causal_forward_tiles(16384, 128, 128) == (1024, 1024) and fa.causal_forward_tiles(8192, 256, 256) == (1024, 512)
+    assert fa.causal_forward_tiles(4224, 128, 128) == (384, 384) and fa.causal_forward_tiles(1100, 128, 128) is None
+
+
+def test_a_causal_call_with_more_keys_than_queries_keeps_its_maps_inside_the_sequences():
+    """`sq != sk`: the bounds are the predicates' own, held inside the grid."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    n, tile = fa._inner_tile(4, 2, 128, 128, None, keys=False, causal=True)  # 4 key tiles, 2 query tiles
+    assert [[tile(ki, qi) for qi in range(n)] for ki in range(4)] == [[0, 1], [1, 1], [1, 1], [1, 1]]
+    n, tile = fa._inner_tile(2, 4, 128, 128, None, keys=True, causal=True)
+    assert [[tile(qi, ki) for ki in range(n)] for qi in range(2)] == [[0, 0, 0, 0], [0, 1, 1, 1]]
+    q, k, v = _qkv(jax.random.PRNGKey(11), b=1, s=512, h=2, d=64)
+    q = q[:, :256]
+    flash = lambda q, k, v: fa.flash_attention(  # noqa: E731
+        q, k, v, causal=True, block_q=128, block_k=128, bwd_block_q=128, bwd_block_k=128)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)), np.asarray(reference_attention(q, k, v, causal=True)),
+                               atol=2e-5, rtol=2e-5)
+    grads = jax.grad(lambda *a: flash(*a).sum(), argnums=(0, 1, 2))(q, k, v)
+    wanted = jax.grad(lambda *a: reference_attention(*a, causal=True).sum(), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(grads, wanted):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
