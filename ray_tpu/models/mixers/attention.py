@@ -4,11 +4,18 @@ q/k/v projections (GQA: `n_heads` q heads, `n_kv_heads` k and v heads of
 `head_dim`), no bias; optionally (`qk_norm`) an RMSNorm with a learned scale
 over the WHOLE projected q and k (True: OLMoE, OLMo 2; scales [heads,
 head_dim]) or over each HEAD of them ("per_head": the Qwen3 family's; one
-scale [head_dim] for q's heads, one for k's); the rotary embedding of the layer
-(`layer_ropes`: ops/rotary.py `Rope`) or else the model's (`rope_theta`; none
-when that is None); causal softmax of `q k^T * attention_scale`
+scale [head_dim] for q's heads, one for k's; `1 + w` under `norm_zero_centred`);
+the rotary embedding of the layer (`layer_ropes`: ops/rotary.py `Rope`) or else
+the model's (`rope_theta`, on the first `rotary_dim` dims of each head where
+the model states a rotated PART, the rest of the head passing as it is; none
+when `rope_theta` is None); causal softmax of `q k^T * attention_scale`
 (`head_dim ** -0.5` when None), with a window w (`layer_windows`) query i
-seeing keys i - w + 1 .. i; output projection.  In a model that has
+seeing keys i - w + 1 .. i; with `attn_output_gate` (Qwen3-Next) q's
+projection is twice as wide, each head's columns its q and then its gate, and
+the core's output is multiplied by `sigmoid(gate)` (the gate is a named
+residual, `attn_gate`, so `qkv_attn` runs `wq` once); output projection (in
+such a model the gate, `wo` and the residual add lie under one more scope,
+`attn/gate`).  In a model that has
 `layer_windows` the core lies under one more scope, `attn/window` or
 `attn/full`, which tells a trace the two kinds of layer apart.
 
@@ -29,29 +36,31 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.mixers.base import (
-    Leaf, Mixer, constrainer, fitting_axis, joined, may_ring, normal, ones, out_scale, proj_scale,
+    Leaf, Mixer, constrainer, fitting_axis, joined, may_ring, norm_scale, normal, out_scale, proj_scale,
     refuse_attn_bias, ring_axis, rms_norm, stream_norm,
 )
 from ray_tpu.ops.attention import dot_product_attention
 from ray_tpu.ops.rotary import Rope, apply_rope
 from ray_tpu.util import tracing
 
+ATTN_GATE = "attn_gate"  # the output gate's logits, the second half of each head's columns of `wq`
+
 
 def leaves(config):
     c, hd = config, config.head_dim
     q, kv = ("embed", "heads", "head_dim"), ("embed", "kv_heads", "head_dim")
     out = {
-        "wq": Leaf((c.d_model, c.n_heads, hd), q, normal(proj_scale(c))),
+        "wq": Leaf((c.d_model, c.n_heads, 2 * hd if c.attn_output_gate else hd), q, normal(proj_scale(c))),
         "wk": Leaf((c.d_model, c.n_kv_heads, hd), kv, normal(proj_scale(c))),
         "wv": Leaf((c.d_model, c.n_kv_heads, hd), kv, normal(proj_scale(c))),
         "wo": Leaf((c.n_heads, hd, c.d_model), ("heads", "head_dim", "embed"), normal(out_scale(c))),
     }
     if c.qk_norm == "per_head":
-        out["q_norm"] = ones((hd,), ("head_dim",))
-        out["k_norm"] = ones((hd,), ("head_dim",))
+        out["q_norm"] = norm_scale(c, (hd,), ("head_dim",))
+        out["k_norm"] = norm_scale(c, (hd,), ("head_dim",))
     elif c.qk_norm:
-        out["q_norm"] = ones((c.n_heads, hd), ("heads", "head_dim"))
-        out["k_norm"] = ones((c.n_kv_heads, hd), ("kv_heads", "head_dim"))
+        out["q_norm"] = norm_scale(c, (c.n_heads, hd), ("heads", "head_dim"))
+        out["k_norm"] = norm_scale(c, (c.n_kv_heads, hd), ("kv_heads", "head_dim"))
     return out
 
 
@@ -86,19 +95,21 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     with tracing.scope("layer/attn_proj"):
         h = stream_norm(c, x, layer_params, "ln1")
         q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(dt))
+        if c.attn_output_gate:
+            q, gate = q[..., :c.head_dim], checkpoint_name(q[..., c.head_dim:], ATTN_GATE)
         kk = jnp.einsum("bse,ehd->bshd", h, p["wk"].astype(dt))
         vv = jnp.einsum("bse,ehd->bshd", h, p["wv"].astype(dt))
         q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
         kk = constrain(kk, ("act_batch", "act_seq", "act_kv_heads", "act_head_dim"))
         if c.qk_norm == "per_head":
-            q = rms_norm(q, p["q_norm"], c.norm_eps)
-            kk = rms_norm(kk, p["k_norm"], c.norm_eps)
+            q = rms_norm(q, p["q_norm"], c.norm_eps, zero_centred=c.norm_zero_centred)
+            kk = rms_norm(kk, p["k_norm"], c.norm_eps, zero_centred=c.norm_zero_centred)
         elif c.qk_norm:
             # over the WHOLE projection: heads * head_dim is one vector per position
-            q = rms_norm(q, p["q_norm"], c.norm_eps, axis=(-2, -1))
-            kk = rms_norm(kk, p["k_norm"], c.norm_eps, axis=(-2, -1))
+            q = rms_norm(q, p["q_norm"], c.norm_eps, axis=(-2, -1), zero_centred=c.norm_zero_centred)
+            kk = rms_norm(kk, p["k_norm"], c.norm_eps, axis=(-2, -1), zero_centred=c.norm_zero_centred)
         if rope is None and c.rope_theta is not None:
-            rope = Rope(c.rope_theta)
+            rope = Rope(c.rope_theta, rotary_dim=c.rotary_dim)
         if rope is not None:
             q = apply_rope(q, positions, rope)
             kk = apply_rope(kk, positions, rope)
@@ -138,10 +149,14 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
                 batch_axes=batch_axes, head_axis=head_ax,
                 **({} if window is None else {"window": window}),
             )
-    with tracing.scope("layer/attn_proj"):
+    # `attn/gate` holds the gate AND `wo`: XLA fuses the gate's pass into the projection's operand, and a fusion
+    # carries one name (its root's), so a scope around the gate alone would reach no op of a trace
+    with tracing.scope("layer/attn_proj"), (tracing.scope("attn/gate") if c.attn_output_gate else contextlib.nullcontext()):
+        if c.attn_output_gate:
+            attn = (attn * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
         attn_out = jnp.einsum("bshd,hde->bse", attn, p["wo"].astype(dt))
         return joined(c, x, attn_out, constrain), {}
 
 
-MIXER = Mixer("attention", "layers", "attn", leaves, refuse_attn_bias, mix, rotates=True, placement=placement,
-              flash_heads=lambda c: (c.head_dim, c.head_dim))
+MIXER = Mixer("attention", "layers", "attn", leaves, refuse_attn_bias, mix, saved=(ATTN_GATE,), rotates=True,
+              placement=placement, flash_heads=lambda c: (c.head_dim, c.head_dim))

@@ -79,6 +79,11 @@ def log_uniform(low: float, high: float):
     return lambda key, shape: jnp.exp(jax.random.uniform(key, shape, jnp.float32) * span + floor)
 
 
+def log_of_uniform(low: float, high: float):
+    """log A with A uniform in [low, high]: a delta rule's stored `A_log`."""
+    return lambda key, shape: jnp.log(jax.random.uniform(key, shape, jnp.float32, low, high))
+
+
 def inv_softplus(draw):
     """The bias b with softplus(b) = what `draw` gives (a step dt)."""
     def init(key, shape):
@@ -98,9 +103,15 @@ def ones(shape, axes=None) -> Leaf:
     return Leaf(shape, axes or (None,) * len(shape), lambda key, full: jnp.ones(full, jnp.float32), draws=False)
 
 
-def zeros(shape) -> Leaf:
-    """A bias: starts at 0."""
-    return Leaf(shape, (None,) * len(shape), lambda key, full: jnp.zeros(full, jnp.float32), draws=False)
+def zeros(shape, axes=None) -> Leaf:
+    """A bias, a zero-centred norm's stored scale: starts at 0."""
+    return Leaf(shape, axes or (None,) * len(shape), lambda key, full: jnp.zeros(full, jnp.float32), draws=False)
+
+
+def norm_scale(config, shape, axes=None) -> Leaf:
+    """The stored scale of a norm of the stream or of q and k: 1, or with
+    `norm_zero_centred` the w of `1 + w`, 0."""
+    return (zeros if config.norm_zero_centred else ones)(shape, axes)
 
 
 def proj_scale(config) -> float:
@@ -115,10 +126,21 @@ def out_scale(config) -> float:
 # -- the stream ------------------------------------------------------------------------
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float, axis=-1) -> jax.Array:
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float, axis=-1, zero_centred: bool = False) -> jax.Array:
+    """`zero_centred`: the scale is `1 + weight` (the Qwen3-Next family stores
+    w and starts it at 0, so weight decay pulls the scale to 1), applied in
+    float32 before the one rounding to x's dtype."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=axis, keepdims=True)
+    if zero_centred:
+        return (xf * jax.lax.rsqrt(var + eps) * (1.0 + weight.astype(jnp.float32))).astype(x.dtype)
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
+
+
+def l2_normed(x: jax.Array, scale: float = 1.0, eps: float = 1e-6) -> jax.Array:
+    """x / |x|_2 over the last axis (a head), times `scale`, in float32: a delta rule's q and k."""
+    xf = x.astype(jnp.float32)
+    return xf * (jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps) * scale)
 
 
 def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array, eps: float) -> jax.Array:
@@ -134,7 +156,7 @@ def stream_norm(config, x: jax.Array, params: Dict, name: str) -> jax.Array:
     """The stream's norm called `name` in `params`, of the configured kind."""
     if config.norm_kind == "layer":
         return layer_norm(x, params[name], params[name + "_b"], config.norm_eps)
-    return rms_norm(x, params[name], config.norm_eps)
+    return rms_norm(x, params[name], config.norm_eps, zero_centred=config.norm_zero_centred)
 
 
 def constrainer(rules: Optional[Rules], mesh):

@@ -23,8 +23,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.mixers.base import (
-    Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, log_uniform, normal, ones, out_scale,
-    proj_scale, rms_norm, stream_norm,
+    Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, l2_normed, log_of_uniform, log_uniform, normal, ones,
+    out_scale, proj_scale, rms_norm, stream_norm,
 )
 from ray_tpu.ops.kda import kda_chunked
 from ray_tpu.ops.ssm import causal_conv1d_silu
@@ -52,7 +52,7 @@ def leaves(config):
         "g_down": Leaf((c.d_model, dim), ("embed", None), into),
         "g_up": Leaf((dim, inner), (None, None), up),
         "w_beta": Leaf((c.d_model, heads), ("embed", None), into),
-        "A_log": Leaf((heads,), (None,), lambda key, shape: jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))),
+        "A_log": Leaf((heads,), (None,), log_of_uniform(1.0, 16.0)),
         "dt_bias": Leaf((inner,), (None,), inv_softplus(log_uniform(1e-3, 1e-1))),
         "norm": ones((dim,)),
         "wo": Leaf((inner, c.d_model), (None, "embed"), normal(out_scale(c))),
@@ -62,12 +62,6 @@ def leaves(config):
 def validate(config) -> None:
     if not (config.kda_heads > 0 and config.kda_head_dim > 0):
         raise ValueError("a kda layer needs kda_heads and kda_head_dim")
-
-
-def _l2_normed(x: jax.Array, scale: float = 1.0, eps: float = 1e-6) -> jax.Array:
-    """x / |x|_2 over the last axis (a head), times `scale`, in float32."""
-    xf = x.astype(jnp.float32)
-    return xf * (jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps) * scale)
 
 
 def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, data=None, shared=None, emit=False):
@@ -98,7 +92,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
         with tracing.scope("kda/conv"):
             qkv = causal_conv1d_silu(qkv, p["conv_w"], jnp.zeros((3 * inner,), p["conv_w"].dtype), **sharded)
             q, k, v = (a.reshape(*a.shape[:2], heads, dim) for a in jnp.split(qkv, 3, axis=-1))
-            q, k = _l2_normed(q, dim ** -0.5), _l2_normed(k)
+            q, k = l2_normed(q, dim ** -0.5), l2_normed(k)
             step = jax.nn.softplus(decay_in.astype(f32) + p["dt_bias"].astype(f32))
             g = step.reshape(*step.shape[:2], heads, dim) * -jnp.exp(p["A_log"].astype(f32))[:, None]
             beta = jax.nn.sigmoid(low[..., 2 * dim:].astype(f32))

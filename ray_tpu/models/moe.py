@@ -6,8 +6,8 @@ kind is "experts" gets this block where a dense one has its SwiGLU
 description of the model, this module reads `d_model`, `expert_width` (ONE
 expert's width), `n_experts`, `experts_per_token`, `norm_topk_prob`,
 `router_activation`, `routed_scaling_factor`, `n_shared_experts`,
-`shared_expert_width`, `expert_kind`, `n_experts_held` / `first_expert_held`,
-`dtype`.
+`shared_expert_width`, `shared_expert_gate`, `expert_kind`, `n_experts_held` /
+`first_expert_held`, `dtype`.
 
 An expert, routed or shared, is one of two forms (`expert_kind`): "swiglu",
 `W_down(silu(W_gate u) * (W_up u))`, three matrices (OLMoE, Kimi Linear), or
@@ -39,7 +39,9 @@ The layer, on T tokens with K choices each out of E experts:
 - `moe/shared` (with `n_shared_experts`): one more expert of the same form,
   `n_shared_experts` experts wide or as wide as the model states
   (`shared_expert_d_ff`: Nemotron-3-Nano's 3712 beside routed 1856), that
-  every token goes through, added to the routed result.
+  every token goes through, added to the routed result; with
+  `shared_expert_gate` (Qwen3-Next) times `sigmoid(u w_sg)`, a scalar a token
+  from a stored `w_sg` [d, 1] (`shared.gate`).
 
 Held experts (`n_experts_held`): the layer is TOLD which experts it holds,
 `first_expert_held .. + n_experts_held` of the E the router scores, as one
@@ -136,6 +138,8 @@ def moe_param_axes(config: Any) -> Dict:
         axes["router_bias"] = (None,)
     if config.shared_expert_width:
         axes["shared"] = {n: one[n] for n in names}
+        if config.shared_expert_gate:
+            axes["shared"]["gate"] = ("embed", None)
     return axes
 
 
@@ -177,6 +181,8 @@ def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
         params["router_bias"] = jnp.zeros(leading + (E,), c.param_dtype)
     if c.shared_expert_width:
         params["shared"] = matrices(jax.random.split(jax.random.fold_in(key, 1), 3), c.shared_expert_width, down_scale)
+        if c.shared_expert_gate:
+            params["shared"]["gate"] = init(jax.random.fold_in(key, 2), (D, 1), scale)
     return params
 
 
@@ -536,7 +542,11 @@ def moe_ffn(
         with tracing.scope("moe/shared"):
             w = [params["shared"][k].astype(x.dtype) for k in expert_leaves(config)]
             hidden = _activation(x, w[:-1], lambda h, m: jnp.einsum("bse,ef->bsf", h, m))
-            return y + jnp.einsum("bsf,fe->bse", hidden, w[-1])
+            out = jnp.einsum("bsf,fe->bse", hidden, w[-1])
+            if config.shared_expert_gate:
+                logit = jnp.einsum("bse,ef->bsf", x, params["shared"]["gate"].astype(x.dtype))
+                out = out * jax.nn.sigmoid(logit.astype(jnp.float32)).astype(x.dtype)
+            return y + out
 
     if not across_devices:
         y, rows, moved = body(x, expert_idx, gates, *weights)

@@ -4,7 +4,8 @@ What one `TransformerConfig` expresses: a stack of pre-norm layers, each a
 (MIXER, FFN) pair around a residual stream.  The mixer (`layer_types`) is
 one of the kinds of `ray_tpu/models/mixers/`, one module each with its
 mathematics: causal softmax attention, a Mamba-2 selective state-space
-layer, Kimi Delta Attention, latent attention (with or without a rotary part), and
+layer, Kimi Delta Attention, Gated DeltaNet (a delta rule with one decay a
+head), latent attention (with or without a rotary part), and
 the four of SambaY's decoder-hybrid-decoder (a Mamba-1 selective scan,
 differential attention, a Gated Memory Unit that reads one scan's output,
 differential cross-attention over one attention layer's keys and values).
@@ -31,7 +32,10 @@ run through it at their published widths (benchmarks/configs/), and
 GLM-4.7-Flash with them: latent attention with a rope on part of each head
 and a low-rank q (`mla_rope`, `q_lora_rank`), and a multi-token-prediction
 module behind the trunk (`mtp_depth`: `mtp_rows`, weighed into the loss by
-models/lm.py).
+models/lm.py); and Qwen3-Next: "gdn" layers 3:1 with softmax attention whose
+heads rotate a part of themselves (`rotary_dim`) and whose output passes a
+sigmoid gate (`attn_output_gate`), zero-centred norms (`norm_zero_centred`)
+and a shared expert behind a scalar gate (`shared_expert_gate`).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -90,7 +94,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.mixers import MIXERS, Leaf, Mixer
-from ray_tpu.models.mixers.base import joined, normal, ones, out_scale, proj_scale, stream_norm, zeros
+from ray_tpu.models.mixers.base import joined, norm_scale, normal, ones, out_scale, proj_scale, stream_norm, zeros
 # the names lm.py and the tests hold these two by
 from ray_tpu.models.mixers.base import constrainer as _constrainer, rms_norm  # noqa: F401
 from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
@@ -195,6 +199,21 @@ class TransformerConfig:
     # them, one scale of `head_dim` for q's heads and one for k's (the Qwen3
     # family).
     qk_norm: Union[bool, str] = False
+    # What of "attention" layers the Qwen3-Next family adds.  `rotary_dim`:
+    # the model's rope (`rope_theta`) rotates the first `rotary_dim` dims of
+    # each head and passes the rest (None = the whole head).
+    # `attn_output_gate`: `wq` is twice as wide, each head's columns its q and
+    # its gate, and the core's output is multiplied by `sigmoid(gate)` before
+    # `wo`.  `norm_zero_centred`: every RMSNorm of the stream (`ln1`, `ln2`,
+    # `final_norm`) and of q and k (`qk_norm`) scales by `1 + w` with w stored
+    # and started at 0, so weight decay pulls the scale to 1 and not to 0; the
+    # norms INSIDE a mixer (a latent's, a delta rule's gated one) stay plain.
+    # `shared_expert_gate`: the shared expert's output is multiplied by
+    # `sigmoid(u w_sg)`, one scalar a token (`w_sg` [d, 1]).
+    rotary_dim: Optional[int] = None
+    attn_output_gate: bool = False
+    norm_zero_centred: bool = False
+    shared_expert_gate: bool = False
     # The mixer of each layer, one of `mixers.MIXERS`, one entry per layer;
     # None = attention everywhere.  The Mamba-2 sizes are read only when some
     # layer is "mamba": heads x head size = the mixer's inner width, the
@@ -219,6 +238,15 @@ class TransformerConfig:
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
+    # Gated DeltaNet, read only when some layer is "gdn": the heads of q and k
+    # and their size, the heads of v (a multiple: value head j reads key head
+    # j // (value heads / key heads)) and their size, the width of the causal
+    # depthwise convolutions.
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
     # Latent attention, read only when some layer is "mla" (`n_heads` heads):
     # the latent's width, the two parts of a q/k head, the size of a v head.
     # `q_lora_rank`: q is low-rank too, `RMSNorm(u W_qa) W_qb` (None = one
@@ -316,6 +344,11 @@ class TransformerConfig:
             raise ValueError(f"qk_norm is False, True (over the whole projection) or 'per_head', got {self.qk_norm!r}")
         if self.norm_kind not in ("rms", "layer"):
             raise ValueError(f"unknown norm_kind {self.norm_kind!r}; expected 'rms' or 'layer'")
+        if self.norm_zero_centred and (self.norm_kind != "rms" or self.mtp_depth):
+            raise ValueError("norm_zero_centred is the RMSNorms' of the stream and of q and k: not beside norm_kind "
+                             "'layer', nor beside mtp_depth (the module's own norms are plain)")
+        if self.shared_expert_gate and not self.shared_expert_width:
+            raise ValueError("shared_expert_gate needs a shared expert (n_shared_experts)")
         if self.ffn_types is not None:
             object.__setattr__(self, "ffn_types", tuple(self.ffn_types))
             unknown = set(self.ffn_types) - set(FFN_KINDS)
@@ -475,7 +508,7 @@ def _model_leaves(config: TransformerConfig) -> Dict:
     shapes and axes as they are: nothing is stacked)."""
     c = config
     top = {"embed": {"tokens": Leaf((c.vocab_size, c.d_model), ("vocab", "embed"), normal(proj_scale(c)))},
-           "final_norm": ones((c.d_model,))}
+           "final_norm": norm_scale(c, (c.d_model,))}
     if c.norm_kind == "layer":
         top["final_norm_b"] = zeros((c.d_model,))
     if not c.tie_embeddings:
@@ -494,7 +527,7 @@ def _layer_leaves(config: TransformerConfig, mixer: str, ffn: str) -> Dict:
         "w_up": Leaf((d, c.d_ff), ("embed", "mlp"), normal(proj_scale(c))),
         "w_down": Leaf((c.d_ff, d), ("mlp", "embed"), normal(out_scale(c))),
     }
-    norms = {"ln1": ones((d,)), "ln2": ones((d,))}
+    norms = {"ln1": norm_scale(c, (d,)), "ln2": norm_scale(c, (d,))}
     if c.norm_kind == "layer":
         norms.update(ln1_b=zeros((d,)), ln2_b=zeros((d,)))
     if ffn == "none":

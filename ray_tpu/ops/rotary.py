@@ -16,6 +16,11 @@ position p by the angle `p * inv_freq[i]`:
   to `low` keep their frequency, pairs from `high` on are divided by s, linear
   between.  `cos` and `sin` are multiplied by `attention_factor` (None:
   `0.1 ln s + 1`).
+
+A rope may rotate a PART of a head (`rotary_dim` R < D, a published
+`partial_rotary_factor`: Qwen3-Next rotates 64 of 256): the first R dims are
+rotated as a head of size R would be (`inv_freq[i] = theta^(-2i / R)`, R / 2
+pairs), the other D - R pass unrotated.
 """
 
 from __future__ import annotations
@@ -38,8 +43,11 @@ class Rope:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: Optional[float] = None
+    rotary_dim: Optional[int] = None  # the rotated width, the FIRST dims of a head; None = the whole head
 
     def __post_init__(self):
+        if self.rotary_dim is not None and (self.rotary_dim <= 0 or self.rotary_dim % 2):
+            raise ValueError(f"a rope rotates pairs: rotary_dim is even and positive, got {self.rotary_dim}")
         if self.factor is not None and not (self.factor >= 1.0 and self.original_max_position > 0):
             raise ValueError(f"a YaRN rope needs factor >= 1 and original_max_position > 0, got {self}")
 
@@ -77,7 +85,14 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0) -> jax.Array:
 
 def apply_rope(x: jax.Array, positions: jax.Array, rope: Rope) -> jax.Array:
     """Rotate [..., seq, heads, head_dim] by absolute positions [seq] (or
-    broadcastable [..., seq]) with `rope`."""
+    broadcastable [..., seq]) with `rope`: the whole head, or its first
+    `rope.rotary_dim` dims with the rest passed on as they are."""
+    if rope.rotary_dim is not None and rope.rotary_dim != x.shape[-1]:
+        if rope.rotary_dim > x.shape[-1]:
+            raise ValueError(f"rotary_dim {rope.rotary_dim} is wider than the head ({x.shape[-1]})")
+        whole = dataclasses.replace(rope, rotary_dim=None)
+        rotated = apply_rope(x[..., :rope.rotary_dim], positions, whole)
+        return jnp.concatenate([rotated, x[..., rope.rotary_dim:]], axis=-1)
     freqs = rope.inv_freq(x.shape[-1])
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, D/2]
     cos = jnp.cos(angles)[..., :, None, :]

@@ -38,6 +38,7 @@ def test_forward_shapes():
 SIZES = dict(
     norm_kind="layer", qk_norm=True, rope_theta=None, ssm_heads=2, ssm_head_dim=16, ssm_state=8, kda_heads=2,
     kda_head_dim=8, kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=32, s6_inner=96,
+    gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=16,
 )
 EXPERTS = dict(n_experts=4, experts_per_token=2, moe_d_ff=48, n_shared_experts=1, router_activation="sigmoid")
 
