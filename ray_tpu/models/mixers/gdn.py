@@ -9,14 +9,15 @@ of Hk:
 column order), `[b | a] = u W_ba` (d -> 2 Hv);
 `[q | k | v] <- silu(causal_depthwise_conv1d([q | k | v]))`, width `gdn_conv`,
 one call over the 2 Hk Dk + Hv Dv channels; value head j reads key head
-`j // (Hv / Hk)` (q and k repeated); per head `q <- q / |q|_2 * Dk^-0.5`,
+`j // (Hv / Hk)`; per head `q <- q / |q|_2 * Dk^-0.5`,
 `k <- k / |k|_2`; `beta = sigmoid(b)` and the log decay
 `g = -exp(A_log[h]) * softplus(a + dt_bias[h])`, one number a value head,
 float32; the recurrence of `ops/kda.py` (state [Dk, Dv] per value head,
-float32) in its chunked form, the head's decay broadcast over the key's
-channels: a decay per head IS the per-channel rule with equal channels, so
-`kda_chunked` computes it exactly (a form that takes the scalar decay as one
-[C, C] mask on a plain `k k^T`, as `ssd_chunked` does, is PERF.md section 7's);
+float32) in its chunked form with ONE decay a head, `ops/gdn.py`'s
+`gdn_chunked`: q and k go in unrepeated and g as [B, S, Hv], and for TPU the
+scalar decay is one [C, C] mask on a plain `k k^T`, as `ssd_chunked` has
+Mamba-2's (everywhere else it is the per-channel rule with equal channels,
+which is the same numbers);
 `o <- RMSNorm_head(o) * w * silu(z)` (norm over each head's Dv with one learned
 scale [Dv], stored and started at 1 whatever `norm_zero_centred` says of the
 stream's norms; the gate full-rank); `W_o: Hv Dv -> d`.
@@ -34,7 +35,7 @@ from ray_tpu.models.mixers.base import (
     Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, l2_normed, log_of_uniform, log_uniform, normal, ones,
     out_scale, proj_scale, rms_norm, stream_norm,
 )
-from ray_tpu.ops.kda import kda_chunked
+from ray_tpu.ops.gdn import gdn_chunked
 from ray_tpu.ops.ssm import causal_conv1d_silu
 from ray_tpu.util import tracing
 
@@ -82,7 +83,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     projection, beta's and the decay's logits, `wo`, the residual add),
     `gdn/conv` (the convolutions + SiLU in one call, on TPU Mamba-2's kernels;
     the L2 norms, the decay's activation, the gated per-head RMSNorm),
-    `gdn/scan` (the chunked recurrence, `ops/kda.py`).
+    `gdn/scan` (the chunked recurrence, `ops/gdn.py`).
 
     With the three `saved` residuals kept the backward runs none of the
     d-wide projections again (the convolution, the recurrence and the gated
@@ -92,7 +93,6 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     f32 = jnp.float32
     constrain, sharded = constrainer(rules, mesh), batch_sharded(rules, mesh)
     (qk, v_width), heads = _widths(c), c.gdn_value_heads
-    group = heads // c.gdn_key_heads
     with tracing.scope("layer/attn_proj"):
         with tracing.scope("gdn/proj"):
             h = stream_norm(c, x, layer_params, "ln1")
@@ -103,14 +103,11 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
                                       **sharded)
             q, k = (a.reshape(*a.shape[:2], c.gdn_key_heads, c.gdn_key_dim) for a in jnp.split(conv[..., :qk], 2, axis=-1))
             v = conv[..., qk:].reshape(*conv.shape[:2], heads, c.gdn_value_dim)
-            # value head j reads key head j // group
-            q = jnp.repeat(l2_normed(q, c.gdn_key_dim ** -0.5), group, axis=2)
-            k = jnp.repeat(l2_normed(k), group, axis=2)
+            q, k = l2_normed(q, c.gdn_key_dim ** -0.5), l2_normed(k)  # value head j reads key head j // (Hv / Hk)
             beta = jax.nn.sigmoid(ba[..., :heads].astype(f32))
             g = jax.nn.softplus(ba[..., heads:].astype(f32) + p["dt_bias"].astype(f32)) * -jnp.exp(p["A_log"].astype(f32))
-            g = jnp.broadcast_to(g[..., None], k.shape)  # one decay a head, over the key's channels
     with tracing.scope("layer/attn_core"):
-        o = kda_chunked(q, k, v, g, beta, scope="gdn/scan", **sharded)
+        o = gdn_chunked(q, k, v, g, beta, **sharded)
     with tracing.scope("layer/attn_proj"):
         with tracing.scope("gdn/conv"):
             z = qkvz[..., qk + v_width:].astype(f32).reshape(o.shape)

@@ -336,7 +336,6 @@ def kda_chunked(
     chunk: Optional[int] = None,
     mesh=None,
     batch_axes=None,
-    scope: str = "kda/scan",
 ) -> jax.Array:
     """The gated delta rule of the module docstring, chunked.
 
@@ -348,15 +347,14 @@ def kda_chunked(
     mesh / batch_axes say how the arguments are sharded.  GSPMD partitions the
     plain form by itself; a Mosaic kernel it cannot, so with a mesh the kernel
     runs under shard_map over the batch axes, each device on its own rows with
-    the whole sequence and every head.  `scope` names the region in a trace
-    (a layer whose decay is one number a head calls it `gdn/scan`)."""
+    the whole sequence and every head."""
     s = k.shape[1]
     chunk = min(chunk or CHUNK, s)
     if s % chunk or chunk & (chunk - 1):
         raise ValueError(f"kda_chunked: sequence length {s} needs a power-of-two chunk that divides it, got {chunk}")
 
     def run(q, k, v, g, beta):  # the scope INSIDE what shard_map wraps: its body starts a name stack of its own
-        with tracing.scope(scope):
+        with tracing.scope("kda/scan"):
             return _kda(q, k, v, g, beta, chunk)
 
     if mesh is None or not _kernel_takes(k, v, chunk):

@@ -253,13 +253,21 @@ def test_supported_is_what_the_kernel_takes():
 
 
 def kernel_jaxprs():
+    """The three KDA kernels, and the three of the scalar-decay pair (`ops/pallas/gdn.py`, PR 58), which
+    hold the same contract: one value head, its decay one number a position."""
+    from ray_tpu.ops.pallas import gdn
+
     q, k, v, g, beta = inputs(13, 128, 1.0, 1, h=1)
     blocks = [kda._segments(x, 64, 2) for x in (q, k, v, g)]
     pairs = jnp.zeros((1, 1, 1, 1, 128, 128))
+    scalar = (q, k, v, g[..., 0], beta)
     return {
         "forward": jax.make_jaxpr(kernels.kda_fwd)(*blocks, beta),
         "forward-with-pair-states": jax.make_jaxpr(functools.partial(kernels.kda_fwd, pair_states=True))(*blocks, beta),
         "backward": jax.make_jaxpr(kernels.kda_bwd)(*blocks, beta, pairs, blocks[2]),
+        "gdn-forward": jax.make_jaxpr(functools.partial(gdn.gdn_fwd, per_segment=2))(*scalar),
+        "gdn-forward-with-pair-states": jax.make_jaxpr(functools.partial(gdn.gdn_fwd, per_segment=2, pair_states=True))(*scalar),
+        "gdn-backward": jax.make_jaxpr(functools.partial(gdn.gdn_bwd, per_segment=2))(*scalar, pairs[0], v),
     }
 
 
@@ -271,11 +279,16 @@ def equations(jaxpr):
             yield from equations(sub)
 
 
-KERNELS = pytest.mark.parametrize("which,least", [("forward", 80), ("forward-with-pair-states", 80), ("backward", 200)])
+# (the kernel, its dots at least, its exponentials at least: KDA's six levels, from the start, to the end, the chunk's
+# whole; the scalar decay's one [C, C] mask, from the start, to the end, each chunk's whole)
+KERNELS = pytest.mark.parametrize(
+    "which,least,decays",
+    [("forward", 80, 9), ("forward-with-pair-states", 80, 9), ("backward", 200, 9),
+     ("gdn-forward", 50, 5), ("gdn-forward-with-pair-states", 50, 5), ("gdn-backward", 90, 5)])
 
 
 @KERNELS
-def test_no_kernel_dot_takes_float32_operands(which, least):
+def test_no_kernel_dot_takes_float32_operands(which, least, decays):
     """The trap: a float32 dot without a precision is ONE bf16 pass in Mosaic
     and exact in interpret mode, so no test on the CPU would see it.  Every
     dot of the kernels' jaxprs has bf16 operands and a float32 result."""
@@ -286,11 +299,11 @@ def test_no_kernel_dot_takes_float32_operands(which, least):
 
 
 @KERNELS
-def test_every_exponent_in_a_kernel_is_clamped_at_zero(which, least):
+def test_every_exponent_in_a_kernel_is_clamped_at_zero(which, least, decays):
     """A decay is `exp(min(.., 0))`: the rounding of a running sum may not turn
     one over 1, in the backward's recomputation as in the forward."""
     found = [(eqn, jaxpr) for eqn, jaxpr in equations(kernel_jaxprs()[which].jaxpr) if eqn.primitive.name == "exp"]
-    assert len(found) >= 9  # six levels, from the start, to the end, the chunk's whole
+    assert len(found) >= decays
     for eqn, jaxpr in found:
         made_by = {id(out): e for e in jaxpr.eqns for out in e.outvars}[id(eqn.invars[0])]
         assert made_by.primitive.name == "min", made_by

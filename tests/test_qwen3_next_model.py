@@ -252,10 +252,11 @@ def test_gradients_agree_with_the_reference_leaf_by_leaf(loss_and_grads):
 
 
 @pytest.mark.parametrize("decay", [1.0, 30.0], ids=["decay-mid", "decay-near-0"])
-def test_gdn_through_kda_chunked_is_the_recurrence_forward_and_gradient(tiny, decay):
+def test_gdn_through_gdn_chunked_is_the_recurrence_forward_and_gradient(tiny, decay):
     """One delta layer, 2 value heads a key head: the program's half of a layer
-    (`kda_chunked` with the head's decay broadcast over the key's channels,
-    chunks of 16 in 64 positions) against the reference's token-by-token scan,
+    (`gdn_chunked`: off TPU and at these heads of 16 the per-channel plain form
+    with the head's decay broadcast over the key's channels, one chunk of 64
+    positions) against the reference's token-by-token scan,
     the output and the gradient of every leaf and of the stream.  `decay`
     scales A: at 30 a head keeps e^-20 a token at its fastest."""
     cfg = tiny["cfg"]

@@ -364,18 +364,18 @@ def test_the_glm_cells_flash_kernels_at_heads_of_256_and_grouped_matmuls_compile
 
 
 def test_the_qwen3_next_cells_scan_kernels_and_grouped_matmuls_compile_at_its_shapes(topo):
-    """PR 57: no new kernel, shapes no cell had.  A delta layer's recurrence
-    through `kda_chunked` as the layer calls it (32 value heads of 128 / 128 at
-    one 8,192-token sequence, the head's decay broadcast over the key's
-    channels, q and k repeated over two value heads): `kda_fwd` forward and for
-    the backward, `kda_bwd`, under the layer's own name.  And the grouped
+    """A delta layer's recurrence through `gdn_chunked` as the layer calls it
+    (PR 58: 16 key heads and 32 value heads of 128 / 128 at one 8,192-token
+    sequence, q and k unrepeated, one decay a value head): `gdn_fwd` forward
+    and for the backward, `gdn_bwd`, under `gdn/scan`, and no per-channel
+    kernel (PR 57 ran the layer through `kda_fwd` / `kda_bwd`).  And the grouped
     matmuls at 2048 x 512 on the lowest rung's 10,240 rows of 32 held experts
     (320 rows an expert at most there: under one 512-row tile), both ways
     round, none refused."""
     from jax.sharding import SingleDeviceSharding
 
+    from ray_tpu.ops.gdn import gdn_chunked
     from ray_tpu.ops.grouped_matmul import REFUSED_SCOPE, grouped_matmul
-    from ray_tpu.ops.kda import kda_chunked
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
@@ -383,19 +383,20 @@ def test_the_qwen3_next_cells_scan_kernels_and_grouped_matmuls_compile_at_its_sh
     decay = shaped((1, 8192, 32), jnp.float32)
 
     def scan(q, k, v, g, beta, d_o):
-        def layer(q, k, v, g, beta):
-            q, k = jnp.repeat(q, 2, axis=2), jnp.repeat(k, 2, axis=2)
-            return kda_chunked(q, k, v, jnp.broadcast_to(g[..., None], k.shape), beta, scope="gdn/scan")
-        return jax.grad(lambda *a: jnp.sum(layer(*a) * d_o), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+        return jax.grad(lambda *a: jnp.sum(gdn_chunked(*a) * d_o), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
 
     with _no_compile_cache():
         lowered = jax.jit(scan).lower(keys, keys, values, decay, decay, shaped((1, 8192, 32, 128), jnp.float32))
-        text = lowered.compile().as_text()
+        compiled = lowered.compile()
+        text = compiled.as_text()
         rows, sizes = shaped((10240, 2048)), shaped((32,), jnp.int32)
         up = jax.jit(grouped_matmul).lower(rows, shaped((32, 2048, 512)), sizes).compile().as_text()
         down = jax.jit(grouped_matmul).lower(shaped((10240, 512)), shaped((32, 512, 2048)), sizes).compile().as_text()
-    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2 and "kda_fwd" in text and "kda_bwd" in text
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2 and "gdn_fwd" in text and "gdn_bwd" in text
+    assert "kda_fwd" not in text and "kda_bwd" not in text
     assert "gdn/scan" in lowered.as_text(debug_info=True) and "kda/scan" not in lowered.as_text(debug_info=True)
+    got = [(s.shape, s.dtype) for s in jax.tree.leaves(compiled.out_info)]
+    assert got == [(x.shape, x.dtype) for x in (keys, keys, values, decay, decay)]  # dq and dk summed over a group
     for compiled in (up, down):
         assert compiled.count('custom_call_target="tpu_custom_call"') == 1 and "moe_gmm" in compiled
         assert REFUSED_SCOPE not in compiled

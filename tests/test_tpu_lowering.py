@@ -185,7 +185,7 @@ def test_kimi_step_lowers_for_tpu_with_the_kda_kernels_inside_kda_scan(n_devices
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=KIMI, debug_info=True)
     kernels = _mosaic_kernels(text)
     assert kernels["kda_fwd"] == 2 and kernels["kda_bwd"] == 1 and kernels["flash_fwd"] == 1, kernels
-    assert not [name for name in kernels if name.startswith("kda_") and name not in ("kda_fwd", "kda_bwd")]
+    assert not [name for name in kernels if name.startswith(("kda_", "gdn_")) and name not in ("kda_fwd", "kda_bwd")]
     for kernel, path in _kernel_paths(text, "kda_fwd|kda_bwd"):
         assert "kda/scan" in path and kernel in path, path
         if kernel == "kda_bwd":  # in the layer's backward, not its recompute: the reader's `bwd`
@@ -194,6 +194,39 @@ def test_kimi_step_lowers_for_tpu_with_the_kda_kernels_inside_kda_scan(n_devices
 
 def test_kimi_step_lowered_for_the_cpu_holds_no_kernel():
     assert "tpu_custom_call" not in _lowered_text(1, MeshSpec(data=1), "dp", cfg=KIMI)
+
+
+# Qwen3-Next's mixers in small, at the head sizes the scalar-decay kernels take: two delta layers (one run, one scan
+# body) of one key head and two value heads of 128 / 128, and one gated-attention layer.
+QWEN3_NEXT = TransformerConfig.tiny(
+    n_layers=3, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, max_seq_len=128, remat=True, remat_policy="qkv_attn",
+    layer_types=("gdn", "gdn", "attention"), gdn_key_heads=1, gdn_value_heads=2, gdn_key_dim=128, gdn_value_dim=128,
+)
+
+
+@pytest.mark.parametrize(
+    "n_devices,spec,strategy",
+    [(1, MeshSpec(data=1), "dp"), (4, MeshSpec(data=1, fsdp=4), "fsdp"), (4, MeshSpec(data=2, tensor=2), "tp")],
+    ids=["dp1", "fsdp4", "tp4"],
+)
+def test_a_delta_layer_lowers_for_tpu_with_the_scalar_decay_kernels_inside_gdn_scan(n_devices, spec, strategy):
+    """PR 58: a layer with ONE decay a head runs `gdn_fwd` (forward, and the
+    recompute) and `gdn_bwd` (once, in the layer's backward), under shard_map
+    on a mesh like the others, each under `gdn/scan` and its own name; no
+    per-channel kernel, which is a `kda` layer's (the Kimi step above holds no
+    `gdn_*`: the layer kind picks the op)."""
+    text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=QWEN3_NEXT, debug_info=True)
+    kernels = _mosaic_kernels(text)
+    assert kernels["gdn_fwd"] == 2 and kernels["gdn_bwd"] == 1 and kernels["flash_fwd"] == 1, kernels
+    assert not [name for name in kernels if name.startswith(("kda_", "gdn_")) and name not in ("gdn_fwd", "gdn_bwd")]
+    for kernel, path in _kernel_paths(text, "gdn_fwd|gdn_bwd"):
+        assert "gdn/scan" in path and kernel in path, path
+        if kernel == "gdn_bwd":  # in the layer's backward, not its recompute: the reader's `bwd`
+            assert "rematted_computation" not in path, path
+
+
+def test_a_delta_step_lowered_for_the_cpu_holds_no_kernel():
+    assert "tpu_custom_call" not in _lowered_text(1, MeshSpec(data=1), "dp", cfg=QWEN3_NEXT)
 
 
 # SambaY's Mamba-1 layers in small, at widths the scan's kernel takes (512 channels, 16 states; one
