@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import kernel_pair
+
 NEG_INF = -1e30
 
 # `checkpoint_name`s of what attention's backward needs besides q, k and v.
@@ -166,8 +168,8 @@ def dot_product_attention(
     shapes are tile-aligned (sequence lengths a multiple of 128, both head
     sizes, q/k's and v's, a multiple of 64); every other lowering, and every other shape, gets the
     XLA forms (blockwise scan beyond block_size, else reference).
-    The choice rides `jax.lax.platform_dependent`, so it follows the
-    platform a step is compiled for, not the process's default backend.
+    The choice is `kernel_pair.dispatch`'s, so it follows the platform a step
+    is compiled for, not the process's default backend.
 
     window: see the module docstring; every form takes it.
 
@@ -202,10 +204,8 @@ def dot_product_attention(
 
     if impl is None:
         xla = blockwise if q.shape[1] > block_size else reference
-        if (q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0
-                and q.shape[-1] % 64 == 0 and v.shape[-1] % 64 == 0):
-            return jax.lax.platform_dependent(q, k, v, tpu=pallas, default=xla)
-        return xla(q, k, v)
+        takes = q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0 and q.shape[-1] % 64 == 0 and v.shape[-1] % 64 == 0
+        return kernel_pair.dispatch(takes, pallas, xla, q, k, v)
     forms = {"reference": reference, "blockwise": blockwise, "pallas": pallas}
     if impl not in forms:
         raise ValueError(f"unknown attention impl {impl!r}")

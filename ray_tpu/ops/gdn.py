@@ -1,7 +1,9 @@
 """The gated delta rule with ONE decay a head (Gated DeltaNet, arXiv:2412.06464,
 as Qwen3-Next runs it), chunked: `ops/kda.py`'s recurrence with
 `alpha_t = exp(g_t)` a NUMBER per value head, and value head j reading the q
-and k of key head `j // (Hv / Hk)`.  `gdn_chunked` is one `jax.custom_vjp`.
+and k of key head `j // (Hv / Hk)`.  `gdn_chunked` is one `jax.custom_vjp`,
+declared as a `kernel_pair.KernelPair` (`PAIR`) and run by `ops/kernel_pair.py`'s
+scaffold.
 
 A decay per head IS the per-channel rule with equal channels, so everywhere
 but on TPU, and at the shapes the kernels refuse, both directions are
@@ -23,64 +25,48 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops import kda
-from ray_tpu.parallel.sharding import _fit_spec
-from ray_tpu.util import tracing
-
-
-def _kernels():
-    """`ops/pallas/gdn.py`, imported at first use like the other ops' kernels."""
-    from ray_tpu.ops.pallas import gdn
-
-    return gdn
-
-
-def _kernel_takes(k, v, chunk: int) -> bool:
-    return _kernels().supported(k.shape[-1], v.shape[-1], chunk, kda._per_segment(k.shape[1], chunk), v.shape[2], k.shape[2])
+from ray_tpu.ops import kda, kernel_pair
 
 
 def _per_channel(q, k, v, g, beta, chunk: int):
     """The arguments as the per-channel rule's plain form takes them: q and k
     repeated over the value heads of their key head, g over the key's channels,
-    each as `kda._segments` gives it (beta [segments, b, c, H, l, 1])."""
+    each as `kda.segments` gives it (beta [segments, b, c, H, l, 1])."""
     group = v.shape[2] // k.shape[2]
     q, k = jnp.repeat(q, group, axis=2), jnp.repeat(k, group, axis=2)
     g = jnp.broadcast_to(g[..., None], k.shape)
-    segments = functools.partial(kda._segments, chunk=chunk, per_segment=kda._per_segment(k.shape[1], chunk))
+    segments = functools.partial(kda.segments, chunk=chunk, per_segment=kda.per_segment(k.shape[1], chunk))
     return tuple(map(segments, (q, k, v, g, beta[..., None])))
 
 
 def _plain_forward(q, k, v, g, beta, chunk: int):
-    """(o [b, S, Hv, V] float32, the state that enters each segment): `kda._plain_forward` on `_per_channel`'s arguments."""
-    o, entering = kda._plain_forward(*_per_channel(q, k, v, g, beta, chunk))
-    return kda._positions(o), entering
+    """(o [b, S, Hv, V] float32, the state that enters each segment): `kda.plain_forward` on `_per_channel`'s arguments."""
+    o, entering = kda.plain_forward(*_per_channel(q, k, v, g, beta, chunk))
+    return kda.positions(o), entering
 
 
 def _plain_backward(q, k, v, g, beta, entering, d_o, chunk: int):
-    """`kda._plain_backward` on `_per_channel`'s arguments, what it gives for
+    """`kda.plain_backward` on `_per_channel`'s arguments, what it gives for
     the repeated q and k summed back over a group's value heads and for the
     broadcast g over the key's channels: each cotangent in its argument's shape
     and dtype."""
     hk, group = k.shape[2], v.shape[2] // k.shape[2]
-    d_o = kda._segments(d_o, chunk, kda._per_segment(k.shape[1], chunk))
-    d = kda._plain_backward(*_per_channel(q, k, v, g, beta, chunk), entering, d_o)
-    dq, dk, dv, dg, dbeta = map(kda._positions, d)  # each in its argument's dtype: `_segment` casts inside
+    d_o = kda.segments(d_o, chunk, kda.per_segment(k.shape[1], chunk))
+    d = kda.plain_backward(*_per_channel(q, k, v, g, beta, chunk), entering, d_o)
+    dq, dk, dv, dg, dbeta = map(kda.positions, d)  # each in its argument's dtype: `_segment` casts inside
     of_group = lambda x: x.reshape(*x.shape[:2], hk, group, x.shape[-1]).sum(axis=3)
     return of_group(dq), of_group(dk), dv, dg.sum(axis=-1), dbeta[..., 0]
 
 
-def _forward(q, k, v, g, beta, chunk: int, pair_states: bool = False):
+def _forward(call, q, k, v, g, beta):
     """(o [b, S, Hv, V] float32, the state that enters each segment
-    [segments, b, Hv, K, V], and with `pair_states` at shapes the kernels take
-    the state that enters each PAIR of chunks [b, S / 128, Hv, K, V], which the
-    backward kernel starts from; else None).  As in `ops/kda.py` the form
-    follows the platform a step is LOWERED for, and the plain form gives zeros
-    where it has no use for the pairs' states: both branches of a dispatch
-    return the same shapes."""
-    takes = _kernel_takes(k, v, chunk)
-    pair_states = pair_states and takes
+    [segments, b, Hv, K, V], and where the forward runs for a backward at
+    shapes the kernels take the state that enters each PAIR of chunks
+    [b, S / 128, Hv, K, V], which the backward kernel starts from; else None).
+    As in `ops/kda.py` the plain form gives zeros where it has no use for the
+    pairs' states."""
+    chunk, pair_states = call.chunk, call.residuals and call.takes
 
     def plain(q, k, v, g, beta):
         o, entering = _plain_forward(q, k, v, g, beta, chunk)
@@ -88,43 +74,32 @@ def _forward(q, k, v, g, beta, chunk: int, pair_states: bool = False):
             return o, entering
         return o, entering, jnp.zeros((v.shape[0], v.shape[1] // (2 * chunk), *entering.shape[2:]), jnp.float32)
 
-    if not takes:
-        o, entering, *pairs = plain(q, k, v, g, beta)
-    else:
-        kernel = functools.partial(_kernels().gdn_fwd, per_segment=kda._per_segment(k.shape[1], chunk), pair_states=pair_states)
-        o, entering, *pairs = jax.lax.platform_dependent(q, k, v, g, beta, tpu=kernel, default=plain)
+    kernel = functools.partial(call.kernels.gdn_fwd, per_segment=kda.per_segment(k.shape[1], chunk), pair_states=pair_states)
+    o, entering, *pairs = call(kernel, plain, q, k, v, g, beta)
     return o, entering, (pairs[0] if pairs else None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _gdn(q, k, v, g, beta, chunk: int):
-    return _forward(q, k, v, g, beta, chunk)[0]
-
-
-def _gdn_fwd(q, k, v, g, beta, chunk: int):
-    o, entering, pairs = _forward(q, k, v, g, beta, chunk, pair_states=True)
-    return o, (q, k, v, g, beta, entering, pairs)
-
-
-def _gdn_bwd(chunk: int, res, do):
+def _backward(call, q, k, v, g, beta, entering, pairs, do):
     """(dq, dk, dv, dg, dbeta), each in its argument's shape and dtype.  For
     TPU at the shapes the kernels take, `gdn_bwd` from the state that entered
     each pair of chunks; everywhere else `_plain_backward` from the state that
     entered each segment."""
-    q, k, v, g, beta, entering, pairs = res
 
     def plain(q, k, v, g, beta, entering, pairs, d_o):
-        return _plain_backward(q, k, v, g, beta, entering, d_o, chunk)
+        return _plain_backward(q, k, v, g, beta, entering, d_o, call.chunk)
 
     def kernel(q, k, v, g, beta, entering, pairs, d_o):
-        return _kernels().gdn_bwd(q, k, v, g, beta, pairs, d_o, per_segment=kda._per_segment(k.shape[1], chunk))
+        return call.kernels.gdn_bwd(q, k, v, g, beta, pairs, d_o, per_segment=kda.per_segment(k.shape[1], call.chunk))
 
-    if pairs is None:
-        return plain(q, k, v, g, beta, entering, pairs, do)
-    return jax.lax.platform_dependent(q, k, v, g, beta, entering, pairs, do, tpu=kernel, default=plain)
+    return call(kernel, plain, q, k, v, g, beta, entering, pairs, do)
 
 
-_gdn.defvjp(_gdn_fwd, _gdn_bwd)
+PAIR = kernel_pair.KernelPair(
+    name="gdn_chunked", scope="gdn/scan", kernels="gdn", power_of_two_chunk=True,
+    takes=lambda kernels, q, k, v, g, beta, chunk: kernels.supported(
+        k.shape[-1], v.shape[-1], chunk, kda.per_segment(k.shape[1], chunk), v.shape[2], k.shape[2]),
+    forward=_forward, backward=_backward,
+)
 
 
 def gdn_chunked(
@@ -148,19 +123,6 @@ def gdn_chunked(
     mesh / batch_axes as `kda_chunked` has them: with a mesh the kernels run
     under shard_map over the batch axes, each device on its own rows with the
     whole sequence and every head."""
-    s = k.shape[1]
-    chunk = min(chunk or kda.CHUNK, s)
-    if s % chunk or chunk & (chunk - 1):
-        raise ValueError(f"gdn_chunked: sequence length {s} needs a power-of-two chunk that divides it, got {chunk}")
     if v.shape[2] % k.shape[2]:
         raise ValueError(f"gdn_chunked: {v.shape[2]} value heads are not whole groups of {k.shape[2]} key heads")
-
-    def run(q, k, v, g, beta):  # the scope INSIDE what shard_map wraps: its body starts a name stack of its own
-        with tracing.scope("gdn/scan"):
-            return _gdn(q, k, v, g, beta, chunk)
-
-    if mesh is None or not _kernel_takes(k, v, chunk):
-        return run(q, k, v, g, beta)
-    rows = _fit_spec(k.shape, P(batch_axes, None, None, None), mesh)
-    return jax.shard_map(run, mesh=mesh, in_specs=(rows, rows, rows, P(*rows[:3]), P(*rows[:3])), out_specs=rows,
-                         check_vma=False)(q, k, v, g, beta)
+    return kernel_pair.run(PAIR, q, k, v, g, beta, chunk=chunk or kda.CHUNK, mesh=mesh, batch_axes=batch_axes)

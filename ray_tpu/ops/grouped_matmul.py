@@ -6,7 +6,7 @@ Rows behind the last group (where `group_sizes` sums to less than m) are
 not defined.  Differentiable in both operands.
 
 Like attention, the form follows the platform a step is LOWERED for
-(`jax.lax.platform_dependent`), not the process's backend: the Pallas kernels
+(`kernel_pair.dispatch`), not the process's backend: the Pallas kernels
 (`ops/pallas/grouped_matmul.py`) for TPU when the shapes are ones they take
 (rows in multiples of 128; k and n each a multiple of 128, tiled, or a
 multiple of 8 from 128 to 2,048, as one whole block: Nemotron-3-Nano's 1856), the
@@ -29,6 +29,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import kernel_pair
 from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
@@ -103,8 +104,7 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> ja
 
     shape = (lhs.shape[0], rhs.shape[1], rhs.shape[2])
     if kernels.supported(*shape):
-        return jax.lax.platform_dependent(
-            lhs, rhs, group_sizes, tpu=kernels.grouped_matmul, default=grouped_matmul_xla)
+        return kernel_pair.dispatch(True, kernels.grouped_matmul, grouped_matmul_xla, lhs, rhs, group_sizes)
     if shape not in refused_shapes:
         logger.warning("grouped_matmul: the TPU kernels refuse m, k, n = %s; the XLA form runs on every platform", shape)
     refused_shapes[shape] = refused_shapes.get(shape, 0) + 1

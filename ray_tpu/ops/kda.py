@@ -1,14 +1,15 @@
 """Kimi Delta Attention (KDA, "Kimi Linear", arXiv:2510.26692): a gated delta
 rule whose decay is PER CHANNEL of the key, in its chunked form; no
 counterpart in the reference (SURVEY.md §5.7).  `kda_chunked` is one
-`jax.custom_vjp`.  Both directions are plain `jax.numpy` everywhere but on
-TPU: the forward `_plain_forward`, a scan over `_segment`; the backward
-`_plain_backward`, JAX's own differentiation of `_segment`, a segment at a
-time (no gradient in THIS file is derived by hand).  For TPU, at the shapes
-they take, two Pallas kernels keep a chunk's matrices in VMEM
+`jax.custom_vjp`, declared as a `kernel_pair.KernelPair` (`PAIR`) and run by
+`ops/kernel_pair.py`'s scaffold.  Both directions are plain `jax.numpy`
+everywhere but on TPU: the forward `plain_forward`, a scan over `_segment`;
+the backward `plain_backward`, JAX's own differentiation of `_segment`, a
+segment at a time (no gradient in THIS file is derived by hand).  For TPU, at
+the shapes they take, two Pallas kernels keep a chunk's matrices in VMEM
 (`ops/pallas/kda.py`: `kda_fwd`, PR 39, the same arithmetic at the same
 precision; `kda_bwd`, PR 41, the cotangents of that arithmetic derived by
-hand, at the same precision, and held to `_plain_backward` by the tests; see
+hand, at the same precision, and held to `plain_backward` by the tests; see
 Precision below).  `kda/scan`, the scope around all of this, is what the
 benchmark reads it by (PERF.md section 3).
 
@@ -48,7 +49,7 @@ the segments from the last to the first: `jax.vjp` of `_segment` at that
 state, its forward once more and then its backward, the state's cotangent
 carried.  The backward kernel walks the same way 128 positions at a time and
 recomputes a pair of chunks from the state that entered it, so where the
-forward runs for a backward (`_kda_fwd`) the kernel also writes the state that
+forward runs for a backward (`call.residuals`) the kernel also writes the state that
 enters each PAIR of chunks (128 x [b, 32, 128, 128] float32, 268 MB a layer,
 alive from the layer's recompute to the end of its backward); the plain form
 has no use for them and gives zeros in their place.
@@ -99,10 +100,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.sharding import _fit_spec
-from ray_tpu.util import tracing
+from ray_tpu.ops import kernel_pair
 
 # The precision of every matmul here (module docstring).
 EXACT = jax.lax.Precision.HIGH
@@ -165,7 +164,7 @@ def _segment(state, inp):
     """Some chunks at once, in plain `jax.numpy`: (the state that enters, their
     q, k, v, g, beta as [b, c, H, l, d]) -> (the state that leaves,
     o [b, c, H, l, V]).  The forward off TPU, and the function whose `jax.vjp`
-    is the backward everywhere (`_kda_bwd`)."""
+    is the backward everywhere (`plain_backward`)."""
     f32 = jnp.float32
     qc, kc, vc, gc, bc = (x.astype(f32) for x in inp)  # here, a segment at a time: v stays bf16 until then
     chunk, dv = qc.shape[-2], vc.shape[-1]
@@ -202,26 +201,27 @@ def _segment(state, inp):
     return state, o
 
 
-def _segments(x, chunk: int, per_segment: int):
+def segments(x, chunk: int, per_segment: int):
     """[b, S, H, d] -> [segments, b, c, H, l, d]: what the scans over `_segment` and the kernel walk."""
     b, s, h, d = x.shape
     x = x.reshape(b, s // (chunk * per_segment), per_segment, chunk, h, d)
     return x.transpose(1, 0, 2, 4, 3, 5)
 
 
-def _positions(o):
+def positions(o):
     """Back: [segments, b, c, H, l, d] -> [b, S, H, d]."""
     n, b, c, h, l, d = o.shape
     return o.transpose(1, 0, 2, 4, 3, 5).reshape(b, n * c * l, h, d)
 
 
-def _per_segment(s: int, chunk: int) -> int:
+def per_segment(s: int, chunk: int) -> int:
     return math.gcd(s // chunk, SEGMENT)
 
 
-def _plain_forward(q, k, v, g, beta):
-    """One `lax.scan` over `_segment`: q, k, v, g, beta as `_segments` gives them ->
-    (o [segments, b, c, H, l, V], the state that enters each segment [segments, b, H, K, V])."""
+def plain_forward(q, k, v, g, beta):
+    """One `lax.scan` over `_segment`: q, k, v, g, beta as `segments` gives them ->
+    (o [segments, b, c, H, l, V], the state that enters each segment [segments, b, H, K, V]).
+    The plain form of the per-channel rule, which `ops/gdn.py`'s scalar-decay rule is defined by."""
     _, b, _, h, _, dk = k.shape
 
     def step(state, inp):
@@ -231,62 +231,12 @@ def _plain_forward(q, k, v, g, beta):
     return jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32), (q, k, v, g, beta))[1]
 
 
-def _kernels():
-    """`ops/pallas/kda.py`, imported at first use like the other ops' kernels."""
-    from ray_tpu.ops.pallas import kda
-
-    return kda
-
-
-def _kernel_takes(k, v, chunk: int) -> bool:
-    return _kernels().supported(k.shape[-1], v.shape[-1], chunk, _per_segment(k.shape[1], chunk))
-
-
-def _forward(q, k, v, g, beta, chunk: int, pair_states: bool = False):
-    """(o [b, S, H, V] float32, the state that enters each segment, and with
-    `pair_states` at shapes the kernel takes the state that enters each PAIR
-    of chunks [segments, b, c / 2, H, K, V], which the backward kernel starts
-    from; else None).  Like attention and the convolution, the form follows the
-    platform a step is LOWERED for, not the process's backend: the kernel for
-    TPU at shapes it takes, the plain form everywhere else (its backward has no
-    use for the pairs' states: zeros of their shape, because both branches of a
-    dispatch return the same shapes)."""
-    segments = functools.partial(_segments, chunk=chunk, per_segment=_per_segment(k.shape[1], chunk))
-    takes = _kernel_takes(k, v, chunk)
-    pair_states = pair_states and takes
-
-    def plain(q, k, v, g, beta):
-        o, entering = _plain_forward(q, k, v, g, segments(beta[..., None]))
-        if not pair_states:
-            return o, entering
-        n, b, c = k.shape[:3]
-        return o, entering, jnp.zeros((n, b, c // 2, *entering.shape[2:]), jnp.float32)
-
-    inputs = (*map(segments, (q, k, v, g)), beta)
-    if takes:
-        kernel = functools.partial(_kernels().kda_fwd, pair_states=pair_states)
-        o, entering, *pairs = jax.lax.platform_dependent(*inputs, tpu=kernel, default=plain)
-    else:
-        o, entering, *pairs = plain(*inputs)
-    return _positions(o), entering, (pairs[0] if pairs else None)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda(q, k, v, g, beta, chunk: int):
-    return _forward(q, k, v, g, beta, chunk)[0]
-
-
-def _kda_fwd(q, k, v, g, beta, chunk: int):
-    o, entering, pairs = _forward(q, k, v, g, beta, chunk, pair_states=True)
-    return o, (q, k, v, g, beta, entering, pairs)
-
-
-def _plain_backward(q, k, v, g, beta, entering, d_o):
+def plain_backward(q, k, v, g, beta, entering, d_o):
     """JAX's own differentiation of `_segment`, a segment at a time from the
     last to the first: the segment's forward once more at the state that
     entered it, then its backward, the state's cotangent carried along.  The
     [chunk, chunk] matrices, their decayed operands and every cotangent of them
-    exist for one segment at a time.  Arguments and cotangents as `_segments`
+    exist for one segment at a time.  Arguments and cotangents as `segments`
     gives them (beta [segments, b, c, H, l, 1])."""
 
     def step(d_state, xs):
@@ -301,30 +251,51 @@ def _plain_backward(q, k, v, g, beta, entering, d_o):
     return jax.lax.scan(step, jnp.zeros_like(entering[0]), (entering, (q, k, v, g, beta), d_o), reverse=True)[1]
 
 
-def _kda_bwd(chunk: int, res, do):
+def _forward(call, q, k, v, g, beta):
+    """(o [b, S, H, V] float32, the state that enters each segment, and where
+    the forward runs for a backward at shapes the kernel takes the state that
+    enters each PAIR of chunks [segments, b, c / 2, H, K, V], which the
+    backward kernel starts from; else None).  The plain form's backward has no
+    use for the pairs' states and gives zeros of their shape."""
+    cut = functools.partial(segments, chunk=call.chunk, per_segment=per_segment(k.shape[1], call.chunk))
+    pair_states = call.residuals and call.takes
+
+    def plain(q, k, v, g, beta):
+        o, entering = plain_forward(q, k, v, g, cut(beta[..., None]))
+        if not pair_states:
+            return o, entering
+        n, b, c = k.shape[:3]
+        return o, entering, jnp.zeros((n, b, c // 2, *entering.shape[2:]), jnp.float32)
+
+    kernel = functools.partial(call.kernels.kda_fwd, pair_states=pair_states)
+    o, entering, *pairs = call(kernel, plain, *map(cut, (q, k, v, g)), beta)
+    return positions(o), entering, (pairs[0] if pairs else None)
+
+
+def _backward(call, q, k, v, g, beta, entering, pairs, do):
     """(dq, dk, dv, dg, dbeta), each in its argument's dtype.  For TPU at the
     shapes the kernel takes, `kda_bwd` from the state that entered each pair of
-    chunks; everywhere else `_plain_backward` from the state that entered each
-    segment.  Both read the arrays the forward read, as `_segments` gives them."""
-    q, k, v, g, beta, entering, pairs = res
-    segments = functools.partial(_segments, chunk=chunk, per_segment=_per_segment(k.shape[1], chunk))
+    chunks; everywhere else `plain_backward` from the state that entered each
+    segment.  Both read the arrays the forward read, as `segments` gives them."""
+    cut = functools.partial(segments, chunk=call.chunk, per_segment=per_segment(k.shape[1], call.chunk))
 
     def plain(q, k, v, g, beta, entering, pairs, d_o):
-        *d_inputs, dbeta = _plain_backward(q, k, v, g, segments(beta[..., None]), entering, d_o)
-        return (*d_inputs, _positions(dbeta)[..., 0])  # each in its argument's dtype: `_segment` casts inside
+        *d_inputs, dbeta = plain_backward(q, k, v, g, cut(beta[..., None]), entering, d_o)
+        return (*d_inputs, positions(dbeta)[..., 0])  # each in its argument's dtype: `_segment` casts inside
 
     def kernel(q, k, v, g, beta, entering, pairs, d_o):
-        return _kernels().kda_bwd(q, k, v, g, beta, pairs, d_o)
+        return call.kernels.kda_bwd(q, k, v, g, beta, pairs, d_o)
 
-    inputs = (*map(segments, (q, k, v, g)), beta, entering, pairs, segments(do))
-    if pairs is None:
-        *d_inputs, dbeta = plain(*inputs)
-    else:
-        *d_inputs, dbeta = jax.lax.platform_dependent(*inputs, tpu=kernel, default=plain)
-    return (*map(_positions, d_inputs), dbeta)
+    *d_inputs, dbeta = call(kernel, plain, *map(cut, (q, k, v, g)), beta, entering, pairs, cut(do))
+    return (*map(positions, d_inputs), dbeta)
 
 
-_kda.defvjp(_kda_fwd, _kda_bwd)
+PAIR = kernel_pair.KernelPair(
+    name="kda_chunked", scope="kda/scan", kernels="kda", power_of_two_chunk=True,
+    takes=lambda kernels, q, k, v, g, beta, chunk: kernels.supported(
+        k.shape[-1], v.shape[-1], chunk, per_segment(k.shape[1], chunk)),
+    forward=_forward, backward=_backward,
+)
 
 
 def kda_chunked(
@@ -348,20 +319,7 @@ def kda_chunked(
     plain form by itself; a Mosaic kernel it cannot, so with a mesh the kernel
     runs under shard_map over the batch axes, each device on its own rows with
     the whole sequence and every head."""
-    s = k.shape[1]
-    chunk = min(chunk or CHUNK, s)
-    if s % chunk or chunk & (chunk - 1):
-        raise ValueError(f"kda_chunked: sequence length {s} needs a power-of-two chunk that divides it, got {chunk}")
-
-    def run(q, k, v, g, beta):  # the scope INSIDE what shard_map wraps: its body starts a name stack of its own
-        with tracing.scope("kda/scan"):
-            return _kda(q, k, v, g, beta, chunk)
-
-    if mesh is None or not _kernel_takes(k, v, chunk):
-        return run(q, k, v, g, beta)
-    rows = _fit_spec(k.shape, P(batch_axes, None, None, None), mesh)
-    return jax.shard_map(run, mesh=mesh, in_specs=(rows, rows, rows, rows, P(*rows[:3])), out_specs=rows,
-                         check_vma=False)(q, k, v, g, beta)
+    return kernel_pair.run(PAIR, q, k, v, g, beta, chunk=chunk or CHUNK, mesh=mesh, batch_axes=batch_axes)
 
 
 def kda_recurrent(q, k, v, g, beta):

@@ -1,6 +1,7 @@
 """Mamba-1's selective scan (S6, arXiv:2312.00752) in a chunked form; no
 counterpart in the reference (SURVEY.md §5.7).  `selective_scan` is one
-`jax.custom_vjp`.  Both directions are plain `jax.numpy` everywhere but on TPU
+`jax.custom_vjp`, declared as a `kernel_pair.KernelPair` (`PAIR`) and run by
+`ops/kernel_pair.py`'s scaffold.  Both directions are plain `jax.numpy` everywhere but on TPU
 (`_plain_forward`, a scan over `_chunk_body`; `_plain_backward`, JAX's own
 differentiation of `_chunk_body` a chunk at a time from the state that entered
 it: no gradient in this file is derived by hand); for TPU, at the shapes they
@@ -78,10 +79,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.sharding import _fit_spec
-from ray_tpu.util import tracing
+from ray_tpu.ops import kernel_pair
 
 # Positions a chunk holds: log2 levels of the associative scan inside, S / CHUNK serial steps outside
 # (module docstring: why 32).
@@ -136,43 +135,12 @@ def _plain_forward(x, dt, A_t, B, C, D, chunk: int):
     return _positions(y).astype(x.dtype), entering
 
 
-def _kernel():
-    """`ops/pallas/selective_scan.py`, imported at first use like the other ops' kernels."""
-    from ray_tpu.ops.pallas import selective_scan
-
-    return selective_scan
-
-
-def _kernel_takes(x, B, chunk: int) -> bool:
-    return _kernel().supported(x.shape[2], B.shape[-1], x.shape[1], chunk)
-
-
-def _dispatch(kernel: str, plain, x, B, chunk: int, inputs):
-    """One direction of the scan on `inputs`.  Like attention and the
-    convolution, the form follows the platform a step is LOWERED for, not the
-    process's backend: the kernel of that name for TPU at shapes it takes, the
-    plain form everywhere else."""
-    plain = functools.partial(plain, chunk=chunk)
-    if _kernel_takes(x, B, chunk):
-        kernel = functools.partial(getattr(_kernel(), kernel), chunk=chunk)
-        return jax.lax.platform_dependent(*inputs, tpu=kernel, default=plain)
-    return plain(*inputs)
-
-
-def _forward(x, dt, A, B, C, D, chunk: int):
+def _forward(call, x, dt, A, B, C, D):
     """(y, the state that enters each chunk)."""
     f32 = jnp.float32
-    return _dispatch("s6_scan_fwd", _plain_forward, x, B, chunk, (x, dt.astype(f32), A.astype(f32).T, B, C, D))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _scan(x, dt, A, B, C, D, chunk: int):
-    return _forward(x, dt, A, B, C, D, chunk)[0]
-
-
-def _scan_fwd(x, dt, A, B, C, D, chunk: int):
-    y, entering = _forward(x, dt, A, B, C, D, chunk)
-    return y, (x, dt, A, B, C, D, entering)
+    kernel = functools.partial(call.kernels.s6_scan_fwd, chunk=call.chunk)
+    plain = functools.partial(_plain_forward, chunk=call.chunk)
+    return call(kernel, plain, x, dt.astype(f32), A.astype(f32).T, B, C, D)
 
 
 def _plain_backward(x, dt, A_t, B, C, D32, entering, dy, chunk: int):
@@ -201,19 +169,23 @@ def _plain_backward(x, dt, A_t, B, C, D32, entering, dy, chunk: int):
     return dx, d_dt, d_A, dB, dC, d_D
 
 
-def _scan_bwd(chunk: int, res, dy):
+def _backward(call, x, dt, A, B, C, D, entering, dy):
     """From the entering states either forward wrote: for TPU, at the shapes
     the kernel takes, the recurrence's own adjoint a position at a time
     (`s6_scan_bwd`); everywhere else `_plain_backward`.  Cotangents in their
     arguments' dtypes."""
-    x, dt, A, B, C, D, entering = res
     f32 = jnp.float32
-    dx, d_dt, d_A, dB, dC, d_D = _dispatch("s6_scan_bwd", _plain_backward, x, B, chunk,
-                                           (x, dt, A.astype(f32).T, B, C, D.astype(f32), entering, dy))
+    kernel = functools.partial(call.kernels.s6_scan_bwd, chunk=call.chunk)
+    plain = functools.partial(_plain_backward, chunk=call.chunk)
+    dx, d_dt, d_A, dB, dC, d_D = call(kernel, plain, x, dt, A.astype(f32).T, B, C, D.astype(f32), entering, dy)
     return dx, d_dt.astype(dt.dtype), d_A.T.astype(A.dtype), dB, dC, d_D.astype(D.dtype)
 
 
-_scan.defvjp(_scan_fwd, _scan_bwd)
+PAIR = kernel_pair.KernelPair(
+    name="selective_scan", scope="s6/scan", kernels="selective_scan",
+    takes=lambda kernels, x, dt, A, B, C, D, chunk: kernels.supported(x.shape[2], B.shape[-1], x.shape[1], chunk),
+    forward=_forward, backward=_backward, replicated=(2, 5),  # A and D
+)
 
 
 def selective_scan(
@@ -238,20 +210,7 @@ def selective_scan(
     replicated).  GSPMD partitions the plain form by itself; a Mosaic kernel it
     cannot, so with a mesh the kernel runs under shard_map over the batch axes,
     each device on its own rows with the whole sequence and every channel."""
-    s = x.shape[1]
-    chunk = min(chunk or CHUNK, s)
-    if s % chunk:
-        raise ValueError(f"selective_scan: sequence length {s} is not a multiple of the chunk {chunk}")
-
-    def run(x, dt, A, B, C, D):  # the scope INSIDE what shard_map wraps: its body starts a name stack of its own
-        with tracing.scope("s6/scan"):
-            return _scan(x, dt, A, B, C, D, chunk)
-
-    if mesh is None or not _kernel_takes(x, B, chunk):
-        return run(x, dt, A, B, C, D)
-    rows = _fit_spec(x.shape, P(batch_axes, None, None), mesh)
-    return jax.shard_map(run, mesh=mesh, in_specs=(rows, rows, P(), rows, rows, P()), out_specs=rows,
-                         check_vma=False)(x, dt, A, B, C, D)
+    return kernel_pair.run(PAIR, x, dt, A, B, C, D, chunk=chunk or CHUNK, mesh=mesh, batch_axes=batch_axes)
 
 
 def selective_scan_recurrent(

@@ -11,7 +11,9 @@ plus three reductions, no float32 [B, S, C] array anywhere.  For a step
 lowered for TPU at tile-aligned shapes both directions are Pallas kernels
 (`ops/pallas/ssm_conv.py`: every full-size array crosses HBM once, in bf16);
 every other shape and platform takes the plain form below, chosen from the
-shapes alone.
+shapes alone.  Convolution and scan each declare a `kernel_pair.KernelPair`
+(`CONV`, `SCAN`); `ops/kernel_pair.py`'s scaffold makes the choice and builds
+the `custom_vjp`s.
 
 The recurrence, per batch row and per head h, with a state `H_t` of shape
 [P, N] (head size x state size), a positive step `dt_t`, a negative scalar
@@ -79,10 +81,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.sharding import _fit_spec
-from ray_tpu.util import tracing
+from ray_tpu.ops import kernel_pair
 
 # The published chunk length (`mamba_chunk_size`); the program's own constant.
 CHUNK = 256
@@ -129,51 +129,31 @@ def _conv_silu_bwd_plain(x, w, b, dy):
     return dx, dw, jnp.sum(dpre, axis=(0, 1))
 
 
-def _kernels():
-    """`ops/pallas/ssm_conv.py`, imported at first use like the other ops' kernels."""
-    from ray_tpu.ops.pallas import ssm_conv
-
-    return ssm_conv
-
-
-def _kernels_take(x: jax.Array, w: jax.Array) -> bool:
-    return _kernels().supported(x.shape[1], x.shape[2], w.shape[1])
-
-
+# The kernel forms are functions of the module, not closures of a call: jax finds a branch it has traced by the function.
 def _conv_silu_kernel(x, w, b):
-    return _kernels().conv_fwd(x.swapaxes(1, 2), w, b).swapaxes(1, 2)
+    return CONV.module().conv_fwd(x.swapaxes(1, 2), w, b).swapaxes(1, 2)
 
 
 def _conv_silu_bwd_kernel(x, w, b, dy):
-    dx, dw, db = _kernels().conv_bwd(x.swapaxes(1, 2), w, b, dy.swapaxes(1, 2))
+    dx, dw, db = CONV.module().conv_bwd(x.swapaxes(1, 2), w, b, dy.swapaxes(1, 2))
     return dx.swapaxes(1, 2), dw, db
 
 
-def _by_platform(kernel, plain, x, w, *rest):
-    """Like attention, the form follows the platform a step is LOWERED for,
-    not the process's backend: the kernels for TPU at shapes they take, the
-    plain form everywhere else."""
-    if _kernels_take(x, w):
-        return jax.lax.platform_dependent(x, w, *rest, tpu=kernel, default=plain)
-    return plain(x, w, *rest)
+def _conv_forward(call, x, w, b):
+    return (call(_conv_silu_kernel, _conv_silu_plain, x, w, b),)
 
 
-@jax.custom_vjp
-def _conv_silu_vjp(x, w, b):
-    return _by_platform(_conv_silu_kernel, _conv_silu_plain, x, w, b)
-
-
-def _conv_silu_fwd(x, w, b):
-    return _by_platform(_conv_silu_kernel, _conv_silu_plain, x, w, b), (x, w, b)
-
-
-def _conv_silu_bwd(res, dy):
-    x, w, b = res
-    dx, dw, db = _by_platform(_conv_silu_bwd_kernel, _conv_silu_bwd_plain, x, w, b, dy)
+def _conv_backward(call, x, w, b, dy):
+    dx, dw, db = call(_conv_silu_bwd_kernel, _conv_silu_bwd_plain, x, w, b, dy)
     return dx, dw.astype(w.dtype), db.astype(b.dtype)
 
 
-_conv_silu_vjp.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+# No scope of its own: each mixer names the convolution (`ssm/conv`, `kda/conv`, ..).
+CONV = kernel_pair.KernelPair(
+    name="causal_conv1d_silu", scope=None, kernels="ssm_conv",
+    takes=lambda kernels, x, w, b, chunk: kernels.supported(x.shape[1], x.shape[2], w.shape[1]),
+    forward=_conv_forward, backward=_conv_backward, replicated=(1, 2),  # w and b
+)
 
 
 def causal_conv1d_silu(x: jax.Array, w: jax.Array, b: jax.Array, mesh=None, batch_axes=None) -> jax.Array:
@@ -189,10 +169,7 @@ def causal_conv1d_silu(x: jax.Array, w: jax.Array, b: jax.Array, mesh=None, batc
     partitions the plain form by itself; a Mosaic kernel it cannot, so with a
     mesh the kernels run under shard_map over the batch axes, each device on
     its own rows with the whole sequence."""
-    if mesh is None or not _kernels_take(x, w):
-        return _conv_silu_vjp(x, w, b)
-    spec = _fit_spec(x.shape, P(batch_axes, None, None), mesh)
-    return jax.shard_map(_conv_silu_vjp, mesh=mesh, in_specs=(spec, P(), P()), out_specs=spec, check_vma=False)(x, w, b)
+    return kernel_pair.run(CONV, x, w, b, mesh=mesh, batch_axes=batch_axes)
 
 
 def _plain_forward(x, dt, A, B, C, D, chunk: int):
@@ -275,19 +252,12 @@ def _running_sums(dt, A, chunk: int):
     return dtc, jnp.cumsum(dtc * A.astype(jnp.float32), axis=2)  # cum_t = sum_{s<=t} a_s, a <= 0
 
 
-def _scan_kernels():
-    """`ops/pallas/ssd.py`, imported at first use like the other ops' kernels."""
-    from ray_tpu.ops.pallas import ssd
-
-    return ssd
-
-
 # Traced once a process, however many bodies of a loop over layers call them (PERF.md section 6, PR 48: `setup_trace_s`).
 @functools.partial(jax.jit, static_argnums=(6,))
 def _kernel_forward(x, dt, A, B, C, D, chunk: int):
     """(y, the state that enters each chunk as `ssd_fwd` lays it out)."""
     dtc, cum = _running_sums(dt, A, chunk)
-    return _scan_kernels().ssd_fwd(x, dtc.reshape(dt.shape), cum.reshape(dt.shape), B, C, D, chunk=chunk)
+    return SCAN.module().ssd_fwd(x, dtc.reshape(dt.shape), cum.reshape(dt.shape), B, C, D, chunk=chunk)
 
 
 @functools.partial(jax.jit, static_argnums=(8,))
@@ -299,7 +269,7 @@ def _kernel_backward(x, dt, A, B, C, D, entering, dy, chunk: int):
     through dt."""
     f32 = jnp.float32
     dtc, cum = _running_sums(dt, A, chunk)
-    dx, d_dt, d_cum, dB, dC, dD = _scan_kernels().ssd_bwd(
+    dx, d_dt, d_cum, dB, dC, dD = SCAN.module().ssd_bwd(
         x, dtc.reshape(dt.shape), cum.reshape(dt.shape), B, C, D, entering, dy, chunk=chunk)
     d_a = jax.lax.cumsum(d_cum.reshape(dtc.shape), axis=2, reverse=True).reshape(dt.shape)
     d_dt = d_dt + d_a * A.astype(f32)
@@ -307,37 +277,31 @@ def _kernel_backward(x, dt, A, B, C, D, entering, dy, chunk: int):
     return dx, d_dt.astype(dt.dtype), d_A.astype(A.dtype), dB.astype(B.dtype), dC.astype(C.dtype), dD.astype(D.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _ssd(x, dt, A, B, C, D, chunk: int):
-    return _ssd_fwd(x, dt, A, B, C, D, chunk)[0]
-
-
-def _ssd_fwd(x, dt, A, B, C, D, chunk: int):
-    """Like the convolution, the form follows the platform a step is LOWERED
-    for: the kernels for TPU, the plain form everywhere else (its backward is
-    JAX's own and starts from the arguments: zeros for the states, because
-    both branches of a dispatch return the same shapes)."""
+def _forward(call, x, dt, A, B, C, D):
+    """(y, the state that enters each chunk).  The plain form's backward is
+    JAX's own and starts from the arguments: zeros for the states."""
     b, s, h, p = x.shape
 
     def plain(x, dt, A, B, C, D):
-        return _plain_forward(x, dt, A, B, C, D, chunk), jnp.zeros((b, s // chunk, B.shape[-1], h * p), jnp.float32)
+        return _plain_forward(x, dt, A, B, C, D, call.chunk), jnp.zeros((b, s // call.chunk, B.shape[-1], h * p), jnp.float32)
 
-    with tracing.scope("ssm/scan"):
-        kernel = functools.partial(_kernel_forward, chunk=chunk)
-        y, entering = jax.lax.platform_dependent(x, dt, A, B, C, D, tpu=kernel, default=plain)
-    return y, (x, dt, A, B, C, D, entering)
+    return call(functools.partial(_kernel_forward, chunk=call.chunk), plain, x, dt, A, B, C, D)
 
 
-def _ssd_bwd(chunk: int, res, dy):
+def _backward(call, x, dt, A, B, C, D, entering, dy):
     def plain(x, dt, A, B, C, D, entering, dy):
-        return jax.vjp(functools.partial(_plain_forward, chunk=chunk), x, dt, A, B, C, D)[1](dy)
+        return jax.vjp(functools.partial(_plain_forward, chunk=call.chunk), x, dt, A, B, C, D)[1](dy)
 
-    with tracing.scope("ssm/scan"):
-        kernel = functools.partial(_kernel_backward, chunk=chunk)
-        return jax.lax.platform_dependent(*res, dy, tpu=kernel, default=plain)
+    return call(functools.partial(_kernel_backward, chunk=call.chunk), plain, x, dt, A, B, C, D, entering, dy)
 
 
-_ssd.defvjp(_ssd_fwd, _ssd_bwd)
+# At shapes the kernels refuse the plain form runs OUTSIDE the `custom_vjp`, differentiated by JAX whole (`refused`).
+SCAN = kernel_pair.KernelPair(
+    name="ssd_chunked", scope="ssm/scan", kernels="ssd",
+    takes=lambda kernels, x, dt, A, B, C, D, chunk: kernels.supported(
+        x.shape[2], x.shape[3], B.shape[-1], B.shape[2] if B.ndim == 4 else 1, x.shape[1], chunk),
+    forward=_forward, backward=_backward, replicated=(2, 5), refused=_plain_forward,  # A and D
+)
 
 
 def ssd_chunked(
@@ -366,20 +330,6 @@ def ssd_chunked(
     replicated).  GSPMD partitions the plain form by itself; a Mosaic kernel it
     cannot, so with a mesh the kernels run under shard_map over the batch axes,
     each device on its own rows with the whole sequence and every head."""
-    b, s, h, p = x.shape
-    groups = B.shape[2] if B.ndim == 4 else None
-    if groups is not None and h % groups:
-        raise ValueError(f"ssd_chunked: {groups} groups of B and C do not divide {h} heads")
-    chunk = min(chunk or CHUNK, s)
-    if s % chunk:
-        raise ValueError(f"ssd_chunked: sequence length {s} is not a multiple of the chunk {chunk}")
-    if not _scan_kernels().supported(h, p, B.shape[-1], groups or 1, s, chunk):
-        with tracing.scope("ssm/scan"):
-            return _plain_forward(x, dt, A, B, C, D, chunk)
-    run = functools.partial(_ssd, chunk=chunk)  # names its scope INSIDE what shard_map wraps, whose body starts a name stack
-    if mesh is None:
-        return run(x, dt, A, B, C, D)
-    rows = _fit_spec(x.shape, P(batch_axes, None, None, None), mesh)
-    small, group = P(*rows[:3]), P(*rows[:B.ndim])
-    return jax.shard_map(run, mesh=mesh, in_specs=(rows, small, P(), group, group, P()), out_specs=rows,
-                         check_vma=False)(x, dt, A, B, C, D)
+    if B.ndim == 4 and x.shape[2] % B.shape[2]:
+        raise ValueError(f"ssd_chunked: {B.shape[2]} groups of B and C do not divide {x.shape[2]} heads")
+    return kernel_pair.run(SCAN, x, dt, A, B, C, D, chunk=chunk or CHUNK, mesh=mesh, batch_axes=batch_axes)

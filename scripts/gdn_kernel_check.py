@@ -85,7 +85,7 @@ def per_channel(q, k, g):
 
 def both(q, k, v, g, beta, probe):
     """((o, the five cotangents) of the kernels, the same of the plain form)."""
-    per = kda._per_segment(q.shape[1], kda.CHUNK)
+    per = kda.per_segment(q.shape[1], kda.CHUNK)
     o, _, pairs = kernels.gdn_fwd(q, k, v, g, beta, per_segment=per, pair_states=True)
     kernel = (o, *kernels.gdn_bwd(q, k, v, g, beta, pairs, probe, per_segment=per))
     o, entering = gdn._plain_forward(q, k, v, g, beta, kda.CHUNK)
@@ -146,10 +146,10 @@ def main() -> int:
             ok &= line[f"{name}_kernel_vs_recurrent"] <= 1.1 * line[f"{name}_plain_vs_recurrent"] + (1e-6 if a.dtype == jnp.float32 else 4e-3)
         print(json.dumps(line), flush=True)
 
-    per = kda._per_segment(S, kda.CHUNK)
+    per = kda.per_segment(S, kda.CHUNK)
     pairs = jax.jit(functools.partial(kernels.gdn_fwd, per_segment=per, pair_states=True))(q, k, v, g, beta)[2]
     qr, kr, gr = jax.jit(per_channel)(q, k, g)
-    blocks = jax.jit(lambda *xs: tuple(kda._segments(x, kda.CHUNK, per) for x in xs))(qr, kr, v, gr, probe)
+    blocks = jax.jit(lambda *xs: tuple(kda.segments(x, kda.CHUNK, per) for x in xs))(qr, kr, v, gr, probe)
     kda_pairs = jax.jit(functools.partial(kda_kernels.kda_fwd, pair_states=True))(*blocks[:4], beta)[2]
     calls = (
         ("gdn_fwd", functools.partial(kernels.gdn_fwd, per_segment=per), (q, k, v, g, beta)),

@@ -77,18 +77,18 @@ PREFIX = 2048
 
 def backward(first_seed: int, seeds: int) -> bool:
     def segmented(*arrays):
-        per = kda._per_segment(arrays[0].shape[1], kda.CHUNK)
-        return tuple(kda._segments(x, kda.CHUNK, per) for x in arrays)
+        per = kda.per_segment(arrays[0].shape[1], kda.CHUNK)
+        return tuple(kda.segments(x, kda.CHUNK, per) for x in arrays)
 
     def both(q, k, v, g, beta, probe):
         """(the kernel's five cotangents, the plain form's), as [b, S, H, d] / [b, S, H]."""
         *blocks, d_o, cut = segmented(q, k, v, g, probe, beta[..., None])
         pairs = kernels.kda_fwd(*blocks, beta, pair_states=True)[2]
         *d, dbeta = kernels.kda_bwd(*blocks, beta, pairs, d_o)
-        kernel = (*map(kda._positions, d), dbeta)
-        entering = kda._plain_forward(*blocks, cut)[1]
-        *d, dbeta = kda._plain_backward(*blocks, cut, entering, d_o)
-        return kernel, (*map(kda._positions, d), kda._positions(dbeta)[..., 0])
+        kernel = (*map(kda.positions, d), dbeta)
+        entering = kda.plain_forward(*blocks, cut)[1]
+        *d, dbeta = kda.plain_backward(*blocks, cut, entering, d_o)
+        return kernel, (*map(kda.positions, d), kda.positions(dbeta)[..., 0])
 
     both = jax.jit(both)
     recurrent = jax.jit(jax.grad(lambda q, k, v, g, beta, probe: jnp.sum(kda.kda_recurrent(q, k, v, g, beta) * probe),
@@ -116,7 +116,7 @@ def backward(first_seed: int, seeds: int) -> bool:
     *blocks, d_o, cut = jax.jit(segmented)(q, k, v, g, probe, beta[..., None])
     _, entering, pairs = jax.jit(functools.partial(kernels.kda_fwd, pair_states=True))(*blocks, beta)
     heads_and_pairs = B * H * S // (2 * kda.CHUNK)
-    for name, f, args in (("plain", kda._plain_backward, (*blocks, cut, entering, d_o)),
+    for name, f, args in (("plain", kda.plain_backward, (*blocks, cut, entering, d_o)),
                           ("kernel", kernels.kda_bwd, (*blocks, beta, pairs, d_o)),
                           ("forward_with_pair_states", functools.partial(kernels.kda_fwd, pair_states=True),
                            (*blocks, beta)),
@@ -137,11 +137,11 @@ def main() -> int:
         return 1
     if args.backward:
         return 0 if backward(args.first_seed, args.seeds) else 1
-    per = kda._per_segment(S, kda.CHUNK)
-    segments = lambda x: kda._segments(x, kda.CHUNK, per)
+    per = kda.per_segment(S, kda.CHUNK)
+    segments = lambda x: kda.segments(x, kda.CHUNK, per)
     prepare = jax.jit(lambda q, k, v, g, beta: (*map(segments, (q, k, v, g)), beta, segments(beta[..., None])))
     kernel = jax.jit(lambda q, k, v, g, beta, _: kernels.kda_fwd(q, k, v, g, beta))
-    plain = jax.jit(lambda q, k, v, g, _, beta: kda._plain_forward(q, k, v, g, beta))
+    plain = jax.jit(lambda q, k, v, g, _, beta: kda.plain_forward(q, k, v, g, beta))
     recurrent = jax.jit(kda.kda_recurrent)
     ok = True
     for seed in range(args.first_seed, args.first_seed + args.seeds):
@@ -152,8 +152,8 @@ def main() -> int:
         o_plain, s_plain = plain(*prepared)
         line = {
             "seed": seed,
-            "kernel_vs_recurrent": rel(kda._positions(o_kernel), want),
-            "plain_vs_recurrent": rel(kda._positions(o_plain), want),
+            "kernel_vs_recurrent": rel(kda.positions(o_kernel), want),
+            "plain_vs_recurrent": rel(kda.positions(o_plain), want),
             "kernel_vs_plain": rel(o_kernel, o_plain),
             "states_kernel_vs_plain": rel(s_kernel[1:], s_plain[1:]),
             "finite": bool(jnp.all(jnp.isfinite(o_kernel))),

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops import ssm as op
+from ray_tpu.ops import kernel_pair, ssm as op
 from ray_tpu.ops.pallas import ssd as kernels
 
 B, S, H, P, N = 1, 8192, 64, 64, 128
@@ -78,7 +78,7 @@ def plain(*args):
 
 
 def kernel(*args):
-    return op._ssd(*args, op.CHUNK)
+    return kernel_pair.vjp(op.SCAN)(op.CHUNK, *args)
 
 
 def both_ways(form):

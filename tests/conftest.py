@@ -75,3 +75,34 @@ def ray_start_cluster():
     cluster = Cluster(initialize_head=True, head_node_args={"num_cpus": 2})
     yield cluster
     cluster.shutdown()
+
+
+# -- ops with a Mosaic kernel beside a plain form (ray_tpu/ops/kernel_pair.py) ------------------
+
+
+def as_lowered_for_tpu(patch):
+    """From here to `patch`'s undoing, every op's choice of form is the one a
+    step LOWERED FOR TPU makes: the kernel form at shapes the kernels take, the
+    plain form at the others.  `patch` is a `pytest.MonkeyPatch`.  The caller
+    makes the kernels it will reach runnable here (`interpret=True` partials)."""
+    from ray_tpu.ops import kernel_pair
+
+    patch.setattr(kernel_pair, "dispatch",
+                  lambda takes, kernel, plain, *inputs: kernel(*inputs) if takes else plain(*inputs))
+
+
+@pytest.fixture
+def lowered_for_tpu_on_the_cpu(monkeypatch):
+    as_lowered_for_tpu(monkeypatch)
+
+
+@pytest.fixture
+def no_kernel_runs(monkeypatch):
+    """As `lowered_for_tpu_on_the_cpu`, but a choice that would take a kernel fails the test."""
+    from ray_tpu.ops import kernel_pair
+
+    def dispatch(takes, kernel, plain, *inputs):
+        assert not takes, f"a step lowered for TPU would call {kernel}"
+        return plain(*inputs)
+
+    monkeypatch.setattr(kernel_pair, "dispatch", dispatch)

@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import pytest
+from conftest import as_lowered_for_tpu
 
 from ray_tpu.ops import gdn, kda
 from ray_tpu.ops.pallas import gdn as kernels
@@ -63,7 +64,7 @@ def kernels_on_the_cpu():
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(kernels, "gdn_fwd", functools.partial(kernels.gdn_fwd, interpret=True))
         patch.setattr(kernels, "gdn_bwd", functools.partial(kernels.gdn_bwd, interpret=True))
-        patch.setattr(jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+        as_lowered_for_tpu(patch)
         yield
 
 
@@ -117,9 +118,9 @@ def oracle():
     """JAX's own differentiation of the plain form on the repeated and broadcast arguments: `jnp.repeat`'s and
     `broadcast_to`'s transposes sum dq and dk over a group and dg over the channels."""
     def plain(q, k, v, g, beta):
-        segments = functools.partial(kda._segments, chunk=kda.CHUNK, per_segment=kda._per_segment(k.shape[1], kda.CHUNK))
+        segments = functools.partial(kda.segments, chunk=kda.CHUNK, per_segment=kda.per_segment(k.shape[1], kda.CHUNK))
         q, k, v, g, beta = per_channel(q, k, v, g, beta)
-        return kda._positions(kda._plain_forward(*map(segments, (q, k, v, g, beta[..., None])))[0])
+        return kda.positions(kda.plain_forward(*map(segments, (q, k, v, g, beta[..., None])))[0])
 
     return jax.jit(jax.grad(lambda q, k, v, g, beta, probe: jnp.sum(plain(q, k, v, g, beta) * probe), argnums=range(5)))
 
@@ -191,7 +192,7 @@ def test_shapes_the_kernels_refuse_run_the_plain_form(s, d, chunk):
     entering states alone, and o and the cotangents are the recurrence's."""
     args = inputs(11, s, 1.0, 1, 2, d=d)
     chunk_ = chunk or kda.CHUNK
-    per_segment = kda._per_segment(s, chunk_)
+    per_segment = kda.per_segment(s, chunk_)
     assert not kernels.supported(d, d, chunk_, per_segment, 2, 1)
     if chunk is None:  # the kernels' own chunk: they say so themselves
         with pytest.raises(ValueError, match="gdn_fwd: unsupported"):
@@ -201,7 +202,7 @@ def test_shapes_the_kernels_refuse_run_the_plain_form(s, d, chunk):
     loss = lambda f: lambda *a: jnp.sum(jnp.square(f(*a)))
     grad = jax.grad(loss(functools.partial(gdn.gdn_chunked, chunk=chunk)), argnums=range(5))
     with kernels_on_the_cpu():
-        assert gdn._gdn_fwd(*args, chunk_)[1][-1] is None
+        assert gdn.PAIR.forward(gdn.PAIR.call(args, chunk_, residuals=True), *args)[-1] is None
         text = str(jax.make_jaxpr(grad)(*args))
         assert "pallas_call" not in text and "platform_index" not in text
         got_o, got = gdn.gdn_chunked(*args, chunk=chunk), grad(*args)

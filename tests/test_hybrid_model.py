@@ -37,7 +37,7 @@ from benchmarks.lib import reference_hybrid  # noqa: E402
 from ray_tpu.models import LMTrainContext, TransformerConfig  # noqa: E402
 from ray_tpu.models import transformer  # noqa: E402
 from ray_tpu.models.mixers import MIXERS, attention  # noqa: E402
-from ray_tpu.ops import ssm  # noqa: E402
+from ray_tpu.ops import kernel_pair, ssm  # noqa: E402
 from ray_tpu.ops.pallas import ssm_conv  # noqa: E402
 from ray_tpu.parallel import MeshSpec, build_mesh  # noqa: E402
 
@@ -352,7 +352,7 @@ def test_a_mamba_layer_reaches_the_convolution_through_one_hand_written_backward
 
     forward = jax.make_jaxpr(run)(layer, x).jaxpr
     backward = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(run(p, x)), argnums=(0, 1)))(layer, x).jaxpr
-    for jaxpr, calls in ((forward, [ssm._conv_silu_bwd, transformer._dense_ffn_bwd]), (backward, [])):
+    for jaxpr, calls in ((forward, [kernel_pair.vjp(ssm.CONV).bwd, transformer._dense_ffn_bwd]), (backward, [])):
         eqns = list(_primitives(jaxpr))
         along_sequence = [e for e in eqns if e.primitive.name == "pad" and len(e.params["padding_config"]) == 3
                           and tuple(e.params["padding_config"][1]) != (0, 0, 0)]

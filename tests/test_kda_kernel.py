@@ -1,5 +1,5 @@
 """The KDA kernels (`ops/pallas/kda.py`) in interpret mode on the CPU: the
-forward against the plain form it replaces on TPU (`ops/kda.py:_plain_forward`)
+forward against the plain form it replaces on TPU (`ops/kda.py:plain_forward`)
 and the recurrence itself; the backward against JAX's own differentiation of
 the plain segment, which stays the backward off TPU and at refused shapes; the
 `custom_vjp` around both.
@@ -46,18 +46,16 @@ def segments_of_two_chunks(monkeypatch):
 
 
 @pytest.fixture
-def kernel_on_the_cpu(monkeypatch):
-    """`kda_chunked` as a step lowered for TPU has it, the kernel interpreted:
-    the dispatch takes its `tpu` branch."""
+def kernel_on_the_cpu(monkeypatch, lowered_for_tpu_on_the_cpu):
+    """`kda_chunked` as a step lowered for TPU has it (conftest.py), the kernel interpreted."""
     monkeypatch.setattr(kernels, "kda_fwd", functools.partial(kernels.kda_fwd, interpret=True))
     monkeypatch.setattr(kernels, "kda_bwd", functools.partial(kernels.kda_bwd, interpret=True))
-    monkeypatch.setattr(kda.jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
 
 
 def plain(q, k, v, g, beta):
     """The plain form alone, for JAX to differentiate: the oracle."""
-    segments = functools.partial(kda._segments, chunk=kda.CHUNK, per_segment=kda._per_segment(k.shape[1], kda.CHUNK))
-    return kda._positions(kda._plain_forward(*map(segments, (q, k, v, g, beta[..., None])))[0])
+    segments = functools.partial(kda.segments, chunk=kda.CHUNK, per_segment=kda.per_segment(k.shape[1], kda.CHUNK))
+    return kda.positions(kda.plain_forward(*map(segments, (q, k, v, g, beta[..., None])))[0])
 
 
 DECAYS = pytest.mark.parametrize("decay", [1e-3, 1.0, 40.0], ids=["slow", "mixed", "fast"])
@@ -79,9 +77,9 @@ def test_kernel_forward_is_the_plain_form_and_the_recurrence(kernel_on_the_cpu, 
 
 def test_kernel_writes_the_state_that_enters_each_segment(kernel_on_the_cpu):
     q, k, v, g, beta = inputs(3, 512, 0.05, 2)
-    segments = functools.partial(kda._segments, chunk=64, per_segment=2)
+    segments = functools.partial(kda.segments, chunk=64, per_segment=2)
     o, entering = kernels.kda_fwd(*map(segments, (q, k, v, g)), beta)
-    want_o, want = kda._plain_forward(*map(segments, (q, k, v, g, beta[..., None])))
+    want_o, want = kda.plain_forward(*map(segments, (q, k, v, g, beta[..., None])))
     assert entering.shape == want.shape == (4, 2, 2, 128, 128) and o.shape == want_o.shape
     assert not entering[0].any()  # a sequence starts from nothing
     assert rel(entering[1:], want[1:]) <= 1e-5
@@ -119,13 +117,13 @@ def test_gradients_through_the_kernel_are_the_plain_forms(kernel_on_the_cpu, dec
 @functools.cache
 def backward_kernel_and_oracle():
     """Jitted once: the cases of one shape share a compile."""
-    segments = lambda x: kda._segments(x, kda.CHUNK, kda._per_segment(x.shape[1], kda.CHUNK))
+    segments = lambda x: kda.segments(x, kda.CHUNK, kda.per_segment(x.shape[1], kda.CHUNK))
 
     def kernel(q, k, v, g, beta, probe):
         blocks = tuple(map(segments, (q, k, v, g)))
         _, entering, pairs = kernels.kda_fwd(*blocks, beta, pair_states=True, interpret=True)
         *d, dbeta = kernels.kda_bwd(*blocks, beta, pairs, segments(probe), interpret=True)
-        return (*map(kda._positions, d), dbeta), entering, pairs
+        return (*map(kda.positions, d), dbeta), entering, pairs
 
     oracle = jax.grad(lambda q, k, v, g, beta, probe: jnp.sum(plain(q, k, v, g, beta) * probe), argnums=range(5))
     return jax.jit(kernel), jax.jit(oracle)
@@ -185,12 +183,12 @@ def test_the_forward_kernels_arithmetic_is_pr_39s_text_for_text():
 
 def test_pair_states_are_a_third_output_and_change_neither_of_the_two():
     q, k, v, g, beta = inputs(3, 512, 0.05, 2)
-    blocks = [kda._segments(x, 64, 4) for x in (q, k, v, g)]  # two pairs a segment, two segments
+    blocks = [kda.segments(x, 64, 4) for x in (q, k, v, g)]  # two pairs a segment, two segments
     o, entering = kernels.kda_fwd(*blocks, beta, interpret=True)
     o_too, entering_too, pairs = kernels.kda_fwd(*blocks, beta, pair_states=True, interpret=True)
     assert bool(jnp.all(o == o_too)) and bool(jnp.all(entering == entering_too))  # bit for bit
     assert pairs.shape == (2, 2, 2, 2, 128, 128) and bool(jnp.all(pairs[:, :, 0] == entering))
-    want = kda._plain_forward(*[kda._segments(x, 64, 2) for x in (q, k, v, g, beta[..., None])])[1]  # a pair a segment
+    want = kda.plain_forward(*[kda.segments(x, 64, 2) for x in (q, k, v, g, beta[..., None])])[1]  # a pair a segment
     assert rel(jnp.moveaxis(pairs, 2, 1).reshape(want.shape)[1:], want[1:]) <= 1e-5
 
 
@@ -216,9 +214,9 @@ def test_off_tpu_the_custom_vjp_is_the_plain_form():
 )
 def test_shapes_the_kernel_refuses_run_the_plain_form(kernel_on_the_cpu, s, d, chunk, why):
     args = inputs(11, s, 1.0, 1, d=d)
-    per_segment = kda._per_segment(s, chunk or kda.CHUNK)
+    per_segment = kda.per_segment(s, chunk or kda.CHUNK)
     assert not kernels.supported(d, d, chunk or kda.CHUNK, per_segment), why
-    segments = functools.partial(kda._segments, chunk=chunk or kda.CHUNK, per_segment=per_segment)
+    segments = functools.partial(kda.segments, chunk=chunk or kda.CHUNK, per_segment=per_segment)
     with pytest.raises(ValueError, match="kda_fwd: unsupported"):
         kernels.kda_fwd(*map(segments, args[:4]), args[4])
     assert rel(kda.kda_chunked(*args, chunk=chunk), kda.kda_recurrent(*args)) <= KDA_TOL
@@ -232,10 +230,10 @@ def test_at_shapes_the_kernel_refuses_the_backward_is_jaxs_own_of_the_segment(ke
     entering states alone, and the cotangents are the recurrence's."""
     args = inputs(11, s, 1.0, 1, d=d)
     chunk_ = chunk or kda.CHUNK
-    segments = functools.partial(kda._segments, chunk=chunk_, per_segment=kda._per_segment(s, chunk_))
+    segments = functools.partial(kda.segments, chunk=chunk_, per_segment=kda.per_segment(s, chunk_))
     with pytest.raises(ValueError, match="kda_bwd: unsupported"):
         kernels.kda_bwd(*map(segments, args[:4]), args[4], None, segments(args[2]))
-    assert kda._kda_fwd(*args, chunk_)[1][-1] is None
+    assert kda.PAIR.forward(kda.PAIR.call(args, chunk_, residuals=True), *args)[-1] is None
     loss = lambda f: lambda *a: jnp.sum(jnp.square(f(*a)))
     grad = jax.grad(loss(functools.partial(kda.kda_chunked, chunk=chunk)), argnums=range(5))
     text = str(jax.make_jaxpr(grad)(*args))
@@ -258,7 +256,7 @@ def kernel_jaxprs():
     from ray_tpu.ops.pallas import gdn
 
     q, k, v, g, beta = inputs(13, 128, 1.0, 1, h=1)
-    blocks = [kda._segments(x, 64, 2) for x in (q, k, v, g)]
+    blocks = [kda.segments(x, 64, 2) for x in (q, k, v, g)]
     pairs = jnp.zeros((1, 1, 1, 1, 128, 128))
     scalar = (q, k, v, g[..., 0], beta)
     return {

@@ -1,0 +1,95 @@
+"""The contract of `ray_tpu/ops/kernel_pair.py`, held against every declared
+record at once: both forms of each direction return the same shapes and
+dtypes where the kernels take the shapes; where they refuse them no kernel and
+no choice is traced, in either direction; a call lowered for the CPU holds no
+Mosaic kernel.  Shapes and lowerings only: no kernel runs here (the kernels'
+arithmetic is `test_kda_kernel.py`'s, `test_gdn_kernel.py`'s,
+`test_selective_scan_kernel.py`'s, `test_ssd_kernel.py`'s and
+`test_hybrid_model.py`'s)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.ops import gdn, kda, kernel_pair, selective_scan, ssm
+
+f32, bf16 = jnp.float32, jnp.bfloat16
+shaped = jax.ShapeDtypeStruct
+
+
+def delta(s, hk, hv, d, per_channel):
+    """q, k, v, g, beta of a delta rule: `hv` value heads on `hk` key heads, g per channel or per head."""
+    g = (1, s, hv, d) if per_channel else (1, s, hv)
+    return shaped((1, s, hk, d), f32), shaped((1, s, hk, d), f32), shaped((1, s, hv, d), bf16), shaped(g, f32), shaped((1, s, hv), f32)
+
+
+def s6(s, c, n=16):
+    return (shaped((1, s, c), bf16), shaped((1, s, c), f32), shaped((c, n), f32), shaped((1, s, n), bf16),
+            shaped((1, s, n), bf16), shaped((c,), f32))
+
+
+def ssd(s, h, p, n=128):
+    return (shaped((1, s, h, p), bf16), shaped((1, s, h), f32), shaped((h,), f32), shaped((1, s, n), bf16),
+            shaped((1, s, n), bf16), shaped((h,), f32))
+
+
+def conv(s, c, k=4):
+    return shaped((1, s, c), bf16), shaped((c, k), bf16), shaped((c,), bf16)
+
+
+# record, the op that runs it, arguments the kernels take, arguments they refuse
+PAIRS = {
+    "kda": (kda.PAIR, kda.kda_chunked, delta(128, 1, 1, 128, True), delta(128, 1, 1, 64, True)),
+    "gdn": (gdn.PAIR, gdn.gdn_chunked, delta(256, 1, 2, 128, False), delta(256, 1, 2, 64, False)),
+    "s6": (selective_scan.PAIR, selective_scan.selective_scan, s6(256, 128), s6(256, 80)),
+    "ssd": (ssm.SCAN, ssm.ssd_chunked, ssd(256, 4, 64), ssd(256, 4, 48)),
+    "conv": (ssm.CONV, ssm.causal_conv1d_silu, conv(128, 16), conv(96, 16)),
+}
+EACH = pytest.mark.parametrize("name", PAIRS)
+
+
+def gradients(op, args):
+    return jax.grad(lambda *a: jnp.sum(op(*a).astype(f32)), argnums=range(len(args)))
+
+
+def test_every_record_of_the_ops_is_held_here():
+    declared = {id(value) for module in (gdn, kda, selective_scan, ssm) for value in vars(module).values()
+                if isinstance(value, kernel_pair.KernelPair)}
+    assert declared == {id(pair) for pair, *_ in PAIRS.values()}
+
+
+@EACH
+def test_both_forms_of_each_direction_return_the_same_shapes_and_dtypes(name, monkeypatch):
+    pair, op, taken, _ = PAIRS[name]
+    seen = []
+
+    def both(takes, kernel, plain, *inputs):
+        assert takes
+        kernel_out, plain_out = jax.eval_shape(kernel, *inputs), jax.eval_shape(plain, *inputs)
+        assert jax.tree.structure(kernel_out) == jax.tree.structure(plain_out)
+        assert [(o.shape, o.dtype) for o in jax.tree.leaves(kernel_out)] == [(o.shape, o.dtype) for o in jax.tree.leaves(plain_out)]
+        seen.append(len(inputs))
+        return plain(*inputs)
+
+    monkeypatch.setattr(kernel_pair, "dispatch", both)
+    cotangents = jax.eval_shape(gradients(op, taken), *taken)
+    assert [(c.shape, c.dtype) for c in cotangents] == [(a.shape, a.dtype) for a in taken]
+    forward, backward = seen  # one choice a direction; the backward reads the states and the output's cotangent too
+    assert forward == len(taken) < backward
+
+
+@EACH
+def test_at_shapes_the_kernels_refuse_no_kernel_and_no_choice_is_traced_in_either_direction(name, no_kernel_runs):
+    pair, op, _, refused = PAIRS[name]  # `no_kernel_runs` fails a choice that would take a kernel
+    text = str(jax.make_jaxpr(gradients(op, refused))(*refused))
+    assert "pallas_call" not in text and "platform_index" not in text
+    # a record with `refused` hands the plain form alone to JAX's differentiation
+    assert ("custom_vjp_call" in str(jax.make_jaxpr(op)(*refused))) == (pair.refused is None)
+
+
+@EACH
+def test_lowered_for_the_cpu_a_call_at_shapes_the_kernels_take_holds_no_mosaic_kernel(name):
+    _, op, taken, _ = PAIRS[name]
+    traced = jax.jit(gradients(op, taken)).trace(*taken)
+    assert str(traced.jaxpr).count("pallas_call") >= 2  # both directions' kernels are traced: one branch of each choice
+    assert "tpu_custom_call" not in traced.lower(lowering_platforms=("cpu",)).as_text()

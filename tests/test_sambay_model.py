@@ -206,7 +206,7 @@ def test_gradients_agree_with_the_reference_leaf_by_leaf(loss_and_grads):
         assert rel(grads[stack][mixer][leaf][-1], want[stack][mixer][leaf][-1]) < 10 * RTOL
 
 
-def test_the_cut_model_with_the_scan_kernel_interpreted_is_the_plain_run(cut, loss_and_grads, monkeypatch):
+def test_the_cut_model_with_the_scan_kernel_interpreted_is_the_plain_run(cut, loss_and_grads, monkeypatch, lowered_for_tpu_on_the_cpu):
     """The ten layers as a step lowered for TPU has their scans (PRs 42, 51):
     both kernels interpreted, under shard_map on the context's mesh, the
     forward's in the forward and in the layer's recompute; the backward's
@@ -220,7 +220,6 @@ def test_the_cut_model_with_the_scan_kernel_interpreted_is_the_plain_run(cut, lo
     monkeypatch.setattr(kernels, "s6_scan_fwd", lambda *a, **kw: calls.append(kw) or real(*a, interpret=True, **kw))
     monkeypatch.setattr(kernels, "s6_scan_bwd",
                         lambda *a, **kw: backward_calls.append(kw) or real_backward(*a, interpret=True, **kw))
-    monkeypatch.setattr(jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
     ctx = LMTrainContext(dataclasses.replace(cut["cfg"], remat=True, remat_policy="qkv_attn"),
                          mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
     with ctx.mesh:

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import as_lowered_for_tpu
 
 from ray_tpu.models.transformer import _remat_policy
 from ray_tpu.ops import selective_scan as op
@@ -50,10 +51,10 @@ def small_blocks(monkeypatch):
 
 def _as_lowered_for_tpu(patch):
     """`selective_scan` as a step lowered for TPU has it, the kernels
-    interpreted: both dispatches take their `tpu` branch."""
+    interpreted: both directions' choices take the kernel."""
     for name in ("s6_scan_fwd", "s6_scan_bwd"):
         patch.setattr(kernels, name, functools.partial(getattr(kernels, name), interpret=True))
-    patch.setattr(op.jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+    as_lowered_for_tpu(patch)
 
 
 @pytest.fixture
@@ -62,13 +63,8 @@ def kernel_on_the_cpu(monkeypatch):
 
 
 @pytest.fixture
-def no_kernel(monkeypatch):
-    def refuse(*args, **kwargs):
-        raise AssertionError("the kernel was called")
-
-    monkeypatch.setattr(kernels, "s6_scan_fwd", refuse)
-    monkeypatch.setattr(kernels, "s6_scan_bwd", refuse)
-    monkeypatch.setattr(op.jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+def no_kernel(no_kernel_runs):
+    """As a step lowered for TPU has it, and a choice that takes a kernel fails (conftest.py)."""
 
 
 def seed_form(x, dt, A, B, C, D, chunk=CHUNK):

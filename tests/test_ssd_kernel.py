@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import as_lowered_for_tpu
 
 from ray_tpu.models.transformer import _remat_policy
 from ray_tpu.ops import ssm as op
@@ -59,7 +60,7 @@ def _as_lowered_for_tpu(patch):
     jits around the kernels forget what they traced before and after."""
     for name in ("ssd_fwd", "ssd_bwd"):
         patch.setattr(kernels, name, functools.partial(getattr(kernels, name), interpret=True))
-    patch.setattr(op.jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+    as_lowered_for_tpu(patch)
     op._kernel_forward.clear_cache()
     op._kernel_backward.clear_cache()
 
@@ -189,8 +190,7 @@ def test_supported_refuses(name):
 @pytest.mark.parametrize("kw,chunk", [(dict(s=256, h=4, p=48), 128), (dict(s=256, h=4, n=64), 128),
                                       (dict(s=192, h=4), 64), (dict(s=256, h=4, groups=4), 128)],
                          ids=["odd_head_size", "small_state", "short_chunk", "one_head_a_group"])
-def test_refused_shapes_run_the_plain_form_differentiated_by_jax(no_kernel, monkeypatch, kw, chunk):
-    monkeypatch.setattr(op.jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+def test_refused_shapes_run_the_plain_form_differentiated_by_jax(no_kernel, lowered_for_tpu_on_the_cpu, kw, chunk):
     args = inputs(**kw)
     got = both(functools.partial(op.ssd_chunked, chunk=chunk), args)
     want = both(functools.partial(op._plain_forward, chunk=chunk), args)
