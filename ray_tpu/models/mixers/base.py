@@ -137,12 +137,6 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float, axis=-1, zero_centred:
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
 
 
-def l2_normed(x: jax.Array, scale: float = 1.0, eps: float = 1e-6) -> jax.Array:
-    """x / |x|_2 over the last axis (a head), times `scale`, in float32: a delta rule's q and k."""
-    xf = x.astype(jnp.float32)
-    return xf * (jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps) * scale)
-
-
 def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array, eps: float) -> jax.Array:
     """LayerNorm over the last axis: statistics in float32, the scale and the
     bias applied in x's dtype, as `rms_norm` applies its scale."""

@@ -32,11 +32,11 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.mixers.base import (
-    Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, l2_normed, log_of_uniform, log_uniform, normal, ones,
-    out_scale, proj_scale, rms_norm, stream_norm,
+    Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, log_of_uniform, log_uniform, normal, ones, out_scale,
+    proj_scale, rms_norm, stream_norm,
 )
+from ray_tpu.ops.delta_conv import delta_conv
 from ray_tpu.ops.gdn import gdn_chunked
-from ray_tpu.ops.ssm import causal_conv1d_silu
 from ray_tpu.util import tracing
 
 # The fused q|k|v|z projection before its convolution, beta's and the decay's
@@ -81,8 +81,11 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     """The gdn half of a layer.  Its regions sit inside the two mixer scopes
     every layer has, as a KDA layer's do: `gdn/proj` (ln1, the fused q|k|v|z
     projection, beta's and the decay's logits, `wo`, the residual add),
-    `gdn/conv` (the convolutions + SiLU in one call, on TPU Mamba-2's kernels;
-    the L2 norms, the decay's activation, the gated per-head RMSNorm),
+    `gdn/conv` (`ops/delta_conv.py` `delta_conv`: the convolutions + SiLU and
+    the L2 norms of q and k in one call, positions-major, read from the
+    q | k | v columns of `gdn_qkvz` where they lie, on TPU the kernels
+    `delta_conv_fwd` / `delta_conv_bwd`; the decay's activation, the gated
+    per-head RMSNorm),
     `gdn/scan` (the chunked recurrence, `ops/gdn.py`).
 
     With the three `saved` residuals kept the backward runs none of the
@@ -99,11 +102,10 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
             qkvz = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["wqkvz"].astype(dt)), GDN_QKVZ)
             ba = checkpoint_name(jnp.einsum("bse,ef->bsf", h, p["wba"].astype(dt)), GDN_BA)
         with tracing.scope("gdn/conv"):
-            conv = causal_conv1d_silu(qkvz[..., :qk + v_width], p["conv_w"], jnp.zeros((qk + v_width,), p["conv_w"].dtype),
-                                      **sharded)
-            q, k = (a.reshape(*a.shape[:2], c.gdn_key_heads, c.gdn_key_dim) for a in jnp.split(conv[..., :qk], 2, axis=-1))
-            v = conv[..., qk:].reshape(*conv.shape[:2], heads, c.gdn_value_dim)
-            q, k = l2_normed(q, c.gdn_key_dim ** -0.5), l2_normed(k)  # value head j reads key head j // (Hv / Hk)
+            wq, wk, wv = jnp.split(p["conv_w"], (qk // 2, qk), axis=0)  # q's and k's go by head, [Hk, Dk, K]
+            by_head = (c.gdn_key_heads, c.gdn_key_dim, -1)
+            q, k, v = delta_conv(qkvz, wq.reshape(by_head), wk.reshape(by_head), wv, **sharded)  # z's columns stay behind
+            v = v.reshape(*v.shape[:2], heads, c.gdn_value_dim)  # value head j reads key head j // (Hv / Hk)
             beta = jax.nn.sigmoid(ba[..., :heads].astype(f32))
             g = jax.nn.softplus(ba[..., heads:].astype(f32) + p["dt_bias"].astype(f32)) * -jnp.exp(p["A_log"].astype(f32))
     with tracing.scope("layer/attn_core"):

@@ -23,11 +23,11 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.mixers.base import (
-    Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, l2_normed, log_of_uniform, log_uniform, normal, ones,
-    out_scale, proj_scale, rms_norm, stream_norm,
+    Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, log_of_uniform, log_uniform, normal, ones, out_scale,
+    proj_scale, rms_norm, stream_norm,
 )
+from ray_tpu.ops.delta_conv import delta_conv
 from ray_tpu.ops.kda import kda_chunked
-from ray_tpu.ops.ssm import causal_conv1d_silu
 from ray_tpu.util import tracing
 
 # The fused q|k|v projection before its convolution, the two low-rank gates'
@@ -68,8 +68,10 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     """The KDA half of a layer.  Its regions sit inside the two mixer scopes
     every layer has, as a Mamba-2 layer's do: `kda/proj` (ln1, the fused
     q|k|v projection, both low-rank gates, beta, `wo`, the residual add),
-    `kda/conv` (the convolutions + SiLU in one call, on TPU Mamba-2's kernels;
-    the L2 norms, the decay's activation, the gated per-head RMSNorm),
+    `kda/conv` (`ops/delta_conv.py` `delta_conv`: the convolutions + SiLU and
+    the L2 norms of q and k in one call, positions-major, on TPU the kernels
+    `delta_conv_fwd` / `delta_conv_bwd`; the decay's activation, the gated
+    per-head RMSNorm),
     `kda/scan` (the chunked recurrence, named in `ops/kda.py`).
 
     With the three `saved` residuals kept the backward runs none of the
@@ -90,9 +92,9 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
             decay_in = jnp.einsum("bsr,rf->bsf", low[..., :dim], p["f_up"].astype(dt))
             gate_in = jnp.einsum("bsr,rf->bsf", low[..., dim: 2 * dim], p["g_up"].astype(dt))
         with tracing.scope("kda/conv"):
-            qkv = causal_conv1d_silu(qkv, p["conv_w"], jnp.zeros((3 * inner,), p["conv_w"].dtype), **sharded)
-            q, k, v = (a.reshape(*a.shape[:2], heads, dim) for a in jnp.split(qkv, 3, axis=-1))
-            q, k = l2_normed(q, dim ** -0.5), l2_normed(k)
+            wq, wk, wv = jnp.split(p["conv_w"], 3, axis=0)  # each [H * D, K]: q's and k's go by head
+            q, k, v = delta_conv(qkv, wq.reshape(heads, dim, -1), wk.reshape(heads, dim, -1), wv, **sharded)
+            v = v.reshape(*v.shape[:2], heads, dim)
             step = jax.nn.softplus(decay_in.astype(f32) + p["dt_bias"].astype(f32))
             g = step.reshape(*step.shape[:2], heads, dim) * -jnp.exp(p["A_log"].astype(f32))[:, None]
             beta = jax.nn.sigmoid(low[..., 2 * dim:].astype(f32))

@@ -7,13 +7,14 @@ only caller of `jax.lax.platform_dependent(.., tpu=.., default=..)` under
 `ray_tpu/ops/` (`ops/pallas/flash_attention.py`'s `tpu=compiled,
 cpu=interpreted` is a kernel's own interpret mode, another decision).
 
-An op that is a recurrence with a kernel for EACH direction also declares a
-`KernelPair` beside its mathematics, and `run` is the scaffold every such op
-shares: cut the chunk to the sequence, one `jax.custom_vjp` a record whose two
-directions go through `dispatch`, the record's scope around it, and with a
-mesh, at shapes the kernels take, a `jax.shard_map` over the batch axes
-(GSPMD partitions a plain form by itself; a Mosaic call it cannot).  A new
-recurrence is its mathematics, its kernels and one record.
+An op with a kernel for EACH direction (the recurrences, the convolutions
+that feed them) also declares a `KernelPair` beside its mathematics, and `run`
+is the scaffold every such op shares: cut the chunk to the sequence, one
+`jax.custom_vjp` a record whose two directions go through `dispatch`, the
+record's scope around it, and with a mesh, at shapes the kernels take, a
+`jax.shard_map` over the batch axes (GSPMD partitions a plain form by itself;
+a Mosaic call it cannot).  A new op is its mathematics, its kernels and one
+record.
 
 Nothing here is an option: no argument, field or variable selects a form.  A
 test that wants a step "as lowered for TPU" on the CPU replaces `dispatch`
@@ -68,7 +69,9 @@ class KernelPair:
     `forward(call, *args) -> (out, *states)` and `backward(call, *args,
     *states, d_out) -> the cotangents`, each in its argument's shape and
     dtype, hold the op's own layout work around ONE `call(kernel, plain,
-    *inputs)`: the kernel form, the plain form and what both read.  A state
+    *inputs)`: the kernel form, the plain form and what both read.  `out` is
+    one array or a tuple of them, each [batch, sequence, ...] (`delta_conv`'s
+    q, k and v), and `d_out` then a tuple alike.  A state
     the plain form has no use for is zeros of the kernel's shape, or None
     where `call.takes` is false."""
 
@@ -142,6 +145,14 @@ def run(pair: KernelPair, *args, chunk: Optional[int] = None, mesh=None, batch_a
 
     if mesh is None or not takes:
         return scoped(*args)
+    # An op its caller names takes that name inside with it: on more than one device the body is lowered as a function
+    # of its own, which starts a new name stack (on one device jax lowers it in line, under the caller's).
+    caller = None if pair.scope or mesh.size == 1 else tracing.open_scope()
+
+    def sharded(*args):  # `jax.named_scope` alone: the caller's own `tracing.scope` keeps the host's account
+        with jax.named_scope(caller) if caller else contextlib.nullcontext():
+            return scoped(*args)
+
     rows = _fit_spec(args[0].shape, P(batch_axes, *[None] * (args[0].ndim - 1)), mesh)
     specs = tuple(P() if i in pair.replicated else P(*rows[: a.ndim]) for i, a in enumerate(args))
-    return jax.shard_map(scoped, mesh=mesh, in_specs=specs, out_specs=rows, check_vma=False)(*args)
+    return jax.shard_map(sharded, mesh=mesh, in_specs=specs, out_specs=rows, check_vma=False)(*args)

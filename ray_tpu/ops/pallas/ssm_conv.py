@@ -4,7 +4,12 @@ Pallas for TPU (`ops/ssm.py` has the contract and the plain form).
 The kernels take x as [B, C, S], the SEQUENCE along the lanes: the order in
 which XLA keeps every [1, S, features] array of a Mamba-2 layer on the v5e
 (its scan wants the 256 positions of a chunk in the lanes, not the 64 of a
-head), so the caller's `swapaxes` on both sides are bitcasts.  x is walked in
+head), so the caller's `swapaxes` on both sides are bitcasts THERE: for the
+Mamba-2 and S6 layers (`mixers/mamba2.py`, `mixers/s6.py`), the callers this
+file has.  They are not for a delta layer, whose projection is saved
+positions-major and whose scan reads [b, S, H * 128] blocks: it crossed the
+whole array twice more a direction, and has had a convolution of its own since
+PR 60 (`ops/delta_conv.py`, `ops/pallas/delta_conv.py`).  x is walked in
 blocks of `rows` channels by `lanes` positions.  A tap `x_{t-j}` is the block
 rolled by j lanes (`pltpu.roll`); the j positions that roll in from the wrong
 end are taken from a 128-lane HALO, the neighbouring block's edge, passed as

@@ -288,6 +288,16 @@ class scope:
                 state.table, state.kernels = {}, set()
 
 
+def open_scope() -> Optional[str]:
+    """The name of the innermost scope open on the calling thread, or None:
+    for code that starts a name stack of its own under its caller's name (the
+    body of a `jax.shard_map`) and wants its ops found under that name still."""
+    stack = getattr(getattr(_scopes, "state", None), "stack", None)
+    if not stack:
+        return None
+    return stack[-1][0][len(stack[-2][0]) + 1:] if len(stack) > 1 else stack[-1][0]
+
+
 def take_scopes(start: float, end: float) -> Optional[Tuple[Dict[str, List[Any]], List[str]]]:
     """(table, kernel names) of what the calling thread's scopes accrued in
     blocks that lie inside [start, end], summed, or None if there is none.

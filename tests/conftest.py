@@ -91,6 +91,23 @@ def as_lowered_for_tpu(patch):
                   lambda takes, kernel, plain, *inputs: kernel(*inputs) if takes else plain(*inputs))
 
 
+def only_the_delta_convolution_runs_its_kernels(patch, rows=32):
+    """From here to `patch`'s undoing a delta layer's convolution ALONE takes
+    its kernel form, interpreted in blocks of `rows` positions
+    (`ops/pallas/delta_conv.py`), as in a step lowered for TPU; every other op
+    keeps the plain form it has on the CPU.  For a whole model at heads of 128."""
+    import functools
+
+    from ray_tpu.ops import delta_conv, kernel_pair
+    from ray_tpu.ops.pallas import delta_conv as kernels
+
+    mine = (delta_conv._kernel_forward, delta_conv._kernel_backward)
+    for name in ("conv_fwd", "conv_bwd"):
+        patch.setattr(kernels, name, functools.partial(getattr(kernels, name), rows=rows, interpret=True))
+    patch.setattr(kernel_pair, "dispatch",
+                  lambda takes, kernel, plain, *inputs: kernel(*inputs) if takes and kernel in mine else plain(*inputs))
+
+
 @pytest.fixture
 def lowered_for_tpu_on_the_cpu(monkeypatch):
     as_lowered_for_tpu(monkeypatch)

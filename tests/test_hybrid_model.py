@@ -449,7 +449,7 @@ SAMBAY = dict(
 FAMILIES = {"dense": {}, "expert": EXPERT, "granite": dict(BASE, max_seq_len=128), "kimi": KIMI, "sambay": SAMBAY}
 
 
-@pytest.mark.parametrize("family, equations", [("dense", 700), ("expert", 2894), ("granite", 1977), ("kimi", 17451),
+@pytest.mark.parametrize("family, equations", [("dense", 700), ("expert", 2894), ("granite", 1977), ("kimi", 17727),
                                                ("sambay", 4642)])
 def test_a_dense_and_an_expert_step_trace_to_the_parents_program(family, equations):
     """Counted at the parent of PR 30 with this function (the dense count is
@@ -459,7 +459,10 @@ def test_a_dense_and_an_expert_step_trace_to_the_parents_program(family, equatio
     at the parent of PR 43, before a kind of mixer became one record; `kimi`,
     which holds a SHARE of its experts, again at PR 48 (15,438 before it: the
     share's block is now `moe._sized_experts`, whose backward traces the
-    rung's forward again; the all-experts step, `expert`, did not move)."""
+    rung's forward again; the all-experts step, `expert`, did not move) and
+    at PR 60 (17,451 before it: a KDA layer's convolution is
+    `ops/delta_conv.py`'s `custom_vjp`, the norm inside it, and both of its
+    forms are traced; no other family has a delta layer)."""
     ctx = one_device_ctx(TransformerConfig.tiny(**FAMILIES[family]))
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)

@@ -4,14 +4,14 @@ dtypes where the kernels take the shapes; where they refuse them no kernel and
 no choice is traced, in either direction; a call lowered for the CPU holds no
 Mosaic kernel.  Shapes and lowerings only: no kernel runs here (the kernels'
 arithmetic is `test_kda_kernel.py`'s, `test_gdn_kernel.py`'s,
-`test_selective_scan_kernel.py`'s, `test_ssd_kernel.py`'s and
-`test_hybrid_model.py`'s)."""
+`test_selective_scan_kernel.py`'s, `test_ssd_kernel.py`'s,
+`test_delta_conv_kernel.py`'s and `test_hybrid_model.py`'s)."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops import gdn, kda, kernel_pair, selective_scan, ssm
+from ray_tpu.ops import delta_conv, gdn, kda, kernel_pair, selective_scan, ssm
 
 f32, bf16 = jnp.float32, jnp.bfloat16
 shaped = jax.ShapeDtypeStruct
@@ -37,6 +37,11 @@ def conv(s, c, k=4):
     return shaped((1, s, c), bf16), shaped((c, k), bf16), shaped((c,), bf16)
 
 
+def delta_conv_of(s, cx, hq, hk, d, cv, k=4):
+    """x and the three weights of a delta layer's convolution: `hq` + `hk` heads of `d`, `cv` channels of v, of x's `cx` columns."""
+    return shaped((1, s, cx), bf16), shaped((hq, d, k), f32), shaped((hk, d, k), f32), shaped((cv, k), f32)
+
+
 # record, the op that runs it, arguments the kernels take, arguments they refuse
 PAIRS = {
     "kda": (kda.PAIR, kda.kda_chunked, delta(128, 1, 1, 128, True), delta(128, 1, 1, 64, True)),
@@ -44,16 +49,19 @@ PAIRS = {
     "s6": (selective_scan.PAIR, selective_scan.selective_scan, s6(256, 128), s6(256, 80)),
     "ssd": (ssm.SCAN, ssm.ssd_chunked, ssd(256, 4, 64), ssd(256, 4, 48)),
     "conv": (ssm.CONV, ssm.causal_conv1d_silu, conv(128, 16), conv(96, 16)),
+    # one key head and two value heads of 128 read from a wider array (Qwen3-Next's layout); heads of 64 are refused
+    "delta_conv": (delta_conv.PAIR, delta_conv.delta_conv, delta_conv_of(32, 768, 1, 1, 128, 256), delta_conv_of(32, 768, 2, 2, 64, 256)),
 }
 EACH = pytest.mark.parametrize("name", PAIRS)
 
 
 def gradients(op, args):
-    return jax.grad(lambda *a: jnp.sum(op(*a).astype(f32)), argnums=range(len(args)))
+    """Of the sum of the op's output, or of each of its outputs (`delta_conv` has three)."""
+    return jax.grad(lambda *a: sum(jnp.sum(out.astype(f32)) for out in jax.tree.leaves(op(*a))), argnums=range(len(args)))
 
 
 def test_every_record_of_the_ops_is_held_here():
-    declared = {id(value) for module in (gdn, kda, selective_scan, ssm) for value in vars(module).values()
+    declared = {id(value) for module in (delta_conv, gdn, kda, selective_scan, ssm) for value in vars(module).values()
                 if isinstance(value, kernel_pair.KernelPair)}
     assert declared == {id(pair) for pair, *_ in PAIRS.values()}
 

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import only_the_delta_convolution_runs_its_kernels
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -106,6 +107,31 @@ def rel(a, b):
 
 
 # -- the model against the reference ----------------------------------------------------
+
+
+def test_at_heads_of_128_the_layers_convolution_runs_its_kernels_and_no_mamba_2_kernel(monkeypatch):
+    """PR 60: at the published head size a KDA layer's q, k and v come from
+    `delta_conv`'s kernels (two blocks of positions here), with the same loss
+    and gradients as the plain form gives, `conv_w`'s among them: the layer
+    hands its weights over by head.  And the step lowered for TPU holds those
+    kernels under `kda/conv` and none of `ssm_conv_*`."""
+    cfg = config_of(kda_head_dim=128, kda_heads=2, n_layers=2, layer_types=("kda", "kda"), ffn_types=("dense", "experts"),
+                    remat=True, remat_policy="qkv_attn")
+    params = redrawn(transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 64), 0, cfg.vocab_size)
+    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, axis=1)}
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=jax.devices()[:1]), strategy="dp")
+    objective = lambda p: ctx._loss(p, batch)[0]  # noqa: E731
+    want_loss, want = jax.jit(jax.value_and_grad(objective))(params)
+    lowered = jax.jit(jax.grad(objective)).trace(params).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "ssm_conv" not in lowered and "kda/conv/cond/branch_0_fun/delta_conv_fwd" in lowered and "delta_conv_bwd" in lowered
+    only_the_delta_convolution_runs_its_kernels(monkeypatch)
+    loss, got = jax.jit(jax.value_and_grad(objective))(params)
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    worst = {jax.tree_util.keystr(path): rel(g, w) for (path, g), w in
+             zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want)) if float(jnp.abs(w).max()) > 0}
+    assert max(worst.values()) < 1e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:5]
+    assert any("conv_w" in path for path in worst)
 
 
 def test_the_stack_is_runs_of_pairs_with_one_parameter_stack_a_pair(tiny):

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from conftest import only_the_delta_convolution_runs_its_kernels
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -246,6 +247,33 @@ def test_gradients_agree_with_the_reference_leaf_by_leaf(loss_and_grads):
     worst = {jax.tree_util.keystr(p): rel(got[p], want[p]) for p in got}
     assert max(worst.values()) < 2e-3, sorted(worst.items(), key=lambda kv: -kv[1])[:5]
     assert all(float(jnp.abs(want[p]).max()) > 0 for p in want)  # every leaf has a gradient: both gates, A_log, dt_bias
+
+
+# -- the delta layer's convolution (PR 60) -------------------------------------------------------
+
+
+def test_at_heads_of_128_the_layers_convolution_runs_its_kernels_and_no_mamba_2_kernel(monkeypatch):
+    """PR 60: at the published head sizes a delta layer's q, k and v come from
+    `delta_conv`'s kernels (two blocks of positions here), read from the
+    q | k | v columns of the fused projection with z's left behind, with the
+    same loss and gradients as the plain form gives, `conv_w`'s and `wqkvz`'s
+    among them.  And the step lowered for TPU holds those kernels under
+    `gdn/conv` and none of `ssm_conv_*`."""
+    cfg = config_of(dict(CONFIG, linear_key_head_dim=128, linear_value_head_dim=128), remat=True, remat_policy="qkv_attn")
+    params = redrawn(transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, SEQ), 0, cfg.vocab_size)
+    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, axis=1)}
+    objective = lambda p: one_device_ctx(cfg)._loss(p, batch)[0]  # noqa: E731
+    want_loss, want = jax.jit(jax.value_and_grad(objective))(params)
+    lowered = jax.jit(jax.grad(objective)).trace(params).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "ssm_conv" not in lowered and "gdn/conv/cond/branch_0_fun/delta_conv_fwd" in lowered and "delta_conv_bwd" in lowered
+    only_the_delta_convolution_runs_its_kernels(monkeypatch)
+    loss, got = jax.jit(jax.value_and_grad(objective))(params)
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    worst = {jax.tree_util.keystr(path): rel(g, w) for (path, g), w in
+             zip(jax.tree_util.tree_flatten_with_path(got)[0], jax.tree_util.tree_leaves(want))}
+    assert max(worst.values()) < 1e-4, sorted(worst.items(), key=lambda kv: -kv[1])[:5]
+    assert any("conv_w" in path for path in worst) and any("wqkvz" in path for path in worst)
 
 
 # -- the delta layer against the token-by-token recurrence ---------------------------------------

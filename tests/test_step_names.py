@@ -273,6 +273,34 @@ def test_lowered_kimi_step_holds_each_flash_kernel_exactly_once_at_two_head_size
     assert "x192x" in calls[0] and "x128x" in calls[0]  # q/k heads of 192 beside v heads of 128, no padding
 
 
+# What PR 60 put under `kda/conv`: the positions-major convolution with the L2 norm inside, calls of a lowered step
+# (three runs of KDA layers: one over the dense FFN, two over experts around the MLA layer; each one body: forward and
+# recompute, one backward).
+KIMI_CONV_KERNELS = {"delta_conv_fwd": 6, "delta_conv_bwd": 3}
+
+
+@pytest.mark.parametrize("kernel", KIMI_CONV_KERNELS)
+def test_lowered_kimi_step_holds_the_positions_major_convolution_under_kda_conv(kimi_lowered_for_tpu, kernel):
+    """As `kimi-linear-ep16-1chip.seq16k`'s step holds them 8 + 4 times (four
+    KDA layers in bodies of their own there): every call under
+    `layer/attn_proj/kda/conv` and the kernel's own name, which is how
+    `benchmarks/lib/trace_kimi.py` finds its time, the backward kernel in the
+    layer's backward and not in its recompute."""
+    calls = [line for line in kimi_lowered_for_tpu.splitlines() if "@tpu_custom_call" in line
+             and f'kernel_name = "{kernel}"' in line]
+    assert len(calls) == KIMI_CONV_KERNELS[kernel]
+    paths = [path for path in re.findall(r'#loc\d+ = loc\("([^"]+)"', kimi_lowered_for_tpu) if path.endswith(f"{kernel}/pallas_call")]
+    assert paths and all("layer/attn_proj/kda/conv/" in path for path in paths), paths
+    directions = {path.split("layer/")[0] for path in paths}
+    assert directions == ({"checkpoint/"} if "bwd" in kernel else {"", "checkpoint/rematted_computation/"})
+
+
+def test_lowered_kimi_step_holds_none_of_mamba_2s_convolution_kernels(kimi_lowered_for_tpu):
+    """The layer kind picks the op: a delta layer's convolution is not `ssm.CONV`'s, whose `[B, C, S]` kernels a
+    Mamba-2 and an S6 layer keep (`SSM_KERNELS` above, `test_tpu_lowering.py`)."""
+    assert "ssm_conv" not in kimi_lowered_for_tpu
+
+
 def test_lowered_kimi_step_runs_no_d_wide_mixer_projection_twice(kimi_lowered_for_tpu):
     """Under `qkv_attn` a KDA layer's fused q|k|v projection and an MLA
     layer's q projection run in the forward and never again: the recompute
@@ -543,7 +571,7 @@ def test_scope_gives_the_lowered_text_named_scope_gave():
 
 STEPS = {"dense": (CFG, KERNELS), "experts": (MOE_CFG, KERNELS + MOE_KERNELS),
          "hybrid": (HYBRID_CFG, KERNELS + ("ssm_conv_fwd", "ssm_conv_bwd")),
-         "kimi": (KIMI_CFG, KERNELS + MOE_KERNELS + ("kda_fwd", "kda_bwd", "ssm_conv_fwd", "ssm_conv_bwd"))}
+         "kimi": (KIMI_CFG, KERNELS + MOE_KERNELS + ("kda_fwd", "kda_bwd", "delta_conv_fwd", "delta_conv_bwd"))}
 
 
 @pytest.fixture(scope="module", params=sorted(STEPS))

@@ -400,3 +400,39 @@ def test_the_qwen3_next_cells_scan_kernels_and_grouped_matmuls_compile_at_its_sh
     for compiled in (up, down):
         assert compiled.count('custom_call_target="tpu_custom_call"') == 1 and "moe_gmm" in compiled
         assert REFUSED_SCOPE not in compiled
+
+
+@pytest.mark.parametrize("s,cx,hq,hk,cv", [(16384, 12288, 32, 32, 4096), (8192, 12288, 16, 16, 4096)], ids=["kimi-linear", "qwen3-next"])
+def test_the_delta_layers_convolution_compiles_at_both_cells_shapes_and_nothing_leaves_positions_major(topo, s, cx, hq, hk, cv):
+    """PR 60: `delta_conv` as the two delta layers call it (Kimi Linear: the
+    whole fused projection, 32 + 32 + 32 heads of 128 at 16,384 positions;
+    Qwen3-Next: the first 8,192 of `gdn_qkvz`'s 12,288 columns, 16 + 16 key
+    heads and 32 value heads at 8,192), forward and backward: `delta_conv_fwd`
+    and `delta_conv_bwd` and none of Mamba-2's kernels; no array of the
+    sequence's length is laid out with the sequence minor ([., C, S] order: what
+    `ssm_conv_*` wanted and a delta layer paid two relayouts a direction for),
+    and the kernels read x itself, no slice of it."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.delta_conv import delta_conv
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    f32 = jnp.float32
+    args = shaped((1, s, cx)), shaped((hq, 128, 4), f32), shaped((hk, 128, 4), f32), shaped((cv, 4), f32)
+    probe = shaped((1, s, hq, 128), f32), shaped((1, s, hk, 128), f32), shaped((1, s, cv))
+
+    def both(x, wq, wk, wv, dq, dk, dv):
+        out, vjp = jax.vjp(delta_conv, x, wq, wk, wv)
+        return out, vjp((dq, dk, dv))
+
+    with _no_compile_cache():
+        compiled = jax.jit(both).lower(*args, *probe).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2 and "ssm_conv" not in text
+    for kernel in ("delta_conv_fwd", "delta_conv_bwd"):
+        (call,) = [line for line in text.splitlines() if re.search(rf"%{kernel}(\.\d+)? = ", line)]
+        assert re.search(r"custom-call\(%x(\.\d+)?, %x(\.\d+)?,", call), call  # x as it came in, and its halo(s)
+    assert not re.findall(r"\[1,\d{4,},\d{4,}\]\{1,2,0", text)  # a full-size array in any order but positions-major
+    got = [(o.shape, o.dtype) for o in jax.tree.leaves(compiled.out_info)]
+    assert got == [(p.shape, p.dtype) for p in probe] + [(a.shape, a.dtype) for a in args]

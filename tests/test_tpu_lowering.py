@@ -158,8 +158,10 @@ def test_a_step_with_groups_of_b_and_c_lowers_for_tpu_with_the_same_scan_kernels
     heads a program), one run of three layers: the scan's forward kernel
     twice (forward, recompute) and its backward kernel once."""
     cfg = dataclasses.replace(HYBRID, n_layers=3, layer_types=("mamba",) * 3, ssm_groups=2)
-    kernels = _mosaic_kernels(_lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=cfg))
-    assert kernels == {"ssm_conv_fwd": 2, "ssm_conv_bwd": 1, "ssd_fwd": 2, "ssd_bwd": 1}
+    text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=cfg, debug_info=True)
+    assert _mosaic_kernels(text) == {"ssm_conv_fwd": 2, "ssm_conv_bwd": 1, "ssd_fwd": 2, "ssd_bwd": 1}
+    for _, path in _kernel_paths(text, "ssm_conv_fwd|ssm_conv_bwd"):  # named by its caller, on a mesh too (PR 60)
+        assert "ssm/conv/" in path and "ssm/conv/ssm/conv" not in path, path
 
 
 # Kimi Linear's mixers in small, at the head size the KDA kernel takes: two KDA
@@ -189,6 +191,26 @@ def test_kimi_step_lowers_for_tpu_with_the_kda_kernels_inside_kda_scan(n_devices
     for kernel, path in _kernel_paths(text, "kda_fwd|kda_bwd"):
         assert "kda/scan" in path and kernel in path, path
         if kernel == "kda_bwd":  # in the layer's backward, not its recompute: the reader's `bwd`
+            assert "rematted_computation" not in path, path
+    _holds_the_positions_major_convolution(text, kernels, "kda/conv/", n_devices)
+
+
+def _holds_the_positions_major_convolution(text, kernels, name, n_devices):
+    """PR 60: a delta layer's convolution is `ops/delta_conv.py`'s, with the L2
+    norm inside: its forward kernel twice in the run's body (forward, and the
+    recompute), its backward kernel once, under the layer's `*/conv` name and
+    their own ONCE on every mesh (on more than one device a `shard_map`'s body
+    starts a name stack of its own, and the op has no name but its caller's:
+    `kernel_pair.run` takes that name inside, as the scans' own names are
+    entered there; on one device the layer's whole path stands), and none of
+    Mamba-2's `ssm_conv_*` (whose counts in the Mamba-2 and S6 steps of this
+    file stand as they were: the layer kind picks the op)."""
+    assert kernels["delta_conv_fwd"] == 2 and kernels["delta_conv_bwd"] == 1, kernels
+    assert not [kernel for kernel in kernels if kernel.startswith("ssm_conv")], kernels
+    for kernel, path in _kernel_paths(text, "delta_conv_fwd|delta_conv_bwd"):
+        assert ("layer/attn_proj/" + name if n_devices == 1 else name) in path and 2 * name not in path, path
+        assert f"/{kernel}/pallas_call" in path, path
+        if kernel == "delta_conv_bwd":
             assert "rematted_computation" not in path, path
 
 
@@ -223,6 +245,7 @@ def test_a_delta_layer_lowers_for_tpu_with_the_scalar_decay_kernels_inside_gdn_s
         assert "gdn/scan" in path and kernel in path, path
         if kernel == "gdn_bwd":  # in the layer's backward, not its recompute: the reader's `bwd`
             assert "rematted_computation" not in path, path
+    _holds_the_positions_major_convolution(text, kernels, "gdn/conv/", n_devices)
 
 
 def test_a_delta_step_lowered_for_the_cpu_holds_no_kernel():
