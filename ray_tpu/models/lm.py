@@ -5,6 +5,18 @@ drives from host actors — the TPU replacement for the reference's
 DDP-wrapped user loop (python/ray/train/torch/train_loop_utils.py:92-98 +
 NCCL allreduce).  Gradient reduction is not a runtime call: the mesh sharding
 of params/batch makes XLA emit reduce-scatter/all-reduce over ICI.
+
+Two objectives (`LMTrainContext._loss`).  A next-token model's: the mean
+cross entropy of each position's logits against the token after it
+(`head_cross_entropy`), plus the router's terms and a multi-token-prediction
+module's.  A block-diffusion model's (`TransformerConfig.diffusion_block`):
+the block-diffusion NELBO under a linear schedule (`diffusion_noise`,
+`head_weighted_cross_entropy`): each block k of a sequence gets a masking
+rate t_k, each of its tokens is replaced by the mask id with probability t_k,
+the noisy copy runs the stack beside the clean one, and the loss is `(1/S)
+sum_i m_i / t_b(i) * -log p(x_0,i | row i)` over the MASKED rows of the noisy
+half, row i predicting token i (no shift; the batch's `targets` are not
+read), plus the router's terms over all 2S rows.
 """
 
 from __future__ import annotations
@@ -24,6 +36,7 @@ from ray_tpu.models.transformer import (
     TransformerConfig,
     _constrainer,
     check_placement,
+    diffusion_forward,
     forward,
     init_params,
     mtp_forward,
@@ -92,7 +105,13 @@ def _row_weights(targets, mask):
     return mask / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def _head_cross_entropy_fwd(constrain, x, head, targets, mask):
+def _weights_given(targets, weights):
+    """float32 [B,S]: the weights as the caller made them (`head_weighted_cross_entropy`)."""
+    del targets
+    return weights.astype(jnp.float32)
+
+
+def _head_cross_entropy_fwd(constrain, x, head, targets, mask, row_weights=_row_weights):
     with tracing.scope("lm_head"):
         logits = constrain(jnp.einsum("bse,ev->bsv", x, head), LOGITS_AXES)
     with tracing.scope("loss"):
@@ -100,11 +119,11 @@ def _head_cross_entropy_fwd(constrain, x, head, targets, mask):
         row_max = jnp.max(wide, axis=-1)
         lse = row_max + jnp.log(jnp.sum(jnp.exp(wide - row_max[..., None]), axis=-1))
         target_logit = jnp.sum(jnp.where(_target_columns(logits, targets), wide, 0.0), axis=-1)
-        loss = jnp.sum((lse - target_logit) * _row_weights(targets, mask))
+        loss = jnp.sum((lse - target_logit) * row_weights(targets, mask))
     return loss, (x, head, logits, lse, targets, mask)
 
 
-def _head_cross_entropy_bwd(constrain, residuals, g):
+def _head_cross_entropy_bwd(constrain, residuals, g, row_weights=_row_weights):
     x, head, logits, lse, targets, mask = residuals
     with tracing.scope("loss"):
         # Not behind a barrier: XLA fuses this pass into the operand of both
@@ -113,7 +132,7 @@ def _head_cross_entropy_bwd(constrain, residuals, g):
         # writing it once (granite-h-micro's cell, PERF.md section 6, PR 34).
         softmax = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
         dlogits = jnp.where(_target_columns(logits, targets), softmax - 1.0, softmax)
-        dlogits = dlogits * (g * _row_weights(targets, mask))[..., None]
+        dlogits = dlogits * (g * row_weights(targets, mask))[..., None]
         dlogits = constrain(dlogits.astype(logits.dtype), LOGITS_AXES)
     with tracing.scope("lm_head"):
         dx = jnp.einsum("bsv,ev->bse", dlogits, head)
@@ -124,15 +143,62 @@ def _head_cross_entropy_bwd(constrain, residuals, g):
 head_cross_entropy.defvjp(_head_cross_entropy_fwd, _head_cross_entropy_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def head_weighted_cross_entropy(constrain, x, head, targets, weights):
+    """`head_cross_entropy` with each row's weight GIVEN: `sum_rows weights *
+    (lse(logits) - logits[target])`, weights [B,S] float (not differentiated),
+    the same passes, residuals and hand-written backward.  A block-diffusion
+    step's loss: the weights are `m_i / t_b(i) / rows` (`diffusion_noise`),
+    zero on the rows that were not masked."""
+    return _head_cross_entropy_fwd(constrain, x, head, targets, weights, _weights_given)[0]
+
+
+head_weighted_cross_entropy.defvjp(
+    functools.partial(_head_cross_entropy_fwd, row_weights=_weights_given),
+    functools.partial(_head_cross_entropy_bwd, row_weights=_weights_given))
+
+
+NOISE_STREAM = 0x5DA2  # folded into `init_state`'s key: the key of a block-diffusion model's noise, beside the weights' draws
+
+
+def diffusion_noise(key: jax.Array, tokens: jax.Array, *, block: int, mask_id: int, eps: float):
+    """The forward process of a block-diffusion step under the linear schedule
+    (alpha_t = 1 - t, so the NELBO weighs a masked token by 1/t): tokens
+    [N, S] in n = S / block blocks -> (the noisy copy x_t [N, S]; m [N, S]
+    bool, the masked tokens; t [N, S] float32, each token's block's rate).
+
+    The recipe, which `benchmarks/lib/reference_sdar.py` copies to the letter
+    (same key, same draws): `k_u, k_order, k_mask = jax.random.split(key, 3)`;
+    one `u ~ U[0, 1)` a sequence from `k_u` ([N, 1]); the strata `frac(u + k /
+    n)`, k < n, one rate in every 1/n of [0, 1) (MDLM's and BD3-LMs'
+    low-discrepancy draw, which takes the draw of the rates out of the loss's
+    step-to-step spread); the strata dealt to the blocks in the order
+    `argsort(U[0, 1) [N, n] from k_order)`; `t = eps + (1 - eps) * stratum`;
+    `m = U[0, 1) [N, S] from k_mask < t` of the token's block; `x_t = mask_id`
+    where m, else the token.  All float32."""
+    n_seqs, seq = tokens.shape
+    blocks = seq // block
+    k_u, k_order, k_mask = jax.random.split(key, 3)
+    strata = (jax.random.uniform(k_u, (n_seqs, 1)) + jnp.arange(blocks, dtype=jnp.float32) / blocks) % 1.0
+    order = jnp.argsort(jax.random.uniform(k_order, (n_seqs, blocks)), axis=-1)
+    t = jnp.repeat(eps + (1.0 - eps) * jnp.take_along_axis(strata, order, axis=-1), block, axis=-1)
+    masked = jax.random.uniform(k_mask, (n_seqs, seq)) < t
+    return jnp.where(masked, jnp.asarray(mask_id, tokens.dtype), tokens), masked, t
+
+
 # The step counters of the run's record (train/run_record.py), among the step's metrics: the key tiles a
 # windowed flash forward visits and the grid steps of a causal one that copy (PR 55); what a layer that holds a
 # share of its experts was given (`models/moe.py` `router_losses`: rows per held expert, mean and busiest, the
 # busiest expert's load over the mean, and the share of the T*K assignments whose rows the share's buffers moved);
-# the multi-token-prediction module's cross entropy.
+# the multi-token-prediction module's cross entropy; a block-diffusion step's two: the share of the sequence's
+# tokens the noise masked (0.5 in expectation) and the mask's pairs over the pairs of the tiles the flash forward
+# visits (a constant of the traced step, like the two of PR 55).
 WINDOW_TILES = "attn_window_tiles_visited_pct"
 CAUSAL_STEPS = "attn_causal_steps_copying_pct"
+MASKED_SHARE = "diffusion_masked_share"
+DIFFUSION_FILL = "attn_diffusion_mask_fill_pct"
 STEP_COUNTERS = (WINDOW_TILES, CAUSAL_STEPS, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
-                 "mtp_loss")
+                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL)
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
@@ -159,8 +225,12 @@ def _causal_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
     and like it a statement about the kernels at this length: it is noted
     whichever form the dispatch gives the step (`ops.attention`; off the
     chip and under the ring no flash kernel runs).  Nothing for a model
-    without such a layer or a length no tile divides."""
+    without such a layer (a block-diffusion model's calls are not causal) or a
+    length no tile divides."""
     from ray_tpu.ops.pallas.flash_attention import causal_forward_tiles, causal_steps_copying_pct
+
+    if config.diffusion_block is not None:
+        return {}
 
     heads = [MIXERS[mixer].flash_heads(config) for i, (mixer, _) in enumerate(config.layer_pairs())
              if config.layer_variant(i)[0] is None]
@@ -169,6 +239,19 @@ def _causal_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
         return {}
     copying = {t: causal_steps_copying_pct(seq, *t, keys=True) for t in set(tiles)}  # once a tiling, not a layer
     return {CAUSAL_STEPS: sum(copying[t] for t in tiles) / len(tiles)}
+
+
+def _diffusion_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
+    """`DIFFUSION_FILL`: the pairs the training mask holds over the pairs of
+    the tiles the flash forward visits for it (`flash_attention.
+    diffusion_mask_fill_pct`), at the tiles the head size gives at this
+    length; known when the step is traced and noted whichever form the
+    dispatch gives the step, as `CAUSAL_STEPS`.  Nothing at a length no tile
+    divides."""
+    from ray_tpu.ops.pallas.flash_attention import diffusion_mask_fill_pct
+
+    fill = diffusion_mask_fill_pct(seq, config.diffusion_block, config.head_dim, config.head_dim)
+    return {} if fill is None else {DIFFUSION_FILL: fill}
 
 
 def _mtp_term(params, h, head, batch, config, rules, mesh):
@@ -260,10 +343,18 @@ class LMTrainContext:
 
         cfg, rules, opt = self.config, self.rules, self.optimizer
 
+        # A block-diffusion model's state carries the key of its noise beside the step's count, drawn from the
+        # seed that draws the weights (`init_state`): step k's noise is `fold_in(noise_key, k)`.  No other
+        # model's state has the entry.
+        noise_state = {} if cfg.diffusion_block is None else {"noise_key": self.repl}
+
         def _init(key):
             params = init_params(cfg, key)
             opt_state = opt.init(params)
-            return {"params": params, "opt_state": opt_state, "step": jnp.zeros((), jnp.int32)}
+            state = {"params": params, "opt_state": opt_state, "step": jnp.zeros((), jnp.int32)}
+            if noise_state:
+                state["noise_key"] = jax.random.fold_in(key, NOISE_STREAM)
+            return state
 
         self._init = jax.jit(
             _init,
@@ -271,10 +362,37 @@ class LMTrainContext:
                 "params": self.param_shardings,
                 "opt_state": self.opt_shardings,
                 "step": self.repl,
+                **noise_state,
             },
         )
 
-        def _loss(params, batch):
+        def _diffusion_loss(params, batch, key):
+            """A block-diffusion model's `_loss`: (the block-diffusion NELBO of
+            the module docstring + the router's terms over all 2S rows, the
+            terms).  The noise is drawn on the device from `key` under the
+            scope `diffusion/noise`; a batch's `mask` (0 on padding) weighs
+            into the rows' weights; `ce_loss` is the NELBO's data term."""
+            constrain = _constrainer(rules, self.mesh)
+            tokens = batch["tokens"]
+            with tracing.scope("diffusion/noise"):
+                noisy, masked, t = diffusion_noise(key, tokens, block=cfg.diffusion_block, mask_id=cfg.mask_id,
+                                                   eps=cfg.diffusion_eps)
+                m = masked.astype(jnp.float32)
+                weights = m / t / m.size
+                if batch.get("mask") is not None:
+                    weights = weights * batch["mask"].astype(jnp.float32)
+                counters = {MASKED_SHARE: jnp.mean(m)}
+            x, head, router_stats = trunk(params, tokens, cfg, rules=rules, mesh=self.mesh, noisy=noisy)
+            ce = head_weighted_cross_entropy(constrain, x, head, tokens, weights)
+            with tracing.scope("loss"):
+                counters.update(_diffusion_counters(cfg, tokens.shape[1]))
+                if router_stats is None:
+                    return ce, counters
+                terms = router_losses(router_stats, cfg)
+                loss = ce + cfg.router_aux_loss_coef * terms["moe_lb_loss"] + cfg.router_z_loss_coef * terms["moe_z_loss"]
+                return loss, {"ce_loss": ce, **terms, **counters}
+
+        def _loss(params, batch, noise_key=None):
             """(the objective that is differentiated, its terms).  Dense: the
             cross entropy and no terms.  With experts: cross entropy +
             `router_aux_loss_coef` * load balancing + `router_z_loss_coef` *
@@ -283,7 +401,10 @@ class LMTrainContext:
             (`_mtp_term`), `ce_loss` and `mtp_loss` among the terms, the
             module's block one more layer of the router statistics.  Beside the
             terms ride the attention kernels' counters, constants of the
-            traced step (`_window_counters`, `_causal_counters`)."""
+            traced step (`_window_counters`, `_causal_counters`).  A
+            block-diffusion model's is `_diffusion_loss`, from `noise_key`."""
+            if cfg.diffusion_block is not None:
+                return _diffusion_loss(params, batch, noise_key)
             constrain = _constrainer(rules, self.mesh)
             x, head, router_stats = trunk(
                 params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
@@ -309,9 +430,11 @@ class LMTrainContext:
         def _train_step(state, batch):
             # On the host only (no op's name changes): what runs after `_loss` returns, the backward
             # pass's transposition and partial evaluation, is under a name in the trace's `scopes`.
+            # a block-diffusion step's noise: one key a step, from the state's key and the step's count
+            noise = (jax.random.fold_in(state["noise_key"], state["step"]),) if noise_state else ()
             with tracing.scope("autodiff", host_only=True):
                 (loss, terms), grads = jax.value_and_grad(_loss, has_aux=True)(
-                    state["params"], batch)
+                    state["params"], batch, *noise)
             with tracing.scope("optimizer"):
                 updates, opt_state = opt.update(grads, state["opt_state"], state["params"])
                 params = optax.apply_updates(state["params"], updates)
@@ -323,7 +446,7 @@ class LMTrainContext:
                 **terms,
             }
             return (
-                {"params": params, "opt_state": opt_state, "step": state["step"] + 1},
+                {"params": params, "opt_state": opt_state, "step": state["step"] + 1, **{k: state[k] for k in noise_state}},
                 metrics,
             )
 
@@ -337,6 +460,7 @@ class LMTrainContext:
                     "params": self.param_shardings,
                     "opt_state": self.opt_shardings,
                     "step": self.repl,
+                    **noise_state,
                 },
                 self.repl,
             ),
@@ -352,6 +476,11 @@ class LMTrainContext:
             return mtp_forward(params, tokens, next_tokens, cfg, rules=rules, mesh=self.mesh)
 
         self._forward_mtp = jax.jit(_forward_mtp)
+
+        def _forward_diffusion(params, noisy, clean):
+            return diffusion_forward(params, noisy, clean, cfg, rules=rules, mesh=self.mesh)
+
+        self._forward_diffusion = jax.jit(_forward_diffusion)
 
     # -- public API -------------------------------------------------------
     def init_state(self, seed: int = 0) -> Dict[str, Any]:
@@ -398,6 +527,15 @@ class LMTrainContext:
     def apply(self, params, tokens) -> jax.Array:
         with self.mesh:
             return self._forward(params, tokens)
+
+    def apply_diffusion(self, params, noisy, clean) -> jax.Array:
+        """A block-diffusion model's training forward: the logits [B, S, V] of
+        the noisy rows of `[noisy ‖ clean]` (`transformer.diffusion_forward`;
+        `apply` is its plain forward, one copy under the block-causal mask)."""
+        if self.config.diffusion_block is None:
+            raise ValueError("apply_diffusion needs a block-diffusion model (diffusion_block)")
+        with self.mesh:
+            return self._forward_diffusion(params, noisy, clean)
 
     def apply_mtp(self, params, tokens, next_tokens) -> jax.Array:
         """The multi-token-prediction module's logits [B, S, V] for the token

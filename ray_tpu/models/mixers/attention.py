@@ -19,12 +19,21 @@ such a model the gate, `wo` and the residual add lie under one more scope,
 `layer_windows` the core lies under one more scope, `attn/window` or
 `attn/full`, which tells a trace the two kinds of layer apart.
 
+In a block-diffusion model (`diffusion_block` B: generation by masked
+diffusion inside blocks of B tokens, left to right between blocks) the mask
+is not causal: the core takes `ops.attention.BlockDiffusion(B, noisy_rows)`
+under one more scope, `attn/block_diffusion`.  `noisy_rows` 0 is the plain
+forward, block-causal over one copy of the sequence (what a sampler's pass
+over the finished blocks and the current one is); `noisy_rows` S is the
+training step's `[x_t ‖ x_0]`, 2S rows whose `positions` are `[0..S-1,
+0..S-1]`: a noisy token rotates as the clean token it stands for.
+
 The core dispatches to the pallas flash kernel when lowered for TPU (under
 shard_map when there is a mesh), the XLA forms otherwise
 (ray_tpu.ops.attention), or ring attention when the mesh has a nontrivial
 `seq` axis.  The ring takes no `window`, no rope of a layer's own, no
-`attention_scale` and no per-head norm: `placement` refuses the pairing by
-name when configuration, rules and mesh first meet.
+`attention_scale`, no per-head norm and no block-diffusion mask: `placement`
+refuses the pairing by name when configuration, rules and mesh first meet.
 """
 
 from __future__ import annotations
@@ -39,7 +48,7 @@ from ray_tpu.models.mixers.base import (
     Leaf, Mixer, constrainer, fitting_axis, joined, may_ring, norm_scale, normal, out_scale, proj_scale,
     refuse_attn_bias, ring_axis, rms_norm, stream_norm,
 )
-from ray_tpu.ops.attention import dot_product_attention
+from ray_tpu.ops.attention import BlockDiffusion, dot_product_attention
 from ray_tpu.ops.rotary import Rope, apply_rope
 from ray_tpu.util import tracing
 
@@ -76,6 +85,7 @@ def placement(config, rules, mesh) -> None:
         ("rope of a layer's own (layer_ropes)", c.layer_ropes is not None),
         ("attention_scale", c.attention_scale is not None),
         ("per-head QK-norm (qk_norm 'per_head')", c.qk_norm == "per_head"),
+        ("block-diffusion mask (diffusion_block)", c.diffusion_block is not None),
     ) if there]
     if has:
         raise ValueError("ring attention takes no " + ", no ".join(has)
@@ -83,9 +93,11 @@ def placement(config, rules, mesh) -> None:
 
 
 def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, data=None, shared=None, emit=False,
-        rope=None):
+        rope=None, noisy_rows=None):
     """The attention half of a layer; `rope` is the layer's own rotary
-    embedding (None: the model's `rope_theta`)."""
+    embedding (None: the model's `rope_theta`); `noisy_rows` (a
+    block-diffusion model's alone) how many of x's first rows are the noisy
+    copy: 0 in the plain forward, half of them in a training step."""
     del data, shared, emit
     c, dt, p = config, config.dtype, layer_params["attn"]
     constrain = constrainer(rules, mesh)
@@ -126,6 +138,10 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     # the layer's kind, named only in a model that has two
     kind = (contextlib.nullcontext() if c.layer_windows is None
             else tracing.scope("attn/full" if window is None else "attn/window"))
+    masked = {} if window is None else {"window": window}
+    if c.diffusion_block is not None:  # the mask that takes the causal one's place, and its name in a trace
+        kind = tracing.scope("attn/block_diffusion")
+        masked = {"block_diffusion": BlockDiffusion(c.diffusion_block, noisy_rows or 0)}
     with tracing.scope("layer/attn_core"), kind:
         if seq_axis is not None:
             # Sequence parallelism: activations are seq-sharded, so full
@@ -146,8 +162,7 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
             attn = dot_product_attention(
                 q, kk, vv, causal=True, scale=c.attention_scale, impl=c.attention_impl,
                 mesh=mesh if rules is not None else None,
-                batch_axes=batch_axes, head_axis=head_ax,
-                **({} if window is None else {"window": window}),
+                batch_axes=batch_axes, head_axis=head_ax, **masked,
             )
     # `attn/gate` holds the gate AND `wo`: XLA fuses the gate's pass into the projection's operand, and a fusion
     # carries one name (its root's), so a scope around the gate alone would reach no op of a trace

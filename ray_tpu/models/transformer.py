@@ -35,7 +35,12 @@ module behind the trunk (`mtp_depth`: `mtp_rows`, weighed into the loss by
 models/lm.py); and Qwen3-Next: "gdn" layers 3:1 with softmax attention whose
 heads rotate a part of themselves (`rotary_dim`) and whose output passes a
 sigmoid gate (`attn_output_gate`), zero-centred norms (`norm_zero_centred`)
-and a shared expert behind a scalar gate (`shared_expert_gate`).
+and a shared expert behind a scalar gate (`shared_expert_gate`); and SDAR, a
+block-diffusion model over Qwen3-MoE's block (`diffusion_block`: attention is
+causal BETWEEN blocks of that many tokens and two-sided inside one; `forward`
+runs one copy of a sequence under that mask, `trunk(..., noisy=x_t)` and
+`diffusion_forward` the training step's noisy copy beside the clean one, whose
+objective is models/lm.py's).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -307,6 +312,18 @@ class TransformerConfig:
     # cross entropy in the loss (models/lm.py).
     mtp_depth: int = 0
     mtp_loss_weight: float = 0.0
+    # Block diffusion (BD3-LMs, arXiv:2503.09573; SDAR, arXiv:2510.06303): the
+    # sequence is cut into blocks of `diffusion_block` tokens, generated left
+    # to right, the tokens inside a block by masked diffusion (None = a
+    # next-token model).  Every layer is "attention" under the block-diffusion
+    # mask (ops/attention.py `BlockDiffusion`) and the objective is the
+    # block-diffusion NELBO of models/lm.py: `diffusion_mask_id` is laid over
+    # the masked tokens (None = the table's last row) and a block's share of
+    # them t is drawn from [`diffusion_eps`, 1]; the draw's key is the train
+    # state's (`lm.LMTrainContext.init_state`: one seed governs a run).
+    diffusion_block: Optional[int] = None
+    diffusion_mask_id: Optional[int] = None
+    diffusion_eps: float = 1e-3
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -340,6 +357,18 @@ class TransformerConfig:
             if last.reads or last.source is not None:
                 raise ValueError(f"mtp_depth: the module's block is one more {last.name} layer, a kind that crosses layers "
                                  f"({last.source or last.reads}); it runs behind the trunk and can neither read nor hand on")
+        if self.diffusion_block is not None:
+            others = sorted(set(self.layer_types or ()) - {_DEFAULT_MIXER})
+            has = [name for name, there in (
+                (f"layer_types {others}", bool(others)),
+                ("layer_windows", self.layer_windows is not None and any(w is not None for w in self.layer_windows)),
+                ("mtp_depth", bool(self.mtp_depth)),
+            ) if there]
+            if has or self.diffusion_block < 1 or not 0.0 < self.diffusion_eps <= 1.0:
+                raise ValueError(
+                    f"diffusion_block={self.diffusion_block} (>= 1; diffusion_eps={self.diffusion_eps} in (0, 1]) puts the "
+                    f"block-diffusion mask in the causal one's place in every layer, all of them '{_DEFAULT_MIXER}': it takes no "
+                    + ", no ".join(has or ["other kind of layer"]))
         if self.qk_norm not in (False, True, "per_head"):
             raise ValueError(f"qk_norm is False, True (over the whole projection) or 'per_head', got {self.qk_norm!r}")
         if self.norm_kind not in ("rms", "layer"):
@@ -404,6 +433,11 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads if self.attn_head_dim is None else self.attn_head_dim
+
+    @property
+    def mask_id(self) -> int:
+        """The id a block-diffusion step lays over its masked tokens."""
+        return self.vocab_size - 1 if self.diffusion_mask_id is None else self.diffusion_mask_id
 
     def layer_variant(self, i: int) -> Tuple[Optional[int], Optional[Rope], bool]:
         """What of layer i is STATIC beside its pair, so that a run of one
@@ -674,15 +708,18 @@ def layer(
     shared: Optional[Dict] = None,
     emit: bool = False,
     rope: Optional[Rope] = None,
+    noisy_rows: Optional[int] = None,
 ):
     """One layer of any kind, the kind's `mix` and then the FFN half: (x,
     this layer's router statistics, None when the FFN is dense; what it hands
     on to later layers, by name).  `ffn` is the layer's kind of FFN (None:
     what the configuration's every layer has); the rest is `Mixer.mix`'s,
-    `rope` given only where the layer has one of its own."""
+    `rope` given only where the layer has one of its own, `noisy_rows` only in
+    a block-diffusion model (how many of x's first rows are the noisy copy)."""
     x, handed = mixer.mix(x, layer_params, positions, config, rules, mesh,
                           window=window, data=data, shared=shared, emit=emit,
-                          **({} if rope is None else {"rope": rope}))
+                          **({} if rope is None else {"rope": rope}),
+                          **({} if noisy_rows is None else {"noisy_rows": noisy_rows}))
     return (*_ffn_half(x, layer_params, config, _constrainer(rules, mesh), rules, mesh, ffn), handed)
 
 
@@ -753,6 +790,9 @@ def _refuse_unequal_stages(config: TransformerConfig) -> None:
             "strategy 'pp' runs dense layers only: the router statistics of "
             "an expert layer do not come out of the pipeline schedule"
         )
+    if c.diffusion_block is not None:
+        raise ValueError("strategy 'pp' runs next-token models only: a block-diffusion step (diffusion_block) sends a noisy "
+                         "and a clean copy of each sequence through the stack under a mask of its own")
     if (c.layer_types is not None or c.ffn_types is not None or c.layer_windows is not None
             or c.layer_ropes is not None or c.qk_norm == "per_head"):
         default, *others = MIXERS
@@ -869,7 +909,9 @@ def forward(
 ) -> jax.Array:
     """Token ids [B, S] -> logits [B, S, vocab] (f32): `trunk`, then the
     head's matmul in the model's dtype, widened.  (The training objective
-    never forms these: `lm.head_cross_entropy` takes the trunk's output.)
+    never forms these: `lm.head_cross_entropy` takes the trunk's output.)  In a
+    block-diffusion model this is the plain forward: one copy of the sequence
+    under the block-causal mask, row i the logits of token i (no shift).
 
     `rules` come with the `mesh` they refer to: ring attention, the pipeline
     schedule and the flash kernel's shard_map are all built from it."""
@@ -886,17 +928,36 @@ def trunk(
     *,
     rules: Optional[Rules] = None,
     mesh=None,
+    noisy: Optional[jax.Array] = None,
 ):
     """Everything up to the head's matmul: (the rows that enter the head,
     [B, S, d] in `config.dtype`, normed and divided by `logits_scaling`; the
     head [d, vocab] in `config.dtype`, the embedding table transposed when
     tied; router statistics stacked over the layers, `[L, ...]` each, as
-    `moe.router_losses` takes them, None for a dense model)."""
+    `moe.router_losses` takes them, None for a dense model).
+
+    `noisy` [B, S] (a block-diffusion model's training step): the noisy copy
+    x_t of `tokens`.  The stack then runs the 2S rows `[x_t ‖ x_0]` at the
+    positions `[0..S-1, 0..S-1]` under the block-diffusion mask, every layer
+    and the router statistics over all of them, and the rows returned are the
+    NOISY half's, [B, S, d]."""
     c = config
     if rules is not None and mesh is None:
         raise ValueError("forward(rules=...) needs the mesh the rules refer to")
+    seq = tokens.shape[1]
+    diffusion = {}  # what a block-diffusion model's layers are told, and no other's
+    if c.diffusion_block is not None:
+        if seq % c.diffusion_block:
+            raise ValueError(f"diffusion_block={c.diffusion_block} does not divide the sequence's {seq} tokens")
+        diffusion = {"noisy_rows": 0 if noisy is None else seq}
+    if noisy is not None:
+        if c.diffusion_block is None or noisy.shape != tokens.shape:
+            raise ValueError("trunk(noisy=...) is a block-diffusion model's (diffusion_block), the noisy copy shaped as the tokens")
+        tokens = jnp.concatenate([noisy, tokens], axis=1)
     x = _embed(params, tokens, c, rules, mesh)
-    positions = jnp.arange(tokens.shape[1])
+    positions = jnp.arange(seq)
+    if noisy is not None:
+        positions = jnp.concatenate([positions, positions])
     pp = pipeline_axes(rules, mesh, c.n_layers)
     # `layers` names what the loop over the stack itself costs (each layer's
     # weights sliced out of the stack, gradients and residuals stacked back);
@@ -923,7 +984,7 @@ def trunk(
                 window, rope, emit = c.layer_variant(start)
 
                 layer_fn = functools.partial(layer, mixer, positions=positions, config=c, rules=rules, mesh=mesh,
-                                             ffn=ffn, window=window, emit=emit, rope=rope)
+                                             ffn=ffn, window=window, emit=emit, rope=rope, **diffusion)
                 if c.remat:
                     layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(c))
 
@@ -949,6 +1010,8 @@ def trunk(
             elif per_run:
                 router_stats = jax.tree_util.tree_map(lambda *a: jnp.concatenate(a, axis=0), *per_run)
     with tracing.scope("final_norm"):
+        if noisy is not None:
+            x = x[:, :seq]  # the head reads the noisy half alone
         x = stream_norm(c, x, params, "final_norm")
     with tracing.scope("lm_head"):
         head = (
@@ -1015,5 +1078,19 @@ def mtp_forward(params: Dict, tokens: jax.Array, next_tokens: jax.Array, config:
     h, head, _ = trunk(params, tokens, config, rules=rules, mesh=mesh)
     x, _ = mtp_rows(params, h, next_tokens, config, rules=rules, mesh=mesh)
     with tracing.scope("mtp"), tracing.scope("lm_head"):
+        logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
+        return _constrainer(rules, mesh)(logits, LOGITS_AXES)
+
+
+def diffusion_forward(params: Dict, noisy: jax.Array, clean: jax.Array, config: TransformerConfig, *,
+                      rules: Optional[Rules] = None, mesh=None) -> jax.Array:
+    """A block-diffusion model's TRAINING forward: the noisy copy x_t and the
+    clean copy x_0 of each sequence, [B, S] both -> the logits of the noisy
+    rows [B, S, vocab] (f32), row i what the model makes of token i given its
+    own block's noisy tokens and the clean blocks before: `trunk(noisy=...)`
+    and the head, as the objective runs them (it forms no logits:
+    `lm.head_weighted_cross_entropy` takes the rows)."""
+    x, head, _ = trunk(params, clean, config, rules=rules, mesh=mesh, noisy=noisy)
+    with tracing.scope("lm_head"):
         logits = jnp.einsum("bse,ev->bsv", x, head).astype(jnp.float32)
         return _constrainer(rules, mesh)(logits, LOGITS_AXES)

@@ -15,12 +15,29 @@ w, the query's own position counted: query i sees keys i - w + 1 .. i.  All
 three forms take it: `reference` and `blockwise` as a second term of their
 masks, the flash kernels as tiles never visited (ops/pallas/flash_attention.py).
 Ring attention does not (models/mixers/attention.py refuses the pairing by name).
+
+A `block_diffusion` (`BlockDiffusion(block, noisy)`; None = none; REPLACES the
+causal mask and excludes a window; equal sequence lengths) is the mask of a
+block-diffusion model (BD3-LMs, arXiv:2503.09573): positions are cut into
+blocks of `block`, b(i) = i // block.  The first `noisy` rows of the call are
+the noisy copy x_t of a sequence and the rest its clean copy x_0, both at
+positions 0.. (a row's position is its index in its own copy):
+
+    noisy query -> noisy key  iff b(j) == b(i)    (two-sided inside its block)
+    noisy query -> clean key  iff b(j) <  b(i)    (the finished blocks before it)
+    clean query -> clean key  iff b(j) <= b(i)    (block-causal)
+    clean query -> noisy key  never
+
+`noisy=0` is the plain forward of such a model, block-causal over one copy;
+`noisy = rows / 2` is its training step over `[x_t ‖ x_0]`.  All three forms
+take it; the flash kernels leave out the tile pairs no query sees.  The ring
+does not (refused by name in the mixer's `placement`).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +56,29 @@ NEG_INF = -1e30
 # backward re-runs the whole forward for the one it lacks.
 ATTN_OUT = "attn"
 ATTN_LSE = "attn_lse"
+
+
+class BlockDiffusion(NamedTuple):
+    """The block-diffusion mask of a call (module docstring): the block
+    length, and how many of the call's first rows are the noisy copy."""
+    block: int
+    noisy: int = 0
+
+    def check(self, sq: int, sk: int) -> None:
+        clean = sq - self.noisy
+        if sq != sk or self.block < 1 or 2 * self.noisy not in (0, sq) or clean % self.block:
+            raise ValueError(f"{self} needs equal sequence lengths (got {sq}, {sk}), the noisy rows none or the first "
+                             f"half of them, and a block length that divides a copy's {clean} positions")
+
+
+def _seen_block_diffusion(qrow: jax.Array, krow: jax.Array, bd: BlockDiffusion) -> jax.Array:
+    """bool [q, k] from the rows' indices in the call: the three rules of the
+    module docstring (a clean query sees no noisy key)."""
+    q_noisy, k_noisy = qrow < bd.noisy, krow < bd.noisy
+    qb = (jnp.where(q_noisy, qrow, qrow - bd.noisy) // bd.block)[:, None]
+    kb = (jnp.where(k_noisy, krow, krow - bd.noisy) // bd.block)[None, :]
+    q_noisy, k_noisy = q_noisy[:, None], k_noisy[None, :]
+    return jnp.where(k_noisy, q_noisy & (kb == qb), jnp.where(q_noisy, kb < qb, kb <= qb))
 
 
 def _seen(qpos: jax.Array, kpos: jax.Array, window: Optional[int]) -> jax.Array:
@@ -68,17 +108,22 @@ def reference_attention(
     scale: Optional[float] = None,
     q_offset: int = 0,
     window: Optional[int] = None,
+    block_diffusion: Optional[BlockDiffusion] = None,
 ) -> jax.Array:
     """O(S^2) materialized-scores attention. Ground truth for tests.
 
     q_offset: absolute position of q[0] relative to k[0] (decode/ring steps).
+    block_diffusion: the module docstring's mask, in `causal`'s place.
     """
     b, sq, h, d = q.shape
     k = _repeat_kv(k, h)
     v = _repeat_kv(v, h)
     scale = scale if scale is not None else d ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
-    if causal:
+    if block_diffusion is not None:
+        block_diffusion.check(sq, k.shape[1])
+        logits = jnp.where(_seen_block_diffusion(jnp.arange(sq), jnp.arange(sq), block_diffusion), logits, NEG_INF)
+    elif causal:
         sk = k.shape[1]
         logits = jnp.where(_seen(jnp.arange(sq) + q_offset, jnp.arange(sk), window), logits, NEG_INF)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -95,6 +140,7 @@ def blockwise_attention(
     block_size: int = 512,
     q_offset: int = 0,
     window: Optional[int] = None,
+    block_diffusion: Optional[BlockDiffusion] = None,
 ) -> jax.Array:
     """Flash-attention semantics in pure JAX: scan over KV blocks with an
     online softmax, never materializing the [S, S] score matrix.  XLA keeps
@@ -108,6 +154,8 @@ def blockwise_attention(
     v = _repeat_kv(v, h)
     sk, dv = k.shape[1], v.shape[-1]
     scale = scale if scale is not None else d ** -0.5
+    if block_diffusion is not None:
+        block_diffusion.check(sq, sk)
     if sk % block_size != 0:
         block_size = sk  # fall back to one block rather than pad
     n_blocks = sk // block_size
@@ -124,7 +172,9 @@ def blockwise_attention(
         acc, m, l = carry
         kb, vb, kpos = blk
         logits = jnp.einsum("bqhd,bkhd->bhqk", qf, kb)
-        if causal:
+        if block_diffusion is not None:
+            logits = jnp.where(_seen_block_diffusion(qpos, kpos, block_diffusion)[None, None], logits, NEG_INF)
+        elif causal:
             logits = jnp.where(_seen(qpos, kpos, window)[None, None], logits, NEG_INF)
         m_blk = jnp.max(logits, axis=-1)
         m_new = jnp.maximum(m, m_blk)
@@ -160,6 +210,7 @@ def dot_product_attention(
     batch_axes=None,
     head_axis: Optional[str] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[BlockDiffusion] = None,
 ) -> jax.Array:
     """Dispatching attention entry point used by models/.
 
@@ -171,24 +222,28 @@ def dot_product_attention(
     The choice is `kernel_pair.dispatch`'s, so it follows the platform a step
     is compiled for, not the process's default backend.
 
-    window: see the module docstring; every form takes it.
+    window, block_diffusion: see the module docstring; every form takes
+    them, one or the other.
 
     mesh / batch_axes / head_axis say how q, k, v are sharded.  GSPMD
     partitions the XLA forms by itself; a Mosaic kernel it cannot, so with
     a mesh the kernel runs under shard_map over those axes.
     """
 
-    if window is not None and not causal:
-        raise ValueError("a window needs causal=True")
-    windowed = {} if window is None else {"window": window}
+    if window is not None and (not causal or block_diffusion is not None):
+        raise ValueError("a window is the last keys of a CAUSAL mask: it needs causal=True, and no block_diffusion "
+                         "(whose mask takes the causal one's place)")
+    masked = {} if window is None else {"window": window}
+    if block_diffusion is not None:  # handed on as a window is
+        masked = {"block_diffusion": block_diffusion}
 
     def reference(q, k, v):
-        out = reference_attention(q, k, v, causal=causal, scale=scale, **windowed)
+        out = reference_attention(q, k, v, causal=causal, scale=scale, **masked)
         return checkpoint_name(out, ATTN_OUT)
 
     def blockwise(q, k, v):
         out = blockwise_attention(
-            q, k, v, causal=causal, scale=scale, block_size=block_size, **windowed
+            q, k, v, causal=causal, scale=scale, block_size=block_size, **masked
         )
         return checkpoint_name(out, ATTN_OUT)
 
@@ -196,10 +251,10 @@ def dot_product_attention(
         from ray_tpu.ops.pallas import flash_attention as fa
 
         if mesh is None:
-            return fa.flash_attention(q, k, v, causal=causal, scale=scale, **windowed)
+            return fa.flash_attention(q, k, v, causal=causal, scale=scale, **masked)
         return fa.flash_attention_sharded(
             q, k, v, mesh, batch_axes=batch_axes, head_axis=head_axis,
-            causal=causal, scale=scale, **windowed,
+            causal=causal, scale=scale, **masked,
         )
 
     if impl is None:

@@ -39,6 +39,22 @@ step past the last visible tile is predicated off, in all three kernels.  At
 `window=None` nothing of this is traced: grids, index maps and kernel bodies
 are the ones they were.
 
+A `block_diffusion` (`ops.attention.BlockDiffusion(block, noisy)`; static;
+None = none) puts the block-diffusion mask in the causal one's place: the
+call's first `noisy` rows are a sequence's noisy copy, the rest its clean copy
+(`noisy` 0: one copy, block-causal; `rows / 2`: a training step's doubled
+rows), and of the four quadrants of the doubled rows a query tile sees a
+block-diagonal band of the noisy keys and the clean keys up to its own blocks
+(a clean tile the second alone).  Tiles divide a COPY, so that none lies
+across both, and the grids count, as a windowed call's do, only the tiles a
+tile of the outer axis can see: two runs of them (`_diffusion_ranges`), walked
+one after the other, the index held on the last visible tile through the
+steps past it, which the kernels predicate off (80 of a head's 256 forward
+tile pairs are visited at 2 x 8,192 rows, blocks of 4 and 1024-tiles, 9 inner
+steps where 16 would span the rows).  The two boundary kinds of tile are
+masked inside from the rows' indices alone.  At `block_diffusion=None` nothing
+of this is traced either.
+
 Which form of a kernel runs is decided by the platform the enclosing program
 is LOWERED for (`jax.lax.platform_dependent`), never by the process-global
 default backend: a TPU lowering gets the Mosaic kernel, a CPU lowering gets
@@ -64,7 +80,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, _repeat_kv
+from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT, BlockDiffusion, _repeat_kv
 from ray_tpu.util import tracing
 
 NEG_INF = -1e30
@@ -132,6 +148,112 @@ def _window_mask(logits, qpos, kpos, window):
     if window is not None:
         seen = seen & (qpos - kpos < window)
     return jnp.where(seen, logits, NEG_INF)
+
+
+# -- the block-diffusion mask ---------------------------------------------------
+
+
+def _pick(cond, a, b):
+    """a if cond else b, of traced grid indices or Python ints."""
+    traced = any(isinstance(x, jax.Array) for x in (cond, a, b))
+    return jnp.where(cond, a, b) if traced else (a if cond else b)
+
+
+def _diffusion_ranges(i, own: int, other: int, rows: int, bd: BlockDiffusion, *, keys: bool):
+    """The tiles of the OTHER sequence that tile `i` (of `own` rows) can see
+    under the block-diffusion mask, as two runs (first, count, first, count),
+    a count possibly 0: with `keys`, i is a query tile and the runs are key
+    tiles (of `other` rows): the noisy keys of its own blocks, then the clean
+    keys up to its blocks; without, i is a key tile and the runs are the noisy
+    and then the clean query tiles that see it.  `i` may be a traced grid
+    index or a Python int.  Tiles divide a copy, or are the whole call."""
+    if own == rows:  # one tile of every row sees, and is seen by, every tile
+        return 0, rows // other, 0, 0
+    if other == rows:
+        return 0, 1, 0, 0
+    block, clean = bd.block, rows - bd.noisy
+    n_noisy, n_clean = bd.noisy // other, clean // other  # tiles of the other sequence in each copy
+    noisy = i < bd.noisy // own
+    first_pos = (i - _pick(noisy, 0, bd.noisy // own)) * own  # the tile's positions in its copy
+    b0, b1 = first_pos // block, (first_pos + own - 1) // block  # its first and last block
+    own_first, own_last = (b0 * block) // other, ((b1 + 1) * block - 1) // other  # the tiles that hold its blocks
+    if keys:
+        # a noisy query: the noisy keys of its blocks; the clean keys of the blocks BEFORE its last (ceil of b1 * block / other tiles);
+        # a clean query: no noisy key; the clean keys up to its last block
+        return (own_first, _pick(noisy, own_last - own_first + 1, 0),
+                n_noisy, ((b1 + _pick(noisy, 0, 1)) * block + other - 1) // other)
+    # a noisy key: the noisy queries of its blocks, no clean one; a clean key: the noisy queries of the blocks AFTER its
+    # first, and the clean queries from its first block on
+    after = _lower(((b0 + 1) * block) // other, n_noisy)
+    return (_pick(noisy, own_first, after), _pick(noisy, own_last - own_first + 1, n_noisy - after),
+            n_noisy + own_first, _pick(noisy, 0, n_clean - own_first))
+
+
+def _diffusion_step(i, j, own: int, other: int, rows: int, bd: BlockDiffusion, *, keys: bool):
+    """(the other sequence's tile at grid step (i, j), whether the step
+    runs): the two runs of `_diffusion_ranges` one after the other, and past
+    them the last visible tile, held, so that the pipeline copies nothing."""
+    a1, n1, a2, n2 = _diffusion_ranges(i, own, other, rows, bd, keys=keys)
+    second = _pick(n2 > 0, a2 + _lower(j - n1, n2 - 1), a1 + n1 - 1)
+    return _pick(j < n1, a1 + j, second), j < n1 + n2
+
+
+def _diffusion_visible(n_own: int, own: int, other: int, rows: int, bd: BlockDiffusion, *, keys: bool) -> list:
+    """How many tiles of the other sequence each tile sees; the most of them
+    is the innermost grid dimension of a block-diffusion call."""
+    ranges = (_diffusion_ranges(i, own, other, rows, bd, keys=keys) for i in range(n_own))
+    return [n1 + n2 for _, n1, _, n2 in ranges]
+
+
+def _diffusion_mask(logits, q_start, k_start, bd: BlockDiffusion):
+    """The three rules inside a tile, from the rows' indices in the call
+    (`ops.attention._seen_block_diffusion`), worked out on a column of query
+    rows and a row of key rows and joined by two compares of integers (Mosaic
+    selects no booleans): a clean key stands for its block, a noisy key for
+    its block + `_NOISY`; a query sees the clean blocks up to its own (a noisy
+    query: before its own) and the one noisy value that is its own block's (a
+    clean query: none, -1)."""
+    bq, bk = logits.shape
+    qrow = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    krow = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    q_noisy, k_noisy = qrow < bd.noisy, krow < bd.noisy
+    qb = _block_of(jnp.where(q_noisy, qrow, qrow - bd.noisy), bd.block)
+    kb = _block_of(jnp.where(k_noisy, krow, krow - bd.noisy), bd.block)
+    key = jnp.where(k_noisy, kb + _NOISY, kb)
+    seen = (key <= jnp.where(q_noisy, qb - 1, qb)) | (key == jnp.where(q_noisy, qb + _NOISY, -1))
+    return jnp.where(seen, logits, NEG_INF)
+
+
+_NOISY = 1 << 30  # what tells a noisy key's block from every clean one's in `_diffusion_mask`
+
+
+def _block_of(pos, block: int):
+    """pos // block of non-negative int32 positions: a shift where the block length is a power of two."""
+    if block & (block - 1) == 0:
+        return jnp.right_shift(pos, block.bit_length() - 1)
+    return jax.lax.div(pos, jnp.int32(block))
+
+
+def _diffusion_blocks(rows: int, bd: BlockDiffusion, blocks):
+    """A block-diffusion call's tiles: each the whole call where the call is
+    no longer than the request, else the request fitted to a COPY's rows
+    (`_fit_block`), so that no tile lies across the two copies."""
+    copy = rows - bd.noisy
+    return tuple(rows if rows <= b else _fit_block(copy, b) for b in blocks)
+
+
+def diffusion_mask_fill_pct(seq: int, block: int, d: int, dv: int) -> Optional[float]:
+    """The pairs the training mask of `seq` positions in blocks of `block`
+    holds (seq^2 + seq * block over the doubled rows: the clean copy's
+    block-causal half-square, the noisy copy's diagonal blocks and its view of
+    the clean blocks before) as % of the pairs of the tiles the FORWARD kernel
+    visits, from the block sizes in use; None at a length no tile divides."""
+    bd = BlockDiffusion(block, seq)
+    bq, bk = _diffusion_blocks(2 * seq, bd, _head_blocks(d, dv, DEFAULT_BLOCKS)[:2])
+    if bq is None or bk is None:
+        return None
+    visited = sum(_diffusion_visible(2 * seq // bq, bq, bk, 2 * seq, bd, keys=True))
+    return 100.0 * (seq * seq + seq * block) / (visited * bq * bk)
 
 
 def window_tiles_visited_pct(seq: int, window: int) -> Optional[float]:
@@ -205,7 +327,8 @@ def _head_blocks(d: int, dv: int, blocks):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, scale: float, causal: bool, window: Optional[int] = None,
+    *, scale: float, causal: bool, window: Optional[int] = None, diffusion: Optional[BlockDiffusion] = None,
+    rows: Optional[int] = None,
 ):
     # Blocks: q [1, 1, bq, D]; k [1, 1, bk, D]; v [1, 1, bk, Dv]; o [1, 1, bq, Dv];
     # lse [1, 1, bq, 1].  Scratch (carried across the kv grid dim): acc [bq, Dv] f32,
@@ -227,6 +350,9 @@ def _fwd_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     run = (k_start <= q_start + bq - 1) if causal else True
+    if diffusion is not None:  # the grid walks the visible key tiles, noisy then clean
+        k_tile, run = _diffusion_step(qi, ki, bq, bk, rows, diffusion, keys=True)
+        k_start = k_tile * bk
 
     @pl.when(run)
     def _step():
@@ -240,6 +366,8 @@ def _fwd_kernel(
             qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             logits = _window_mask(logits, qpos, kpos, window)
+        if diffusion is not None:
+            logits = _diffusion_mask(logits, q_start, k_start, diffusion)
         m_prev = m_ref[:, :1]  # [bq, 1]
         l_prev = l_ref[:, :1]
         m_blk = jnp.max(logits, axis=-1, keepdims=True)  # [bq, 1]
@@ -261,7 +389,7 @@ def _fwd_kernel(
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l_safe)
 
 
-def _inner_tile(n_own, n_other, own, other, window, *, keys, causal):
+def _inner_tile(n_own, n_other, own, other, window, *, keys, causal, diffusion=None):
     """(innermost grid extent, index of the other sequence's tile at grid
     step (i, j)) of a call whose outer tiles are `own` wide: every tile and
     the step itself without a window and without a mask; with one, the
@@ -273,7 +401,12 @@ def _inner_tile(n_own, n_other, own, other, window, *, keys, causal):
     the last visible key tile (`keys`: the off steps come last), on the first
     visible query tile (the off steps come first; held inside the sequence
     for a call with more keys than queries).  The bounds are the ones the
-    kernels' `run` predicates compare with."""
+    kernels' `run` predicates compare with.  A block-diffusion call counts
+    its visible tiles too, two runs of them (`_diffusion_step`)."""
+    if diffusion is not None:
+        rows = n_own * own
+        return (max(_diffusion_visible(n_own, own, other, rows, diffusion, keys=keys)),
+                lambda i, j: _diffusion_step(i, j, own, other, rows, diffusion, keys=keys)[0])
     if window is not None:
         first = functools.partial(_first_visible, own=own, other=other, window=window, keys=keys)
         return (max(_visible(n_own, n_other, own, other, window, keys=keys)),
@@ -287,7 +420,7 @@ def _inner_tile(n_own, n_other, own, other, window, *, keys, causal):
     return n_other, lambda i, j: _lower(_higher(j, (i * own) // other), n_other - 1)
 
 
-def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None):
+def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None, diffusion=None):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[-1]  # q and k share one head size, v and the output another
     # Kernels work in [B, H, S, D].
@@ -296,9 +429,9 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None):
     vt = v.transpose(0, 2, 1, 3)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True, causal=causal)
+    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True, causal=causal, diffusion=diffusion)
     grid = (b, h, sq // block_q, n_k)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, window=window)
+    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, window=window, diffusion=diffusion, rows=sq)
     out, lse = _pallas_call(
         kernel,
         name="flash_fwd",
@@ -330,7 +463,8 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None):
 
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
-    *, scale: float, causal: bool, window: Optional[int] = None,
+    *, scale: float, causal: bool, window: Optional[int] = None, diffusion: Optional[BlockDiffusion] = None,
+    rows: Optional[int] = None,
 ):
     # q/dq [1, 1, bq, D]; k [1, 1, bk, D]; v [1, 1, bk, Dv]; do [1, 1, bq, Dv];
     # lse/delta [1, 1, bq, 1].
@@ -349,6 +483,9 @@ def _bwd_dq_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     run = (k_start <= q_start + bq - 1) if causal else True
+    if diffusion is not None:  # as the forward: the visible key tiles, noisy then clean
+        k_tile, run = _diffusion_step(qi, ki, bq, bk, rows, diffusion, keys=True)
+        k_start = k_tile * bk
 
     @pl.when(run)
     def _step():
@@ -365,6 +502,8 @@ def _bwd_dq_kernel(
             qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             logits = _window_mask(logits, qpos, kpos, window)
+        if diffusion is not None:
+            logits = _diffusion_mask(logits, q_start, k_start, diffusion)
         p = jnp.exp(logits - lse)  # masked -> exp(-inf) = 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -383,6 +522,7 @@ def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref,
     *, scale: float, causal: bool, window: Optional[int] = None, q_tiles: Optional[int] = None,
+    diffusion: Optional[BlockDiffusion] = None, rows: Optional[int] = None,
 ):
     # Grid (b, h, kv_tile, q_tile) — q innermost so k/v blocks stay resident.
     # k/dk [1, 1, bk, D]; v/dv [1, 1, bk, Dv]; q [1, 1, bq, D]; do [1, 1, bq, Dv];
@@ -405,6 +545,9 @@ def _bwd_dkv_kernel(
     run = (q_start + bq - 1 >= k_start) if causal else True
     if window is not None:  # neither past the newest query of the window nor past the sequence
         run = run & (q_start <= k_start + bk - 1 + window - 1) & (q_start < q_tiles * bq)
+    if diffusion is not None:  # the query tiles that see this key tile, noisy then clean
+        q_tile, run = _diffusion_step(ki, qi, bk, bq, rows, diffusion, keys=False)
+        q_start = q_tile * bq
 
     @pl.when(run)
     def _step():
@@ -421,6 +564,8 @@ def _bwd_dkv_kernel(
             qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             logits = _window_mask(logits, qpos, kpos, window)
+        if diffusion is not None:
+            logits = _diffusion_mask(logits, q_start, k_start, diffusion)
         p = jnp.exp(logits - lse)
         dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -440,13 +585,13 @@ def _bwd_dkv_kernel(
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=None):
+def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=None, diffusion=None):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True, causal=causal)
-    n_q, q_tile = _inner_tile(sk // block_k, sq // block_q, block_k, block_q, window, keys=False, causal=causal)
+    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True, causal=causal, diffusion=diffusion)
+    n_q, q_tile = _inner_tile(sk // block_k, sq // block_q, block_k, block_q, window, keys=False, causal=causal, diffusion=diffusion)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -456,7 +601,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=N
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     ).transpose(0, 2, 1)[..., None]  # [B, H, Sq, 1]
 
-    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, window=window)
+    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, window=window, diffusion=diffusion, rows=sq)
     dq = _pallas_call(
         dq_kernel,
         name="flash_bwd_dq",
@@ -477,7 +622,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=N
     )(qt, kt, vt, dot, lse, delta)
 
     dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, causal=causal, window=window, q_tiles=sq // block_q)
+        _bwd_dkv_kernel, scale=scale, causal=causal, window=window, q_tiles=sq // block_q, diffusion=diffusion, rows=sq)
     dk, dv = _pallas_call(
         dkv_kernel,
         name="flash_bwd_dkv",
@@ -513,17 +658,17 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=N
 # -- custom_vjp wiring -----------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window, diffusion=None):
     out, _ = _flash_fwd(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k, window=window
+        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k, window=window, diffusion=diffusion
     )
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window):
+def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window, diffusion):
     out, lse = _flash_fwd(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k, window=window
+        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k, window=window, diffusion=diffusion
     )
     # Named so that a remat policy can keep them (ops/attention.py); any
     # other policy runs this forward again in the backward pass.  The
@@ -534,11 +679,11 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q, bwd_bl
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window, res, g):
+def _flash_vjp_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k, window, diffusion, res, g):
     q, k, v, out, lse = res
     return _flash_bwd(
         q, k, v, out, lse[..., None], g,
-        causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k, window=window,
+        causal=causal, scale=scale, block_q=bwd_block_q, block_k=bwd_block_k, window=window, diffusion=diffusion,
     )
 
 
@@ -557,6 +702,7 @@ def flash_attention(
     bwd_block_q: int = DEFAULT_BLOCKS[2],
     bwd_block_k: int = DEFAULT_BLOCKS[3],
     window: Optional[int] = None,
+    block_diffusion: Optional[BlockDiffusion] = None,
 ) -> jax.Array:
     """Flash attention, [B, S, H, D] layout, GQA via repeated kv heads.  q and
     k share one head size, v and the output may have another (latent
@@ -580,7 +726,17 @@ def flash_attention(
     sees keys i - window + 1 .. i, and a tile no query of the call sees is
     never visited (module docstring).  A windowed call gets tiles no larger
     than its window (`_window_blocks`): 512 x 512 in all three kernels at a
-    window of 512, two key tiles a query tile."""
+    window of 512, two key tiles a query tile.
+
+    `block_diffusion` (static; excludes `window`, takes `causal`'s place;
+    equal sequence lengths): the mask of a block-diffusion model over one copy
+    of a sequence or over its noisy and its clean copy (module docstring); its
+    tiles divide a copy (`_diffusion_blocks`)."""
+    if block_diffusion is not None:
+        if window is not None:
+            raise ValueError("flash_attention: block_diffusion takes the causal mask's place and has no window")
+        block_diffusion.check(q.shape[1], k.shape[1])
+        causal = False  # the kernels' causal paths are not traced: the mask and the visited tiles are the block-diffusion ones
     if window is not None:
         if not causal or q.shape[1] != k.shape[1] or window < 1:
             raise ValueError("flash_attention: a window needs causal=True, equal sequence lengths and window >= 1")
@@ -598,19 +754,22 @@ def flash_attention(
     # division).  A length no tile divides is the caller's to route
     # elsewhere (ops.attention.dot_product_attention does): answering with
     # a different algorithm here would hide that the kernel did not run.
-    blocks = (
-        _fit_block(q.shape[1], block_q),
-        _fit_block(k.shape[1], block_k),
-        _fit_block(q.shape[1], bwd_block_q),
-        _fit_block(k.shape[1], bwd_block_k),
-    )
+    if block_diffusion is not None:
+        blocks = _diffusion_blocks(q.shape[1], block_diffusion, (block_q, block_k, bwd_block_q, bwd_block_k))
+    else:
+        blocks = (
+            _fit_block(q.shape[1], block_q),
+            _fit_block(k.shape[1], block_k),
+            _fit_block(q.shape[1], bwd_block_q),
+            _fit_block(k.shape[1], bwd_block_k),
+        )
     if None in blocks:
         raise ValueError(
             f"flash_attention: no tile divides seq lengths q={q.shape[1]} "
             f"k={k.shape[1]} (requested blocks {block_q}/{block_k}, bwd "
             f"{bwd_block_q}/{bwd_block_k})"
         )
-    return _flash(q, k, v, causal, scale, *blocks, window)
+    return _flash(q, k, v, causal, scale, *blocks, window, block_diffusion)
 
 
 def flash_attention_sharded(
@@ -624,6 +783,7 @@ def flash_attention_sharded(
     causal: bool = True,
     scale: Optional[float] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[BlockDiffusion] = None,
 ) -> jax.Array:
     """flash_attention on global [B, S, H, D] arrays sharded over a mesh.
 
@@ -636,7 +796,7 @@ def flash_attention_sharded(
 
     spec = P(batch_axes, None, head_axis, None)
     qspec, kspec = _fit_spec(q.shape, spec, mesh), _fit_spec(k.shape, spec, mesh)
-    body = functools.partial(flash_attention, causal=causal, scale=scale, window=window)
+    body = functools.partial(flash_attention, causal=causal, scale=scale, window=window, block_diffusion=block_diffusion)
     return jax.shard_map(
         body,
         mesh=mesh,

@@ -40,8 +40,8 @@ from ray_tpu.ops.pallas import flash_attention as fa
 _held = fa._inner_tile
 
 
-def _every_step_its_own(*args, keys, causal):
-    n, tile = _held(*args, keys=keys, causal=causal)
+def _every_step_its_own(*args, **masks):
+    n, tile = _held(*args, **masks)
     return (n, lambda i, j: j) if args[4] is None else (n, tile)
 
 

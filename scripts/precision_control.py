@@ -15,7 +15,11 @@ errors, one a sequence.  A cell of the kind `mla_moe_decoder`
 (`glm47-flash-ep8-1chip.seq8k`) has TWO compared outputs, the main logits and
 the multi-token-prediction module's (`ctx.apply_mtp` against the reference's
 second output): every list is then the main logits' errors followed by the
-module's.  A diagnostic for PERF.md (section 6, PRs 50 and 54: the limit of the
+module's.  A cell of the kind `block_diffusion_moe_decoder`
+(`sdar-ep8-1chip.seq8k`) has two as well: the plain forward's logits, then the
+TIMED training forward's on the noisy rows (`ctx.apply_diffusion` on `[x_t ‖
+x_0]` with the builder's fixed noise, against `reference_sdar.training_logits`).
+A diagnostic for PERF.md (section 6, PRs 50 and 54: the limit of the
 harness beside both readings); no cell or metric reads it.  `--cpu-toy` runs
 the harness's rehearsal widths on the CPU (no device number).
 """
@@ -68,6 +72,17 @@ def main() -> int:
         def reference_outputs(lowered=()):
             main, module = ref.both_logits(config, params, tokens, after, last=last, lowered=lowered)
             return [*main, *module]
+    elif config["kind"] == "block_diffusion_moe_decoder":  # two too: the plain forward, then the training forward's noisy rows
+        from benchmarks.lib import reference_sdar as ref
+
+        noisy = np.asarray(ref.noise(jax.random.PRNGKey(builder.NOISE_KEY), jax.numpy.asarray(tokens),
+                                     block=builder.block_length(config), mask_id=ref.mask_id(config),
+                                     eps=float(config["assumed"]["noise_schedule"]["eps"]))[0])
+        got += [jax.device_get(ctx.apply_diffusion(params, noisy[i: i + 1], tokens[i: i + 1])[0, -last:]) for i in range(n_ref)]
+
+        def reference_outputs(lowered=()):
+            return [*ref.logits(config, params, tokens, last=last, lowered=lowered),
+                    *ref.training_logits(config, params, noisy, tokens, last=last, lowered=lowered)]
     else:
         from benchmarks.lib import reference_mellum as ref
 

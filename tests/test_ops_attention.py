@@ -168,8 +168,8 @@ def _maps_of_the_parent(monkeypatch):
 
     real = fa._inner_tile
 
-    def parents(*args, keys, causal):
-        n, tile = real(*args, keys=keys, causal=causal)
+    def parents(*args, **masks):
+        n, tile = real(*args, **masks)
         return (n, lambda i, j: j) if args[4] is None else (n, tile)
 
     monkeypatch.setattr(fa, "_inner_tile", parents)
@@ -261,3 +261,147 @@ def test_a_causal_call_with_more_keys_than_queries_keeps_its_maps_inside_the_seq
     wanted = jax.grad(lambda *a: reference_attention(*a, causal=True).sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(grads, wanted):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+
+
+# -- the block-diffusion mask (PR 62) ------------------------------------------------------
+
+
+def _explicit_block_diffusion(q, k, v, block, noisy):
+    """Softmax attention under the mask built pair by pair from the three
+    rules, in numpy: the oracle of the three forms."""
+    rows, heads = q.shape[1], q.shape[2]
+    is_noisy = np.arange(rows) < noisy
+    blk = np.where(is_noisy, np.arange(rows), np.arange(rows) - noisy) // block
+    mask = np.zeros((rows, rows), bool)
+    for i in range(rows):
+        for j in range(rows):
+            if is_noisy[i] and is_noisy[j]:
+                mask[i, j] = blk[j] == blk[i]
+            elif is_noisy[i]:
+                mask[i, j] = blk[j] < blk[i]
+            elif not is_noisy[j]:
+                mask[i, j] = blk[j] <= blk[i]
+    kk, vv = (jnp.repeat(a, heads // a.shape[2], axis=2) for a in (k, v))
+    logits = jnp.where(mask, jnp.einsum("bqhd,bkhd->bhqk", q, kk) * q.shape[-1] ** -0.5, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, axis=-1), vv), mask
+
+
+# (rows, noisy rows, pallas tiles): tiles smaller than, equal to and larger than a copy (one tile holds the call),
+# a query tile that is the whole call over key tiles that are not, and the plain forward's one copy
+_DIFFUSION_LAYOUTS = [(256, 128, (64, 64, 64, 32)), (256, 128, (128, 128, 128, 64)), (256, 128, (1024, 1024, 1024, 512)),
+                      (256, 128, (256, 128, 256, 64)), (256, 0, (64, 64, 64, 32)), (256, 0, (128, 64, 64, 128))]
+
+
+@pytest.mark.parametrize("block", [4, 32])
+@pytest.mark.parametrize("rows, noisy, tiles", _DIFFUSION_LAYOUTS)
+@pytest.mark.parametrize("form", ["reference", "blockwise", "pallas"])
+def test_the_three_forms_under_the_block_diffusion_mask_match_an_explicit_mask(form, rows, noisy, tiles, block):
+    """Forward and the three gradients of each form of the core (the Pallas
+    kernels in interpret mode) against the mask written out pair by pair."""
+    from ray_tpu.ops.attention import BlockDiffusion
+    from ray_tpu.ops.pallas.flash_attention import flash_attention
+
+    bd = BlockDiffusion(block, noisy)
+    q, k, v = _qkv(jax.random.PRNGKey(7), b=1, s=rows, h=4, kv=2, d=64)
+    weight = jax.random.normal(jax.random.PRNGKey(8), q.shape)
+    core = {
+        "reference": lambda q, k, v: reference_attention(q, k, v, block_diffusion=bd),
+        "blockwise": lambda q, k, v: blockwise_attention(q, k, v, block_size=64, block_diffusion=bd),
+        "pallas": lambda q, k, v: flash_attention(q, k, v, block_diffusion=bd, block_q=tiles[0], block_k=tiles[1],
+                                                  bwd_block_q=tiles[2], bwd_block_k=tiles[3]),
+    }[form]
+    want, want_grads = jax.value_and_grad(
+        lambda q, k, v: (_explicit_block_diffusion(q, k, v, block, noisy)[0] * weight).sum(), (0, 1, 2))(q, k, v)
+    got, got_grads = jax.jit(jax.value_and_grad(lambda q, k, v: (core(q, k, v) * weight).sum(), (0, 1, 2)))(q, k, v)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5, atol=1e-3)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("seq, block", [(8192, 4), (1024, 32), (256, 4)])
+def test_the_mask_holds_s_squared_plus_s_b_pairs_and_the_forward_visits_the_tiles_that_hold_them(seq, block):
+    """The pair count the needed FLOPs rest on, against a brute-force count at
+    a small size; every visited tile pair holds a pair and no other does
+    (80 of 256 at 2 x 8,192 rows and 1024-tiles); the counter is their ratio."""
+    from ray_tpu.ops.attention import BlockDiffusion, _seen_block_diffusion
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    bd = BlockDiffusion(block, seq)
+    bq, bk = fa._diffusion_blocks(2 * seq, bd, fa.DEFAULT_BLOCKS[:2])
+    visited = set()
+    for i in range(2 * seq // bq):
+        a1, n1, a2, n2 = fa._diffusion_ranges(i, bq, bk, 2 * seq, bd, keys=True)
+        visited |= {(i, j) for j in (*range(a1, a1 + n1), *range(a2, a2 + n2))}
+    if seq == 8192:
+        assert len(visited) == 80 and (2 * seq // bq) * (2 * seq // bk) == 256
+        assert fa._inner_tile(16, 16, bq, bk, None, keys=True, causal=False, diffusion=bd)[0] == 9
+    else:
+        mask = np.asarray(_seen_block_diffusion(jnp.arange(2 * seq), jnp.arange(2 * seq), bd))
+        assert mask.sum() == seq * seq + seq * block
+        holding = {(i, j) for i in range(2 * seq // bq) for j in range(2 * seq // bk)
+                   if mask[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()}
+        assert visited == holding
+    fill = fa.diffusion_mask_fill_pct(seq, block, 128, 128)
+    assert fill == pytest.approx(100.0 * (seq * seq + seq * block) / (len(visited) * bq * bk))
+    assert seq != 8192 or fill == pytest.approx(80.04, abs=0.01)
+
+
+@pytest.mark.parametrize("keys", [True, False])
+def test_a_block_diffusion_map_walks_the_visible_tiles_and_holds_the_last_through_the_off_steps(keys):
+    """The index map of the inner axis at the cell's shapes (forward / dq and
+    dkv): a step that runs names a tile some query of the pair sees, each once,
+    and a step that is off names the tile the step before named, so the
+    pipeline copies nothing for it."""
+    from ray_tpu.ops.attention import BlockDiffusion
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    bd, rows = BlockDiffusion(4, 8192), 16384
+    own, other = (1024, 512) if keys else (512, 1024)
+    n_inner, tile = fa._inner_tile(rows // own, rows // other, own, other, None, keys=keys, causal=False, diffusion=bd)
+    counts = fa._diffusion_visible(rows // own, own, other, rows, bd, keys=keys)
+    assert n_inner == max(counts) and sum(counts) == 160
+    for i, count in enumerate(counts):
+        named = [int(tile(i, j)) for j in range(n_inner)]
+        assert len(set(named[:count])) == count and set(named[count:]) <= {named[count - 1]}
+        assert all(bool(fa._diffusion_step(i, j, own, other, rows, bd, keys=keys)[1]) == (j < count) for j in range(n_inner))
+
+
+def test_block_diffusion_refuses_a_window_unequal_lengths_and_a_block_that_does_not_divide():
+    from ray_tpu.ops.attention import BlockDiffusion, dot_product_attention
+    from ray_tpu.ops.pallas.flash_attention import flash_attention
+
+    q, k, v = _qkv(jax.random.PRNGKey(9), b=1, s=128, h=2, d=64)
+    with pytest.raises(ValueError, match="no block_diffusion"):
+        dot_product_attention(q, k, v, window=16, block_diffusion=BlockDiffusion(4))
+    with pytest.raises(ValueError, match="has no window"):
+        flash_attention(q, k, v, window=16, block_diffusion=BlockDiffusion(4))
+    with pytest.raises(ValueError, match="equal sequence lengths"):
+        reference_attention(q, k[:, :64], v[:, :64], block_diffusion=BlockDiffusion(4))
+    with pytest.raises(ValueError, match="divides a copy"):
+        blockwise_attention(q, k, v, block_diffusion=BlockDiffusion(48))
+    with pytest.raises(ValueError, match="first\\s+half"):
+        reference_attention(q, k, v, block_diffusion=BlockDiffusion(4, 32))
+
+
+# sha256[:16] of `str(jax.make_jaxpr(grad(flash_attention(..).sum())))` at the PARENT of PR 62 (commit 757862b), `0x..`
+# addresses and `.py:<line>` blanked: a call without `block_diffusion` traces what it traced, kernel bodies included
+_PARENT_JAXPRS = {None: "fa03f709fee805ab", 96: "fb19a6cc9ad0beaa"}
+
+
+@pytest.mark.parametrize("window", [None, 96])
+def test_a_call_without_block_diffusion_traces_what_the_parent_traced(window):
+    import hashlib
+    import re
+
+    from ray_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = jnp.zeros((1, 256, 4, 64), jnp.float32)
+    k = jnp.zeros((1, 256, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window, block_q=128, block_k=128, bwd_block_q=128,
+                               bwd_block_k=64).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k))
+    text = re.sub(r"\.py:\d+", ".py", re.sub(r"0x[0-9a-f]+", "0x", text))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _PARENT_JAXPRS[window]

@@ -436,3 +436,48 @@ def test_the_delta_layers_convolution_compiles_at_both_cells_shapes_and_nothing_
     assert not re.findall(r"\[1,\d{4,},\d{4,}\]\{1,2,0", text)  # a full-size array in any order but positions-major
     got = [(o.shape, o.dtype) for o in jax.tree.leaves(compiled.out_info)]
     assert got == [(p.shape, p.dtype) for p in probe] + [(a.shape, a.dtype) for a in args]
+
+
+def test_a_block_diffusion_step_holds_the_flash_kernels_under_its_mask_and_no_rows_by_rows_array(topo):
+    """PR 62: a small block-diffusion step (640 tokens, 1,280 rows `[x_t | x_0]`, blocks of 4) compiled for the
+    described chip.  Its core is the three flash kernels under `attn/block_diffusion`, one call a layer and
+    direction (a scan body holds each once), and no float array of rows x rows, of a copy x a copy or of one by the
+    other reaches HBM: the mask is worked out inside the tiles from the rows' indices."""
+    seq = 640
+    cfg = TransformerConfig.tiny(n_heads=2, n_kv_heads=1, d_model=256, d_ff=256, attn_head_dim=128, max_seq_len=seq,
+                                 remat=True, remat_policy="qkv_attn", vocab_size=V, dtype=jnp.bfloat16, diffusion_block=4)
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=topo.devices[:1]), strategy="dp")
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((1, seq), jnp.int32, sharding=ctx.batch_sharding)
+    with _no_compile_cache(), ctx.mesh:
+        text = ctx._train_step.lower(state, {"tokens": toks, "targets": toks}).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 3 and all("attn/block_diffusion" in line for line in kernels)
+    assert all(any(name in line for line in kernels) for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    square = re.compile(rf"(?:f32|bf16)\[(?:\d+,)*(?:{seq}|{2 * seq}),(?:{seq}|{2 * seq})\]")
+    assert [(name, shape) for name, shape, _, _ in _buffers(text) if square.search(shape)] == []
+    assert "diffusion/noise" in text
+
+
+def test_the_sdar_cells_flash_kernels_compile_at_its_shapes(topo):
+    """PR 62: no new kernel, one mask no cell had.  The 16,384 rows of one 8,192-token sequence (noisy copy beside
+    clean copy), 32 q heads of 128 over 4 K/V heads, blocks of 4: tiles of 1024 (512 keys in the backward), grids
+    of 9 / 18 / 16 inner steps that walk the visible tiles alone, three custom calls forward + backward; and the
+    plain forward's block-causal call over one copy."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.attention import BlockDiffusion
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    q, kv = shaped((1, 16384, 32, 128)), shaped((1, 16384, 4, 128))
+    grad = jax.grad(lambda q, k, v, do: jnp.sum(
+        (fa.flash_attention(q, k, v, block_diffusion=BlockDiffusion(4, 8192)) * do).astype(jnp.float32)), argnums=(0, 1, 2))
+    with _no_compile_cache():
+        text = jax.jit(grad).lower(q, kv, kv, q).compile().as_text()
+        plain = jax.jit(lambda q, k, v: fa.flash_attention(q, k, v, block_diffusion=BlockDiffusion(4))).lower(
+            shaped((1, 8192, 32, 128)), shaped((1, 8192, 4, 128)), shaped((1, 8192, 4, 128))).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert all(name in text for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    assert plain.count('custom_call_target="tpu_custom_call"') == 1 and "flash_fwd" in plain
