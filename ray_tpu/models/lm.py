@@ -192,13 +192,15 @@ def diffusion_noise(key: jax.Array, tokens: jax.Array, *, block: int, mask_id: i
 # busiest expert's load over the mean, and the share of the T*K assignments whose rows the share's buffers moved);
 # the multi-token-prediction module's cross entropy; a block-diffusion step's two: the share of the sequence's
 # tokens the noise masked (0.5 in expectation) and the mask's pairs over the pairs of the tiles the flash forward
-# visits (a constant of the traced step, like the two of PR 55).
+# visits (a constant of the traced step, like the two of PR 55); and the share of a flash forward's run steps whose tile
+# the mask's edge does not cross, which run the body without the mask (PR 63; a constant of the traced step too).
 WINDOW_TILES = "attn_window_tiles_visited_pct"
 CAUSAL_STEPS = "attn_causal_steps_copying_pct"
 MASKED_SHARE = "diffusion_masked_share"
 DIFFUSION_FILL = "attn_diffusion_mask_fill_pct"
+TILES_UNMASKED = "attn_tiles_unmasked_pct"
 STEP_COUNTERS = (WINDOW_TILES, CAUSAL_STEPS, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
-                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL)
+                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL, TILES_UNMASKED)
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
@@ -252,6 +254,26 @@ def _diffusion_counters(config: TransformerConfig, seq: int) -> Dict[str, float]
 
     fill = diffusion_mask_fill_pct(seq, config.diffusion_block, config.head_dim, config.head_dim)
     return {} if fill is None else {DIFFUSION_FILL: fill}
+
+
+def _unmasked_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
+    """`TILES_UNMASKED`: the run steps of the flash forward that take the
+    body without the mask, as % of a head's run steps (`flash_attention.
+    tiles_unmasked_pct`, from the predicate the kernels are given), the mean
+    over the layers whose core is an attention call (`Mixer.flash_heads`),
+    each under its own window or the model's block-diffusion mask, at the
+    tiles its head sizes give at this length.  Known when the step is traced
+    and noted whichever form the dispatch gives the step, as `CAUSAL_STEPS`.
+    Nothing for a model without such a layer or a length no tile divides."""
+    from ray_tpu.ops.pallas.flash_attention import tiles_unmasked_pct
+
+    calls = [(MIXERS[mixer].flash_heads(config), config.layer_variant(i)[0]) for i, (mixer, _) in enumerate(config.layer_pairs())]
+    calls = [call for call in calls if call[0] is not None]
+    shares = {(heads, window): tiles_unmasked_pct(seq, *heads, window=window, diffusion_block=config.diffusion_block)
+              for heads, window in set(calls)}  # once a kind of call, not a layer
+    if not calls or None in shares.values():
+        return {}
+    return {TILES_UNMASKED: sum(shares[call] for call in calls) / len(calls)}
 
 
 def _mtp_term(params, h, head, batch, config, rules, mesh):
@@ -385,7 +407,7 @@ class LMTrainContext:
             x, head, router_stats = trunk(params, tokens, cfg, rules=rules, mesh=self.mesh, noisy=noisy)
             ce = head_weighted_cross_entropy(constrain, x, head, tokens, weights)
             with tracing.scope("loss"):
-                counters.update(_diffusion_counters(cfg, tokens.shape[1]))
+                counters.update(**_diffusion_counters(cfg, tokens.shape[1]), **_unmasked_counters(cfg, tokens.shape[1]))
                 if router_stats is None:
                     return ce, counters
                 terms = router_losses(router_stats, cfg)
@@ -401,7 +423,8 @@ class LMTrainContext:
             (`_mtp_term`), `ce_loss` and `mtp_loss` among the terms, the
             module's block one more layer of the router statistics.  Beside the
             terms ride the attention kernels' counters, constants of the
-            traced step (`_window_counters`, `_causal_counters`).  A
+            traced step (`_window_counters`, `_causal_counters`,
+            `_unmasked_counters`).  A
             block-diffusion model's is `_diffusion_loss`, from `noise_key`."""
             if cfg.diffusion_block is not None:
                 return _diffusion_loss(params, batch, noise_key)
@@ -416,7 +439,7 @@ class LMTrainContext:
                     router_stats = jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b], axis=0), router_stats, stats)
             with tracing.scope("loss"):
                 seq = batch["tokens"].shape[1]
-                counters = {**_window_counters(cfg, seq), **_causal_counters(cfg, seq)}
+                counters = {**_window_counters(cfg, seq), **_causal_counters(cfg, seq), **_unmasked_counters(cfg, seq)}
                 loss = ce + cfg.mtp_loss_weight * mtp["mtp_loss"] if mtp else ce
                 if router_stats is None:
                     return loss, {"ce_loss": ce, **mtp, **counters} if mtp else counters

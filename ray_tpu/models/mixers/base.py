@@ -59,7 +59,7 @@ class Mixer:
     data: Callable[[Any], Dict[str, Tuple[float, ...]]] = lambda config: {}
     # config -> (q/k head size, v head size) of the causal attention call its core makes (the flash kernels' two
     # head sizes, from which their tiles follow); None for a kind whose core is no attention call.  Read by the step
-    # counter `lm._causal_counters` alone; held to the real call by tests/test_mellum_model.py
+    # counters `lm._causal_counters` and `lm._unmasked_counters` alone; held to the real call by tests/test_mellum_model.py
     flash_heads: Callable[[Any], Optional[Tuple[int, int]]] = lambda config: None
     rotates: bool = False  # its `mix` takes `rope=` (a layer of another kind may have no entry in `layer_ropes`)
     # (config, rules, mesh) -> None; raises ValueError on what the kind cannot run UNDER THESE RULES ON THIS MESH,

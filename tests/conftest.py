@@ -108,6 +108,18 @@ def only_the_delta_convolution_runs_its_kernels(patch, rows=32):
                   lambda takes, kernel, plain, *inputs: kernel(*inputs) if takes and kernel in mine else plain(*inputs))
 
 
+def without_file_locations(text: str) -> str:
+    """Lowered text with `debug_info=True`, less the lines that name a source
+    FILE (`#locN = loc("/path/x.py":..)`).  A function jax traced earlier in
+    the process (a jitted rung of the experts, a kernel's wrapper) keeps the
+    frames of its first trace, another test file's among them, so a statement
+    about what a program does NOT hold reads its ops' and scopes' names alone:
+    with the frames it held or failed by which file a worker ran before."""
+    import re
+
+    return "\n".join(line for line in text.splitlines() if not re.match(r'#loc\d+ = loc\("[^"]*\.py":', line))
+
+
 @pytest.fixture
 def lowered_for_tpu_on_the_cpu(monkeypatch):
     as_lowered_for_tpu(monkeypatch)

@@ -450,7 +450,7 @@ def test_a_dense_model_takes_a_module_too():
     assert set(params["mtp"]["block"]) == {"attn", "mlp", "ln1", "ln2"}
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
     loss, terms = ctx._loss(params, {"tokens": tokens, "targets": jnp.roll(tokens, -1, axis=1)})
-    assert set(terms) == {"ce_loss", "mtp_loss", "attn_causal_steps_copying_pct"}
+    assert set(terms) == {"ce_loss", "mtp_loss", "attn_causal_steps_copying_pct", "attn_tiles_unmasked_pct"}
     assert float(loss) == pytest.approx(float(terms["ce_loss"]) + 0.5 * float(terms["mtp_loss"]), rel=1e-6)
 
 

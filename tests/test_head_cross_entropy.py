@@ -116,7 +116,8 @@ def test_loss_gives_the_plain_objectives_value_and_every_gradient_leaf(dtype, ti
     assert ("lm_head" in params) != tied
     batch = _batch(cfg, jax.random.PRNGKey(1))
     (loss, terms), grads = jax.jit(jax.value_and_grad(ctx._loss, has_aux=True))(params, batch)
-    assert set(terms) == {"attn_causal_steps_copying_pct"}  # no term of the objective: the step's counter alone (PR 55)
+    # no term of the objective: the attention kernels' two counters alone (PR 55, PR 63)
+    assert set(terms) == {"attn_causal_steps_copying_pct", "attn_tiles_unmasked_pct"}
     want = jax.jit(jax.value_and_grad(lambda p: _plain_objective(ctx, p, batch)))(params)
     _close((loss, grads), want, dtype)
 

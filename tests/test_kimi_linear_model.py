@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import only_the_delta_convolution_runs_its_kernels
+from conftest import only_the_delta_convolution_runs_its_kernels, without_file_locations
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -124,7 +124,8 @@ def test_at_heads_of_128_the_layers_convolution_runs_its_kernels_and_no_mamba_2_
     objective = lambda p: ctx._loss(p, batch)[0]  # noqa: E731
     want_loss, want = jax.jit(jax.value_and_grad(objective))(params)
     lowered = jax.jit(jax.grad(objective)).trace(params).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
-    assert "ssm_conv" not in lowered and "kda/conv/cond/branch_0_fun/delta_conv_fwd" in lowered and "delta_conv_bwd" in lowered
+    assert "ssm_conv" not in without_file_locations(lowered)
+    assert "kda/conv/cond/branch_0_fun/delta_conv_fwd" in lowered and "delta_conv_bwd" in lowered
     only_the_delta_convolution_runs_its_kernels(monkeypatch)
     loss, got = jax.jit(jax.value_and_grad(objective))(params)
     assert abs(float(loss) - float(want_loss)) < 1e-5

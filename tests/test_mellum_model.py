@@ -461,6 +461,39 @@ def test_the_causal_steps_that_copy_reach_the_run_record_and_add_no_equation_to_
         assert equations() == with_counter
 
 
+def test_the_unmasked_tiles_counter_is_the_mean_over_every_flash_layer_each_under_its_own_window(tiny):
+    """`attn_tiles_unmasked_pct` (PR 63): the forward's share of run steps that
+    take the body without the mask, at the tiles in use, windowed layers
+    counted with the others; a constant of the traced step."""
+    assert lm.TILES_UNMASKED in lm.STEP_COUNTERS
+    full = dataclasses.replace(tiny["cfg"], layer_windows=None)
+    for seq, clear, run in ((16384, 120, 136), (8192, 28, 36), (4096, 6, 10), (1024, 0, 1)):  # the cells' lengths
+        assert lm._unmasked_counters(full, seq) == {lm.TILES_UNMASKED: pytest.approx(100 * clear / run)}
+    # the stack of `mellum2`: six layers under a window of 1,024 (both visited tiles are boundary tiles), two without
+    cell = dataclasses.replace(tiny["cfg"], layer_windows=(1024, 1024, 1024, None) * 2)
+    assert lm._unmasked_counters(cell, 16384) == {lm.TILES_UNMASKED: pytest.approx(100 * (120 / 136) * 2 / 8)}
+    assert lm._unmasked_counters(cell, 1100) == {}  # no tile divides it: the kernels do not run
+    latent = TransformerConfig.tiny(layer_types=("mla", "attention"), kv_lora_rank=16, qk_nope_head_dim=192, qk_rope_head_dim=64,
+                                    v_head_dim=256)  # 1024 x 512 at heads of 256 / 256: 56 of 72, the share 1024 x 1024 has
+    assert lm._unmasked_counters(latent, 8192) == {lm.TILES_UNMASKED: pytest.approx(100 * 28 / 36)}
+    assert lm._unmasked_counters(TransformerConfig.tiny(layer_types=("mamba",) * 2, ssm_heads=4, ssm_head_dim=16, ssm_state=16), 8192) == {}  # no attention call
+    cfg = dataclasses.replace(tiny["cfg"], max_seq_len=1280)  # tiles of 640 x 640, and of 128 x 128 under the windows of 8
+    ctx = one_device_ctx(cfg)
+    run_record.drain_step_counters(), run_record.drain_step_series()
+    state = ctx.init_state(seed=0)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(2), (1, 1280), 0, cfg.vocab_size))
+    batch = {"tokens": tokens, "targets": tokens}
+    state, metrics = ctx.train_step(state, batch)
+    jax.block_until_ready(metrics)
+    # windows of 8 at tiles of 128: a query tile's two key tiles are both crossed; the two full layers: 1 of 3 run steps
+    assert run_record.drain_step_counters()[lm.TILES_UNMASKED] == pytest.approx(100 * (1 / 3) * 2 / 8)
+    equations = lambda: len(jax.make_jaxpr(ctx._train_step.__wrapped__)(state, ctx.make_batch(batch)).jaxpr.eqns)  # noqa: E731
+    with_counter = equations()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(lm, "_unmasked_counters", lambda config, seq: {})
+        assert equations() == with_counter
+
+
 @pytest.mark.parametrize("kw", [
     dict(layer_types=("attention",) * 2),
     dict(layer_types=("mla", "attention"), kv_lora_rank=16, qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=64),

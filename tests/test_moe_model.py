@@ -164,7 +164,7 @@ def test_train_step_reports_the_terms_and_differentiates_the_objective(tiny):
     ctx = tiny["ctx"]
     _, metrics = ctx.train_step(_state_with(ctx, tiny["params"]), tiny["batch"])
     assert set(metrics) == {"loss", "grad_norm", "step", "ce_loss", "moe_lb_loss", "moe_z_loss",
-                            "moe_load_max_over_mean", "attn_causal_steps_copying_pct"}
+                            "moe_load_max_over_mean", "attn_causal_steps_copying_pct", "attn_tiles_unmasked_pct"}
     np.testing.assert_allclose(float(metrics["loss"]), float(tiny["ref_loss"]), rtol=1e-5)
     ref_norm = math.sqrt(sum(float(jnp.sum(g ** 2)) for g in jax.tree_util.tree_leaves(tiny["ref_grads"])))
     np.testing.assert_allclose(float(metrics["grad_norm"]), ref_norm, rtol=1e-3)

@@ -383,13 +383,164 @@ def test_block_diffusion_refuses_a_window_unequal_lengths_and_a_block_that_does_
         reference_attention(q, k, v, block_diffusion=BlockDiffusion(4, 32))
 
 
-# sha256[:16] of `str(jax.make_jaxpr(grad(flash_attention(..).sum())))` at the PARENT of PR 62 (commit 757862b), `0x..`
-# addresses and `.py:<line>` blanked: a call without `block_diffusion` traces what it traced, kernel bodies included
-_PARENT_JAXPRS = {None: "fa03f709fee805ab", 96: "fb19a6cc9ad0beaa"}
+# -- only the tiles the mask's edge crosses are masked (PR 63) -----------------------------------
+
+
+def _mask_of_a_tile(qrows, krows, window, bd):
+    """The mask over one tile pair by pair, in numpy, from the rules
+    themselves (the causal one and its window; the three of block diffusion)."""
+    q, k = qrows[:, None], krows[None, :]
+    if bd is None:
+        return (q >= k) if window is None else (q >= k) & (q - k < window)
+    q_noisy, k_noisy = q < bd.noisy, k < bd.noisy
+    qb, kb = np.where(q_noisy, q, q - bd.noisy) // bd.block, np.where(k_noisy, k, k - bd.noisy) // bd.block
+    return np.where(k_noisy, q_noisy & (kb == qb), np.where(q_noisy, kb < qb, kb <= qb))
+
+
+def _run_steps(rows, bq, bk, window, bd, *, keys):
+    """The (query tile, key tile) pairs of a head's run steps, in the grid's
+    order, as the kernels find them: the outer axis's tile, the inner axis's
+    from the kernel's own arithmetic, the kernel's `run`.  `keys`: forward and
+    dq (query tiles outside); without: dkv."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    n_q, n_k = rows // bq, rows // bk
+    own, other, n_own, n_other = (bq, bk, n_q, n_k) if keys else (bk, bq, n_k, n_q)
+    n_inner, _ = fa._inner_tile(n_own, n_other, own, other, window, keys=keys, causal=bd is None, diffusion=bd)
+    steps = []
+    for i in range(n_own):
+        for j in range(n_inner):
+            if bd is not None:
+                tile, run = fa._diffusion_step(i, j, own, other, rows, bd, keys=keys)
+            else:
+                tile = j if window is None else fa._first_visible(i, own, other, window, keys=keys) + j
+                q_start, k_start = (i * bq, tile * bk) if keys else (tile * bq, i * bk)
+                run = k_start <= q_start + bq - 1
+                if window is not None and not keys:
+                    run = run and q_start <= k_start + bk - 1 + window - 1 and q_start < n_q * bq
+            if run:
+                steps.append((i, int(tile)) if keys else (int(tile), i))
+    return steps
+
+
+# (what, rows, noisy rows, window, forward tiles, backward tiles, (wholly visible, crossed) forward, the same backward)
+_EDGE_CASES = [
+    ("causal", 16384, 0, None, (1024, 1024), (1024, 512), (120, 16), (240, 32)),
+    ("causal", 8192, 0, None, (1024, 1024), (1024, 512), (28, 8), (56, 16)),
+    ("causal", 4096, 0, None, (1024, 1024), (1024, 512), (6, 4), (12, 8)),
+    ("causal", 1024, 0, None, (1024, 1024), (1024, 512), (0, 1), (0, 2)),
+    ("window", 8192, 0, 512, (512, 512), (512, 512), (0, 31), (0, 31)),  # `phi4`'s layers: both visited tiles are boundary tiles
+    ("window", 16384, 0, 1024, (1024, 1024), (1024, 512), (0, 31), (0, 62)),  # `mellum2`'s
+    ("window", 8192, 0, 3072, (1024, 1024), (1024, 512), (13, 13), (26, 26)),  # three tiles wide: the middle ones are whole
+    ("diffusion", 16384, 8192, None, (1024, 1024), (1024, 512), (56, 24), (112, 48)),  # `sdar`'s step
+    ("diffusion", 8192, 0, None, (1024, 1024), (1024, 512), (28, 8), (56, 16)),  # one copy alone: block-causal
+]
+
+
+@pytest.mark.parametrize("kernel", ["forward", "dq", "dkv"])
+@pytest.mark.parametrize("what, rows, noisy, window, fwd, bwd, fwd_counts, bwd_counts", _EDGE_CASES,
+                         ids=[f"{c[0]}-{c[1]}" + (f"-w{c[3]}" if c[3] else "") for c in _EDGE_CASES])
+def test_a_run_step_is_taken_as_wholly_visible_iff_the_mask_over_its_tile_is_all_true(
+        kernel, what, rows, noisy, window, fwd, bwd, fwd_counts, bwd_counts):
+    """The predicate against the mask itself at the cells' shapes, in the three
+    kernels' tiles and orientations; the tiles that hold a pair are the ones
+    visited, as before."""
+    from ray_tpu.ops.attention import BlockDiffusion
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    bd = BlockDiffusion(4, noisy) if what == "diffusion" else None
+    (bq, bk), counts = (fwd, fwd_counts) if kernel == "forward" else (bwd, bwd_counts)
+    steps = _run_steps(rows, bq, bk, window, bd, keys=kernel != "dkv")
+    assert len(set(steps)) == len(steps)
+    holding, whole = set(), set()
+    for qi in range(rows // bq):
+        for ki in range(rows // bk):
+            mask = _mask_of_a_tile(np.arange(qi * bq, (qi + 1) * bq), np.arange(ki * bk, (ki + 1) * bk), window, bd)
+            if mask.any():
+                holding.add((qi, ki))
+            if mask.all():
+                whole.add((qi, ki))
+    assert set(steps) == holding
+    taken = {(qi, ki) for qi, ki in steps if fa._wholly_visible(qi * bq, ki * bk, bq, bk, window, bd)}
+    assert taken == whole
+    assert (len(taken), len(steps) - len(taken)) == counts
+    assert fa.run_steps_unmasked(rows, bq, bk, window, bd) == (len(taken), len(steps))  # what the counter counts, either axis outside
+
+
+def test_tiles_unmasked_counts_the_forwards_run_steps_at_the_tiles_in_use():
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    assert fa.tiles_unmasked_pct(8192, 128, 128, diffusion_block=4) == pytest.approx(70.0)  # `sdar`: 56 of 80
+    assert fa.tiles_unmasked_pct(16384, 128, 128) == pytest.approx(100 * 120 / 136)  # 88.2
+    assert fa.tiles_unmasked_pct(16384, 192, 128) == pytest.approx(100 * 120 / 136)  # Kimi's latent layer
+    assert fa.tiles_unmasked_pct(8192, 128, 128) == pytest.approx(100 * 28 / 36)  # 77.8
+    assert fa.tiles_unmasked_pct(8192, 256, 256) == pytest.approx(100 * 56 / 72)  # on a key tile of 512: 77.8 too
+    assert fa.tiles_unmasked_pct(4096, 128, 128) == pytest.approx(60.0)
+    assert fa.tiles_unmasked_pct(1024, 128, 128) == 0.0  # one tile a head, the diagonal's
+    assert fa.tiles_unmasked_pct(8192, 64, 128, window=512) == 0.0 == fa.tiles_unmasked_pct(16384, 128, 128, window=1024)
+    assert fa.tiles_unmasked_pct(8192, 128, 128, window=3072) == pytest.approx(100 * 13 / 26)
+    assert fa.tiles_unmasked_pct(1100, 128, 128) is None  # no tile divides it: the kernels do not run
+
+
+def _every_tile_crossed(monkeypatch):
+    """`_wholly_visible` answering no on every tile: the kernels of the parent,
+    every run step masked (a traced False, as the grid's values are)."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_wholly_visible", lambda q_start, k_start, *tile: q_start < 0)
+
+
+# (rows, noisy rows, window, block of the block-diffusion mask or None for a causal call)
+_BIT_CASES = {"causal": (512, 0, None, None), "window": (768, 0, 400, None), "diffusion": (1024, 512, None, 4),
+              "diffusion-blocks-of-32": (1024, 512, None, 32), "diffusion-one-copy": (512, 0, None, 4)}
+
+
+@pytest.mark.parametrize("case", list(_BIT_CASES))
+def test_leaving_the_mask_off_the_wholly_visible_tiles_changes_no_bit_of_out_lse_dq_dk_dv(monkeypatch, case):
+    """Several tiles a side, some of them wholly visible in all three kernels:
+    the call is bit for bit the call whose every run step is masked, and
+    agrees with `reference_attention` as it did."""
+    from ray_tpu.ops.attention import BlockDiffusion
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    rows, noisy, window, block = _BIT_CASES[case]
+    bd = None if block is None else BlockDiffusion(block, noisy)
+    kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(rows + (window or 0)), 4)
+    q = jax.random.normal(kq, (1, rows, 2, 64), jnp.float32)
+    k = jax.random.normal(kk, (1, rows, 2, 64), jnp.float32)
+    v = jax.random.normal(kv, (1, rows, 2, 128), jnp.float32)
+    g = jax.random.normal(kg, (1, rows, 2, 128), jnp.float32)
+    mask = dict(causal=bd is None, scale=0.125, window=window, diffusion=bd)
+    for (bq, bk), keys in (((128, 128), True), ((128, 64), True), ((128, 64), False)):  # forward, dq, dkv
+        steps = _run_steps(rows, bq, bk, window, bd, keys=keys)
+        whole = sum(bool(fa._wholly_visible(qi * bq, ki * bk, bq, bk, window, bd)) for qi, ki in steps)
+        assert 0 < whole < len(steps)
+
+    def everything():
+        out, lse = fa._flash_fwd(q, k, v, block_q=128, block_k=128, **mask)
+        return (out, lse) + fa._flash_bwd(q, k, v, out, lse, g, block_q=128, block_k=64, **mask)
+
+    changed = everything()
+    _every_tile_crossed(monkeypatch)
+    parents = everything()
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), changed, parents):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    plain = lambda q, k, v: reference_attention(q, k, v, causal=True, scale=0.125, window=window, block_diffusion=bd)  # noqa: E731
+    ref, vjp = jax.vjp(plain, q, k, v)
+    np.testing.assert_allclose(np.asarray(changed[0]), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    for a, b in zip(changed[2:], vjp(g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+
+
+# sha256[:16] of `str(jax.make_jaxpr(grad(flash_attention(..).sum())))`, `0x..` addresses and `.py:<line>` blanked.  Taken
+# first at the PARENT of PR 62 (to show that PR left a call without `block_diffusion` alone, kernel bodies included) and
+# RE-TAKEN at PR 63, which changes the bodies on purpose (two of them a kernel: a run step's tile is wholly visible or
+# crossed by the mask's edge): a later PR that means to leave a causal or a windowed call alone holds these.
+_PINNED_JAXPRS = {None: "412f8a8cc2ff613e", 96: "d9ec86f84133d5de"}
 
 
 @pytest.mark.parametrize("window", [None, 96])
-def test_a_call_without_block_diffusion_traces_what_the_parent_traced(window):
+def test_a_call_without_block_diffusion_traces_the_pinned_program(window):
     import hashlib
     import re
 
@@ -404,4 +555,4 @@ def test_a_call_without_block_diffusion_traces_what_the_parent_traced(window):
 
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k))
     text = re.sub(r"\.py:\d+", ".py", re.sub(r"0x[0-9a-f]+", "0x", text))
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _PARENT_JAXPRS[window]
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _PINNED_JAXPRS[window]

@@ -285,7 +285,10 @@ def test_a_step_names_its_core_and_its_noise_and_its_counters_reach_the_run_reco
     assert newest[DIFFUSION_FILL] == pytest.approx(fa.diffusion_mask_fill_pct(256, 4, 16, 16))
     assert 0.3 < newest[MASKED_SHARE] < 0.7
     assert lm.CAUSAL_STEPS not in newest and "moe_held_rows_mean" in newest
-    assert {MASKED_SHARE, DIFFUSION_FILL} <= set(lm.STEP_COUNTERS)
+    # 256 rows a copy hold one tile: the step's one tile holds both copies and the edge crosses it; the cell's 2 x 8,192: 56 of 80
+    assert newest[lm.TILES_UNMASKED] == 0.0 == fa.tiles_unmasked_pct(256, 16, 16, diffusion_block=4)
+    assert lm._unmasked_counters(config_of(max_seq_len=8192), 8192) == {lm.TILES_UNMASKED: pytest.approx(70.0)}
+    assert {MASKED_SHARE, DIFFUSION_FILL, lm.TILES_UNMASKED} <= set(lm.STEP_COUNTERS)
 
 
 def test_one_seed_governs_a_run_the_states_key_and_the_steps_count_draw_the_noise():
