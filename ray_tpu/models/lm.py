@@ -42,6 +42,7 @@ from ray_tpu.models.transformer import (
     mtp_forward,
     mtp_rows,
     param_axes,
+    saved_names,
     trunk,
 )
 from ray_tpu.parallel.mesh import build_mesh
@@ -193,14 +194,16 @@ def diffusion_noise(key: jax.Array, tokens: jax.Array, *, block: int, mask_id: i
 # the multi-token-prediction module's cross entropy; a block-diffusion step's two: the share of the sequence's
 # tokens the noise masked (0.5 in expectation) and the mask's pairs over the pairs of the tiles the flash forward
 # visits (a constant of the traced step, like the two of PR 55); and the share of a flash forward's run steps whose tile
-# the mask's edge does not cross, which run the body without the mask (PR 63; a constant of the traced step too).
+# the mask's edge does not cross, which run the body without the mask (PR 63; a constant of the traced step too); and the
+# share of the layers with a `KernelPair` recurrence whose forward kernel the backward runs again (PR 64; a constant too).
 WINDOW_TILES = "attn_window_tiles_visited_pct"
 CAUSAL_STEPS = "attn_causal_steps_copying_pct"
 MASKED_SHARE = "diffusion_masked_share"
 DIFFUSION_FILL = "attn_diffusion_mask_fill_pct"
 TILES_UNMASKED = "attn_tiles_unmasked_pct"
+SCAN_RERUN = "scan_forward_rerun_pct"
 STEP_COUNTERS = (WINDOW_TILES, CAUSAL_STEPS, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
-                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL, TILES_UNMASKED)
+                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL, TILES_UNMASKED, SCAN_RERUN)
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
@@ -274,6 +277,23 @@ def _unmasked_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
     if not calls or None in shares.values():
         return {}
     return {TILES_UNMASKED: sum(shares[call] for call in calls) / len(calls)}
+
+
+def _rerun_counters(config: TransformerConfig) -> Dict[str, float]:
+    """`SCAN_RERUN`: of the layers whose mixer runs a `KernelPair` recurrence
+    (`Mixer.recurrence`: kda, gdn, Mamba-2's SSD, the selective scan), the %
+    whose residual names the configured policy does NOT keep
+    (`transformer.saved_names`), i.e. whose forward scan kernel the layer's
+    recompute runs again before the backward kernel can start.  From the
+    configuration alone, and noted whichever form the dispatch gives the
+    step, as `CAUSAL_STEPS`.  0 without `remat` (JAX keeps every residual);
+    nothing for a model without such a layer."""
+    of_layers = [names for mixer, _ in config.layer_pairs() if (names := MIXERS[mixer].recurrence)]
+    if not of_layers:
+        return {}
+    kept = set(saved_names(config))
+    again = [config.remat and not set(names) <= kept for names in of_layers]
+    return {SCAN_RERUN: 100.0 * sum(again) / len(again)}
 
 
 def _mtp_term(params, h, head, batch, config, rules, mesh):
@@ -407,7 +427,8 @@ class LMTrainContext:
             x, head, router_stats = trunk(params, tokens, cfg, rules=rules, mesh=self.mesh, noisy=noisy)
             ce = head_weighted_cross_entropy(constrain, x, head, tokens, weights)
             with tracing.scope("loss"):
-                counters.update(**_diffusion_counters(cfg, tokens.shape[1]), **_unmasked_counters(cfg, tokens.shape[1]))
+                counters.update(**_diffusion_counters(cfg, tokens.shape[1]), **_unmasked_counters(cfg, tokens.shape[1]),
+                                **_rerun_counters(cfg))
                 if router_stats is None:
                     return ce, counters
                 terms = router_losses(router_stats, cfg)
@@ -424,7 +445,7 @@ class LMTrainContext:
             module's block one more layer of the router statistics.  Beside the
             terms ride the attention kernels' counters, constants of the
             traced step (`_window_counters`, `_causal_counters`,
-            `_unmasked_counters`).  A
+            `_unmasked_counters`) and the recurrences' (`_rerun_counters`).  A
             block-diffusion model's is `_diffusion_loss`, from `noise_key`."""
             if cfg.diffusion_block is not None:
                 return _diffusion_loss(params, batch, noise_key)
@@ -439,7 +460,8 @@ class LMTrainContext:
                     router_stats = jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b], axis=0), router_stats, stats)
             with tracing.scope("loss"):
                 seq = batch["tokens"].shape[1]
-                counters = {**_window_counters(cfg, seq), **_causal_counters(cfg, seq), **_unmasked_counters(cfg, seq)}
+                counters = {**_window_counters(cfg, seq), **_causal_counters(cfg, seq), **_unmasked_counters(cfg, seq),
+                            **_rerun_counters(cfg)}
                 loss = ce + cfg.mtp_loss_weight * mtp["mtp_loss"] if mtp else ce
                 if router_stats is None:
                     return loss, {"ce_loss": ce, **mtp, **counters} if mtp else counters

@@ -47,6 +47,11 @@ class Mixer:
     # no d-wide projection again, beside attention's own q, k, v, output and
     # log-sum-exp: what `remat_policy="qkv_attn"` keeps.
     saved: Tuple[str, ...] = ()
+    # `KernelPair.residual_names` of the recurrence its core runs, () for a kind without one.  A kind that also lists
+    # them in `saved` runs the recurrence's forward kernel once a layer under "qkv_attn" (its backward reads what the
+    # first call wrote); one that does not runs it again in the layer's recompute.  Read by the step counter
+    # `lm._rerun_counters` alone.
+    recurrence: Tuple[str, ...] = ()
     # What crosses layers.  A value is RETURNED by the layer that makes it
     # (the layer whose index the config field `source` holds), carried by
     # `trunk` beside the stream and given to the later layers that read it as

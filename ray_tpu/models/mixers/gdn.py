@@ -36,7 +36,7 @@ from ray_tpu.models.mixers.base import (
     proj_scale, rms_norm, stream_norm,
 )
 from ray_tpu.ops.delta_conv import delta_conv
-from ray_tpu.ops.gdn import gdn_chunked
+from ray_tpu.ops.gdn import PAIR, gdn_chunked
 from ray_tpu.util import tracing
 
 # The fused q|k|v|z projection before its convolution, beta's and the decay's
@@ -90,7 +90,12 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
 
     With the three `saved` residuals kept the backward runs none of the
     d-wide projections again (the convolution, the recurrence and the gated
-    norm run again)."""
+    norm run again).  The recurrence names its own residuals
+    (`KernelPair.residual_names`, `recurrence` below), and `kda` lists its
+    op's in `saved`; this kind does NOT: with them kept (277 MB a layer at
+    8,192 positions and 32 value heads) `qwen3-next-ep16-1chip.seq8k`'s step
+    compiles to 15.659 GB, over the 15.6 GB ISSUE 64 set, and libtpu's
+    rematerialization pass duplicates two matmuls (PERF.md section 7)."""
     del positions, window, data, shared, emit  # the decay carries position
     c, dt, p = config, config.dtype, layer_params["gdn"]
     f32 = jnp.float32
@@ -119,4 +124,5 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
             return checkpoint_name(joined(c, x, out, constrain), GDN_MIXED), {}
 
 
-MIXER = Mixer("gdn", "gdn_layers", "gdn", leaves, validate, mix, saved=(GDN_QKVZ, GDN_BA, GDN_MIXED))
+MIXER = Mixer("gdn", "gdn_layers", "gdn", leaves, validate, mix, saved=(GDN_QKVZ, GDN_BA, GDN_MIXED),
+              recurrence=PAIR.residual_names)

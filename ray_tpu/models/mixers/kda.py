@@ -27,7 +27,7 @@ from ray_tpu.models.mixers.base import (
     proj_scale, rms_norm, stream_norm,
 )
 from ray_tpu.ops.delta_conv import delta_conv
-from ray_tpu.ops.kda import kda_chunked
+from ray_tpu.ops.kda import PAIR, kda_chunked
 from ray_tpu.util import tracing
 
 # The fused q|k|v projection before its convolution, the two low-rank gates'
@@ -74,9 +74,13 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     per-head RMSNorm),
     `kda/scan` (the chunked recurrence, named in `ops/kda.py`).
 
-    With the three `saved` residuals kept the backward runs none of the
-    d-wide projections again (the gates' narrow-to-wide halves, the
-    convolution, the recurrence and the gated norm run again)."""
+    With the `saved` residuals kept the backward runs none of the d-wide
+    projections again, and not the recurrence's forward kernel: the op names
+    its output and the states its backward starts from
+    (`KernelPair.residual_names`; o float32 and the state that enters each
+    pair of chunks, 553 MB a layer at 16,384 positions and 32 heads), so the
+    gated norm's recompute reads the o the first call wrote (the gates'
+    narrow-to-wide halves, the convolution and the gated norm run again)."""
     del positions, window, data, shared, emit  # the decay carries position
     c, dt, p = config, config.dtype, layer_params["kda"]
     f32 = jnp.float32
@@ -109,4 +113,5 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
             return checkpoint_name(joined(c, x, out, constrain), KDA_MIXED), {}
 
 
-MIXER = Mixer("kda", "kda_layers", "kda", leaves, validate, mix, saved=(KDA_QKV, KDA_LOW, KDA_MIXED))
+MIXER = Mixer("kda", "kda_layers", "kda", leaves, validate, mix, saved=(KDA_QKV, KDA_LOW, KDA_MIXED, *PAIR.residual_names),
+              recurrence=PAIR.residual_names)

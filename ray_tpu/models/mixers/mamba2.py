@@ -33,7 +33,7 @@ from ray_tpu.models.mixers.base import (
     Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, log_arange, log_uniform, normal, ones,
     out_scale, proj_scale, rms_norm, stream_norm, zeros,
 )
-from ray_tpu.ops.ssm import causal_conv1d_silu, ssd_chunked
+from ray_tpu.ops.ssm import SCAN, causal_conv1d_silu, ssd_chunked
 from ray_tpu.util import tracing
 
 # `in_proj`'s output before its split into z, x|B|C and dt, and the residual
@@ -117,4 +117,5 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
             return checkpoint_name(joined(c, x, out, constrain), SSM_MIXED), {}
 
 
-MIXER = Mixer("mamba", "mamba_layers", "ssm", leaves, validate, mix, saved=(SSM_IN_PROJ, SSM_MIXED))
+MIXER = Mixer("mamba", "mamba_layers", "ssm", leaves, validate, mix, saved=(SSM_IN_PROJ, SSM_MIXED),
+              recurrence=SCAN.residual_names)

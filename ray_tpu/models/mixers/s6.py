@@ -27,7 +27,7 @@ from ray_tpu.models.mixers.base import (
     Leaf, Mixer, batch_sharded, constrainer, inv_softplus, joined, log_arange, log_uniform, normal, ones,
     out_scale, proj_scale, stream_norm, zeros,
 )
-from ray_tpu.ops.selective_scan import selective_scan
+from ray_tpu.ops.selective_scan import PAIR, selective_scan
 from ray_tpu.ops.ssm import causal_conv1d_silu
 from ray_tpu.util import tracing
 
@@ -102,4 +102,4 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
 
 
 MIXER = Mixer("s6", "s6_layers", "s6", leaves, validate, mix, saved=(S6_IN_PROJ, S6_MIXED),
-              hands=(MEMORY,), source="s6_memory_layer")
+              recurrence=PAIR.residual_names, hands=(MEMORY,), source="s6_memory_layer")

@@ -753,32 +753,40 @@ def _split_runs_bwd(bounds, _, d_runs):
 _split_runs.defvjp(_split_runs_fwd, _split_runs_bwd)
 
 
-def _remat_policy(config: TransformerConfig):
-    """Validated checkpoint policy for the configured remat granularity
-    (shared by the scan and pipeline paths, and by every kind of layer: a
-    name that a layer's kind does not carry matches nothing in it).  It says
-    what JAX recomputes; over libtpu's limit XLA's pass may duplicate more
-    (see `_dense_ffn`)."""
+def saved_names(config: TransformerConfig) -> Tuple[str, ...]:
+    """The `checkpoint_name`s a layer's `jax.checkpoint` keeps under the
+    configured remat granularity, validated (shared by every kind of layer: a
+    name that a layer's kind does not carry matches nothing in it)."""
     # The attention op names its own residuals (ops/attention.py): a policy
     # that keeps the output without the log-sum-exp would still re-run the
     # kernel's forward in the backward pass.
     if config.remat_policy == "attn":
-        return jax.checkpoint_policies.save_only_these_names(ATTN_OUT, ATTN_LSE)
+        return ATTN_OUT, ATTN_LSE
     if config.remat_policy == "qkv_attn":
         # No d-wide mixer projection is recomputed: attention's q, k, v
         # (latent and differential attention's too, both maps of the latter
-        # in the one output and log-sum-exp) and what each kind names.
-        return jax.checkpoint_policies.save_only_these_names(
-            "q", "k", "v", ATTN_OUT, ATTN_LSE, *(name for m in MIXERS.values() for name in m.saved))
+        # in the one output and log-sum-exp) and what each kind names; `kda`
+        # among it its recurrence's own residuals (the op names them as the
+        # attention op does, `ops/kernel_pair.py`), so that its forward
+        # kernel too runs once a layer.
+        return ("q", "k", "v", ATTN_OUT, ATTN_LSE, *(name for m in MIXERS.values() for name in m.saved))
     if config.remat_policy is None:
         # Save nothing per layer: the backward re-runs the whole layer, the
-        # flash forward included.  The minimum-memory mode.
-        return None
+        # flash forward and the recurrences' included.  The minimum-memory mode.
+        return ()
     of_kinds = "; ".join(f"{m.name}: {', '.join(map(repr, m.saved))}" for m in MIXERS.values() if m.saved)
     raise ValueError(
         f"unknown remat_policy {config.remat_policy!r}; expected None (save nothing), 'attn' "
         f"(saves {ATTN_OUT!r}, {ATTN_LSE!r}) or 'qkv_attn' (those, 'q', 'k', 'v' and each kind's own: {of_kinds})"
     )
+
+
+def _remat_policy(config: TransformerConfig):
+    """The checkpoint policy of `saved_names` (shared by the scan and pipeline
+    paths).  It says what JAX recomputes; over libtpu's limit XLA's pass may
+    duplicate more (see `_dense_ffn`)."""
+    names = saved_names(config)
+    return jax.checkpoint_policies.save_only_these_names(*names) if names else None
 
 
 def _refuse_unequal_stages(config: TransformerConfig) -> None:

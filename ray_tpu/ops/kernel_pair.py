@@ -30,6 +30,7 @@ import importlib
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.sharding import _fit_spec
@@ -86,6 +87,15 @@ class KernelPair:
     # at shapes the kernels refuse, this (*args, chunk) -> out IN PLACE of the `custom_vjp`, differentiated by JAX whole
     refused: Optional[Callable] = None
 
+    @property
+    def residual_names(self) -> Tuple[str, str]:
+        """The `checkpoint_name`s `vjp(self).fwd` puts on `out` and on the
+        states: a checkpoint policy that lists both keeps what the backward
+        reads, and the layer's recompute holds no forward kernel of this op;
+        one that lists neither (a name no policy lists lowers to nothing)
+        runs the forward again."""
+        return f"{self.name}/out", f"{self.name}/states"
+
     def module(self):
         """Imported at first use: a dense model's process loads no Pallas for this op."""
         return importlib.import_module(f"ray_tpu.ops.pallas.{self.kernels}")
@@ -105,7 +115,9 @@ def vjp(pair: KernelPair):
 
     def fwd(chunk, *args):
         out, *states = pair.forward(pair.call(args, chunk, residuals=True), *args)
-        return out, (args, tuple(states))
+        of_out, of_states = pair.residual_names
+        named = lambda tree, name: jax.tree_util.tree_map(lambda a: checkpoint_name(a, name), tree)  # a None stays None
+        return named(out, of_out), (args, named(tuple(states), of_states))
 
     def bwd(chunk, res, d_out):
         args, states = res

@@ -449,8 +449,8 @@ SAMBAY = dict(
 FAMILIES = {"dense": {}, "expert": EXPERT, "granite": dict(BASE, max_seq_len=128), "kimi": KIMI, "sambay": SAMBAY}
 
 
-@pytest.mark.parametrize("family, equations", [("dense", 700), ("expert", 2894), ("granite", 1977), ("kimi", 17727),
-                                               ("sambay", 4642)])
+@pytest.mark.parametrize("family, equations", [("dense", 700), ("expert", 2894), ("granite", 1979), ("kimi", 17742),
+                                               ("sambay", 4648)])
 def test_a_dense_and_an_expert_step_trace_to_the_parents_program(family, equations):
     """Counted at the parent of PR 30 with this function (the dense count is
     `tests/test_moe_model.py`'s 709 + 1 - 10; 710 / 2904 before PR 34's
@@ -462,7 +462,11 @@ def test_a_dense_and_an_expert_step_trace_to_the_parents_program(family, equatio
     rung's forward again; the all-experts step, `expert`, did not move) and
     at PR 60 (17,451 before it: a KDA layer's convolution is
     `ops/delta_conv.py`'s `custom_vjp`, the norm inside it, and both of its
-    forms are traced; no other family has a delta layer)."""
+    forms are traced; no other family has a delta layer).  The three again
+    at PR 64 (1,977 / 17,727 / 4,642 before it): `kernel_pair.vjp`'s forward
+    gives its output and each state a `name` equation, one an array a trace of
+    the forward (forward and recompute), and nothing else moved; a name no
+    policy lists lowers to nothing."""
     ctx = one_device_ctx(TransformerConfig.tiny(**FAMILIES[family]))
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
     toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)

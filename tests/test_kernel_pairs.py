@@ -101,3 +101,26 @@ def test_lowered_for_the_cpu_a_call_at_shapes_the_kernels_take_holds_no_mosaic_k
     traced = jax.jit(gradients(op, taken)).trace(*taken)
     assert str(traced.jaxpr).count("pallas_call") >= 2  # both directions' kernels are traced: one branch of each choice
     assert "tpu_custom_call" not in traced.lower(lowering_platforms=("cpu",)).as_text()
+
+
+@EACH
+def test_residual_names_are_what_the_forward_of_a_backward_puts_on_out_and_on_the_states(name):
+    """PR 64: `KernelPair.residual_names` are the two names `vjp(pair).fwd`
+    gives its output (every array of it) and every state it hands the
+    backward, so a checkpoint policy that lists them keeps all the backward
+    reads of what the forward kernel wrote; read from the jaxpr of a `jax.vjp`
+    of the op, whose results are the output and then the residuals.  What the
+    backward holds beside them is the op's own arguments.  The primal path (no
+    differentiation) names nothing."""
+    pair, op, taken, _ = PAIRS[name]
+    of_out, of_states = pair.residual_names
+    assert (of_out, of_states) == (f"{pair.name}/out", f"{pair.name}/states")
+    jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(op, *a))(*taken).jaxpr
+    named = {eqn.outvars[0]: eqn.params["name"] for eqn in jaxpr.eqns if eqn.primitive.name == "name"}
+    out = jax.tree.leaves(jax.eval_shape(op, *taken))
+    sized = lambda avals: sorted((a.size, str(a.dtype)) for a in avals)  # `delta_conv` cuts q and k into heads behind the name
+    assert sized(var.aval for var, given in named.items() if given == of_out) == sized(out)
+    states = [named.get(var) for var in jaxpr.outvars[len(out):] if var not in jaxpr.invars]
+    assert set(states) <= {of_states} and len(named) == len(out) + len(states), (named, states)
+    assert bool(states) == (name not in ("conv", "delta_conv"))  # a convolution's backward reads its arguments alone
+    assert " name[" not in str(jax.make_jaxpr(op)(*taken))

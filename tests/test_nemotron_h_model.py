@@ -358,7 +358,9 @@ def test_what_the_experts_were_given_reaches_the_run_record(tiny):
     jax.block_until_ready(metrics)
     newest, series = run_record.drain_step_counters(), run_record.drain_step_series()
     assert set(newest) == {"moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
-                           "attn_causal_steps_copying_pct", "attn_tiles_unmasked_pct"}  # the one attention block's, since PRs 55 and 63
+                           "attn_causal_steps_copying_pct", "attn_tiles_unmasked_pct",  # the one attention block's, since PRs 55 and 63
+                           "scan_forward_rerun_pct"}  # the Mamba-2 blocks', since PR 64
+    assert newest["scan_forward_rerun_pct"] == 0.0  # this step has no checkpoint; under one 100: no kind lists the SSD's names (the cell's reads 100)
     assert newest["moe_rows_moved_share"] == 1.0  # under one row tile of assignments: one rung, all T*K rows
     assert [step for step, _ in series] == [0, 1, 2] and series[-1][1] == newest
     tokens, k, total = tiny["tokens"].size, 3, 16

@@ -257,7 +257,8 @@ def test_lowered_kimi_step_names_its_regions_inside_the_three_layer_scopes(kimi_
         assert f'"checkpoint/rematted_computation/{outer}/{scope}/' not in kimi_lowered_for_tpu
         return
     assert f'"{outer}/{scope}/' in kimi_lowered_for_tpu
-    assert f'"checkpoint/rematted_computation/{outer}/{scope}/' in kimi_lowered_for_tpu
+    # PR 64: the recurrence's residuals are kept under `qkv_attn`, so the recompute holds nothing of `kda/scan`
+    assert (f'"checkpoint/rematted_computation/{outer}/{scope}/' in kimi_lowered_for_tpu) == (scope != "kda/scan")
 
 
 @pytest.mark.parametrize("scope", SCOPES)

@@ -179,19 +179,20 @@ KIMI = TransformerConfig.tiny(
     ids=["dp1", "fsdp4", "tp4"],
 )
 def test_kimi_step_lowers_for_tpu_with_the_kda_kernels_inside_kda_scan(n_devices, spec, strategy):
-    """The KDA run is one scan body: the recurrence's forward kernel twice
-    (forward, and the recompute: `qkv_attn` keeps the projections, not the
-    recurrence's output) and its backward kernel once (PR 41; before it the
-    backward was JAX's own, of the plain segment, on TPU too); under shard_map
-    on a mesh like the others, and each under `kda/scan` and its own name."""
+    """The KDA run is one scan body: the recurrence's forward kernel ONCE
+    (PR 64: `qkv_attn` keeps the residuals the op names, its output and the
+    states its backward starts from, inside a `shard_map`'s body too, so the
+    recompute holds no `kda_fwd`; twice until then) and its backward kernel
+    once (PR 41; before it the backward was JAX's own, of the plain segment, on
+    TPU too); under shard_map on a mesh like the others, and each under
+    `kda/scan` and its own name."""
     text = _lowered_text(n_devices, spec, strategy, platforms=("tpu",), cfg=KIMI, debug_info=True)
     kernels = _mosaic_kernels(text)
-    assert kernels["kda_fwd"] == 2 and kernels["kda_bwd"] == 1 and kernels["flash_fwd"] == 1, kernels
+    assert kernels["kda_fwd"] == 1 and kernels["kda_bwd"] == 1 and kernels["flash_fwd"] == 1, kernels
     assert not [name for name in kernels if name.startswith(("kda_", "gdn_")) and name not in ("kda_fwd", "kda_bwd")]
     for kernel, path in _kernel_paths(text, "kda_fwd|kda_bwd"):
         assert "kda/scan" in path and kernel in path, path
-        if kernel == "kda_bwd":  # in the layer's backward, not its recompute: the reader's `bwd`
-            assert "rematted_computation" not in path, path
+        assert "rematted_computation" not in path, path  # `kda_bwd` in the layer's backward (the reader's `bwd`), `kda_fwd` in its forward
     _holds_the_positions_major_convolution(text, kernels, "kda/conv/", n_devices)
 
 
@@ -233,7 +234,9 @@ QWEN3_NEXT = TransformerConfig.tiny(
 )
 def test_a_delta_layer_lowers_for_tpu_with_the_scalar_decay_kernels_inside_gdn_scan(n_devices, spec, strategy):
     """PR 58: a layer with ONE decay a head runs `gdn_fwd` (forward, and the
-    recompute) and `gdn_bwd` (once, in the layer's backward), under shard_map
+    recompute: the op names its residuals since PR 64, but `gdn` does not list
+    them in `Mixer.saved`, `qwen3-next`'s step has no room for them) and
+    `gdn_bwd` (once, in the layer's backward), under shard_map
     on a mesh like the others, each under `gdn/scan` and its own name; no
     per-channel kernel, which is a `kda` layer's (the Kimi step above holds no
     `gdn_*`: the layer kind picks the op)."""
@@ -285,6 +288,59 @@ def test_s6_step_lowers_for_tpu_with_the_scan_kernel_inside_s6_scan(n_devices, s
 
 def test_s6_step_lowered_for_the_cpu_holds_no_kernel():
     assert "tpu_custom_call" not in _lowered_text(1, MeshSpec(data=1), "dp", cfg=S6)
+
+
+# PR 64: a run of one kind's layers (one scan body) and the two kernels of its recurrence.  Mamba-2's is the step of
+# `test_a_step_with_groups_of_b_and_c_lowers_for_tpu_with_the_same_scan_kernels`.
+SCANS = {
+    "kda": (dataclasses.replace(KIMI, n_layers=2, layer_types=("kda", "kda")), "kda_fwd", "kda_bwd"),
+    "gdn": (dataclasses.replace(QWEN3_NEXT, n_layers=2, layer_types=("gdn", "gdn")), "gdn_fwd", "gdn_bwd"),
+    "mamba": (dataclasses.replace(HYBRID, n_layers=3, layer_types=("mamba",) * 3, ssm_groups=2), "ssd_fwd", "ssd_bwd"),
+    "s6": (S6, "s6_scan_fwd", "s6_scan_bwd"),
+}
+
+
+def _no_mesh_text(cfg):
+    """The gradient of a tiny loss over `transformer.forward` with no mesh at all, lowered for TPU."""
+    from ray_tpu.models import transformer
+
+    params = jax.eval_shape(lambda key: transformer.init_params(cfg, key), jax.random.PRNGKey(0))
+    loss = lambda p, tokens: jnp.mean(jnp.square(transformer.forward(p, tokens, cfg).astype(jnp.float32)))
+    traced = jax.jit(jax.grad(loss)).trace(params, jax.ShapeDtypeStruct((8, 128), jnp.int32))
+    return traced.lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("policy", ["qkv_attn", "attn", None], ids=str)
+@pytest.mark.parametrize("kind,devices", [(kind, 1) for kind in SCANS] + [("kda", None), ("kda", 4), ("gdn", None), ("gdn", 4)],
+                         ids=str)
+def test_the_recurrences_forward_kernel_runs_once_a_layer_where_the_policy_keeps_what_the_op_names(kind, devices, policy, monkeypatch):
+    """The boundary of PR 64, pinned.  A recurrence names the residuals of its
+    `custom_vjp` (`KernelPair.residual_names`) and `kda` lists those names in
+    `Mixer.saved`: under `"qkv_attn"` a `kda` layer holds ONE forward scan
+    kernel, with no mesh, on a mesh of one device and inside the `shard_map`
+    of a mesh of four (jax hands the policy down to its body); under `"attn"`
+    and `None`, which keep none of a recurrence's names, two (the forward's,
+    and the recompute's that the backward kernel waits for).  `gdn`'s
+    recurrence, Mamba-2's SSD and the selective scan get names no kind lists:
+    two under all three (`gdn` for `qwen3-next`'s compiled peak, PERF.md
+    section 7; the others are ROADMAP Speed 3(b)'s smaller entries).  One
+    backward kernel a layer everywhere; `lm._rerun_counters` says the same
+    from the configuration alone."""
+    from ray_tpu.models import lm
+    from ray_tpu.ops.pallas import selective_scan as s6_kernels
+
+    monkeypatch.setattr(s6_kernels, "_BLOCK_S", 128)
+    cfg, forward, backward = SCANS[kind]
+    cfg = dataclasses.replace(cfg, remat_policy=policy)
+    if devices is None:
+        text = _no_mesh_text(cfg)
+    else:
+        spec, strategy = (MeshSpec(data=1), "dp") if devices == 1 else (MeshSpec(data=1, fsdp=4), "fsdp")
+        text = _lowered_text(devices, spec, strategy, platforms=("tpu",), cfg=cfg)
+    kept = kind == "kda" and policy == "qkv_attn"
+    kernels = _mosaic_kernels(text)
+    assert (kernels[forward], kernels[backward]) == (1 if kept else 2, 1), kernels
+    assert lm._rerun_counters(cfg) == {lm.SCAN_RERUN: 0.0 if kept else 100.0}
 
 
 # A share of the experts in small, at widths the grouped-matmul kernels take: two attention + expert layers (one
