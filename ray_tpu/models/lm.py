@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import optax
 
 from ray_tpu.models.mixers import MIXERS
+from ray_tpu.models.mixers.dsa import INDEX_KL, SELECTED_PAIRS  # what a learned-sparse layer reports
 from ray_tpu.models.moe import router_losses
 from ray_tpu.models.transformer import (
     LOGITS_AXES,
@@ -44,6 +45,7 @@ from ray_tpu.models.transformer import (
     param_axes,
     saved_names,
     trunk,
+    trunk_reports,
 )
 from ray_tpu.parallel.mesh import build_mesh
 from ray_tpu.parallel.sharding import (
@@ -202,8 +204,9 @@ MASKED_SHARE = "diffusion_masked_share"
 DIFFUSION_FILL = "attn_diffusion_mask_fill_pct"
 TILES_UNMASKED = "attn_tiles_unmasked_pct"
 SCAN_RERUN = "scan_forward_rerun_pct"
+CAUSAL_PAIRS = "dsa_causal_pairs"
 STEP_COUNTERS = (WINDOW_TILES, CAUSAL_STEPS, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
-                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL, TILES_UNMASKED, SCAN_RERUN)
+                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL, TILES_UNMASKED, SCAN_RERUN, INDEX_KL, SELECTED_PAIRS, CAUSAL_PAIRS)
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
@@ -294,6 +297,19 @@ def _rerun_counters(config: TransformerConfig) -> Dict[str, float]:
     kept = set(saved_names(config))
     again = [config.remat and not set(names) <= kept for names in of_layers]
     return {SCAN_RERUN: 100.0 * sum(again) / len(again)}
+
+
+def _index_terms(reports: Dict[str, jax.Array], seq: int) -> Dict[str, jax.Array]:
+    """What the learned-sparse layers report (`mixers/dsa.py`), as the terms
+    ride: `INDEX_KL`, the SUM over those layers of the indexer's KL term, the
+    nats the objective adds to the cross entropy; `SELECTED_PAIRS`, the (query,
+    key) pairs a layer's core attended, a sequence, the mean over the layers;
+    `CAUSAL_PAIRS`, what a dense causal core would attend, `S (S + 1) / 2`.
+    Nothing for a model without such a layer."""
+    if INDEX_KL not in reports:
+        return {}
+    return {INDEX_KL: jnp.sum(reports[INDEX_KL]), SELECTED_PAIRS: jnp.mean(reports[SELECTED_PAIRS]),
+            CAUSAL_PAIRS: jnp.float32(seq * (seq + 1) // 2)}
 
 
 def _mtp_term(params, h, head, batch, config, rules, mesh):
@@ -450,25 +466,27 @@ class LMTrainContext:
             if cfg.diffusion_block is not None:
                 return _diffusion_loss(params, batch, noise_key)
             constrain = _constrainer(rules, self.mesh)
-            x, head, router_stats = trunk(
+            x, head, router_stats, reports = trunk_reports(
                 params, batch["tokens"], cfg, rules=rules, mesh=self.mesh)
             ce = head_cross_entropy(constrain, x, head, batch["targets"], batch.get("mask"))
-            mtp = {}
+            beside = _index_terms(reports, batch["tokens"].shape[1])  # the terms beside the cross entropy, `ce_loss` apart from them
             if cfg.mtp_depth:
-                mtp["mtp_loss"], stats = _mtp_term(params, x, head, batch, cfg, rules, self.mesh)
+                beside["mtp_loss"], stats = _mtp_term(params, x, head, batch, cfg, rules, self.mesh)
                 if stats is not None:
                     router_stats = jax.tree_util.tree_map(lambda a, b: jnp.concatenate([a, b], axis=0), router_stats, stats)
             with tracing.scope("loss"):
                 seq = batch["tokens"].shape[1]
                 counters = {**_window_counters(cfg, seq), **_causal_counters(cfg, seq), **_unmasked_counters(cfg, seq),
                             **_rerun_counters(cfg)}
-                loss = ce + cfg.mtp_loss_weight * mtp["mtp_loss"] if mtp else ce
+                loss = ce + cfg.mtp_loss_weight * beside["mtp_loss"] if cfg.mtp_depth else ce
+                if INDEX_KL in beside:
+                    loss = loss + beside[INDEX_KL]
                 if router_stats is None:
-                    return loss, {"ce_loss": ce, **mtp, **counters} if mtp else counters
+                    return loss, {"ce_loss": ce, **beside, **counters} if beside else counters
                 terms = router_losses(router_stats, cfg)
                 loss = (loss + cfg.router_aux_loss_coef * terms["moe_lb_loss"]
                         + cfg.router_z_loss_coef * terms["moe_z_loss"])
-                return loss, {"ce_loss": ce, **mtp, **terms, **counters}
+                return loss, {"ce_loss": ce, **beside, **terms, **counters}
 
         self._loss = _loss
 

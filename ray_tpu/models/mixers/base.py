@@ -67,6 +67,10 @@ class Mixer:
     # counters `lm._causal_counters` and `lm._unmasked_counters` alone; held to the real call by tests/test_mellum_model.py
     flash_heads: Callable[[Any], Optional[Tuple[int, int]]] = lambda config: None
     rotates: bool = False  # its `mix` takes `rope=` (a layer of another kind may have no entry in `layer_ropes`)
+    # Per-layer float32 scalars its `mix` returns beside what it hands on, under these names: `transformer.trunk_reports`
+    # stacks them over the kind's layers, [layers of the kind] each, for the objective and the step counters (models/lm.py)
+    reports: Tuple[str, ...] = ()
+    holds_heads: bool = False  # it reads `TransformerConfig.head_share` (a model with one has no layer of a kind that does not)
     # (config, rules, mesh) -> None; raises ValueError on what the kind cannot run UNDER THESE RULES ON THIS MESH,
     # when the three first meet (`transformer.check_placement`), not when a step is traced
     placement: Callable[[Any, Optional[Rules], Any], None] = lambda config, rules, mesh: None

@@ -20,6 +20,9 @@ sizes).  It runs local attention only: no sequence-parallel ring.
 
 from __future__ import annotations
 
+import dataclasses
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -36,35 +39,134 @@ from ray_tpu.util import tracing
 MLA_MIXED = "mla_mixed"
 
 
-def leaves(config):
-    c, rank = config, config.kv_lora_rank
+@dataclasses.dataclass(frozen=True)
+class Latent:
+    """The sizes of ONE latent geometry: what "mla" reads from the
+    configuration's own fields (`latent_of`) and what a model with a second
+    geometry states for its other kind (`TransformerConfig.window_latent`;
+    mixers/dsa.py).  `heads` of the model's `all_heads` (None: all of them)
+    are held, from `first_head` on: the leaves are `heads` wide and drawn as
+    that range of the whole model's."""
+    heads: int
+    q_rank: Optional[int]  # None: q is one projection
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    all_heads: Optional[int] = None
+    first_head: int = 0
+
+    @property
+    def qk(self) -> int:
+        return self.nope + self.rope
+
+    def held(self, index: int, of: int) -> "Latent":
+        """Share `index` of `of` of the heads."""
+        if self.heads % of:
+            raise ValueError(f"{self.heads} heads are no {of} equal shares")
+        return dataclasses.replace(self, heads=self.heads // of, all_heads=self.heads, first_head=index * (self.heads // of))
+
+    def of_heads(self, scale: float, axis: int):
+        """`normal(scale)` for a leaf whose `axis` (counted from the end) is
+        the heads': the held range of the draw for all the model's heads."""
+        if self.all_heads in (None, self.heads):
+            return normal(scale)
+
+        def init(key, shape):
+            whole = list(shape)
+            whole[axis] = self.all_heads
+            return jax.lax.slice_in_dim(normal(scale)(key, tuple(whole)), self.first_head, self.first_head + self.heads,
+                                        axis=len(shape) + axis)
+        return init
+
+
+def latent_of(config) -> Latent:
+    c = config
+    return Latent(c.n_heads, c.q_lora_rank, c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim)
+
+
+def latent_leaves(config, g: Latent, rescale: bool = False):
+    """`rescale`: the layer multiplies each normed latent by `(d_model / its
+    rank) ** 0.5` (`project`), so an up-projection's input has mean square
+    `d_model / rank` and its fan-in scale is `d_model ** -0.5`, a d-wide
+    projection's: q, k_nope and v start at the variance of k_pe, as they do
+    without the rescale."""
+    c, rank = config, g.kv_rank
     heads = ("heads", "head_dim")
-    qk = c.qk_nope_head_dim + c.qk_rope_head_dim
-    if c.q_lora_rank is None:
-        q = {"wq": Leaf((c.d_model, c.n_heads, qk), ("embed", *heads), normal(proj_scale(c)))}
+    up = (lambda r: proj_scale(c)) if rescale else (lambda r: r ** -0.5)
+    if g.q_rank is None:
+        q = {"wq": Leaf((c.d_model, g.heads, g.qk), ("embed", *heads), g.of_heads(proj_scale(c), -2))}
     else:
-        q = {"w_qa": Leaf((c.d_model, c.q_lora_rank), ("embed", None), normal(proj_scale(c))),
-             "q_norm": ones((c.q_lora_rank,)),
-             "w_qb": Leaf((c.q_lora_rank, c.n_heads, qk), (None, *heads), normal(c.q_lora_rank ** -0.5))}
+        q = {"w_qa": Leaf((c.d_model, g.q_rank), ("embed", None), normal(proj_scale(c))),
+             "q_norm": ones((g.q_rank,)),
+             "w_qb": Leaf((g.q_rank, g.heads, g.qk), (None, *heads), g.of_heads(up(g.q_rank), -2))}
     return {
         **q,
-        "w_kva": Leaf((c.d_model, rank + c.qk_rope_head_dim), ("embed", None), normal(proj_scale(c))),
+        "w_kva": Leaf((c.d_model, rank + g.rope), ("embed", None), normal(proj_scale(c))),
         "kv_norm": ones((rank,)),
-        "w_kvb": Leaf((rank, c.n_heads, c.qk_nope_head_dim + c.v_head_dim), (None, *heads), normal(rank ** -0.5)),
-        "wo": Leaf((c.n_heads, c.v_head_dim, c.d_model), (*heads, "embed"), normal(out_scale(c))),
+        "w_kvb": Leaf((rank, g.heads, g.nope + g.v), (None, *heads), g.of_heads(up(rank), -2)),
+        "wo": Leaf((g.heads, g.v, c.d_model), (*heads, "embed"), g.of_heads(out_scale(c), -3)),
     }
 
 
+def leaves(config):
+    return latent_leaves(config, latent_of(config))
+
+
+def validate_latent(g: Latent, rope, field: str = "mla_rope") -> None:
+    if not (g.kv_rank > 0 and g.nope > 0 and g.v > 0):
+        raise ValueError("a latent-attention layer needs kv_lora_rank, qk_nope_head_dim and v_head_dim")
+    if g.q_rank is not None and g.q_rank <= 0:
+        raise ValueError(f"q_lora_rank is None (q is one projection) or the rank of its two, got {g.q_rank}")
+    if rope is not None and not (isinstance(rope, Rope) and g.rope > 0 and g.rope % 2 == 0):
+        raise ValueError(f"{field} is an ops.rotary.Rope over an even qk_rope_head_dim, or None; got {rope!r} "
+                         f"over {g.rope}")
+
+
 def validate(config) -> None:
-    if not (config.kv_lora_rank > 0 and config.qk_nope_head_dim > 0 and config.v_head_dim > 0):
-        raise ValueError("an mla layer needs kv_lora_rank, qk_nope_head_dim and v_head_dim")
-    if config.q_lora_rank is not None and config.q_lora_rank <= 0:
-        raise ValueError(f"q_lora_rank is None (q is one projection) or the rank of its two, got {config.q_lora_rank}")
-    if config.mla_rope is not None and not (isinstance(config.mla_rope, Rope) and config.qk_rope_head_dim > 0
-                                            and config.qk_rope_head_dim % 2 == 0):
-        raise ValueError(f"mla_rope is an ops.rotary.Rope over an even qk_rope_head_dim, or None; got {config.mla_rope!r} "
-                         f"over {config.qk_rope_head_dim}")
+    validate_latent(latent_of(config), config.mla_rope)
     refuse_attn_bias(config)
+
+
+def project(config, g: Latent, p, h, positions, rope: Optional[Rope], constrain, rescale: bool = False):
+    """q [B, S, H, nope + rope], k alike, v [B, S, H, v] of the normed stream
+    `h`, q, k and v under attention's names, and the q latent (None where q
+    is one projection).  `rescale`: each normed latent times `(d_model /
+    its rank) ** 0.5` (LongCat-Flash's `mla_scale_q_lora` / `mla_scale_kv_lora`)."""
+    c, dt, rank, nope = config, config.dtype, g.kv_rank, g.nope
+    c_q = None
+    if g.q_rank is None:
+        q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(dt))
+    else:
+        c_q = rms_norm(jnp.einsum("bse,er->bsr", h, p["w_qa"].astype(dt)), p["q_norm"], c.norm_eps)
+        if rescale:
+            c_q = c_q * jnp.asarray((c.d_model / g.q_rank) ** 0.5, dt)
+        q = jnp.einsum("bsr,rhd->bshd", c_q, p["w_qb"].astype(dt))
+    latent = jnp.einsum("bse,ef->bsf", h, p["w_kva"].astype(dt))
+    c_kv = rms_norm(latent[..., :rank], p["kv_norm"], c.norm_eps)
+    if rescale:
+        c_kv = c_kv * jnp.asarray((c.d_model / rank) ** 0.5, dt)
+    kv = jnp.einsum("bsr,rhd->bshd", c_kv, p["w_kvb"].astype(dt))
+    k_pe = latent[..., None, rank:]  # [B, S, 1, rope]: one for all heads
+    if rope is not None:
+        q = jnp.concatenate([q[..., :nope], apply_rope(q[..., nope:], positions, rope)], axis=-1)
+        k_pe = apply_rope(k_pe, positions, rope)
+    k_pe = jnp.broadcast_to(k_pe, (*kv.shape[:3], g.rope))
+    kk = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+    q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
+    kk = constrain(kk, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
+    return checkpoint_name(q, "q"), checkpoint_name(kk, "k"), checkpoint_name(kv[..., nope:], "v"), c_q
+
+
+def local_heads(rules, mesh, q):
+    """(batch axes, the mesh axis the heads are split over) of a local attention call; a ring is refused."""
+    batch_axes = head_ax = None
+    if rules is not None:
+        batch_axes = rules.get("act_batch")
+        head_ax = fitting_axis(rules.get("act_heads"), mesh, q.shape[2])
+    if ring_axis(rules, mesh, q) is not None:
+        raise ValueError("a latent-attention layer runs local attention only (no sequence-parallel ring)")
+    return batch_axes, head_ax
 
 
 def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, data=None, shared=None, emit=False):
@@ -75,34 +177,10 @@ def mix(x, layer_params, positions, config, rules, mesh=None, *, window=None, da
     del window, data, shared, emit
     c, dt, p = config, config.dtype, layer_params["mla"]
     constrain = constrainer(rules, mesh)
-    rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
     with tracing.scope("layer/attn_proj"), tracing.scope("mla/proj"):
         h = stream_norm(c, x, layer_params, "ln1")
-        if c.q_lora_rank is None:
-            q = jnp.einsum("bse,ehd->bshd", h, p["wq"].astype(dt))
-        else:
-            c_q = rms_norm(jnp.einsum("bse,er->bsr", h, p["w_qa"].astype(dt)), p["q_norm"], c.norm_eps)
-            q = jnp.einsum("bsr,rhd->bshd", c_q, p["w_qb"].astype(dt))
-        latent = jnp.einsum("bse,ef->bsf", h, p["w_kva"].astype(dt))
-        kv = jnp.einsum("bsr,rhd->bshd", rms_norm(latent[..., :rank], p["kv_norm"], c.norm_eps),
-                        p["w_kvb"].astype(dt))
-        k_pe = latent[..., None, rank:]  # [B, S, 1, rope]: one for all heads
-        if c.mla_rope is not None:
-            q = jnp.concatenate([q[..., :nope], apply_rope(q[..., nope:], positions, c.mla_rope)], axis=-1)
-            k_pe = apply_rope(k_pe, positions, c.mla_rope)
-        k_pe = jnp.broadcast_to(k_pe, (*kv.shape[:3], c.qk_rope_head_dim))
-        kk = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
-        q = constrain(q, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
-        kk = constrain(kk, ("act_batch", "act_seq", "act_heads", "act_head_dim"))
-        q = checkpoint_name(q, "q")
-        kk = checkpoint_name(kk, "k")
-        vv = checkpoint_name(kv[..., nope:], "v")
-    batch_axes = head_ax = None
-    if rules is not None:
-        batch_axes = rules.get("act_batch")
-        head_ax = fitting_axis(rules.get("act_heads"), mesh, q.shape[2])
-    if ring_axis(rules, mesh, q) is not None:
-        raise ValueError("an mla layer runs local attention only (no sequence-parallel ring)")
+        q, kk, vv, _ = project(c, latent_of(c), p, h, positions, c.mla_rope, constrain)
+    batch_axes, head_ax = local_heads(rules, mesh, q)
     with tracing.scope("layer/attn_core"):
         attn = dot_product_attention(
             q, kk, vv, causal=True, scale=q.shape[-1] ** -0.5, impl=c.attention_impl,

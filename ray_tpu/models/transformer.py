@@ -40,7 +40,13 @@ block-diffusion model over Qwen3-MoE's block (`diffusion_block`: attention is
 causal BETWEEN blocks of that many tokens and two-sided inside one; `forward`
 runs one copy of a sequence under that mask, `trunk(..., noisy=x_t)` and
 `diffusion_forward` the training step's noisy copy beside the clean one, whose
-objective is models/lm.py's).
+objective is models/lm.py's); and dots3-note-prev: latent attention of TWO
+geometries in one stack (mixers/dsa.py: "mla_sparse" layers attend the keys a
+learned indexer selects and report its KL term, `index_*`; "mla_window" layers
+read `window_latent` under the layer's window), rescaled latents, head-wise
+gates, and a model TOLD which heads it holds (`head_share`, beside
+`n_experts_held`); what such layers report leaves the stack through
+`trunk_reports`.
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -99,6 +105,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.mixers import MIXERS, Leaf, Mixer
+from ray_tpu.models.mixers.mla import Latent
 from ray_tpu.models.mixers.base import joined, norm_scale, normal, ones, out_scale, proj_scale, stream_norm, zeros
 # the names lm.py and the tests hold these two by
 from ray_tpu.models.mixers.base import constrainer as _constrainer, rms_norm  # noqa: F401
@@ -264,6 +271,17 @@ class TransformerConfig:
     v_head_dim: int = 0
     q_lora_rank: Optional[int] = None
     mla_rope: Optional[Rope] = None
+    # Latent attention's two further kinds (mixers/dsa.py), both with rescaled latents, the layer's own rope
+    # (`layer_ropes`) and a head-wise output gate.  "mla_sparse" reads the fields above and attends each query's
+    # `index_topk` causal keys of largest indexer score (`index_heads` heads of `index_head_dim`, trained by a KL term
+    # of the objective, models/lm.py); "mla_window" reads a second geometry, `window_latent` (its own heads, ranks and
+    # head sizes), under the layer's window (`layer_windows`).  `head_share` = (index, of): the model holds that share
+    # of each such kind's heads, one rank's share of a tensor-parallel attention, on one device.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    window_latent: Optional[Latent] = None
+    head_share: Optional[Tuple[int, int]] = None
     # Published multipliers (Granite's muP form), 1.0 each = absent: on the
     # embeddings, on each block's output before it joins the residual
     # stream, and a divisor of the logits.  `attention_scale` multiplies
@@ -354,9 +372,10 @@ class TransformerConfig:
             if self.logits_scaling != 1.0:
                 raise ValueError("mtp_depth takes the rows that enter the head as the trunk's output: logits_scaling must be 1.0")
             last = MIXERS[self.layer_pairs()[-1][0]]
-            if last.reads or last.source is not None:
+            if last.reads or last.source is not None or last.reports:
                 raise ValueError(f"mtp_depth: the module's block is one more {last.name} layer, a kind that crosses layers "
-                                 f"({last.source or last.reads}); it runs behind the trunk and can neither read nor hand on")
+                                 f"({last.source or last.reads or last.reports}); it runs behind the trunk and can neither read, "
+                                 f"hand on nor report")
         if self.diffusion_block is not None:
             others = sorted(set(self.layer_types or ()) - {_DEFAULT_MIXER})
             has = [name for name, there in (
@@ -369,6 +388,13 @@ class TransformerConfig:
                     f"diffusion_block={self.diffusion_block} (>= 1; diffusion_eps={self.diffusion_eps} in (0, 1]) puts the "
                     f"block-diffusion mask in the causal one's place in every layer, all of them '{_DEFAULT_MIXER}': it takes no "
                     + ", no ".join(has or ["other kind of layer"]))
+        if self.head_share is not None:
+            object.__setattr__(self, "head_share", tuple(self.head_share))
+            others = sorted({kind for kind, _ in self.layer_pairs() if not MIXERS[kind].holds_heads})
+            if others or len(self.head_share) != 2:
+                raise ValueError(f"head_share={self.head_share} is (index, of), the share of its heads a model holds whose "
+                                 f"every layer is of a kind that reads it ({[m.name for m in MIXERS.values() if m.holds_heads]}); "
+                                 f"this one has {others}")
         if self.qk_norm not in (False, True, "per_head"):
             raise ValueError(f"qk_norm is False, True (over the whole projection) or 'per_head', got {self.qk_norm!r}")
         if self.norm_kind not in ("rms", "layer"):
@@ -929,7 +955,12 @@ def forward(
         return _constrainer(rules, mesh)(logits, LOGITS_AXES)
 
 
-def trunk(
+def trunk(params: Dict, tokens: jax.Array, config: TransformerConfig, **kw):
+    """`trunk_reports` without the layers' reports: (rows, head, router statistics)."""
+    return trunk_reports(params, tokens, config, **kw)[:3]
+
+
+def trunk_reports(
     params: Dict,
     tokens: jax.Array,
     config: TransformerConfig,
@@ -942,7 +973,9 @@ def trunk(
     [B, S, d] in `config.dtype`, normed and divided by `logits_scaling`; the
     head [d, vocab] in `config.dtype`, the embedding table transposed when
     tied; router statistics stacked over the layers, `[L, ...]` each, as
-    `moe.router_losses` takes them, None for a dense model).
+    `moe.router_losses` takes them, None for a dense model; what the layers
+    REPORT, name -> float32 [layers that report it] in the stack's order
+    (`Mixer.reports`), {} for a model whose kinds report nothing).
 
     `noisy` [B, S] (a block-diffusion model's training step): the noisy copy
     x_t of `tokens`.  The stack then runs the 2S rows `[x_t ‖ x_0]` at the
@@ -970,7 +1003,7 @@ def trunk(
     # `layers` names what the loop over the stack itself costs (each layer's
     # weights sliced out of the stack, gradients and residuals stacked back);
     # the regions of a layer are named inside it.
-    router_stats = None
+    router_stats, reports = None, {}
     with tracing.scope("layers"):
         if pp is not None:
             stack = params.get(MIXERS[_DEFAULT_MIXER].stack)  # None: a stack it refuses by name
@@ -1009,7 +1042,9 @@ def trunk(
                 data = {name: jnp.asarray(values[start: start + count], jnp.float32)
                         for name, values in mixer.data(c).items()}
                 x, (run_stats, handed) = jax.lax.scan(body, x, (next(stacks[kind, ffn]), data))
-                shared.update({name: value[0] for name, value in handed.items()})
+                shared.update({name: handed[name][0] for name in mixer.hands if name in handed})
+                for name in mixer.reports:
+                    reports[name] = handed[name] if name not in reports else jnp.concatenate([reports[name], handed[name]])
                 if run_stats is not None:
                     per_run.append(run_stats)
             # the expert layers' statistics, [expert layers, ...] in the stack's order
@@ -1032,7 +1067,7 @@ def trunk(
             # two, as published, does not even move a rounding) without a
             # pass over the logits.
             x = x / jnp.asarray(c.logits_scaling, x.dtype)
-    return x, head, router_stats
+    return x, head, router_stats, reports
 
 
 def mtp_rows(

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops import delta_conv, gdn, kda, kernel_pair, selective_scan, ssm
+from ray_tpu.ops import delta_conv, gdn, kda, kernel_pair, selective_scan, sparse_attention, ssm
 
 f32, bf16 = jnp.float32, jnp.bfloat16
 shaped = jax.ShapeDtypeStruct
@@ -42,6 +42,11 @@ def delta_conv_of(s, cx, hq, hk, d, cv, k=4):
     return shaped((1, s, cx), bf16), shaped((hq, d, k), f32), shaped((hk, d, k), f32), shaped((cv, k), f32)
 
 
+def selected(s, h, d, dv):
+    """q, k, v and the selection's mask (any dtype: nonzero is selected; float here, so that every argument has a cotangent)."""
+    return shaped((1, s, h, d), bf16), shaped((1, s, h, d), bf16), shaped((1, s, h, dv), bf16), shaped((1, s, s), f32)
+
+
 # record, the op that runs it, arguments the kernels take, arguments they refuse
 PAIRS = {
     "kda": (kda.PAIR, kda.kda_chunked, delta(128, 1, 1, 128, True), delta(128, 1, 1, 64, True)),
@@ -50,6 +55,8 @@ PAIRS = {
     "ssd": (ssm.SCAN, ssm.ssd_chunked, ssd(256, 4, 64), ssd(256, 4, 48)),
     "conv": (ssm.CONV, ssm.causal_conv1d_silu, conv(128, 16), conv(96, 16)),
     # one key head and two value heads of 128 read from a wider array (Qwen3-Next's layout); heads of 64 are refused
+    # the sparse core at latent attention's two head sizes; a length no 128-tile divides is refused
+    "selected": (sparse_attention.PAIR, sparse_attention.selected_attention, selected(256, 2, 192, 128), selected(192, 2, 192, 128)),
     "delta_conv": (delta_conv.PAIR, delta_conv.delta_conv, delta_conv_of(32, 768, 1, 1, 128, 256), delta_conv_of(32, 768, 2, 2, 64, 256)),
 }
 EACH = pytest.mark.parametrize("name", PAIRS)
@@ -61,7 +68,7 @@ def gradients(op, args):
 
 
 def test_every_record_of_the_ops_is_held_here():
-    declared = {id(value) for module in (delta_conv, gdn, kda, selective_scan, ssm) for value in vars(module).values()
+    declared = {id(value) for module in (delta_conv, gdn, kda, selective_scan, sparse_attention, ssm) for value in vars(module).values()
                 if isinstance(value, kernel_pair.KernelPair)}
     assert declared == {id(pair) for pair, *_ in PAIRS.values()}
 

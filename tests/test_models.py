@@ -15,6 +15,7 @@ import pytest
 
 from ray_tpu.models import LMTrainContext, TransformerConfig, forward, init_params, param_axes
 from ray_tpu.models.mixers import MIXERS
+from ray_tpu.models.mixers.mla import Latent
 from ray_tpu.models.transformer import FFN_KINDS
 from ray_tpu.parallel import MeshSpec, build_mesh, resolve_rules
 
@@ -41,6 +42,8 @@ SIZES = dict(
     gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=16,
 )
 EXPERTS = dict(n_experts=4, experts_per_token=2, moe_d_ff=48, n_shared_experts=1, router_activation="sigmoid")
+# what latent attention's two further kinds read beside SIZES: a low-rank q (the rescale and the indexer read it), the indexer, a second geometry
+SPARSE = dict(q_lora_rank=16, index_heads=2, index_head_dim=16, index_topk=8, window_latent=Latent(2, 16, 24, 16, 8, 32))
 
 
 @pytest.mark.parametrize("ffn", FFN_KINDS)
@@ -55,7 +58,8 @@ def test_param_count_matches_config(mixer, ffn):
     kinds = (*(m.name for m in makers), mixer, mixer)
     cfg = TransformerConfig.tiny(
         n_layers=len(kinds), layer_types=kinds, ffn_types=(ffn,) * len(kinds), attn_bias=MIXERS[mixer].subtree == "diff",
-        **{m.source: i for i, m in enumerate(makers)}, **SIZES, **(EXPERTS if ffn == "experts" else {}))
+        **{m.source: i for i, m in enumerate(makers)}, **SIZES, **(EXPERTS if ffn == "experts" else {}),
+        **(SPARSE if MIXERS[mixer].holds_heads else {}))
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     assert sum(math.prod(a.shape) for a in jax.tree_util.tree_leaves(params)) == cfg.num_params()
     axes = param_axes(cfg)

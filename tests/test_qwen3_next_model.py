@@ -118,7 +118,7 @@ def test_two_periods_are_four_runs_over_two_stacks(tiny):
     assert cfg.layer_runs() == (("gdn", "experts", 0, 3), ("attention", "experts", 0, 1),
                                 ("gdn", "experts", 3, 3), ("attention", "experts", 1, 1))
     assert {k: v[2] for k, v in cfg.stacks().items()} == {"layers": 2, "gdn_layers": 6}
-    assert list(MIXERS)[-1] == "gdn"  # appended: the order fixes the key sequence of every other model's weights
+    assert list(MIXERS)[8] == "gdn"  # appended behind the eight before it (PR 66's two behind it): the order fixes the key sequence of every other model's weights
     attn, delta = tiny["params"]["layers"]["attn"], tiny["params"]["gdn_layers"]["gdn"]
     assert attn["wq"].shape == (2, 64, 4, 64) and attn["q_norm"].shape == (2, 32)  # q | gate a head; one scale for all heads
     assert sorted(delta) == ["A_log", "conv_w", "dt_bias", "norm", "wba", "wo", "wqkvz"]

@@ -481,3 +481,34 @@ def test_the_sdar_cells_flash_kernels_compile_at_its_shapes(topo):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert all(name in text for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
     assert plain.count('custom_call_target="tpu_custom_call"') == 1 and "flash_fwd" in plain
+
+
+def test_the_dots3_cells_sparse_core_and_window_kernels_compile_at_its_shapes(topo):
+    """PR 66.  One 8,192-token sequence of the 16 held heads of 192 | 128 over
+    an int8 selection [8192, 8192]: the sparse core's forward, its two
+    backward kernels and the target's kernel (`ops/pallas/sparse_attention.py`,
+    512 x 512 tiles, the mask's tile one more operand), four custom calls; and
+    the flash kernels at the sliding kind's 8 held heads of 256 | 128 under a
+    window of 513, which is a multiple of no tile (tiles of 512, two key tiles
+    a query tile), three more."""
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import sparse_attention as sa
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    q, v, mask = shaped((1, 8192, 16, 192)), shaped((1, 8192, 16, 128)), shaped((1, 8192, 8192), jnp.int8)
+
+    def sparse(q, k, v, mask, do):
+        (out, lse), back = jax.vjp(lambda q, k, v: sa.selected_attention(q, k, v, mask), q, k, v)
+        return back((do, jnp.zeros_like(lse))), sa.head_mean_probs(q, k, lse, mask)
+
+    windowed = jax.grad(lambda q, k, v, do: jnp.sum((fa.flash_attention(q, k, v, window=513) * do).astype(jnp.float32)), argnums=(0, 1, 2))
+    wq, wv = shaped((1, 8192, 8, 256)), shaped((1, 8192, 8, 128))
+    with _no_compile_cache():
+        text = jax.jit(sparse).lower(q, q, v, mask, v).compile().as_text()
+        window_text = jax.jit(windowed).lower(wq, wq, wv, wv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert all(name in text for name in ("dsa_attn_fwd", "dsa_attn_bwd_dq", "dsa_attn_bwd_dkv", "dsa_target"))
+    assert window_text.count('custom_call_target="tpu_custom_call"') == 3
