@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import optax
 
 from ray_tpu.models.mixers import MIXERS
-from ray_tpu.models.mixers.dsa import INDEX_KL, SELECTED_PAIRS  # what a learned-sparse layer reports
+from ray_tpu.models.mixers.dsa import INDEX_KL, SELECTED_PAIRS, SPARSE  # what a learned-sparse layer reports, and its kind
 from ray_tpu.models.moe import router_losses
 from ray_tpu.models.transformer import (
     LOGITS_AXES,
@@ -197,7 +197,8 @@ def diffusion_noise(key: jax.Array, tokens: jax.Array, *, block: int, mask_id: i
 # tokens the noise masked (0.5 in expectation) and the mask's pairs over the pairs of the tiles the flash forward
 # visits (a constant of the traced step, like the two of PR 55); and the share of a flash forward's run steps whose tile
 # the mask's edge does not cross, which run the body without the mask (PR 63; a constant of the traced step too); and the
-# share of the layers with a `KernelPair` recurrence whose forward kernel the backward runs again (PR 64; a constant too).
+# share of the layers with a `KernelPair` recurrence whose forward kernel the backward runs again (PR 64; a constant too); and
+# the tile pairs the indexer's forward kernel runs, of all tile pairs (PR 67; a constant too).
 WINDOW_TILES = "attn_window_tiles_visited_pct"
 CAUSAL_STEPS = "attn_causal_steps_copying_pct"
 MASKED_SHARE = "diffusion_masked_share"
@@ -205,8 +206,10 @@ DIFFUSION_FILL = "attn_diffusion_mask_fill_pct"
 TILES_UNMASKED = "attn_tiles_unmasked_pct"
 SCAN_RERUN = "scan_forward_rerun_pct"
 CAUSAL_PAIRS = "dsa_causal_pairs"
+INDEX_TILES = "dsa_index_tiles_visited_pct"
 STEP_COUNTERS = (WINDOW_TILES, CAUSAL_STEPS, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
-                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL, TILES_UNMASKED, SCAN_RERUN, INDEX_KL, SELECTED_PAIRS, CAUSAL_PAIRS)
+                 "mtp_loss", MASKED_SHARE, DIFFUSION_FILL, TILES_UNMASKED, SCAN_RERUN, INDEX_KL, SELECTED_PAIRS, CAUSAL_PAIRS,
+                 INDEX_TILES)
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
@@ -297,6 +300,23 @@ def _rerun_counters(config: TransformerConfig) -> Dict[str, float]:
     kept = set(saved_names(config))
     again = [config.remat and not set(names) <= kept for names in of_layers]
     return {SCAN_RERUN: 100.0 * sum(again) / len(again)}
+
+
+def _index_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
+    """`INDEX_TILES`: the (query tile, key tile) pairs the indexer's forward
+    kernel runs, as % of all tile pairs at the tiles in use at this length
+    (`ops/pallas/sparse_attention.py` `index_tiles_visited_pct`: the pairs on or
+    under the diagonal), the mean over the learned-sparse layers, which share
+    one shape; 100 at shapes the kernels refuse, where the plain form scores
+    every pair.  Known when the step is traced and noted whichever form the
+    dispatch gives the step, as `CAUSAL_STEPS`.  Nothing for a model without
+    such a layer."""
+    if not any(mixer == SPARSE.name for mixer, _ in config.layer_pairs()):
+        return {}
+    from ray_tpu.ops.pallas.sparse_attention import index_supported, index_tiles_visited_pct
+
+    takes = index_supported((1, seq, config.index_heads, config.index_head_dim))
+    return {INDEX_TILES: index_tiles_visited_pct(seq) if takes else 100.0}
 
 
 def _index_terms(reports: Dict[str, jax.Array], seq: int) -> Dict[str, jax.Array]:
@@ -461,7 +481,8 @@ class LMTrainContext:
             module's block one more layer of the router statistics.  Beside the
             terms ride the attention kernels' counters, constants of the
             traced step (`_window_counters`, `_causal_counters`,
-            `_unmasked_counters`) and the recurrences' (`_rerun_counters`).  A
+            `_unmasked_counters`), the recurrences' (`_rerun_counters`) and the
+            indexer's (`_index_counters`).  A
             block-diffusion model's is `_diffusion_loss`, from `noise_key`."""
             if cfg.diffusion_block is not None:
                 return _diffusion_loss(params, batch, noise_key)
@@ -477,7 +498,7 @@ class LMTrainContext:
             with tracing.scope("loss"):
                 seq = batch["tokens"].shape[1]
                 counters = {**_window_counters(cfg, seq), **_causal_counters(cfg, seq), **_unmasked_counters(cfg, seq),
-                            **_rerun_counters(cfg)}
+                            **_rerun_counters(cfg), **_index_counters(cfg, seq)}
                 loss = ce + cfg.mtp_loss_weight * beside["mtp_loss"] if cfg.mtp_depth else ce
                 if INDEX_KL in beside:
                     loss = loss + beside[INDEX_KL]

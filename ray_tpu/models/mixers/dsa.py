@@ -184,7 +184,7 @@ def mix_sparse(x, layer_params, positions, config, rules, mesh=None, *, window=N
                 qi, ki = apply_rope(qi, positions, part), apply_rope(ki, positions, part)
             qi, ki = qi.astype(dt), ki.astype(dt)
             w = jnp.einsum("bse,ej->bsj", sg(h), p["wi_w"].astype(dt), **f32) * (c.index_heads * c.index_head_dim) ** -0.5
-            scores = sa.index_scores(qi, ki[:, :, 0], w)
+            scores = sa.index_scores(qi, ki[:, :, 0], w, mesh=mesh if rules is not None else None, batch_axes=batch_axes)
         with tracing.scope("dsa/topk"):
             mask = checkpoint_name(sa.select_topk(scores, c.index_topk), sa.MASK)
         with tracing.scope("dsa/attn"):

@@ -5,7 +5,14 @@ core runs over those alone.  Four steps, `[batch, seq, ...]` throughout:
 - `index_scores(qi, ki, w)`: `I[t, s] = sum_j w[t, j] * relu(qi[t, j] . ki[s])`
   over the indexer's heads j, ONE key a position; operands as they come (bf16
   in a step), products accumulated, weighed and summed in float32.  `[B, S, S]`
-  float32, every pair: the selection looks at the causal ones alone.
+  float32: every causal pair scored, every pair above the diagonal 0.0 (the
+  selection and the loss look at causal pairs alone).  A `KernelPair`
+  (`INDEX`): in a step lowered for TPU, at shapes they take, the kernels
+  `dsa_index_fwd` / `dsa_index_bwd_dq` / `dsa_index_bwd_dk` of
+  `ops/pallas/sparse_attention.py`, which keep a tile's heads in VMEM and walk
+  the causal tiles alone; everywhere else the plain form below, query blocks
+  under a `jax.checkpoint`, its backward JAX's own.  Neither form names a
+  residual: the backward reads qi, ki and w as its caller's recompute makes them.
 - `select_topk(scores, k)`: the mask `[B, S, S]` int8 of each query's
   `min(t + 1, k)` causal keys of largest score.  The k-th largest score of a
   row is found exactly, by 32 passes that fix one bit each of its float32
@@ -62,17 +69,45 @@ def _block_of(s: int) -> int:
     return QUERY_BLOCK if s % QUERY_BLOCK == 0 else s
 
 
-def index_scores(qi: jax.Array, ki: jax.Array, w: jax.Array) -> jax.Array:
-    """qi [B, S, J, D], ki [B, S, D], w [B, S, J] float32 -> I [B, S, S] float32."""
+def _plain_scores(ki, qi, w):
+    s = qi.shape[1]
+    block = _block_of(s)
 
     @jax.checkpoint
-    def one(block):
-        q, weights = block
+    def one(rows):
+        q, weights, first = rows
         z = jnp.einsum("bqjd,bsd->bqjs", q, ki, preferred_element_type=jnp.float32)
-        return jnp.sum(jax.nn.relu(z) * weights[..., None], axis=2)
+        causal = first + jnp.arange(block)[:, None] >= jnp.arange(s)[None, :]
+        return jnp.where(causal, jnp.sum(jax.nn.relu(z) * weights[..., None], axis=2), 0.0)
 
-    block = _block_of(qi.shape[1])
-    return _positions(jax.lax.map(one, (_blocks(qi, block), _blocks(w.astype(jnp.float32), block))))
+    return _positions(jax.lax.map(one, (_blocks(qi, block), _blocks(w.astype(jnp.float32), block), jnp.arange(0, s, block))))
+
+
+def _plain_scores_backward(ki, qi, w, d_scores):
+    return jax.vjp(_plain_scores, ki, qi, w)[1](d_scores)
+
+
+def _index_forward(call, ki, qi, w):
+    return (call(call.kernels.index_fwd, _plain_scores, ki, qi, w),)
+
+
+def _index_backward(call, ki, qi, w, d_scores):
+    """(dk, dq, dw) from the scores' cotangent."""
+    return call(call.kernels.index_bwd, _plain_scores_backward, ki, qi, w, d_scores)
+
+
+# ki first: the scaffold places the output as it places its first argument, and the scores have the key's rank
+INDEX = kernel_pair.KernelPair(
+    name="index_scores", scope=None, kernels="sparse_attention",
+    takes=lambda kernels, ki, qi, w, chunk: kernels.index_supported(qi.shape),
+    forward=_index_forward, backward=_index_backward,
+)
+
+
+def index_scores(qi: jax.Array, ki: jax.Array, w: jax.Array, mesh=None, batch_axes=None) -> jax.Array:
+    """qi [B, S, J, D], ki [B, S, D], w [B, S, J] float32 -> I [B, S, S] float32, 0.0 above the diagonal.  mesh /
+    batch_axes as the other pairs have them."""
+    return kernel_pair.run(INDEX, ki, qi, w, mesh=mesh, batch_axes=batch_axes)
 
 
 def _ordered(scores: jax.Array) -> jax.Array:

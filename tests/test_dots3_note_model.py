@@ -162,6 +162,7 @@ def test_loss_and_both_terms_agree_with_the_reference(loss_and_grads):
         assert abs(float(terms[name]) - float(want_terms[name])) < 1e-4 * abs(float(want_terms[name])), name
     assert float(terms["dsa_index_kl"]) > 0.01  # two layers' terms, nats: part of the objective, not noise
     assert float(terms["dsa_causal_pairs"]) == SEQ * (SEQ + 1) / 2
+    assert float(terms["dsa_index_tiles_visited_pct"]) == 100.0  # 8 heads of 16 at 64 positions: the plain form
     wanted = sum(min(t + 1, 16) for t in range(SEQ))  # and the keys that tie with a query's 16th (eight heads' ReLUs can all be 0)
     assert wanted <= float(terms["dsa_selected_pairs"]) < wanted + 4
 
@@ -296,6 +297,80 @@ def test_the_core_attends_exactly_the_selected_keys_in_both_forms_and_directions
     target = sa.head_mean_probs(q, k, lse, mask)
     assert float(jnp.max(jnp.abs(target - jnp.mean(probs, axis=1)))) < 1e-5
     assert "pallas_call" in str(jax.make_jaxpr(lambda *a: sa.selected_attention(*a, mask))(q, k, v))  # both forms are traced
+
+
+def _index_inputs(dtype, s=384, j=8, d=128):
+    """384 positions are three 128-tiles a side: three tile pairs lie wholly above the diagonal and are never run."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    qi, ki = jax.random.normal(ks[0], (1, s, j, d)).astype(dtype), jax.random.normal(ks[1], (1, s, d)).astype(dtype)
+    w = jax.random.normal(ks[2], (1, s, j)) * (j * d) ** -0.5
+    selected = sa.select_topk(jax.random.normal(ks[3], (1, s, s)), 64) != 0
+    return qi, ki, w, jnp.where(selected, jax.random.normal(ks[4], (1, s, s)), 0.0)
+
+
+def _dense_scores(qi, ki, w):
+    z = jnp.einsum("bqjd,bsd->bqjs", qi.astype(jnp.float32), ki.astype(jnp.float32), precision="highest")
+    return jnp.tril(jnp.sum(jax.nn.relu(z) * w[..., None], axis=2))
+
+
+@pytest.mark.parametrize("form", ["plain", "kernels"])
+def test_the_indexers_scores_agree_in_both_forms_and_directions(form, monkeypatch):
+    """PR 67.  The plain form and the Pallas kernels (interpret mode,
+    128-tiles over 384 positions: a tile pair above the diagonal is never run)
+    against the explicit sum over the heads: every causal pair to float32
+    rounding, 0.0 on every pair above the diagonal; `dq`, `dk`, `dw` against
+    `jax.vjp` of the plain form for a cotangent that is zero off a random
+    selection, in float32 and with bf16 operands (there both forms round the
+    backward's `dz` and two of the sums to bf16)."""
+    if form == "kernels":
+        from tests.conftest import as_lowered_for_tpu
+
+        as_lowered_for_tpu(monkeypatch)
+    for dtype, tolerance in ((jnp.float32, 1e-5), (jnp.bfloat16, 8e-3)):
+        qi, ki, w, d_scores = _index_inputs(dtype)
+        scores, pull = jax.vjp(sa.index_scores, qi, ki, w)
+        assert scores.dtype == jnp.float32 and rel(scores, _dense_scores(qi, ki, w)) < 1e-6
+        assert float(jnp.max(jnp.abs(jnp.triu(scores[0], 1)))) == 0.0
+        want = jax.vjp(lambda qi, ki, w: sa._plain_scores(ki, qi, w), qi, ki, w)[1](d_scores)
+        got = pull(d_scores)
+        assert [(g.shape, g.dtype) for g in got] == [(a.shape, a.dtype) for a in (qi, ki, w)]
+        assert all(rel(a.astype(jnp.float32), b.astype(jnp.float32)) < tolerance for a, b in zip(got, want))
+    text = str(jax.make_jaxpr(lambda *a: jax.vjp(sa.index_scores, *a)[1](d_scores))(qi, ki, w))
+    assert all(name in text for name in ("dsa_index_fwd", "dsa_index_bwd_dq", "dsa_index_bwd_dk"))  # both forms are traced
+
+
+def test_the_two_forms_of_the_scores_select_the_same_keys(monkeypatch):
+    """PR 67.  `select_topk` over the kernels' scores and over the plain form's
+    on a seeded input with bf16 operands: the masks are equal but for keys whose
+    score lies within float32 rounding of the query's threshold (the forms sum
+    the heads in different orders); none is expected."""
+    from tests.conftest import as_lowered_for_tpu
+
+    qi, ki, w, _ = _index_inputs(jnp.bfloat16)
+    plain = sa._plain_scores(ki, qi, w)
+    as_lowered_for_tpu(monkeypatch)
+    kernels = sa.index_scores(qi, ki, w)
+    mask, other = sa.select_topk(plain, 64), sa.select_topk(kernels, 64)
+    threshold = jnp.min(jnp.where(mask != 0, plain, jnp.inf), axis=-1, keepdims=True)
+    flipped = mask != other
+    assert int(jnp.sum(flipped & (jnp.abs(plain - threshold) > 1e-5 * jnp.abs(threshold)))) == 0
+    assert int(jnp.sum(flipped)) <= 2, int(jnp.sum(flipped))
+    assert int(jnp.sum(mask)) == sum(min(t + 1, 64) for t in range(384))
+
+
+@pytest.mark.parametrize("heads, dim, seq, want", [(64, 128, 8192, 53.125), (8, 128, 384, 100 * 6 / 9), (8, 16, 64, 100.0)])
+def test_the_step_counts_the_tile_pairs_the_index_kernel_runs(heads, dim, seq, want):
+    """PR 67: `dsa_index_tiles_visited_pct`, a constant of the traced step: the
+    pairs on or under the diagonal at the tiles in use (136 of 256 at 8,192
+    under 512-tiles of keys), 100 at shapes the kernels refuse (the plain form
+    scores every pair), nothing for a model without a learned-sparse layer."""
+    from ray_tpu.models import lm
+
+    cfg = config_of(index_heads=heads, index_head_dim=dim)
+    assert lm._index_counters(cfg, seq) == {lm.INDEX_TILES: pytest.approx(want)}
+    assert lm.INDEX_TILES == "dsa_index_tiles_visited_pct" and lm.INDEX_TILES in lm.STEP_COUNTERS
+    sliding = dataclasses.replace(cfg, layer_types=tuple("mla_window" for _ in cfg.layer_types))
+    assert lm._index_counters(sliding, seq) == {}
 
 
 def test_the_kl_term_and_its_hand_written_gradient():

@@ -47,6 +47,11 @@ def selected(s, h, d, dv):
     return shaped((1, s, h, d), bf16), shaped((1, s, h, d), bf16), shaped((1, s, h, dv), bf16), shaped((1, s, s), f32)
 
 
+def index(s, j, d):
+    """The indexer's ONE key a position, its `j` heads' queries and their weights (the record's order: the key first)."""
+    return shaped((1, s, d), bf16), shaped((1, s, j, d), bf16), shaped((1, s, j), f32)
+
+
 # record, the op that runs it, arguments the kernels take, arguments they refuse
 PAIRS = {
     "kda": (kda.PAIR, kda.kda_chunked, delta(128, 1, 1, 128, True), delta(128, 1, 1, 64, True)),
@@ -57,6 +62,8 @@ PAIRS = {
     # one key head and two value heads of 128 read from a wider array (Qwen3-Next's layout); heads of 64 are refused
     # the sparse core at latent attention's two head sizes; a length no 128-tile divides is refused
     "selected": (sparse_attention.PAIR, sparse_attention.selected_attention, selected(256, 2, 192, 128), selected(192, 2, 192, 128)),
+    # the indexer's scores at 8 heads of 128 over one key a position; heads of 64 are under the MXU's depth
+    "index": (sparse_attention.INDEX, lambda ki, qi, w: sparse_attention.index_scores(qi, ki, w), index(256, 8, 128), index(256, 8, 64)),
     "delta_conv": (delta_conv.PAIR, delta_conv.delta_conv, delta_conv_of(32, 768, 1, 1, 128, 256), delta_conv_of(32, 768, 2, 2, 64, 256)),
 }
 EACH = pytest.mark.parametrize("name", PAIRS)
@@ -129,5 +136,5 @@ def test_residual_names_are_what_the_forward_of_a_backward_puts_on_out_and_on_th
     assert sized(var.aval for var, given in named.items() if given == of_out) == sized(out)
     states = [named.get(var) for var in jaxpr.outvars[len(out):] if var not in jaxpr.invars]
     assert set(states) <= {of_states} and len(named) == len(out) + len(states), (named, states)
-    assert bool(states) == (name not in ("conv", "delta_conv"))  # a convolution's backward reads its arguments alone
+    assert bool(states) == (name not in ("conv", "delta_conv", "index"))  # these backwards read their arguments alone
     assert " name[" not in str(jax.make_jaxpr(op)(*taken))

@@ -512,3 +512,31 @@ def test_the_dots3_cells_sparse_core_and_window_kernels_compile_at_its_shapes(to
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert all(name in text for name in ("dsa_attn_fwd", "dsa_attn_bwd_dq", "dsa_attn_bwd_dkv", "dsa_target"))
     assert window_text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_dots3_cells_index_kernels_compile_at_its_shapes(topo):
+    """PR 67.  One 8,192-token sequence of the indexer's 64 heads of 128 over
+    one key a position: the scores' forward (a query tile's 64 heads, 4 MB, in
+    VMEM twice beside a `[512, 256]` float32 tile) and the backward's two
+    kernels (the query-tile kernel holds q, dq twice and a float32 dq: 25 MB
+    of the 48 MB it asks for), three custom calls, and no `[256, 64, 8192]`
+    block of a query block's products in the compiled text."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import sparse_attention as sa
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+
+    def both(qi, ki, w, d_scores):
+        scores, back = jax.vjp(sa.index_scores, qi, ki, w)
+        return scores, back(d_scores)
+
+    with _no_compile_cache():
+        text = jax.jit(both).lower(shaped((1, 8192, 64, 128)), shaped((1, 8192, 128)), shaped((1, 8192, 64), jnp.float32),
+                                   shaped((1, 8192, 8192), jnp.float32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert all(name in text for name in ("dsa_index_fwd", "dsa_index_bwd_dq", "dsa_index_bwd_dk"))
+    assert not re.search(r"\[(?:\d+,)*256,64,8192\]", text)  # the plain form's block of 256 queries: f32 products, pred compares

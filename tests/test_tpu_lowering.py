@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import LMTrainContext, TransformerConfig
+from ray_tpu.ops.rotary import Rope
 from ray_tpu.parallel import MeshSpec, build_mesh
 
 # 128-aligned sequence and head_dim: the shapes the auto dispatch gives to
@@ -28,11 +29,11 @@ CFG = TransformerConfig.tiny(
 )
 
 
-def _lowered_text(n_devices, spec, strategy, platforms=None, cfg=CFG, debug_info=False):
+def _lowered_text(n_devices, spec, strategy, platforms=None, cfg=CFG, debug_info=False, seq=128):
     mesh = build_mesh(spec, devices=jax.devices()[:n_devices])
     ctx = LMTrainContext(cfg, mesh=mesh, strategy=strategy)
     state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
-    toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
+    toks = jax.ShapeDtypeStruct((8, seq), jnp.int32)
     traced = ctx._train_step.trace(state, {"tokens": toks, "targets": toks})
     kw = {"lowering_platforms": platforms} if platforms else {}
     return traced.lower(**kw).as_text(debug_info=debug_info)
@@ -392,3 +393,49 @@ def test_a_share_of_the_experts_lowers_for_tpu_with_one_switch_a_direction(kind,
     toks = jax.ShapeDtypeStruct((8, 128), jnp.int32)
     step = jax.make_jaxpr(ctx._train_step)(jax.eval_shape(ctx._init, jax.random.PRNGKey(0)), {"tokens": toks, "targets": toks})
     assert [len(eqn.params["branches"]) for eqn in switches(step.jaxpr)] == [len(rungs)] * 2
+
+
+# dots3-note's learned-sparse kind in small, at shapes the indexer's and the sparse core's kernels take: two full
+# layers (one run, one scan body) of two heads of 64 + 64 | 128 and an indexer of 8 heads of 128, top-32 of 256 keys.
+DOTS3 = TransformerConfig.tiny(
+    n_layers=2, n_heads=2, n_kv_heads=2, d_model=256, d_ff=256, max_seq_len=256, remat=True, remat_policy="qkv_attn",
+    rope_theta=None, layer_types=("mla_sparse", "mla_sparse"), layer_ropes=(Rope(theta=1e4),) * 2,
+    q_lora_rank=64, kv_lora_rank=64, qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128,
+    index_heads=8, index_head_dim=128, index_topk=32,
+)
+_INDEX_PRODUCTS = r"tensor<\d+x256x8x256xf32>"  # a block of queries' products with every key, a head each: the plain form's
+
+
+def test_a_learned_sparse_layer_lowers_for_tpu_with_the_index_kernels_once_a_direction():
+    """PR 67: the indexer's scores are `dsa_index_fwd` in the layer's forward
+    and `dsa_index_bwd_dq` / `dsa_index_bwd_dk` in its backward, once each
+    (the scan's one body; neither form names a residual, and the recompute
+    needs the scores for nothing: the mask and the KL term's gradient are kept),
+    under `dsa/index`; and no `[256, 8, 256]` float32 block of a query block's
+    products is in the step.  One device: the target's kernel `dsa_target` sits
+    under no `shard_map` (PR 66), so the layer lowers on no larger mesh."""
+    text = _lowered_text(1, MeshSpec(data=1), "dp", platforms=("tpu",), cfg=DOTS3, debug_info=True, seq=256)
+    kernels = _mosaic_kernels(text)
+    assert {name: n for name, n in kernels.items() if name.startswith("dsa_index")} == {
+        "dsa_index_fwd": 1, "dsa_index_bwd_dq": 1, "dsa_index_bwd_dk": 1}, kernels
+    for kernel, path in _kernel_paths(text, "dsa_index_fwd|dsa_index_bwd_dq|dsa_index_bwd_dk"):
+        assert "dsa/index" in path and kernel in path and "rematted_computation" not in path, path
+    assert not re.search(_INDEX_PRODUCTS, text)
+
+
+def test_the_index_kernels_lower_for_tpu_on_a_mesh_under_shard_map():
+    """The op alone on four devices, its batch over `fsdp`: the scaffold's `shard_map` holds the three kernels."""
+    from ray_tpu.ops import sparse_attention as sa
+
+    mesh = build_mesh(MeshSpec(data=1, fsdp=4), devices=jax.devices()[:4])
+    scores = lambda qi, ki, w: jnp.sum(sa.index_scores(qi, ki, w, mesh=mesh, batch_axes=("data", "fsdp")))
+    shapes = (jax.ShapeDtypeStruct((8, 256, 8, 128), jnp.bfloat16), jax.ShapeDtypeStruct((8, 256, 128), jnp.bfloat16),
+              jax.ShapeDtypeStruct((8, 256, 8), jnp.float32))
+    text = jax.jit(jax.value_and_grad(scores, argnums=(0, 1, 2))).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert _mosaic_kernels(text) == {"dsa_index_fwd": 1, "dsa_index_bwd_dq": 1, "dsa_index_bwd_dk": 1}
+    assert "tensor<2x8x256x128xbf16>" in text  # a device's two rows, heads first: inside the shard_map
+
+
+def test_a_learned_sparse_step_lowered_for_the_cpu_holds_no_kernel_and_the_plain_forms_products():
+    text = _lowered_text(1, MeshSpec(data=1), "dp", cfg=DOTS3, seq=256)
+    assert "tpu_custom_call" not in text and re.search(_INDEX_PRODUCTS, text)
