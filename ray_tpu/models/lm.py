@@ -36,6 +36,7 @@ from ray_tpu.models.transformer import (
     LOGITS_AXES,
     TransformerConfig,
     _constrainer,
+    biases_following_load,
     check_placement,
     diffusion_forward,
     forward,
@@ -198,7 +199,8 @@ def diffusion_noise(key: jax.Array, tokens: jax.Array, *, block: int, mask_id: i
 # visits (a constant of the traced step, like the two of PR 55); and the share of a flash forward's run steps whose tile
 # the mask's edge does not cross, which run the body without the mask (PR 63; a constant of the traced step too); and the
 # share of the layers with a `KernelPair` recurrence whose forward kernel the backward runs again (PR 64; a constant too); and
-# the tile pairs the indexer's forward kernel runs, of all tile pairs (PR 67; a constant too).
+# the tile pairs the indexer's forward kernel runs, of all tile pairs (PR 67; a constant too); and the mean gate value
+# of a router that is a network with a carried state, and the experts a layer has in use where the stored bias follows the load (PR 68).
 WINDOW_TILES = "attn_window_tiles_visited_pct"
 CAUSAL_STEPS = "attn_causal_steps_copying_pct"
 MASKED_SHARE = "diffusion_masked_share"
@@ -207,9 +209,12 @@ TILES_UNMASKED = "attn_tiles_unmasked_pct"
 SCAN_RERUN = "scan_forward_rerun_pct"
 CAUSAL_PAIRS = "dsa_causal_pairs"
 INDEX_TILES = "dsa_index_tiles_visited_pct"
+CHOICE_SHARE = "moe_choice_share"  # the layers' `choice_share` on its way from `_loss` to the bias it moves (`router_bias_update_rate`); no metric
+EXPERTS_IN_USE = "moe_experts_in_use"  # `router_losses`, of a job with `router_bias_update_rate`: 16 of 16 is what the rule keeps up
+GATE_MEAN = "moe_gate_mean"  # an "mlp" router's mean gate value (`models/moe.py` `router_losses`: top-1 of 16 starts near 1/16)
 STEP_COUNTERS = (WINDOW_TILES, CAUSAL_STEPS, "moe_held_rows_mean", "moe_held_rows_max", "moe_load_max_over_mean", "moe_rows_moved_share",
                  "mtp_loss", MASKED_SHARE, DIFFUSION_FILL, TILES_UNMASKED, SCAN_RERUN, INDEX_KL, SELECTED_PAIRS, CAUSAL_PAIRS,
-                 INDEX_TILES)
+                 INDEX_TILES, GATE_MEAN, EXPERTS_IN_USE)
 
 
 def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
@@ -444,6 +449,10 @@ class LMTrainContext:
             },
         )
 
+        def _load_of(router_stats):
+            """Beside the terms of a job whose router bias follows the load: what `_train_step` moves it by."""
+            return {CHOICE_SHARE: router_stats["choice_share"]} if cfg.router_bias_update_rate else {}
+
         def _diffusion_loss(params, batch, key):
             """A block-diffusion model's `_loss`: (the block-diffusion NELBO of
             the module docstring + the router's terms over all 2S rows, the
@@ -469,7 +478,7 @@ class LMTrainContext:
                     return ce, counters
                 terms = router_losses(router_stats, cfg)
                 loss = ce + cfg.router_aux_loss_coef * terms["moe_lb_loss"] + cfg.router_z_loss_coef * terms["moe_z_loss"]
-                return loss, {"ce_loss": ce, **terms, **counters}
+                return loss, {"ce_loss": ce, **terms, **counters, **_load_of(router_stats)}
 
         def _loss(params, batch, noise_key=None):
             """(the objective that is differentiated, its terms).  Dense: the
@@ -507,7 +516,7 @@ class LMTrainContext:
                 terms = router_losses(router_stats, cfg)
                 loss = (loss + cfg.router_aux_loss_coef * terms["moe_lb_loss"]
                         + cfg.router_z_loss_coef * terms["moe_z_loss"])
-                return loss, {"ce_loss": ce, **beside, **terms, **counters}
+                return loss, {"ce_loss": ce, **beside, **terms, **counters, **_load_of(router_stats)}
 
         self._loss = _loss
 
@@ -519,10 +528,15 @@ class LMTrainContext:
             with tracing.scope("autodiff", host_only=True):
                 (loss, terms), grads = jax.value_and_grad(_loss, has_aux=True)(
                     state["params"], batch, *noise)
+            share = terms.pop(CHOICE_SHARE, None)
             with tracing.scope("optimizer"):
                 updates, opt_state = opt.update(grads, state["opt_state"], state["params"])
                 params = optax.apply_updates(state["params"], updates)
                 grad_norm = optax.global_norm(grads)
+                if share is not None:
+                    # the one update that is not the optimizer's: each expert layer's stored bias follows this step's
+                    # load (the optimizer's result for it, a weight decay of a leaf without a gradient, is dropped)
+                    params = biases_following_load(params, state["params"], share, cfg)
             metrics = {
                 "loss": loss,
                 "grad_norm": grad_norm,

@@ -71,6 +71,9 @@ class Mixer:
     # stacks them over the kind's layers, [layers of the kind] each, for the objective and the step counters (models/lm.py)
     reports: Tuple[str, ...] = ()
     holds_heads: bool = False  # it reads `TransformerConfig.head_share` (a model with one has no layer of a kind that does not)
+    # its `mix` joins the stream through the layer's learned scaling (`joined(..., scaling=layer_params.get("res1"))`): a model with
+    # `TransformerConfig.residual_scaling` has no layer of a kind that does not
+    scales_residual: bool = False
     # (config, rules, mesh) -> None; raises ValueError on what the kind cannot run UNDER THESE RULES ON THIS MESH,
     # when the three first meet (`transformer.check_placement`), not when a step is traced
     placement: Callable[[Any, Optional[Rules], Any], None] = lambda config, rules, mesh: None
@@ -170,12 +173,24 @@ def constrainer(rules: Optional[Rules], mesh):
     return lambda h, axes: with_logical_constraint(h, axes, rules, mesh)
 
 
-def joined(config, x: jax.Array, block_out: jax.Array, constrain) -> jax.Array:
-    """The residual stream after a block's output joined it."""
+def residual_scaling_leaves(config) -> Dict[str, Leaf]:
+    """One sub-block's learned residual scaling (`TransformerConfig.residual_scaling`): four [d] vectors, the
+    identity at the seed."""
+    d = (config.d_model,)
+    return {"a_res": ones(d), "b_res": zeros(d), "a_out": ones(d), "b_out": zeros(d)}
+
+
+def joined(config, x: jax.Array, block_out: jax.Array, constrain, scaling: Optional[Dict] = None) -> jax.Array:
+    """The residual stream after a block's output joined it: `x + block_out`, or with the sub-block's learned
+    `scaling` (ZAYA1's: `residual_scaling_leaves`) `(a_res * x + b_res) + (a_out * block_out + b_out)`, in
+    float32 with one rounding to the stream's dtype."""
     block_out = constrain(block_out, ("act_batch", "act_seq", "act_embed"))
     if config.residual_multiplier != 1.0:
         block_out = block_out * jnp.asarray(config.residual_multiplier, block_out.dtype)
-    return x + block_out
+    if scaling is None:
+        return x + block_out
+    a_res, b_res, a_out, b_out = (scaling[name].astype(jnp.float32) for name in ("a_res", "b_res", "a_out", "b_out"))
+    return ((a_res * x.astype(jnp.float32) + b_res) + (a_out * block_out.astype(jnp.float32) + b_out)).astype(x.dtype)
 
 
 def batch_sharded(rules: Optional[Rules], mesh) -> Dict:

@@ -27,7 +27,19 @@ The layer, on T tokens with K choices each out of E experts:
   `score + router_bias` (the stored `e_score_correction_bias`, which takes
   part in the choice alone: it reaches no gate value and gets no gradient),
   the gate values are the chosen SCORES, renormalised under `norm_topk_prob`,
-  times `routed_scaling_factor`.
+  times `routed_scaling_factor`.  The logits are one linear map of the token
+  (`router_kind` "linear") or ZAYA1's network with a state that runs from
+  layer to layer ("mlp", arXiv:2511.17127; all float32, matmuls at HIGHEST):
+  `r = h W_d + b_d` [T, `router_hidden`], `r <- r + gamma * r_prev` with
+  `r_prev` the state the layer before handed on (zeros before the first, whose
+  `gamma` so multiplies nothing) and `gamma` a learned [router_hidden] vector,
+  1 at the seed; r is handed on (`router_state`); the logits are `W_3
+  gelu(W_2 gelu(W_1 RMSNorm(r) + b_1) + b_2)` (exact GeLU, a learned norm
+  scale); such a router has a stored `router_bias` that takes part in the
+  choice alone, whatever its activation, and reports its mean gate value
+  (`gate_mean`).  K = 1 is a top-k like any other: the gate is the chosen
+  expert's unnormalised score (`norm_topk_prob` would make it 1 and cut the
+  router's gradient).
 - `moe/dispatch`: a stable sort of the T*K assignments by expert, the E group
   sizes, a gather of the token rows into expert order, and the T*K gate values
   into the same order (by a sort: `_permuted`).
@@ -134,13 +146,21 @@ def moe_param_axes(config: Any) -> Dict:
     one = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
     names = expert_leaves(config)
     axes = {"router": ("embed", "expert"), **{n: ("expert",) + one[n] for n in names}}
-    if config.router_activation == "sigmoid":
+    if config.router_kind == "mlp":
+        axes["router"] = {"down": ("embed", None), "w1": (None, None), "w2": (None, None), "w3": (None, "expert"),
+                          **{n: (None,) for n in ("down_b", "gamma", "norm", "b1", "b2")}}
+    if _has_router_bias(config):
         axes["router_bias"] = (None,)
     if config.shared_expert_width:
         axes["shared"] = {n: one[n] for n in names}
         if config.shared_expert_gate:
             axes["shared"]["gate"] = ("embed", None)
     return axes
+
+
+def _has_router_bias(config: Any) -> bool:
+    """Whether the layer stores a bias that takes part in the router's choice alone."""
+    return config.router_activation == "sigmoid" or config.router_kind == "mlp"
 
 
 def expert_leaves(config: Any) -> Tuple[str, ...]:
@@ -171,14 +191,23 @@ def init_moe_params(config: Any, key: jax.Array, leading: Tuple[int, ...] = (),
 
     # `routed_branch_init`: a token's K routed outputs are ONE residual branch of the depth-scaled variance, 1 / K each
     routed_down = down_scale * c.experts_per_token ** -0.5 if c.routed_branch_init else down_scale
-    router = init(k1, (D, E), scale)
+    if c.router_kind == "mlp":
+        R = c.router_hidden
+        kd, kw1, kw2, kw3 = jax.random.split(k1, 4)
+        vector = lambda value: jnp.full(leading + (R,), value, c.param_dtype)  # noqa: E731
+        router = {"down": init(kd, (D, R), scale), "down_b": vector(0.0), "gamma": vector(1.0), "norm": vector(1.0),
+                  "w1": init(kw1, (R, R), R ** -0.5), "b1": vector(0.0), "w2": init(kw2, (R, R), R ** -0.5), "b2": vector(0.0),
+                  "w3": init(kw3, (R, E), R ** -0.5)}
+    else:
+        router = init(k1, (D, E), scale)
     if c.router_share_init:  # every share's block starts as the first: a token's K choices start K * held / E on each share
         router = jnp.tile(router[..., :held], E // held)
     params = {"router": router, **matrices((k2, k3, k4), F, routed_down, (held,))}
-    if c.router_activation == "sigmoid":
-        # zero, and the job leaves it so: its published update follows the
-        # experts' load, outside the gradient (a recipe, not a key of a config)
-        params["router_bias"] = jnp.zeros(leading + (E,), c.param_dtype)
+    if _has_router_bias(c):
+        # zero at the seed; a job with `router_bias_update_rate` moves it after every step by the experts' load,
+        # outside the gradient (`load_following_bias`), and any other leaves it so.  An "mlp" router's is float32 as
+        # the rest of that router is: steps of 1e-3 are under bf16's spacing from 0.125 on
+        params["router_bias"] = jnp.zeros(leading + (E,), jnp.float32 if c.router_kind == "mlp" else c.param_dtype)
     if c.shared_expert_width:
         params["shared"] = matrices(jax.random.split(jax.random.fold_in(key, 1), 3), c.shared_expert_width, down_scale)
         if c.shared_expert_gate:
@@ -314,20 +343,47 @@ _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
 # -- the layer ---------------------------------------------------------------------
 
 
-def _route(params: Dict, tokens: jax.Array, config: Any):
+def _dot32(a: jax.Array, b: jax.Array) -> jax.Array:
+    """A float32 product at precision HIGHEST: on a TPU a float32 matmul is otherwise bf16 passes, and routing is discrete."""
+    return jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+
+
+def router_state(params: Dict, x: jax.Array, config: Any, prev: jax.Array) -> jax.Array:
+    """The "mlp" router's state of one layer, float32 [B, S, router_hidden]: the down-projection of the normed hidden
+    state x [B, S, D] plus `gamma` times the state `prev` the layer before handed on (module docstring).  It is what
+    the layer hands to the next one and what its own `moe_ffn(..., router_state=...)` routes by."""
+    p = params["router"]
+    with tracing.scope("moe/router"):
+        r = _dot32(x.reshape(-1, x.shape[-1]), p["down"]) + p["down_b"].astype(jnp.float32)
+        return r.reshape(*x.shape[:-1], -1) + p["gamma"].astype(jnp.float32) * prev
+
+
+def _mlp_logits(p: Dict, state: jax.Array, eps: float) -> jax.Array:
+    """The "mlp" router's logits [T, E] from its state [T, router_hidden] (module docstring), float32."""
+    f32 = jnp.float32
+    r = state * jax.lax.rsqrt(jnp.mean(jnp.square(state), axis=-1, keepdims=True) + eps) * p["norm"].astype(f32)
+    r = jax.nn.gelu(_dot32(r, p["w1"]) + p["b1"].astype(f32), approximate=False)
+    r = jax.nn.gelu(_dot32(r, p["w2"]) + p["b2"].astype(f32), approximate=False)
+    return _dot32(r, p["w3"])
+
+
+def _route(params: Dict, tokens: jax.Array, config: Any, state: Optional[jax.Array] = None):
     """Router of one layer on tokens [T, D]: (expert_idx [T, K] int32, gates
-    [T, K] float32, statistics for the router losses)."""
+    [T, K] float32, statistics for the router losses).  An "mlp" router
+    routes by its `state` [T, router_hidden] (`router_state`) and not by the
+    tokens themselves."""
     c = config
     E, K = c.n_experts, c.experts_per_token
-    logits = jnp.dot(tokens.astype(jnp.float32), params["router"].astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)  # [T, E]
-    if c.router_activation == "sigmoid":
-        probs = jax.nn.sigmoid(logits)  # each expert's own score
+    if c.router_kind == "mlp":
+        logits = _mlp_logits(params["router"], state, c.norm_eps)
+    else:
+        logits = _dot32(tokens, params["router"])  # [T, E]
+    probs = jax.nn.sigmoid(logits) if c.router_activation == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    if _has_router_bias(c):  # sigmoid: each expert's own score
         bias = jax.lax.stop_gradient(params["router_bias"].astype(jnp.float32))
         _, expert_idx = jax.lax.top_k(probs + bias, K)  # the bias takes part in the choice alone
         gates = jnp.take_along_axis(probs, expert_idx, axis=-1)
     else:
-        probs = jax.nn.softmax(logits, axis=-1)
         gates, expert_idx = jax.lax.top_k(probs, K)
     if c.norm_topk_prob:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
@@ -339,6 +395,8 @@ def _route(params: Dict, tokens: jax.Array, config: Any):
         "mean_prob": jnp.mean(probs, axis=0),  # P[e]
         "z": jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1))),
     }
+    if c.router_kind == "mlp":
+        stats["gate_mean"] = jnp.mean(gates)  # the mean gate value of the layer's assignments
     return expert_idx, gates, stats
 
 
@@ -502,15 +560,19 @@ def moe_ffn(
     *,
     rules: Optional[Rules] = None,
     mesh=None,
+    router_state: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x [B, S, D] (the normed hidden state) -> (y [B, S, D], this layer's
     router statistics: `choice_share` [K, E], `mean_prob` [E], `z` [], and
-    with held experts `held_rows` [n_experts_held] and `rows_moved_share` []).  `_ffn_half` calls it
-    inside its `layer/mlp` scope (PERF.md section 3)."""
+    with held experts `held_rows` [n_experts_held] and `rows_moved_share` [],
+    with an "mlp" router `gate_mean` []).  `_ffn_half` calls it
+    inside its `layer/mlp` scope (PERF.md section 3), an "mlp" router's layer
+    with this layer's `router_state` [B, S, router_hidden] (`router_state()`)."""
     B, S, D = x.shape
     held = config.n_experts_held is not None
     with tracing.scope("moe/router"):
-        expert_idx, gates, stats = _route(params, x.reshape(B * S, D), config)
+        state = None if router_state is None else router_state.reshape(B * S, -1)
+        expert_idx, gates, stats = _route(params, x.reshape(B * S, D), config, state)
     expert_idx = expert_idx.reshape(B, S, -1)
     gates = gates.reshape(B, S, -1)
     weights = [params[k].astype(x.dtype) for k in expert_leaves(config)]
@@ -564,6 +626,17 @@ def moe_ffn(
     return with_shared(y), stats
 
 
+def load_following_bias(bias: jax.Array, choice_share: jax.Array, rate: float) -> jax.Array:
+    """The stored `router_bias` [..., E] after one step of the load-following rule (auxiliary-loss-free balancing,
+    arXiv:2408.15664, as DeepSeek-V3 trains with it): `bias_e + rate * sign(mean load - load_e)`, the load of an
+    expert the share of the step's assignments it took (`choice_share` [..., K, E], this step's, summed over the K
+    choices).  An expert over the mean loses `rate` of its part in the choice, one under it gains as much; the bias
+    reaches no gate value and no gradient reaches it (`_route`).  The step is taken in float32."""
+    load = jnp.sum(choice_share, axis=-2)  # [..., E], K in all
+    step = jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
+    return (bias.astype(jnp.float32) + rate * step).astype(bias.dtype)
+
+
 def router_losses(stats: Dict[str, jax.Array], config: Any) -> Dict[str, jax.Array]:
     """The router losses and the load figure of a step, from the statistics
     the layer scan stacked ([L, ...] each); formulas in the module docstring.
@@ -584,4 +657,9 @@ def router_losses(stats: Dict[str, jax.Array], config: Any) -> Dict[str, jax.Arr
         out["moe_held_rows_max"] = jnp.max(stats["held_rows"])
         # rows of the rung the share's buffers took over the T*K assignments, mean over layers (1.0: all were moved)
         out["moe_rows_moved_share"] = jnp.mean(stats["rows_moved_share"])
+    if config.router_bias_update_rate:
+        # what the load-following bias is there to keep up: the experts of a layer that got a row this step, mean over the layers
+        out["moe_experts_in_use"] = jnp.mean(jnp.sum((load > 0).astype(jnp.float32), axis=1))
+    if "gate_mean" in stats:
+        out["moe_gate_mean"] = jnp.mean(stats["gate_mean"])  # an "mlp" router's mean gate value, over the layers
     return out

@@ -46,7 +46,11 @@ learned indexer selects and report its KL term, `index_*`; "mla_window" layers
 read `window_latent` under the layer's window), rescaled latents, head-wise
 gates, and a model TOLD which heads it holds (`head_share`, beside
 `n_experts_held`); what such layers report leaves the stack through
-`trunk_reports`.
+`trunk_reports`; and ZAYA1-8B: "cca" layers (mixers/cca.py: attention in a
+compressed latent mixed along the sequence), top-1 experts behind a router
+that is a network whose state runs from layer to layer (`router_kind` "mlp":
+models/moe.py; `trunk`'s scans carry the state beside the stream), and a
+learned scale and bias on both sides of every join (`residual_scaling`).
 
 The reference has no model code of its own (it trains user-supplied torch
 models through wrappers — python/ray/train/torch/train_loop_utils.py:92-98);
@@ -86,7 +90,11 @@ LayerNorm, a bias) and `norm_eps`:
 What crosses layers (an s6 layer's scan output; a diff_attention layer's k
 and v) is RETURNED by the layer that makes it, carried by `trunk` beside the
 stream and given to the later runs that read it as an argument
-(`Mixer.hands` / `Mixer.reads`).  `tp`, `pp` and the sequence-parallel ring
+(`Mixer.hands` / `Mixer.reads`).  What EVERY layer reads from the one before
+and hands to the one after (an "mlp" router's state, the FFN half's) is part
+of the scans' carry, `carried`, beside the stream: an input and an output of
+each layer's checkpoint, its cotangent running back through the chain; `pp`
+refuses it by name until it crosses stages.  `tp`, `pp` and the sequence-parallel ring
 refuse the differential kinds by name (`pp` any `layer_types`); what a kind
 cannot run under given rules on a given mesh (the ring a window, a layer's
 own rope, a per-head QK-norm; `pp` those and any stack that is not
@@ -106,10 +114,12 @@ import jax.numpy as jnp
 
 from ray_tpu.models.mixers import MIXERS, Leaf, Mixer
 from ray_tpu.models.mixers.mla import Latent
-from ray_tpu.models.mixers.base import joined, norm_scale, normal, ones, out_scale, proj_scale, stream_norm, zeros
+from ray_tpu.models.mixers.base import (
+    fitting_axis, joined, norm_scale, normal, ones, out_scale, proj_scale, residual_scaling_leaves, stream_norm, zeros,
+)
 # the names lm.py and the tests hold these two by
 from ray_tpu.models.mixers.base import constrainer as _constrainer, rms_norm  # noqa: F401
-from ray_tpu.models.moe import init_moe_params, moe_ffn, moe_param_axes
+from ray_tpu.models.moe import init_moe_params, load_following_bias, moe_ffn, moe_param_axes, router_state
 from ray_tpu.ops.attention import ATTN_LSE, ATTN_OUT
 from ray_tpu.ops.rotary import Rope
 from ray_tpu.parallel.sharding import Rules, pipeline_axes, with_logical_constraint
@@ -206,6 +216,20 @@ class TransformerConfig:
     shared_expert_d_ff: Optional[int] = None
     routed_branch_init: bool = False
     router_share_init: bool = False
+    # What ZAYA1 adds (arXiv:2511.17127).  `router_kind`: "linear", the logits one map of the token, or "mlp", a network
+    # over a `router_hidden`-wide state that runs from layer to layer (models/moe.py), with a stored `router_bias`.
+    # `residual_scaling`: every sub-block joins the stream as `(a_res * x + b_res) + (a_out * y + b_out)`, four learned
+    # [d_model] vectors a sub-block (`res1` the mixer's, `res2` the FFN's; the identity at the seed), in every layer, all
+    # of a kind that joins through them (`Mixer.scales_residual`).  `cca_taps`: the taps of a "cca" layer's two causal
+    # convolutions over its q|k latent (mixers/cca.py), read only when some layer is "cca".  `router_bias_update_rate`:
+    # after every training step each expert layer's stored `router_bias` moves by this much toward the experts under
+    # the mean load and away from those over it, outside the gradient and the optimizer (`biases_following_load`;
+    # 0: the bias stays what it is, and nothing of it is traced).
+    router_kind: str = "linear"
+    router_hidden: int = 0
+    residual_scaling: bool = False
+    cca_taps: Tuple[int, int] = (2, 2)
+    router_bias_update_rate: float = 0.0
     # RMSNorm with a learned scale on q and k, before RoPE.  True: over the
     # whole projected q and k (OLMoE, OLMo 2); "per_head": over each head of
     # them, one scale of `head_dim` for q's heads and one for k's (the Qwen3
@@ -421,6 +445,22 @@ class TransformerConfig:
             )
         if self.router_activation not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router_activation {self.router_activation!r}")
+        object.__setattr__(self, "cca_taps", tuple(self.cca_taps))
+        if self.router_kind not in ("linear", "mlp") or (self.router_kind == "mlp" and (
+                self.n_experts is None or self.router_hidden < 1 or self.router_share_init or self.mtp_depth)):
+            raise ValueError(f"router_kind is 'linear' or 'mlp', got {self.router_kind!r}; 'mlp' needs n_experts and "
+                             f"router_hidden >= 1 (got {self.router_hidden}) and takes no router_share_init (its columns are a "
+                             "network's) and no mtp_depth (the module's block lies behind the chain its router state runs along)")
+        if self.router_bias_update_rate and (self.router_bias_update_rate < 0 or self.mtp_depth or self.n_experts is None or not (
+                self.router_activation == "sigmoid" or self.router_kind == "mlp")):
+            raise ValueError(f"router_bias_update_rate {self.router_bias_update_rate} moves a STORED router_bias (router_activation "
+                             "'sigmoid' or router_kind 'mlp') of the stack's expert layers by a rate >= 0; mtp_depth's block is not one")
+        if self.residual_scaling:
+            others = sorted({kind for kind, _ in self.layer_pairs() if not MIXERS[kind].scales_residual})
+            if others or self.mtp_depth:
+                raise ValueError(f"residual_scaling is every layer's: each of a kind that joins the stream through it "
+                                 f"({[m.name for m in MIXERS.values() if m.scales_residual]}), and no mtp_depth; this model has "
+                                 f"{others or 'mtp_depth'}")
         if self.expert_kind not in ("swiglu", "relu2"):
             raise ValueError(f"unknown expert_kind {self.expert_kind!r}; expected 'swiglu' or 'relu2'")
         if self.n_experts_held is not None and not (
@@ -459,6 +499,11 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads if self.attn_head_dim is None else self.attn_head_dim
+
+    @property
+    def carries_router_state(self) -> bool:
+        """Whether the expert layers hand a router state on, each to the next (`trunk`'s `carried`)."""
+        return self.n_experts is not None and self.router_kind == "mlp"
 
     @property
     def mask_id(self) -> int:
@@ -590,8 +635,10 @@ def _layer_leaves(config: TransformerConfig, mixer: str, ffn: str) -> Dict:
     norms = {"ln1": norm_scale(c, (d,)), "ln2": norm_scale(c, (d,))}
     if c.norm_kind == "layer":
         norms.update(ln1_b=zeros((d,)), ln2_b=zeros((d,)))
+    if c.residual_scaling:  # behind the norms: constants, which draw no key
+        norms.update(res1=residual_scaling_leaves(c), res2=residual_scaling_leaves(c))
     if ffn == "none":
-        return {m.subtree: m.leaves(c), **{name: leaf for name, leaf in norms.items() if name.startswith("ln1")}}
+        return {m.subtree: m.leaves(c), **{name: norms[name] for name in ("ln1", "ln1_b", "res1") if name in norms}}  # the mixer half's
     return {m.subtree: m.leaves(c), "mlp": dense if ffn == "dense" else None, **norms}
 
 
@@ -696,27 +743,35 @@ def _dense_ffn_bwd(constrain, swiglu_vjp, d_out):
 _dense_ffn.defvjp(_dense_ffn_fwd, _dense_ffn_bwd)
 
 
-def _ffn_half(x, layer_params, config, constrain, rules, mesh, ffn=None):
+def _ffn_half(x, layer_params, config, constrain, rules, mesh, ffn=None, carried=None):
     """The second half of every layer, whatever its mixer: (x + FFN(ln2(x)),
-    router statistics or None).  `ffn`: "dense", "experts" (None: experts
-    where the configuration has any) or "none": the mixer's result goes on as
-    it is, and nothing is traced under `layer/mlp`."""
+    router statistics or None, `carried` as the next layer takes it).  `ffn`:
+    "dense", "experts" (None: experts where the configuration has any) or
+    "none": the mixer's result goes on as it is, and nothing is traced under
+    `layer/mlp`.  `carried`: what every layer reads from the one before and
+    hands to the one after, by name ({} in a model with nothing of the sort):
+    `router_state`, which an expert layer behind an "mlp" router reads,
+    replaces with its own and routes by."""
     c, dt = config, config.dtype
-    router_stats = None
+    router_stats, carried = None, carried or {}
     if ffn is None:
         ffn = "dense" if c.n_experts is None else "experts"
     if ffn == "none":
-        return x, None
+        return x, None, carried
     with tracing.scope("layer/mlp"):
         h = stream_norm(c, x, layer_params, "ln2")
         if ffn == "experts":
-            down, router_stats = moe_ffn(layer_params["mlp"], h, c, rules=rules, mesh=mesh)
+            state = None
+            if c.carries_router_state:
+                state = router_state(layer_params["mlp"], h, c, carried["router_state"])
+                carried = {"router_state": state}
+            down, router_stats = moe_ffn(layer_params["mlp"], h, c, rules=rules, mesh=mesh, router_state=state)
         else:
             mlp = layer_params["mlp"]
             down = _dense_ffn(
                 constrain, h, mlp["w_gate"].astype(dt), mlp["w_up"].astype(dt), mlp["w_down"].astype(dt)
             )
-        return joined(c, x, down, constrain), router_stats
+        return joined(c, x, down, constrain, layer_params.get("res2")), router_stats, carried
 
 
 def layer(
@@ -735,18 +790,22 @@ def layer(
     emit: bool = False,
     rope: Optional[Rope] = None,
     noisy_rows: Optional[int] = None,
+    carried: Optional[Dict] = None,
 ):
     """One layer of any kind, the kind's `mix` and then the FFN half: (x,
     this layer's router statistics, None when the FFN is dense; what it hands
-    on to later layers, by name).  `ffn` is the layer's kind of FFN (None:
-    what the configuration's every layer has); the rest is `Mixer.mix`'s,
-    `rope` given only where the layer has one of its own, `noisy_rows` only in
-    a block-diffusion model (how many of x's first rows are the noisy copy)."""
+    on to later layers, by name; `carried` as the NEXT layer takes it).  `ffn`
+    is the layer's kind of FFN (None: what the configuration's every layer
+    has); `carried` what `_ffn_half` reads of the layer before (None: nothing);
+    the rest is `Mixer.mix`'s, `rope` given only where the layer has one of
+    its own, `noisy_rows` only in a block-diffusion model (how many of x's
+    first rows are the noisy copy)."""
     x, handed = mixer.mix(x, layer_params, positions, config, rules, mesh,
                           window=window, data=data, shared=shared, emit=emit,
                           **({} if rope is None else {"rope": rope}),
                           **({} if noisy_rows is None else {"noisy_rows": noisy_rows}))
-    return (*_ffn_half(x, layer_params, config, _constrainer(rules, mesh), rules, mesh, ffn), handed)
+    x, stats, carried = _ffn_half(x, layer_params, config, _constrainer(rules, mesh), rules, mesh, ffn, carried)
+    return x, stats, handed, carried
 
 
 def _layer(x, layer_params, positions, config, rules, mesh=None, ffn=None, window=None):
@@ -819,6 +878,9 @@ def _refuse_unequal_stages(config: TransformerConfig) -> None:
     """What the pipeline schedule cannot run, by name: every stage scans ONE
     compiled body of the default kind over its share of ONE stack."""
     c = config
+    if c.carries_router_state:
+        raise ValueError("strategy 'pp' does not carry the router state (router_kind 'mlp': each expert layer reads the "
+                         "state of the layer before) across its stages")
     if c.n_experts is not None:
         raise ValueError(
             "strategy 'pp' runs dense layers only: the router statistics of "
@@ -849,6 +911,10 @@ def check_placement(config: TransformerConfig, rules: Optional[Rules], mesh) -> 
         return
     for kind in dict.fromkeys(m for m, _ in config.layer_pairs()):
         MIXERS[kind].placement(config, rules, mesh)
+    if config.carries_router_state and any(fitting_axis(rules.get(name), mesh, 0) is not None
+                                           for name in ("act_heads", "act_mlp", "expert")):
+        raise ValueError("the router state (router_kind 'mlp') runs from layer to layer whole on each device: strategy 'tp' "
+                         "and a mesh's expert axis do not take it")
     if pipeline_axes(rules, mesh, config.n_layers) is not None:
         _refuse_unequal_stages(config)
         if config.mtp_depth:
@@ -1020,6 +1086,12 @@ def trunk_reports(
                 stacks[mixer, ffn] = iter([params[name]] if whole else _split_runs(params[name], bounds))
             per_run = []
             shared = {}  # what layers have handed on so far (`Mixer.hands`), by name
+            # what every layer reads from the one before (`_ffn_half`), part of the scans' carry: before the first
+            # expert layer the router's state is zeros, so that layer's own state is its down-projection alone
+            carried = {}
+            if c.carries_router_state:
+                carried["router_state"] = _constrainer(rules, mesh)(
+                    jnp.zeros((*x.shape[:2], c.router_hidden), jnp.float32), ("act_batch", "act_seq", None))
             for (kind, ffn, _, count), start in zip(runs, c.run_starts()):
                 mixer = MIXERS[kind]
                 window, rope, emit = c.layer_variant(start)
@@ -1036,12 +1108,12 @@ def trunk_reports(
                 # one layer: `layer_variant`).  A kind with neither takes and
                 # returns empty mappings: nothing of the traced program.
                 def body(carry, xs, layer_fn=layer_fn, read={name: shared[name] for name in mixer.reads}):
-                    carry, stats, handed = layer_fn(carry, xs[0], data=xs[1], shared=read)
-                    return carry, (stats, handed)
+                    x, stats, handed, state = layer_fn(carry[0], xs[0], data=xs[1], shared=read, carried=carry[1])
+                    return (x, state), (stats, handed)
 
                 data = {name: jnp.asarray(values[start: start + count], jnp.float32)
                         for name, values in mixer.data(c).items()}
-                x, (run_stats, handed) = jax.lax.scan(body, x, (next(stacks[kind, ffn]), data))
+                (x, carried), (run_stats, handed) = jax.lax.scan(body, (x, carried), (next(stacks[kind, ffn]), data))
                 shared.update({name: handed[name][0] for name in mixer.hands if name in handed})
                 for name in mixer.reports:
                     reports[name] = handed[name] if name not in reports else jnp.concatenate([reports[name], handed[name]])
@@ -1068,6 +1140,25 @@ def trunk_reports(
             # pass over the logits.
             x = x / jnp.asarray(c.logits_scaling, x.dtype)
     return x, head, router_stats, reports
+
+
+def biases_following_load(params: Dict, before: Dict, choice_share: jax.Array, config: TransformerConfig) -> Dict:
+    """`params` with every expert layer's stored `router_bias` set to `before`'s one step of
+    `moe.load_following_bias` on, at `config.router_bias_update_rate`: `choice_share` [expert layers, K, E] is the
+    step's router statistic of that name as `trunk` stacks it, in the stack's order, and each run of expert layers
+    takes its rows to its pair's stack.  What a training step does to the bias IN PLACE OF the optimizer's update
+    (`before`: the step's own parameters, `params`: the optimizer's result; `models/lm.py` `_train_step`)."""
+    c, out, at = config, dict(params), 0
+    for kind, ffn, first, count in c.layer_runs():
+        if ffn != "experts":
+            continue
+        name = c.stack_name(kind, ffn)
+        bias = before[name]["mlp"]["router_bias"]
+        moved = load_following_bias(bias[first: first + count], choice_share[at: at + count], c.router_bias_update_rate)
+        out[name] = {**out[name], "mlp": {**out[name]["mlp"], "router_bias": bias.at[first: first + count].set(moved)}}
+        before = {**before, name: out[name]}  # the pair's next run moves its own rows of the same leaf
+        at += count
+    return out
 
 
 def mtp_rows(
@@ -1106,7 +1197,7 @@ def mtp_rows(
                                   mesh=mesh, ffn=ffn, window=window, rope=rope)
         if c.remat:
             block = jax.checkpoint(block, policy=_remat_policy(c))
-        x, stats, _ = block(x, p["block"])
+        x, stats, *_ = block(x, p["block"])
         with tracing.scope("mtp/proj"):
             x = rms_norm(x, p["norm"], c.norm_eps)
     return x, None if stats is None else jax.tree_util.tree_map(lambda a: a[None], stats)

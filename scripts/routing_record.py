@@ -8,8 +8,10 @@ record kept of the router, step by step:
 traced run and never their series; a cell that holds a SHARE of its experts
 has a step time that follows the rows the router gives the held ones, so a
 spread over seeds is read beside this line: `[routing] {"seed", "tokens_per_s",
-"series": [[train_step call, {moe_load_max_over_mean, moe_held_rows_mean,
-moe_held_rows_max, moe_rows_moved_share}], ...]}` (calls 0..2 are the compile
+"series": [[train_step call, {every `moe_*` step counter the cell's program
+keeps: moe_load_max_over_mean, a share's moe_held_rows_mean / _max and
+moe_rows_moved_share, a network router's moe_gate_mean, moe_experts_in_use
+where the stored bias follows the load}], ...]}` (calls 0..2 are the compile
 step and the warm-up, the window follows).  A diagnostic for PERF.md: no cell
 or metric reads it, and the run's own result line comes first, unchanged.
 """

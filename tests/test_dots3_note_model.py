@@ -96,7 +96,7 @@ def test_the_stack_is_two_full_runs_and_a_sliding_run_each_with_its_window_and_r
     assert list(cfg.stacks()) == ref.layer_stacks(CONFIG)[:3:1][:1] + ["mla_sparse_layers_experts", "mla_window_layers"]
     assert [cfg.layer_variant(i)[0] for i in range(5)] == [None, None, 9, 9, 9]
     assert [cfg.layer_variant(i)[1].theta for i in range(5)] == [8e7, 8e7, 5e4, 5e4, 5e4]
-    assert list(MIXERS)[-2:] == ["mla_sparse", "mla_window"]  # appended: no other model's weights move
+    assert list(MIXERS)[9:11] == ["mla_sparse", "mla_window"]  # appended behind the nine before them (PR 68 appended "cca" behind these): no other model's weights move
     assert cfg.num_params() == sum(a.size for a in jax.tree_util.tree_leaves(params))
     axes = transformer.param_axes(cfg)
     assert jax.tree_util.tree_structure(jax.tree_util.tree_map(lambda a: 0, params)) == jax.tree_util.tree_structure(

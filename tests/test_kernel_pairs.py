@@ -138,3 +138,16 @@ def test_residual_names_are_what_the_forward_of_a_backward_puts_on_out_and_on_th
     assert set(states) <= {of_states} and len(named) == len(out) + len(states), (named, states)
     assert bool(states) == (name not in ("conv", "delta_conv", "index"))  # these backwards read their arguments alone
     assert " name[" not in str(jax.make_jaxpr(op)(*taken))
+
+
+def test_the_cca_mixing_declares_no_pair_and_its_check_script_waits_for_one():
+    """PR 68: the q|k mixing of a "cca" layer is plain JAX (no Mosaic kernel: PERF.md section 7 says what one would be
+    worth); its module holds no `KernelPair`, names what `qkv_attn` keeps of it, and `scripts/cca_mix_check.py` holds the
+    mixing to the reference's at the published widths, so that a later kernel PR has its check waiting."""
+    import os
+
+    from ray_tpu.models.mixers import cca
+
+    assert not [v for v in vars(cca).values() if isinstance(v, kernel_pair.KernelPair)]
+    assert cca.MIXER.saved == (cca.LATENT, cca.CONV1, cca.MIXED) and cca.MIXER.recurrence == ()
+    assert os.path.exists(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts", "cca_mix_check.py"))

@@ -44,6 +44,10 @@ SIZES = dict(
 EXPERTS = dict(n_experts=4, experts_per_token=2, moe_d_ff=48, n_shared_experts=1, router_activation="sigmoid")
 # what latent attention's two further kinds read beside SIZES: a low-rank q (the rescale and the indexer read it), the indexer, a second geometry
 SPARSE = dict(q_lora_rank=16, index_heads=2, index_head_dim=16, index_topk=8, window_latent=Latent(2, 16, 24, 16, 8, 32))
+# what a "cca" layer refuses of SIZES (its q and k are unit-normed, with no learned scale)
+CCA = dict(qk_norm=False)
+# ZAYA1's FFN half: top-1 behind a router that is a network with a state carried from layer to layer, every join learned
+ZAYA = dict(n_experts=4, experts_per_token=1, moe_d_ff=48, router_kind="mlp", router_hidden=16)
 
 
 @pytest.mark.parametrize("ffn", FFN_KINDS)
@@ -58,14 +62,46 @@ def test_param_count_matches_config(mixer, ffn):
     kinds = (*(m.name for m in makers), mixer, mixer)
     cfg = TransformerConfig.tiny(
         n_layers=len(kinds), layer_types=kinds, ffn_types=(ffn,) * len(kinds), attn_bias=MIXERS[mixer].subtree == "diff",
-        **{m.source: i for i, m in enumerate(makers)}, **SIZES, **(EXPERTS if ffn == "experts" else {}),
-        **(SPARSE if MIXERS[mixer].holds_heads else {}))
+        **{m.source: i for i, m in enumerate(makers)}, **{**SIZES, **(CCA if mixer == "cca" else {})},
+        **(EXPERTS if ffn == "experts" else {}), **(SPARSE if MIXERS[mixer].holds_heads else {}))
     params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     assert sum(math.prod(a.shape) for a in jax.tree_util.tree_leaves(params)) == cfg.num_params()
     axes = param_axes(cfg)
     ranks = jax.tree_util.tree_map(lambda a: a.ndim, params)
     assert ranks == jax.tree_util.tree_map(len, axes, is_leaf=lambda t: isinstance(t, tuple))
     assert params[cfg.stack_name(mixer, ffn)][MIXERS[mixer].subtree]  # the pair's own stack, the mixer's own subtree
+
+
+@pytest.mark.parametrize("ffn", ["experts", "none"])
+def test_param_count_matches_config_with_a_network_router_and_learned_joins(ffn):
+    """The same three readers for what ZAYA1 adds to a layer: the router's nine leaves and its stored choice bias in the
+    experts' subtree, four residual vectors a sub-block (`res1`, and `res2` where the layer has an FFN)."""
+    cfg = TransformerConfig.tiny(n_layers=2, layer_types=("cca", "cca"), ffn_types=(ffn,) * 2, residual_scaling=True, **ZAYA)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    assert sum(math.prod(a.shape) for a in jax.tree_util.tree_leaves(params)) == cfg.num_params()
+    ranks = jax.tree_util.tree_map(lambda a: a.ndim, params)
+    assert ranks == jax.tree_util.tree_map(len, param_axes(cfg), is_leaf=lambda t: isinstance(t, tuple))
+    layer = params["cca_layers"]
+    assert ("res2" in layer, "ln2" in layer, "mlp" in layer) == (ffn == "experts",) * 3 and set(layer["res1"]) == {"a_res", "b_res", "a_out", "b_out"}
+    if ffn == "experts":
+        assert set(layer["mlp"]["router"]) == {"down", "down_b", "gamma", "norm", "w1", "b1", "w2", "b2", "w3"}
+        assert layer["mlp"]["router_bias"].shape == (2, 4)
+
+
+@pytest.mark.parametrize("strategy,spec,kw,match", [
+    ("pp", MeshSpec(data=4, pipeline=2), ZAYA, "strategy 'pp' does not carry the router state"),
+    ("pp_fsdp", MeshSpec(data=2, fsdp=2, pipeline=2), ZAYA, "strategy 'pp' does not carry the router state"),
+    ("tp", MeshSpec(data=2, tensor=4), ZAYA, "the router state .* strategy 'tp'"),
+    ("ep", MeshSpec(data=2, expert=4), ZAYA, "the router state .* a mesh's expert axis"),
+    ("tp", MeshSpec(data=2, tensor=4), dict(layer_types=("cca", "cca")), "a cca layer runs with its heads and its sequence whole"),
+    ("sp", MeshSpec(data=2, seq=4), dict(layer_types=("cca", "cca")), "a cca layer runs with its heads and its sequence whole"),
+], ids=["pp-router_state", "pp_fsdp-router_state", "tp-router_state", "ep-router_state", "tp-cca", "sp-cca"])
+def test_the_carried_router_state_and_cca_are_refused_by_name_where_they_cannot_run(strategy, spec, kw, match):
+    """When configuration, rules and mesh first meet (`check_placement`), not deep inside a trace; `dp` and `fsdp` take both."""
+    cfg = TransformerConfig.tiny(**kw)
+    with pytest.raises(ValueError, match=match):
+        LMTrainContext(cfg, mesh=build_mesh(spec), strategy=strategy)
+    LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=2, fsdp=4)), strategy="fsdp")
 
 
 @pytest.mark.parametrize(

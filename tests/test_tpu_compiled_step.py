@@ -540,3 +540,29 @@ def test_the_dots3_cells_index_kernels_compile_at_its_shapes(topo):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert all(name in text for name in ("dsa_index_fwd", "dsa_index_bwd_dq", "dsa_index_bwd_dk"))
     assert not re.search(r"\[(?:\d+,)*256,64,8192\]", text)  # the plain form's block of 256 queries: f32 products, pred compares
+
+
+def test_a_cca_step_holds_the_flash_kernels_at_its_latent_heads_and_the_names_of_its_mixing_and_router(topo):
+    """PR 68: a small ZAYA1-shaped step (512 tokens, 4 query / 2 key heads of 128 in a latent of 512 | 256 under a
+    stream of 256, two taps a convolution, top-1 of 4 experts behind the network router, learned joins, a tied head)
+    compiled for the described chip.  Its core is the three flash kernels under `layer/attn_core`, once a scan body
+    and direction; the mixing around it is plain XLA under `cca/mix` (no Mosaic kernel there), the projections under
+    `cca/proj`, the whole router under `moe/router`; and the router's state [512, 64] float32 is the one array beside
+    the stream that every layer's body takes and returns."""
+    seq = 512
+    cfg = TransformerConfig.tiny(
+        n_layers=3, layer_types=("cca",) * 3, n_heads=4, n_kv_heads=2, attn_head_dim=128, d_model=256, rotary_dim=64, rope_theta=5e6,
+        tie_embeddings=True, n_experts=4, experts_per_token=1, moe_d_ff=128, router_kind="mlp", router_hidden=64,
+        residual_scaling=True, max_seq_len=seq, remat=True, remat_policy="qkv_attn", vocab_size=V, dtype=jnp.bfloat16)
+    ctx = LMTrainContext(cfg, mesh=build_mesh(MeshSpec(data=1), devices=topo.devices[:1]), strategy="dp")
+    state = jax.eval_shape(ctx._init, jax.random.PRNGKey(0))
+    toks = jax.ShapeDtypeStruct((1, seq), jnp.int32, sharding=ctx.batch_sharding)
+    with _no_compile_cache(), ctx.mesh:
+        text = ctx._train_step.lower(state, {"tokens": toks, "targets": toks}).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    flash = [line for line in kernels if "layer/attn_core" in line]
+    assert len(flash) == 3 and all(any(name in line for line in flash) for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    assert not any("cca/mix" in line or "cca/proj" in line or "moe/router" in line for line in kernels)
+    for name in ("layer/attn_proj/cca/proj", "layer/attn_proj/cca/mix", "layer/mlp/moe/router"):
+        assert name in text, name
+    assert re.search(rf"f32\[1,{seq},64\]", text)  # the carried router state
