@@ -191,7 +191,8 @@ def diffusion_noise(key: jax.Array, tokens: jax.Array, *, block: int, mask_id: i
 
 
 # The step counters of the run's record (train/run_record.py), among the step's metrics: the key tiles a
-# windowed flash forward visits and the grid steps of a causal one that copy (PR 55); what a layer that holds a
+# windowed flash forward visits and the grid steps a causal one has, of its rectangle of tile pairs (PR 55: the steps
+# that copied; since PR 70 the steps there are, the same count); what a layer that holds a
 # share of its experts was given (`models/moe.py` `router_losses`: rows per held expert, mean and busiest, the
 # busiest expert's load over the mean, and the share of the T*K assignments whose rows the share's buffers moved);
 # the multi-token-prediction module's cross entropy; a block-diffusion step's two: the share of the sequence's
@@ -232,17 +233,19 @@ def _window_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
 
 
 def _causal_counters(config: TransformerConfig, seq: int) -> Dict[str, float]:
-    """`CAUSAL_STEPS`: the grid steps of the causal flash forward that make
-    its pipeline copy a key and a value tile, as % of a head's steps
-    (`flash_attention.causal_steps_copying_pct`, from the map the kernel is
-    given), the mean over the layers whose core is a causal attention call
-    without a window (`Mixer.flash_heads`), at the tiles their head sizes
-    give at this length.  Known when the step is traced, as `WINDOW_TILES`,
-    and like it a statement about the kernels at this length: it is noted
-    whichever form the dispatch gives the step (`ops.attention`; off the
-    chip and under the ring no flash kernel runs).  Nothing for a model
-    without such a layer (a block-diffusion model's calls are not causal) or a
-    length no tile divides."""
+    """`CAUSAL_STEPS`: the grid steps the causal flash forward HAS, as % of
+    the rectangle of a head's tile pairs (`flash_attention.
+    causal_steps_copying_pct`, from the table of visible pairs the kernel is
+    handed; until PR 70 the steps of that rectangle that made the pipeline
+    copy, the same count under the name it keeps), the mean over the layers
+    whose core is a causal attention call without a window
+    (`Mixer.flash_heads`), at the tiles their head sizes give at this length.
+    Known when the step is traced, as `WINDOW_TILES`, and like it a statement
+    about the kernels at this length: it is noted whichever form the dispatch
+    gives the step (`ops.attention`; off the chip and under the ring no flash
+    kernel runs).  Nothing for a model without such a layer (a
+    block-diffusion model's calls are not causal) or a length no tile
+    divides."""
     from ray_tpu.ops.pallas.flash_attention import causal_forward_tiles, causal_steps_copying_pct
 
     if config.diffusion_block is not None:

@@ -1,69 +1,53 @@
 """Pallas TPU flash attention: forward + backward kernels.
 
 Forward: online softmax, one (block_q, block_k) tile pair per grid step on a
-4-D grid (batch, head, q_tile, kv_tile); accumulator/max/denominator live in
-VMEM scratch carried across the innermost kv dimension, so VMEM holds only
-the current tiles (full-K/V-resident designs blow the ~16MB/core budget and
-a 128-tile grid design starves the MXU at ~3 TFLOP/s on v5e).  The per-row
-logsumexp is saved for the backward.
+3-D grid (batch, head, tile pair); accumulator/max/denominator live in VMEM
+scratch carried across a query tile's pairs, so VMEM holds only the current
+tiles (full-K/V-resident designs blow the ~16MB/core budget and a 128-tile
+grid design starves the MXU at ~3 TFLOP/s on v5e).  The per-row logsumexp is
+saved for the backward.
 
 Backward: two pallas kernels with flash-style in-kernel recompute (no [S,S]
 materialization, O(S) memory):
-  - dq kernel, grid (b, h, q_tile, kv_tile): recompute P from (q, k, lse),
-    accumulate dq = scale * sum_kv P*(dP - delta) @ K in scratch.
-  - dkv kernel, grid (b, h, kv_tile, q_tile): accumulate dv = P^T @ dO and
-    dk = (P*(dP - delta))^T @ q_scaled in scratch.
+  - dq kernel, a query tile's key tiles innermost: recompute P from
+    (q, k, lse), accumulate dq = scale * sum_kv P*(dP - delta) @ K in scratch.
+  - dkv kernel, a key tile's query tiles innermost (so k/v blocks stay
+    resident): accumulate dv = P^T @ dO and dk = (P*(dP - delta))^T @ q_scaled.
 
-Causal masking skips fully-masked tile pairs via pl.when predication.  A
-skipped step computes nothing, but the grid of a causal call without a window
-is a rectangle and Pallas's pipeline copies every block whose index differs
-from the step before, run or not: under the map `j -> j` an off step of the
-forward and of dq still copied a key and a value tile (512 KB at 1024 x 128
-bf16 twice, 256 KB at the backward's 512), one of dkv a query and a dO tile
-and the log-sum-exp and delta blocks, whose `[.., 1024, 1]` float32 rows pad
-to 128 lanes (1.5 MB in all), and at 16,384 positions 120 of a head's 256
-forward steps and 240 of its 512 backward steps are off.  So the index map
-of the inner axis (`_inner_tile`) stays on the diagonal's tile through the
-off steps: the last visible key tile where they come last (forward, dq), the
-first visible query tile where they come first (dkv).  An off step then
-names the block its neighbour named and nothing is copied for it; the run
-steps, their order and the bodies are the ones they were
-(`causal_steps_copying_pct` counts the steps that still copy).
+The innermost grid axis of all three walks a TABLE of the tile pairs the
+call's mask lets through (`tile_pairs`), built once a (shape, mask) in numpy
+when the call is traced and handed to the kernel as scalar-prefetched int32
+arrays: for pair p of a head, the tile of the outer sequence (`own`: a query
+tile in the forward and dq, a key tile in dkv), the tile of the other one, and
+a kind word (first pair of its own tile: zero the accumulators; last: write
+the output; crossed by the mask's edge: take the body with the mask).  The
+index maps and the kernels read the table and nothing else, so every grid
+step computes and a tile no query of the call sees is never visited, whatever
+the mask: 136 of the 256 tile pairs of a head at 16,384 causal positions and
+1024 x 1024 (`causal_steps_copying_pct`), the same table for every batch row
+and head.  A call without a mask gets the full rectangle from the same
+builder.
 
-A `window` w (static; None = none) keeps of the causal keys the last w, the
-query's own position counted: query i sees keys i - w + 1 .. i.  A windowed
-call's grids do not span the other sequence: the innermost dimension counts
-only the tiles a tile of the outer one can see (`_visible`), its index maps
-start at the first of them, the two boundary tiles are masked inside and a
-step past the last visible tile is predicated off, in all three kernels.  At
-`window=None` nothing of this is traced: grids, index maps and kernel bodies
-are the ones they were.
+The masks (all static): `causal`; a `window` w, which keeps of the causal
+keys the last w, the query's own position counted (query i sees keys
+i - w + 1 .. i); a `block_diffusion` (`ops.attention.BlockDiffusion(block,
+noisy)`), which takes the causal mask's place: the call's first `noisy` rows
+are a sequence's noisy copy, the rest its clean copy (`noisy` 0: one copy,
+block-causal; `rows / 2`: a training step's doubled rows), and a query sees
+the noisy keys of its own block (a clean query none) and the clean keys up to
+its block (a noisy query: before it).  Its tiles divide a COPY, so that none
+lies across both (80 of a head's 256 forward tile pairs are visited at
+2 x 8,192 rows, blocks of 4 and 1024-tiles).  An own tile's pairs are walked in
+ascending order, under block diffusion the noisy run, then the clean run.
 
-A `block_diffusion` (`ops.attention.BlockDiffusion(block, noisy)`; static;
-None = none) puts the block-diffusion mask in the causal one's place: the
-call's first `noisy` rows are a sequence's noisy copy, the rest its clean copy
-(`noisy` 0: one copy, block-causal; `rows / 2`: a training step's doubled
-rows), and of the four quadrants of the doubled rows a query tile sees a
-block-diagonal band of the noisy keys and the clean keys up to its own blocks
-(a clean tile the second alone).  Tiles divide a COPY, so that none lies
-across both, and the grids count, as a windowed call's do, only the tiles a
-tile of the outer axis can see: two runs of them (`_diffusion_ranges`), walked
-one after the other, the index held on the last visible tile through the
-steps past it, which the kernels predicate off (80 of a head's 256 forward
-tile pairs are visited at 2 x 8,192 rows, blocks of 4 and 1024-tiles, 9 inner
-steps where 16 would span the rows).  The two boundary kinds of tile are
-masked inside from the rows' indices alone.  At `block_diffusion=None` nothing
-of this is traced either.
-
-Only a tile the mask's edge crosses is masked.  A run step of each kernel
-decides from its own grid indices (`_wholly_visible`) whether every query of
-its tile sees every key of it: such a step runs the body without the mask's
-index compares and select (and, in the forward, without the guard for a row
-whose keys are all masked, which no such tile has), every other run step (the
-causal diagonal, a window's two boundary tiles, the boundary tiles of the
+Only a tile the mask's edge crosses is masked.  A pair whose every query sees
+every key (the table's word says so) runs the body without the mask's index
+compares and select (and, in the forward, without the guard for a row whose
+keys are all masked, which no such tile has), every other pair (the causal
+diagonal, a window's two boundary tiles, the boundary tiles of the
 block-diffusion mask) the body with them.  A select whose condition is true
 everywhere returns its operand, so no output changes a bit; 120 of a head's 136
-forward run steps at 16,384 causal positions are wholly visible, 56 of 80 at
+forward pairs at 16,384 causal positions are wholly visible, 56 of 80 at
 2 x 8,192 block-diffusion rows (`tiles_unmasked_pct`).  A call without a mask
 has no edge and traces the unmasked body alone.
 
@@ -83,10 +67,11 @@ Reference parity note: the reference (Ray) has no attention kernels at all
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -121,38 +106,7 @@ def _pallas_call(kernel, *, name: str, **kwargs):
 # two counters below size their tiles from (why these: `flash_attention`'s docstring)
 DEFAULT_BLOCKS = (1024, 1024, 1024, 512)
 
-# -- windows ---------------------------------------------------------------
-
-
-def _lower(a, b):
-    """min of two tile indices, traced grid indices or Python ints."""
-    return jnp.minimum(a, b) if isinstance(a, jax.Array) or isinstance(b, jax.Array) else min(a, b)
-
-
-def _higher(a, b):
-    return jnp.maximum(a, b) if isinstance(a, jax.Array) or isinstance(b, jax.Array) else max(a, b)
-
-
-def _first_visible(i, own: int, other: int, window: int, *, keys: bool):
-    """First tile of the OTHER sequence that tile `i` (of `own` positions)
-    can see under a causal window: with `keys`, i is a query tile and the
-    answer a key tile (of `other` positions); without, the reverse.  `i` may
-    be a traced grid index or a Python int."""
-    if keys:  # the first query's oldest key, i * own - (window - 1), clamped at 0
-        return _higher(i * own - (window - 1), 0) // other
-    return (i * own) // other  # the first key's own position is the first query that sees it
-
-
-def _visible(n_own: int, n_other: int, own: int, other: int, window: int, *, keys: bool) -> list:
-    """How many tiles of the other sequence each tile sees; the most of them
-    is the innermost grid dimension of a windowed call."""
-    counts = []
-    for i in range(n_own):
-        first = _first_visible(i, own, other, window, keys=keys)
-        # the last query's own position / the last key's newest query
-        last_pos = (i + 1) * own - 1 if keys else (i + 1) * own - 1 + window - 1
-        counts.append(min(last_pos // other, n_other - 1) - first + 1)
-    return counts
+# -- the masks inside a tile ------------------------------------------------------
 
 
 def _window_mask(logits, q_start, k_start, window):
@@ -163,61 +117,6 @@ def _window_mask(logits, q_start, k_start, window):
     if window is not None:
         seen = seen & (qpos - kpos < window)
     return jnp.where(seen, logits, NEG_INF)
-
-
-# -- the block-diffusion mask ---------------------------------------------------
-
-
-def _pick(cond, a, b):
-    """a if cond else b, of traced grid indices or Python ints."""
-    traced = any(isinstance(x, jax.Array) for x in (cond, a, b))
-    return jnp.where(cond, a, b) if traced else (a if cond else b)
-
-
-def _diffusion_ranges(i, own: int, other: int, rows: int, bd: BlockDiffusion, *, keys: bool):
-    """The tiles of the OTHER sequence that tile `i` (of `own` rows) can see
-    under the block-diffusion mask, as two runs (first, count, first, count),
-    a count possibly 0: with `keys`, i is a query tile and the runs are key
-    tiles (of `other` rows): the noisy keys of its own blocks, then the clean
-    keys up to its blocks; without, i is a key tile and the runs are the noisy
-    and then the clean query tiles that see it.  `i` may be a traced grid
-    index or a Python int.  Tiles divide a copy, or are the whole call."""
-    if own == rows:  # one tile of every row sees, and is seen by, every tile
-        return 0, rows // other, 0, 0
-    if other == rows:
-        return 0, 1, 0, 0
-    block, clean = bd.block, rows - bd.noisy
-    n_noisy, n_clean = bd.noisy // other, clean // other  # tiles of the other sequence in each copy
-    noisy = i < bd.noisy // own
-    first_pos = (i - _pick(noisy, 0, bd.noisy // own)) * own  # the tile's positions in its copy
-    b0, b1 = first_pos // block, (first_pos + own - 1) // block  # its first and last block
-    own_first, own_last = (b0 * block) // other, ((b1 + 1) * block - 1) // other  # the tiles that hold its blocks
-    if keys:
-        # a noisy query: the noisy keys of its blocks; the clean keys of the blocks BEFORE its last (ceil of b1 * block / other tiles);
-        # a clean query: no noisy key; the clean keys up to its last block
-        return (own_first, _pick(noisy, own_last - own_first + 1, 0),
-                n_noisy, ((b1 + _pick(noisy, 0, 1)) * block + other - 1) // other)
-    # a noisy key: the noisy queries of its blocks, no clean one; a clean key: the noisy queries of the blocks AFTER its
-    # first, and the clean queries from its first block on
-    after = _lower(((b0 + 1) * block) // other, n_noisy)
-    return (_pick(noisy, own_first, after), _pick(noisy, own_last - own_first + 1, n_noisy - after),
-            n_noisy + own_first, _pick(noisy, 0, n_clean - own_first))
-
-
-def _diffusion_step(i, j, own: int, other: int, rows: int, bd: BlockDiffusion, *, keys: bool):
-    """(the other sequence's tile at grid step (i, j), whether the step
-    runs): the two runs of `_diffusion_ranges` one after the other, and past
-    them the last visible tile, held, so that the pipeline copies nothing."""
-    a1, n1, a2, n2 = _diffusion_ranges(i, own, other, rows, bd, keys=keys)
-    second = _pick(n2 > 0, a2 + _lower(j - n1, n2 - 1), a1 + n1 - 1)
-    return _pick(j < n1, a1 + j, second), j < n1 + n2
-
-
-def _diffusion_visible(n_own: int, own: int, other: int, rows: int, bd: BlockDiffusion, *, keys: bool) -> list:
-    """How many tiles of the other sequence each tile sees; the most of them
-    is the innermost grid dimension of a block-diffusion call."""
-    ranges = (_diffusion_ranges(i, own, other, rows, bd, keys=keys) for i in range(n_own))
-    return [n1 + n2 for _, n1, _, n2 in ranges]
 
 
 def _diffusion_mask(logits, q_start, k_start, bd: BlockDiffusion):
@@ -249,46 +148,78 @@ def _block_of(pos, block: int):
     return jax.lax.div(pos, jnp.int32(block))
 
 
-# -- which run steps are masked -------------------------------------------------
-
-
-def _wholly_visible(q_start, k_start, bq: int, bk: int, window: Optional[int], bd: Optional[BlockDiffusion]):
-    """Whether every query of the tile at `q_start` (bq rows) sees every key
-    of the tile at `k_start` (bk rows), so that the mask selects `logits`
-    everywhere: the ONE predicate by which a run step of the three kernels
-    takes the body without the mask, and what `tiles_unmasked_pct` counts.
-    Causal: the tile's last key is no later than its first query and, under a
-    window, its oldest pair is inside it.  Block-diffusion: a clean key tile
-    whose last block is before (noisy queries) or no later than (clean
-    queries) the query tile's first block; a noisy key tile is never wholly
-    visible (its queries see their own blocks' keys alone), nor is a tile that
-    holds both copies.  The starts may be traced grid values or Python ints."""
-    if bd is None:
-        clear = k_start + bk - 1 <= q_start
-        return clear if window is None else clear & (q_start + bq - 1 - k_start < window)
-    q_noisy = q_start < bd.noisy  # tiles divide a copy; one that holds the whole call starts at 0, "noisy", and sees no clean tile whole
-    q_first = (q_start - _pick(q_noisy, 0, bd.noisy)) // bd.block
-    k_last = (k_start - bd.noisy + bk - 1) // bd.block
-    return (k_start >= bd.noisy) & (k_last < q_first + _pick(q_noisy, 0, 1))
-
-
-def _edge_mask(logits, q_start, k_start, window, diffusion):
-    """The mask of a call that has one, inside a tile its edge crosses."""
+def _edge_mask(logits, q_start, k_start, window, diffusion, lead: Optional[int] = None):
+    """The mask of a call that has one, inside a tile its edge crosses.  The
+    causal mask and its window read the two starts through their difference
+    alone: where every masked pair of the table has ONE difference (`lead`: a
+    causal call on square tiles masks its diagonal, 0) the mask is a constant
+    of the kernel, not index arithmetic on every masked step."""
     if diffusion is not None:
         return _diffusion_mask(logits, q_start, k_start, diffusion)
+    if lead is not None:
+        q_start, k_start = lead, 0
     return _window_mask(logits, q_start, k_start, window)
 
 
-def _by_kind(step, run, q_start, k_start, bq: int, bk: int, *, causal: bool, window, diffusion):
-    """Runs `step(masked)` on a grid step that `run`s: without the mask where
-    the tile is wholly visible, with it where the mask's edge crosses the
-    tile.  A call without a mask has no edge and the one body, as it had."""
-    if not causal and diffusion is None:
-        pl.when(run)(functools.partial(step, False))
-        return
-    clear = _wholly_visible(q_start, k_start, bq, bk, window, diffusion)
-    pl.when(run & clear)(functools.partial(step, False))
-    pl.when(run & jnp.logical_not(clear))(functools.partial(step, True))
+# -- the table of visible tile pairs ----------------------------------------------
+
+
+_FIRST, _LAST, _MASKED = 1, 2, 4  # bits of a pair's kind word: see `TilePairs`
+
+
+class TilePairs(NamedTuple):
+    """The tile pairs one head's grid walks, in order (int32 [n_pairs] each):
+    the tile of the outer sequence, the tile of the other one, and the kind
+    word: `_FIRST` on the first pair of its own tile (the accumulators are
+    zeroed), `_LAST` on the last (the output block is written), `_MASKED`
+    where the mask's edge crosses the tile (some query of it misses some key
+    of it), so that the pair takes the body with the mask."""
+    own: np.ndarray
+    other: np.ndarray
+    kind: np.ndarray
+
+
+def _seen_runs(sq: int, sk: int, causal: bool, window: Optional[int], bd: Optional[BlockDiffusion]):
+    """The keys each of the call's `sq` query rows sees, as runs of key rows
+    (first, one past the last), int arrays over the query rows: the mask's
+    rules themselves, the one place the table takes them from."""
+    row = np.arange(sq)
+    if bd is not None:  # a noisy row: its block's noisy keys, the clean blocks before its own; a clean row: the clean blocks up to its own
+        noisy = row < bd.noisy
+        block = np.where(noisy, row, row - bd.noisy) // bd.block
+        return [(np.where(noisy, block * bd.block, 0), np.where(noisy, (block + 1) * bd.block, 0)),
+                (np.full(sq, bd.noisy), bd.noisy + np.where(noisy, block, block + 1) * bd.block)]
+    if not causal:
+        return [(np.zeros(sq, int), np.full(sq, sk))]
+    return [(np.zeros(sq, int) if window is None else np.maximum(row - window + 1, 0), row + 1)]
+
+
+@functools.lru_cache(maxsize=None)  # every layer of a kind asks for the same one
+def tile_pairs(sq: int, sk: int, bq: int, bk: int, causal: bool, window: Optional[int], bd: Optional[BlockDiffusion],
+               keys: bool) -> TilePairs:
+    """The ONE enumeration of the tile pairs a call visits, from its static
+    shapes and mask: with `keys` the query tiles are the outer sequence
+    (forward, dq), without it the key tiles (dkv).  For each own tile, in
+    order, the other sequence's tiles that hold a pair the mask lets through,
+    ascending.  An own tile that sees nothing (the key tiles past the last
+    query of a causal call with more keys than queries) keeps one pair, masked
+    everywhere, since its output block has to be written (with zeros)."""
+    first_key = np.arange(0, sk, bk)
+    seen = sum(np.clip(np.minimum(hi[:, None], first_key + bk) - np.maximum(lo[:, None], first_key), 0, None)
+               for lo, hi in _seen_runs(sq, sk, causal, window, bd))  # [sq, key tiles]: the keys of a tile that a row sees
+    seen = seen.reshape(sq // bq, bq, sk // bk)
+    some, whole = (seen > 0).any(axis=1), (seen == bk).all(axis=1)  # [query tiles, key tiles]
+    if not keys:
+        some, whole = some.T.copy(), whole.T
+    some[~some.any(axis=1), -1] = True
+    own, other = np.nonzero(some)  # row by row, ascending: under block diffusion the noisy run, then the clean run
+    assert some.any(axis=1).all()  # an own tile without a pair would never write its output block
+    turns = own[1:] != own[:-1]
+    kind = _FIRST * np.r_[True, turns] + _LAST * np.r_[turns, True] + _MASKED * ~whole[own, other]
+    pairs = TilePairs(own.astype(np.int32), other.astype(np.int32), kind.astype(np.int32))
+    for column in pairs:
+        column.setflags(write=False)  # the cache hands every caller the same arrays
+    return pairs
 
 
 def _diffusion_blocks(rows: int, bd: BlockDiffusion, blocks):
@@ -319,7 +250,7 @@ def diffusion_mask_fill_pct(seq: int, block: int, d: int, dv: int) -> Optional[f
     bq, bk = _forward_tiles(2 * seq, d, dv, bd=bd)
     if bq is None or bk is None:
         return None
-    visited = sum(_diffusion_visible(2 * seq // bq, bq, bk, 2 * seq, bd, keys=True))
+    visited = len(tile_pairs(2 * seq, 2 * seq, bq, bk, False, None, bd, True).own)
     return 100.0 * (seq * seq + seq * block) / (visited * bq * bk)
 
 
@@ -331,47 +262,38 @@ def window_tiles_visited_pct(seq: int, window: int) -> Optional[float]:
     full = _fit_block(seq, DEFAULT_BLOCKS[0])
     if full is None:
         return None
-    causal = full * full * sum(qi + 1 for qi in range(seq // full))
+    causal = full * full * len(tile_pairs(seq, seq, full, full, True, None, None, True).own)
     bq, bk, _, _ = (_fit_block(seq, b) for b in _window_blocks(window, DEFAULT_BLOCKS))
-    visited = sum(_visible(seq // bq, seq // bk, bq, bk, window, keys=True))
+    visited = len(tile_pairs(seq, seq, bq, bk, True, window, None, True).own)
     return 100.0 * visited * bq * bk / causal
 
 
 def causal_steps_copying_pct(seq: int, block_q: int, block_k: int, *, keys: bool) -> float:
-    """Share (%) of a head's grid steps that make the pipeline copy, in a
-    causal call without a window at these tiles: the steps whose pair of
-    blocks (the outer axis's own tile, the inner axis's by the map) differs
-    from the step before's, evaluated from the map the kernel is given
-    (`_inner_tile`).  With `keys` the outer axis is the query tiles (forward,
-    dq), without it the key tiles (dkv).  100 under the map `j -> j`; with the
-    map held on the diagonal's tile, the visible pairs alone: 136 of 256 at
-    16,384 positions and 1024 x 1024, 272 of 512 at 1024 x 512."""
-    own, other = (block_q, block_k) if keys else (block_k, block_q)
-    n_inner, tile = _inner_tile(seq // own, seq // other, own, other, None, keys=keys, causal=True)
-    steps = [(i, tile(i, j)) for i in range(seq // own) for j in range(n_inner)]
-    return 100.0 * (1 + sum(a != b for a, b in zip(steps, steps[1:]))) / len(steps)
+    """Share (%) of the rectangle of a head's tile pairs that are grid steps
+    at all, in a causal call without a window at these tiles: the pairs of the
+    table the kernel is handed (`tile_pairs`) over the n_q x n_k steps of the
+    rectangular grid it replaces.  With `keys` the outer axis is the query
+    tiles (forward, dq), without it the key tiles (dkv).  Until PR 70 the
+    steps of that rectangle that made the pipeline COPY (an off step named its
+    neighbour's block), which were the same ones: 136 of 256 at 16,384
+    positions and 1024 x 1024, 272 of 512 at 1024 x 512, 100 at one tile a
+    head."""
+    pairs = len(tile_pairs(seq, seq, block_q, block_k, True, None, None, keys).own)
+    return 100.0 * pairs / ((seq // block_q) * (seq // block_k))
 
 
 def run_steps_unmasked(rows: int, bq: int, bk: int, window: Optional[int], bd: Optional[BlockDiffusion]) -> Tuple[int, int]:
-    """(the run steps that take the body without the mask, all run steps) of
-    one head in a kernel with these tiles, under the causal mask, its `window`
-    or the block-diffusion mask `bd`, from the predicate the kernels are given
-    (`_wholly_visible`).  The tile pairs that run are the same whichever axis
-    is the outer one: walked here query tile by query tile, the key tiles from
-    the first visible one to the diagonal's (the kernels' `run`) or the two
-    runs of `_diffusion_ranges`."""
-    if bd is not None:
-        runs = (_diffusion_ranges(i, bq, bk, rows, bd, keys=True) for i in range(rows // bq))
-        steps = [(i * bq, j * bk) for i, (a1, n1, a2, n2) in enumerate(runs) for j in (*range(a1, a1 + n1), *range(a2, a2 + n2))]
-    else:
-        first = (lambda i: 0) if window is None else functools.partial(_first_visible, own=bq, other=bk, window=window, keys=True)
-        steps = [(i * bq, j * bk) for i in range(rows // bq) for j in range(first(i), (i * bq + bq - 1) // bk + 1)]
-    return sum(bool(_wholly_visible(q0, k0, bq, bk, window, bd)) for q0, k0 in steps), len(steps)
+    """(the pairs that take the body without the mask, all pairs) of one head
+    in a kernel with these tiles, under the causal mask, its `window` or the
+    block-diffusion mask `bd`, from the kind words of the table the kernels
+    are handed.  The pairs are the same whichever axis is the outer one."""
+    kind = tile_pairs(rows, rows, bq, bk, bd is None, window, bd, True).kind
+    return int(np.sum(kind & _MASKED == 0)), len(kind)
 
 
 def tiles_unmasked_pct(seq: int, d: int, dv: int, *, window: Optional[int] = None,
                        diffusion_block: Optional[int] = None) -> Optional[float]:
-    """Share (%) of a head's FORWARD run steps that take the body without the
+    """Share (%) of a head's FORWARD tile pairs that take the body without the
     mask (`run_steps_unmasked`) at the tiles in use (`_forward_tiles`): of a
     causal call over `seq` positions, of one under `window`, or of a training
     step's block-diffusion call over 2 x `seq` rows in blocks of
@@ -417,44 +339,91 @@ def _head_blocks(d: int, dv: int, blocks):
     kernel may scope (libtpu refuses it; PERF.md section 6, PR 54).  Heads
     whose two sizes sum over 448 halve the forward's key tile; the backward's
     1024 x 512 fits them as it is.  Heads of 64, 128 and 192 / 128 keep the
-    tiles they had."""
+    tiles they had.  (Since PR 70 a causal forward's masked tiles hold no
+    int32 position arrays, `_edge_mask`'s `lead`, and 256 / 256 compiles at
+    1024 x 1024; by what margin, and whether it is faster, is a sweep's to
+    say: `scripts/flash_tiles_check.py`.)"""
     block_q, block_k, bwd_block_q, bwd_block_k = blocks
     if d + dv > _FWD_FULL_TILE_HEADS:
         block_k = min(block_k, 512)
     return block_q, block_k, bwd_block_q, bwd_block_k
 
 
+# -- walking the table ---------------------------------------------------------
+
+
+def _pair(own_ref, other_ref, kind_ref, bq: int, bk: int, *, keys: bool):
+    """(first query row, first key row, kind word) of this grid step's pair."""
+    p = pl.program_id(2)
+    q_tile, k_tile = (own_ref[p], other_ref[p]) if keys else (other_ref[p], own_ref[p])
+    return q_tile * bq, k_tile * bk, kind_ref[p]
+
+
+def _common(pairs: TilePairs, bq: int, bk: int, *, keys: bool) -> Tuple[int, int, Optional[int]]:
+    """What all the words of a table agree on, static when the call is traced
+    (`_walk`, `_edge_mask`): the bits every kind word has, the bits some word
+    has, and `q_start - k_start` of the masked pairs where it is one value."""
+    q_tile, k_tile = (pairs.own, pairs.other) if keys else (pairs.other, pairs.own)
+    leads = np.unique((q_tile * bq - k_tile * bk)[pairs.kind & _MASKED != 0])
+    return int(np.bitwise_and.reduce(pairs.kind)), int(np.bitwise_or.reduce(pairs.kind)), int(leads[0]) if len(leads) == 1 else None
+
+
+def _walk(kind, common, init, step, finish):
+    """One grid step: `init` on the first pair of an own tile, `step(masked)`
+    by the table's word, `finish` on the last.  Every step computes.  What all
+    the table's words agree on (`_common`) is decided when the call is traced,
+    as the grids this replaces decided it: a table of one pair a head
+    (`mistral7b-1chip.seq1k`) has no branch at all, a call without a mask
+    traces the unmasked body alone, one whose every pair is a boundary tile (a
+    window no wider than a tile) the masked body alone."""
+    every, some, _ = common
+
+    def on(bit: int, fn, *, unset: bool = False):
+        always, sometimes = (not some & bit, not every & bit) if unset else (every & bit, some & bit)
+        if always:
+            fn()
+        elif sometimes:
+            pl.when(kind & bit == 0 if unset else kind & bit != 0)(fn)
+
+    on(_FIRST, init)
+    on(_MASKED, functools.partial(step, False), unset=True)
+    on(_MASKED, functools.partial(step, True))
+    on(_LAST, finish)
+
+
+def _tile_spec(rows: int, width: int, *, own: bool):
+    """A [rows, width] block of one head at the tile the table names for the
+    grid step: the own (outer) sequence's or the other's."""
+    return pl.BlockSpec((1, 1, rows, width), lambda bi, hi, p, own_t, other_t, kind: (bi, hi, (own_t if own else other_t)[p], 0))
+
+
+def _pairs_call(kernel, name: str, b: int, h: int, call: tuple, *, keys: bool, **specs):
+    """The kernel on the grid (batch, head, pair of the table), the table of
+    `call` (`tile_pairs`' arguments but the last, `keys`) scalar-prefetched and
+    what its words agree on (`_common`) the kernel's static `common`."""
+    pairs = tile_pairs(*call, keys)
+    kernel = functools.partial(kernel, common=_common(pairs, *call[2:4], keys=keys))
+    run = _pallas_call(kernel, name=name, out_shape=specs.pop("out_shape"), grid_spec=pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(pairs), grid=(b, h, len(pairs.own)), **specs))
+    return functools.partial(run, *map(jnp.asarray, pairs))
+
+
 # -- forward ---------------------------------------------------------------
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, scale: float, causal: bool, window: Optional[int] = None, diffusion: Optional[BlockDiffusion] = None,
-    rows: Optional[int] = None,
+    own_ref, other_ref, kind_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+    *, scale: float, common, window: Optional[int] = None, diffusion: Optional[BlockDiffusion] = None,
 ):
     # Blocks: q [1, 1, bq, D]; k [1, 1, bk, D]; v [1, 1, bk, Dv]; o [1, 1, bq, Dv];
-    # lse [1, 1, bq, 1].  Scratch (carried across the kv grid dim): acc [bq, Dv] f32,
+    # lse [1, 1, bq, 1].  Scratch (carried across a query tile's pairs): acc [bq, Dv] f32,
     # m/l [bq, LANES] f32 (lane-broadcast row scalars).
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
-    bq = q_ref.shape[2]
-    bk = k_ref.shape[2]
-    q_start = qi * bq
-    k_start = ki * bk
-    if window is not None:  # the grid counts from the first visible key tile
-        k_start = (_first_visible(qi, bq, bk, window, keys=True) + ki) * bk
+    q_start, k_start, kind = _pair(own_ref, other_ref, kind_ref, q_ref.shape[2], k_ref.shape[2], keys=True)
 
-    @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-
-    run = (k_start <= q_start + bq - 1) if causal else True
-    if diffusion is not None:  # the grid walks the visible key tiles, noisy then clean
-        k_tile, run = _diffusion_step(qi, ki, bq, bk, rows, diffusion, keys=True)
-        k_start = k_tile * bk
 
     def _step(masked: bool):
         q = q_ref[0, 0].astype(jnp.float32) * scale  # [bq, D]
@@ -464,7 +433,7 @@ def _fwd_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bq, bk]
         if masked:
-            logits = _edge_mask(logits, q_start, k_start, window, diffusion)
+            logits = _edge_mask(logits, q_start, k_start, window, diffusion, common[2])
         m_prev = m_ref[:, :1]  # [bq, 1]
         l_prev = l_ref[:, :1]
         m_blk = jnp.max(logits, axis=-1, keepdims=True)  # [bq, 1]
@@ -480,45 +449,13 @@ def _fwd_kernel(
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    _by_kind(_step, run, q_start, k_start, bq, bk, causal=causal, window=window, diffusion=diffusion)
-
-    @pl.when(ki == nk - 1)
     def _finish():
         l = l_ref[:, :1]
         l_safe = jnp.maximum(l, 1e-37)
         o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[:, :1] + jnp.log(l_safe)
 
-
-def _inner_tile(n_own, n_other, own, other, window, *, keys, causal, diffusion=None):
-    """(innermost grid extent, index of the other sequence's tile at grid
-    step (i, j)) of a call whose outer tiles are `own` wide: every tile and
-    the step itself without a window and without a mask; with one, the
-    visible tiles alone, counted from the first, the index held inside the
-    sequence (a step past the last visible tile is predicated off in the
-    kernel).  A causal call without a window keeps every step, and on the
-    steps its kernel predicates off the index stays where the run steps
-    beside them have it, so that the pipeline copies nothing for them: on
-    the last visible key tile (`keys`: the off steps come last), on the first
-    visible query tile (the off steps come first; held inside the sequence
-    for a call with more keys than queries).  The bounds are the ones the
-    kernels' `run` predicates compare with.  A block-diffusion call counts
-    its visible tiles too, two runs of them (`_diffusion_step`)."""
-    if diffusion is not None:
-        rows = n_own * own
-        return (max(_diffusion_visible(n_own, own, other, rows, diffusion, keys=keys)),
-                lambda i, j: _diffusion_step(i, j, own, other, rows, diffusion, keys=keys)[0])
-    if window is not None:
-        first = functools.partial(_first_visible, own=own, other=other, window=window, keys=keys)
-        return (max(_visible(n_own, n_other, own, other, window, keys=keys)),
-                lambda i, j: jnp.minimum(first(i) + j, n_other - 1))
-    if not causal:
-        return n_other, lambda i, j: j
-    if keys:  # the last key tile that `_fwd_kernel`'s and `_bwd_dq_kernel`'s `run` let through: k_start <= q_start + bq - 1
-        return n_other, lambda i, j: _lower(j, (i * own + own - 1) // other)
-    # (i * own) // other is the first query tile that `_bwd_dkv_kernel`'s `run` lets through:
-    # q_start + bq - 1 >= k_start, with k_start = i * own and bq = other
-    return n_other, lambda i, j: _lower(_higher(j, (i * own) // other), n_other - 1)
+    _walk(kind, common, _init, _step, _finish)
 
 
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None, diffusion=None):
@@ -530,22 +467,11 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None, diffusi
     vt = v.transpose(0, 2, 1, 3)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True, causal=causal, diffusion=diffusion)
-    grid = (b, h, sq // block_q, n_k)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, window=window, diffusion=diffusion, rows=sq)
-    out, lse = _pallas_call(
-        kernel,
-        name="flash_fwd",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, k_tile(qi, ki), 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, k_tile(qi, ki), 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        ],
+    out, lse = _pairs_call(
+        functools.partial(_fwd_kernel, scale=scale, window=window, diffusion=diffusion), "flash_fwd", b, h,
+        (sq, sk, block_q, block_k, causal, window, diffusion), keys=True,
+        in_specs=[_tile_spec(block_q, d, own=True), _tile_spec(block_k, d, own=False), _tile_spec(block_k, dv, own=False)],
+        out_specs=[_tile_spec(block_q, dv, own=True), _tile_spec(block_q, 1, own=True)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
@@ -563,30 +489,15 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, window=None, diffusi
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
-    *, scale: float, causal: bool, window: Optional[int] = None, diffusion: Optional[BlockDiffusion] = None,
-    rows: Optional[int] = None,
+    own_ref, other_ref, kind_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
+    *, scale: float, common, window: Optional[int] = None, diffusion: Optional[BlockDiffusion] = None,
 ):
     # q/dq [1, 1, bq, D]; k [1, 1, bk, D]; v [1, 1, bk, Dv]; do [1, 1, bq, Dv];
     # lse/delta [1, 1, bq, 1].
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
-    bq = q_ref.shape[2]
-    bk = k_ref.shape[2]
-    q_start = qi * bq
-    k_start = ki * bk
-    if window is not None:  # as the forward: counted from the first visible key tile
-        k_start = (_first_visible(qi, bq, bk, window, keys=True) + ki) * bk
+    q_start, k_start, kind = _pair(own_ref, other_ref, kind_ref, q_ref.shape[2], k_ref.shape[2], keys=True)
 
-    @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    run = (k_start <= q_start + bq - 1) if causal else True
-    if diffusion is not None:  # as the forward: the visible key tiles, noisy then clean
-        k_tile, run = _diffusion_step(qi, ki, bq, bk, rows, diffusion, keys=True)
-        k_start = k_tile * bk
 
     def _step(masked: bool):
         q = q_ref[0, 0].astype(jnp.float32) * scale  # pre-scaled
@@ -599,7 +510,7 @@ def _bwd_dq_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bq, bk]
         if masked:
-            logits = _edge_mask(logits, q_start, k_start, window, diffusion)
+            logits = _edge_mask(logits, q_start, k_start, window, diffusion, common[2])
         p = jnp.exp(logits - lse)  # masked -> exp(-inf) = 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -609,43 +520,25 @@ def _bwd_dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    _by_kind(_step, run, q_start, k_start, bq, bk, causal=causal, window=window, diffusion=diffusion)
-
-    @pl.when(ki == nk - 1)
     def _finish():
         dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
+    _walk(kind, common, _init, _step, _finish)
+
 
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    own_ref, other_ref, kind_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref,
-    *, scale: float, causal: bool, window: Optional[int] = None, q_tiles: Optional[int] = None,
-    diffusion: Optional[BlockDiffusion] = None, rows: Optional[int] = None,
+    *, scale: float, common, window: Optional[int] = None, diffusion: Optional[BlockDiffusion] = None,
 ):
-    # Grid (b, h, kv_tile, q_tile) — q innermost so k/v blocks stay resident.
+    # A key tile's query tiles innermost, so k/v blocks stay resident.
     # k/dk [1, 1, bk, D]; v/dv [1, 1, bk, Dv]; q [1, 1, bq, D]; do [1, 1, bq, Dv];
     # lse/delta [1, 1, bq, 1].
-    ki = pl.program_id(2)
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
-    bk = k_ref.shape[2]
-    bq = q_ref.shape[2]
-    k_start = ki * bk
-    q_start = qi * bq
-    if window is not None:  # counted from the first query tile that sees this key tile
-        q_start = (_first_visible(ki, bk, bq, window, keys=False) + qi) * bq
+    q_start, k_start, kind = _pair(own_ref, other_ref, kind_ref, q_ref.shape[2], k_ref.shape[2], keys=False)
 
-    @pl.when(qi == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
-
-    run = (q_start + bq - 1 >= k_start) if causal else True
-    if window is not None:  # neither past the newest query of the window nor past the sequence
-        run = run & (q_start <= k_start + bk - 1 + window - 1) & (q_start < q_tiles * bq)
-    if diffusion is not None:  # the query tiles that see this key tile, noisy then clean
-        q_tile, run = _diffusion_step(ki, qi, bk, bq, rows, diffusion, keys=False)
-        q_start = q_tile * bq
 
     def _step(masked: bool):
         q = q_ref[0, 0].astype(jnp.float32) * scale
@@ -658,7 +551,7 @@ def _bwd_dkv_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [bq, bk]
         if masked:
-            logits = _edge_mask(logits, q_start, k_start, window, diffusion)
+            logits = _edge_mask(logits, q_start, k_start, window, diffusion, common[2])
         p = jnp.exp(logits - lse)
         dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -672,12 +565,11 @@ def _bwd_dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    _by_kind(_step, run, q_start, k_start, bq, bk, causal=causal, window=window, diffusion=diffusion)
-
-    @pl.when(qi == nq - 1)
     def _finish():
         dk_ref[0, 0] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+    _walk(kind, common, _init, _step, _finish)
 
 
 def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=None, diffusion=None):
@@ -685,8 +577,6 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=N
     sk, dv = k.shape[1], v.shape[-1]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    n_k, k_tile = _inner_tile(sq // block_q, sk // block_k, block_q, block_k, window, keys=True, causal=causal, diffusion=diffusion)
-    n_q, q_tile = _inner_tile(sk // block_k, sq // block_q, block_k, block_q, window, keys=False, causal=causal, diffusion=diffusion)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -695,45 +585,25 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal, scale, block_q, block_k, window=N
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
     ).transpose(0, 2, 1)[..., None]  # [B, H, Sq, 1]
+    masks = dict(scale=scale, window=window, diffusion=diffusion)
 
-    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, window=window, diffusion=diffusion, rows=sq)
-    dq = _pallas_call(
-        dq_kernel,
-        name="flash_bwd_dq",
-        grid=(b, h, sq // block_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi, k_tile(qi, ki), 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, qi, ki: (bi, hi, k_tile(qi, ki), 0)),
-            pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-        ),
+    def operands(queries_own: bool):  # q, k, v, do, lse, delta: the query tile is the own one in dq, the other one in dkv
+        of_q, of_k = functools.partial(_tile_spec, block_q, own=queries_own), functools.partial(_tile_spec, block_k, own=not queries_own)
+        return [of_q(d), of_k(d), of_k(dv), of_q(dv), of_q(1), of_q(1)]
+
+    call = (sq, sk, block_q, block_k, causal, window, diffusion)
+    dq = _pairs_call(
+        functools.partial(_bwd_dq_kernel, **masks), "flash_bwd_dq", b, h, call, keys=True,
+        in_specs=operands(True),
+        out_specs=_tile_spec(block_q, d, own=True),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
     )(qt, kt, vt, dot, lse, delta)
 
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, causal=causal, window=window, q_tiles=sq // block_q, diffusion=diffusion, rows=sq)
-    dk, dv = _pallas_call(
-        dkv_kernel,
-        name="flash_bwd_dkv",
-        grid=(b, h, sk // block_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, ki, qi: (bi, hi, q_tile(ki, qi), 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, dv), lambda bi, hi, ki, qi: (bi, hi, q_tile(ki, qi), 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, q_tile(ki, qi), 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda bi, hi, ki, qi: (bi, hi, q_tile(ki, qi), 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),
-        ],
+    dk, dv = _pairs_call(
+        functools.partial(_bwd_dkv_kernel, **masks), "flash_bwd_dkv", b, h, call, keys=False,
+        in_specs=operands(False),
+        out_specs=[_tile_spec(block_k, d, own=True), _tile_spec(block_k, dv, own=True)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, sk, dv), v.dtype),
@@ -808,18 +678,14 @@ def flash_attention(
     [bq, bk] f32 intermediates (logits/p/dp/ds) at once, so 1024x1024 there
     would exceed the ~16MB VMEM scoped budget.
 
-    Which steps copy: a causal call visits every (q tile, k tile) pair of its
-    rectangular grids and predicates off the pairs above the diagonal; their
-    index maps stay on the diagonal's tile through those steps
-    (`_inner_tile`), so only a step that runs brings in a key and value tile
-    (forward, dq) or a query, dO, log-sum-exp and delta tile (dkv): 136 of a
-    head's 256 forward steps at 16,384 positions, 272 of its 512 backward
-    steps (`causal_steps_copying_pct`).  A non-causal call runs and copies on
-    every step.
+    Which tile pairs are grid steps: the ones the mask lets through and no
+    other (`tile_pairs`, a table the kernels are handed): a causal call walks
+    the pairs on and under the diagonal, 136 of the 256 of a head's forward
+    at 16,384 positions, 272 of the 512 of each backward kernel
+    (`causal_steps_copying_pct`); a call without a mask walks them all.
 
     `window` (static; needs `causal` and equal sequence lengths): query i
-    sees keys i - window + 1 .. i, and a tile no query of the call sees is
-    never visited (module docstring).  A windowed call gets tiles no larger
+    sees keys i - window + 1 .. i.  A windowed call gets tiles no larger
     than its window (`_window_blocks`): 512 x 512 in all three kernels at a
     window of 512, two key tiles a query tile.
 
@@ -831,7 +697,7 @@ def flash_attention(
         if window is not None:
             raise ValueError("flash_attention: block_diffusion takes the causal mask's place and has no window")
         block_diffusion.check(q.shape[1], k.shape[1])
-        causal = False  # the kernels' causal paths are not traced: the mask and the visited tiles are the block-diffusion ones
+        causal = False  # the table of tile pairs and the mask inside a tile are the block-diffusion ones
     if window is not None:
         if not causal or q.shape[1] != k.shape[1] or window < 1:
             raise ValueError("flash_attention: a window needs causal=True, equal sequence lengths and window >= 1")

@@ -1,17 +1,18 @@
 #!/usr/bin/env python3
 """On the chip: what the mask costs a run step of the three flash kernels, and that leaving it off the wholly visible
 tiles changes no bit.  Since PR 63 a run step whose tile the mask's edge does not cross takes the body without the
-mask's index compares and select (`flash_attention._wholly_visible`); here each kernel runs as written and with the
-predicate forced to "crossed" (every run step masked: the kernels of PR 62), both in ONE process.
+mask's index compares and select (since PR 70 the kind word of `flash_attention.tile_pairs` says which); here each kernel
+runs as written and with the masked bit forced onto every pair (every grid step masked: the kernels of PR 62), both in ONE
+process.
 
-    chiprun -- python3 scripts/flash_mask_check.py [--heads 32] [--reps 12] [--off-us 0.24 0.20 0.30]
+    chiprun -- python3 scripts/flash_mask_check.py [--heads 32] [--reps 12]
 
 Three calls, bf16, one sequence, the tiles `flash_attention` gives them: 16,384 causal positions at heads of 128
 (`mistral7b-1chip.seq16k`), 8,192 causal at heads of 256 / 256 (`glm47-flash`: the forward on a key tile of 512), and
 2 x 8,192 block-diffusion rows in blocks of 4 (`sdar-ep8-1chip.seq8k`).  By kernel: ms a call both ways; the us of a
-masked run step (the forced call's time less its off steps at `--off-us`, PERF.md section 7's readings, over its run
-steps) and of an unmasked one (the written call's time less its off and crossed steps, over its wholly visible steps);
-and whether out, lse, dq, dk, dv are bit-equal.  dq and dkv are timed alone (`_flash_bwd` with the other output dropped,
+masked grid step (the forced call's time over its pairs: since PR 70 there is no off step to take out) and of an unmasked
+one (the written call's time less its crossed pairs, over its wholly visible ones); and whether out, lse, dq, dk, dv are
+bit-equal.  dq and dkv are timed alone (`_flash_bwd` with the other output dropped,
 so XLA removes its kernel; `delta`'s elementwise pass rides both).  Off the chip it exits 1 before it times anything.
 PERF.md section 6, PR 63, holds the readings.
 """
@@ -34,14 +35,15 @@ import numpy as np
 from ray_tpu.ops.attention import BlockDiffusion
 from ray_tpu.ops.pallas import flash_attention as fa
 
-_written = fa._wholly_visible
+_written = fa.tile_pairs
 # (name, rows, q/k head size, v head size, block-diffusion mask or None for the causal one)
 SHAPES = (("causal-16384-d128", 16384, 128, 128, None), ("causal-8192-d256", 8192, 256, 256, None),
           ("diffusion-2x8192-d128", 16384, 128, 128, BlockDiffusion(4, 8192)))
 
 
-def _crossed(q_start, k_start, *tile):
-    return q_start < 0  # a traced False, as the grid's values are: every run step takes the masked body
+def _crossed(*call):
+    table = _written(*call)
+    return table._replace(kind=table.kind | fa._MASKED)  # every grid step takes the masked body
 
 
 def timed(fn, args, reps):
@@ -59,7 +61,6 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--reps", type=int, default=12)
-    ap.add_argument("--off-us", type=float, nargs=3, default=[0.24, 0.20, 0.30], metavar=("FWD", "DQ", "DKV"))
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         print("this check needs the chip: off it the kernels run interpreted and their times say nothing", file=sys.stderr)
@@ -87,26 +88,22 @@ def main() -> int:
         blocks = fa._head_blocks(d, dv, fa.DEFAULT_BLOCKS)
         tiles = fa._diffusion_blocks(rows, bd, blocks) if bd is not None else tuple(fa._fit_block(rows, b) for b in blocks)
         ms, results = {}, {}
-        for form, predicate in (("masked", _crossed), ("written", _written), ("masked again", _crossed)):
-            fa._wholly_visible = predicate
+        for form, table in (("masked", _crossed), ("written", _written), ("masked again", _crossed)):
+            fa.tile_pairs = table
             ms[form], results[form] = calls(rows, d, dv, bd, tiles)
-        fa._wholly_visible = _written
+        fa.tile_pairs = _written
         line = {"shape": name, "tiles": tiles, "heads": h}
-        for kernel, (bq, bk), keys, off_us in (("fwd", tiles[:2], True, args.off_us[0]), ("dq", tiles[2:], True, args.off_us[1]),
-                                               ("dkv", tiles[2:], False, args.off_us[2])):
+        for kernel, (bq, bk) in (("fwd", tiles[:2]), ("dq", tiles[2:]), ("dkv", tiles[2:])):
             clear, run = fa.run_steps_unmasked(rows, bq, bk, None, bd)
-            own, other = (bq, bk) if keys else (bk, bq)
-            n_inner, _ = fa._inner_tile(rows // own, rows // other, own, other, None, keys=keys, causal=bd is None, diffusion=bd)
-            off = rows // own * n_inner - run
             masked_ms = min(ms["masked"][kernel], ms["masked again"][kernel])
-            masked_us = (1e3 * masked_ms / h - off * off_us) / run
+            masked_us = 1e3 * masked_ms / h / run
             line[kernel] = {
                 "ms_masked": round(masked_ms, 4), "ms_written": round(ms["written"][kernel], 4),
                 "ms_masked_both_runs": [round(ms[f][kernel], 4) for f in ("masked", "masked again")],
                 "change_pct": round(100 * (ms["written"][kernel] / masked_ms - 1), 2),
-                "steps_a_head": {"wholly_visible": clear, "crossed": run - clear, "off": off},
+                "pairs_a_head": {"wholly_visible": clear, "crossed": run - clear},
                 "us_masked_step": round(masked_us, 3),
-                "us_unmasked_step": round((1e3 * ms["written"][kernel] / h - off * off_us - (run - clear) * masked_us) / clear, 3),
+                "us_unmasked_step": round((1e3 * ms["written"][kernel] / h - (run - clear) * masked_us) / clear, 3),
             }
         line["bit_equal out lse dq dk dv"] = [bool(np.array_equal(a, b)) for a, b in zip(results["masked"], results["written"])]
         print(json.dumps(line), flush=True)

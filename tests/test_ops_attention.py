@@ -159,26 +159,78 @@ def test_ring_attention_matches_reference(causal):
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
 
 
-# -- the index maps of a causal call (PR 55) -------------------------------------------------------
+# -- the table of visible tile pairs (PR 70; in the place of PR 55's index maps) -------------------
 
 
-def _maps_of_the_parent(monkeypatch):
-    """`_inner_tile` with the causal call's map put back to `j -> j`."""
+def _mask_of_a_tile(qrows, krows, window, bd, causal=True):
+    """The mask over one tile pair by pair, in numpy, from the rules
+    themselves (the causal one and its window; the three of block diffusion)."""
+    q, k = qrows[:, None], krows[None, :]
+    if bd is None:
+        seen = (q >= k) if causal else np.ones((len(qrows), len(krows)), bool)
+        return seen if window is None else seen & (q - k < window)
+    q_noisy, k_noisy = q < bd.noisy, k < bd.noisy
+    qb, kb = np.where(q_noisy, q, q - bd.noisy) // bd.block, np.where(k_noisy, k, k - bd.noisy) // bd.block
+    return np.where(k_noisy, q_noisy & (kb == qb), np.where(q_noisy, kb < qb, kb <= qb))
+
+
+def _tiles_of_the_mask(sq, sk, bq, bk, causal, window, bd):
+    """(holds a pair, every pair) of the dense mask by (query tile, key tile), brute force."""
+    some, whole = np.zeros((sq // bq, sk // bk), bool), np.zeros((sq // bq, sk // bk), bool)
+    for qi in range(sq // bq):
+        for ki in range(sk // bk):
+            mask = _mask_of_a_tile(np.arange(qi * bq, (qi + 1) * bq), np.arange(ki * bk, (ki + 1) * bk), window, bd, causal)
+            some[qi, ki], whole[qi, ki] = mask.any(), mask.all()
+    return some, whole
+
+
+def _the_table_is_the_masks(sq, sk, bq, bk, causal, window, bd):
+    """`tile_pairs` against the dense mask reduced to tiles, both outer axes:
+    the same pairs, ascending within an own tile, every own tile present (one
+    that sees nothing with ONE pair, masked), the first / last flags on the
+    right pairs, the masked word iff the mask over the tile is not all true.
+    Returns the forward-oriented (query tile, key tile) pairs."""
     from ray_tpu.ops.pallas import flash_attention as fa
 
-    real = fa._inner_tile
+    some, whole = _tiles_of_the_mask(sq, sk, bq, bk, causal, window, bd)
+    for keys in (True, False):
+        some_, whole_ = (some, whole) if keys else (some.T, whole.T)
+        want = []
+        for own in range(some_.shape[0]):
+            others = np.flatnonzero(some_[own]) if some_[own].any() else [some_.shape[1] - 1]
+            want += [(own, int(j), n == 0, n == len(others) - 1, not whole_[own, j]) for n, j in enumerate(others)]
+        table = fa.tile_pairs(sq, sk, bq, bk, causal, window, bd, keys)
+        assert {a.dtype for a in table} == {np.dtype(np.int32)} and len({len(a) for a in table}) == 1
+        got = [(int(i), int(j), bool(w & fa._FIRST), bool(w & fa._LAST), bool(w & fa._MASKED)) for i, j, w in zip(*table)]
+        assert got == want, keys
+    return [tuple(int(x) for x in p) for p in np.argwhere(some)]
 
-    def parents(*args, **masks):
-        n, tile = real(*args, **masks)
-        return (n, lambda i, j: j) if args[4] is None else (n, tile)
 
-    monkeypatch.setattr(fa, "_inner_tile", parents)
+def _pairs(table, *, keys=True):
+    """A table's (own tile, other tile) pairs in the grid's order; without
+    `keys` (a dkv table: key tiles outside) turned to (query tile, key tile)."""
+    return [(int(i), int(j)) if keys else (int(j), int(i)) for i, j in zip(table.own, table.other)]
+
+
+def _rectangle_every_pair_masked(monkeypatch):
+    """`tile_pairs` answering with every tile pair of the rectangle, each
+    under the mask: the grid that leaves no pair out and trusts the mask alone."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    real = fa.tile_pairs
+
+    def rectangle(sq, sk, bq, bk, causal, window, bd, keys):
+        table = real(sq, sk, bq, bk, False, None, None, keys)
+        return table._replace(kind=table.kind | fa._MASKED)
+
+    monkeypatch.setattr(fa, "tile_pairs", rectangle)
 
 
 @pytest.mark.parametrize("seq, tiles, d, dv", [(512, (128, 128), 64, 64), (1024, (256, 128), 128, 128),
                                                (512, (128, 128), 192, 128)])
-def test_a_causal_calls_clamped_maps_change_no_bit_of_out_lse_dq_dk_dv(monkeypatch, seq, tiles, d, dv):
-    """The maps name other blocks on the steps that compute nothing alone."""
+def test_leaving_the_invisible_pairs_out_of_a_causal_calls_grid_changes_no_bit_of_out_lse_dq_dk_dv(monkeypatch, seq, tiles, d, dv):
+    """A pair no query of which sees a key adds exact zeros wherever it stands
+    in its row's walk; the visible ones are walked in the rectangle's order."""
     from ray_tpu.ops.pallas import flash_attention as fa
 
     kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(seq + d), 4)
@@ -194,9 +246,10 @@ def test_a_causal_calls_clamped_maps_change_no_bit_of_out_lse_dq_dk_dv(monkeypat
         return (out, lse) + fa._flash_bwd(q, k, v, out, lse, g, **blocks)
 
     changed = everything()
-    _maps_of_the_parent(monkeypatch)
-    parents = everything()
-    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), changed, parents):
+    _rectangle_every_pair_masked(monkeypatch)
+    assert len(fa.tile_pairs(seq, seq, bq, bk, True, None, None, True).own) == (seq // bq) * (seq // bk)
+    rectangles = everything()
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), changed, rectangles):
         assert np.array_equal(np.asarray(a), np.asarray(b)), name
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(changed[0]), np.asarray(ref), atol=2e-5, rtol=2e-5)
@@ -204,31 +257,24 @@ def test_a_causal_calls_clamped_maps_change_no_bit_of_out_lse_dq_dk_dv(monkeypat
 
 @pytest.mark.parametrize("seq, bq, bk", [(16384, 1024, 1024), (16384, 1024, 512), (4096, 1024, 512), (1024, 256, 128),
                                          (1024, 128, 256), (1024, 1024, 512)])
-def test_a_causal_map_gives_a_run_step_its_own_tile_and_an_off_step_its_neighbours(monkeypatch, seq, bq, bk):
+def test_a_causal_calls_table_holds_the_tiles_on_and_under_the_diagonal_in_the_rectangles_order(seq, bq, bk):
     from ray_tpu.ops.pallas import flash_attention as fa
 
     n_q, n_k = seq // bq, seq // bk
-    runs = lambda qi, ki: ki * bk <= qi * bq + bq - 1  # noqa: E731  (the kernels' `run`, all three)
-    pairs = sum(runs(qi, ki) for qi in range(n_q) for ki in range(n_k))
-    # forward and dq: key tiles inside, the off steps LAST
-    n, tile = fa._inner_tile(n_q, n_k, bq, bk, None, keys=True, causal=True)
-    assert n == n_k
-    for qi in range(n_q):
-        last = max(ki for ki in range(n_k) if runs(qi, ki))
-        assert [tile(qi, ki) for ki in range(n_k)] == [ki if runs(qi, ki) else last for ki in range(n_k)]
-    # dkv: query tiles inside, the off steps FIRST
-    n, tile = fa._inner_tile(n_k, n_q, bk, bq, None, keys=False, causal=True)
-    assert n == n_q
-    for ki in range(n_k):
-        first = min(qi for qi in range(n_q) if runs(qi, ki))
-        assert [tile(ki, qi) for qi in range(n_q)] == [qi if runs(qi, ki) else first for qi in range(n_q)]
-    # the counter: the visible pairs, from the same maps; a map `j -> j` copies on every step
-    for keys, steps in ((True, n_q * n_k), (False, n_k * n_q)):
-        assert fa.causal_steps_copying_pct(seq, bq, bk, keys=keys) == pytest.approx(100 * pairs / steps)
-    no_mask = fa._inner_tile(n_q, n_k, bq, bk, None, keys=True, causal=False)[1]
-    assert [no_mask(3, j) for j in range(n_k)] == list(range(n_k))
-    _maps_of_the_parent(monkeypatch)
-    assert fa.causal_steps_copying_pct(seq, bq, bk, keys=True) == fa.causal_steps_copying_pct(seq, bq, bk, keys=False) == 100
+    runs = lambda qi, ki: ki * bk <= qi * bq + bq - 1  # noqa: E731  (what the parent's kernels predicated on, all three)
+    visible = [(qi, ki) for qi in range(n_q) for ki in range(n_k) if runs(qi, ki)]
+    assert _the_table_is_the_masks(seq, seq, bq, bk, True, None, None) == visible
+    # forward and dq: a query tile's key tiles ascending; dkv: a key tile's query tiles ascending
+    assert _pairs(fa.tile_pairs(seq, seq, bq, bk, True, None, None, True)) == visible
+    assert _pairs(fa.tile_pairs(seq, seq, bq, bk, True, None, None, False), keys=False) == sorted(visible, key=lambda p: p[::-1])
+    # the counter: the table's pairs over the rectangle they replace, either axis outside
+    for keys in (True, False):
+        assert fa.causal_steps_copying_pct(seq, bq, bk, keys=keys) == pytest.approx(100 * len(visible) / (n_q * n_k))
+    # a call without a mask: the full rectangle from the same builder, no pair masked
+    for keys in (True, False):
+        whole = fa.tile_pairs(seq, seq, bq, bk, False, None, None, keys)
+        n_own, n_other = (n_q, n_k) if keys else (n_k, n_q)
+        assert _pairs(whole) == [(i, j) for i in range(n_own) for j in range(n_other)] and not (whole.kind & fa._MASKED).any()
 
 
 def test_causal_steps_copying_counts_the_visible_pairs_at_the_tiles_in_use():
@@ -237,20 +283,48 @@ def test_causal_steps_copying_counts_the_visible_pairs_at_the_tiles_in_use():
     assert fa.causal_steps_copying_pct(16384, 1024, 1024, keys=True) == pytest.approx(100 * 136 / 256)
     assert fa.causal_steps_copying_pct(16384, 1024, 512, keys=True) == pytest.approx(100 * 272 / 512)  # dq
     assert fa.causal_steps_copying_pct(16384, 1024, 512, keys=False) == pytest.approx(100 * 272 / 512)  # dkv
-    assert fa.causal_steps_copying_pct(1024, 1024, 1024, keys=True) == 100  # one q tile: every step runs
+    assert fa.causal_steps_copying_pct(1024, 1024, 1024, keys=True) == 100  # one q tile: the table is the rectangle
     assert fa.causal_steps_copying_pct(1024, 1024, 512, keys=False) == 100
     assert fa.causal_forward_tiles(16384, 128, 128) == (1024, 1024) and fa.causal_forward_tiles(8192, 256, 256) == (1024, 512)
     assert fa.causal_forward_tiles(4224, 128, 128) == (384, 384) and fa.causal_forward_tiles(1100, 128, 128) is None
 
 
-def test_a_causal_call_with_more_keys_than_queries_keeps_its_maps_inside_the_sequences():
-    """`sq != sk`: the bounds are the predicates' own, held inside the grid."""
+# (what, queries, keys, window, mask, forward tiles, backward tiles, pairs a head: forward, dq, dkv)
+_TABLE_CASES = [
+    ("causal-16384", 16384, 16384, None, None, (1024, 1024), (1024, 512), (136, 272, 272)),  # `mistral7b-1chip.seq16k`
+    ("diffusion-2x8192-b4", 16384, 16384, None, (4, 8192), (1024, 1024), (1024, 512), (80, 160, 160)),  # `sdar`
+    ("window-513-of-2048", 2048, 2048, 513, None, (512, 512), (512, 512), (7, 7, 7)),  # `dots3-note`'s tiles: [1, 2, 2, 2]
+    ("window-1024-of-16384", 16384, 16384, 1024, None, (1024, 1024), (1024, 512), (31, 62, 62)),  # `mellum2`
+    ("no-mask", 1024, 512, None, None, (256, 128), (128, 256), (16, 16, 16)),
+]
+
+
+@pytest.mark.parametrize("what, sq, sk, window, diffusion, fwd, bwd, counts", _TABLE_CASES, ids=[c[0] for c in _TABLE_CASES])
+def test_the_table_is_the_dense_mask_reduced_to_tiles_at_the_cells_shapes(what, sq, sk, window, diffusion, fwd, bwd, counts):
+    from ray_tpu.ops.attention import BlockDiffusion
     from ray_tpu.ops.pallas import flash_attention as fa
 
-    n, tile = fa._inner_tile(4, 2, 128, 128, None, keys=False, causal=True)  # 4 key tiles, 2 query tiles
-    assert [[tile(ki, qi) for qi in range(n)] for ki in range(4)] == [[0, 1], [1, 1], [1, 1], [1, 1]]
-    n, tile = fa._inner_tile(2, 4, 128, 128, None, keys=True, causal=True)
-    assert [[tile(qi, ki) for ki in range(n)] for qi in range(2)] == [[0, 0, 0, 0], [0, 1, 1, 1]]
+    bd = None if diffusion is None else BlockDiffusion(*diffusion)
+    causal = what != "no-mask" and bd is None
+    _the_table_is_the_masks(sq, sk, *fwd, causal, window, bd)
+    _the_table_is_the_masks(sq, sk, *bwd, causal, window, bd)
+    tables = [fa.tile_pairs(sq, sk, *tiles, causal, window, bd, keys) for tiles, keys in ((fwd, True), (bwd, True), (bwd, False))]
+    assert tuple(len(t.own) for t in tables) == counts
+    if window == 513:
+        assert np.bincount(tables[0].own).tolist() == [1, 2, 2, 2]
+    assert fa.tile_pairs(sq, sk, *fwd, causal, window, bd, True) is tables[0]  # built once a (shape, mask): every layer asks for the same one
+
+
+def test_a_causal_call_with_more_keys_than_queries_gives_every_key_tile_a_pair_and_matches_the_reference():
+    """`sq != sk`: a key tile past the last query sees nothing and keeps ONE
+    pair, masked everywhere, so that its dk and dv blocks are written (zeros)."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    _the_table_is_the_masks(256, 512, 128, 128, True, None, None)
+    _the_table_is_the_masks(512, 256, 128, 128, True, None, None)
+    dkv = fa.tile_pairs(256, 512, 128, 128, True, None, None, False)  # 4 key tiles, 2 query tiles
+    assert _pairs(dkv) == [(0, 0), (0, 1), (1, 1), (2, 1), (3, 1)] and (dkv.kind[-2:] == fa._FIRST | fa._LAST | fa._MASKED).all()
+    assert _pairs(fa.tile_pairs(256, 512, 128, 128, True, None, None, True)) == [(0, 0), (1, 0), (1, 1)]
     q, k, v = _qkv(jax.random.PRNGKey(11), b=1, s=512, h=2, d=64)
     q = q[:, :256]
     flash = lambda q, k, v: fa.flash_attention(  # noqa: E731
@@ -261,6 +335,7 @@ def test_a_causal_call_with_more_keys_than_queries_keeps_its_maps_inside_the_seq
     wanted = jax.grad(lambda *a: reference_attention(*a, causal=True).sum(), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(grads, wanted):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+    assert not np.asarray(grads[1][:, 256:]).any() and not np.asarray(grads[2][:, 256:]).any()
 
 
 # -- the block-diffusion mask (PR 62) ------------------------------------------------------
@@ -328,13 +403,9 @@ def test_the_mask_holds_s_squared_plus_s_b_pairs_and_the_forward_visits_the_tile
 
     bd = BlockDiffusion(block, seq)
     bq, bk = fa._diffusion_blocks(2 * seq, bd, fa.DEFAULT_BLOCKS[:2])
-    visited = set()
-    for i in range(2 * seq // bq):
-        a1, n1, a2, n2 = fa._diffusion_ranges(i, bq, bk, 2 * seq, bd, keys=True)
-        visited |= {(i, j) for j in (*range(a1, a1 + n1), *range(a2, a2 + n2))}
+    visited = set(_pairs(fa.tile_pairs(2 * seq, 2 * seq, bq, bk, False, None, bd, True)))
     if seq == 8192:
         assert len(visited) == 80 and (2 * seq // bq) * (2 * seq // bk) == 256
-        assert fa._inner_tile(16, 16, bq, bk, None, keys=True, causal=False, diffusion=bd)[0] == 9
     else:
         mask = np.asarray(_seen_block_diffusion(jnp.arange(2 * seq), jnp.arange(2 * seq), bd))
         assert mask.sum() == seq * seq + seq * block
@@ -347,23 +418,27 @@ def test_the_mask_holds_s_squared_plus_s_b_pairs_and_the_forward_visits_the_tile
 
 
 @pytest.mark.parametrize("keys", [True, False])
-def test_a_block_diffusion_map_walks_the_visible_tiles_and_holds_the_last_through_the_off_steps(keys):
-    """The index map of the inner axis at the cell's shapes (forward / dq and
-    dkv): a step that runs names a tile some query of the pair sees, each once,
-    and a step that is off names the tile the step before named, so the
-    pipeline copies nothing for it."""
+def test_a_block_diffusion_table_walks_an_own_tiles_noisy_run_and_then_its_clean_run(keys):
+    """The table at the cell's shapes (forward / dq and dkv): 160 pairs where
+    the parent's grids had 16 x 18 and 32 x 16 steps, each own tile's pairs
+    the noisy tiles that see it (or that it sees), then the clean ones, each
+    run ascending and without a gap, the flags on the first and the last."""
     from ray_tpu.ops.attention import BlockDiffusion
     from ray_tpu.ops.pallas import flash_attention as fa
 
     bd, rows = BlockDiffusion(4, 8192), 16384
     own, other = (1024, 512) if keys else (512, 1024)
-    n_inner, tile = fa._inner_tile(rows // own, rows // other, own, other, None, keys=keys, causal=False, diffusion=bd)
-    counts = fa._diffusion_visible(rows // own, own, other, rows, bd, keys=keys)
-    assert n_inner == max(counts) and sum(counts) == 160
-    for i, count in enumerate(counts):
-        named = [int(tile(i, j)) for j in range(n_inner)]
-        assert len(set(named[:count])) == count and set(named[count:]) <= {named[count - 1]}
-        assert all(bool(fa._diffusion_step(i, j, own, other, rows, bd, keys=keys)[1]) == (j < count) for j in range(n_inner))
+    table = fa.tile_pairs(rows, rows, 1024, 512, False, None, bd, keys)
+    assert len(table.own) == 160 and np.array_equal(np.unique(table.own), np.arange(rows // own))
+    for i in range(rows // own):
+        mine = table.other[table.own == i]
+        noisy, clean = mine[mine < 8192 // other], mine[mine >= 8192 // other]
+        assert np.array_equal(mine, np.r_[noisy, clean])
+        assert all(len(run) == 0 or np.array_equal(run, np.arange(run[0], run[-1] + 1)) for run in (noisy, clean))
+        kinds = table.kind[table.own == i]
+        assert kinds[0] & fa._FIRST and kinds[-1] & fa._LAST and not (kinds[1:] & fa._FIRST).any() and not (kinds[:-1] & fa._LAST).any()
+        if keys:  # a noisy query tile: the two key tiles of its own blocks (a clean one: no noisy key); the clean tiles up to its last block
+            assert (len(noisy), len(clean)) == (2 if i < 8 else 0, 2 * (i % 8) + 2)
 
 
 def test_block_diffusion_refuses_a_window_unequal_lengths_and_a_block_that_does_not_divide():
@@ -386,41 +461,21 @@ def test_block_diffusion_refuses_a_window_unequal_lengths_and_a_block_that_does_
 # -- only the tiles the mask's edge crosses are masked (PR 63) -----------------------------------
 
 
-def _mask_of_a_tile(qrows, krows, window, bd):
-    """The mask over one tile pair by pair, in numpy, from the rules
-    themselves (the causal one and its window; the three of block diffusion)."""
-    q, k = qrows[:, None], krows[None, :]
-    if bd is None:
-        return (q >= k) if window is None else (q >= k) & (q - k < window)
-    q_noisy, k_noisy = q < bd.noisy, k < bd.noisy
-    qb, kb = np.where(q_noisy, q, q - bd.noisy) // bd.block, np.where(k_noisy, k, k - bd.noisy) // bd.block
-    return np.where(k_noisy, q_noisy & (kb == qb), np.where(q_noisy, kb < qb, kb <= qb))
-
-
 def _run_steps(rows, bq, bk, window, bd, *, keys):
-    """The (query tile, key tile) pairs of a head's run steps, in the grid's
-    order, as the kernels find them: the outer axis's tile, the inner axis's
-    from the kernel's own arithmetic, the kernel's `run`.  `keys`: forward and
-    dq (query tiles outside); without: dkv."""
+    """The (query tile, key tile) pairs of a head's grid steps, in the grid's
+    order, as the kernels are handed them.  `keys`: forward and dq (query
+    tiles outside); without: dkv."""
     from ray_tpu.ops.pallas import flash_attention as fa
 
-    n_q, n_k = rows // bq, rows // bk
-    own, other, n_own, n_other = (bq, bk, n_q, n_k) if keys else (bk, bq, n_k, n_q)
-    n_inner, _ = fa._inner_tile(n_own, n_other, own, other, window, keys=keys, causal=bd is None, diffusion=bd)
-    steps = []
-    for i in range(n_own):
-        for j in range(n_inner):
-            if bd is not None:
-                tile, run = fa._diffusion_step(i, j, own, other, rows, bd, keys=keys)
-            else:
-                tile = j if window is None else fa._first_visible(i, own, other, window, keys=keys) + j
-                q_start, k_start = (i * bq, tile * bk) if keys else (tile * bq, i * bk)
-                run = k_start <= q_start + bq - 1
-                if window is not None and not keys:
-                    run = run and q_start <= k_start + bk - 1 + window - 1 and q_start < n_q * bq
-            if run:
-                steps.append((i, int(tile)) if keys else (int(tile), i))
-    return steps
+    return _pairs(fa.tile_pairs(rows, rows, bq, bk, bd is None, window, bd, keys), keys=keys)
+
+
+def _taken_whole(rows, bq, bk, window, bd, *, keys):
+    """The (query tile, key tile) pairs whose kind word sends them to the body without the mask."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    table = fa.tile_pairs(rows, rows, bq, bk, bd is None, window, bd, keys)
+    return {pair for pair, kind in zip(_pairs(table, keys=keys), table.kind) if not kind & fa._MASKED}
 
 
 # (what, rows, noisy rows, window, forward tiles, backward tiles, (wholly visible, crossed) forward, the same backward)
@@ -442,9 +497,9 @@ _EDGE_CASES = [
                          ids=[f"{c[0]}-{c[1]}" + (f"-w{c[3]}" if c[3] else "") for c in _EDGE_CASES])
 def test_a_run_step_is_taken_as_wholly_visible_iff_the_mask_over_its_tile_is_all_true(
         kernel, what, rows, noisy, window, fwd, bwd, fwd_counts, bwd_counts):
-    """The predicate against the mask itself at the cells' shapes, in the three
-    kernels' tiles and orientations; the tiles that hold a pair are the ones
-    visited, as before."""
+    """The table's kind word against the mask itself at the cells' shapes, in
+    the three kernels' tiles and orientations; the tiles that hold a pair are
+    the ones visited, as before."""
     from ray_tpu.ops.attention import BlockDiffusion
     from ray_tpu.ops.pallas import flash_attention as fa
 
@@ -461,7 +516,7 @@ def test_a_run_step_is_taken_as_wholly_visible_iff_the_mask_over_its_tile_is_all
             if mask.all():
                 whole.add((qi, ki))
     assert set(steps) == holding
-    taken = {(qi, ki) for qi, ki in steps if fa._wholly_visible(qi * bq, ki * bk, bq, bk, window, bd)}
+    taken = _taken_whole(rows, bq, bk, window, bd, keys=kernel != "dkv")
     assert taken == whole
     assert (len(taken), len(steps) - len(taken)) == counts
     assert fa.run_steps_unmasked(rows, bq, bk, window, bd) == (len(taken), len(steps))  # what the counter counts, either axis outside
@@ -483,11 +538,11 @@ def test_tiles_unmasked_counts_the_forwards_run_steps_at_the_tiles_in_use():
 
 
 def _every_tile_crossed(monkeypatch):
-    """`_wholly_visible` answering no on every tile: the kernels of the parent,
-    every run step masked (a traced False, as the grid's values are)."""
+    """`tile_pairs` with the masked bit on every pair: the kernels of PR 62, every grid step under the mask."""
     from ray_tpu.ops.pallas import flash_attention as fa
 
-    monkeypatch.setattr(fa, "_wholly_visible", lambda q_start, k_start, *tile: q_start < 0)
+    real = fa.tile_pairs
+    monkeypatch.setattr(fa, "tile_pairs", lambda *call: (table := real(*call))._replace(kind=table.kind | fa._MASKED))
 
 
 # (rows, noisy rows, window, block of the block-diffusion mask or None for a causal call)
@@ -498,7 +553,7 @@ _BIT_CASES = {"causal": (512, 0, None, None), "window": (768, 0, 400, None), "di
 @pytest.mark.parametrize("case", list(_BIT_CASES))
 def test_leaving_the_mask_off_the_wholly_visible_tiles_changes_no_bit_of_out_lse_dq_dk_dv(monkeypatch, case):
     """Several tiles a side, some of them wholly visible in all three kernels:
-    the call is bit for bit the call whose every run step is masked, and
+    the call is bit for bit the call whose every grid step is masked, and
     agrees with `reference_attention` as it did."""
     from ray_tpu.ops.attention import BlockDiffusion
     from ray_tpu.ops.pallas import flash_attention as fa
@@ -512,9 +567,7 @@ def test_leaving_the_mask_off_the_wholly_visible_tiles_changes_no_bit_of_out_lse
     g = jax.random.normal(kg, (1, rows, 2, 128), jnp.float32)
     mask = dict(causal=bd is None, scale=0.125, window=window, diffusion=bd)
     for (bq, bk), keys in (((128, 128), True), ((128, 64), True), ((128, 64), False)):  # forward, dq, dkv
-        steps = _run_steps(rows, bq, bk, window, bd, keys=keys)
-        whole = sum(bool(fa._wholly_visible(qi * bq, ki * bk, bq, bk, window, bd)) for qi, ki in steps)
-        assert 0 < whole < len(steps)
+        assert 0 < len(_taken_whole(rows, bq, bk, window, bd, keys=keys)) < len(_run_steps(rows, bq, bk, window, bd, keys=keys))
 
     def everything():
         out, lse = fa._flash_fwd(q, k, v, block_q=128, block_k=128, **mask)
@@ -533,10 +586,13 @@ def test_leaving_the_mask_off_the_wholly_visible_tiles_changes_no_bit_of_out_lse
 
 
 # sha256[:16] of `str(jax.make_jaxpr(grad(flash_attention(..).sum())))`, `0x..` addresses and `.py:<line>` blanked.  Taken
-# first at the PARENT of PR 62 (to show that PR left a call without `block_diffusion` alone, kernel bodies included) and
-# RE-TAKEN at PR 63, which changes the bodies on purpose (two of them a kernel: a run step's tile is wholly visible or
-# crossed by the mask's edge): a later PR that means to leave a causal or a windowed call alone holds these.
-_PINNED_JAXPRS = {None: "412f8a8cc2ff613e", 96: "d9ec86f84133d5de"}
+# first at the PARENT of PR 62 (to show that PR left a call without `block_diffusion` alone, kernel bodies included),
+# re-taken at PR 63, which changed the bodies on purpose (two of them a kernel), and RE-TAKEN at PR 70 for both windows,
+# which changes every call's grid on purpose (one inner axis over a scalar-prefetched table of the visible tile pairs; the
+# bodies read the table where they read `program_id`s, and what all its words agree on is decided when the call is traced:
+# under this window every pair is a boundary tile, so the masked body alone is traced): a later PR that means to leave a causal or a windowed call alone
+# holds these.
+_PINNED_JAXPRS = {None: "16586049dd519e00", 96: "0417be8334e85cdd"}
 
 
 @pytest.mark.parametrize("window", [None, 96])

@@ -428,9 +428,10 @@ def test_a_windowed_call_leaves_tiles_out_of_its_grids_and_no_window_is_todays_c
         grad = jax.grad(lambda *a: jnp.sum(fa.flash_attention(*a, **kw) * do), argnums=(0, 1, 2))
         return _pallas_grids(jax.make_jaxpr(grad)(q, k, v).jaxpr)
 
-    # tiles of 128 at a window of 128: 8 x 8 tile pairs, of which a query tile sees 2, a key tile 2
-    assert grids(window=128) == {"flash_fwd": (1, 2, 8, 2), "flash_bwd_dq": (1, 2, 8, 2), "flash_bwd_dkv": (1, 2, 8, 2)}
-    assert grids() == {"flash_fwd": (1, 2, 1, 1), "flash_bwd_dq": (1, 2, 1, 2), "flash_bwd_dkv": (1, 2, 2, 1)}
+    # tiles of 128 at a window of 128: 8 x 8 tile pairs, of which a query tile sees 2 (the first 1), a key tile 2 (the last 1):
+    # since PR 70 the innermost axis walks those 15 pairs and no other step (it was 8 x 2, one step of it predicated off)
+    assert grids(window=128) == {"flash_fwd": (1, 2, 15), "flash_bwd_dq": (1, 2, 15), "flash_bwd_dkv": (1, 2, 15)}
+    assert grids() == {"flash_fwd": (1, 2, 1), "flash_bwd_dq": (1, 2, 2), "flash_bwd_dkv": (1, 2, 2)}
     # `window=None` traces nothing new: the jaxpr of the call, text for text
     text = lambda **kw: str(jax.make_jaxpr(lambda *a: fa.flash_attention(*a, **kw))(q, k, v))  # noqa: E731
     assert text() == text(window=None)

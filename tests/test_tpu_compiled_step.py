@@ -330,8 +330,8 @@ def test_the_mellum_cells_window_kernels_and_grouped_matmuls_compile_at_its_shap
 
 def test_the_glm_cells_flash_kernels_at_heads_of_256_and_grouped_matmuls_compile_at_its_shapes(topo):
     """PR 54: no new kernel, heads no cell had.  One 8,192-token sequence of
-    20 heads of 256 / 256: the forward kernel at its 1024 x 1024 tile scopes
-    16.47 MB of VMEM and libtpu refuses it (its limit is 16 MB), so
+    20 heads of 256 / 256: the forward kernel at its 1024 x 1024 tile scoped
+    16.47 MB of VMEM and libtpu refused it (its limit is 16 MB), so
     `_head_blocks` halves the key tile; the backward's 1024 x 512 fits as it
     is.  Three custom calls forward + backward.  And the grouped matmuls at
     2048 x 1536 on the lower rung's 10,240 rows of 16 held experts, both ways
@@ -351,8 +351,12 @@ def test_the_glm_cells_flash_kernels_at_heads_of_256_and_grouped_matmuls_compile
 
     with _no_compile_cache():
         text = jax.jit(grad()).lower(q, q, q, q).compile().as_text()
-        with pytest.raises(Exception, match="vmem"):  # what the tile's choice is for
-            jax.jit(lambda q, k, v: fa._flash(q, k, v, True, 256 ** -0.5, 1024, 1024, 1024, 512, None)).lower(q, q, q).compile()
+        # Until PR 70 libtpu refused the forward at 1024 x 1024 here (16.47 of 16 MB of scoped VMEM: what the tile's choice
+        # was made for).  Since PR 70 the diagonal's mask is a constant of the kernel (`_edge_mask`'s `lead`: no int32
+        # position arrays a tile wide) and it compiles, by a margin nobody has measured: the halved key tile stays until a
+        # sweep on the chip says otherwise (ROADMAP Speed 2f).
+        full = jax.jit(lambda q, k, v: fa._flash(q, k, v, True, 256 ** -0.5, 1024, 1024, 1024, 512, None)).lower(q, q, q).compile()
+        assert "flash_fwd" in full.as_text()
         rows, sizes = shaped((10240, 2048)), shaped((16,), jnp.int32)
         up = jax.jit(grouped_matmul).lower(rows, shaped((16, 2048, 1536)), sizes).compile().as_text()
         down = jax.jit(grouped_matmul).lower(shaped((10240, 1536)), shaped((16, 1536, 2048)), sizes).compile().as_text()
