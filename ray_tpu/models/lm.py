@@ -606,9 +606,12 @@ class LMTrainContext:
 
     def train_step(self, state, batch) -> Tuple[Dict, Dict]:
         # Always on: the step's period, entry to entry, for the run record's
-        # stalled steps (train/run_record.py); no span unless tracing is on.
+        # rows of the steady step and its stalled steps (train/run_record.py);
+        # no span unless tracing is on.
         clock = self._step_clock
         clock.enter()
+        if batch["tokens"].shape != clock.batch_shape:  # the global batch: every process holds it whole
+            clock.note_batch(batch["tokens"].shape)
         if not all(isinstance(x, jax.Array) for x in jax.tree_util.tree_leaves(batch)):
             t0 = time.perf_counter()
             with tracing.annotate("train_step/make_batch"):

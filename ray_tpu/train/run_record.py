@@ -16,11 +16,16 @@ Always on, whether `RAY_TPU_TRACE` is set or not:
     The driver's come from its own store, the workers' ride their `poll`
     replies (the same spans also take the flush to the head, for `ray_tpu
     timeline`);
-  * stalled steps: `StepClock`, entered by `LMTrainContext.train_step`,
-    keeps the last periods of the step and appends ONE event for a period
-    over twice their median, with what tells a descheduled thread from a
-    busy one (thread and process CPU seconds, involuntary switches, major
-    faults, garbage collections);
+  * the steady step: `StepClock`, entered by `LMTrainContext.train_step`,
+    closes a period at every entry and keeps ONE row of it (`steps.rows`:
+    the period, the seconds the library spent in it sharding the batch,
+    dispatching the step and in `train.report`, the stepping thread's CPU
+    seconds), with the tokens of a step and a summary (`steps.summary`:
+    the step's period and tokens per second, from the program's own clock);
+  * stalled steps: the same clock keeps the last periods and appends ONE
+    event for a period over twice their median, with what tells a
+    descheduled thread from a busy one (thread and process CPU seconds,
+    involuntary switches, major faults, garbage collections);
   * report delivery: seconds from `session.report` in the worker to the
     driver's `on_report`, per report;
   * step counters: the newest values of the step metrics a training context
@@ -40,6 +45,7 @@ from __future__ import annotations
 
 import bisect
 import gc
+import math
 import resource
 import statistics
 import sys
@@ -56,12 +62,14 @@ _counters: Dict[str, Any] = {}
 
 
 def counters() -> Dict[str, Any]:
-    """The record's three counters, made on first use (a process that never
-    trains registers none): `compiles` = `train_compiles_total{cache=hit|
-    miss|off}`, `stalls` = `train_step_stalls_total`, `chip_wait` =
-    `train_chip_wait_seconds`."""
+    """The record's three counters and two gauges, made on first use (a
+    process that never trains registers none): `compiles` =
+    `train_compiles_total{cache=hit|miss|off}`, `stalls` =
+    `train_step_stalls_total`, `chip_wait` = `train_chip_wait_seconds`,
+    `step_seconds` = `train_step_seconds`, `tokens_per_second` =
+    `train_tokens_per_second`."""
     if not _counters:
-        from ray_tpu.util.metrics import Counter
+        from ray_tpu.util.metrics import Counter, Gauge
 
         _counters.update(
             compiles=Counter(
@@ -77,17 +85,36 @@ def counters() -> Dict[str, Any]:
                 "train_chip_wait_seconds",
                 "seconds train workers waited for another process to release the chips",
             ),
+            step_seconds=Gauge(
+                "train_step_seconds",
+                "median period of the train step, entry to entry, over the last 4096 steps",
+                tag_keys=("rank",),
+            ),
+            tokens_per_second=Gauge(
+                "train_tokens_per_second",
+                "tokens of the global batch over the median period of the train step",
+                tag_keys=("rank",),
+            ),
         )
     return _counters
 
 
-# -- worker side: stalled steps -------------------------------------------------
+# -- worker side: the steady step and its stalls ----------------------------------
+
+SERIES_KEPT = 4096  # steps of the rows and of the counters' series a record keeps, the newest
 
 _stalls: Deque[Dict[str, Any]] = deque(maxlen=256)
+_rows: Deque[list] = deque(maxlen=SERIES_KEPT)  # closed periods not drained yet, oldest first; by position `ROW`
+_stepping: Optional["StepClock"] = None  # the clock this process entered last: `train.report` marks its open period
 _gc_totals = [0, 0.0, 0.0]  # collections, seconds, start of the one running
 # What `StepClock.enter` stamps at a step's entry (a list, for its cost), by position:
-# the last two are the slots `StepClock.mark` takes.
-_T, _WALL, _THREAD_CPU, _PROCESS_CPU, _SWITCHES, _FAULTS, _GC_N, _GC_S, MAKE_BATCH, DISPATCH = range(10)
+# the last three are the slots `StepClock.mark` and `add_report_seconds` take.
+_T, _WALL, _THREAD_CPU, _PROCESS_CPU, _SWITCHES, _FAULTS, _GC_N, _GC_S, MAKE_BATCH, DISPATCH, REPORT = range(11)
+ROW = ("step", "start", "period_s", "make_batch_s", "dispatch_s", "report_s", "thread_cpu_s")
+# The clocks, given as `tracing._clock` is: names a test moves by hand.
+_clock = time.perf_counter  # periods and slots
+_wall = time.time  # where a row or an event lies among the record's spans
+_thread_cpu = time.thread_time
 
 
 def _on_gc(phase: str, info: Dict[str, Any]) -> None:
@@ -101,11 +128,12 @@ def _on_gc(phase: str, info: Dict[str, Any]) -> None:
 class StepClock:
     """Periods of a train step, entry to entry, on the calling thread.
 
-    `enter()` closes the period that is open and opens the next; a closed
-    period over `FACTOR` times the median of the ring (once it holds
-    `MIN_PERIODS`) appends one event to the process's stall events, which
-    the worker's `poll` hands to the driver.  A few clock reads and one
-    `getrusage` per step, no span: about 3 us."""
+    `enter()` closes the period that is open and opens the next.  A closed
+    period leaves one row (`ROW`) in the process's ring, which the worker's
+    `poll` hands to the driver; one over `FACTOR` times the median of the
+    clock's own ring (once it holds `MIN_PERIODS`) also appends one event to
+    the process's stall events.  A few clock reads, one `getrusage` and one
+    append per step, no span: about 3 us."""
 
     RING = 4096
     MIN_PERIODS = 5
@@ -117,19 +145,24 @@ class StepClock:
         self._open: Optional[list] = None
         self._last_thread_cpu = 0.0
         self.steps = 0
+        self.batch_shape: Optional[tuple] = None
+        self.tokens_per_step: Optional[int] = None
         if _on_gc not in gc.callbacks:
             gc.callbacks.append(_on_gc)
 
     def enter(self) -> None:
-        now = time.perf_counter()
+        global _stepping
+        now = _clock()
         ru = resource.getrusage(resource.RUSAGE_SELF)
-        stamps = [now, time.time(), time.thread_time(), ru.ru_utime + ru.ru_stime, ru.ru_nivcsw,
-                  ru.ru_majflt, _gc_totals[0], _gc_totals[1], 0.0, 0.0]  # in the order of _T .. DISPATCH
+        stamps = [now, _wall(), _thread_cpu(), ru.ru_utime + ru.ru_stime, ru.ru_nivcsw,
+                  ru.ru_majflt, _gc_totals[0], _gc_totals[1], 0.0, 0.0, 0.0]  # in the order of _T .. REPORT
         prev, self._open = self._open, stamps
+        _stepping = self
         if prev is None:
             return
         period = now - prev[_T]
         thread_cpu = stamps[_THREAD_CPU] - prev[_THREAD_CPU]
+        _rows.append([self.steps, prev[_WALL], period, prev[MAKE_BATCH], prev[DISPATCH], prev[REPORT], thread_cpu])
         ring, ordered = self._ring, self._sorted
         if len(ring) >= self.MIN_PERIODS and period > self.FACTOR * ordered[len(ordered) // 2]:
             self._stalled(prev, stamps, period, thread_cpu, ordered[len(ordered) // 2])
@@ -144,7 +177,12 @@ class StepClock:
     def mark(self, slot: int, since: float) -> None:
         """Add the seconds since `since` to the open period's `MAKE_BATCH`
         or `DISPATCH` slot."""
-        self._open[slot] += time.perf_counter() - since
+        self._open[slot] += _clock() - since
+
+    def note_batch(self, shape: tuple) -> None:
+        """The global batch's `tokens` shape, once per shape: its product is
+        the tokens of a step."""
+        self.batch_shape, self.tokens_per_step = shape, math.prod(shape)
 
     def _stalled(self, prev: list, now: list, period: float, thread_cpu: float, median: float) -> None:
         excess = period - median
@@ -163,6 +201,15 @@ class StepClock:
         counters()["stalls"].inc()
 
 
+def add_report_seconds(seconds: float) -> None:
+    """`train.report`'s own seconds, to the `REPORT` slot of the period this
+    process's stepping has open; nothing where none is (a loop that never
+    calls `train_step`)."""
+    clock = _stepping
+    if clock is not None and clock._open is not None:
+        clock._open[REPORT] += seconds
+
+
 def drain_stalls() -> List[Dict[str, Any]]:
     out = []
     while _stalls:
@@ -170,9 +217,32 @@ def drain_stalls() -> List[Dict[str, Any]]:
     return out
 
 
-# -- worker side: step counters --------------------------------------------------
+def drain_step_rows() -> List[list]:
+    """The periods closed since the last `poll`, oldest first, each by `ROW`."""
+    out = []
+    while _rows:
+        out.append(_rows.popleft())
+    return out
 
-SERIES_KEPT = 4096  # steps of the counters' series a record keeps, the newest
+
+def set_step_gauges(rank: int) -> Optional[int]:
+    """At a `poll`, not per step: `train_step_seconds` (the median of the
+    stepping clock's ring) and `train_tokens_per_second` for `ray_tpu
+    metrics` on a live job, under the worker's `rank` (every rank of an SPMD
+    job steps the same global batch: the cluster's view adds up what shares
+    a tag).  Returns the clock's tokens per step, for the record."""
+    clock = _stepping
+    if clock is None:
+        return None
+    if clock._sorted:
+        median, tags = clock._sorted[len(clock._sorted) // 2], {"rank": str(rank)}
+        counters()["step_seconds"].set(median, tags=tags)
+        if clock.tokens_per_step and median > 0:
+            counters()["tokens_per_second"].set(clock.tokens_per_step / median, tags=tags)
+    return clock.tokens_per_step
+
+
+# -- worker side: step counters --------------------------------------------------
 
 _noted: Dict[str, Any] = {}  # name -> the newest step's value, still on the device
 _series: Deque[Any] = deque(maxlen=SERIES_KEPT)  # (step, {name: value}) not drained yet, oldest first
@@ -336,6 +406,8 @@ class RunRecord:
         self.delivery_s: List[float] = []
         self.step_counters: Dict[str, float] = {}
         self.step_series: Deque[List[Any]] = deque(maxlen=SERIES_KEPT)
+        self.step_rows: Deque[list] = deque(maxlen=SERIES_KEPT)  # `ROW` + the worker's rank
+        self.tokens_per_step: Optional[int] = None
         self.polls = 0
 
     def add_poll(self, rank: int, reply: Dict[str, Any]) -> None:
@@ -349,6 +421,8 @@ class RunRecord:
             self.stalls.append(dict(e, rank=rank))
         self.step_counters.update(reply.get("step_counters") or {})
         self.step_series.extend(reply.get("step_series") or ())
+        self.step_rows.extend([*row, rank] for row in reply.get("step_rows") or ())
+        self.tokens_per_step = reply.get("tokens_per_step") or self.tokens_per_step
         for rep in reply["reports"]:
             if "t" in rep:
                 self.delivery_s.append(now - rep["t"])
@@ -369,10 +443,36 @@ class RunRecord:
             "stalls": list(self.stalls),
             "step_counters": dict(self.step_counters),
             "step_counter_series": list(self.step_series),
+            "steps": self._steps(),
             "reports": {"count": len(d), "polls": self.polls,
                         "median_s": statistics.median(d) if d else None,
                         "max_s": max(d) if d else None},
         }
+
+
+    def _steps(self) -> Dict[str, Any]:
+        """The steady step from inside: the newest `SERIES_KEPT` periods as
+        rows (`ROW` and `rank`), the tokens of a step, and the rows' summary:
+        the period's median, p10, p90 and max, the median of each slot and of
+        the thread's CPU seconds, tokens per second over the median period, and
+        `thread_cpu_share`: the thread's CPU seconds over the periods', both
+        summed over the rows of at most `StepClock.FACTOR` medians (where
+        `time.thread_time` moves in ticks of 10 ms, as on a TPU VM's host, the
+        median row's CPU seconds read 0 and only the totals say anything)."""
+        rows = [dict(zip(ROW + ("rank",), row)) for row in self.step_rows]
+        summary: Dict[str, Any] = {"count": len(rows)}
+        if rows:
+            periods = sorted(r["period_s"] for r in rows)
+            median = statistics.median(periods)
+            summary.update(
+                period_s={"median": median, "p10": periods[round(0.1 * (len(periods) - 1))],
+                          "p90": periods[round(0.9 * (len(periods) - 1))], "max": periods[-1]},
+                **{key: statistics.median(r[key] for r in rows) for key in ROW[3:]},
+                tokens_per_s=self.tokens_per_step / median if self.tokens_per_step and median > 0 else None)
+            steady = [r for r in rows if r["period_s"] <= StepClock.FACTOR * median]
+            summary["thread_cpu_share"] = (sum(r["thread_cpu_s"] for r in steady)
+                                           / max(sum(r["period_s"] for r in steady), 1e-12))
+        return {"tokens_per_step": self.tokens_per_step, "rows": rows, "summary": summary}
 
 
 def _by_start(spans) -> List[Dict[str, Any]]:

@@ -13,6 +13,8 @@ import time
 from typing import Any, Dict, List, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.train import run_record
+from ray_tpu.util import tracing
 
 _session: Optional["TrainSession"] = None
 
@@ -40,9 +42,13 @@ class TrainSession:
 
     def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None):
         # "t" stamps the report beside the payload: the driver takes the
-        # seconds to its `on_report` from it (train/run_record.py).
-        with self._lock:
+        # seconds to its `on_report` from it (train/run_record.py).  The call's
+        # own seconds go to the step that is open, and under a profiler the
+        # span sits beside `train_step/*` on the device planes' clock.
+        t0 = time.perf_counter()
+        with tracing.annotate("train/report"), self._lock:
             self._reports.append({"metrics": dict(metrics), "checkpoint": checkpoint, "t": time.time()})
+        run_record.add_report_seconds(time.perf_counter() - t0)
 
     def drain(self) -> List[Dict[str, Any]]:
         with self._lock:

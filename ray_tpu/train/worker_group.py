@@ -74,15 +74,16 @@ class TrainWorker:
     def poll(self) -> Dict[str, Any]:
         """Drain buffered session.report() payloads (driver poll loop), and
         with them what this worker has for the run's record: its lifecycle
-        spans of the caller's trace not sent yet, its stalled steps and the
-        step counters the device has finished."""
+        spans of the caller's trace not sent yet, its steady steps' rows, its
+        stalled steps and the step counters the device has finished."""
         spans, recorded = [], tracing.lifecycle_count()
         ctx = tracing.current_context()
         if ctx and recorded != self._spans_seen:  # nothing to scan in a steady step
             spans = tracing.lifecycle_spans(ctx["trace_id"], since=self._spans_seen)
             self._spans_seen = recorded
         out = {"reports": [], "done": False, "spans": spans, "stalls": run_record.drain_stalls(),
-               "step_counters": run_record.drain_step_counters(), "step_series": run_record.drain_step_series()}
+               "step_counters": run_record.drain_step_counters(), "step_series": run_record.drain_step_series(),
+               "step_rows": run_record.drain_step_rows(), "tokens_per_step": run_record.set_step_gauges(self.rank)}
         if self.session is not None:
             out["reports"], out["done"] = self.session.drain(), self.session.done
         return out

@@ -1,6 +1,7 @@
 """The run record (`ray_tpu/train/run_record.py`): one per `fit()`, kept with
 `RAY_TPU_TRACE` unset — lifecycle spans under one trace id across the
-driver/worker hop, compile events, stalled steps, report delivery."""
+driver/worker hop, compile events, the steady step's rows, stalled steps,
+report delivery."""
 
 import errno
 import os
@@ -31,8 +32,13 @@ def _loop(config):
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.train import run_record
+
     f = jax.jit(lambda x: jnp.tanh(x) @ x)
+    clock = run_record.StepClock()  # the step's clock alone, as `LMTrainContext.train_step` drives it
+    clock.note_batch((2, 8))
     for i in range(6):
+        clock.enter()
         train.report({"i": i, "y": float(f(jnp.ones((8, 8)))[0, 0])})
 
 
@@ -274,38 +280,178 @@ def toy():
     return ctx, state, batch
 
 
+class FakeClock:
+    """A clock moved by hand: `tracing._clock`, or `run_record._clock` and `_wall`."""
+
+    def __init__(self, now=100.0):
+        self.now = now
+
+    def __call__(self):
+        return self.now
+
+    def tick(self, seconds):
+        self.now += seconds
+
+
+@pytest.fixture
+def step_time(monkeypatch):
+    """`StepClock`'s period and wall clocks as one hand-moved clock; the
+    process's rows, stall events and stepping clock start empty and are left so."""
+    fake = FakeClock()
+    monkeypatch.setattr(run_record, "_clock", fake)
+    monkeypatch.setattr(run_record, "_wall", fake)
+    monkeypatch.setattr(run_record, "_stepping", None)
+    run_record.drain_step_rows(), run_record.drain_stalls()
+    yield fake
+    run_record.drain_step_rows(), run_record.drain_stalls()
+
+
+def _sleep(seconds):
+    time.sleep(0.1 * seconds)  # off the CPU for as long as it lasts: its length is the hand-moved clock's
+
+
 def _busy(seconds):
-    end = time.perf_counter() + seconds
-    while time.perf_counter() < end:
+    end = time.thread_time() + seconds  # ON a CPU for `seconds`, however long a loaded host takes to give them
+    while time.thread_time() < end:
         pass
 
 
-@pytest.mark.parametrize("pause, off_cpu", [(time.sleep, (90.0, 100.0)), (_busy, (0.0, 50.0))],
+@pytest.mark.parametrize("pause, off_cpu", [(_sleep, (99.0, 100.0)), (_busy, (0.0, 50.0))],
                          ids=["sleep_is_off_cpu", "busy_loop_is_on_cpu"])
-def test_a_pause_between_two_steps_gives_one_stall_event_that_says_where_the_thread_was(toy, pause, off_cpu):
-    """(Bounds with room for a loaded host: a busy loop that is descheduled
-    for a part of its time is off the CPU for that part, and says so.)"""
-    ctx, state, batch = toy
-    ctx._step_clock = run_record.StepClock()
-    run_record.drain_stalls()
+def test_a_pause_between_two_steps_gives_one_stall_event_that_says_where_the_thread_was(step_time, pause, off_cpu):
+    """The periods are the hand-moved clock's, so the index, the period, the
+    one event and the counter are exact on any host; where the thread was
+    is the REAL `time.thread_time` around a real pause."""
+    clock = run_record.StepClock()
     stalls_before = run_record.counters()["stalls"].snapshot().get((), 0.0)
     for i in range(16):
-        time.sleep(0.03)  # a steady step of 30 ms: the host's jitter stays under twice it
-        if i == 10:
-            pause(0.6)
-        state, metrics = ctx.train_step(state, batch)
-        jax.block_until_ready(metrics["loss"])
-    toy[1].update(state)  # the step donates its state: hand the live one on
+        clock.enter()
+        since = step_time.now
+        step_time.tick(0.002)
+        clock.mark(run_record.DISPATCH, since)
+        step_time.tick(0.028)  # a steady step of 30 ms
+        if i == 9:
+            pause(0.2)
+            step_time.tick(0.2)
     events = run_record.drain_stalls()
-    assert run_record.counters()["stalls"].snapshot()[()] == stalls_before + len(events)
-    events = [e for e in events if e["period_s"] >= 0.3]
+    assert run_record.counters()["stalls"].snapshot()[()] == stalls_before + 1
     assert len(events) == 1, events
     e = events[0]
-    assert e["step"] == 9 and 0.6 <= e["period_s"] < 3.0 and e["period_s"] > 2 * e["median_s"]
-    assert e["end"] - e["start"] == pytest.approx(e["period_s"], abs=0.05)
+    assert e["step"] == 9 and e["period_s"] == pytest.approx(0.23) and e["median_s"] == pytest.approx(0.03)
+    assert e["end"] - e["start"] == pytest.approx(e["period_s"])
     assert off_cpu[0] <= e["off_cpu_pct"] <= off_cpu[1], e
-    assert 0.0 < e["dispatch_s"] < e["period_s"] and e["make_batch_s"] == 0.0
+    assert e["dispatch_s"] == pytest.approx(0.002) and e["make_batch_s"] == 0.0
     assert e["process_cpu_s"] >= e["thread_cpu_s"] - 0.01 and e["gc_collections"] >= 0
+    rows = run_record.drain_step_rows()
+    assert [r[0] for r in rows] == list(range(15)) and rows[9][2] == e["period_s"]  # the stalled step has its row too
+
+
+# -- the steady step: a row per period -------------------------------------------------
+
+
+def test_n_steps_leave_n_minus_one_rows_whose_slots_add_up_under_the_period(step_time, monkeypatch):
+    cpu = FakeClock(0.0)
+    monkeypatch.setattr(run_record, "_thread_cpu", cpu)
+    clock = run_record.StepClock()
+    for i in range(5):
+        clock.enter()
+        for slot, seconds in ((run_record.MAKE_BATCH, 0.001 * (i + 1)), (run_record.DISPATCH, 0.002)):
+            since = step_time.now
+            step_time.tick(seconds)
+            clock.mark(slot, since)
+        run_record.add_report_seconds(0.0005)
+        cpu.tick(0.004)
+        step_time.tick(0.1)
+    rows = [dict(zip(run_record.ROW, r)) for r in run_record.drain_step_rows()]
+    assert [r["step"] for r in rows] == [0, 1, 2, 3] and clock.steps == 4  # the last step's period is still open
+    for i, r in enumerate(rows):
+        assert r["make_batch_s"] == pytest.approx(0.001 * (i + 1)) and r["dispatch_s"] == pytest.approx(0.002)
+        assert r["report_s"] == 0.0005 and r["thread_cpu_s"] == pytest.approx(0.004)
+        assert r["period_s"] == pytest.approx(0.102 + 0.001 * (i + 1)) and r["start"] == pytest.approx(
+            100.0 + sum(0.102 + 0.001 * (j + 1) for j in range(i)))
+        assert r["make_batch_s"] + r["dispatch_s"] + r["report_s"] < r["period_s"]
+    assert run_record.drain_step_rows() == []  # drained once
+
+
+def test_report_lands_in_the_step_that_was_open_and_nowhere_without_one(step_time):
+    from ray_tpu.train.session import TrainSession
+
+    session = TrainSession(rank=0, world_size=1)
+    session.report({"before": "any step"})  # no period is open: nothing to mark, nothing raised
+    clock = run_record.StepClock()
+    for i in range(4):
+        clock.enter()
+        step_time.tick(0.1)
+        if i == 1:
+            session.report({"i": i})
+            session.report({"i": i, "again": True})
+    report_s = [r[run_record.ROW.index("report_s")] for r in run_record.drain_step_rows()]
+    assert report_s[0] == report_s[2] == 0.0 and 0.0 < report_s[1] < 0.1  # two real calls, both in step 1
+    assert len(session.drain()) == 3
+
+
+def test_train_step_keeps_a_row_a_step_with_the_tokens_of_its_batch(toy):
+    """Through `LMTrainContext.train_step`, on the real clocks: what must hold on any host."""
+    ctx, state, _ = toy
+    ctx._step_clock = run_record.StepClock()
+    run_record.drain_step_rows()
+    toks = np.zeros((2, 32), np.int32)
+    for _ in range(4):
+        state, metrics = ctx.train_step(state, {"tokens": toks, "targets": toks})  # a host batch: `make_batch` runs
+    jax.block_until_ready(metrics["loss"])
+    toy[1].update(state)  # the step donates its state: hand the live one on
+    rows = [dict(zip(run_record.ROW, r)) for r in run_record.drain_step_rows()]
+    assert [r["step"] for r in rows] == [0, 1, 2] and ctx._step_clock.tokens_per_step == 64
+    for r in rows:
+        assert 0.0 < r["make_batch_s"] and 0.0 < r["dispatch_s"] and r["report_s"] == 0.0
+        assert r["make_batch_s"] + r["dispatch_s"] <= r["period_s"] and 0.0 <= r["thread_cpu_s"]
+    assert run_record.set_step_gauges(rank=3) == 64  # what the worker's `poll` does
+    gauges = run_record.counters()
+    median = sorted(r["period_s"] for r in rows)[1]
+    assert gauges["step_seconds"].snapshot()[(("rank", "3"),)] == median
+    assert gauges["tokens_per_second"].snapshot()[(("rank", "3"),)] == pytest.approx(64 / median)
+    from ray_tpu.util import metrics
+
+    assert {"train_step_seconds", "train_tokens_per_second"} <= set(metrics.collect())  # what `ray_tpu metrics` shows
+
+
+def test_rows_cross_two_polls_once_each_and_the_summary_reads_a_known_series():
+    record = run_record.RunRecord({"trace_id": "t", "span_id": "s"})
+    assert record.to_dict()["steps"] == {"tokens_per_step": None, "rows": [], "summary": {"count": 0}}
+    periods = [0.5, 0.1, 0.2, 0.3, 0.4, 0.6, 0.7, 0.8, 0.9, 1.0, 5.0]
+    rows = [[i, 10.0 + i, p, 0.001 * i, 0.002, 0.003, 0.5 * p] for i, p in enumerate(periods)]
+    record.add_poll(0, {"reports": [], "step_rows": rows[:4], "tokens_per_step": 1200})
+    record.add_poll(1, {"reports": [], "step_rows": rows[4:], "tokens_per_step": None})  # a poll before any step
+    record.add_poll(0, {"reports": []})  # a parent's worker: no rows in its reply
+    steps = record.to_dict()["steps"]
+    assert [r["step"] for r in steps["rows"]] == list(range(11))
+    assert [r["rank"] for r in steps["rows"]] == [0] * 4 + [1] * 7
+    assert steps["rows"][5] == {"step": 5, "start": 15.0, "period_s": 0.6, "make_batch_s": 0.005,
+                                "dispatch_s": 0.002, "report_s": 0.003, "thread_cpu_s": 0.3, "rank": 1}
+    assert steps["tokens_per_step"] == 1200
+    assert steps["summary"] == {
+        "count": 11, "period_s": {"median": 0.6, "p10": 0.2, "p90": 1.0, "max": 5.0},
+        "make_batch_s": 0.005, "dispatch_s": 0.002, "report_s": 0.003, "thread_cpu_s": 0.3,
+        "tokens_per_s": pytest.approx(2000.0),
+        "thread_cpu_share": pytest.approx(0.5)}  # of the ten rows of at most two medians: the 5 s one is set apart
+    record.add_poll(0, {"reports": [], "step_rows": [[11 + i, 0.0, 0.1, 0.0, 0.0, 0.0, 0.0]
+                                                      for i in range(run_record.SERIES_KEPT)]})
+    assert len(record.step_rows) == run_record.SERIES_KEPT and record.step_rows[0][0] == 11  # the newest are kept
+
+
+def test_fit_with_tracing_off_has_the_steps_in_its_record(fit_record):
+    result, record, _ = fit_record
+    assert result.run_record["steps"]["summary"]["count"] == 5  # six entries close five periods
+    steps = record["steps"]
+    assert [(r["step"], r["rank"]) for r in steps["rows"]] == [(i, 0) for i in range(5)]
+    assert steps["tokens_per_step"] == 16
+    run_fn = _by_name(record)["train::worker::run_train_fn"][0]
+    for r in steps["rows"]:
+        assert 0.0 < r["report_s"] < r["period_s"] and r["make_batch_s"] == r["dispatch_s"] == 0.0
+        assert run_fn["start"] - SLACK_S <= r["start"] <= run_fn["end"] + SLACK_S  # on the spans' clock
+    summary = steps["summary"]
+    assert summary["period_s"]["p10"] <= summary["period_s"]["median"] <= summary["period_s"]["p90"]
+    assert summary["tokens_per_s"] == pytest.approx(16 / summary["period_s"]["median"])
 
 
 def test_five_thousand_steps_leave_the_ring_bounded_no_step_span_and_cost_microseconds(monkeypatch):
@@ -317,6 +463,7 @@ def test_five_thousand_steps_leave_the_ring_bounded_no_step_span_and_cost_micros
     ctx._train_step = lambda state, batch: (state, {})
     batch = {"tokens": jnp.zeros((1, 1), jnp.int32)}
     tracing.drain_spans()
+    run_record.drain_step_rows()
 
     def run(n):
         t0 = time.perf_counter()
@@ -324,30 +471,20 @@ def test_five_thousand_steps_leave_the_ring_bounded_no_step_span_and_cost_micros
             ctx.train_step(None, batch)
         return (time.perf_counter() - t0) / n
 
-    with_clock = min(run(2500), run(2500))
+    with_clock = min(run(500) for _ in range(10))  # the least of ten short bursts: a loaded host disturbs few of them
     assert clock.steps == 4999 and len(clock._ring) == len(clock._sorted) == 1024
     assert clock._sorted == sorted(clock._ring)
-    ctx._step_clock = type("NoClock", (), {"enter": lambda s: None, "mark": lambda s, a, b: None})()
-    without = min(run(2500), run(2500))
+    rows = run_record.drain_step_rows()  # no poll drained them: the newest SERIES_KEPT are left
+    assert len(rows) == run_record.SERIES_KEPT and [rows[0][0], rows[-1][0]] == [4999 - run_record.SERIES_KEPT, 4998]
+    ctx._step_clock = type("NoClock", (), {"enter": lambda s: None, "mark": lambda s, a, b: None,
+                                           "batch_shape": (1, 1)})()
+    without = min(run(500) for _ in range(10))
     assert with_clock - without < 20e-6, (with_clock, without)
     assert [s for s in tracing.drain_spans() if s["name"].startswith("train_step/")] == []
     run_record.drain_stalls()
 
 
 # -- scopes: the table of `tracing.scope` and the span it lands on ---------------------
-
-
-class FakeClock:
-    """`tracing._clock`, moved by hand."""
-
-    def __init__(self, now=100.0):
-        self.now = now
-
-    def __call__(self):
-        return self.now
-
-    def tick(self, seconds):
-        self.now += seconds
 
 
 @pytest.fixture
